@@ -42,7 +42,33 @@ def _maybe_init_distributed():
             pass
 
 
+def _place_compile_cache():
+    """Point jax's persistent compilation cache at a fixed directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself and nothing is
+    set here — that is how a deployment (or a machine that keeps one
+    directory between runs) moves the cache. Unset: the cache lives at
+    ``<checkout>/.jax_cache``, derived from this package's own location
+    and nothing else — the directory is part of jax's cache key, so a
+    path that moves between runs never hits. Every ``lower().compile()``
+    (``aot.AOTProgram``, the fused train step) goes through this cache —
+    except a window compiled with compiler-chosen layouts, which
+    ``executor._compile_uncached`` keeps out of it. Must run before the
+    first compile, so it lives at package import.
+    """
+    import os
+
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(root, ".jax_cache"))
+
+
 _maybe_init_distributed()
+_place_compile_cache()
 
 from .base import MXNetError, __version__
 from . import env  # noqa: F401 (also imported inside _maybe_init_distributed)

@@ -69,7 +69,9 @@ class Context:
         ``cpu`` → jax CPU backend devices. ``tpu``/``gpu`` → the default
         (accelerator) backend's devices; on a TPU machine ``gpu(i)`` therefore
         lands on TPU chip ``i``, which is exactly the portability the
-        reference scripts need.
+        reference scripts need. When the default backend IS the CPU (no
+        accelerator found, or ``JAX_PLATFORMS=cpu``) an accelerator context
+        raises instead of quietly computing on the host.
         """
         import jax
 
@@ -77,9 +79,12 @@ class Context:
             devs = jax.devices("cpu")
         else:
             devs = jax.devices()  # default backend: tpu when present
-            if devs and devs[0].platform == "cpu" and self.device_type == "tpu":
-                # CPU-only test environment: tpu(i) falls back to cpu(i).
-                pass
+            if devs[0].platform == "cpu":
+                raise MXNetError(
+                    f"{self}: no accelerator — the default jax backend is "
+                    f"'cpu' ({len(devs)} device(s)); use mx.cpu() to run "
+                    "on the host"
+                )
         if jax.process_count() > 1:
             # multi-host: device ids index THIS process's devices (the
             # reference's dev_id is per-worker); the global list would
@@ -128,6 +133,19 @@ def current_context():
     if not hasattr(Context._default_ctx, "value"):
         Context._default_ctx.value = Context("cpu", 0)
     return Context._default_ctx.value
+
+
+def is_tpu(ctx=None):
+    """True when ``ctx`` (default: the default jax backend) is a TPU chip.
+
+    The one device test behind every TPU-only choice — NHWC lowering, TPU
+    compiler options, compiler-chosen window layouts. A context that cannot
+    resolve raises rather than answering "no": a chip that failed to
+    initialise must not silently select the host code paths."""
+    import jax
+
+    dev = jax.devices()[0] if ctx is None else ctx.jax_device()
+    return dev.platform == "tpu"
 
 
 def num_gpus():

@@ -126,7 +126,7 @@ _declare("MXNET_TRAIN_WINDOW", str, "",
          "train_window(K) chunks; 'auto' probes a few single-step batches "
          "and picks K from the measured dispatch-vs-residual telemetry "
          "ratio (aot.choose_train_window) — deep windows on "
-         "dispatch-bound (tunneled) runtimes, K=1 when device/data-bound. "
+         "dispatch-bound loops, K=1 when device/data-bound. "
          "Windows move lr-schedule and metric updates to window "
          "granularity. Empty (default) keeps the per-batch loop.")
 _declare("MXNET_DISPATCH_DEPTH", str, "",

@@ -455,7 +455,7 @@ class BaseModule:
             window = None
         if window is not None and guard is not None and \
                 guard.mode == "rollback":
-            # boundary-fence taxonomy (docs/architecture.md): rollback
+            # boundary-fence classes (docs/architecture.md): rollback
             # escalation restores checkpointed state, so its decision
             # points must see a fully drained pipeline — no window may
             # still be in flight past a boundary it could roll back over.

@@ -506,9 +506,9 @@ class Module(BaseModule):
         module already fuses a whole step into one donated program, and a
         window goes one further: ``lax.fori_loop`` advances parameters,
         optimizer state, BatchNorm statistics and the rng counter on-device
-        across iterations, so K steps cost one host dispatch. On
-        dispatch-latency-bound runtimes (remote/tunneled chips) this
-        removes a per-execute round trip that host pipelining cannot hide.
+        across iterations, so K steps cost one host dispatch — where the
+        per-execute host cost is what bounds the loop, the window divides
+        it by K.
 
         ``data_batch`` alone trains every iteration on that batch (the
         reference's ``--benchmark 1`` synthetic methodology). ``batches``

@@ -36,6 +36,7 @@ other layout never false-hits).
 from __future__ import annotations
 
 from ..base import MXNetError
+from ..context import is_tpu
 
 __all__ = [
     "resolve", "aware", "follower", "to_cl", "from_cl",
@@ -55,21 +56,7 @@ def resolve(ctx=None):
     if val != "AUTO":
         raise MXNetError(
             f"MXNET_CONV_LAYOUT={val!r}: expected NCHW, NHWC or auto")
-    return "NHWC" if _is_tpu(ctx) else "NCHW"
-
-
-def _is_tpu(ctx):
-    try:
-        if ctx is not None:
-            dev = ctx.jax_device()
-        else:
-            import jax
-
-            dev = jax.devices()[0]
-        return dev.platform == "tpu" or "TPU" in getattr(
-            dev, "device_kind", "")
-    except Exception:
-        return False
+    return "NHWC" if is_tpu(ctx) else "NCHW"
 
 
 def to_cl(x):

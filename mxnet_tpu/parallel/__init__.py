@@ -11,7 +11,7 @@ program. The mental model is the standard TPU recipe: pick a mesh,
 annotate shardings, let XLA insert collectives over ICI/DCN.
 """
 
-from .compat import shard_map, supports_shard_map
+from .compat import shard_map
 from .mesh import (
     GraftMesh,
     as_graft,

@@ -1,69 +1,18 @@
-"""jax version compatibility for the manual-collectives surface.
+"""The framework's one spelling of ``jax.shard_map``.
 
-Every ``shard_map`` user in the framework (GPipe pipeline, ring attention,
-the composed-mesh train step) routes through :func:`shard_map` here instead
-of touching ``jax.shard_map`` directly. The API moved twice upstream:
-
-* jax >= 0.5: top-level ``jax.shard_map`` with the ``check_vma`` flag
-  (varying-manual-axes replication checking);
-* jax 0.4.x: ``jax.experimental.shard_map.shard_map`` with the older
-  ``check_rep`` flag and no ``jax.lax.pcast``.
-
-One shim keeps call sites on the modern spelling and degrades the
-replication-checking knob on runtimes that cannot express it — on the
-0.4.x API the checker is disabled outright (its rep-tracking rejects the
-fori-loop accumulator patterns ``pcast`` exists to bless, and ``pcast``
-itself does not exist there). Semantics are unchanged either way: the
-checks are compile-time lints, not runtime behavior.
+Every ``shard_map`` user (GPipe pipeline, ring attention, the composed-mesh
+train step) routes through :func:`shard_map` here so each can hand the
+installed :class:`~mxnet_tpu.parallel.mesh.GraftMesh` straight through.
 """
 
 from __future__ import annotations
 
 
-def _resolve():
-    import jax
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, "vma"
-    from jax.experimental.shard_map import shard_map as legacy
-
-    return legacy, "rep"
-
-
 def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions (see module docstring).
-
-    ``mesh`` may be a raw ``jax.sharding.Mesh`` or a
-    :class:`~mxnet_tpu.parallel.mesh.GraftMesh` (unwrapped here so every
-    caller can hand the installed mesh straight through).
-    """
-    raw = getattr(mesh, "mesh", mesh)
-    fn, flavor = _resolve()
-    if flavor == "vma":
-        return fn(f, mesh=raw, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    return fn(f, mesh=raw, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
-def to_varying(x, axis_name):
-    """``jax.lax.pcast(x, axis, to="varying")`` where it exists; identity on
-    jax 0.4.x, whose shard_map runs with replication checking off (the cast
-    is purely a checker annotation — values are untouched on every
-    version)."""
+    """``jax.shard_map`` over a raw ``jax.sharding.Mesh`` or a
+    :class:`~mxnet_tpu.parallel.mesh.GraftMesh` (unwrapped here)."""
     import jax
 
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axis_name, to="varying")
-
-
-def supports_shard_map():
-    """True when some shard_map implementation is importable."""
-    try:
-        _resolve()
-        return True
-    except Exception:
-        return False
+    return jax.shard_map(f, mesh=getattr(mesh, "mesh", mesh),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)

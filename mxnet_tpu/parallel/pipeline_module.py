@@ -906,7 +906,7 @@ class PipelineEngine:
                           for n in self.infos[-1].label_names]
         # the rng key stays device-resident across steps (each program
         # returns its successor) — a fresh host-built key per execute
-        # would stall the dispatch pipeline on tunneled runtimes, the
+        # would stall the dispatch pipeline on a host->device upload, the
         # failure mode executor.py's _next_step exists to avoid
         if self._rng_dev is None:
             self._rng_dev = jax.random.PRNGKey(0)

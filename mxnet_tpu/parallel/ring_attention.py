@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
-from .compat import shard_map as _shard_map, to_varying as _to_varying
+from .compat import shard_map as _shard_map
 
 
 def _ring_attn_shard(q, k, v, axis_name, causal, scale):
@@ -40,7 +40,7 @@ def _ring_attn_shard(q, k, v, axis_name, causal, scale):
 
     # accumulators are per-device state (varying over the ring axis)
     def _vary(x):
-        return _to_varying(x, axis_name)
+        return jax.lax.pcast(x, axis_name, to="varying")
 
     o = _vary(jnp.zeros((B, H, Tl, D), jnp.float32))
     m = _vary(jnp.full((B, H, Tl), -jnp.inf, jnp.float32))

@@ -47,9 +47,7 @@ def get_state():
     import numpy as np
 
     key = _get_key()
-    data = jax.random.key_data(key) if hasattr(jax.random, "key_data") \
-        else key
-    return [int(x) for x in np.asarray(data).ravel()]
+    return [int(x) for x in np.asarray(jax.random.key_data(key)).ravel()]
 
 
 def set_state(state):

@@ -1,0 +1,128 @@
+"""What the chip bring-up relies on, as far as a CPU can check it.
+
+* jax's persistent compilation cache is placed from OUTSIDE when
+  ``JAX_COMPILATION_CACHE_DIR`` is set, and otherwise at one fixed path
+  derived from the package's own location (``<checkout>/.jax_cache``) — the
+  same path in every process, so a second run finds what the first compiled.
+* A window compiled with compiler-chosen layouts stays OUT of that cache
+  (``executor._compile_uncached``): under jax 0.9.0 the outputs of such an
+  executable, once deserialized, misreport their layout (seen on the v5e).
+* An accelerator context never lands on the host: ``mx.tpu(0)`` raises on
+  the CPU backend, and ``chip_smoke.py`` refuses to run without a TPU.
+
+The cache tests run in subprocesses: conftest switches the cache off for the
+pytest process itself (a hermetic suite does not read a developer's cache).
+"""
+
+import os
+import subprocess
+import sys
+import time
+
+import pytest
+
+import mxnet_tpu as mx
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CHECKOUT_CACHE = os.path.join(_ROOT, ".jax_cache")
+
+_TINY_STEP = """
+import numpy as np
+import jax
+import mxnet_tpu as mx
+
+d = mx.sym.Variable("data")
+net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(d, num_hidden=4), name="softmax")
+it = mx.io.NDArrayIter(np.ones((8, 6), np.float32), np.zeros((8,), np.float32),
+                       batch_size=8)
+mx.mod.Module(net, context=mx.cpu()).fit(it, num_epoch=1)
+print("CACHE_DIR=" + str(jax.config.jax_compilation_cache_dir))
+"""
+
+_PRINT_DIR = """
+import jax
+import mxnet_tpu
+print("CACHE_DIR=" + str(jax.config.jax_compilation_cache_dir))
+"""
+
+_UNCACHED_THEN_CACHED = """
+import os
+import jax
+import jax.numpy as jnp
+from mxnet_tpu.executor import _compile_uncached
+
+d = jax.config.jax_compilation_cache_dir
+count = lambda: len(os.listdir(d)) if os.path.isdir(d) else 0
+lower = lambda: jax.jit(lambda x: x * 2 + 1).lower(
+    jax.ShapeDtypeStruct((8,), jnp.float32))
+_compile_uncached(lower())
+print("UNCACHED=%d" % count())
+print("THRESHOLD=%r" % jax.config.jax_persistent_cache_min_compile_time_secs)
+lower().compile()
+print("CACHED=%d" % count())
+"""
+
+
+def _run(code, **env_overrides):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        filter(None, [_ROOT, env.get("PYTHONPATH")]))
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"  # conftest turned it off
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.update(env_overrides)
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd="/",
+                          capture_output=True, text=True, timeout=240)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return dict(l.split("=", 1) for l in proc.stdout.splitlines()
+                if "=" in l and l.split("=", 1)[0].isupper())
+
+
+def _listing(path):
+    return sorted(os.listdir(path)) if os.path.isdir(path) else None
+
+
+def test_compile_cache_goes_where_the_environment_says(tmp_path):
+    outside = tmp_path / "cache"
+    before = _listing(_CHECKOUT_CACHE)
+    used = _run(_TINY_STEP, JAX_COMPILATION_CACHE_DIR=str(outside))
+    assert used["CACHE_DIR"] == str(outside)
+    assert _listing(outside), "the train step cached nothing"
+    assert _listing(_CHECKOUT_CACHE) == before, (
+        "entries were written under the checkout although "
+        "JAX_COMPILATION_CACHE_DIR names another directory")
+
+
+def test_compile_cache_default_is_one_fixed_checkout_path():
+    assert _run(_PRINT_DIR)["CACHE_DIR"] == _CHECKOUT_CACHE
+    assert _run(_PRINT_DIR)["CACHE_DIR"] == _CHECKOUT_CACHE
+
+
+def test_auto_layout_compile_leaves_no_cache_entry(tmp_path):
+    got = _run(_UNCACHED_THEN_CACHED,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    assert got["UNCACHED"] == "0"
+    assert got["THRESHOLD"] == "0.0"  # restored after the compile
+    assert int(got["CACHED"]) >= 1  # the same program, compiled plainly
+
+
+def test_accelerator_context_raises_on_the_cpu_backend():
+    for ctx in (mx.tpu(0), mx.gpu(0)):
+        with pytest.raises(mx.MXNetError, match="no accelerator"):
+            ctx.jax_device()
+    assert mx.num_gpus() == 0
+    assert mx.cpu(0).jax_device().platform == "cpu"
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    tic = time.monotonic()
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_ROOT, "chip_smoke.py")], env=env,
+        cwd=_ROOT, capture_output=True, text=True, timeout=60)
+    assert time.monotonic() - tic < 60
+    assert proc.returncode != 0
+    lines = proc.stdout.strip().splitlines()
+    assert len(lines) == 1 and "cpu" in lines[0], proc.stdout
+    assert '"ok"' not in proc.stdout

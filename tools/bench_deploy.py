@@ -26,23 +26,16 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--dev-type", type=int, default=2,
-                    help="1=cpu 2=accelerator (TPU)")
-    ap.add_argument("--iters-bs1", type=int, default=100)
-    ap.add_argument("--iters-bs32", type=int, default=20)
-    ap.add_argument("--amal-dir", default=None,
-                    help="reuse an existing amalgamation build dir")
-    args = ap.parse_args()
-
+def _py_leg(args, prefix):
+    """Child process: write the random ResNet-50 checkpoint under ``prefix``
+    and time the in-process Python ``Predictor`` on it. Everything that
+    touches jax lives here — a chip belongs to one process at a time, and
+    the C client legs that follow need it."""
     import numpy as np
 
     import mxnet_tpu as mx
     from mxnet_tpu import models
-
-    work = tempfile.mkdtemp(prefix="mxtpu_deploy_")
-    prefix = os.path.join(work, "resnet50")
+    from mxnet_tpu.predictor import Predictor
 
     sym = models.resnet(num_classes=1000, num_layers=50,
                         image_shape="3,224,224")
@@ -65,10 +58,6 @@ def main():
     mx.model.save_checkpoint(prefix, 0, sym, arg_params, aux_params)
     sym_file, params_file = f"{prefix}-symbol.json", f"{prefix}-0000.params"
 
-    # ---- python predictor ----
-    from mxnet_tpu.predictor import Predictor
-
-    results = {}
     for batch, iters in ((1, args.iters_bs1), (32, args.iters_bs32)):
         pred = Predictor(
             open(sym_file).read(), params_file,
@@ -90,8 +79,53 @@ def main():
             out = once()
         np.asarray(out)
         rate = batch * iters / (time.time() - tic)
-        results[("py", batch)] = rate
         print(f"PY {batch} {rate:.2f}", flush=True)
+
+
+def _rates(stdout, tag):
+    """{batch: rate} from a leg's ``<tag> <batch> <rate>`` lines."""
+    out = {}
+    for line in stdout.splitlines():
+        f = line.split()
+        if len(f) == 3 and f[0] == tag:
+            out[int(f[1])] = float(f[2])
+    return out
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--dev-type", type=int, default=2,
+                    help="1=cpu 2=accelerator (TPU)")
+    ap.add_argument("--iters-bs1", type=int, default=100)
+    ap.add_argument("--iters-bs32", type=int, default=20)
+    ap.add_argument("--amal-dir", default=None,
+                    help="reuse an existing amalgamation build dir")
+    ap.add_argument("--py-leg", metavar="PREFIX", default=None,
+                    help=argparse.SUPPRESS)  # internal: run as the child
+    args = ap.parse_args()
+    if args.py_leg:
+        _py_leg(args, args.py_leg)
+        return
+
+    # The parent never imports jax or mxnet_tpu: each leg below is a child
+    # that owns the accelerator for its lifetime and releases it on exit.
+    work = tempfile.mkdtemp(prefix="mxtpu_deploy_")
+    prefix = os.path.join(work, "resnet50")
+    sym_file, params_file = f"{prefix}-symbol.json", f"{prefix}-0000.params"
+    results = {}
+
+    # ---- python predictor ----
+    r = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--py-leg", prefix,
+         "--dev-type", str(args.dev_type),
+         "--iters-bs1", str(args.iters_bs1),
+         "--iters-bs32", str(args.iters_bs32)],
+        stdout=subprocess.PIPE, text=True, timeout=2400)
+    if r.returncode != 0:
+        sys.exit(f"python predictor leg failed (rc={r.returncode})")
+    print(r.stdout, end="", flush=True)
+    for batch, rate in _rates(r.stdout, "PY").items():
+        results[("py", batch)] = rate
 
     # ---- C client over the amalgamated .so ----
     amal = args.amal_dir
@@ -121,10 +155,8 @@ def main():
             capture_output=True, text=True, env=env, timeout=1200)
         if r.returncode != 0:
             sys.exit(f"C bench failed:\n{r.stderr[-2000:]}")
-        line = r.stdout.strip().splitlines()[-1]
-        rate = float(line.split()[-1])
-        results[("c", batch)] = rate
-        print(line, flush=True)
+        print(r.stdout, end="", flush=True)
+        results[("c", batch)] = _rates(r.stdout, "C")[batch]
 
     for batch in (1, 32):
         ratio = results[("c", batch)] / results[("py", batch)]
