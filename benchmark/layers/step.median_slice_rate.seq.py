@@ -1,0 +1,12 @@
+"""Median of the fenced slices' rates: the steady step, which one stalled
+slice does not move; train_tokens_per_s is the whole window, which it does."""
+
+from benchmark.lib import readers
+
+NAME = "step.median_slice_rate.seq"
+UNIT = "tokens/s"
+LAYER = "fused step"
+MOVES = "train_tokens_per_s"
+BETTER = "higher"
+SOURCE = "host_clock"
+read = readers.median_slice_rate
