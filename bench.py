@@ -40,8 +40,9 @@ boundary contract the pipelined fit loop uses).
 The result JSON always embeds a telemetry snapshot (``"telemetry"`` key)
 so BENCH_* files carry the bound — data- vs dispatch- vs sync-bound — of
 the measured run. With ``MXNET_TELEMETRY=1`` in fit mode, the run
-additionally captures host spans + the jax device trace and writes one
-merged Perfetto-loadable timeline (``BENCH_TRACE_OUT``, default
+additionally runs under jax's profiler, whose trace holds the program's
+host spans beside the device operations, and writes that one
+Perfetto-loadable timeline (``BENCH_TRACE_OUT``, default
 bench_trace.json) plus the snapshot JSON/Prometheus pair
 (``BENCH_TELEMETRY_OUT``, default bench_telemetry.json).
 
@@ -1431,13 +1432,13 @@ def main():
                         on_tpu)
 
     if mode == "fit":
-        # MXNET_TELEMETRY=1: record host spans + the jax device trace over
-        # the fit epochs and write one merged Chrome/Perfetto timeline
+        # MXNET_TELEMETRY=1: run the fit epochs under jax's profiler; the
+        # program's spans are TraceMes on its host plane, so the trace it
+        # writes is the one Chrome/Perfetto timeline
         tracing = mx.telemetry.spans_enabled()
         if tracing:
             trace_out = os.environ.get("BENCH_TRACE_OUT", "bench_trace.json")
-            mx.profiler.profiler_set_config(
-                filename=os.path.splitext(trace_out)[0] + "_device.json")
+            mx.profiler.profiler_set_config(filename=trace_out)
             mx.profiler.profiler_set_state("run")
         # _run_fit_mode resets telemetry again at the first epoch boundary
         # so the snapshot covers the steady-state epochs only
@@ -1474,18 +1475,17 @@ def main():
                 record["best_xla_flags"] = os.environ.get(
                     "MXNET_XLA_FLAGS", "")
         if tracing:
-            device_trace = mx.profiler.dump_profile()  # stops the trace
-            merged = mx.telemetry.merge_chrome_trace(
-                mx.telemetry.events(), device_trace, trace_out)
+            trace = mx.profiler.dump_profile()  # stops the trace
             snap_path, prom_path = mx.telemetry.dump(
                 os.environ.get("BENCH_TELEMETRY_OUT", "bench_telemetry.json"))
-            record["trace"] = merged
+            record["trace"] = trace
             record["telemetry_snapshot"] = snap_path
-            # attribute per-kernel device time straight off the merged
-            # timeline the run already paid for
-            record["kernels"] = mx.telemetry.kernel_table(merged)
-            print(f"merged trace: {merged}  snapshot: {snap_path} "
-                  f"{prom_path}", file=sys.stderr)
+            # attribute per-kernel device time straight off the timeline
+            # the run already paid for
+            record["kernels"] = mx.telemetry.kernel_table(trace) \
+                if trace else []
+            print(f"trace: {trace}  snapshot: {snap_path} {prom_path}",
+                  file=sys.stderr)
         if "kernels" not in record or not record["kernels"]:
             rng = np.random.RandomState(3)
             abatch = mx.io.DataBatch(

@@ -6,6 +6,14 @@ Import as ``import mxnet_tpu as mx``; the namespace mirrors the reference's
 ``mx.kv``, ``mx.metric``, ``mx.optimizer``, ``mx.init``, ``mx.rnn``, etc.
 """
 
+from . import telemetry
+
+# top to bottom of this file, jax's own import included: what a new process
+# pays before its first line of user code (closed at the end of the file)
+_import_span = telemetry.span("startup.import")
+_import_span.__enter__()
+
+
 def _maybe_init_distributed():
     """Join the multi-host jax runtime when launched by tools/launch.py.
 
@@ -120,7 +128,6 @@ from . import module as mod
 from . import rnn
 from . import image
 from . import profiler
-from . import telemetry
 from . import aot
 from . import visualization
 from . import visualization as viz
@@ -131,3 +138,6 @@ from . import operator
 from . import predictor
 from . import serving
 from . import rtc
+
+_import_span.__exit__(None, None, None)
+del _import_span

@@ -176,7 +176,9 @@ def load(key_digest):
         _tm.counter("aot.cache_miss").inc()
         return None
     try:
-        with _tm.span("aot.deserialize"):
+        # reading an executable back is the compile layer's work too
+        with _tm.span("executor.compile", source="aot_cache"), \
+                _tm.span("aot.deserialize"):
             with open(path, "rb") as f:
                 blob = pickle.load(f)
             from jax.experimental import serialize_executable as _se
@@ -268,7 +270,10 @@ class AOTProgram:
             try:
                 _tm.counter(self._counter).inc()  # graftlint: allow=telemetry-catalog(forwards a constructor-chosen literal: executor.jit_compile or aot.trace_compile, both catalogued)
                 with _tm.span(self._span):  # graftlint: allow=telemetry-catalog(forwards a constructor-chosen literal: executor.jit_build or aot.compile, both catalogued)
-                    compiled = self.jit_fn.lower(*args).compile()
+                    with _tm.span("executor.trace_lower"):
+                        lowered = self.jit_fn.lower(*args)
+                    with _tm.span("executor.compile"):
+                        compiled = lowered.compile()
             except Exception:
                 # tracing raised (e.g. a graph-contract error) or AOT
                 # lowering is unsupported here: let the jit path surface
@@ -294,7 +299,8 @@ class AOTProgram:
             if exe is None:
                 return self.jit_fn(*args)
         try:
-            return exe(*args)
+            with _tm.span("executor.launch"):
+                return exe(*args)
         except Exception:
             # aval mismatch (an argument changed device/layout in a way the
             # executable rejects) — the jit path handles it; stop using AOT

@@ -44,12 +44,11 @@ _declare("MXNET_DEVICE_PREFETCH", _parse_bool, True,
 _declare("MXNET_PROFILER_AUTOSTART", _parse_bool, False,
          "Start the profiler at import (reference env_var.md:69-78).")
 _declare("MXNET_TELEMETRY", _parse_bool, False,
-         "Enable host-side span recording (telemetry.span emits Chrome "
-         "trace events mergeable with the device trace via "
-         "tools/trace_merge.py). Counters/gauges/histograms are always on "
-         "at near-zero cost; this flag only gates trace-event capture. "
-         "The in-engine-profiler analogue of the reference's "
-         "MXNET_PROFILER_AUTOSTART, for the host timeline.")
+         "Keep every telemetry.span as an in-memory event as well "
+         "(telemetry.events(): name, id, parent, ts, dur). "
+         "Counters/gauges/histograms are always on at near-zero cost, and "
+         "a trace taken by jax's profiler holds the spans whether or not "
+         "this is set; the flag only gates the in-memory event list.")
 _declare("MXNET_PROFILER_MODE", str, "symbolic",
          "Profiler mode ('symbolic' or 'all'); recorded in the trace "
          "metadata (XLA traces always cover all device ops).")

@@ -183,7 +183,8 @@ def _compile_uncached(lowered):
     keep = getattr(jax.config, name)
     jax.config.update(name, float("inf"))  # nothing compiles this slowly
     try:
-        return lowered.compile()
+        with _tm.span("executor.compile", source="compiled"):
+            return lowered.compile()
     finally:
         jax.config.update(name, keep)
 
@@ -1827,58 +1828,59 @@ class Executor:
             self._fused_plan[plan_key] = plan
         fn, upd_idx, other_idx, st_pack, aot, auto_layout = plan
 
-        args_in = self._bwd_args
-        args_flat = getattr(self, "_bwd_args_flat", None)
-        aux_flat = getattr(self, "_bwd_aux_flat", None)
-        upd_vals = [args_in[i] for i in upd_idx]
-        other_vals = [args_in[i] for i in other_idx]
-        st_flat = None
-        if st_pack is not None:
-            handle_map = dict(enumerate(state_handles))
-            st_flat = self._pack_gather(st_pack, handle_map)
-            packed_j = set(st_pack["names"])
-            state_leaves = [None if j in packed_j else state_handles[j]._data
-                            for j in range(len(state_handles))]
-        elif state_handles is not None and state_leaves is None:
-            state_leaves = [h._data for h in state_handles]
-        # Per-step hyperparams stay device-resident: a fresh numpy argument
-        # per execute costs a blocking host->device transfer and stalls the
-        # pipeline. The program returns next step's
-        # hyper (t+1) donated in place; the host keeps a numpy mirror and
-        # re-uploads only when the wanted values diverge (lr schedule fired,
-        # optimizer/param-set changed, first step).
-        hyper_host = np.stack([
-            np.asarray(lrs, np.float32),
-            np.asarray(wds, np.float32),
-            np.asarray(ts, np.float32),
-        ])
-        cache = getattr(self, "_hyper_dev_cache", None)
-        if (
-            cache is not None
-            and cache[0] is not None
-            and cache[1].shape == hyper_host.shape
-            and np.array_equal(cache[1], hyper_host)
-        ):
-            hyper = cache[0]
-        else:
-            hyper = jax.device_put(hyper_host)
-        self._hyper_dev_cache = None  # donated below; never reuse on failure
+        with _tm.span("executor.stage_args"):
+            args_in = self._bwd_args
+            args_flat = getattr(self, "_bwd_args_flat", None)
+            aux_flat = getattr(self, "_bwd_aux_flat", None)
+            upd_vals = [args_in[i] for i in upd_idx]
+            other_vals = [args_in[i] for i in other_idx]
+            st_flat = None
+            if st_pack is not None:
+                handle_map = dict(enumerate(state_handles))
+                st_flat = self._pack_gather(st_pack, handle_map)
+                packed_j = set(st_pack["names"])
+                state_leaves = [None if j in packed_j else state_handles[j]._data
+                                for j in range(len(state_handles))]
+            elif state_handles is not None and state_leaves is None:
+                state_leaves = [h._data for h in state_handles]
+            # Per-step hyperparams stay device-resident: a fresh numpy argument
+            # per execute costs a blocking host->device transfer and stalls the
+            # pipeline. The program returns next step's
+            # hyper (t+1) donated in place; the host keeps a numpy mirror and
+            # re-uploads only when the wanted values diverge (lr schedule fired,
+            # optimizer/param-set changed, first step).
+            hyper_host = np.stack([
+                np.asarray(lrs, np.float32),
+                np.asarray(wds, np.float32),
+                np.asarray(ts, np.float32),
+            ])
+            cache = getattr(self, "_hyper_dev_cache", None)
+            if (
+                cache is not None
+                and cache[0] is not None
+                and cache[1].shape == hyper_host.shape
+                and np.array_equal(cache[1], hyper_host)
+            ):
+                hyper = cache[0]
+            else:
+                hyper = jax.device_put(hyper_host)
+            self._hyper_dev_cache = None  # donated below; never reuse on failure
 
-        # guard counters live on device across steps (donated in, new value
-        # out); a fresh zeros buffer only on the first guarded step or after
-        # a rollback reset. The same (dead) buffer rides along un-guarded
-        # programs so the calling convention stays uniform.
-        guard_in = getattr(self, "_guard_dev", None)
-        if guard_in is None:
-            guard_in = self._guard_zeros()
+            # guard counters live on device across steps (donated in, new value
+            # out); a fresh zeros buffer only on the first guarded step or after
+            # a rollback reset. The same (dead) buffer rides along un-guarded
+            # programs so the calling convention stays uniform.
+            guard_in = getattr(self, "_guard_dev", None)
+            if guard_in is None:
+                guard_in = self._guard_zeros()
 
-        call_args = (
-            upd_vals, args_flat, other_vals, self._bwd_aux, aux_flat,
-            self._bwd_rng, head_grads, self._bwd_prev, state_leaves,
-            st_flat, hyper, guard_in,
-        )
-        if n_steps > 1:
-            call_args += (stack_vals,)
+            call_args = (
+                upd_vals, args_flat, other_vals, self._bwd_aux, aux_flat,
+                self._bwd_rng, head_grads, self._bwd_prev, state_leaves,
+                st_flat, hyper, guard_in,
+            )
+            if n_steps > 1:
+                call_args += (stack_vals,)
         from .parallel.mesh import with_mesh
 
         dispatched = False
@@ -1912,12 +1914,17 @@ class Executor:
                             lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype),
                             call_args,
                         )
-                        lowered = fn.lower(*lower_args)
+                        with _tm.span("executor.trace_lower"):
+                            lowered = fn.lower(*lower_args)
                         exe = _compile_uncached(lowered)
                         aot[1] = jax.tree_util.tree_leaves(exe.input_formats)
                     else:
-                        lowered = fn.lower(*call_args)
-                        exe = lowered.compile()
+                        with _tm.span("executor.trace_lower"):
+                            lowered = fn.lower(*call_args)
+                        # jax's persistent cache answers inside compile():
+                        # a read is this layer's work too
+                        with _tm.span("executor.compile"):
+                            exe = lowered.compile()
                     aot[0] = exe
                     _record_fused_hlo(lowered, exe, call_args, aot[1])
                     _aot.store(pdigest, exe)
@@ -1926,22 +1933,26 @@ class Executor:
                     # compiled formats (they are last window's outputs);
                     # convert only leaves that do not (first window, fresh
                     # data uploads, checkpoint restores)
-                    flat_a, td = jax.tree_util.tree_flatten(call_args)
-                    conv = []
-                    for v, f in zip(flat_a, aot[1]):
-                        if getattr(v, "format", None) != f:
-                            v = jax.device_put(v, f)
-                        conv.append(v)
-                    call_args = jax.tree_util.tree_unflatten(td, conv)
+                    with _tm.span("executor.stage_args"):
+                        flat_a, td = jax.tree_util.tree_flatten(call_args)
+                        conv = []
+                        for v, f in zip(flat_a, aot[1]):
+                            if getattr(v, "format", None) != f:
+                                v = jax.device_put(v, f)
+                            conv.append(v)
+                        call_args = jax.tree_util.tree_unflatten(td, conv)
                 dispatched = True
+                # the call into the executable and nothing else
+                with _tm.span("executor.launch"):
+                    results = aot[0](*call_args)
                 if publish:
                     (outs, aux_upd, aux_flat_out, grad_map, grad_flat,
                      new_params, arg_flat_out, new_leaves, st_flat_out,
-                     next_hyper, new_guard, next_step) = aot[0](*call_args)
+                     next_hyper, new_guard, next_step) = results
                 else:
                     (outs, aux_upd, aux_flat_out,
                      new_params, arg_flat_out, new_leaves, st_flat_out,
-                     next_hyper, new_guard, next_step) = aot[0](*call_args)
+                     next_hyper, new_guard, next_step) = results
                     grad_map, grad_flat = {}, None
         except Exception:
             # a failure AFTER dispatch leaves the donated pack flats

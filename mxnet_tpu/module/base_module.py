@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import logging
 import time
+from collections import deque
 
 import numpy as np
 
@@ -52,6 +53,35 @@ def _fast_forward(data_iter, n):
                 break
             skipped += 1
     return skipped
+
+
+class _StepsInFlight:
+    """How far the host runs ahead of the device: observed after every
+    dispatch, how many of the last <= ``RING`` dispatched steps (a window
+    counts as one) have not finished (``fit.steps_in_flight``).
+
+    A step is represented by the rng step counter its program returned: a
+    scalar that is never donated and that nothing else waits for. Reading
+    ``is_ready()`` blocks on nothing and dispatches nothing."""
+
+    RING = 64
+
+    def __init__(self):
+        self._ring = deque(maxlen=self.RING)
+        self._hist = _tm.histogram("fit.steps_in_flight")
+
+    def observe(self, token):
+        if token is None:  # a module that cannot name its last step
+            return
+        ring = self._ring
+        ring.append(token)
+        # one device queue retires steps in order: the oldest finish first
+        while ring and ring[0].is_ready():
+            ring.popleft()
+        self._hist.observe(len(ring))
+
+    def clear(self):
+        self._ring.clear()
 
 
 def _check_input_names(symbol, names, typename, throw):
@@ -472,11 +502,11 @@ class BaseModule:
         # handles stay in flight; the host fences only on the OLDEST one
         # (fit.window_wait) before assembling the next chunk, so window
         # N+1's stack build + dispatch overlap window N's execution
-        from collections import deque as _deque
-
-        inflight = _deque()
+        inflight = deque()
         prefetch_auto = _env.get("MXNET_PREFETCH_DEPTH") == 0
         fit_completed = False
+        done = 0  # batches dispatched by the epochs before this one
+        ahead = _StepsInFlight()
         try:
             for epoch in range(begin_epoch, num_epoch):
                 tic = time.time()
@@ -490,156 +520,163 @@ class BaseModule:
                 with _tm.span("fit.data_wait"):
                     pending = next(batches, None)
                 while pending is not None:
-                    data_batch = pending
-                    k = window.next_k() if window is not None else 1
-                    if k > 1:
-                        # window dispatch: the program publishes only the
-                        # last iteration's outputs, so metric updates and
-                        # batch callbacks move to window granularity (the
-                        # same contract train_window documents for lr
-                        # schedules)
-                        chunk = [data_batch]
+                    # one whole iteration, batch-end callback included: the spans
+                    # below are its children, and the profiler groups device work
+                    # by its step_num (batches dispatched before it)
+                    with _tm.span("fit.step", step_num=done + nbatch):
+                        data_batch = pending
+                        k = window.next_k() if window is not None else 1
+                        if k > 1:
+                            # window dispatch: the program publishes only the
+                            # last iteration's outputs, so metric updates and
+                            # batch callbacks move to window granularity (the
+                            # same contract train_window documents for lr
+                            # schedules)
+                            chunk = [data_batch]
+                            with _tm.span("fit.data_wait"):
+                                while len(chunk) < k:
+                                    nxt = next(batches, None)
+                                    if nxt is None:
+                                        break
+                                    chunk.append(nxt)
+                            if len(chunk) < k:
+                                # epoch tail shorter than K: dispatch single
+                                # steps — a partial window would trace (and
+                                # persist) an extra fused program shape per
+                                # tail size that runs once per epoch (the
+                                # same cost bench.py's whole-window warmup
+                                # avoids)
+                                for b in chunk:
+                                    with _tm.span("fit.dispatch"):
+                                        self.forward_backward(b)
+                                        self.update()
+                                    ahead.observe(self._step_token())
+                                    with _tm.span("fit.metric"):
+                                        self.update_metric(eval_metric, b.label)
+                                    nbatch += 1
+                                window.observe(len(chunk))
+                                pending = None  # chunk short ⇔ iterator drained
+                            else:
+                                if (prefetch_auto
+                                        and isinstance(
+                                            train_data,
+                                            io_mod.DevicePrefetchIter)
+                                        and train_data.depth
+                                        < k * window.depth + 1):
+                                    # the pipeline is only as deep as the data
+                                    # already staged: cover depth windows of K
+                                    # batches (+1 so the producer never idles)
+                                    train_data.set_depth(k * window.depth + 1)
+                                # per-window span: the merged host+device trace
+                                # shows each window's dispatch/boundary work
+                                # and the operative (k, depth) on its args
+                                with _tm.span("fit.window", k=k,
+                                              depth=window.depth,
+                                              in_flight=len(inflight)):
+                                    with _tm.span("fit.dispatch"):
+                                        # boundary publication is LAZY: the
+                                        # window's f32 gradient publish is
+                                        # dead-coded; the metric below reads
+                                        # only the (published) outputs
+                                        boundary = self.train_window(
+                                            None, batches=chunk,
+                                            publish_grads=False)
+                                    ahead.observe(self._step_token())
+                                    if boundary is not None:
+                                        inflight.append(boundary)
+                                        _tm.gauge("fit.windows_in_flight").set(
+                                            len(inflight))
+                                    with _tm.span("fit.data_wait"):
+                                        pending = next(batches, None)
+                                        if pending is not None:
+                                            self.prepare(pending)
+                                    with _tm.span("fit.metric"):
+                                        self.update_metric(eval_metric,
+                                                           chunk[-1].label)
+                                nbatch += len(chunk)
+                                window.observe(len(chunk))
+                            if batch_end_callback is not None:
+                                batch_end_params = BatchEndParam(
+                                    epoch=epoch, nbatch=nbatch - 1,
+                                    eval_metric=eval_metric, locals=locals(),
+                                )
+                                with _tm.span("fit.callback"):
+                                    for callback in _as_list(batch_end_callback):
+                                        callback(batch_end_params)
+                            if manager is not None:
+                                # a boundary that checkpoints is a real fence:
+                                # the save reads this window's params, which
+                                # blocks on everything dispatched so far
+                                manager.batch_tick(epoch, nbatch)  # graftlint: allow=host-sync(a boundary that checkpoints is a real fence by design — cold checkpoint subtree)
+                            while len(inflight) >= window.depth:
+                                # backpressure: fence on the OLDEST in-flight
+                                # window (an execution barrier, not a d2h
+                                # read) so at most `depth` windows are queued
+                                # — each holds K staged batches of device
+                                # memory — while the next chunk assembles
+                                with _tm.span("fit.window_wait"):
+                                    inflight.popleft().wait()
+                                _tm.gauge("fit.windows_in_flight").set(
+                                    len(inflight))
+                            continue
+                        if monitor is not None:
+                            monitor.tic()
+                        data_batch = _fi.on_train_batch(data_batch)
+                        with _tm.span("fit.dispatch"):
+                            self.forward_backward(data_batch)
+                            try:
+                                self.update()
+                            except ElasticServerLost as e:
+                                # the elastic coordinator restarted and lost
+                                # its store: re-seed it from this survivor's
+                                # live params, then replay the update (the
+                                # server dedupes per-round contributions, so
+                                # any half-pushed keys are idempotent)
+                                if not hasattr(self, "_elastic_reseed"):
+                                    raise
+                                self.logger.warning("fit: %s", e)
+                                self._elastic_reseed()  # graftlint: allow=host-sync(coordinator-restart recovery — a one-shot re-seed of the restarted store is a deliberate cold fence)
+                                self.update()
+                        ahead.observe(self._step_token())
+                        # fetch + stage the successor while this step's results
+                        # are still in flight (the device computes under the
+                        # host's data work — the same overlap the reference's
+                        # threaded iterators buy)
                         with _tm.span("fit.data_wait"):
-                            while len(chunk) < k:
-                                nxt = next(batches, None)
-                                if nxt is None:
-                                    break
-                                chunk.append(nxt)
-                        if len(chunk) < k:
-                            # epoch tail shorter than K: dispatch single
-                            # steps — a partial window would trace (and
-                            # persist) an extra fused program shape per
-                            # tail size that runs once per epoch (the
-                            # same cost bench.py's whole-window warmup
-                            # avoids)
-                            for b in chunk:
-                                with _tm.span("fit.dispatch"):
-                                    self.forward_backward(b)
-                                    self.update()
-                                with _tm.span("fit.metric"):
-                                    self.update_metric(eval_metric, b.label)
-                                nbatch += 1
-                            window.observe(len(chunk))
-                            pending = None  # chunk short ⇔ iterator drained
-                        else:
-                            if (prefetch_auto
-                                    and isinstance(
-                                        train_data,
-                                        io_mod.DevicePrefetchIter)
-                                    and train_data.depth
-                                    < k * window.depth + 1):
-                                # the pipeline is only as deep as the data
-                                # already staged: cover depth windows of K
-                                # batches (+1 so the producer never idles)
-                                train_data.set_depth(k * window.depth + 1)
-                            # per-window span: the merged host+device trace
-                            # shows each window's dispatch/boundary work
-                            # and the operative (k, depth) on its args
-                            with _tm.span("fit.window", k=k,
-                                          depth=window.depth,
-                                          in_flight=len(inflight)):
-                                with _tm.span("fit.dispatch"):
-                                    # boundary publication is LAZY: the
-                                    # window's f32 gradient publish is
-                                    # dead-coded; the metric below reads
-                                    # only the (published) outputs
-                                    boundary = self.train_window(
-                                        None, batches=chunk,
-                                        publish_grads=False)
-                                if boundary is not None:
-                                    inflight.append(boundary)
-                                    _tm.gauge("fit.windows_in_flight").set(
-                                        len(inflight))
-                                with _tm.span("fit.data_wait"):
-                                    pending = next(batches, None)
-                                    if pending is not None:
-                                        self.prepare(pending)
-                                with _tm.span("fit.metric"):
-                                    self.update_metric(eval_metric,
-                                                       chunk[-1].label)
-                            nbatch += len(chunk)
-                            window.observe(len(chunk))
+                            pending = next(batches, None)
+                            if pending is not None:
+                                self.prepare(pending)
+                        with _tm.span("fit.metric"):
+                            self.update_metric(eval_metric, data_batch.label)
+                        if monitor is not None:
+                            monitor.toc_print()  # graftlint: allow=host-sync(installing a Monitor opts into per-batch stat fetches — debug instrument, cold by contract)
                         if batch_end_callback is not None:
                             batch_end_params = BatchEndParam(
-                                epoch=epoch, nbatch=nbatch - 1,
+                                epoch=epoch, nbatch=nbatch,
                                 eval_metric=eval_metric, locals=locals(),
                             )
                             with _tm.span("fit.callback"):
                                 for callback in _as_list(batch_end_callback):
                                     callback(batch_end_params)
+                        nbatch += 1
+                        if guard is not None:
+                            guard.after_batch()  # 'raise' mode only (syncs)  # graftlint: allow=host-sync(guard 'raise' mode documents the per-batch sync it buys — deliberate debug boundary)
                         if manager is not None:
-                            # a boundary that checkpoints is a real fence:
-                            # the save reads this window's params, which
-                            # blocks on everything dispatched so far
-                            manager.batch_tick(epoch, nbatch)  # graftlint: allow=host-sync(a boundary that checkpoints is a real fence by design — cold checkpoint subtree)
-                        while len(inflight) >= window.depth:
-                            # backpressure: fence on the OLDEST in-flight
-                            # window (an execution barrier, not a d2h
-                            # read) so at most `depth` windows are queued
-                            # — each holds K staged batches of device
-                            # memory — while the next chunk assembles
-                            with _tm.span("fit.window_wait"):
-                                inflight.popleft().wait()
-                            _tm.gauge("fit.windows_in_flight").set(
-                                len(inflight))
-                        continue
-                    if monitor is not None:
-                        monitor.tic()
-                    data_batch = _fi.on_train_batch(data_batch)
-                    with _tm.span("fit.dispatch"):
-                        self.forward_backward(data_batch)
-                        try:
-                            self.update()
-                        except ElasticServerLost as e:
-                            # the elastic coordinator restarted and lost
-                            # its store: re-seed it from this survivor's
-                            # live params, then replay the update (the
-                            # server dedupes per-round contributions, so
-                            # any half-pushed keys are idempotent)
-                            if not hasattr(self, "_elastic_reseed"):
-                                raise
-                            self.logger.warning("fit: %s", e)
-                            self._elastic_reseed()  # graftlint: allow=host-sync(coordinator-restart recovery — a one-shot re-seed of the restarted store is a deliberate cold fence)
-                            self.update()
-                    # fetch + stage the successor while this step's results
-                    # are still in flight (the device computes under the
-                    # host's data work — the same overlap the reference's
-                    # threaded iterators buy)
-                    with _tm.span("fit.data_wait"):
-                        pending = next(batches, None)
-                        if pending is not None:
-                            self.prepare(pending)
-                    with _tm.span("fit.metric"):
-                        self.update_metric(eval_metric, data_batch.label)
-                    if monitor is not None:
-                        monitor.toc_print()  # graftlint: allow=host-sync(installing a Monitor opts into per-batch stat fetches — debug instrument, cold by contract)
-                    if batch_end_callback is not None:
-                        batch_end_params = BatchEndParam(
-                            epoch=epoch, nbatch=nbatch,
-                            eval_metric=eval_metric, locals=locals(),
-                        )
-                        with _tm.span("fit.callback"):
-                            for callback in _as_list(batch_end_callback):
-                                callback(batch_end_params)
-                    nbatch += 1
-                    if guard is not None:
-                        guard.after_batch()  # 'raise' mode only (syncs)  # graftlint: allow=host-sync(guard 'raise' mode documents the per-batch sync it buys — deliberate debug boundary)
-                    if manager is not None:
-                        manager.batch_tick(epoch, nbatch)  # graftlint: allow=host-sync(periodic checkpoint tick — the save it may trigger is a deliberate fence, cold checkpoint subtree)
-                    ekv = getattr(self, "_kvstore", None)
-                    if ekv is not None and hasattr(ekv,
-                                                   "membership_event"):
-                        # elastic plane: a join/leave/death observed on
-                        # any reply since the last fence surfaces here
-                        # (polling — the push/pull hot path stays
-                        # exception-free), and the fenced reshard runs
-                        # BETWEEN batches, never mid-update
-                        ev = ekv.membership_event()
-                        if ev is not None:
-                            self._elastic_reshard(ev, epoch, nbatch,  # graftlint: allow=host-sync(membership transition IS a fence: survivors block at the reshard barrier and snapshot — cold by design)
-                                                  manager)
-                    if window is not None:
-                        window.observe(1)
+                            manager.batch_tick(epoch, nbatch)  # graftlint: allow=host-sync(periodic checkpoint tick — the save it may trigger is a deliberate fence, cold checkpoint subtree)
+                        ekv = getattr(self, "_kvstore", None)
+                        if ekv is not None and hasattr(ekv,
+                                                       "membership_event"):
+                            # elastic plane: a join/leave/death observed on
+                            # any reply since the last fence surfaces here
+                            # (polling — the push/pull hot path stays
+                            # exception-free), and the fenced reshard runs
+                            # BETWEEN batches, never mid-update
+                            ev = ekv.membership_event()
+                            if ev is not None:
+                                self._elastic_reshard(ev, epoch, nbatch,  # graftlint: allow=host-sync(membership transition IS a fence: survivors block at the reshard barrier and snapshot — cold by design)
+                                                      manager)
+                        if window is not None:
+                            window.observe(1)
                 if inflight:
                     # drain the pipeline: every boundary retires before the
                     # epoch's sync points (metric read, guard escalation,
@@ -651,6 +688,7 @@ class BaseModule:
                             inflight.popleft().wait()
                     _tm.gauge("fit.windows_in_flight").set(0)
                 _tm.counter("fit.batches").inc(nbatch)
+                done += nbatch
                 _tm.counter("fit.epochs").inc()
 
                 with _tm.span("fit.metric"):
@@ -698,6 +736,7 @@ class BaseModule:
                     train_data.reset()
             fit_completed = True
         finally:
+            ahead.clear()
             if manager is not None:
                 # drain the async checkpoint writer: a commit handed off
                 # right before fit returned (or raised) must land
@@ -797,6 +836,12 @@ class BaseModule:
 
     def update_metric(self, eval_metric, labels):
         raise NotImplementedError()
+
+    def _step_token(self):
+        """A device scalar that the last dispatched step's program returned,
+        never donated and a few bytes long, or None: ``fit`` asks it whether
+        that step has finished (``fit.steps_in_flight``)."""
+        return None
 
     # --- binding ----------------------------------------------------------
     def bind(self, data_shapes, label_shapes=None, for_training=True,

@@ -379,6 +379,9 @@ class BucketingModule(BaseModule):
         self._require(bound=True, params=True)
         self._curr_module.update_metric(eval_metric, labels)
 
+    def _step_token(self):
+        return self._curr_module._step_token()
+
     def install_monitor(self, mon):
         self._require(bound=True)
         for mod in self._buckets.values():

@@ -18,6 +18,7 @@ import warnings
 
 from .. import context as ctx_mod
 from .. import optimizer as opt
+from .. import telemetry as _tm
 from ..base import MXNetError
 from ..initializer import InitDesc, Uniform
 from ..model import (
@@ -226,6 +227,7 @@ class Module(BaseModule):
             self._sync_params_from_devices()
         return (self._arg_params, self._aux_params)
 
+    @_tm.span("module.init_params")
     def init_params(self, initializer=Uniform(0.01), arg_params=None,
                     aux_params=None, allow_missing=False, force_init=False):
         if self.params_initialized and not force_init:
@@ -286,6 +288,7 @@ class Module(BaseModule):
         self.params_initialized = True
 
     # ------------------------------------------------------------------
+    @_tm.span("module.bind")
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None,
              grad_req="write"):
@@ -371,6 +374,7 @@ class Module(BaseModule):
         return self._exec_group._exec.compile(kinds)
 
     # ------------------------------------------------------------------
+    @_tm.span("module.init_optimizer")
     def init_optimizer(self, kvstore="local", optimizer="sgd",
                        optimizer_params=(("learning_rate", 0.01),),
                        force_init=False):
@@ -718,8 +722,6 @@ class Module(BaseModule):
         first-init-wins, so the trained copy beats it regardless of
         arrival order), then let ``fit`` re-run the interrupted update —
         the server's per-round worker dedupe makes the replay idempotent."""
-        from .. import telemetry as _tm
-
         kv = self._kvstore
         _tm.counter("kvstore.elastic_reseed").inc()
         self.logger.warning(
@@ -741,8 +743,6 @@ class Module(BaseModule):
         so the new topology has a resume point. Training then continues —
         each survivor keeps consuming its own shard; the recorded cursor
         positions any later restart."""
-        from .. import telemetry as _tm
-
         kv = self._kvstore
         self.logger.warning(
             "elastic kvstore: %s; entering reshard fence at "
@@ -801,6 +801,10 @@ class Module(BaseModule):
 
     def update_metric(self, eval_metric, labels):
         self._exec_group.update_metric(eval_metric, labels)
+
+    def _step_token(self):
+        # the rng step counter the step's program returned (executor.py)
+        return self._exec_group._exec._step_dev
 
     # ------------------------------------------------------------------
     def _sync_params_from_devices(self):

@@ -14,8 +14,8 @@ Every entry point degrades gracefully when jax profiling is unavailable
 (stripped builds, backends without a profiler plugin): the operation
 becomes a warn-once no-op instead of raising at import or construction
 time — profiling must never be able to take a training job down. The
-host half of the timeline lives in :mod:`mxnet_tpu.telemetry`; merge the
-two with ``telemetry.merge_chrome_trace`` / ``tools/trace_merge.py``.
+host half of the timeline lives in :mod:`mxnet_tpu.telemetry`: while a
+trace runs, every ``telemetry.span`` is on its host plane already.
 """
 
 from __future__ import annotations

@@ -15,6 +15,7 @@ import logging
 
 import numpy as np
 
+from .. import telemetry as _tm
 from ..io import DataBatch, DataDesc, DataIter
 from ..ndarray import array
 
@@ -56,6 +57,7 @@ class BucketSentenceIter(DataIter):
     time-major.
     """
 
+    @_tm.span("rnn.bucket_iter_build")
     def __init__(self, sentences, batch_size, buckets=None, invalid_label=-1,
                  data_name="data", label_name="softmax_label", dtype="float32",
                  layout="NTC", seed=0):

@@ -13,14 +13,20 @@ dispatch-bound or sync-bound. This module is the host half:
   Prometheus-style text exposition, :func:`reset` zeroes values in place
   (handles cached by hot paths stay valid).
 
-- **Spans** (:func:`span`) time a region. The duration always feeds the
-  histogram of the same name (microseconds), and — only when span
-  recording is enabled via ``MXNET_TELEMETRY`` (:func:`enable_spans`) — a
-  Chrome trace *complete* event is recorded. :func:`dump_trace` writes
-  the host events as trace-event JSON; :func:`merge_chrome_trace` splices
-  them into the device trace ``profiler.dump_profile`` produced, yielding
-  one Perfetto-loadable timeline (host rows keyed by pid/tid next to the
-  device rows). ``tools/trace_merge.py`` is the CLI for the same merge.
+- **Spans** (:func:`span`) time a region and know the span that was open
+  on the same thread when they began (a thread-local stack). The duration
+  always feeds the histogram of the same name (microseconds), whose
+  ``self_sum`` is the duration less the part child spans covered, so two
+  :func:`snapshot` calls give self time over a window with no event list.
+  Whenever jax's profiler is tracing — whoever started it — every span is
+  also a ``TraceMe`` on the trace's host plane under its own name, on the
+  same timeline as the device operations; a span given ``step_num`` is
+  emitted as ``jax.profiler.StepTraceAnnotation`` emits a step, and the
+  spans opened inside it carry its ``step``. Only when span recording is
+  enabled via ``MXNET_TELEMETRY`` (:func:`enable_spans`) is an in-memory
+  event kept as well (:func:`events`): name, ``id``, ``parent``, and
+  ``ts``/``dur`` from the clock the profiler uses (epoch nanoseconds, read
+  once per boundary).
 
 Instrumented hot paths (see docs/observability.md for the full catalog):
 ``io.prefetch.*`` (DevicePrefetchIter), ``fit.*``/``score.*`` (Module
@@ -40,6 +46,8 @@ queue-wait/infer/latency, hot reloads — mxnet_tpu.serving).
 
 from __future__ import annotations
 
+import functools
+import itertools
 import json
 import os
 import threading
@@ -47,8 +55,7 @@ import time
 
 __all__ = [
     "counter", "gauge", "histogram", "span", "snapshot", "dump", "reset",
-    "prometheus", "spans_enabled", "enable_spans", "events", "dump_trace",
-    "merge_chrome_trace", "phase_totals",
+    "prometheus", "spans_enabled", "enable_spans", "events", "phase_totals",
 ]
 
 
@@ -102,22 +109,26 @@ class Gauge:
 
 class Histogram:
     """Streaming count/sum/min/max (values are whatever unit the caller
-    observes; span durations are microseconds)."""
+    observes; span durations are microseconds). ``self_sum`` is the part
+    of ``sum`` that no child span covered; a plain ``observe`` has no
+    children, so there it grows with ``sum``."""
 
-    __slots__ = ("name", "count", "sum", "min", "max", "_lock")
+    __slots__ = ("name", "count", "sum", "self_sum", "min", "max", "_lock")
 
     def __init__(self, name):
         self.name = name
         self.count = 0
         self.sum = 0
+        self.self_sum = 0
         self.min = None
         self.max = None
         self._lock = threading.Lock()
 
-    def observe(self, v):
+    def observe(self, v, self_v=None):
         with self._lock:
             self.count += 1
             self.sum += v
+            self.self_sum += v if self_v is None else self_v
             if self.min is None or v < self.min:
                 self.min = v
             if self.max is None or v > self.max:
@@ -127,11 +138,13 @@ class Histogram:
         with self._lock:
             self.count = 0
             self.sum = 0
+            self.self_sum = 0
             self.min = None
             self.max = None
 
     def _render(self):
-        out = {"count": self.count, "sum": self.sum}
+        out = {"count": self.count, "sum": self.sum,
+               "self_sum": self.self_sum}
         if self.count:
             out["min"] = self.min
             out["max"] = self.max
@@ -177,24 +190,22 @@ def histogram(name):
 # --- span recording --------------------------------------------------------
 
 def _env_spans():
-    # late import so telemetry stays importable standalone (trace_merge CLI)
-    try:
-        from . import env as _env
+    from . import env as _env
 
-        return bool(_env.get("MXNET_TELEMETRY"))
-    except Exception:
-        raw = os.environ.get("MXNET_TELEMETRY", "")  # graftlint: allow=env-registry(standalone-import fallback: the trace_merge CLI uses telemetry without the package, so the registry may be unimportable here)
-        return str(raw).lower() not in ("", "0", "false")
+    return bool(_env.get("MXNET_TELEMETRY"))
 
 
 _spans_on = _env_spans()
 _events = []
 _events_lock = threading.Lock()
 _MAX_EVENTS = 500_000  # memory backstop; overflow counted, not grown
+_open = threading.local()  # .stack: the spans open on this thread, outermost first
+_ids = itertools.count(1)  # next() is atomic under the interpreter lock
+_trace_me = None  # jaxlib's TraceMe, resolved at the first span
 
 
 def spans_enabled():
-    """True when span() calls record Chrome trace events."""
+    """True when span() calls record in-memory trace events."""
     return _spans_on
 
 
@@ -205,47 +216,123 @@ def enable_spans(on=True):
     _spans_on = bool(on)
 
 
-class _Span:
-    """Times a region: histogram always, trace event when spans are on."""
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``: imported at the first span and not
+    with this module, so the ``startup.import`` span covers jax's import."""
+    global _trace_me
+    from jax.profiler import TraceAnnotation
 
-    __slots__ = ("name", "args", "_t0", "_ts")
+    _trace_me = TraceAnnotation
+    return _trace_me
+
+
+class _Span:
+    """Times a region on the profiler's clock. Always: the histogram, with
+    the time no child covered. While jax's profiler traces: a TraceMe of
+    the same name. When spans are on: an in-memory event with id and
+    parent. Usable as a decorator: every call of the function is a span."""
+
+    __slots__ = ("name", "args", "id", "parent", "step", "_t0", "_child_ns",
+                 "_annotation")
 
     def __init__(self, name, args):
         self.name = name
         self.args = args
 
+    def __call__(self, fn):
+        name, args = self.name, self.args
+
+        @functools.wraps(fn)
+        def spanned(*a, **kw):
+            with _Span(name, args):
+                return fn(*a, **kw)
+
+        return spanned
+
     def __enter__(self):
-        # wall-clock start is always captured: spans may be enabled while
-        # this one is open (enable_spans from a callback) and __exit__
-        # must not find _ts unset
-        self._ts = time.time_ns() // 1000
-        self._t0 = time.perf_counter_ns()
+        try:
+            stack = _open.stack
+        except AttributeError:
+            stack = _open.stack = []
+        parent = self.parent = stack[-1] if stack else None
+        self.id = next(_ids)
+        step = self.args.get("step_num") if self.args else None
+        if step is None and parent is not None:
+            step = parent.step
+        self.step = step
+        self._child_ns = 0
+        self._annotation = None
+        trace_me, t0 = _trace_me, None
+        if trace_me is None:
+            # the first span of the process (startup.import) pays for
+            # importing jax's profiler: that time is inside it
+            t0 = time.time_ns()
+            trace_me = _profiler_annotation()
+        if trace_me.is_enabled():
+            meta = self._labels()
+            if "step_num" in meta:
+                meta["_r"] = 1  # what StepTraceAnnotation adds: a step root
+            self._annotation = trace_me(self.name, **meta)
+            self._annotation.__enter__()
+        stack.append(self)
+        self._t0 = t0 or time.time_ns()
         return self
 
     def __exit__(self, *exc):
-        dur_us = (time.perf_counter_ns() - self._t0) // 1000
-        histogram(self.name).observe(dur_us)
+        dur_ns = max(time.time_ns() - self._t0, 0)  # the epoch clock may step
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        stack = getattr(_open, "stack", None)
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif stack and self in stack:
+            # a span that was entered above this one and never left goes
+            # with it (a span left on another thread than it was entered on
+            # is not in this stack, which then stays as it is)
+            while stack.pop() is not self:
+                pass
+        if self.parent is not None:
+            self.parent._child_ns += dur_ns
+        histogram(self.name).observe(
+            dur_ns // 1000, max(dur_ns - self._child_ns, 0) // 1000)
         if _spans_on:
-            ev = {
-                "name": self.name, "ph": "X", "cat": "host",
-                "ts": self._ts, "dur": max(dur_us, 1),
-                "pid": os.getpid(), "tid": threading.get_ident(),
-            }
-            if self.args:
-                ev["args"] = dict(self.args)
-            with _events_lock:
-                if len(_events) < _MAX_EVENTS:
-                    _events.append(ev)
-                else:
-                    counter("telemetry.dropped_events").inc()
+            self._record(dur_ns)
         return False
+
+    def _labels(self):
+        """The span's arguments, with the step it inherited."""
+        args = dict(self.args)
+        if self.step is not None and "step_num" not in args:
+            args["step"] = self.step
+        return args
+
+    def _record(self, dur_ns):
+        ev = {
+            "name": self.name, "ph": "X", "cat": "host",
+            "ts": self._t0 / 1e3, "dur": max(dur_ns / 1e3, 1e-3),
+            "pid": os.getpid(), "tid": threading.get_ident(),
+            "id": self.id,
+            "parent": None if self.parent is None else self.parent.id,
+        }
+        args = self._labels()
+        if args:
+            ev["args"] = args
+        with _events_lock:
+            if len(_events) < _MAX_EVENTS:
+                _events.append(ev)
+            else:
+                counter("telemetry.dropped_events").inc()
 
 
 def span(name, **args):
-    """Context manager timing a region.
+    """Context manager (or decorator) timing a region.
 
-    The duration (microseconds) always feeds ``histogram(name)``; when
-    span recording is enabled a Chrome trace-event is captured as well.
+    The duration (microseconds) always feeds ``histogram(name)``, and its
+    ``self_sum`` the part no child span covered. While jax's profiler is
+    tracing the span is on the trace's host plane under ``name``; with
+    ``step_num=n`` it is a step as ``jax.profiler.StepTraceAnnotation``
+    marks one, and spans opened inside it carry ``step=n``. When span
+    recording is enabled an in-memory event is kept as well.
     """
     return _Span(name, args)
 
@@ -254,38 +341,6 @@ def events():
     """A copy of the recorded host trace events."""
     with _events_lock:
         return list(_events)
-
-
-def dump_trace(path):
-    """Write the recorded host spans as Chrome trace-event JSON."""
-    with open(path, "w") as f:
-        json.dump({"traceEvents": events(), "displayTimeUnit": "ms"}, f)
-    return path
-
-
-def merge_chrome_trace(host, device, out):
-    """Merge host spans and a device trace into one Chrome trace JSON.
-
-    ``host``: a path to a trace JSON, a list of events, or None.
-    ``device``: a path to the trace ``profiler.dump_profile`` wrote
-    (gzip transparently handled), or None. Device-side metadata keys are
-    preserved; event lists are concatenated (Perfetto keys rows by
-    pid/tid, so host and device tracks coexist on one timeline).
-    """
-    merged = {"displayTimeUnit": "ms"}
-    evts = []
-    if device:
-        merged.update(_load_trace(device))
-        evts.extend(merged.get("traceEvents") or [])
-    if host is not None:
-        if isinstance(host, (list, tuple)):
-            evts.extend(host)
-        else:
-            evts.extend(_load_trace(host).get("traceEvents") or [])
-    merged["traceEvents"] = evts
-    with open(out, "w") as f:
-        json.dump(merged, f)
-    return out
 
 
 def _load_trace(path):
@@ -300,11 +355,11 @@ def _load_trace(path):
 
 
 def kernel_table(trace, top=10):
-    """Per-kernel device-time attribution from a (merged or device) trace.
+    """Per-kernel device-time attribution from a Chrome trace.
 
-    ``trace`` is a path to a Chrome trace JSON (gzip ok), a loaded trace
-    dict, or an event list — the merged timeline ``merge_chrome_trace``
-    writes works directly. The per-kernel rows are the complete events
+    ``trace`` is a path to a Chrome trace JSON (gzip ok: what
+    ``profiler.dump_profile`` writes), a loaded trace dict, or an event
+    list. The per-kernel rows are the complete events
     (``ph == "X"``) the jax profiler tags with an ``hlo_op`` arg — one per
     executed XLA op on the device/runtime track, on TPU and CPU alike;
     host spans and metadata rows carry no ``hlo_op`` and are skipped.

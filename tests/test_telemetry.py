@@ -1,9 +1,8 @@
-"""Telemetry subsystem (ISSUE 2): registry semantics, span recording,
-hot-path instrumentation wiring, and the host+device trace merge."""
+"""Telemetry subsystem (ISSUE 2, ISSUE 24): registry semantics, spans that
+nest and sit on the profiler's clock, hot-path instrumentation wiring."""
 
 import json
 import os
-import subprocess
 import sys
 
 import numpy as np
@@ -119,67 +118,302 @@ def test_span_histogram_always_on_events_gated():
     assert len(evts) == 1
     ev = evts[0]
     assert ev["name"] == "t.phase" and ev["ph"] == "X"
-    assert ev["dur"] >= 1 and "ts" in ev and "pid" in ev and "tid" in ev
+    assert ev["dur"] > 0 and "ts" in ev and "pid" in ev and "tid" in ev
+    assert ev["id"] >= 1 and ev["parent"] is None
     assert ev["args"] == {"detail": "x"}
     assert tm.histogram("t.phase").count == 2
 
 
-def test_dump_trace_and_merge(tmp_path):
+def test_nested_spans_record_id_and_parent():
     tm.enable_spans(True)
-    with tm.span("fit.data_wait"):
+    with tm.span("t.outer"):
+        with tm.span("t.inner"):
+            pass
+        with tm.span("t.inner"):
+            pass
+    with tm.span("t.outer"):
         pass
-    host_path = tm.dump_trace(str(tmp_path / "host.json"))
-    device_path = str(tmp_path / "device.json")
-    with open(device_path, "w") as f:
-        json.dump({"traceEvents": [
-            {"name": "fusion", "ph": "X", "ts": 1, "dur": 2,
-             "pid": 99, "tid": 1}],
-            "metadata": {"clock": "tsc"}}, f)
-    out = tm.merge_chrome_trace(host_path, device_path,
-                                str(tmp_path / "merged.json"))
-    with open(out) as f:
-        merged = json.load(f)
-    names = {e["name"] for e in merged["traceEvents"]}
-    assert {"fit.data_wait", "fusion"} <= names
-    assert merged["metadata"] == {"clock": "tsc"}  # device metadata kept
+    by_id = {e["id"]: e for e in tm.events()}
+    assert len(by_id) == 4  # every span has an id of its own
+    outers = [e for e in by_id.values() if e["name"] == "t.outer"]
+    inners = [e for e in by_id.values() if e["name"] == "t.inner"]
+    assert all(e["parent"] is None for e in outers)
+    first = min(outers, key=lambda e: e["ts"])
+    assert [e["parent"] for e in inners] == [first["id"]] * 2
+    for e in inners:  # one clock: a child lies inside its parent
+        assert first["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= first["ts"] + first["dur"]
 
 
-def test_merge_accepts_event_list_and_missing_device(tmp_path):
+def test_parent_is_per_thread():
+    import threading
+
     tm.enable_spans(True)
-    with tm.span("host.only"):
+    inside, release = threading.Event(), threading.Event()
+
+    def worker():
+        with tm.span("t.worker"):
+            inside.set()
+            assert release.wait(30)
+            with tm.span("t.worker_child"):
+                pass
+
+    th = threading.Thread(target=worker)
+    with tm.span("t.main"):
+        th.start()
+        assert inside.wait(30)  # t.worker is open on the other thread
+        with tm.span("t.main_child"):
+            pass
+        release.set()
+        th.join(30)
+        assert not th.is_alive()
+    ev = {e["name"]: e for e in tm.events()}
+    assert ev["t.main"]["parent"] is None and ev["t.worker"]["parent"] is None
+    assert ev["t.main_child"]["parent"] == ev["t.main"]["id"]
+    assert ev["t.worker_child"]["parent"] == ev["t.worker"]["id"]
+    assert ev["t.main"]["tid"] != ev["t.worker"]["tid"]
+    # the other thread's span is nobody's child here: all of t.main's time
+    # outside t.main_child is its own
+    h = tm.histogram
+    assert h("t.main").self_sum >= h("t.main").sum - h("t.main_child").sum - 2
+
+
+@pytest.mark.parametrize("children", [2, 0])
+def test_self_sum_is_duration_less_children(children):
+    import time
+
+    with tm.span("t.parent"):
+        time.sleep(0.002)
+        for _ in range(children):
+            with tm.span("t.child"):
+                time.sleep(0.003)
+    parent, child = tm.histogram("t.parent"), tm.histogram("t.child")
+    assert child.count == children and child.self_sum == child.sum
+    # microsecond floors: one per span
+    assert abs(parent.self_sum - (parent.sum - child.sum)) <= children + 1
+    assert parent.self_sum >= 2000
+    if not children:
+        assert parent.self_sum == parent.sum
+
+
+def test_plain_observe_counts_as_self():
+    h = tm.histogram("t.plain")
+    h.observe(5)
+    h.observe(7)
+    assert (h.sum, h.self_sum) == (12, 12)
+
+
+def test_exception_leaves_the_span_stack_clean():
+    tm.enable_spans(True)
+    with pytest.raises(ValueError):
+        with tm.span("t.raises"):
+            with tm.span("t.raises_inner"):
+                raise ValueError("boom")
+    assert tm._open.stack == []
+    # a span entered by hand and never left goes with its parent
+    with tm.span("t.tidy"):
+        tm.span("t.leaked").__enter__()
+    assert tm._open.stack == []
+    with tm.span("t.after"):
         pass
-    out = tm.merge_chrome_trace(tm.events(), None,
-                                str(tmp_path / "host_only.json"))
-    with open(out) as f:
-        merged = json.load(f)
-    assert [e["name"] for e in merged["traceEvents"]] == ["host.only"]
+    after = [e for e in tm.events() if e["name"] == "t.after"]
+    assert after[0]["parent"] is None
+    assert tm.histogram("t.raises").count == 1  # still timed
 
 
-def test_trace_merge_cli_smoke(tmp_path):
-    """tools/trace_merge.py merges a host span file + gzipped device trace."""
-    import gzip
+def test_snapshot_carries_self_sum():
+    with tm.span("snap.outer"):
+        with tm.span("snap.inner"):
+            pass
+    snap = tm.snapshot()["snap"]
+    for leaf in (snap["outer"], snap["inner"]):
+        assert {"count", "sum", "self_sum"} <= set(leaf)
+    assert snap["inner"]["self_sum"] == snap["inner"]["sum"]
+    assert snap["outer"]["self_sum"] <= snap["outer"]["sum"]
 
-    host = tmp_path / "host.json"
-    with open(host, "w") as f:
-        json.dump({"traceEvents": [
-            {"name": "fit.dispatch", "ph": "X", "ts": 5, "dur": 3,
-             "pid": 1, "tid": 1}]}, f)
-    device = tmp_path / "device.trace.json.gz"
-    with gzip.open(device, "wt") as f:
-        json.dump({"traceEvents": [
-            {"name": "xla_op", "ph": "X", "ts": 6, "dur": 1,
-             "pid": 2, "tid": 2}]}, f)
-    out = tmp_path / "merged.json"
-    r = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "tools", "trace_merge.py"),
-         str(host), str(device), "-o", str(out)],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr
-    with open(out) as f:
-        merged = json.load(f)
-    assert {e["name"] for e in merged["traceEvents"]} == {
-        "fit.dispatch", "xla_op"}
+
+def test_span_as_decorator_times_every_call():
+    @tm.span("t.decorated")
+    def work(x):
+        """doc"""
+        return x + 1
+
+    assert work(1) == 2 and work(2) == 3
+    assert work.__name__ == "work" and work.__doc__ == "doc"
+    assert tm.histogram("t.decorated").count == 2
+
+
+def test_steps_share_an_identifier():
+    tm.enable_spans(True)
+    for n in (7, 8):
+        with tm.span("t.step", step_num=n):
+            with tm.span("t.phase"):
+                with tm.span("t.leaf", k=1):
+                    pass
+    ev = tm.events()
+    assert [e["args"] for e in ev if e["name"] == "t.step"] == [
+        {"step_num": 7}, {"step_num": 8}]
+    assert [e["args"] for e in ev if e["name"] == "t.phase"] == [
+        {"step": 7}, {"step": 8}]
+    assert [e["args"] for e in ev if e["name"] == "t.leaf"] == [
+        {"k": 1, "step": 7}, {"k": 1, "step": 8}]
+
+
+# ---------------------------------------------------------------------------
+# the loop's spans: in the profiler's trace, and in the registry
+# ---------------------------------------------------------------------------
+def _mlp():
+    d = mx.sym.Variable("data")
+    net = mx.sym.FullyConnected(d, num_hidden=8, name="fc1")
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.FullyConnected(net, num_hidden=3, name="fc2")
+    return mx.sym.SoftmaxOutput(net, name="softmax")
+
+
+def _mlp_iter(batches, batch_size=8):
+    rng = np.random.RandomState(0)
+    n = batches * batch_size
+    return mx.io.NDArrayIter(
+        rng.uniform(size=(n, 4)).astype(np.float32),
+        rng.randint(0, 3, (n,)).astype(np.float32), batch_size=batch_size)
+
+
+def _host_events(trace_dir, names):
+    """[(name, start_ns, end_ns, stats)] of the events called one of
+    ``names`` in the profiler's trace, read as benchmark/lib/trace.py does."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert found, "the profiler wrote no .xplane.pb"
+    out = []
+    for plane in ProfileData.from_file(found[-1]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return out
+
+
+def test_profiler_trace_holds_the_loop_spans(tmp_path):
+    import jax
+
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    assert not tm.spans_enabled()  # the trace needs no MXNET_TELEMETRY
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        mod.fit(_mlp_iter(2), num_epoch=1, optimizer="sgd")
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(str(tmp_path),
+                      {"fit.step", "fit.dispatch", "executor.launch"})
+    steps = [e for e in ev if e[0] == "fit.step"]
+    dispatches = [e for e in ev if e[0] == "fit.dispatch"]
+    launches = [e for e in ev if e[0] == "executor.launch"]
+    assert len(steps) == 2 and len(dispatches) == 2 and len(launches) >= 2
+    # emitted as StepTraceAnnotation emits a step: step_num, and _r
+    assert sorted(s[3]["step_num"] for s in steps) == [0, 1]
+    assert all(s[3]["_r"] == 1 for s in steps)
+    for _, lo, hi, stats in dispatches:
+        holder = [s for s in steps if s[1] <= lo and hi <= s[2]]
+        assert len(holder) == 1
+        assert stats["step"] == holder[0][3]["step_num"]
+    for _, lo, hi, _ in launches:
+        assert any(d[1] <= lo and hi <= d[2] for d in dispatches) or \
+            any(s[1] <= lo and hi <= s[2] for s in steps)
+
+
+def test_fit_step_count_equals_batches():
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    mod.fit(_mlp_iter(3), num_epoch=2, optimizer="sgd")
+    assert tm.counter("fit.batches").value == 6
+    assert tm.histogram("fit.step").count == 6
+    # the phases are its children: what the iteration itself keeps is small
+    step = tm.histogram("fit.step")
+    assert step.self_sum < step.sum
+    assert tm.histogram("fit.dispatch").count == 6
+    assert tm.histogram("executor.launch").count >= 6
+    assert tm.histogram("executor.stage_args").count == 6
+    assert tm.histogram("module.bind").count == 1
+    assert tm.histogram("module.init_params").count == 1
+    assert tm.histogram("module.init_optimizer").count == 1
+    assert tm.histogram("startup.import").count == 0  # reset by the fixture
+
+
+def test_two_bucket_fit_counts_binds_and_programs():
+    from mxnet_tpu.rnn import BucketSentenceIter
+
+    rng = np.random.RandomState(3)
+    sents = [list(rng.randint(1, 20, n)) for n in [3] * 8 + [6] * 8]
+    it = BucketSentenceIter(sents, 4, buckets=[4, 8], invalid_label=0)
+    assert tm.histogram("rnn.bucket_iter_build").count == 1
+
+    def sym_gen(seq_len):
+        data = mx.sym.Variable("data")
+        label = mx.sym.Variable("softmax_label")
+        emb = mx.sym.Embedding(data, input_dim=20, output_dim=8, name="emb")
+        pred = mx.sym.FullyConnected(
+            mx.sym.Reshape(emb, shape=(-1, 8)), num_hidden=20, name="pred")
+        out = mx.sym.SoftmaxOutput(pred, mx.sym.Reshape(label, shape=(-1,)),
+                                   name="softmax")
+        return out, ("data",), ("softmax_label",)
+
+    mod = mx.mod.BucketingModule(sym_gen, default_bucket_key=8,
+                                 context=mx.cpu())
+    tm.reset()
+    mod.fit(it, num_epoch=2, optimizer="sgd",
+            eval_metric=mx.metric.Perplexity(0))
+    assert tm.histogram("module.bind").count == 2  # one per bucket
+    assert tm.counter("bucketing.compile_on_switch").value == 1
+    # every program built was traced and lowered once and compiled once:
+    # the fused steps (one plan per bucket) and what went through AOTProgram
+    built = (tm.counter("executor.fused_plan_compile").value
+             + tm.counter("executor.jit_compile").value)
+    assert tm.counter("executor.fused_plan_compile").value == 2
+    assert tm.histogram("executor.trace_lower").count == built
+    assert tm.histogram("executor.compile").count == built
+    assert tm.histogram("fit.step").count == tm.counter("fit.batches").value
+
+
+def test_steps_in_flight_stays_in_its_ring_and_lets_go(monkeypatch):
+    from mxnet_tpu.module import base_module
+
+    made = []
+
+    class Watched(base_module._StepsInFlight):
+        RING = 3
+
+        def __init__(self):
+            super().__init__()
+            made.append(self)
+
+    class NeverReady:
+        def is_ready(self):
+            return False
+
+    monkeypatch.setattr(base_module, "_StepsInFlight", Watched)
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    # steps that never finish: the worst a device can do to the ring
+    monkeypatch.setattr(mx.mod.Module, "_step_token",
+                        lambda self: NeverReady())
+    mod.fit(_mlp_iter(6), num_epoch=1, optimizer="sgd")
+    h = tm.histogram("fit.steps_in_flight")
+    assert h.count == 6 and h.max == 3 and h.min == 1
+    assert len(made) == 1 and len(made[0]._ring) == 0  # nothing kept
+
+
+def test_steps_in_flight_reads_the_step_counter_the_program_returned():
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    mod.fit(_mlp_iter(4), num_epoch=1, optimizer="sgd")
+    token = mod._step_token()
+    assert token.shape == () and token.nbytes <= 8  # a scalar, not an output
+    assert token.is_ready() in (True, False)
+    h = tm.histogram("fit.steps_in_flight")
+    assert h.count == 4 and 0 <= h.min and h.max <= 4
 
 
 # ---------------------------------------------------------------------------
