@@ -250,6 +250,91 @@ def fused_window_input_formats():
     return _FUSED_HLO.get("input_formats")
 
 
+class _FCBatches:
+    """What the grad core asks of ``_CompiledGraph.evaluate`` for the
+    ``FullyConnected`` nodes that share a weight (``_shared_fc_plan``).
+
+    ``batched`` maps the first node of a group whose inputs do not depend
+    on each other to all of its nodes: they run as ONE matmul over their
+    stacked rows, in ``order`` (the graph's topological order with those
+    nodes pulled together). ``probe`` is the set of nodes whose (data rows,
+    output) pair the shape probe wants left in ``seen``.
+    """
+
+    __slots__ = ("batched", "order", "skip", "probe", "seen")
+
+    def __init__(self, batched=(), order=None, probe=()):
+        self.batched = {id(nodes[0]): nodes for nodes in batched}
+        self.order = order
+        self.skip = {id(n) for nodes in batched for n in nodes[1:]}
+        self.probe = probe
+        self.seen = {}
+
+
+def _fc_rows(data, params):
+    """A ``FullyConnected`` node's data as the matmul sees it."""
+    return data.reshape((data.shape[0], -1)) if params["flatten"] else data
+
+
+def _order_with_groups(topo, groups):
+    """``topo`` reordered so that the nodes of each group are consecutive,
+    after the producers of every input of every one of them. The groups
+    must be independent (``_independent``), or no such order exists."""
+    group_of = {id(n): nodes for nodes in groups for n in nodes}
+    done, order = set(), []
+    for root in topo:
+        stack = [root]
+        while stack:
+            node = stack[-1]
+            if id(node) in done:
+                stack.pop()
+                continue
+            nodes = group_of.get(id(node), (node,))
+            pending = [i for n in nodes for (i, _ix) in n.inputs
+                       if id(i) not in done]
+            if pending:
+                stack.extend(pending)
+                continue
+            stack.pop()
+            for n in nodes:
+                done.add(id(n))
+                order.append(n)
+    return order
+
+
+def _chunks(nodes, cap):
+    """``nodes`` cut into runs of at most ``cap`` (at least 2), as equal
+    as they come."""
+    n = -(-len(nodes) // max(cap, 2))
+    size, extra = divmod(len(nodes), n)
+    out, at = [], 0
+    for i in range(n):
+        step = size + (i < extra)
+        out.append(nodes[at:at + step])
+        at += step
+    return out
+
+
+def _independent(order, accepted, cand):
+    """Whether no node of ``cand`` reads, through any path, the output of
+    another: then all of them can run at once. Each group of ``accepted``
+    already runs as one node (``order`` has them consecutive), which makes
+    every one of its nodes depend on the inputs of all of them."""
+    cand_ids = {id(n) for n in cand}
+    group_of = {id(n): nodes for nodes in accepted for n in nodes}
+    reach = {}  # id(node) -> whether it depends on a node of cand
+    for node in order:
+        if id(node) in reach:
+            continue
+        nodes = group_of.get(id(node), (node,))
+        r = any(reach[id(i)] for n in nodes for (i, _ix) in n.inputs)
+        if r and id(node) in cand_ids:
+            return False
+        for n in nodes:
+            reach[id(n)] = r or id(n) in cand_ids
+    return True
+
+
 class _CompiledGraph:
     """The symbol lowered to a pure function over ordered value lists.
 
@@ -285,8 +370,31 @@ class _CompiledGraph:
                 serial += 1
         self.num_rng_ops = serial
 
+    def shared_fc_groups(self):
+        """[(weight name, nodes)] of the ``FullyConnected`` nodes, two or
+        more, that read the same weight (and bias) Variable with the same
+        parameters on one device: the time steps of an unrolled recurrent
+        cell. A group split over devices by ctx-group placement is left
+        out."""
+        groups = {}
+        for node in self.topo:
+            if node.is_variable or node.op.name != "FullyConnected":
+                continue
+            pvars = [inode for (inode, _idx) in node.inputs[1:]]
+            if not all(v.is_variable and not v.is_aux for v in pvars):
+                continue
+            params = node.params()
+            key = (tuple(v.name for v in pvars), params["num_hidden"],
+                   params["flatten"])
+            groups.setdefault(key, []).append(node)
+        return [
+            (key[0][0], nodes) for key, nodes in groups.items()
+            if len(nodes) > 1
+            and len({self.node2dev.get(id(n)) for n in nodes}) == 1
+        ]
+
     def evaluate(self, arg_vals, aux_vals, rng, is_train, monitor=None,
-                 limit=None, monitor_all=False):
+                 limit=None, monitor_all=False, fc_batches=None):
         """Run the graph. Returns (head_outputs, aux_updates_list).
 
         With ``limit`` set, interprets only the first ``limit`` op nodes and
@@ -295,7 +403,9 @@ class _CompiledGraph:
         placement/remat/rng handling can never diverge). ``monitor_all``
         additionally reports every VARIABLE value (weights/data/aux) under
         its own name — the reference's SetMonitorCallbackEX input
-        monitoring (op outputs already cover all interior edges)."""
+        monitoring (op outputs already cover all interior edges).
+        ``fc_batches`` (:class:`_FCBatches`) is the grad core's: no other
+        caller passes it."""
         import jax
 
         from .ops import layout as _lay
@@ -307,20 +417,9 @@ class _CompiledGraph:
         executed = 0
         last_outs = []
         last_cl = []
-        for node in self.topo:
-            if node.is_variable:
-                if node.is_aux:
-                    env[id(node)] = [aux_vals[self._aux_index[node.name]]]
-                else:
-                    env[id(node)] = [arg_vals[self._arg_index[node.name]]]
-                if nhwc:
-                    cl[id(node)] = [False]
-                if monitor is not None and monitor_all:
-                    monitor(node.name, env[id(node)][0])
-                continue
-            if limit is not None and executed >= limit:
-                break
-            params = node.params()
+
+        def operands(node, params):
+            """The node's inputs as its op takes them, and its layout."""
             ins = [env[id(inode)][idx] for (inode, idx) in node.inputs]
             node_layout = None
             if nhwc:
@@ -355,6 +454,39 @@ class _CompiledGraph:
                 # transposes the copy so gradients flow back to the source
                 # device — the backward _CrossDeviceCopy of the reference)
                 ins = [jax.device_put(x, dev) for x in ins]
+            return ins, node_layout
+
+        order, skip, batched, probe = self.topo, (), {}, ()
+        if fc_batches is not None:
+            order = fc_batches.order or self.topo
+            skip, batched = fc_batches.skip, fc_batches.batched
+            probe = fc_batches.probe
+        for node in order:
+            if node.is_variable:
+                if node.is_aux:
+                    env[id(node)] = [aux_vals[self._aux_index[node.name]]]
+                else:
+                    env[id(node)] = [arg_vals[self._arg_index[node.name]]]
+                if nhwc:
+                    cl[id(node)] = [False]
+                if monitor is not None and monitor_all:
+                    monitor(node.name, env[id(node)][0])
+                continue
+            if limit is not None and executed >= limit:
+                break
+            if id(node) in skip:
+                continue  # ran with the first node of its group
+            params = node.params()
+            ins, node_layout = operands(node, params)
+            group = batched.get(id(node))
+            if group:
+                # the group's rows stacked on a new leading axis (a batch
+                # axis sharded over a mesh stays where it is): one matmul
+                # with M = nodes x rows, whose gradient is one too
+                ins[0] = jax.numpy.stack([_fc_rows(ins[0], params)] + [
+                    _fc_rows(operands(n, params)[0][0], params)
+                    for n in group[1:]])
+                params = dict(params, flatten=False)
             node_rng = None
             if node.op.need_rng:
                 node_rng = jax.random.fold_in(rng, self._rng_serial[id(node)])
@@ -371,6 +503,15 @@ class _CompiledGraph:
                     ins, params,
                     OpMode(is_train=is_train, rng=node_rng, layout=op_layout),
                 )
+            if group:
+                for i, n in enumerate(group[1:], 1):
+                    env[id(n)] = [outs[0][i]]
+                    if nhwc:
+                        cl[id(n)] = [False]
+                outs = [outs[0][0]]
+            elif id(node) in probe:
+                fc_batches.seen[id(node)] = (_fc_rows(ins[0], params),
+                                             outs[0])
             env[id(node)] = outs
             if nhwc:
                 if node_layout == "NHWC":
@@ -485,6 +626,7 @@ class Executor:
         self._sig_cache = None  # memoized _jit_signature
         self._sym_sha_cache = None  # memoized symbol-graph digest
         self._guard_dev = None  # device [total, consec] non-finite counters
+        self._fc_plan = None  # memoized _shared_fc_plan
         if shared_exec is not None:
             # bucketing: share compiled-function cache and memory with the
             # master executor (reference shared_exec data_pool_ reuse,
@@ -1122,6 +1264,84 @@ class Executor:
             (i, *pack["offs"][n]) for i, n in enumerate(order) if n in packed
         )
 
+    def _shared_fc_plan(self):
+        """The ``FullyConnected`` groups that this executor's train programs
+        run as batched matmuls: ``(batched, order, weights)``, the runs of
+        nodes that each become one matmul, the order to run the graph in,
+        and the number of shared weights they cover; ``([], None, 0)``
+        where there is none.
+
+        An unrolled recurrent cell applies one weight at every time step,
+        and under ``jax.grad`` each application is three small matmuls
+        (forward, data gradient, weight gradient) that stream the whole
+        weight for a batch's rows. Where the nodes of such a group do not
+        read each other's outputs (the i2h of every layer: its inputs are
+        there before the recurrence starts) a run of them is ONE matmul over
+        their stacked rows, and ``jax.grad`` of that is one matmul for the
+        data gradient and one for the weight's. A group whose nodes feed
+        each other (the h2h chain) is left as it is.
+
+        Decided once an executor from what the graph and the bound shapes
+        show (one abstract forward trace, only where the graph has a shared
+        weight at all): a group is batched when the weight traffic that
+        removes, weight bytes x (nodes - 1), exceeds the copies it adds, the
+        nodes' data and output bytes; and it is cut into runs whose rows
+        outweigh the weight by less than one node's, because a matmul that
+        streams more rows than weight has nothing left to amortise and its
+        stacks crowd the weights out of the chip's fast memory (on the v5e
+        runs of 10 time steps of the PTB LSTM beat runs of 5, 15, 20 and the
+        whole of 30 or 60; PERF.md, PR 25). Nodes of unlike data shape form
+        groups of their own.
+        """
+        if self._fc_plan is not None:
+            return self._fc_plan
+        import jax
+
+        graph = self.graph
+        cand = [g for g in graph.shared_fc_groups()
+                if g[0] in self._wrt_names]
+        batched, order, weights = [], graph.topo, 0
+        if cand:
+            probe = _FCBatches(probe={id(n) for g in cand for n in g[1]})
+
+            def forward(arg_vals, aux_vals, key):
+                graph.evaluate(arg_vals, aux_vals, key, True,
+                               fc_batches=probe)
+                return probe.seen
+
+            def structs(arrays, names):
+                return [jax.ShapeDtypeStruct(arrays[n].shape, arrays[n].dtype)
+                        for n in names]
+
+            seen = jax.eval_shape(
+                forward, structs(self.arg_dict, self.arg_names),
+                structs(self.aux_dict, self.aux_names), self._base_key)
+            for wname, nodes in cand:
+                by_shape = {}
+                for n in nodes:
+                    x, _y = seen[id(n)]
+                    by_shape.setdefault((x.shape, x.dtype), []).append(n)
+                w = self.arg_dict[wname]
+                w_bytes = w.size * w.dtype.itemsize
+                for same in by_shape.values():
+                    copied = sum(v.size * v.dtype.itemsize
+                                 for n in same for v in seen[id(n)])
+                    if (w_bytes * (len(same) - 1) > copied
+                            and _independent(order, batched, same)):
+                        batched.extend(_chunks(
+                            same, -(-w_bytes * len(same) // copied)))
+                        weights += 1
+                        order = _order_with_groups(graph.topo, batched)
+        self._fc_plan = (batched, order if batched else None, weights)
+        return self._fc_plan
+
+    def _count_stacked_wgrad(self):
+        """One launch of a train program: the shared weights whose gradient
+        it computes as one matmul."""
+        weights = self._shared_fc_plan()[2]
+        if weights:
+            _tm.counter("executor.stacked_wgrad").inc(weights)
+
     def _make_grad_core(self):
         """Shared fwd+bwd tracing core used by both the plain train_step
         program and the fused train_update program, so loss construction /
@@ -1130,6 +1350,8 @@ class Executor:
         import jax.numpy as jnp
 
         graph = self.graph
+        batched, order, _weights = self._shared_fc_plan()
+        fc_batches = _FCBatches(batched, order) if batched else None
         wrt_idx = [graph._arg_index[n] for n in self._wrt_names]
         wrt_names = tuple(self._wrt_names)
         add_names = [n for n in self._wrt_names if self.grad_req[n] == "add"]
@@ -1151,7 +1373,8 @@ class Executor:
                 full = list(arg_vals)
                 for i, v in zip(wrt_idx, wrt_vals):
                     full[i] = v
-                outs, aux_upd = graph.evaluate(full, aux_vals, key, True)
+                outs, aux_upd = graph.evaluate(full, aux_vals, key, True,
+                                               fc_batches=fc_batches)
                 total = None
                 for j, o in enumerate(outs):
                     if not jnp.issubdtype(o.dtype, jnp.floating):
@@ -1394,6 +1617,7 @@ class Executor:
                 self._bwd_aux, getattr(self, "_bwd_aux_flat", None),
                 self._bwd_rng, head_grads, self._bwd_prev,
             )
+        self._count_stacked_wgrad()
         self._accept_next_step(
             next_step, getattr(self, "_bwd_rng_val", self._step)
         )
@@ -1971,6 +2195,7 @@ class Executor:
                 self._guard_dev = None  # donated; counters restart at zero
             raise
         self._guard_dev = new_guard
+        self._count_stacked_wgrad()
         self._accept_next_step(
             next_step,
             getattr(self, "_bwd_rng_val", self._step) + (n_steps - 1),

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
+import stacked_wgrad_cases as swc
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.test_utils import assert_almost_equal
 
@@ -217,3 +218,186 @@ def test_xla_flags_reach_compile_options_and_digests(monkeypatch):
     assert _parse_xla_flag("false") is False
     # different flags => different AOT digest for the SAME program
     assert aot.digest("probe") != base_digest
+
+
+# --- FullyConnected nodes that share a weight (Executor._shared_fc_plan) ---
+# a group whose nodes do not read each other runs as one matmul, forward and
+# backward: one stacked weight-gradient matmul a shared weight
+
+def _check_grads(exe, want, rtol, atol):
+    for n, g in want.items():
+        assert_almost_equal(exe.grad_dict[n].asnumpy(), g, rtol=rtol,
+                            atol=atol, names=(f"grad[{n}]", "reference"))
+
+
+@pytest.mark.parametrize("case", sorted(swc.CASES))
+def test_shared_fc_grads_match_per_node_reference(case):
+    sym, shapes, loss, n_groups = swc.CASES[case]()
+    vals = swc.values(sym, shapes)
+    exe = swc.bound(sym, shapes, vals)
+    assert swc.n_stacked(exe) == n_groups
+    exe.forward(is_train=True)
+    exe.backward()
+    tol = (5e-2, 5e-2) if case == "bf16" else (1e-5, 1e-5)
+    _check_grads(exe, swc.reference_grads(loss, vals), *tol)
+
+
+def test_shared_fc_grad_req_add():
+    sym, shapes, loss, n_groups = swc.recurrent("lstm")
+    vals = swc.values(sym, shapes)
+    exe = swc.bound(sym, shapes, vals, grad_req="add")
+    assert swc.n_stacked(exe) == n_groups
+    for g in exe.grad_dict.values():
+        g[:] = 1.0
+    exe.forward(is_train=True)
+    exe.backward()
+    want = {n: g + 1.0 for n, g in swc.reference_grads(loss, vals).items()}
+    _check_grads(exe, want, 1e-5, 1e-5)
+
+
+def test_shared_fc_under_remat(monkeypatch):
+    monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
+    sym, shapes, loss, n_groups = swc.recurrent("lstm")
+    vals = swc.values(sym, shapes)
+    exe = swc.bound(sym, shapes, vals)
+    assert exe.graph.remat and swc.n_stacked(exe) == n_groups
+    exe.forward(is_train=True)
+    exe.backward()
+    _check_grads(exe, swc.reference_grads(loss, vals), 1e-5, 1e-5)
+
+
+def test_shared_fc_on_a_dp_mesh():
+    """Two CPU devices, the batch sharded: the nodes' rows are stacked on a
+    new axis, the sharded batch axis stays where it is, and the gradients
+    still equal the reference's."""
+    sym, shapes, loss, n_groups = swc.recurrent("lstm")
+    vals = swc.values(sym, shapes)
+    fed = sorted(shapes)
+    mod = mx.mod.Module(sym, data_names=fed, label_names=None,
+                        context=[mx.cpu(0), mx.cpu(1)])
+    mod.bind(data_shapes=[(n, shapes[n]) for n in fed], for_training=True,
+             inputs_need_grad=True)
+    mod.init_params(arg_params={n: mx.nd.array(v) for n, v in vals.items()
+                                if n not in shapes}, allow_missing=False)
+    exe = mod._exec_group._exec
+    assert str(exe.arg_dict["data"]._data.sharding.spec) == \
+        "PartitionSpec('dp',)"
+    assert swc.n_stacked(exe) == n_groups
+    mod.forward_backward(mx.io.DataBatch(
+        data=[mx.nd.array(vals[n]) for n in fed], label=None))
+    _check_grads(exe, swc.reference_grads(loss, vals), 1e-5, 1e-5)
+
+
+@pytest.mark.parametrize("groups,batched", [
+    (("a", "a", "a"), 1),  # one device: batched there
+    (("a", "b", "a"), 0),  # split over devices: left alone
+])
+def test_shared_fc_groups_with_ctx_groups(groups, batched):
+    sym, shapes, loss, _n = swc.towers(groups=groups)
+    vals = swc.values(sym, shapes)
+    exe = swc.bound(sym, shapes, vals,
+                    group2ctx={"a": mx.cpu(1), "b": mx.cpu(2)})
+    assert swc.n_stacked(exe) == batched
+    exe.forward(is_train=True)
+    exe.backward()
+    _check_grads(exe, swc.reference_grads(loss, vals), 1e-5, 1e-5)
+
+
+def _lowered_train_step(exe):
+    exe.forward(is_train=True)
+    exe.backward()
+    fn = exe._get_jit("train_step")
+    return fn.jit_fn.lower(
+        exe._bwd_args, exe._bwd_args_flat, exe._bwd_aux, exe._bwd_aux_flat,
+        exe._bwd_rng, None, exe._bwd_prev).as_text()
+
+
+def test_shared_fc_groups_lower_batched_dots():
+    """T = 6, two layers. Each i2h group runs as one batched matmul forward,
+    one for the data gradient and one for the weight's (at these sizes a
+    run holds all six time steps); the h2h chains keep a dot a time step."""
+    sym, shapes, _loss, n_groups = swc.recurrent("lstm", steps=6)
+    vals = swc.values(sym, shapes)
+    req = {n: "null" if n in shapes else "write" for n in vals}
+    dots = {}
+    for name, off in (("batched", False), ("per_node", True)):
+        exe = swc.bound(sym, shapes, vals, grad_req=req)
+        if off:
+            swc.disable(exe)
+        else:
+            batched, order, weights = exe._shared_fc_plan()
+            assert [[n.name[-3:] for n in nodes] for nodes in batched] == [
+                ["i2h"] * 6] * 2 and weights == n_groups
+            assert sorted(map(id, order)) == sorted(map(id, exe.graph.topo))
+        dots[name] = _lowered_train_step(exe).count("stablehlo.dot_general")
+    # the data and the begin states need no gradient. One dot a node:
+    # forward 4 x 6, dgrad 3 x 6 - 2, wgrad 4 x 6. Batched: forward 2 + 2 x 6,
+    # dgrad 1 + (2 x 6 - 2), wgrad 2 + 2 x 6
+    assert dots == {"per_node": 24 + 16 + 24, "batched": 14 + 11 + 14}
+
+
+def test_long_groups_run_in_chunks():
+    """Nine time steps: a run's rows stay about the weight's size, so each
+    i2h group is cut in two (5 + 4) and still counts as one shared weight."""
+    sym, shapes, _loss, n_groups = swc.CASES["chunked"]()
+    exe = swc.bound(sym, shapes, swc.values(sym, shapes))
+    batched, _order, weights = exe._shared_fc_plan()
+    assert [len(nodes) for nodes in batched] == [5, 4, 5, 4]
+    assert weights == n_groups == 2
+
+
+def test_chunks_are_as_equal_as_they_come():
+    cut = lambda n, cap: [len(c) for c in mx.executor._chunks(
+        list(range(n)), cap)]
+    assert cut(30, 10) == [10, 10, 10] and cut(60, 10) == [10] * 6
+    assert cut(30, 9) == [8, 8, 7, 7] and cut(10, 10) == [10]
+    assert cut(5, 1) == [2, 2, 1] and cut(6, 100) == [6]
+
+
+def _wide_pair():
+    """A siamese pair of one wide layer at a large batch: the copies of
+    its rows would outweigh the one accumulation they save."""
+    w, b = mx.sym.Variable("fc_weight"), mx.sym.Variable("fc_bias")
+    tower = [mx.sym.FullyConnected(mx.sym.Variable(n), weight=w, bias=b,
+                                   num_hidden=8, name=f"fc_{n}")
+             for n in ("left", "right")]
+    return (mx.sym.MakeLoss(mx.sym.sum(mx.sym.square(tower[0] - tower[1]))),
+            {"left": (64, 8), "right": (64, 8)})
+
+
+def _unshared():
+    net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=8,
+                                name="fc1")
+    net = mx.sym.FullyConnected(mx.sym.tanh(net), num_hidden=8, name="fc2")
+    return mx.sym.MakeLoss(mx.sym.sum(mx.sym.square(net))), {"data": (4, 8)}
+
+
+def _chain():
+    return swc.chain()[:2]
+
+
+@pytest.mark.parametrize("graph", [_wide_pair, _unshared, _chain])
+def test_shared_fc_bypassed_lowers_as_before(graph):
+    """A group the byte rule rejects, a graph with no shared weight and a
+    group whose nodes read each other lower exactly as with the grouping
+    pass disabled."""
+    sym, shapes = graph()
+    vals = swc.values(sym, shapes)
+    exe = swc.bound(sym, shapes, vals)
+    assert swc.n_stacked(exe) == 0
+    assert bool(exe.graph.shared_fc_groups()) == (graph is not _unshared)
+    off = swc.disable(swc.bound(sym, shapes, vals))
+    off.graph.shared_fc_groups = lambda: []
+    assert _lowered_train_step(exe) == _lowered_train_step(off)
+
+
+@pytest.mark.parametrize("is_train", [False, True])
+def test_forward_only_paths_batch_nothing(is_train, monkeypatch):
+    sym, shapes, _loss, _n = swc.recurrent("lstm")
+    exe = swc.bound(sym, shapes, swc.values(sym, shapes))
+    made = []
+    monkeypatch.setattr(mx.executor, "_FCBatches",
+                        lambda *a, **k: made.append(a) or pytest.fail("made"))
+    exe.forward(is_train=is_train)
+    exe.outputs[0].asnumpy()
+    assert made == [] and exe._fc_plan is None

@@ -155,3 +155,53 @@ def test_fused_update_with_monitor_falls_back():
     mod.update()  # falls back; must still apply the update
     after = mod._exec_group._exec.arg_dict["fc1_weight"].asnumpy()
     assert not np.allclose(before, after)
+
+
+@pytest.mark.parametrize("n_steps", [1, 2])
+def test_fused_update_batches_shared_fc(n_steps):
+    """A 2-layer unrolled LSTM through the fused step (one step, and a
+    2-step window over the same batch): the published gradients and the
+    updated parameters equal plain per-step-dot SGD in jax.numpy, with each
+    layer's i2h run as one batched matmul (one stacked wgrad matmul)."""
+    import jax
+    import jax.numpy as jnp
+    import stacked_wgrad_cases as swc
+    from mxnet_tpu import telemetry as tm
+
+    sym, shapes, loss, n_groups = swc.recurrent("lstm")
+    vals = swc.values(sym, shapes)
+    fed = sorted(shapes)
+    mod = mx.mod.Module(sym, data_names=fed, label_names=None,
+                        context=mx.cpu())
+    mod.bind(data_shapes=[(n, shapes[n]) for n in fed])
+    mod.init_params(arg_params={n: mx.nd.array(v) for n, v in vals.items()
+                                if n not in shapes})
+    lr = 0.05
+    mod.init_optimizer(optimizer="sgd", optimizer_params={
+        "learning_rate": lr, "rescale_grad": 1.0})
+    batch = mx.io.DataBatch(data=[mx.nd.array(vals[n]) for n in fed],
+                            label=None)
+    stacked = tm.counter("executor.stacked_wgrad").value
+    plans = tm.counter("executor.fused_plan_compile").value
+    if n_steps == 1:
+        mod.forward_backward(batch)
+        mod.update()
+    else:
+        mod.train_window(batch, n_steps=n_steps)
+    exe = mod._exec_group._exec
+    assert tm.counter("executor.fused_plan_compile").value == plans + 1
+    # one launch, whatever the window's length
+    assert tm.counter("executor.stacked_wgrad").value == stacked + n_groups
+
+    p = {n: jnp.asarray(v) for n, v in vals.items()}
+    for _ in range(n_steps):
+        grads = jax.grad(loss)(p)
+        last = grads
+        p = {n: v if n in shapes else v - lr * grads[n] for n, v in p.items()}
+    params, _ = mod.get_params()
+    for n in params:
+        np.testing.assert_allclose(params[n].asnumpy(), np.asarray(p[n]),
+                                   rtol=1e-5, atol=1e-5, err_msg=n)
+        np.testing.assert_allclose(exe.grad_dict[n].asnumpy(),
+                                   np.asarray(last[n]), rtol=1e-5, atol=1e-5,
+                                   err_msg=f"grad {n}")

@@ -1,6 +1,7 @@
 """RNN cell tests (reference test_rnn.py)."""
 
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu.test_utils import assert_almost_equal
@@ -115,3 +116,32 @@ def test_encode_sentences():
     )
     assert len(vocab) >= 3
     assert sents[0][1] == sents[1][0]  # same token 'b' → same id
+
+
+@pytest.mark.parametrize("mode", ["lstm", "gru", "rnn_tanh"])
+def test_fused_cell_unroll_batches_its_i2h(mode):
+    """FusedRNNCell.unroll unfuses to per-step cells that share their
+    weights: the executor runs each layer's i2h as one batched matmul,
+    forward and backward, and the gradients equal the per-step ones."""
+    import stacked_wgrad_cases as swc
+
+    cell = mx.rnn.FusedRNNCell(swc.H, num_layers=2, mode=mode, prefix="f_")
+    outs, _ = cell.unroll(5, inputs=mx.sym.Variable("data"),
+                          merge_outputs=True)
+    sym = mx.sym.MakeLoss(mx.sym.sum(mx.sym.square(outs)))
+    shapes = {"data": (swc.B, 5, swc.E)}
+    shapes.update({n: (swc.B, swc.H) for n in sym.list_arguments()
+                   if "begin_state" in n})
+    vals = swc.values(sym, shapes)
+    grads = []
+    for stacked in (True, False):
+        exe = swc.bound(sym, shapes, vals)
+        if not stacked:
+            swc.disable(exe)
+        assert swc.n_stacked(exe) == (2 if stacked else 0)
+        exe.forward(is_train=True)
+        exe.backward()
+        grads.append({n: g.asnumpy() for n, g in exe.grad_dict.items()})
+    for n, g in grads[0].items():
+        assert_almost_equal(g, grads[1][n], rtol=1e-5, atol=1e-5,
+                            names=(f"stacked[{n}]", "per-step"))

@@ -608,3 +608,33 @@ def test_kernel_table_top_n_and_trace_dict(tmp_path):
 def test_kernel_table_empty_trace():
     assert tm.kernel_table([]) == []
     assert tm.kernel_table({"traceEvents": []}) == []
+
+
+def test_stacked_wgrad_counter_counts_groups_per_launch():
+    """executor.stacked_wgrad rises by the program's stacked groups at each
+    launch of a train program, and a graph with no shared FullyConnected
+    weight never moves it."""
+    import stacked_wgrad_cases as swc
+
+    tm.reset()
+    sym, shapes, _loss, n_groups = swc.recurrent("lstm")
+    exe = swc.bound(sym, shapes, swc.values(sym, shapes))
+    for launches in (1, 2):
+        exe.forward(is_train=True)
+        exe.backward()
+        exe.grad_dict["l0_i2h_weight"].asnumpy()
+        assert tm.counter("executor.stacked_wgrad").value == \
+            launches * n_groups
+    exe.forward(is_train=False)  # no gradient, no count
+    exe.outputs[0].asnumpy()
+    assert tm.counter("executor.stacked_wgrad").value == 2 * n_groups
+
+    net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=4,
+                                name="fc")
+    plain = mx.sym.MakeLoss(mx.sym.sum(mx.sym.square(net))).simple_bind(
+        mx.cpu(), data=(2, 3))
+    plain.forward(is_train=True)
+    plain.backward()
+    plain.grad_dict["fc_weight"].asnumpy()
+    assert swc.n_stacked(plain) == 0
+    assert tm.counter("executor.stacked_wgrad").value == 2 * n_groups
