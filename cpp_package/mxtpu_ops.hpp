@@ -286,6 +286,18 @@ inline Symbol MakeLoss(const std::string& symbol_name, const Symbol& data, doubl
   return detail::CreateOp("MakeLoss", symbol_name, 1, keys__, ins__, params__);
 }
 
+inline Symbol MoE(const std::string& symbol_name, const Symbol& data, int64_t num_experts, int64_t num_hidden, int64_t top_k, const Symbol* router_weight = nullptr, const Symbol* gate_weight = nullptr, const Symbol* up_weight = nullptr, const Symbol* down_weight = nullptr, double lb_coef = 0.0, double z_coef = 0.0) {
+  detail::ParamMap params__;
+  params__.emplace_back("num_experts", detail::str(num_experts));
+  params__.emplace_back("num_hidden", detail::str(num_hidden));
+  params__.emplace_back("top_k", detail::str(top_k));
+  params__.emplace_back("lb_coef", detail::str(lb_coef));
+  params__.emplace_back("z_coef", detail::str(z_coef));
+  const char* keys__[] = {"data", "router_weight", "gate_weight", "up_weight", "down_weight"};
+  const Symbol* ins__[] = {&data, router_weight, gate_weight, up_weight, down_weight};
+  return detail::CreateOp("MoE", symbol_name, 5, keys__, ins__, params__);
+}
+
 inline Symbol MultiBoxDetection(const std::string& symbol_name, const Symbol& cls_prob, const Symbol* loc_pred = nullptr, const Symbol* anchor = nullptr, int64_t background_id = 0, bool clip = true, bool force_suppress = false, double nms_threshold = 0.5, int64_t nms_topk = -1, double threshold = 0.01, const std::string& variances = "(0.1, 0.1, 0.2, 0.2)") {
   detail::ParamMap params__;
   params__.emplace_back("background_id", detail::str(background_id));
@@ -379,6 +391,14 @@ inline Symbol Proposal(const std::string& symbol_name, const Symbol& cls_prob, c
   return detail::CreateOp("Proposal", symbol_name, 3, keys__, ins__, params__);
 }
 
+inline Symbol RMSNorm(const std::string& symbol_name, const Symbol& data, const Symbol* gamma = nullptr, double eps = 1e-05) {
+  detail::ParamMap params__;
+  params__.emplace_back("eps", detail::str(eps));
+  const char* keys__[] = {"data", "gamma"};
+  const Symbol* ins__[] = {&data, gamma};
+  return detail::CreateOp("RMSNorm", symbol_name, 2, keys__, ins__, params__);
+}
+
 inline Symbol RNN(const std::string& symbol_name, const Symbol& data, const std::string& mode, int64_t num_layers, int64_t state_size, const Symbol* parameters = nullptr, const Symbol* state = nullptr, bool bidirectional = false, const std::string& lstm_q_ = "", double p = 0.0, const std::string& pkeep_ = "", bool state_outputs = false) {
   detail::ParamMap params__;
   params__.emplace_back("mode", mode);
@@ -423,6 +443,14 @@ inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query,
   const char* keys__[] = {"query", "key", "value"};
   const Symbol* ins__[] = {&query, key, value};
   return detail::CreateOp("RingAttention", symbol_name, 3, keys__, ins__, params__);
+}
+
+inline Symbol RotaryEmbedding(const std::string& symbol_name, const Symbol& data, double base = 10000.0) {
+  detail::ParamMap params__;
+  params__.emplace_back("base", detail::str(base));
+  const char* keys__[] = {"data"};
+  const Symbol* ins__[] = {&data};
+  return detail::CreateOp("RotaryEmbedding", symbol_name, 1, keys__, ins__, params__);
 }
 
 inline Symbol SVMOutput(const std::string& symbol_name, const Symbol& data, const Symbol* label = nullptr, double margin = 1.0, double regularization_coefficient = 1.0, bool use_linear = false) {

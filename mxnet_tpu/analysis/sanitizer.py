@@ -83,14 +83,18 @@ class _TLS(threading.local):
 
     def __init__(self):
         self.held = []
+        self.noting = False  # inside _note_acquired's walk of the graph
 
 
 class _State:
     """One per process. ``mutex`` is a BARE ``_thread`` lock — the
-    sanitizer must never watch its own bookkeeping."""
+    sanitizer must never watch its own bookkeeping. It is reentrant: any
+    allocation under it can start a garbage collection, whose finalizers
+    (``MXRecordIO.__del__`` closing a decode pool) take and build
+    sanitized locks on this same thread."""
 
     def __init__(self):
-        self.mutex = _allocate()
+        self.mutex = _thread.RLock()
         self.edges = {}        # a_id -> {b_id: formatted stack (str)}
         self.names = {}        # lock id -> "site (kind#n)"
         self.cycles = []       # report dicts
@@ -161,9 +165,19 @@ def _note_acquired(lock, held):
     lid = lock._san_id
     new_edges = [h._san_id for h in held
                  if lid not in _state.edges.get(h._san_id, ())]
-    if not new_edges:
+    if not new_edges or _tls.noting:
+        # noting: a finalizer run by a collection inside the walk below;
+        # the graph is mid-iteration, so its edge goes unrecorded
         return
     acquiring_stack = _stack(3)
+    _tls.noting = True
+    try:
+        _record_edges(lid, new_edges, acquiring_stack)
+    finally:
+        _tls.noting = False
+
+
+def _record_edges(lid, new_edges, acquiring_stack):
     with _state.mutex:
         for hid in new_edges:
             bucket = _state.edges.setdefault(hid, {})

@@ -54,18 +54,26 @@ def _fold_rng(rng):
     return jax.random.fold_in(base, step)
 
 
-def _lazy_placeholder(shape, dtype):
-    """An NDArray that reports shape/dtype but allocates device zeros only
-    if read before being written (bucketing reshape placeholders)."""
-    nd = NDArray(None)
+def _lazy_placeholder(shape, dtype, ctx=None):
+    """An NDArray that reports shape/dtype but allocates device zeros (on
+    ``ctx``; the default device without one) only if read before being
+    written: what ``simple_bind`` hands out for arguments and gradients,
+    which ``init_params`` and the first backward overwrite, and the
+    bucketing reshape placeholders. ``copyto`` reads the thunk's
+    ``placement`` and writes such a target without making its zeros."""
+    if ctx is not None and not isinstance(ctx, Context):
+        ctx = Context(ctx)
+    nd = NDArray(None, ctx)
 
     def make():
         import jax.numpy as jnp
 
-        nd._data = jnp.zeros(shape, np_dtype(dtype))
+        nd._data = (jnp.zeros(shape, np_dtype(dtype)) if ctx is None
+                    else nd_zeros(shape, ctx=ctx, dtype=dtype)._data)
 
     make.shape = tuple(shape)
     make.dtype = np_dtype(dtype)
+    make.placement = None if ctx is None else ctx.jax_device()
     nd._set_lazy(make)
     return nd
 
@@ -627,6 +635,8 @@ class Executor:
         self._sym_sha_cache = None  # memoized symbol-graph digest
         self._guard_dev = None  # device [total, consec] non-finite counters
         self._fc_plan = None  # memoized _shared_fc_plan
+        self._grads_crowd = None  # memoized _grads_crowd_device
+        self._layer_counts = None  # memoized _transformer_layers
         if shared_exec is not None:
             # bucketing: share compiled-function cache and memory with the
             # master executor (reference shared_exec data_pool_ reuse,
@@ -813,21 +823,25 @@ class Executor:
             if h is None:
                 continue
             # metadata WITHOUT materializing: a deleted (donated) jax array
-            # still exposes its aval shape, and packed-slice thunks carry
-            # shape on the callback — never resolve _data here, that would
-            # slice the pack (per param, per window) just to throw it away
-            old = h._d
-            shape = (tuple(old.shape) if old is not None
-                     else getattr(h._lazy, "shape", None))
+            # still exposes its aval shape, and packed-slice and bind-time
+            # thunks carry shape on the callback — never resolve _data here
+            # (it would slice the pack, or run a scheduled backward, just
+            # to throw the value away). A gradient handle that never held
+            # an array has its argument's shape.
+            shape = next((tuple(v) for v in (
+                getattr(x, "shape", None)
+                for hd in (h, self.arg_dict[n]) for x in (hd._d, hd._lazy))
+                if v is not None), None)
 
             def thunk(n=n):
                 raise MXNetError(
                     f"gradient '{n}' was not published: the last training "
                     "window ran with publish_grads=False (pipelined "
                     "dispatch elides the per-window f32 gradient "
-                    "publication). Run train_window(..., "
-                    "publish_grads=True) or a single step to read "
-                    "per-step gradients.")
+                    "publication), or update() left out gradients that "
+                    "take over an eighth of the device's memory. Read "
+                    "them after backward() and before update(), or run "
+                    "train_window(..., publish_grads=True).")
 
             if shape is not None:
                 thunk.shape = shape
@@ -1335,12 +1349,66 @@ class Executor:
         self._fc_plan = (batched, order if batched else None, weights)
         return self._fc_plan
 
-    def _count_stacked_wgrad(self):
-        """One launch of a train program: the shared weights whose gradient
-        it computes as one matmul."""
+    def _grads_crowd_device(self):
+        """True where one set of this executor's gradients is more than an
+        eighth of its device's memory: the default of a fused step whose
+        caller did not say (``Module.update()``, which cannot know whether
+        its gradients will be read) is then to leave them out of what it
+        returns. Published, a step's gradients live beside the next step's
+        (a fourth float32 copy of a model whose weights and Adam moments
+        already fill most of a chip, twice over) for a reader who, in
+        ``fit``, never comes; left out, they are consumed by the update
+        where they are computed, and ``grad_dict`` raises until a
+        ``backward()`` that is read runs. An explicit ``publish_grads`` is
+        always honoured. False where the device does not report its memory
+        (the CPU). The sizes behind the eighth: docs/architecture.md."""
+        if self._grads_crowd is None:
+            limit = (self._ctx.memory_stats() or {}).get("bytes_limit")
+            # a gradient has its argument's shape and dtype; its own
+            # handle may be a scheduled backward, which a read would run
+            held = sum(
+                int(np.prod(self.arg_dict[n].shape))
+                * np_dtype(self.arg_dict[n].dtype).itemsize
+                for n in self._wrt_names)
+            self._grads_crowd = bool(limit) and held * 8 > limit
+        return self._grads_crowd
+
+    def _transformer_layers(self):
+        """(MoE layers, rows through their grouped matmuls, attention
+        layers) of the bound graph: rows are tokens x ``top_k``, from the
+        bound shapes. Shapes are inferred only where the graph has a
+        ``MoE`` node."""
+        if self._layer_counts is None:
+            ops = [n for n in self.graph.topo if not n.is_variable]
+            moe = [n for n in ops if n.op.name == "MoE"]
+            rows = 0
+            if moe:
+                internals = self._symbol.get_internals()
+                _, shapes, _ = internals.infer_shape(
+                    **{n: tuple(a.shape) for n, a in self.arg_dict.items()})
+                shape_of = dict(zip(internals.list_outputs(), shapes))
+                rows = sum(
+                    int(np.prod(shape_of[n.name + "_output"][:-1]))
+                    * n.params()["top_k"] for n in moe)
+            self._layer_counts = (
+                len(moe), rows,
+                sum(n.op.name == "RingAttention" for n in ops))
+        return self._layer_counts
+
+    def _count_train_launch(self):
+        """One launch of a train program, counted by what it holds: the
+        shared weights whose gradient it computes as one matmul, its
+        sparse-expert layers with the rows they route, its attention
+        layers."""
         weights = self._shared_fc_plan()[2]
         if weights:
             _tm.counter("executor.stacked_wgrad").inc(weights)
+        moe, rows, attention = self._transformer_layers()
+        if moe:
+            _tm.counter("executor.moe_layers").inc(moe)
+            _tm.counter("executor.moe_assignments").inc(rows)
+        if attention:
+            _tm.counter("executor.attention_layers").inc(attention)
 
     def _make_grad_core(self):
         """Shared fwd+bwd tracing core used by both the plain train_step
@@ -1617,7 +1685,7 @@ class Executor:
                 self._bwd_aux, getattr(self, "_bwd_aux_flat", None),
                 self._bwd_rng, head_grads, self._bwd_prev,
             )
-        self._count_stacked_wgrad()
+        self._count_train_launch()
         self._accept_next_step(
             next_step, getattr(self, "_bwd_rng_val", self._step)
         )
@@ -1676,7 +1744,7 @@ class Executor:
         window requires plain ``write`` gradients (no ``add`` accumulation
         carry-in) and no explicit head gradients.
 
-        ``publish_grads=False`` (windows only) drops the boundary gradient
+        ``publish_grads=False`` drops the boundary gradient
         publication from the program's return contract: the final unrolled
         step no longer materialises the f32 ``grad_map``/``grad_flat``
         tensors (XLA dead-codes the casts and the concatenation — for a
@@ -1684,7 +1752,9 @@ class Executor:
         window spent on values nobody reads in a pipelined fit loop).
         Outputs and aux states are still published; reading ``grad_dict``
         after a no-publish window raises MXNetError until the next
-        publishing step runs.
+        publishing step runs. ``None`` (what ``Module.update()`` passes)
+        publishes unless one set of gradients is over an eighth of the
+        device's memory (``_grads_crowd_device``).
         """
         import jax
 
@@ -1788,9 +1858,11 @@ class Executor:
         # tiny donated int32 buffer read back only at sync points (epoch
         # boundaries), so the guard adds zero per-batch host syncs
         guard_on = self._nonfinite_guard_on()
-        # a single step's callers (update(), monitors, guard fallbacks) all
-        # read gradients — publication is only elidable at window depth
-        publish = bool(publish_grads) or n_steps <= 1
+        # the caller's word where it gave one; update() gives none (its
+        # gradients may or may not be read), and then they are published
+        # unless they would crowd the device (_grads_crowd_device)
+        publish = (not self._grads_crowd_device() if publish_grads is None
+                   else bool(publish_grads))
         plan_key = (tuple(update_names), cache_token, with_hg, state_td,
                     state_handles is not None, sched_mesh, n_steps,
                     stack_names, guard_on, publish)
@@ -2038,8 +2110,14 @@ class Executor:
                 )
             else:
                 plan_auto = False
+                step_fn = _step
+                if not publish:
+                    def step_fn(*args):
+                        (outs, aux_big, aux_flat_out, _gm, _gf,
+                         *rest) = _step(*args)
+                        return (outs, aux_big, aux_flat_out, *rest)
                 jit_fn = jax.jit(
-                    _step, donate_argnums=(0, 1, 3, 4, 8, 9, 10, 11),
+                    step_fn, donate_argnums=(0, 1, 3, 4, 8, 9, 10, 11),
                     compiler_options=_compiler_options(self._ctx),
                 )
             plan = (
@@ -2195,7 +2273,7 @@ class Executor:
                 self._guard_dev = None  # donated; counters restart at zero
             raise
         self._guard_dev = new_guard
-        self._count_stacked_wgrad()
+        self._count_train_launch()
         self._accept_next_step(
             next_step,
             getattr(self, "_bwd_rng_val", self._step) + (n_steps - 1),
@@ -2413,7 +2491,7 @@ class Executor:
                     tuple(shared_exec.arg_dict[n].shape) == tuple(s):
                 args[n] = shared_exec.arg_dict[n]
             else:
-                args[n] = nd_zeros(s, ctx=ctx, dtype=d)
+                args[n] = _lazy_placeholder(s, d, ctx)
         grad_req_d = (
             {n: grad_req for n in arg_names}
             if isinstance(grad_req, str)
@@ -2430,7 +2508,7 @@ class Executor:
                         tuple(shared_exec.grad_dict[n].shape) == tuple(s):
                     args_grad[n] = shared_exec.grad_dict[n]
                 else:
-                    args_grad[n] = nd_zeros(s, ctx=ctx, dtype=d)
+                    args_grad[n] = _lazy_placeholder(s, d, ctx)
         aux_states = {}
         for n, s, d in zip(aux_names, aux_shapes, aux_dtypes):
             if shared_exec is not None and n in shared_exec.aux_dict and \

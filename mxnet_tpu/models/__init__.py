@@ -1,7 +1,7 @@
 """Model zoo — symbol builders for the reference's target workloads
 (BASELINE.json configs): MLP/LeNet (MNIST), ResNet-50 (ImageNet DP),
-VGG-16 (SSD backbone), Inception-BN, DCGAN generator/discriminator, and the
-bucketed LSTM language model.
+VGG-16 (SSD backbone), Inception-BN, DCGAN generator/discriminator, the
+bucketed LSTM language model and the OLMoE sparse-expert decoder.
 
 Reference: ``example/image-classification/symbols/*.py`` and
 ``example/rnn``/``example/gan``. Builders return plain Symbols usable with
@@ -21,6 +21,7 @@ from .inception_resnet_v2 import get_symbol as inception_resnet_v2
 from .dcgan import make_generator as dcgan_generator
 from .dcgan import make_discriminator as dcgan_discriminator
 from .lstm_lm import lstm_lm_serving_sym_gen, lstm_lm_sym_gen
+from .olmoe import olmoe_sym_gen
 from . import ssd
 from . import zoo
 from .zoo import SCORE_SYMBOLS

@@ -26,8 +26,9 @@ conv stack in (``MXNET_CONV_LAYOUT``, ops/layout.py) so benches and tools
 can stamp records without re-deriving the resolution rule.
 
 ``estimate_flops`` is the per-symbol analytic model that lets bench report
-MFU for every workload (conv/deconv/dense/rnn counted from the serialized
-graph + inferred shapes) instead of hardcoding ResNet-50@224. Grouped and
+MFU for every workload (conv/deconv/dense/rnn/attention/routed experts
+counted from the serialized graph + inferred shapes) instead of hardcoding
+ResNet-50@224. Grouped and
 depthwise Convolution count ``in_ch/num_group`` MACs per output — computed
 from the node attrs, not the weight-shape lookup, so ResNeXt-style MFU is
 not overstated even when the weight input is an already-shaped composite.
@@ -38,7 +39,7 @@ import json
 import numpy as np
 
 from .. import symbol as sym
-from ..base import parse_shape
+from ..base import parse_bool, parse_shape
 
 # name -> (compute/activation dtype, parameter master dtype)
 RECIPES = {
@@ -143,7 +144,9 @@ def _node_shape(shape_dict, nodes, node_ref):
 def estimate_flops(symbol, batch=None, **shape_kwargs):
     """Analytic forward FLOPs **per sample** for ``symbol``.
 
-    Counts Convolution, Deconvolution, FullyConnected and the fused RNN op
+    Counts Convolution, Deconvolution, FullyConnected, the fused RNN op,
+    RingAttention (a causal one at half its scores) and MoE (the router and
+    the ``top_k`` routed experts, not all of them)
     in the published-table convention (one multiply-add = one FLOP, the
     convention behind the ResNet-50 = 4.1 GFLOPs/img figure that bench's
     MFU numbers have used since PR-3); the unrolled LSTM graphs decompose
@@ -170,9 +173,26 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
     total = 0.0
     for node_id, node in enumerate(nodes):
         op = node["op"]
-        if op not in ("Convolution", "Deconvolution", "FullyConnected", "RNN"):
+        if op not in ("Convolution", "Deconvolution", "FullyConnected", "RNN",
+                      "RingAttention", "MoE"):
             continue
         attrs = node.get("attrs") or {}
+        if op == "RingAttention":
+            # q (B, H, T, D): q.k and p.v, a causal row sees half the keys
+            q = _node_shape(shape_dict, nodes, node["inputs"][0])
+            if q:
+                seen = 0.5 if parse_bool(attrs.get("causal", False)) else 1.0
+                total += 2.0 * seen * _prod(q) * int(q[2]) / batch
+            continue
+        if op == "MoE":
+            # every row through the router, and through gate, up and down
+            # of the top_k experts it is routed to (not of all of them)
+            data = _node_shape(shape_dict, nodes, node["inputs"][0])
+            if data:
+                h, f = int(data[-1]), int(attrs["num_hidden"])
+                total += (_prod(data[:-1]) / batch) * h * (
+                    int(attrs["num_experts"]) + int(attrs["top_k"]) * 3 * f)
+            continue
         if op == "RNN":
             # data (T, N, C); per layer/dir: gates × h × (in + h) MACs/step
             data_shape = _node_shape(shape_dict, nodes, node["inputs"][0])
@@ -197,6 +217,8 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
             # the graph folds time into the leading axis (seq-major heads)
             in_shape = _node_shape(shape_dict, nodes, node["inputs"][0])
             rows = int(in_shape[0]) if in_shape else batch
+            if in_shape and not parse_bool(attrs.get("flatten", True)):
+                rows = _prod(in_shape[:-1])  # FC over the last axis
             total += 1.0 * (rows / batch) * _prod(w)
         elif op == "Convolution":
             out = _node_shape(shape_dict, nodes, (node_id, 0))

@@ -309,7 +309,7 @@ class DataParallelExecutorGroup:
         return getattr(self._exec, "_bwd_scheduled", False)
 
     def update_fused(self, optimizer, updater, n_steps=1, data_stacks=None,
-                     publish_grads=True):
+                     publish_grads=None):
         """Apply the optimizer inside the executor's jitted train step.
 
         TPU replacement for the reference's per-parameter ``Updater`` loop

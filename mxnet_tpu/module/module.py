@@ -474,7 +474,15 @@ class Module(BaseModule):
         self._require(bound=True, params=True)
         self._exec_group.backward(out_grads=out_grads)
 
-    def update(self):
+    def update(self, publish_grads=None):
+        """Apply the optimizer to the gradients of the last backward.
+
+        Where the step runs as one fused program, ``publish_grads`` says
+        whether that program returns its gradients for a later read of
+        ``grad_dict``: True or False is honoured; None, for a caller who
+        cannot know, publishes them unless one set is over an eighth of
+        the device's memory (``Executor._grads_crowd_device``). The
+        unfused path always leaves them readable."""
         self._require(bound=True, params=True, optimizer=True)
         self._params_dirty = True
         if self._fusable_update():
@@ -482,7 +490,8 @@ class Module(BaseModule):
                 self._kvstore._updater if self._update_on_kvstore
                 else self._updater
             )
-            self._exec_group.update_fused(self._optimizer, updater)
+            self._exec_group.update_fused(self._optimizer, updater,
+                                          publish_grads=publish_grads)
             self._sync_kvstore_after_fused()
             return
         if self._nonfinite_skip_imperative():
@@ -552,8 +561,11 @@ class Module(BaseModule):
             for i in range(max(1, n_steps)):
                 b = batches[i] if batches is not None else data_batch
                 self.forward_backward(b)
-                self.update()
-            # the serial loop leaves real values in grad_dict either way;
+                # asked for, gradients are published; not asked for, the
+                # step keeps update()'s own default
+                self.update(publish_grads=True if publish_grads else None)
+            # the serial loop leaves real values in grad_dict either way
+            # (but for gradients that crowd the device, Module.update);
             # honoring publish_grads skips the per-window by-value snapshot
             # (len(_wrt_names) NDArray wraps + packed-slice materializations)
             # the pipelined fit loop would immediately discard

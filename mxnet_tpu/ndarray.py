@@ -176,11 +176,17 @@ class NDArray:
         if isinstance(other, NDArray):
             if other is self:
                 return other
-            tgt = other._data
-            placement = tgt.sharding if hasattr(tgt, "sharding") else list(tgt.devices())[0]
-            other._data = jax.device_put(
-                self._data.astype(tgt.dtype), placement
-            )
+            # zeros not made yet (executor._lazy_placeholder): nothing to
+            # read, so do not make them just to drop them
+            lazy = other._lazy if other._d is None else None
+            placement = getattr(lazy, "placement", None)
+            if placement is not None:
+                dtype = lazy.dtype
+            else:
+                tgt = other._data
+                dtype = tgt.dtype
+                placement = tgt.sharding if hasattr(tgt, "sharding") else list(tgt.devices())[0]
+            other._data = jax.device_put(self._data.astype(dtype), placement)
             return other
         if isinstance(other, Context):
             return NDArray(jax.device_put(self._data, other.jax_device()), other)

@@ -8,6 +8,7 @@ from . import defs_elemwise  # noqa: F401
 from . import defs_tensor  # noqa: F401
 from . import defs_reduce  # noqa: F401
 from . import defs_nn  # noqa: F401
+from . import defs_transformer  # noqa: F401
 from . import defs_random  # noqa: F401
 from . import defs_optimizer  # noqa: F401
 from . import defs_contrib  # noqa: F401

@@ -563,8 +563,10 @@ def _ring_attention_op(ins, params, mode):
     the configured sequence axis is installed (``mx.parallel.with_mesh``)
     at trace time, attention runs as blockwise ring attention — K/V blocks
     rotate over ICI via ppermute inside the caller's jitted program
-    (parallel/ring_attention.py); without one it is exact full attention,
-    so the same symbol serves single-chip and sequence-parallel runs.
+    (parallel/ring_attention.py); without one it is the same online
+    softmax over blocks of queries on one device (``blockwise_attention``:
+    exact, and linear in T where the whole score matrix is quadratic), so
+    the same symbol serves single-chip and sequence-parallel runs.
     """
     from ..parallel.mesh import current_mesh
     from ..parallel.ring_attention import ring_attention_traced

@@ -176,6 +176,53 @@ def test_rlock_reentry_is_not_a_cycle(armed):
     assert rep["cycles"] == []
 
 
+@pytest.mark.parametrize("inside", ["building a lock", "noting an edge"])
+def test_finalizer_inside_the_sanitizers_own_section_cannot_deadlock(
+        armed, monkeypatch, inside):
+    """A garbage collection can start at any allocation, also under the
+    sanitizer's own mutex, and its finalizers take and build sanitized
+    locks on the same thread (``MXRecordIO.__del__`` closing a decode
+    pool inside ``RLock()``: the hang of the whole tier-1 run that PR 26
+    found). Stood in for by a hook that does what such a finalizer does."""
+    outer, inner = threading.Lock(), threading.Lock()
+
+    ran = []
+
+    def finalizer():
+        if ran:
+            return
+        ran.append(True)
+        with outer:
+            with inner:        # a first-seen edge: takes the mutex
+                pass
+        threading.Lock()       # and so does building a lock
+
+    if inside == "building a lock":
+        def hooked(site=sanitizer._site):
+            finalizer()
+            return site()
+        monkeypatch.setattr(sanitizer, "_site", hooked)
+        work = threading.Lock
+    else:
+        first, second = threading.Lock(), threading.Lock()
+
+        def hooked(frm, to, walk=sanitizer._path_exists):
+            finalizer()
+            return walk(frm, to)
+        monkeypatch.setattr(sanitizer, "_path_exists", hooked)
+
+        def work():
+            with first:
+                with second:
+                    pass
+
+    t = threading.Thread(target=work, daemon=True)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive()
+    assert sanitizer.report()["cycles"] == []
+
+
 # ------------------------------------------------------------ plumbing
 
 def test_install_uninstall_roundtrip():
