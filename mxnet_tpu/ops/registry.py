@@ -30,11 +30,13 @@ a traced input, making whole training steps reproducible from one seed.
 from __future__ import annotations
 
 import functools
+import threading
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from .. import telemetry as _tm
 from ..base import MXNetError, np_dtype
 
 _REQUIRED = object()
@@ -188,7 +190,18 @@ class OpDef:
         """Return (completed_in_shapes, out_shapes, aux_shapes).
 
         ``in_shapes`` covers args then aux, entries may be None (unknown).
+        Answered from the process-wide memo where this signature was
+        evaluated before (see :class:`_InferMemo`).
         """
+        key = _signature(
+            "shape", self.name, params,
+            [None if s is None else tuple(s) for s in in_shapes],
+            None if in_dtypes is None
+            else [None if d is None else np_dtype(d) for d in in_dtypes])
+        return _MEMO.answer(
+            key, lambda: self._eval_shapes(in_shapes, params, in_dtypes))
+
+    def _eval_shapes(self, in_shapes, params, in_dtypes):
         import jax
 
         names = self.arg_names(params) + self.aux_names(params)
@@ -213,6 +226,7 @@ class OpDef:
             for s, d in zip(shapes, dtypes)
         ]
         mode = OpMode(is_train=True, rng=_dummy_key_struct() if self.need_rng else None)
+        _INFER_EVAL.inc()
         try:
             outs, new_aux = jax.eval_shape(
                 lambda ins: self.apply(ins, params, mode), structs
@@ -222,7 +236,6 @@ class OpDef:
                 f"op {self.name}: shape inference failed for inputs "
                 f"{list(zip(names, shapes))}: {e}"
             ) from e
-        n_aux = len(self.aux_names(params))
         n_args = len(self.arg_names(params))
         arg_shapes = [tuple(s) for s in shapes[:n_args]]
         aux_shapes = [tuple(s) for s in shapes[n_args:]]
@@ -230,14 +243,22 @@ class OpDef:
         return arg_shapes, out_shapes, aux_shapes
 
     def infer_dtype(self, in_dtypes, params):
+        """Return (arg_dtypes, out_dtypes, aux_dtypes), memoised as
+        :meth:`infer_shape` is."""
+        key = _signature(
+            "dtype", self.name, params,
+            [None if d is None else np_dtype(d) for d in in_dtypes], None)
+        return _MEMO.answer(
+            key, lambda: self._eval_dtypes(in_dtypes, params))
+
+    def _eval_dtypes(self, in_dtypes, params):
         import jax
 
-        names = self.arg_names(params) + self.aux_names(params)
         dtypes = self._complete_dtypes(list(in_dtypes), params)
         # Outputs via eval_shape on rank-consistent dummy shapes is not
         # possible without shapes; use scalar-broadcastable probe shapes.
-        probe = [(1,) * 0 for _ in names]
         mode = OpMode(is_train=True, rng=_dummy_key_struct() if self.need_rng else None)
+        _INFER_EVAL.inc()
         try:
             structs = [
                 jax.ShapeDtypeStruct((), np_dtype(d)) for d in dtypes
@@ -263,6 +284,96 @@ def _dummy_key_struct():
     import jax
 
     return jax.random.PRNGKey(0)
+
+
+# ---------------------------------------------------------------------------
+# Inference memo
+# ---------------------------------------------------------------------------
+_INFER_EVAL = _tm.counter("symbol.infer_eval")
+_INFER_MEMO_HIT = _tm.counter("symbol.infer_memo_hit")
+
+
+class _InferMemo:
+    """Results of abstract evaluation by node signature, for the process.
+
+    Abstract evaluation of a registered op is a pure function of (op,
+    params, input shapes, input dtypes), and an unrolled or repeated graph
+    asks the same few questions thousands of times (``Module.bind`` of six
+    unrolled LSTM graphs: 12 684 evaluations, 82 distinct). Entries are
+    immutable tuples; an error is never stored, so a failed inference fails
+    again. At most ``cap`` entries, the least recently asked out first: a
+    server that binds ever new shapes does not grow, and keeps the
+    signatures of its steady buckets. There is no option and no switch;
+    ``cap=0`` (store nothing) is the tests' bypass
+    (``test_utils.infer_memo_table``) and nothing else passes it.
+    ``Custom`` overrides both inference methods (its prop's callbacks are
+    user code that may hold state) and never gets here.
+    """
+
+    def __init__(self, cap=4096):
+        self.cap = cap
+        self._table = {}
+        self._lock = threading.Lock()
+
+    def __len__(self):
+        return len(self._table)
+
+    def answer(self, key, evaluate):
+        """The three lists ``evaluate()`` returns, from the table where
+        ``key`` (None: not memoised) was answered before. Callers write
+        into what they get: fresh lists of immutable entries each time."""
+        res = None
+        if key is not None:
+            with self._lock:
+                res = self._table.pop(key, None)
+                if res is not None:
+                    self._table[key] = res  # asked last: evicted last
+        if res is not None:
+            _INFER_MEMO_HIT.inc()
+        else:
+            res = tuple(tuple(part) for part in evaluate())
+            if key is not None and self.cap > 0:
+                with self._lock:
+                    # room first: a reader never sees more than ``cap``
+                    self._table.pop(key, None)
+                    if len(self._table) >= self.cap:
+                        del self._table[next(iter(self._table))]
+                    self._table[key] = res
+        return list(res[0]), list(res[1]), list(res[2])
+
+
+_MEMO = _InferMemo()
+
+
+def _frozen(v):
+    """``v`` as a hashable value that differs wherever ``v`` does: the type
+    goes in, since ``1 == 1.0 == True`` hash alike. TypeError for a value
+    it does not know (an array, say) or that never equals itself (NaN,
+    which would miss every time and fill the table): that node is then not
+    memoised."""
+    if v is None or isinstance(v, (str, np.dtype)):
+        return v
+    if isinstance(v, (bool, int, float, np.generic)):
+        if v != v:
+            raise TypeError("nan")
+        return (type(v).__name__, v)
+    if isinstance(v, (tuple, list)):
+        return (type(v).__name__,) + tuple(_frozen(x) for x in v)
+    raise TypeError(type(v).__name__)
+
+
+def _signature(kind, op_name, params, ins, in_dtypes):
+    """Memo key of one inference question, or None where a parameter value
+    cannot be frozen. ``jax_enable_x64`` changes the dtypes jax answers
+    with, so it is part of the question."""
+    import jax
+
+    try:
+        return (kind, op_name, bool(jax.config.jax_enable_x64),
+                tuple(sorted((k, _frozen(v)) for k, v in params.items())),
+                tuple(ins), None if in_dtypes is None else tuple(in_dtypes))
+    except TypeError:
+        return None
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ test_utils.py:470), ``check_symbolic_forward/backward`` (:591,656),
 
 from __future__ import annotations
 
+import contextlib
 import time
 
 import numpy as np
@@ -456,3 +457,18 @@ def simple_forward(sym, ctx=None, is_train=False, **inputs):
     if len(outputs) == 1:
         outputs = outputs[0]
     return outputs
+
+
+@contextlib.contextmanager
+def infer_memo_table(cap=0):
+    """Tests only: shape and type inference against a fresh memo table of
+    ``cap`` entries while the block runs. 0 stores nothing, so every
+    question is evaluated anew: the path without the memo."""
+    from .ops import registry
+
+    saved = registry._MEMO
+    registry._MEMO = table = registry._InferMemo(cap=cap)
+    try:
+        yield table
+    finally:
+        registry._MEMO = saved
