@@ -8,8 +8,8 @@ points a user calls, at the full width of the north-star model (ResNet-50,
 * train leg — ``Module.fit`` over the resident synthetic iterator of
   ``examples/common/fit.py`` (the code path of ``train_imagenet.py
   --benchmark 1``): one epoch of 8 single-step batches, then one epoch under
-  ``MXNET_TRAIN_WINDOW=4 MXNET_DISPATCH_DEPTH=2`` so the fused K-step window,
-  its compiler-chosen-layout branch and pipelined dispatch all run;
+  ``MXNET_TRAIN_WINDOW=4 MXNET_DISPATCH_DEPTH=2`` so the fused K-step window
+  and pipelined dispatch both run;
 * serve leg — a ``ModelServer`` on the same symbol and the weights just
   trained, one bucket of 8, three ``predict`` calls, answers compared with
   the trained module's own inference forward.
@@ -118,7 +118,6 @@ def train_leg(mx, ctxs, batch, image, num_layers, num_classes, clock):
 
     from mxnet_tpu import models
     from mxnet_tpu import telemetry as tm
-    from mxnet_tpu.executor import fused_window_input_formats
 
     devices = [c.jax_device() for c in ctxs]
     layout = models.recipe.conv_layout(ctxs[0])
@@ -214,18 +213,8 @@ def train_leg(mx, ctxs, batch, image, num_layers, num_classes, clock):
           f"resident-batch accuracy did not rise: boundaries {accs0[0]} -> "
           f"{accs1[-1]}, fit metric {fit_acc0} -> {fit_acc1}")
 
-    # the K-step window executable and its buffer layouts
-    formats = fused_window_input_formats()
-    single = len(devices) == 1
-    if single and devices[0].platform == "tpu":
-        check(formats is not None,
-              "window executable was compiled with default layouts")
-    report("train.window_layouts",
-           compiler_chosen=formats is not None,
-           inputs=len(formats or ()),
-           distinct_layouts=len({str(f.layout) for f in formats or ()}))
-
     # where the state lives
+    single = len(devices) == 1
     exe = mod._exec_group.execs[0]
     for name, arr in list(exe.arg_dict.items()) + list(exe.aux_dict.items()):
         check(_on_devices(arr._data, devices),

@@ -59,9 +59,7 @@ def _place_compile_cache():
     ``<checkout>/.jax_cache``, derived from this package's own location
     and nothing else — the directory is part of jax's cache key, so a
     path that moves between runs never hits. Every ``lower().compile()``
-    (``aot.AOTProgram``, the fused train step) goes through this cache —
-    except a window compiled with compiler-chosen layouts, which
-    ``executor._compile_uncached`` keeps out of it. Must run before the
+    (``aot.AOTProgram``) goes through this cache. Must run before the
     first compile, so it lives at package import.
     """
     import os

@@ -4,14 +4,19 @@ Host dispatch *around* the XLA computation is time the device idles, and a
 fresh process pays full XLA compilation for every graph signature. This
 module is the standard JAX production answer, in three coordinated pieces:
 
-1. **AOT dispatch** (:class:`AOTProgram`) — ``Executor._get_jit`` programs
-   are ``lower().compile()``d to concrete executables on first call and
+1. **AOT dispatch** (:class:`AOTProgram`) — every executor program
+   (forward, train step, the fused donating train update) is
+   ``lower().compile()``d to a concrete executable on first call and
    invoked directly from then on: no re-trace machinery, no per-call jit
-   cache lookup or argument re-inference in the steady-state hot loop. Any
-   AOT failure falls back (permanently, per program) to the plain jitted
-   callable, so semantics never depend on the fast path; every fallback
-   is counted (``aot.compile_fallback`` / ``aot.exec_fallback``) so a run
-   can assert it stayed on the fast path.
+   cache lookup or argument re-inference in the steady-state hot loop.
+   :meth:`AOTProgram._resolve` is the one place where an executable is
+   looked up, lowered, compiled and stored. A program that donates nothing
+   falls back (permanently, per program) to the plain jitted callable on
+   any AOT failure, so semantics never depend on the fast path; every
+   fallback is counted (``aot.compile_fallback`` / ``aot.exec_fallback``)
+   so a run can assert it stayed on the fast path. A program that donates
+   has no second try: its failures raise (:class:`DonatedCallError` once
+   the executable was called).
 
 2. **Persistent executable cache** (:func:`load` / :func:`store`) — compiled
    executables serialize to ``MXNET_AOT_CACHE_DIR`` when ``MXNET_AOT_CACHE``
@@ -52,12 +57,14 @@ import threading
 
 from . import env as _env
 from . import telemetry as _tm
+from .base import MXNetError
 
-_CACHE_FORMAT = 1  # bump to invalidate every persisted executable
+_CACHE_FORMAT = 2  # bump to invalidate every persisted executable
 _SUFFIX = ".aotx"
 
 __all__ = [
-    "AOTProgram", "cache_enabled", "cache_dir", "digest", "load", "store",
+    "AOTProgram", "DonatedCallError", "cache_enabled", "cache_dir", "digest",
+    "load", "store",
     "supports_serialization", "choose_train_window", "train_window_setting",
     "choose_dispatch_depth", "dispatch_depth_setting",
     "TrainWindowScheduler",
@@ -181,10 +188,16 @@ def load(key_digest):
                 _tm.span("aot.deserialize"):
             with open(path, "rb") as f:
                 blob = pickle.load(f)
+            import jax
             from jax.experimental import serialize_executable as _se
 
+            # for the devices it was compiled for: jax's default is every
+            # device of the backend, and a one-device program loaded so
+            # wants a shard of each argument on all of them
+            by_id = {d.id: d for d in jax.devices()}
             loaded = _se.deserialize_and_load(
-                blob["payload"], blob["in_tree"], blob["out_tree"]
+                blob["payload"], blob["in_tree"], blob["out_tree"],
+                execution_devices=[by_id[i] for i in blob["devices"]],
             )
     except Exception:
         _tm.counter("aot.deserialize_error").inc()
@@ -209,9 +222,11 @@ def store(key_digest, compiled):
             from jax.experimental import serialize_executable as _se
 
             payload, in_tree, out_tree = _se.serialize(compiled)
+            devices = compiled.runtime_executable().local_devices()
             blob = pickle.dumps({
                 "format": _CACHE_FORMAT, "payload": payload,
                 "in_tree": in_tree, "out_tree": out_tree,
+                "devices": [d.id for d in devices],
             })
     except Exception:
         _tm.counter("aot.serialize_unsupported").inc()
@@ -231,6 +246,12 @@ def store(key_digest, compiled):
 
 # --- AOT program wrapper ----------------------------------------------------
 
+class DonatedCallError(MXNetError):
+    """A donating executable failed after it was called: the buffers it
+    was given are consumed, so the call can be neither retried nor rolled
+    back, and what they held must be restored from outside."""
+
+
 class AOTProgram:
     """A jitted program dispatched through its ahead-of-time executable.
 
@@ -240,20 +261,29 @@ class AOTProgram:
     persisting the result). Steady-state calls invoke the executable
     directly — the jit re-dispatch machinery (cache lookup, argument
     re-inference) costs real milliseconds per step at executor argument
-    counts. Any AOT failure falls back permanently to the jit callable, and
-    a failed *executable* call is retried through jit so a call never
-    half-executes (these programs donate nothing).
+    counts.
+
+    A program that donates nothing never fails for being ahead of time: any
+    AOT failure falls back permanently to the jit callable, and a failed
+    *executable* call is retried through jit. ``donates=True`` says the
+    program consumes arguments, and then there is no second try: a trace or
+    compile failure raises to the caller with every buffer alive, and a
+    failure of the executable's call raises :class:`DonatedCallError`.
+    ``on_compile(lowered, compiled, args)`` is called after each real
+    compile (not after a cache read, which lowers nothing).
     """
 
-    __slots__ = ("jit_fn", "key_digest", "executable", "_counter", "_span",
-                 "_fallback", "_lock")
+    __slots__ = ("jit_fn", "key_digest", "executable", "donates",
+                 "on_compile", "_counter", "_span", "_fallback", "_lock")
 
     def __init__(self, jit_fn, key_digest=None,
                  compile_counter="aot.trace_compile",
-                 compile_span="aot.compile"):
+                 compile_span="aot.compile", donates=False, on_compile=None):
         self.jit_fn = jit_fn
         self.key_digest = key_digest
         self.executable = None
+        self.donates = donates
+        self.on_compile = on_compile
         self._counter = compile_counter
         self._span = compile_span
         self._fallback = False
@@ -268,19 +298,25 @@ class AOTProgram:
                 self.executable = loaded
                 return loaded
             try:
-                _tm.counter(self._counter).inc()  # graftlint: allow=telemetry-catalog(forwards a constructor-chosen literal: executor.jit_compile or aot.trace_compile, both catalogued)
+                _tm.counter(self._counter).inc()  # graftlint: allow=telemetry-catalog(forwards a constructor-chosen literal: executor.jit_compile, executor.fused_plan_compile or aot.trace_compile, all catalogued)
                 with _tm.span(self._span):  # graftlint: allow=telemetry-catalog(forwards a constructor-chosen literal: executor.jit_build or aot.compile, both catalogued)
                     with _tm.span("executor.trace_lower"):
                         lowered = self.jit_fn.lower(*args)
+                    # jax's persistent cache answers inside compile(): a
+                    # read is this layer's work too
                     with _tm.span("executor.compile"):
                         compiled = lowered.compile()
             except Exception:
+                if self.donates:
+                    raise  # nothing was donated; the jit path would donate
                 # tracing raised (e.g. a graph-contract error) or AOT
                 # lowering is unsupported here: let the jit path surface
                 # the same behaviour
                 _tm.counter("aot.compile_fallback").inc()
                 self._fallback = True
                 return None
+            if self.on_compile is not None:
+                self.on_compile(lowered, compiled, args)
             store(self.key_digest, compiled)
             self.executable = compiled
             return compiled
@@ -301,7 +337,11 @@ class AOTProgram:
         try:
             with _tm.span("executor.launch"):
                 return exe(*args)
-        except Exception:
+        except Exception as e:
+            if self.donates:
+                raise DonatedCallError(
+                    "a donating executable failed after it was called; the "
+                    "buffers donated to it are consumed") from e
             # aval mismatch (an argument changed device/layout in a way the
             # executable rejects) — the jit path handles it; stop using AOT
             # for this program rather than paying a failed call per step

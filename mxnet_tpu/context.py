@@ -138,10 +138,10 @@ def current_context():
 def is_tpu(ctx=None):
     """True when ``ctx`` (default: the default jax backend) is a TPU chip.
 
-    The one device test behind every TPU-only choice — NHWC lowering, TPU
-    compiler options, compiler-chosen window layouts. A context that cannot
-    resolve raises rather than answering "no": a chip that failed to
-    initialise must not silently select the host code paths."""
+    The one device test behind the TPU-only choice, NHWC lowering
+    (``ops/layout.py``). A context that cannot resolve raises rather than
+    answering "no": a chip that failed to initialise must not silently
+    select the host code paths."""
     import jax
 
     dev = jax.devices()[0] if ctx is None else ctx.jax_device()

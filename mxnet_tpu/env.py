@@ -79,15 +79,6 @@ _declare("MXNET_PACK_SMALL_PARAMS", _parse_bool, True,
          "tensors otherwise each pay an async staging copy per step. "
          "Disabled automatically under meshes/sharding, ctx-group "
          "placement and NaiveEngine.")
-_declare("MXNET_WINDOW_AUTO_LAYOUT", _parse_bool, True,
-         "Let the TPU compiler choose parameter/state buffer layouts for "
-         "training-window programs (Executor.fused_train_update n_steps>1, "
-         "single device). Kills per-iteration weight-relayout copies the "
-         "default layouts force inside the window loop (measured +2%); "
-         "boundary format conversions happen once, then donated buffers "
-         "stay in compiler-preferred formats. Single-step programs keep "
-         "default layouts (measured -3% there: per-step boundary "
-         "relayouts outweigh the win).")
 _declare("MXNET_PP_MICROBATCHES", int, 0,
          "GPipe microbatch count used when SequentialModule lowers to the "
          "pipeline schedule under a 'pp' mesh axis; 0 = the pp degree. "
@@ -503,22 +494,15 @@ _declare("MXNET_SANITIZER_HOLD_MS", float, 0.0,
          "serving plane). 0 (default) disables hold tracking — the "
          "acquire-path stack capture it needs is the expensive part of "
          "the sanitizer.")
-_declare("MXNET_XLA_TPU_OPTIONS", str, "",
-         "Comma-separated key=value XLA compiler options attached to every "
-         "executor program when the target is a TPU (ignored on CPU). The "
-         "TPU analogue of the reference's cuDNN autotune/workspace knobs "
-         "(MXNET_CUDNN_AUTOTUNE_DEFAULT, Convolution workspace param) — "
-         "e.g. 'xla_tpu_scoped_vmem_limit_kib=65536' trades fusion VMEM "
-         "budget against pipelining (helps some matmul-heavy programs, "
-         "hurts ResNet-style conv nets; benchmark before setting).")
 _declare("MXNET_XLA_FLAGS", str, "",
          "Comma-separated key=value XLA compiler options attached to every "
-         "executor program on EVERY backend (unlike MXNET_XLA_TPU_OPTIONS, "
-         "which is TPU-only; when both are set the TPU options win on "
-         "conflicting keys). Values parse as bool/int/float when they look "
-         "like one, else stay strings — e.g. "
-         "'xla_latency_hiding_scheduler=true,xla_llvm_disable_expensive_"
-         "passes=false'. Feeds the AOT env fingerprint and both executable "
+         "executor program, on every backend (a key the backend does not "
+         "know is XLA's error to report). The analogue of the reference's "
+         "cuDNN autotune/workspace knobs (MXNET_CUDNN_AUTOTUNE_DEFAULT, "
+         "Convolution workspace param). Values parse as bool/int/float when "
+         "they look like one, else stay strings — e.g. "
+         "'xla_latency_hiding_scheduler=true,xla_tpu_scoped_vmem_limit_kib="
+         "65536'. Feeds the AOT env fingerprint and the executable "
          "digests, so persisted AOT caches never serve a program compiled "
          "under different flags. Sweep candidates with BENCH_SWEEP=xla "
          "before adopting a winner (docs/benchmarks.md, Device-side "

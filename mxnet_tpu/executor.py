@@ -29,10 +29,12 @@ bulk execution disables itself when a monitor is installed
 
 from __future__ import annotations
 
+from typing import Any, NamedTuple
+
 import numpy as np
 
 from .base import MXNetError, np_dtype
-from .context import Context, current_context, is_tpu
+from .context import Context, current_context
 from .ndarray import NDArray, ones as nd_ones, zeros as nd_zeros
 from .ops.registry import OpMode
 from . import aot as _aot
@@ -106,6 +108,41 @@ def _split_out(vals, fill):
     return out, jnp.concatenate(segs)
 
 
+class _Packs(NamedTuple):
+    """The small-parameter packs as an executor's programs see them: the
+    static ``(index, offset, size, shape)`` fills of its argument and
+    auxiliary lists, and the names whose gradients share the argument
+    flat's layout."""
+
+    arg_fill: tuple
+    aux_fill: tuple
+    grad_names: tuple
+
+
+def _unpack(packs, arg_vals, arg_flat, aux_vals, aux_flat):
+    """Every program's prologue: the full argument and auxiliary lists
+    from what crossed the program boundary."""
+    return (_fill_packed(arg_vals, arg_flat, packs.arg_fill),
+            _fill_packed(aux_vals, aux_flat, packs.aux_fill))
+
+
+def _repack(packs, aux_upd, grad_map=None):
+    """Every program's epilogue, ``(grad_map, grad_flat, aux_big,
+    aux_flat)``: the gradients of the packed arguments leave the map for
+    one flat f32 buffer, the packed auxiliary states for another."""
+    import jax.numpy as jnp
+
+    grad_flat = None
+    if grad_map is not None and packs.grad_names:
+        grad_map = dict(grad_map)
+        grad_flat = jnp.concatenate([
+            grad_map.pop(n).astype(jnp.float32).ravel()
+            for n in packs.grad_names
+        ])
+    aux_big, aux_flat = _split_out(aux_upd, packs.aux_fill)
+    return grad_map, grad_flat, aux_big, aux_flat
+
+
 def _head_loss_flags(graph):
     """Which graph heads are loss outputs (drive an implicit backward).
 
@@ -140,19 +177,16 @@ def _parse_xla_flag(v):
     return v
 
 
-def _compiler_options(ctx):
-    """XLA compiler options for this executor's programs.
-
-    The stand-in for the reference's per-device kernel tuning knobs (cuDNN
-    autotune registry / Convolution ``workspace``), carried by two
-    catalogued env vars: ``MXNET_XLA_FLAGS`` applies on every backend
-    (values coerced to bool/int/float when they look like one — XLA's
-    debug-option overrides are typed), and ``MXNET_XLA_TPU_OPTIONS`` is
-    layered on top for TPU targets only, winning on conflicting keys.
-    Both feed the AOT digests and the cache env fingerprint, so a
-    persisted executable never serves a program compiled under different
-    flags. ``BENCH_SWEEP=xla`` (bench.py) sweeps candidate flag sets
-    before a winner is adopted.
+def _compiler_options():
+    """XLA compiler options for every executor program, from
+    ``MXNET_XLA_FLAGS``: the stand-in for the reference's per-device kernel
+    tuning knobs (cuDNN autotune registry / Convolution ``workspace``).
+    Values are coerced to bool/int/float when they look like one (XLA's
+    debug-option overrides are typed); a key the backend does not know is
+    the user's error, and XLA says so. The flags feed the AOT digests and
+    the cache env fingerprint, so a persisted executable never serves a
+    program compiled under different flags. ``BENCH_SWEEP=xla`` (bench.py)
+    sweeps candidate flag sets before a winner is adopted.
     """
     from . import env
 
@@ -161,40 +195,7 @@ def _compiler_options(ctx):
         k, _, v = item.strip().partition("=")
         if k:
             opts[k] = _parse_xla_flag(v.strip())
-    if is_tpu(ctx):
-        for item in env.get("MXNET_XLA_TPU_OPTIONS").split(","):
-            k, _, v = item.strip().partition("=")
-            if k:
-                opts[k] = v.strip()
     return opts or None
-
-
-def _compile_uncached(lowered):
-    """``lowered.compile()`` that leaves no entry in jax's persistent
-    compilation cache.
-
-    For executables compiled with compiler-chosen (AUTO) layouts. Under
-    jax 0.9.0 / libtpu 0.0.34 the arrays that come out of an executable
-    *deserialized* from that cache report the default layout whatever
-    layout their buffers really have (read on the v5e: a warm run's second
-    window saw ``f32[64,3,7,7]`` outputs report ``{0,3,2,1:T(8,128)}`` while
-    holding ``{0,1,3,2:T(4,128)}``). jax trusts the report when it lowers
-    the next program that takes such an array, so every consumer — the
-    boundary conversion below first — is handed a buffer it did not compile
-    for. Default-layout executables are unaffected (report and buffer
-    agree), so only this compile stays out of the cache; a warm process
-    pays it again.
-    """
-    import jax
-
-    name = "jax_persistent_cache_min_compile_time_secs"
-    keep = getattr(jax.config, name)
-    jax.config.update(name, float("inf"))  # nothing compiles this slowly
-    try:
-        with _tm.span("executor.compile", source="compiled"):
-            return lowered.compile()
-    finally:
-        jax.config.update(name, keep)
 
 
 # Most recent fused-window lowering/executable, kept as live objects and
@@ -204,10 +205,9 @@ _FUSED_HLO = {}
 _FUSED_DONATE = (0, 1, 3, 4, 8, 9, 10, 11)
 
 
-def _record_fused_hlo(lowered, exe, call_args, input_formats):
-    """Stash the fused train-update program for the donation/upcast audit.
-    ``input_formats``: the executable's compiler-chosen flat input formats
-    (AUTO-layout windows), None when it was compiled with default layouts."""
+def _record_fused_hlo(lowered, exe, call_args):
+    """Stash the fused train-update program for the donation/upcast audit
+    (``AOTProgram``'s ``on_compile`` hook of every fused program)."""
     try:
         import jax
 
@@ -223,7 +223,6 @@ def _record_fused_hlo(lowered, exe, call_args, input_formats):
         _FUSED_HLO.update(
             lowered=lowered, compiled=exe, donated_args=donated,
             n_args=pos, param_shapes=param_shapes,
-            input_formats=input_formats,
         )
     except Exception:  # noqa: BLE001 — observability must not break training
         pass
@@ -237,11 +236,8 @@ def fused_window_hlo():
     ``compiled`` (post-optimization HLO text — the ``input_output_alias``
     header is the executable's aliasing table), ``donated_args`` (flat
     indices the executor donated), ``n_args`` and ``param_shapes`` (shapes
-    of the updated parameters) and ``input_formats`` (the flat
-    ``jax.experimental.layout.Format`` list of a window compiled with
-    compiler-chosen layouts, else None). ``tools/hlo_audit.py`` consumes
-    this to fail on un-aliased donations and stray parameter-sized f32
-    upcasts.
+    of the updated parameters). ``tools/hlo_audit.py`` consumes this to
+    fail on un-aliased donations and stray parameter-sized f32 upcasts.
     """
     if not _FUSED_HLO:
         return None
@@ -251,11 +247,100 @@ def fused_window_hlo():
     return rec
 
 
-def fused_window_input_formats():
-    """``fused_window_hlo()["input_formats"]`` without rendering the HLO
-    text: the flat ``Format`` list of the most recent fused-window compile
-    when its buffer layouts were compiler-chosen, else None."""
-    return _FUSED_HLO.get("input_formats")
+class _StepOut(NamedTuple):
+    """What one fused train step returns, in the order the program has
+    always returned it. ``grads`` and ``grad_flat`` are None where the
+    program does not publish gradients (a pytree without leaves: XLA then
+    dead-codes their f32 casts and the concatenation)."""
+
+    outs: Any
+    aux: Any
+    aux_flat: Any
+    grads: Any
+    grad_flat: Any
+    params: Any
+    arg_flat: Any
+    states: Any
+    st_flat: Any
+    hyper: Any
+    guard: Any
+    step: Any
+
+
+class _TrainKey(NamedTuple):
+    """What one executor's fused train program is traced from beyond the
+    executor's own signature (``_jit_signature``, one an executor, which
+    ``_aot_digest`` adds). Keys ``Executor._fused_plan`` and, rendered, the
+    program's persistent-cache digest."""
+
+    update_names: tuple
+    cache_token: Any     # hashable identity of the optimizer's config
+    with_head_grads: bool
+    state_td: Any        # PyTreeDef of the optimizer states
+    mesh: Any            # ambient mesh when backward() was scheduled
+    n_steps: int
+    stack_names: tuple
+    guard_on: bool
+    publish: bool
+
+
+class _TrainPlan(NamedTuple):
+    """One fused train program and what staging its arguments and
+    installing its results need."""
+
+    key: _TrainKey
+    program: Any         # aot.AOTProgram over the donating jit
+    upd_idx: list        # positions of the updated arguments
+    other_idx: list      # positions of the rest
+    st_pack: Any         # pack of the small optimizer-state leaves, or None
+
+
+def _unpublished(out):
+    return out._replace(grads=None, grad_flat=None)
+
+
+def _window_of(step, n_steps, stack_pos, publish):
+    """``n_steps`` consecutive train steps as one program: ``fori_loop``
+    over ``n_steps - 1`` STATE-ONLY iterations (params / opt-state / aux /
+    rng / hyper thread through the carry; per-iteration outputs and f32
+    gradient publication are dropped so XLA dead-codes them), then one
+    final step unrolled OUTSIDE the loop that returns the full single-step
+    output contract. ``stack_pos`` are the positions in ``other_vals`` that
+    iteration ``i`` takes from slice ``i`` of ``stacks``."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def _step_k(upd_vals, arg_flat, other_vals, aux_vals, aux_flat, rng,
+                heads, prev_grads, st_leaves, st_flat, hyper, guard, stacks):
+        def sub_data(i, ov):
+            ov = list(ov)
+            for p, s in zip(stack_pos, stacks):
+                ov[p] = lax.dynamic_index_in_dim(s, i, 0, keepdims=False)
+            return ov
+
+        def body(i, carry):
+            (upd_c, argf_c, aux_c, auxf_c, rng_c, st_c, stf_c, hyper_c,
+             guard_c) = carry
+            o = step(upd_c, argf_c, sub_data(i, other_vals), aux_c, auxf_c,
+                     rng_c, heads, prev_grads, st_c, stf_c, hyper_c, guard_c)
+            return (o.params, o.arg_flat, o.aux, o.aux_flat,
+                    (rng_c[0], o.step), o.states, o.st_flat, o.hyper,
+                    o.guard)
+
+        init = (upd_vals, arg_flat, aux_vals, aux_flat, rng, st_leaves,
+                st_flat, hyper, guard)
+        (upd_f, argf_f, aux_f, auxf_f, rng_f, st_f, stf_f, hyper_f,
+         guard_f) = lax.fori_loop(0, n_steps - 1, body, init)
+        final = step(
+            upd_f, argf_f,
+            sub_data(jnp.asarray(n_steps - 1, jnp.int32), other_vals),
+            aux_f, auxf_f, rng_f, heads, prev_grads, st_f, stf_f, hyper_f,
+            guard_f)
+        # lazy boundary publication: the whole per-window publish cost a
+        # pipelined fit never reads
+        return final if publish else _unpublished(final)
+
+    return _step_k
 
 
 class _FCBatches:
@@ -630,7 +715,7 @@ class Executor:
 
         self._base_key = _random.next_key()
         self._jit_cache = {}
-        self._fused_plan = {}  # (names, token, hg, treedef) -> (fn, idxs)
+        self._fused_plan = {}  # _TrainKey -> _TrainPlan
         self._sig_cache = None  # memoized _jit_signature
         self._sym_sha_cache = None  # memoized symbol-graph digest
         self._guard_dev = None  # device [total, consec] non-finite counters
@@ -757,6 +842,20 @@ class Executor:
                            jax.sharding.SingleDeviceSharding)
         )
 
+    def _make_pack(self, keys, arrays):
+        """A pack layout over the eligible ``arrays[k]`` for ``k`` in
+        ``keys``, or None: not worth one for a handful of tensors."""
+        sel = [k for k in keys if self._pack_eligible(arrays[k])]
+        if len(sel) < 8:
+            return None
+        offs, off = {}, 0
+        for k in sel:
+            a = arrays[k]
+            offs[k] = (off, int(a.size), tuple(a.shape))
+            off += int(a.size)
+        return {"names": sel, "offs": offs, "total": off,
+                "flat": None, "cells": {}}
+
     def _small_state(self):
         """Packing state, built on first use (None when disabled)."""
         if getattr(self, "_small", False) is not False:
@@ -776,23 +875,11 @@ class Executor:
         if current_mesh() is not None:
             return None
 
-        def build(names, handles):
-            sel = [n for n in names if self._pack_eligible(handles[n]._d)]
-            if len(sel) < 8:
-                return None  # not worth a layout for a handful of tensors
-            offs = {}
-            off = 0
-            for n in sel:
-                a = handles[n]._d
-                offs[n] = (off, int(a.size), tuple(a.shape))
-                off += int(a.size)
-            return {"names": sel, "offs": offs, "total": off,
-                    "flat": None, "cells": {}}
-
-        arg_pack = build(
+        arg_pack = self._make_pack(
             [n for n in self._wrt_names if self.grad_req[n] == "write"],
-            self.arg_dict)
-        aux_pack = build(self.aux_names, self.aux_dict)
+            {n: h._d for n, h in self.arg_dict.items()})
+        aux_pack = self._make_pack(
+            self.aux_names, {n: h._d for n, h in self.aux_dict.items()})
         if arg_pack is None and aux_pack is None:
             return None
         grad_pack = None
@@ -971,9 +1058,7 @@ class Executor:
         """
         sig = self._sig_cache
         if sig is None:
-            small = self._small_state()
-            arg_pack = small["arg"] if small else None
-            aux_pack = small["aux"] if small else None
+            packs = self._packs()
             sig = (
                 tuple((n, self.arg_dict[n].shape, str(self.arg_dict[n].dtype))
                       for n in self.arg_names),
@@ -981,8 +1066,8 @@ class Executor:
                       for n in self.aux_names),
                 tuple(self._wrt_names),
                 tuple(sorted((n, r) for n, r in self.grad_req.items())),
-                self._pack_fill(self.arg_names, arg_pack),
-                self._pack_fill(self.aux_names, aux_pack),
+                packs.arg_fill,
+                packs.aux_fill,
                 self.graph.layout,
             )
             self._sig_cache = sig
@@ -1026,14 +1111,20 @@ class Executor:
             out.append((n, str(spec), self._mesh_token(smesh)))
         return tuple(out)
 
-    def _aot_digest(self, cache_key):
-        """Persistent-cache digest for a jit program, or None when it must
-        not persist: cache off, un-renderable shardings, or interpret
-        modes (their "programs" are python closures). Mesh-sharded
-        programs persist keyed by the mesh spec + device assignment — the
-        GraftMesh cache token joins the signature, so a warm process on
-        the same topology (same MXNET_MESH / installed spec) rebinds with
-        zero XLA compiles and a different layout never false-hits."""
+    def _aot_digest(self, what, fields, mesh):
+        """Persistent-cache digest for one of this executor's programs, or
+        None when it must not persist: cache off, un-renderable shardings,
+        or interpret modes (their "programs" are python closures).
+        ``fields`` is what the trace is determined by beyond the symbol
+        graph and the argument signature: kind and mode of a forward /
+        train-step program, the rendered :class:`_TrainKey` of a fused one
+        (state-leaf shapes follow the parameter signature, and
+        hyperparameters are traced inputs).
+        Mesh-sharded programs persist keyed by the mesh spec + device
+        assignment — the GraftMesh cache token joins the signature, so a
+        warm process on the same topology (same MXNET_MESH / installed
+        spec) rebinds with zero XLA compiles and a different layout never
+        false-hits."""
         if not _aot.cache_enabled():
             return None
         if self._node2dev or self._naive:
@@ -1041,42 +1132,12 @@ class Executor:
         shard_tok = self._shardings_token()
         if shard_tok is None:
             return None
-        opts = _compiler_options(self._ctx)
+        opts = _compiler_options()
         dev = self._ctx.jax_device()
         return _aot.digest(
-            "jit", self._sym_sha(), cache_key[:-1],
-            self._mesh_token(cache_key[-1]), shard_tok, self.graph.remat,
-            self.graph.layout, dev.platform,
-            getattr(dev, "device_kind", ""),
-            tuple(sorted(opts.items())) if opts else (),
-        )
-
-    def _fused_aot_digest(self, plan_key, auto_layout):
-        """Persistent-cache digest for a fused train program, or None under
-        the same non-persistable conditions as :meth:`_aot_digest`. The
-        fused program's trace is determined by the graph + argument
-        signature plus the plan key (update set, optimizer token, state
-        tree structure, window depth, data-stack names, guard flag) —
-        state-leaf shapes follow the parameter signature, and
-        hyperparameters are traced inputs."""
-        if not _aot.cache_enabled():
-            return None
-        if self._node2dev or self._naive:
-            return None
-        shard_tok = self._shardings_token()
-        if shard_tok is None:
-            return None
-        (update_names, cache_token, with_hg, state_td, has_handles,
-         sched_mesh, n_steps, stack_names, guard_on, publish) = plan_key
-        opts = _compiler_options(self._ctx)
-        dev = self._ctx.jax_device()
-        return _aot.digest(
-            "fused", self._sym_sha(), self._jit_signature(),
-            (update_names, cache_token, with_hg, repr(state_td),
-             has_handles, n_steps, stack_names, guard_on, publish),
-            self._mesh_token(sched_mesh), shard_tok,
-            auto_layout, self.graph.remat, self.graph.layout,
-            dev.platform, getattr(dev, "device_kind", ""),
+            what, self._sym_sha(), self._jit_signature(), fields,
+            self._mesh_token(mesh), shard_tok, self.graph.remat,
+            self.graph.layout, dev.platform, getattr(dev, "device_kind", ""),
             tuple(sorted(opts.items())) if opts else (),
         )
 
@@ -1146,46 +1207,33 @@ class Executor:
         if fn is not None:
             _tm.counter("executor.jit_cache_hit").inc()
             return fn
-        small = self._small_state()
-        arg_pack = small["arg"] if small else None
-        aux_pack = small["aux"] if small else None
-        arg_fill = self._pack_fill(self.arg_names, arg_pack)
-        aux_fill = self._pack_fill(self.aux_names, aux_pack)
+        packs = self._packs()
         graph = self.graph
 
         if kind == "forward":
 
             def _fwd(arg_vals, arg_flat, aux_vals, aux_flat, rng):
-                full_args = _fill_packed(arg_vals, arg_flat, arg_fill)
-                full_aux = _fill_packed(aux_vals, aux_flat, aux_fill)
+                full_args, full_aux = _unpack(packs, arg_vals, arg_flat,
+                                              aux_vals, aux_flat)
                 outs, aux_upd = graph.evaluate(
                     full_args, full_aux, _fold_rng(rng), is_train
                 )
-                aux_big, aux_flat_out = _split_out(aux_upd, aux_fill)
+                _, _, aux_big, aux_flat_out = _repack(packs, aux_upd)
                 return outs, aux_big, aux_flat_out, _next_step(rng)
 
             traced = _fwd
         elif kind == "train_step":
             core = self._make_grad_core()
-            grad_names = tuple(arg_pack["names"]) if arg_pack else ()
 
             def _tstep(arg_vals, arg_flat, aux_vals, aux_flat, rng, heads,
                        prev):
-                import jax.numpy as jnp
-
-                full_args = _fill_packed(arg_vals, arg_flat, arg_fill)
-                full_aux = _fill_packed(aux_vals, aux_flat, aux_fill)
+                full_args, full_aux = _unpack(packs, arg_vals, arg_flat,
+                                              aux_vals, aux_flat)
                 outs, aux_upd, grad_map = core(
                     full_args, full_aux, rng, heads, prev
                 )
-                aux_big, aux_flat_out = _split_out(aux_upd, aux_fill)
-                grad_flat = None
-                if grad_names:
-                    grad_map = dict(grad_map)
-                    grad_flat = jnp.concatenate([
-                        grad_map.pop(n).astype(jnp.float32).ravel()
-                        for n in grad_names
-                    ])
+                grad_map, grad_flat, aux_big, aux_flat_out = _repack(
+                    packs, aux_upd, grad_map)
                 return (outs, aux_big, aux_flat_out, grad_map, grad_flat,
                         _next_step(rng))
 
@@ -1205,8 +1253,9 @@ class Executor:
         else:
             fn = _aot.AOTProgram(
                 jax.jit(traced,
-                        compiler_options=_compiler_options(self._ctx)),
-                key_digest=self._aot_digest(cache_key),
+                        compiler_options=_compiler_options()),
+                key_digest=self._aot_digest("jit", cache_key[:3],
+                                            cache_key[-1]),
                 # a real XLA compile in steady state is a perf bug worth
                 # surfacing; deserialized warm starts don't count
                 compile_counter="executor.jit_compile",
@@ -1267,16 +1316,19 @@ class Executor:
                 done.append(kind)
         return done
 
-    @staticmethod
-    def _pack_fill(order, pack):
-        """Static (index, offset, size, shape) tuples mapping a pack's
-        names onto their positions in ``order``."""
-        if pack is None:
-            return ()
-        packed = set(pack["names"])
-        return tuple(
-            (i, *pack["offs"][n]) for i, n in enumerate(order) if n in packed
-        )
+    def _packs(self):
+        """The pack layout this executor's programs are traced with."""
+        small = self._small_state() or {"arg": None, "aux": None}
+
+        def fill(order, pack):
+            packed = set(pack["names"]) if pack else ()
+            return tuple((i, *pack["offs"][n]) for i, n in enumerate(order)
+                         if n in packed)
+
+        return _Packs(
+            fill(self.arg_names, small["arg"]),
+            fill(self.aux_names, small["aux"]),
+            tuple(small["arg"]["names"]) if small["arg"] else ())
 
     def _shared_fc_plan(self):
         """The ``FullyConnected`` groups that this executor's train programs
@@ -1547,17 +1599,10 @@ class Executor:
         if self._monitor_callback is not None:
             import jax
 
+            packs = self._packs()
             with with_mesh(mesh):
-                small = self._small_state()
                 outs, aux_upd = self.graph.evaluate(
-                    _fill_packed(args_in, args_flat,
-                                 self._pack_fill(self.arg_names,
-                                                 small["arg"] if small
-                                                 else None)),
-                    _fill_packed(aux_in, aux_flat,
-                                 self._pack_fill(self.aux_names,
-                                                 small["aux"] if small
-                                                 else None)),
+                    *_unpack(packs, args_in, args_flat, aux_in, aux_flat),
                     jax.random.fold_in(rng[0], int(rng[1])),
                     is_train,
                     monitor=self._monitor_callback,
@@ -1565,10 +1610,7 @@ class Executor:
                 )
             # re-pack the interpreter's full aux list (same split as the
             # jitted path)
-            aux_upd, aux_flat_out = _split_out(
-                aux_upd,
-                self._pack_fill(self.aux_names,
-                                small["aux"] if small else None))
+            _, _, aux_upd, aux_flat_out = _repack(packs, aux_upd)
         else:
             with with_mesh(mesh):
                 fn = self._get_jit("forward", is_train=is_train)
@@ -1685,18 +1727,324 @@ class Executor:
                 self._bwd_aux, getattr(self, "_bwd_aux_flat", None),
                 self._bwd_rng, head_grads, self._bwd_prev,
             )
+        self._finish_backward(outs, aux_upd, aux_flat_out, grad_map,
+                              grad_flat, next_step)
+
+    def _finish_backward(self, outs, aux, aux_flat, grads, grad_flat,
+                         next_step, n_steps=1):
+        """The scheduled backward ran, alone or inside a fused program of
+        ``n_steps`` steps: adopt its outputs, aux states, gradients (None:
+        not published) and step counter."""
         self._count_train_launch()
         self._accept_next_step(
-            next_step, getattr(self, "_bwd_rng_val", self._step)
-        )
+            next_step,
+            getattr(self, "_bwd_rng_val", self._step) + (n_steps - 1))
         self._bwd_scheduled = False  # only consumed on success
         self._set_outputs(outs)
-        self._set_aux(aux_upd, snap=self._bwd_aux, flat=aux_flat_out)
-        for n, g in grad_map.items():
-            self.grad_dict[n]._data = g
-        self._install_grad_flat(grad_flat)
+        self._set_aux(aux, snap=self._bwd_aux, flat=aux_flat)
+        if grads is None:
+            self._mark_grads_unpublished()
+        else:
+            for n, g in grads.items():
+                self.grad_dict[n]._data = g
+            self._install_grad_flat(grad_flat)
         self._pending = None
         self._fresh = True
+
+    def _window_stacks(self, n_steps, data_stacks):
+        """``(names, values)`` of a training window's per-iteration inputs,
+        cast and placed as ``_bind_inputs`` places serially-fed batches;
+        raises where a window cannot run."""
+        import jax
+
+        if n_steps <= 1:
+            if data_stacks:
+                raise MXNetError(
+                    "data_stacks requires a window (n_steps>1); a single step "
+                    "trains on the bound inputs"
+                )
+            return (), ()
+        if self._bwd_heads is not None:
+            raise MXNetError(
+                "a training window (n_steps>1) drives loss heads only; "
+                "explicit head gradients change per step — run "
+                "single-step updates instead"
+            )
+        if self._bwd_prev:  # non-empty ⇔ grad_req='add' accumulation
+            raise MXNetError(
+                "a training window requires grad_req='write' (an 'add' "
+                "accumulation carried across window iterations would "
+                "double-count); use single-step updates"
+            )
+        names = tuple(sorted(data_stacks or ()))
+        vals = ()
+        for nm in names:
+            if nm not in self.graph._arg_index:
+                raise MXNetError(
+                    f"data_stacks name '{nm}' is not a bound input"
+                )
+            v = data_stacks[nm]
+            v = v._data if isinstance(v, NDArray) else v
+            tgt = self.arg_dict[nm]
+            want = (n_steps,) + tuple(tgt.shape)
+            if tuple(v.shape) != want:
+                raise MXNetError(
+                    f"data_stacks['{nm}'] shape {tuple(v.shape)} != "
+                    f"(n_steps,)+bound shape {want}"
+                )
+            v = v.astype(np_dtype(tgt.dtype))
+            sh = self._in_shardings.get(nm)
+            if sh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                # the window dim is replicated: every device sees all steps
+                if isinstance(sh, NamedSharding):
+                    sh = NamedSharding(sh.mesh, PartitionSpec(None, *sh.spec))
+                v = jax.device_put(v, sh)
+            vals += (v,)
+        return names, vals
+
+    def _train_step_fn(self, key, apply_fn, upd_idx, other_idx, st_fill):
+        """The traced body of one fused train step: forward + backward (the
+        grad core the plain train-step program shares), the optimizer
+        applied to every updated parameter, the non-finite guard, and the
+        same prologue and epilogue as the other two programs."""
+        import jax
+
+        core = self._make_grad_core()
+        packs = self._packs()
+        packed_args = set(packs.grad_names)
+        arg_index = self.graph._arg_index
+        n_args = len(self.arg_names)
+        update_names, state_td, guard_on = (
+            key.update_names, key.state_td, key.guard_on)
+
+        def _step(upd_vals, arg_flat, other_vals, aux_vals, aux_flat, rng,
+                  heads, prev_grads, st_leaves, st_flat, hyper, guard):
+            import jax.numpy as jnp
+
+            full = [None] * n_args
+            for i, v in zip(upd_idx, upd_vals):
+                full[i] = v
+            for i, v in zip(other_idx, other_vals):
+                full[i] = v
+            full, full_aux = _unpack(packs, full, arg_flat, aux_vals,
+                                     aux_flat)
+            st_full = _fill_packed(st_leaves, st_flat, st_fill)
+            outs, aux_upd, grad_map = core(
+                full, full_aux, rng, heads, prev_grads
+            )
+            rng_key = _fold_rng(rng)
+            lr_v, wd_v, t_v = hyper[0], hyper[1], hyper[2]
+            sts = jax.tree_util.tree_unflatten(state_td, st_full)
+            new_params, new_states = [], []
+            for i, nm in enumerate(update_names):
+                prng = jax.random.fold_in(rng_key, 0x5EED + i)
+                w, s = apply_fn(
+                    i, full[upd_idx[i]], grad_map[nm], sts[i],
+                    lr_v[i], wd_v[i], t_v[i], prng,
+                )
+                new_params.append(w)
+                new_states.append(s)
+            new_guard = guard
+            if guard_on:
+                # one scalar reduction per gradient, fused into the
+                # backward epilogue: any NaN/Inf element propagates to
+                # the sum (Inf-Inf=NaN included), so isfinite of the
+                # summed sums detects every non-finite gradient without
+                # an elementwise isfinite+all pass per tensor. (A
+                # finite sum overflowing f32 would skip a good batch —
+                # harmless and astronomically rare.)
+                probe = jnp.float32(0)
+                for nm in update_names:
+                    probe = probe + jnp.sum(
+                        grad_map[nm].astype(jnp.float32))
+                finite = jnp.isfinite(probe)
+                # a non-finite step keeps the OLD params, optimizer
+                # state AND aux (BN running stats already absorbed the
+                # poisoned batch in forward — roll them back too); the
+                # rng/step/t counters still advance, keeping the host's
+                # schedule mirrors coherent without a round trip
+                new_params = [
+                    jnp.where(finite, w, full[upd_idx[i]])
+                    for i, w in enumerate(new_params)
+                ]
+                new_states = [
+                    jax.tree_util.tree_map(
+                        lambda nw, ol: jnp.where(finite, nw, ol), ns, os_
+                    )
+                    for ns, os_ in zip(new_states, sts)
+                ]
+                aux_upd = [
+                    jnp.where(finite, a, o)
+                    for a, o in zip(aux_upd, full_aux)
+                ]
+                miss = jnp.where(finite, 0, 1).astype(guard.dtype)
+                new_guard = jnp.stack([
+                    guard[0] + miss,
+                    (guard[1] + miss) * miss,  # consecutive: reset on ok
+                ])
+            new_leaves = jax.tree_util.tree_flatten(new_states)[0]
+            new_leaves, st_flat_out = _split_out(new_leaves, st_fill)
+            # pack the small updated params back into their flat
+            arg_flat_out = None
+            if packed_args:
+                newp = dict(zip(update_names, new_params))
+                new_params = [None if nm in packed_args else w
+                              for nm, w in zip(update_names, new_params)]
+                segs = []
+                for nm in packs.grad_names:
+                    w = newp.get(nm)
+                    if w is None:  # packed but not updated: carry over
+                        w = full[arg_index[nm]]
+                    segs.append(w.astype(jnp.float32).ravel())
+                arg_flat_out = jnp.concatenate(segs)
+            grad_map, grad_flat, aux_big, aux_flat_out = _repack(
+                packs, aux_upd, grad_map)
+            # hand the next step its hyperparams without a host round
+            # trip: t advances by one for every updated param each step,
+            # lr/wd only move when a scheduler fires (host re-uploads
+            # then) — so the common-case next hyper is computable here
+            next_hyper = hyper.at[2].add(np.float32(1))
+            return _StepOut(outs, aux_big, aux_flat_out, grad_map, grad_flat,
+                            new_params, arg_flat_out, new_leaves,
+                            st_flat_out, next_hyper, new_guard,
+                            _next_step(rng))
+
+        return _step
+
+    def _build_train_plan(self, key, apply_fn, state_handles):
+        """The fused train program for ``key``: one donating jit over the
+        step body (a K-step window of it where ``key.n_steps > 1``), wrapped
+        like the executor's other programs in an ``AOTProgram``."""
+        import jax
+
+        arg_index = self.graph._arg_index
+        upd_idx = [arg_index[n] for n in key.update_names]
+        upd_set = set(upd_idx)
+        other_idx = [i for i in range(len(self.arg_names))
+                     if i not in upd_set]
+        # the small optimizer-state leaves ride a pack of their own, whose
+        # layout lives in the plan (leaf structure is plan-specific)
+        st_pack = None
+        if self._small_state() is not None:
+            st_pack = self._make_pack(range(len(state_handles)),
+                                      [h._data for h in state_handles])
+        st_fill = tuple(
+            (j, *st_pack["offs"][j]) for j in st_pack["names"]
+        ) if st_pack else ()
+        step = self._train_step_fn(key, apply_fn, upd_idx, other_idx,
+                                   st_fill)
+        if key.n_steps > 1:
+            traced = _window_of(
+                step, key.n_steps,
+                tuple(other_idx.index(arg_index[nm])
+                      for nm in key.stack_names),
+                key.publish)
+        elif key.publish:
+            traced = step
+        else:
+            def step_fn(*args):
+                return _unpublished(step(*args))
+
+            traced = step_fn
+        program = _aot.AOTProgram(
+            jax.jit(traced, donate_argnums=_FUSED_DONATE,
+                    compiler_options=_compiler_options()),
+            key_digest=self._aot_digest(
+                "fused",
+                key._replace(state_td=repr(key.state_td), mesh=None),
+                key.mesh),
+            compile_counter="executor.fused_plan_compile",
+            compile_span="executor.jit_build",
+            donates=True, on_compile=_record_fused_hlo,
+        )
+        return _TrainPlan(key, program, upd_idx, other_idx, st_pack)
+
+    def _stage_train_args(self, plan, state_handles, lrs, wds, ts,
+                          stack_vals):
+        """``(call_args, hyper_host)``: the fused program's arguments from
+        the scheduled backward's snapshot, the optimizer-state handles and
+        the host's hyperparameters, and the latter as the host holds them."""
+        import jax
+
+        args_in = self._bwd_args
+        st_pack, st_flat = plan.st_pack, None
+        if st_pack is not None:
+            st_flat = self._pack_gather(st_pack, state_handles)
+            packed_j = set(st_pack["names"])
+            state_leaves = [None if j in packed_j else h._data
+                            for j, h in enumerate(state_handles)]
+        else:
+            state_leaves = [h._data for h in state_handles]
+        # Per-step hyperparams stay device-resident: a fresh numpy argument
+        # per execute costs a blocking host->device transfer and stalls the
+        # pipeline. The program returns next step's hyper (t+1) donated in
+        # place; the host keeps a numpy mirror and re-uploads only when the
+        # wanted values diverge (lr schedule fired, optimizer/param-set
+        # changed, first step).
+        hyper_host = np.stack([
+            np.asarray(lrs, np.float32),
+            np.asarray(wds, np.float32),
+            np.asarray(ts, np.float32),
+        ])
+        cache = getattr(self, "_hyper_dev_cache", None)
+        if (
+            cache is not None
+            and cache[0] is not None
+            and cache[1].shape == hyper_host.shape
+            and np.array_equal(cache[1], hyper_host)
+        ):
+            hyper = cache[0]
+        else:
+            hyper = jax.device_put(hyper_host)
+        self._hyper_dev_cache = None  # donated below; never reuse on failure
+        # guard counters live on device across steps (donated in, new value
+        # out); a fresh zeros buffer only on the first guarded step or after
+        # a rollback reset. The same (dead) buffer rides along un-guarded
+        # programs so the calling convention stays uniform.
+        guard_in = self._guard_dev
+        if guard_in is None:
+            guard_in = self._guard_zeros()
+        call_args = (
+            [args_in[i] for i in plan.upd_idx],
+            getattr(self, "_bwd_args_flat", None),
+            [args_in[i] for i in plan.other_idx], self._bwd_aux,
+            getattr(self, "_bwd_aux_flat", None), self._bwd_rng,
+            self._bwd_heads, self._bwd_prev, state_leaves, st_flat, hyper,
+            guard_in,
+        )
+        if plan.key.n_steps > 1:
+            call_args += (stack_vals,)
+        return call_args, hyper_host
+
+    def _install_train_update(self, plan, out, upd_vals, state_handles):
+        """Adopt the updated parameters and optimizer state a fused program
+        returned, in place."""
+        # the snapshots now reference donated buffers — drop them
+        self._args_in = None
+        self._aux_in = None
+        self._bwd_args = None
+        self._bwd_aux = None
+        self._bwd_args_flat = None
+        self._bwd_aux_flat = None
+        for nm, w, old in zip(plan.key.update_names, out.params, upd_vals):
+            if w is None:
+                continue  # packed: carried by out.arg_flat below
+            handle = self.arg_dict[nm]
+            # last-write-wins: a user write between forward() and update()
+            # (set_params / copy_params_from) keeps their value, matching
+            # the non-fused path's snapshot guard
+            if handle._d is old:
+                handle._data = w
+        if out.arg_flat is not None:
+            self._pack_install(self._small_state()["arg"], self.arg_dict,
+                               out.arg_flat)
+        for handle, leaf in zip(state_handles, out.states):
+            if leaf is not None:  # packed: carried by out.st_flat below
+                handle._data = leaf
+        if out.st_flat is not None:
+            self._pack_install(plan.st_pack, state_handles, out.st_flat)
 
     def fused_train_update(self, update_names, apply_fn, states, lrs, wds, ts,
                            cache_token, n_steps=1, data_stacks=None,
@@ -1716,48 +2064,38 @@ class Executor:
         update_names : list of arg names to update (⊆ wrt names).
         apply_fn : (i, weight, grad, state, lr, wd, t, rng) -> (w', state'),
             traceable; ``i`` is the position in update_names (static).
-        states : list of state pytrees (jax-array leaves) aligned with
-            update_names; donated.
+        states : ``(treedef, handles)`` — the optimizer states aligned with
+            update_names, flattened: their PyTreeDef and the NDArray leaf
+            handles. The executor reads the leaves itself (small ones stay
+            packed across steps, see ``_small_state``) and they are donated.
         lrs, wds, ts : per-param host scalars, passed traced (no recompile
             when an lr schedule changes them).
         cache_token : hashable identity of the optimizer config; part of the
-            jit cache key.
+            plan key.
 
-        Returns the list of new state pytrees — unless ``states`` is a
-        pre-flattened ``(leaves, treedef)`` pair, in which case the new flat
-        leaves are returned as-is (the hot-loop interface: the caller keeps
-        the flat structure cached and skips per-step pytree work). Outputs,
-        aux states, gradient arrays and parameter arrays are updated in
-        place. Requires a scheduled backward(); raises MXNetError otherwise.
+        Outputs, aux states, gradient arrays, parameter arrays and the
+        state handles are updated in place. Requires a scheduled
+        backward(); raises MXNetError otherwise. A trace or
+        compile failure raises with nothing donated; a failure once the
+        program was launched raises ``aot.DonatedCallError`` and the
+        executor's parameters are invalid.
 
         ``n_steps > 1`` runs that many consecutive train steps inside the
-        SAME program via ``lax.fori_loop`` (a training *window*): parameters,
-        optimizer state, aux statistics, rng counter and the hyperparameter
-        tape all advance on-device between iterations, and only the last
-        iteration's outputs/gradients are published. Every execute costs
-        host dispatch time (a property of the runtime to be measured, not a
-        constant), and amortizing K steps per execute divides it by K;
-        hyperparameters are frozen for the window (lr schedulers take
-        effect at window granularity). ``data_stacks``
-        optionally maps input arg names to ``(n_steps,) + shape`` arrays;
-        iteration ``i`` then trains on slice ``i`` (real epoch windows). The
-        window requires plain ``write`` gradients (no ``add`` accumulation
-        carry-in) and no explicit head gradients.
+        SAME program (a training *window*, ``_window_of``): only the last
+        iteration's outputs/gradients are published, and hyperparameters
+        are frozen for the window (lr schedulers take effect at window
+        granularity). ``data_stacks`` optionally maps input arg names to
+        ``(n_steps,) + shape`` arrays; iteration ``i`` then trains on slice
+        ``i`` (real epoch windows). The window requires plain ``write``
+        gradients and no explicit head gradients.
 
-        ``publish_grads=False`` drops the boundary gradient
-        publication from the program's return contract: the final unrolled
-        step no longer materialises the f32 ``grad_map``/``grad_flat``
-        tensors (XLA dead-codes the casts and the concatenation — for a
-        ResNet-scale graph that is a full parameter-sized f32 write per
-        window spent on values nobody reads in a pipelined fit loop).
-        Outputs and aux states are still published; reading ``grad_dict``
-        after a no-publish window raises MXNetError until the next
+        ``publish_grads=False`` drops the gradients from what the program
+        returns (XLA dead-codes their f32 casts and the concatenation);
+        reading ``grad_dict`` then raises MXNetError until the next
         publishing step runs. ``None`` (what ``Module.update()`` passes)
         publishes unless one set of gradients is over an eighth of the
         device's memory (``_grads_crowd_device``).
         """
-        import jax
-
         if not getattr(self, "_bwd_scheduled", False):
             raise MXNetError(
                 "fused_train_update requires a pending backward(); gradients "
@@ -1769,558 +2107,59 @@ class Executor:
                 "(multi-device graph cannot be one donated program); use the "
                 "imperative update path"
             )
-        head_grads = self._bwd_heads
-        with_hg = head_grads is not None
+        from .parallel.mesh import current_mesh, with_mesh
+
         n_steps = int(n_steps)
-        stack_names = ()
-        stack_vals = ()
-        if data_stacks and n_steps <= 1:
-            raise MXNetError(
-                "data_stacks requires a window (n_steps>1); a single step "
-                "trains on the bound inputs"
-            )
-        if n_steps > 1:
-            if with_hg:
-                raise MXNetError(
-                    "a training window (n_steps>1) drives loss heads only; "
-                    "explicit head gradients change per step — run "
-                    "single-step updates instead"
-                )
-            if self._bwd_prev:  # non-empty ⇔ grad_req='add' accumulation
-                raise MXNetError(
-                    "a training window requires grad_req='write' (an 'add' "
-                    "accumulation carried across window iterations would "
-                    "double-count); use single-step updates"
-                )
-            if data_stacks:
-                stack_names = tuple(sorted(data_stacks))
-                arr_ix = self.graph._arg_index
-                for nm in stack_names:
-                    if nm not in arr_ix:
-                        raise MXNetError(
-                            f"data_stacks name '{nm}' is not a bound input"
-                        )
-                    v = data_stacks[nm]
-                    v = v._data if isinstance(v, NDArray) else v
-                    tgt = self.arg_dict[nm]
-                    want = (n_steps,) + tuple(tgt.shape)
-                    if tuple(v.shape) != want:
-                        raise MXNetError(
-                            f"data_stacks['{nm}'] shape {tuple(v.shape)} != "
-                            f"(n_steps,)+bound shape {want}"
-                        )
-                    # the same dtype-cast + sharding placement _bind_inputs
-                    # applies to serially-fed batches, extended by the
-                    # window dim (replicated: every device sees all steps)
-                    v = v.astype(np_dtype(tgt.dtype))
-                    sh = self._in_shardings.get(nm)
-                    if sh is not None:
-                        from jax.sharding import (NamedSharding,
-                                                  PartitionSpec)
-
-                        if isinstance(sh, NamedSharding):
-                            sh = NamedSharding(
-                                sh.mesh, PartitionSpec(None, *sh.spec)
-                            )
-                        v = jax.device_put(v, sh)
-                    stack_vals += (v,)
-
-        flat_in = (
-            isinstance(states, tuple) and len(states) in (2, 3)
-            and (isinstance(states[0], list)
-                 or (len(states) == 3 and states[0] is None))
-            and isinstance(states[1], jax.tree_util.PyTreeDef)
-        )
-        from .parallel.mesh import current_mesh
-
-        state_handles = None
-        if flat_in:
-            state_leaves, state_td = states[0], states[1]
-            if len(states) == 3:
-                # hot-loop protocol extension: the caller hands the NDArray
-                # leaf handles so small optimizer-state leaves can stay
-                # packed across steps (see _small_state)
-                state_handles = states[2]
-        else:
-            state_leaves, state_td = jax.tree_util.tree_flatten(list(states))
-        # the ambient mesh can be baked into the trace (see _get_jit)
-        # the mesh snapshotted when backward() was scheduled governs the
-        # trace (see _materialize_forward); fall back to the ambient one
-        # for direct callers
-        sched_mesh = getattr(self, "_bwd_mesh", current_mesh())
-        small = self._small_state()
-        arg_pack = small["arg"] if small else None
-        aux_pack = small["aux"] if small else None
-        # non-finite sentinel (MXNET_NONFINITE_GUARD): when on, the program
-        # all-reduces isfinite over every gradient and lax-selects the OLD
-        # params/opt-state/aux on a non-finite step — the skip happens
-        # entirely on device; the [total, consecutive] skip counters ride a
-        # tiny donated int32 buffer read back only at sync points (epoch
-        # boundaries), so the guard adds zero per-batch host syncs
-        guard_on = self._nonfinite_guard_on()
-        # the caller's word where it gave one; update() gives none (its
-        # gradients may or may not be read), and then they are published
-        # unless they would crowd the device (_grads_crowd_device)
-        publish = (not self._grads_crowd_device() if publish_grads is None
-                   else bool(publish_grads))
-        plan_key = (tuple(update_names), cache_token, with_hg, state_td,
-                    state_handles is not None, sched_mesh, n_steps,
-                    stack_names, guard_on, publish)
-        plan = self._fused_plan.get(plan_key)
+        stack_names, stack_vals = self._window_stacks(n_steps, data_stacks)
+        state_td, state_handles = states
+        key = _TrainKey(
+            tuple(update_names), cache_token,
+            self._bwd_heads is not None, state_td,
+            # the mesh snapshotted when backward() was scheduled governs
+            # the trace (see _materialize_forward)
+            getattr(self, "_bwd_mesh", current_mesh()),
+            n_steps, stack_names, self._nonfinite_guard_on(),
+            # the caller's word where it gave one; update() gives none, and
+            # then gradients are published unless they crowd the device
+            (not self._grads_crowd_device() if publish_grads is None
+             else bool(publish_grads)))
+        plan = self._fused_plan.get(key)
         if plan is not None:
             _tm.counter("executor.fused_plan_hit").inc()
         else:
-            _tm.counter("executor.fused_plan_compile").inc()
-        if plan is None:
-            if state_handles is not None and state_leaves is None:
-                state_leaves = [h._data for h in state_handles]
-            arg_index = self.graph._arg_index
-            upd_idx = [arg_index[n] for n in update_names]
-            upd_set = set(upd_idx)
-            other_idx = [
-                i for i in range(len(self.arg_names)) if i not in upd_set
-            ]
-            core = self._make_grad_core()
-            n_args = len(self.arg_names)
-            arg_fill = self._pack_fill(self.arg_names, arg_pack)
-            aux_fill = self._pack_fill(self.aux_names, aux_pack)
-            packed_args = set(arg_pack["names"]) if arg_pack else ()
-            grad_names = tuple(arg_pack["names"]) if arg_pack else ()
-            # optimizer-state leaf packing: its layout lives in the plan
-            # (leaf structure is plan-specific); only available when the
-            # caller hands the leaf handles (the module hot loop)
-            st_pack = None
-            if state_handles is not None and small is not None:
-                sel = [j for j, v in enumerate(state_leaves)
-                       if self._pack_eligible(v)]
-                if len(sel) >= 8:
-                    offs = {}
-                    off = 0
-                    for j in sel:
-                        v = state_leaves[j]
-                        offs[j] = (off, int(v.size), tuple(v.shape))
-                        off += int(v.size)
-                    st_pack = {"names": sel, "offs": offs, "total": off,
-                               "flat": None, "cells": {}}
-            st_fill = tuple(
-                (j, *st_pack["offs"][j]) for j in st_pack["names"]
-            ) if st_pack else ()
-
-            def _step(upd_vals, arg_flat, other_vals, aux_vals, aux_flat,
-                      rng, heads, prev_grads, st_leaves, st_flat, hyper,
-                      guard):
-                import jax.numpy as jnp
-
-                full = [None] * n_args
-                for i, v in zip(upd_idx, upd_vals):
-                    full[i] = v
-                for i, v in zip(other_idx, other_vals):
-                    full[i] = v
-                full = _fill_packed(full, arg_flat, arg_fill)
-                full_aux = _fill_packed(aux_vals, aux_flat, aux_fill)
-                st_full = _fill_packed(st_leaves, st_flat, st_fill)
-                outs, aux_upd, grad_map = core(
-                    full, full_aux, rng, heads, prev_grads
-                )
-                key = _fold_rng(rng)
-                lr_v, wd_v, t_v = hyper[0], hyper[1], hyper[2]
-                sts = jax.tree_util.tree_unflatten(state_td, st_full)
-                new_params, new_states = [], []
-                for i, nm in enumerate(update_names):
-                    prng = jax.random.fold_in(key, 0x5EED + i)
-                    w, s = apply_fn(
-                        i, full[upd_idx[i]], grad_map[nm], sts[i],
-                        lr_v[i], wd_v[i], t_v[i], prng,
-                    )
-                    new_params.append(w)
-                    new_states.append(s)
-                new_guard = guard
-                if guard_on:
-                    # one scalar reduction per gradient, fused into the
-                    # backward epilogue: any NaN/Inf element propagates to
-                    # the sum (Inf-Inf=NaN included), so isfinite of the
-                    # summed sums detects every non-finite gradient without
-                    # an elementwise isfinite+all pass per tensor. (A
-                    # finite sum overflowing f32 would skip a good batch —
-                    # harmless and astronomically rare.)
-                    probe = jnp.float32(0)
-                    for nm in update_names:
-                        probe = probe + jnp.sum(
-                            grad_map[nm].astype(jnp.float32))
-                    finite = jnp.isfinite(probe)
-                    # a non-finite step keeps the OLD params, optimizer
-                    # state AND aux (BN running stats already absorbed the
-                    # poisoned batch in forward — roll them back too); the
-                    # rng/step/t counters still advance, keeping the host's
-                    # schedule mirrors coherent without a round trip
-                    new_params = [
-                        jnp.where(finite, w, full[upd_idx[i]])
-                        for i, w in enumerate(new_params)
-                    ]
-                    new_states = [
-                        jax.tree_util.tree_map(
-                            lambda nw, ol: jnp.where(finite, nw, ol), ns, os_
-                        )
-                        for ns, os_ in zip(new_states, sts)
-                    ]
-                    aux_upd = [
-                        jnp.where(finite, a, o)
-                        for a, o in zip(aux_upd, full_aux)
-                    ]
-                    miss = jnp.where(finite, 0, 1).astype(guard.dtype)
-                    new_guard = jnp.stack([
-                        guard[0] + miss,
-                        (guard[1] + miss) * miss,  # consecutive: reset on ok
-                    ])
-                new_leaves = jax.tree_util.tree_flatten(new_states)[0]
-                new_leaves, st_flat_out = _split_out(new_leaves, st_fill)
-                # pack the small updated params / grads back into flats
-                arg_flat_out = None
-                if packed_args:
-                    newp = dict(zip(update_names, new_params))
-                    new_params = [None if nm in packed_args else w
-                                  for nm, w in zip(update_names, new_params)]
-                    segs = []
-                    for nm in grad_names:
-                        w = newp.get(nm)
-                        if w is None:  # packed but not updated: carry over
-                            w = full[arg_index[nm]]
-                        segs.append(w.astype(jnp.float32).ravel())
-                    arg_flat_out = jnp.concatenate(segs)
-                grad_flat = None
-                if grad_names:
-                    grad_map = dict(grad_map)
-                    grad_flat = jnp.concatenate([
-                        grad_map.pop(nm).astype(jnp.float32).ravel()
-                        for nm in grad_names
-                    ])
-                aux_big, aux_flat_out = _split_out(aux_upd, aux_fill)
-                # hand the next step its hyperparams without a host round
-                # trip: t advances by one for every updated param each step,
-                # lr/wd only move when a scheduler fires (host re-uploads
-                # then) — so the common-case next hyper is computable here
-                next_hyper = hyper.at[2].add(np.float32(1))
-                return (outs, aux_big, aux_flat_out, grad_map, grad_flat,
-                        new_params, arg_flat_out, new_leaves, st_flat_out,
-                        next_hyper, new_guard, _next_step(rng))
-
-            if n_steps > 1:
-                # training window: fori_loop n_steps-1 STATE-ONLY
-                # iterations (params/opt-state/aux/rng/hyper thread through
-                # the carry; per-iteration outputs and f32 gradient
-                # publication are dropped so XLA dead-codes them), then one
-                # final step unrolled OUTSIDE the loop that returns the
-                # full single-step output contract.
-                from jax import lax as _lax
-                import jax.numpy as jnp
-
-                stack_pos = tuple(
-                    other_idx.index(arg_index[nm]) for nm in stack_names
-                )
-
-                def _step_k(upd_vals, arg_flat, other_vals, aux_vals,
-                            aux_flat, rng, heads, prev_grads, st_leaves,
-                            st_flat, hyper, guard, stacks):
-                    def sub_data(i, ov):
-                        ov = list(ov)
-                        for p, s in zip(stack_pos, stacks):
-                            ov[p] = _lax.dynamic_index_in_dim(
-                                s, i, 0, keepdims=False
-                            )
-                        return ov
-
-                    # K-1 state-only iterations: dropping the per-iteration
-                    # outputs/gradients lets XLA dead-code the f32 gradient
-                    # materialization the single-step contract returns (only
-                    # the LAST step publishes grads/outputs) — the loop body
-                    # is leaner than the standalone step program
-                    def body(i, carry):
-                        (upd_c, argf_c, aux_c, auxf_c, rng_c, st_c, stf_c,
-                         hyper_c, guard_c) = carry
-                        (_outs, aux_big, aux_flat_out, _gm, _gf,
-                         new_params, arg_flat_out, new_leaves, st_flat_out,
-                         next_hyper, new_guard, next_step) = _step(
-                            upd_c, argf_c, sub_data(i, other_vals), aux_c,
-                            auxf_c, rng_c, heads, prev_grads, st_c, stf_c,
-                            hyper_c, guard_c,
-                        )
-                        return (new_params, arg_flat_out, aux_big,
-                                aux_flat_out, (rng_c[0], next_step),
-                                new_leaves, st_flat_out, next_hyper,
-                                new_guard)
-
-                    init = (upd_vals, arg_flat, aux_vals, aux_flat, rng,
-                            st_leaves, st_flat, hyper, guard)
-                    (upd_f, argf_f, aux_f, auxf_f, rng_f, st_f, stf_f,
-                     hyper_f, guard_f) = _lax.fori_loop(
-                        0, n_steps - 1, body, init)
-                    # final step, unrolled: full output contract
-                    final = _step(
-                        upd_f, argf_f,
-                        sub_data(jnp.asarray(n_steps - 1, jnp.int32),
-                                 other_vals),
-                        aux_f, auxf_f, rng_f, heads, prev_grads, st_f,
-                        stf_f, hyper_f, guard_f,
-                    )
-                    if publish:
-                        return final
-                    # lazy boundary publication: dropping grad_map/grad_flat
-                    # from the return contract lets XLA dead-code the final
-                    # step's f32 gradient casts + concatenation — the whole
-                    # per-window publish cost a pipelined fit never reads
-                    (outs_f, aux_big_f, aux_flat_f, _gm, _gf, *rest) = final
-                    return (outs_f, aux_big_f, aux_flat_f, *rest)
-
-                from . import env as _env
-
-                jit_kw = {}
-                plan_auto = False
-                # single-device only: an installed mesh (sched_mesh) OR
-                # mesh-derived input shardings (the MXNET_MESH env path
-                # binds NamedShardings with current_mesh() still None)
-                # must not be forced onto a SingleDeviceSharding layout
-                if (sched_mesh is None and not self._in_shardings
-                        and is_tpu(self._ctx)
-                        and _env.get("MXNET_WINDOW_AUTO_LAYOUT")):
-                    # compiler-chosen buffer layouts: inside the window
-                    # loop the default (major-to-minor) parameter layouts
-                    # force a relayout copy per weight per iteration
-                    # (wgrad epilogues prefer transposed layouts); AUTO
-                    # lets the carry live in the compiler's preference,
-                    # and the one-time boundary conversion amortizes over
-                    # the window (round-5 builder reading: -3% on a single
-                    # step, +2% on a window; not re-measured on this stack)
-                    from jax.experimental.layout import Format, Layout
-
-                    # pin the executor's device alongside AUTO layout:
-                    # aval-based lowering otherwise compiles for (and
-                    # silently migrates state to) the default device
-                    auto = Format(
-                        Layout.AUTO,
-                        jax.sharding.SingleDeviceSharding(
-                            self._ctx.jax_device()
-                        ),
-                    )
-                    jit_kw = {"in_shardings": auto, "out_shardings": auto}
-                    plan_auto = True
-                jit_fn = jax.jit(
-                    _step_k, donate_argnums=(0, 1, 3, 4, 8, 9, 10, 11),
-                    compiler_options=_compiler_options(self._ctx),
-                    **jit_kw,
-                )
-            else:
-                plan_auto = False
-                step_fn = _step
-                if not publish:
-                    def step_fn(*args):
-                        (outs, aux_big, aux_flat_out, _gm, _gf,
-                         *rest) = _step(*args)
-                        return (outs, aux_big, aux_flat_out, *rest)
-                jit_fn = jax.jit(
-                    step_fn, donate_argnums=(0, 1, 3, 4, 8, 9, 10, 11),
-                    compiler_options=_compiler_options(self._ctx),
-                )
-            plan = (
-                jit_fn,
-                upd_idx, other_idx, st_pack,
-                # [executable, flat input formats (auto-layout windows)]
-                [None, None],
-                plan_auto,
-            )
-            self._fused_plan[plan_key] = plan
-        fn, upd_idx, other_idx, st_pack, aot, auto_layout = plan
-
+            plan = self._build_train_plan(key, apply_fn, state_handles)
+            self._fused_plan[key] = plan
         with _tm.span("executor.stage_args"):
-            args_in = self._bwd_args
-            args_flat = getattr(self, "_bwd_args_flat", None)
-            aux_flat = getattr(self, "_bwd_aux_flat", None)
-            upd_vals = [args_in[i] for i in upd_idx]
-            other_vals = [args_in[i] for i in other_idx]
-            st_flat = None
-            if st_pack is not None:
-                handle_map = dict(enumerate(state_handles))
-                st_flat = self._pack_gather(st_pack, handle_map)
-                packed_j = set(st_pack["names"])
-                state_leaves = [None if j in packed_j else state_handles[j]._data
-                                for j in range(len(state_handles))]
-            elif state_handles is not None and state_leaves is None:
-                state_leaves = [h._data for h in state_handles]
-            # Per-step hyperparams stay device-resident: a fresh numpy argument
-            # per execute costs a blocking host->device transfer and stalls the
-            # pipeline. The program returns next step's
-            # hyper (t+1) donated in place; the host keeps a numpy mirror and
-            # re-uploads only when the wanted values diverge (lr schedule fired,
-            # optimizer/param-set changed, first step).
-            hyper_host = np.stack([
-                np.asarray(lrs, np.float32),
-                np.asarray(wds, np.float32),
-                np.asarray(ts, np.float32),
-            ])
-            cache = getattr(self, "_hyper_dev_cache", None)
-            if (
-                cache is not None
-                and cache[0] is not None
-                and cache[1].shape == hyper_host.shape
-                and np.array_equal(cache[1], hyper_host)
-            ):
-                hyper = cache[0]
-            else:
-                hyper = jax.device_put(hyper_host)
-            self._hyper_dev_cache = None  # donated below; never reuse on failure
-
-            # guard counters live on device across steps (donated in, new value
-            # out); a fresh zeros buffer only on the first guarded step or after
-            # a rollback reset. The same (dead) buffer rides along un-guarded
-            # programs so the calling convention stays uniform.
-            guard_in = getattr(self, "_guard_dev", None)
-            if guard_in is None:
-                guard_in = self._guard_zeros()
-
-            call_args = (
-                upd_vals, args_flat, other_vals, self._bwd_aux, aux_flat,
-                self._bwd_rng, head_grads, self._bwd_prev, state_leaves,
-                st_flat, hyper, guard_in,
-            )
-            if n_steps > 1:
-                call_args += (stack_vals,)
-        from .parallel.mesh import with_mesh
-
-        dispatched = False
+            call_args, hyper_host = self._stage_train_args(
+                plan, state_handles, lrs, wds, ts, stack_vals)
         try:
-            with with_mesh(sched_mesh):
-                pdigest = None
-                if aot[0] is None:
-                    # ahead-of-time compile once, then call the executable
-                    # directly: the jit re-dispatch machinery (cache lookup,
-                    # arg inference) costs real milliseconds per step at
-                    # this argument count. The persistent cache
-                    # (MXNET_AOT_CACHE) serves the executable across
-                    # processes — warm starts skip the XLA compile.
-                    pdigest = self._fused_aot_digest(plan_key, auto_layout)
-                    loaded = _aot.load(pdigest)
-                    if loaded is not None:
-                        if auto_layout:
-                            aot[1] = jax.tree_util.tree_leaves(
-                                loaded.input_formats
-                            )
-                        aot[0] = loaded
-                if aot[0] is None:
-                    if auto_layout:
-                        # AUTO rejects concrete arrays (their layouts are
-                        # already pinned): lower from avals, then convert
-                        # the first call's buffers to the chosen formats.
-                        # A refusal here is an error, not a quiet recompile
-                        # with default layouts: MXNET_WINDOW_AUTO_LAYOUT=0
-                        # is the only way off this path.
-                        lower_args = jax.tree_util.tree_map(
-                            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype),
-                            call_args,
-                        )
-                        with _tm.span("executor.trace_lower"):
-                            lowered = fn.lower(*lower_args)
-                        exe = _compile_uncached(lowered)
-                        aot[1] = jax.tree_util.tree_leaves(exe.input_formats)
-                    else:
-                        with _tm.span("executor.trace_lower"):
-                            lowered = fn.lower(*call_args)
-                        # jax's persistent cache answers inside compile():
-                        # a read is this layer's work too
-                        with _tm.span("executor.compile"):
-                            exe = lowered.compile()
-                    aot[0] = exe
-                    _record_fused_hlo(lowered, exe, call_args, aot[1])
-                    _aot.store(pdigest, exe)
-                if aot[1] is not None:
-                    # donated steady-state buffers already carry the
-                    # compiled formats (they are last window's outputs);
-                    # convert only leaves that do not (first window, fresh
-                    # data uploads, checkpoint restores)
-                    with _tm.span("executor.stage_args"):
-                        flat_a, td = jax.tree_util.tree_flatten(call_args)
-                        conv = []
-                        for v, f in zip(flat_a, aot[1]):
-                            if getattr(v, "format", None) != f:
-                                v = jax.device_put(v, f)
-                            conv.append(v)
-                        call_args = jax.tree_util.tree_unflatten(td, conv)
-                dispatched = True
-                # the call into the executable and nothing else
-                with _tm.span("executor.launch"):
-                    results = aot[0](*call_args)
-                if publish:
-                    (outs, aux_upd, aux_flat_out, grad_map, grad_flat,
-                     new_params, arg_flat_out, new_leaves, st_flat_out,
-                     next_hyper, new_guard, next_step) = results
-                else:
-                    (outs, aux_upd, aux_flat_out,
-                     new_params, arg_flat_out, new_leaves, st_flat_out,
-                     next_hyper, new_guard, next_step) = results
-                    grad_map, grad_flat = {}, None
-        except Exception:
-            # a failure AFTER dispatch leaves the donated pack flats
-            # consumed: invalidate so packed reads fail LOUDLY (the thunks
-            # raise) instead of serving deleted buffers — same terminal
-            # contract as the donated per-param weights below. A trace or
-            # compile failure donated nothing; the packs stay intact and
-            # the caller's rollback/retry path remains valid.
-            if dispatched and small is not None:
-                for p in (small["arg"], small["aux"]):
-                    if p is not None:
-                        p["flat"] = None
-                if st_pack is not None:
-                    st_pack["flat"] = None
-            if dispatched:
-                self._guard_dev = None  # donated; counters restart at zero
-            raise
-        self._guard_dev = new_guard
-        self._count_train_launch()
-        self._accept_next_step(
-            next_step,
-            getattr(self, "_bwd_rng_val", self._step) + (n_steps - 1),
-        )
+            with with_mesh(key.mesh):
+                out = plan.program(*call_args)
+        except _aot.DonatedCallError as e:
+            # the donated pack flats are consumed: invalidate them, so that
+            # packed reads fail LOUDLY (the thunks raise) instead of serving
+            # deleted buffers — the same terminal contract as the donated
+            # per-param weights. The guard's counters restart at zero.
+            small = self._small_state() or {}
+            for pack in (small.get("arg"), small.get("aux"), plan.st_pack):
+                if pack is not None:
+                    pack["flat"] = None
+            self._guard_dev = None
+            raise _aot.DonatedCallError(
+                "fused train step failed after buffer donation; executor "
+                "parameters were invalidated — re-initialize via "
+                "set_params()/load before continuing") from e.__cause__
+        self._guard_dev = out.guard
+        self._finish_backward(out.outs, out.aux, out.aux_flat, out.grads,
+                              out.grad_flat, out.step, n_steps)
         # the window consumed n_steps rng values; advance the host counter
         # past them (forward() already took +1) so the device mirror stays
         # warm and the next forward doesn't rewind into consumed streams
         self._step += n_steps - 1
         mirror = hyper_host.copy()
         mirror[2] += n_steps
-        self._hyper_dev_cache = (next_hyper, mirror)
-        self._bwd_scheduled = False  # only consumed on success
-        aux_snap = self._bwd_aux
-        # snapshots now reference donated buffers — drop them
-        self._args_in = None
-        self._aux_in = None
-        self._bwd_args = None
-        self._bwd_aux = None
-        self._bwd_args_flat = None
-        self._bwd_aux_flat = None
-        self._set_outputs(outs)
-        self._set_aux(aux_upd, snap=aux_snap, flat=aux_flat_out)
-        if publish:
-            for nm, g in grad_map.items():
-                self.grad_dict[nm]._data = g
-            self._install_grad_flat(grad_flat)
-        else:
-            self._mark_grads_unpublished()
-        for nm, w, old in zip(update_names, new_params, upd_vals):
-            if w is None:
-                continue  # packed: carried by arg_flat_out below
-            handle = self.arg_dict[nm]
-            # last-write-wins: a user write between forward() and update()
-            # (set_params / copy_params_from) keeps their value, matching
-            # the non-fused path's snapshot guard
-            if handle._d is old:
-                handle._data = w
-        if arg_flat_out is not None and arg_pack is not None:
-            self._pack_install(arg_pack, self.arg_dict, arg_flat_out)
-        if st_pack is not None and st_flat_out is not None:
-            self._pack_install(st_pack, dict(enumerate(state_handles)),
-                               st_flat_out)
-        self._pending = None
-        self._fresh = True
-        if flat_in:
-            return new_leaves
-        return jax.tree_util.tree_unflatten(state_td, new_leaves)
+        self._hyper_dev_cache = (out.hyper, mirror)
+        self._install_train_update(plan, out, call_args[0], state_handles)
 
     # ------------------------------------------------------------------
     def debug_str(self):

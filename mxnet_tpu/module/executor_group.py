@@ -402,47 +402,27 @@ class DataParallelExecutorGroup:
                 optimizer._update_count(i)
 
         try:
-            # handles protocol: the executor extracts leaf values itself so
-            # small state leaves can stay packed across steps (reading
-            # nd._data here would materialize their lazy slices every step)
-            new_leaves = exe.fused_train_update(
-                names, host["apply_fn"],
-                (None, host["state_td"], nd_leaves),
+            # the executor extracts leaf values itself so small state
+            # leaves can stay packed across steps (reading nd._data here
+            # would materialize their lazy slices every step)
+            exe.fused_train_update(
+                names, host["apply_fn"], (host["state_td"], nd_leaves),
                 lrs, wds, ts, cache_token=opt_token,
                 n_steps=n_steps, data_stacks=data_stacks,
                 publish_grads=publish_grads,
             )
-        except Exception as e:
+        except Exception:
             # roll back the update counts so a retried/fallback update sees
-            # the right t and lr schedule (valid for trace/compile failures,
-            # where donation never happened)
+            # the right t and lr schedule. The executor says which failure
+            # this was: a trace or compile failure donated nothing and can
+            # be retried; aot.DonatedCallError is terminal
             for i in keys:
                 optimizer._index_update_count[i] -= n_steps
             optimizer.num_update = max(
                 [optimizer.begin_num_update]
                 + list(optimizer._index_update_count.values())
             )
-            # a RUNTIME failure after dispatch has already consumed the
-            # donated weight/state buffers — no retry is possible then
-            small = exe._small_state()
-            dead = bool(
-                small and small["arg"] and small["arg"]["flat"] is None
-                and small["arg"]["cells"]
-            ) or any(
-                getattr(exe.arg_dict[n]._d, "is_deleted", lambda: False)()
-                for n in names
-                if exe.arg_dict[n]._d is not None
-            )
-            if dead:
-                raise MXNetError(
-                    "fused train step failed after buffer donation; executor "
-                    "parameters were invalidated — re-initialize via "
-                    "set_params()/load before continuing"
-                ) from e
             raise
-        for nd, leaf in zip(nd_leaves, new_leaves):
-            if leaf is not None:  # packed leaves stay lazy in the executor
-                nd._data = leaf
 
 
 def _optimizer_token(optimizer):

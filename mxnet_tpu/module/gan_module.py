@@ -313,7 +313,7 @@ class GANModule:
         jit_fn = jax.jit(
             step_fn, donate_argnums=(0, 1, 2, 3, 4, 5),
             static_argnames=(),
-            compiler_options=_compiler_options(g_exe._ctx),
+            compiler_options=_compiler_options(),
         )
         return {"fn": jit_fn, "g_host": g_host, "d_host": d_host,
                 "g_names": g_names, "d_names": d_names,

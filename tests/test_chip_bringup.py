@@ -4,9 +4,6 @@
   ``JAX_COMPILATION_CACHE_DIR`` is set, and otherwise at one fixed path
   derived from the package's own location (``<checkout>/.jax_cache``) — the
   same path in every process, so a second run finds what the first compiled.
-* A window compiled with compiler-chosen layouts stays OUT of that cache
-  (``executor._compile_uncached``): under jax 0.9.0 the outputs of such an
-  executable, once deserialized, misreport their layout (seen on the v5e).
 * An accelerator context never lands on the host: ``mx.tpu(0)`` raises on
   the CPU backend, and ``chip_smoke.py`` refuses to run without a TPU.
 
@@ -45,24 +42,6 @@ import mxnet_tpu
 print("CACHE_DIR=" + str(jax.config.jax_compilation_cache_dir))
 """
 
-_UNCACHED_THEN_CACHED = """
-import os
-import jax
-import jax.numpy as jnp
-from mxnet_tpu.executor import _compile_uncached
-
-d = jax.config.jax_compilation_cache_dir
-count = lambda: len(os.listdir(d)) if os.path.isdir(d) else 0
-lower = lambda: jax.jit(lambda x: x * 2 + 1).lower(
-    jax.ShapeDtypeStruct((8,), jnp.float32))
-_compile_uncached(lower())
-print("UNCACHED=%d" % count())
-print("THRESHOLD=%r" % jax.config.jax_persistent_cache_min_compile_time_secs)
-lower().compile()
-print("CACHED=%d" % count())
-"""
-
-
 def _run(code, **env_overrides):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
@@ -97,14 +76,6 @@ def test_compile_cache_goes_where_the_environment_says(tmp_path):
 def test_compile_cache_default_is_one_fixed_checkout_path():
     assert _run(_PRINT_DIR)["CACHE_DIR"] == _CHECKOUT_CACHE
     assert _run(_PRINT_DIR)["CACHE_DIR"] == _CHECKOUT_CACHE
-
-
-def test_auto_layout_compile_leaves_no_cache_entry(tmp_path):
-    got = _run(_UNCACHED_THEN_CACHED,
-               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-    assert got["UNCACHED"] == "0"
-    assert got["THRESHOLD"] == "0.0"  # restored after the compile
-    assert int(got["CACHED"]) >= 1  # the same program, compiled plainly
 
 
 def test_accelerator_context_raises_on_the_cpu_backend():

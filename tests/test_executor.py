@@ -203,14 +203,14 @@ def test_xla_flags_reach_compile_options_and_digests(monkeypatch):
     from mxnet_tpu.executor import _compiler_options, _parse_xla_flag
 
     monkeypatch.delenv("MXNET_XLA_FLAGS", raising=False)
-    assert _compiler_options(mx.cpu()) is None  # empty -> jax defaults
+    assert _compiler_options() is None  # empty -> jax defaults
     base_digest = aot.digest("probe")
 
     monkeypatch.setenv(
         "MXNET_XLA_FLAGS",
         "xla_cpu_enable_fast_math=true, xla_force_host_platform_device_count=2,"
         "xla_gpu_autotune_level=0.5,xla_dump_to=/tmp/x")
-    opts = _compiler_options(mx.cpu())
+    opts = _compiler_options()
     assert opts == {"xla_cpu_enable_fast_math": True,
                     "xla_force_host_platform_device_count": 2,
                     "xla_gpu_autotune_level": 0.5,

@@ -185,11 +185,11 @@ def test_failed_step_invalidation_semantics():
     def broken_apply(i, w, g, s, lr, wd, t, rng):
         raise RuntimeError("boom at trace time")
 
-    leaves, td = jax.tree_util.tree_flatten(
-        [mx.nd.zeros(exe.arg_dict[n].shape)._data
-         for n in [name]])
+    handles, td = jax.tree_util.tree_flatten(
+        [mx.nd.zeros(exe.arg_dict[n].shape) for n in [name]],
+        is_leaf=lambda x: isinstance(x, mx.nd.NDArray))
     with pytest.raises(Exception):
-        exe.fused_train_update([name], broken_apply, (leaves, td),
+        exe.fused_train_update([name], broken_apply, (td, handles),
                                [0.1], [0.0], [1], cache_token="broken")
     # nothing was donated: the pack survives, params stay readable
     assert small["arg"]["flat"] is not None
