@@ -1426,39 +1426,56 @@ class Executor:
         return self._grads_crowd
 
     def _transformer_layers(self):
-        """(MoE layers, rows through their grouped matmuls, attention
+        """(MoE layers, rows through their grouped matmuls, how many of
+        those matmuls of a train program run the Pallas kernels, attention
         layers) of the bound graph: rows are tokens x ``top_k``, from the
-        bound shapes. Shapes are inferred only where the graph has a
+        bound shapes; the kernels are asked of the rule the op follows
+        (``ops/defs_transformer.moe_kernel_matmuls``) with this executor's
+        platform. Shapes and types are inferred only where the graph has a
         ``MoE`` node."""
         if self._layer_counts is None:
             ops = [n for n in self.graph.topo if not n.is_variable]
             moe = [n for n in ops if n.op.name == "MoE"]
-            rows = 0
+            rows = kernels = 0
             if moe:
+                from .ops.defs_transformer import moe_kernel_matmuls
+
                 internals = self._symbol.get_internals()
                 _, shapes, _ = internals.infer_shape(
                     **{n: tuple(a.shape) for n, a in self.arg_dict.items()})
+                _, dtypes, _ = internals.infer_type(
+                    **{n: a.dtype for n, a in self.arg_dict.items()})
                 shape_of = dict(zip(internals.list_outputs(), shapes))
-                rows = sum(
-                    int(np.prod(shape_of[n.name + "_output"][:-1]))
-                    * n.params()["top_k"] for n in moe)
+                dtype_of = dict(zip(internals.list_outputs(), dtypes))
+                platform = self._ctx.jax_device().platform
+                for n in moe:
+                    out, p = n.name + "_output", n.params()
+                    routed = int(np.prod(shape_of[out][:-1])) * p["top_k"]
+                    rows += routed
+                    kernels += moe_kernel_matmuls(
+                        platform, dtype_of[out],
+                        self.arg_dict[n.inputs[2][0].name].dtype, routed,
+                        shape_of[out][-1], p["num_hidden"])
             self._layer_counts = (
-                len(moe), rows,
+                len(moe), rows, kernels,
                 sum(n.op.name == "RingAttention" for n in ops))
         return self._layer_counts
 
     def _count_train_launch(self):
         """One launch of a train program, counted by what it holds: the
         shared weights whose gradient it computes as one matmul, its
-        sparse-expert layers with the rows they route, its attention
+        sparse-expert layers with the rows they route and the expert
+        matmuls that run the grouped-matmul kernels, its attention
         layers."""
         weights = self._shared_fc_plan()[2]
         if weights:
             _tm.counter("executor.stacked_wgrad").inc(weights)
-        moe, rows, attention = self._transformer_layers()
+        moe, rows, kernels, attention = self._transformer_layers()
         if moe:
             _tm.counter("executor.moe_layers").inc(moe)
             _tm.counter("executor.moe_assignments").inc(rows)
+        if kernels:
+            _tm.counter("executor.moe_kernel_matmuls").inc(kernels)
         if attention:
             _tm.counter("executor.attention_layers").inc(attention)
 

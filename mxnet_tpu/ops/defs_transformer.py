@@ -4,8 +4,9 @@ sparse-expert layer ``MoE``. (Attention is ``RingAttention`` in
 
 No reference twin: MXNet 0.x has none of them. The equations are those of
 the public OLMoE model (Muennighoff et al. 2024, arXiv:2409.02060; HF
-``modeling_olmoe.py``). All three are plain jax lowered by XLA; the grouped
-matmuls of ``MoE`` are ``jax.lax.ragged_dot``.
+``modeling_olmoe.py``). All three are plain jax lowered by XLA, but for
+the grouped matmuls of ``MoE``: Pallas kernels (``grouped_matmul.py``) where
+its rule says they engage, ``jax.lax.ragged_dot`` otherwise.
 
 What is float32 whatever the trunk's dtype: the statistics of ``RMSNorm``,
 the angles and the rotation of ``RotaryEmbedding``, and in ``MoE`` the
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import parse_float, parse_int
+from . import grouped_matmul as _gmm
 from .defs_nn import _castp, _prec
 from .registry import Param, register
 
@@ -119,6 +121,48 @@ def _attach_router_losses(logits, routed_share, lb_coef, z_coef):
     return f(logits)
 
 
+def _expert_plans(platform, vmem_bytes, rows_dtype, w_dtype, m, shapes):
+    """{(K, N): tiles} of one layer's expert matmuls where
+    ``grouped_matmul.plan`` has tiles for every one of ``shapes``, else
+    None: a layer's nine matmuls run the kernels or none does."""
+    plans = {s: _gmm.plan(platform, vmem_bytes, rows_dtype, w_dtype, m, *s)
+             for s in shapes}
+    return None if None in plans.values() else plans
+
+
+def _expert_matmul(counts, rows_dtype, m, weights, vmem_bytes=None,
+                   interpret=False):
+    """``f(rows, w)`` for one layer: ``rows`` (M, K) sorted by expert times
+    ``w`` (E, K, N), one of ``weights``, as the parameter is stored,
+    ``counts`` rows an expert. Where a TPU is attached (``vmem_bytes``:
+    read from its kind unless given) and ``_expert_plans`` has tiles, ``f``
+    is the Pallas kernels in a program lowered for the TPU (they cast a
+    weight tile in VMEM) and ``ragged_dot`` in one lowered for anything
+    else; with no TPU or no plan there is only ``_castp`` +
+    ``ragged_dot``."""
+    plans = _expert_plans(
+        "tpu", vmem_bytes or _gmm.attached_vmem_bytes(), rows_dtype,
+        weights[0].dtype, m, [w.shape[1:] for w in weights])
+    if plans is None:
+        return lambda rows, w: jax.lax.ragged_dot(
+            rows, _castp(w, rows), counts, precision=_prec(rows.dtype))
+    groups = _gmm.groups(counts, m, plans[weights[0].shape[1:]])
+    return lambda rows, w: _gmm.grouped_matmul(
+        rows, w, groups, plans[w.shape[1:]], interpret)
+
+
+def moe_kernel_matmuls(platform, data_dtype, weight_dtype, rows, hidden,
+                       width):
+    """How many of one ``MoE`` layer's nine expert matmuls (forward, dgrad
+    and wgrad of gate, up and down) a train program lowered for
+    ``platform`` runs in the Pallas kernels: the rule ``_expert_matmul``
+    follows, asked from outside the trace (``Executor._count_train_launch``).
+    All nine or none."""
+    return 9 * (_expert_plans(
+        platform, _gmm.attached_vmem_bytes(), data_dtype, weight_dtype, rows,
+        [(hidden, width), (width, hidden)]) is not None)
+
+
 def _moe(ins, params, mode):
     """Sparse mixture of SiLU-gated experts, drop-free.
 
@@ -129,7 +173,7 @@ def _moe(ins, params, mode):
     experts of largest ``p = softmax(router_weight . t)`` and receives
     ``sum p_e * down_e(silu(gate_e t) * up_e t)``, the weights not
     renormalised. The N x top_k assignments are sorted by expert and each
-    expert multiplies exactly its own rows (``jax.lax.ragged_dot``): no
+    expert multiplies exactly its own rows (``_expert_matmul``): no
     capacity, no token dropped, none computed for an expert it was not
     routed to.
     """
@@ -152,13 +196,10 @@ def _moe(ins, params, mode):
 
     order = jnp.argsort(expert, stable=True)                  # by expert
     inverse = jnp.argsort(order)
-    w_gate, w_up, w_down = (_castp(w, x) for w in (w_gate, w_up, w_down))
-    prec = _prec(x.dtype)
     rows = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
-    gate = jax.lax.ragged_dot(rows, w_gate, counts, precision=prec)
-    up = jax.lax.ragged_dot(rows, w_up, counts, precision=prec)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, counts,
-                             precision=prec)
+    matmul = _expert_matmul(counts, x.dtype, n * k, (w_gate, w_up, w_down))
+    gate, up = matmul(rows, w_gate), matmul(rows, w_up)
+    out = matmul(jax.nn.silu(gate) * up, w_down)
     out = _permute_rows(out, inverse, order).reshape(n, k, -1)
     out = jnp.sum(out.astype(jnp.float32) * p[..., None], axis=1)
     return out.astype(x.dtype).reshape(shape)

@@ -1,0 +1,404 @@
+"""The grouped-matmul kernels of ``MoE`` (``mxnet_tpu/ops/grouped_matmul.py``)
+in Pallas's interpreter on the CPU, at small shapes: forward, dgrad (the
+weights read transposed) and wgrad against ``jax.lax.ragged_dot`` and
+``jax.vjp`` of it; the in-kernel cast; the rule that says where the kernels
+engage; ``MoE`` through the kernel path against the plain reference; and
+the kernels compiled for a described v5e at the OLMoE cell's widths (no
+chip: a compile that passes is not a chip run).
+"""
+
+import functools
+import importlib.util
+import os
+
+import numpy as np
+import pytest
+
+from mxnet_tpu.ops import defs_transformer as dt
+from mxnet_tpu.ops import grouped_matmul as gm
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+V5E_VMEM = 128 << 20
+
+# rows an expert; M = 512 in every pattern: two row tiles of 256 (four of
+# 128 for wgrad), each of 128-row chunks
+PATTERNS = {
+    "uniform": [128, 128, 128, 128],
+    "an_empty_expert": [200, 0, 184, 128],
+    "one_expert_takes_every_row": [0, 0, 512, 0],
+    "boundaries_inside_a_tile": [100, 156, 3, 253],
+    "last_group_short": [255, 129, 127, 1],
+}
+DTYPES = {"bf16_rows_f32_weights": "float32",
+          "bf16_rows_bf16_weights": "bfloat16"}
+K, N = 256, 128
+
+
+@functools.lru_cache(maxsize=None)
+def _kernels_and_ragged_dot(pattern, weight_dtype):
+    """{kind: (kernel's, ragged_dot's)} for one pattern and weight dtype;
+    panels of 128 so that the forward and dgrad kernels walk two weight
+    panels a group and the prefetch wraps from one panel to the next."""
+    import jax
+    import jax.numpy as jnp
+
+    counts = jnp.asarray(PATTERNS[pattern], jnp.int32)
+    m, e = int(counts.sum()), counts.shape[0]
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    rows = jax.random.normal(keys[0], (m, K)).astype(jnp.bfloat16)
+    w = (0.1 * jax.random.normal(keys[1], (e, K, N))).astype(weight_dtype)
+    g = jax.random.normal(keys[2], (m, N)).astype(jnp.bfloat16)
+    plan = gm.Plan(tm=256, tmw=128, tn=128, tk=128, tw=128,
+                   vmem_limit=32 << 20)
+
+    def kernel(r, w):
+        return gm.grouped_matmul(r, w, gm.groups(counts, m, plan), plan, True)
+
+    def ragged(r, w):
+        return jax.lax.ragged_dot(r, w.astype(jnp.bfloat16), counts)
+
+    out, vjp = jax.vjp(kernel, rows, w)
+    want, want_vjp = jax.vjp(ragged, rows, w)
+    got = (out,) + vjp(g)
+    want = (want,) + want_vjp(g)
+    return {kind: (np.asarray(a, np.float32), np.asarray(b, np.float32), a)
+            for kind, a, b in zip(("forward", "dgrad", "wgrad"), got, want)}
+
+
+@pytest.mark.parametrize("kind", ["forward", "dgrad", "wgrad"])
+@pytest.mark.parametrize("weights", sorted(DTYPES))
+@pytest.mark.parametrize("pattern", sorted(PATTERNS))
+def test_kernel_matches_ragged_dot(pattern, weights, kind):
+    """Both round float32 sums of the same bfloat16 products to bfloat16
+    once; they may order the sums differently, so an element is at most a
+    last place of a bfloat16 (2^-8 of its size) apart."""
+    got, want, raw = _kernels_and_ragged_dot(pattern, DTYPES[weights])[kind]
+    assert got.shape == want.shape
+    assert str(raw.dtype) == {"wgrad": DTYPES[weights]}.get(kind, "bfloat16")
+    assert np.isfinite(got).all()
+    scale = np.maximum(np.abs(want), np.abs(want).max() * 2.0 ** -7)
+    assert np.max(np.abs(got - want) / scale) <= 2.0 ** -7
+    if kind == "wgrad":     # an expert with no rows has a zero gradient
+        for e, c in enumerate(PATTERNS[pattern]):
+            assert c or not got[e].any()
+
+
+def test_in_kernel_cast_is_astype_bfloat16_bit_for_bit():
+    """Identity rows times one float32 tile: every output element is one
+    product 1 x bfloat16(w), so the output IS the kernel's cast of the
+    tile."""
+    import jax
+    import jax.numpy as jnp
+
+    w = jax.random.normal(jax.random.PRNGKey(0), (1, 128, 128)) * 3.0
+    w = w.at[0, 0, :4].set(jnp.asarray(
+        [1.00390625, 1.01171875, -1.00390625, 3.3895e38]))  # ties, near max
+    eye = jnp.eye(128, dtype=jnp.bfloat16)
+    plan = gm.Plan(128, 128, 128, 128, 128, 32 << 20)
+    one_group = gm.groups(jnp.asarray([128], jnp.int32), 128, plan)
+    out = gm.grouped_matmul(eye, w, one_group, plan, True)
+    want = w[0].astype(jnp.bfloat16)
+    assert out.dtype == want.dtype
+    assert np.array_equal(np.asarray(out).view(np.uint16),
+                          np.asarray(want).view(np.uint16))
+
+
+VISIT_CASES = {
+    "uniform_aligned": ([256, 256, 256, 256], 256),
+    "boundaries_inside_tiles": ([100, 156, 3, 253, 512], 128),
+    "empty_groups_first_last_and_between": ([0, 300, 0, 0, 212, 0], 128),
+    "one_group_has_every_row": ([0, 0, 1024, 0], 256),
+    "many_groups_in_one_tile": ([5, 7, 1, 3, 112, 128], 128),
+}
+
+
+@pytest.mark.parametrize("visit_empty", [False, True])
+@pytest.mark.parametrize("case", sorted(VISIT_CASES))
+def test_visit_lists_are_megabloxs(case, visit_empty):
+    """The dense-compare visit lists against the metadata of jax's own
+    megablox kernels, which they replace: same visits in the same order
+    (the row tile of an empty group's visit is free: nothing is read)."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+
+    counts, tm = VISIT_CASES[case]
+    m = sum(counts)
+    (offsets, group_ids, m_tile_ids), visits = make_group_metadata(
+        group_sizes=jnp.asarray(counts, jnp.int32), m=m, tm=tm,
+        start_group=jnp.int32(0), num_nonzero_groups=len(counts),
+        visit_empty_groups=visit_empty)
+    got = gm._visit_lists(jnp.asarray(counts, jnp.int32), m, tm, visit_empty)
+    v = int(visits)
+    assert int(got[3]) == v
+    assert np.array_equal(got[0], offsets)
+    assert np.array_equal(got[1][:v], group_ids[:v])
+    held = np.asarray(counts)[np.asarray(group_ids[:v])] > 0
+    assert np.array_equal(np.asarray(got[2][:v])[held],
+                          np.asarray(m_tile_ids[:v])[held])
+
+
+def test_kernels_come_back_from_the_cache_directory(tmp_path, monkeypatch):
+    """A second process (here: an emptied memo) reads the exported kernels
+    and traces none: the same program text, no call into the kernels'
+    Python."""
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(gm, "_kernel_cache_dir", lambda: str(tmp_path))
+    monkeypatch.setattr(gm, "_EXPORTED", {})
+    plan = gm.plan("tpu", V5E_VMEM, "bfloat16", "float32", 512, 256, 128)
+    counts = jnp.asarray([100, 156, 0, 256], jnp.int32)
+
+    def step(rows, w, g):
+        gr = gm.groups(counts, 512, plan)
+        out, vjp = jax.vjp(
+            lambda r, w: gm.grouped_matmul(r, w, gr, plan), rows, w)
+        return (out,) + vjp(g)
+
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((512, 256), jnp.bfloat16), ((4, 256, 128), jnp.float32),
+        ((512, 128), jnp.bfloat16))]
+    first = str(jax.make_jaxpr(step)(*args))
+    assert "call_exported" in first and len(list(tmp_path.iterdir())) == 3
+
+    def _gmm(*a, **k):
+        raise AssertionError("traced again")
+
+    def _tgmm(*a, **k):
+        raise AssertionError("traced again")
+
+    monkeypatch.setattr(gm, "_EXPORTED", {})
+    monkeypatch.setattr(gm, "_gmm", _gmm)
+    monkeypatch.setattr(gm, "_tgmm", _tgmm)
+    again = str(jax.make_jaxpr(step)(*args))
+    assert "call_exported" in again and "pallas_call" not in again
+
+
+def test_no_cache_directory_where_jaxs_cache_is_off():
+    """The suite runs with jax's persistent cache off (conftest): kernels
+    are traced in place and nothing is written."""
+    assert gm._kernel_cache_dir() is None
+
+
+# (platform, VMEM bytes, rows dtype, weight dtype, M, K, N) -> engages?
+RULE_CASES = {
+    "olmoe_gate_on_a_v5e": (("tpu", V5E_VMEM, "bfloat16", "float32",
+                             32768, 2048, 1024), True),
+    "olmoe_down_on_a_v5e": (("tpu", V5E_VMEM, "bfloat16", "float32",
+                             32768, 1024, 2048), True),
+    "moonlight_64_experts_top6_width_1408": (
+        ("tpu", V5E_VMEM, "bfloat16", "float32", 8192 * 6, 2048, 1408), True),
+    "granite_h_small_72_experts_top10_width_768": (
+        ("tpu", V5E_VMEM, "bfloat16", "float32", 4096 * 10, 4096, 768), True),
+    "bfloat16_weights": (("tpu", V5E_VMEM, "bfloat16", "bfloat16",
+                          1024, 256, 384), True),
+    "lowered_for_the_cpu": (("cpu", V5E_VMEM, "bfloat16", "float32",
+                             32768, 2048, 1024), False),
+    "no_tpu_attached": (("tpu", None, "bfloat16", "float32",
+                         32768, 2048, 1024), False),
+    "float32_trunk_keeps_ragged_dot": (("tpu", V5E_VMEM, "float32", "float32",
+                                        32768, 2048, 1024), False),
+    "float16_weights": (("tpu", V5E_VMEM, "bfloat16", "float16",
+                         32768, 2048, 1024), False),
+    "width_the_tiles_do_not_divide": (("tpu", V5E_VMEM, "bfloat16", "float32",
+                                       32768, 2048, 1000), False),
+    "depth_the_tiles_do_not_divide": (("tpu", V5E_VMEM, "bfloat16", "float32",
+                                       32768, 32, 128), False),
+    "rows_no_tile_divides": (("tpu", V5E_VMEM, "bfloat16", "float32",
+                              32768 + 64, 2048, 1024), False),
+    "rows_only_the_smallest_tile_divides": (
+        ("tpu", V5E_VMEM, "bfloat16", "float32", 128 * 7, 256, 256), True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE_CASES))
+def test_rule_says_where_the_kernels_engage(case):
+    args, engages = RULE_CASES[case]
+    plan = gm.plan(*args)
+    assert (plan is not None) == engages
+    if plan is None:
+        return
+    _, vmem, _, _, m, k, n = args
+    assert m % plan.tm == 0 and plan.tm in gm._ROW_TILES
+    assert m % plan.tmw == 0 and plan.tmw in gm._WGRAD_ROW_TILES
+    for panel, width in ((plan.tn, n), (plan.tk, k), (plan.tw, n)):
+        assert width % panel == 0 and panel % 128 == 0
+    assert plan.vmem_limit <= vmem * 3 // 4
+
+
+def test_rule_narrows_the_weight_panel_to_a_small_vmem():
+    """One expert's float32 matrix of the OLMoE cell is 8 MiB: whole in a
+    v5e's 128 MiB, in panels where a core has 16 MiB."""
+    args = ("bfloat16", "float32", 32768, 2048, 1024)
+    whole, small = gm.plan("tpu", V5E_VMEM, *args), gm.plan(
+        "tpu", 16 << 20, *args)
+    assert (whole.tn, whole.tk, whole.tw) == (1024, 2048, 1024)
+    assert small is not None and small.tn < 1024 and small.tw < 1024
+
+
+def test_counter_rule_counts_nine_or_none():
+    """What ``Executor._count_train_launch`` asks: on the CPU no kernel
+    (``attached_vmem_bytes`` is None here), and the op takes ragged_dot
+    without a ``platform_dependent`` around it."""
+    assert gm.attached_vmem_bytes() is None
+    assert dt.moe_kernel_matmuls("cpu", "bfloat16", "float32",
+                                 32768, 2048, 1024) == 0
+    assert dt.moe_kernel_matmuls("tpu", "bfloat16", "float32",
+                                 32768, 2048, 1024) == 0
+
+
+@pytest.mark.parametrize("chips,kind,vmem", [
+    (1, "TPU v5 lite", V5E_VMEM), (4, "TPU v5 lite", None),
+    (1, "TPU v9 not listed", None)])
+def test_attached_vmem_is_of_the_one_listed_chip(monkeypatch, chips, kind,
+                                                 vmem):
+    """Several chips (XLA cannot partition a Mosaic call) or a kind whose
+    VMEM is not listed: no figure, so no kernel."""
+    import jax
+    from types import SimpleNamespace
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [SimpleNamespace(device_kind=kind)] * chips)
+    assert gm.attached_vmem_bytes() == vmem
+
+
+def test_counter_rule_with_a_v5e_attached(monkeypatch):
+    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: V5E_VMEM)
+    nine = ("bfloat16", "float32", 32768, 2048, 1024)
+    assert dt.moe_kernel_matmuls("tpu", *nine) == 9
+    assert dt.moe_kernel_matmuls("cpu", *nine) == 0
+    assert dt.moe_kernel_matmuls("tpu", "float32", "float32",
+                                 32768, 2048, 1024) == 0
+    # a hidden size no tile divides: all nine take ragged_dot
+    assert dt.moe_kernel_matmuls("tpu", "bfloat16", "float32",
+                                 32768, 2000, 1024) == 0
+
+
+# --- MoE through the kernel path against the plain reference ----------------
+
+MOE_INPUTS = ["data", "router_weight", "gate_weight", "up_weight",
+              "down_weight"]
+
+
+@functools.lru_cache(maxsize=None)
+def _moe_three_ways():
+    """(kernel path, ragged_dot path, float32 reference), each the output
+    and the gradient of every input of one ``MoE`` layer: 64 bfloat16
+    tokens of 128 features, 4 experts of width 128, top-2, float32
+    masters. The kernel path is forced through the rule's inputs: a plan
+    for a v5e, run in the interpreter."""
+    import jax
+    import jax.numpy as jnp
+
+    spec = importlib.util.spec_from_file_location(
+        "olmoe_reference",
+        os.path.join(ROOT, "benchmark", "reference", "olmoe-1b-7b.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+
+    rs = np.random.RandomState(11)
+    tok = jnp.asarray(rs.randn(64, 128), jnp.bfloat16)
+    ws = [jnp.asarray(rs.randn(*s) * 0.1, jnp.float32)
+          for s in ((4, 128), (4, 128, 128), (4, 128, 128), (4, 128, 128))]
+    head = jnp.asarray(rs.randn(64, 128), jnp.float32)
+    params = dict(num_experts=4, num_hidden=128, top_k=2, lb_coef=0.01,
+                  z_coef=0.001)
+    def run(matmul):
+        def scalar(*ins):
+            out = dt._moe(list(ins), params, None)
+            return jnp.sum(out.astype(jnp.float32) * head), out
+
+        old, dt._expert_matmul = dt._expert_matmul, matmul
+        try:
+            grads, out = jax.grad(scalar, argnums=tuple(range(5)),
+                                  has_aux=True)(tok, *ws)
+        finally:
+            dt._expert_matmul = old
+        return [out] + list(grads)
+
+    def reference(*ins):
+        out, pen = ref.moe(*ins, 2, 0.01, 0.001)
+        return jnp.sum(out * head) + ins[0].shape[0] * pen, out
+
+    kernel = run(functools.partial(dt._expert_matmul, vmem_bytes=V5E_VMEM,
+                                   interpret=True))
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda *ins: dt._expert_matmul(
+            jnp.asarray([32] * 4, jnp.int32), tok.dtype, 128, ws[1:],
+            vmem_bytes=V5E_VMEM, interpret=True)(*ins))(
+                jnp.repeat(tok, 2, 0), ws[1]))
+    ragged = run(dt._expert_matmul)     # no TPU here: ragged_dot alone
+    with jax.default_matmul_precision("highest"):
+        grads, out = jax.grad(reference, argnums=tuple(range(5)),
+                              has_aux=True)(tok.astype(jnp.float32), *ws)
+    return [[np.asarray(a, np.float64) for a in side]
+            for side in (kernel, ragged, [out] + list(grads))]
+
+
+def _rel(a, b):
+    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
+
+
+@pytest.mark.parametrize("tensor", ["output"] + MOE_INPUTS)
+def test_moe_through_the_kernels_matches_the_reference(tensor):
+    """The kernel path is the ragged_dot path with other orders of float32
+    sums: a bfloat16 rounding apart at most, and no further from the
+    float32 reference than the ragged_dot path is (a bfloat16 trunk: the
+    tolerance is that path's own distance, with a half on top)."""
+    i = (["output"] + MOE_INPUTS).index(tensor)
+    kernel, ragged, want = (side[i] for side in _moe_three_ways())
+    assert kernel.shape == want.shape
+    assert _rel(kernel, ragged) < 2.0 ** -7
+    assert _rel(kernel, want) < 1.5 * _rel(ragged, want) + 1e-6
+    assert _rel(kernel, want) < 3e-2
+
+
+# --- compiled for a described v5e at the cell's widths ----------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("matmul", ["gate_and_up", "down"])
+def test_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip, matmul):
+    """Mosaic accepts forward, dgrad and wgrad at 32 768 rows, 64 experts,
+    2048 x 1024 (what interpret mode cannot show: tiling, VMEM)."""
+    import jax
+    import jax.numpy as jnp
+
+    m, e = 32768, 64
+    k, n = (2048, 1024) if matmul == "gate_and_up" else (1024, 2048)
+    plan = gm.plan("tpu", V5E_VMEM, jnp.bfloat16, jnp.float32, m, k, n)
+
+    def step(rows, w, counts, g):
+        gr = gm.groups(counts, m, plan)
+        out, vjp = jax.vjp(
+            lambda r, w: gm.grouped_matmul(r, w, gr, plan), rows, w)
+        return (out,) + vjp(g)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        arg((m, k), jnp.bfloat16), arg((e, k, n), jnp.float32),
+        arg((e,), jnp.int32), arg((m, n), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    for name in ("moe_gmm", "moe_gmm_dgrad", "moe_gmm_wgrad"):
+        assert name in text
+    # no bfloat16 copy of the weights: the largest temporary is the
+    # bfloat16 wgrad before its float32 convert
+    assert compiled.memory_analysis().temp_size_in_bytes <= e * k * n * 2 \
+        + (8 << 20)
