@@ -286,12 +286,18 @@ inline Symbol MakeLoss(const std::string& symbol_name, const Symbol& data, doubl
   return detail::CreateOp("MakeLoss", symbol_name, 1, keys__, ins__, params__);
 }
 
-inline Symbol MoE(const std::string& symbol_name, const Symbol& data, int64_t num_experts, int64_t num_hidden, int64_t top_k, const Symbol* router_weight = nullptr, const Symbol* gate_weight = nullptr, const Symbol* up_weight = nullptr, const Symbol* down_weight = nullptr, double lb_coef = 0.0, double z_coef = 0.0) {
+inline Symbol MoE(const std::string& symbol_name, const Symbol& data, int64_t num_experts, int64_t num_hidden, int64_t top_k, const Symbol* router_weight = nullptr, const Symbol* gate_weight = nullptr, const Symbol* up_weight = nullptr, const Symbol* down_weight = nullptr, bool expert_bias = false, int64_t expert_offset = 0, double lb_coef = 0.0, int64_t num_local_experts = 0, bool route_norm = false, double route_scale = 1.0, const std::string& score_func = "softmax", double z_coef = 0.0) {
   detail::ParamMap params__;
   params__.emplace_back("num_experts", detail::str(num_experts));
   params__.emplace_back("num_hidden", detail::str(num_hidden));
   params__.emplace_back("top_k", detail::str(top_k));
+  params__.emplace_back("expert_bias", detail::str(expert_bias));
+  params__.emplace_back("expert_offset", detail::str(expert_offset));
   params__.emplace_back("lb_coef", detail::str(lb_coef));
+  params__.emplace_back("num_local_experts", detail::str(num_local_experts));
+  params__.emplace_back("route_norm", detail::str(route_norm));
+  params__.emplace_back("route_scale", detail::str(route_scale));
+  params__.emplace_back("score_func", score_func);
   params__.emplace_back("z_coef", detail::str(z_coef));
   const char* keys__[] = {"data", "router_weight", "gate_weight", "up_weight", "down_weight"};
   const Symbol* ins__[] = {&data, router_weight, gate_weight, up_weight, down_weight};
@@ -434,12 +440,13 @@ inline Symbol Reshape(const std::string& symbol_name, const Symbol& data, const 
   return detail::CreateOp("Reshape", symbol_name, 1, keys__, ins__, params__);
 }
 
-inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query, const Symbol* key = nullptr, const Symbol* value = nullptr, const std::string& axis_name = "sp", const std::string& batch_axis = "", bool causal = false, double scale = -1.0) {
+inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query, const Symbol* key = nullptr, const Symbol* value = nullptr, const std::string& axis_name = "sp", const std::string& batch_axis = "", bool causal = false, double scale = -1.0, int64_t window = 0) {
   detail::ParamMap params__;
   params__.emplace_back("axis_name", axis_name);
   if (!batch_axis.empty()) params__.emplace_back("batch_axis", batch_axis);
   params__.emplace_back("causal", detail::str(causal));
   params__.emplace_back("scale", detail::str(scale));
+  params__.emplace_back("window", detail::str(window));
   const char* keys__[] = {"query", "key", "value"};
   const Symbol* ins__[] = {&query, key, value};
   return detail::CreateOp("RingAttention", symbol_name, 3, keys__, ins__, params__);

@@ -1426,58 +1426,93 @@ class Executor:
         return self._grads_crowd
 
     def _transformer_layers(self):
-        """(MoE layers, rows through their grouped matmuls, how many of
-        those matmuls of a train program run the Pallas kernels, attention
-        layers) of the bound graph: rows are tokens x ``top_k``, from the
-        bound shapes; the kernels are asked of the rule the op follows
+        """What the bound graph's transformer layers hold, as the counters
+        of ``_count_train_launch`` name it: ``moe_layers``;
+        ``moe_assignments``, tokens x ``top_k`` from the bound shapes;
+        ``moe_local_experts``, the experts the layers hold here;
+        ``moe_kernel_matmuls``, how many expert matmuls of a train program
+        run the Pallas kernels, asked of the rule the op follows
         (``ops/defs_transformer.moe_kernel_matmuls``) with this executor's
-        platform. Shapes and types are inferred only where the graph has a
-        ``MoE`` node."""
+        platform and the rows of one dispatch round;
+        ``attention_layers``; ``attention_window_layers``, those with a
+        ``window``; ``attention_scored_pairs``, the query-key pairs their
+        block plans score (``parallel/ring_attention.scored_pairs`` x
+        heads x batch). Shapes and types are inferred only where the graph
+        has such a node."""
         if self._layer_counts is None:
             ops = [n for n in self.graph.topo if not n.is_variable]
             moe = [n for n in ops if n.op.name == "MoE"]
-            rows = kernels = 0
-            if moe:
-                from .ops.defs_transformer import moe_kernel_matmuls
+            attention = [n for n in ops if n.op.name == "RingAttention"]
+            counts = dict.fromkeys((
+                "moe_layers", "moe_assignments", "moe_local_experts",
+                "moe_kernel_matmuls", "attention_layers",
+                "attention_window_layers", "attention_scored_pairs"), 0)
+            if moe or attention:
+                from .ops.defs_transformer import (held_round_rows,
+                                                   moe_kernel_matmuls)
+                from .parallel.ring_attention import (block_q_of,
+                                                      scored_pairs)
 
                 internals = self._symbol.get_internals()
                 _, shapes, _ = internals.infer_shape(
                     **{n: tuple(a.shape) for n, a in self.arg_dict.items()})
-                _, dtypes, _ = internals.infer_type(
-                    **{n: a.dtype for n, a in self.arg_dict.items()})
                 shape_of = dict(zip(internals.list_outputs(), shapes))
-                dtype_of = dict(zip(internals.list_outputs(), dtypes))
+                if moe:
+                    _, dtypes, _ = internals.infer_type(
+                        **{n: a.dtype for n, a in self.arg_dict.items()})
+                    dtype_of = dict(zip(internals.list_outputs(), dtypes))
                 platform = self._ctx.jax_device().platform
                 for n in moe:
                     out, p = n.name + "_output", n.params()
                     routed = int(np.prod(shape_of[out][:-1])) * p["top_k"]
-                    rows += routed
-                    kernels += moe_kernel_matmuls(
+                    held = p["num_local_experts"] or p["num_experts"]
+                    counts["moe_layers"] += 1
+                    counts["moe_assignments"] += routed
+                    counts["moe_local_experts"] += held
+                    counts["moe_kernel_matmuls"] += moe_kernel_matmuls(
                         platform, dtype_of[out],
-                        self.arg_dict[n.inputs[2][0].name].dtype, routed,
+                        self.arg_dict[n.inputs[2][0].name].dtype,
+                        held_round_rows(routed, held, p["num_experts"]),
                         shape_of[out][-1], p["num_hidden"])
-            self._layer_counts = (
-                len(moe), rows, kernels,
-                sum(n.op.name == "RingAttention" for n in ops))
+                for n in attention:
+                    p = n.params()
+                    batch, heads, seq_len, _ = shape_of[n.name + "_output"]
+                    counts["attention_layers"] += 1
+                    counts["attention_window_layers"] += bool(p["window"])
+                    counts["attention_scored_pairs"] += batch * heads \
+                        * scored_pairs(
+                            seq_len, p["causal"], p["window"],
+                            block_q_of(batch, heads, seq_len, p["window"]))
+            self._layer_counts = counts
         return self._layer_counts
 
     def _count_train_launch(self):
         """One launch of a train program, counted by what it holds: the
-        shared weights whose gradient it computes as one matmul, its
-        sparse-expert layers with the rows they route and the expert
-        matmuls that run the grouped-matmul kernels, its attention
-        layers."""
+        shared weights whose gradient it computes as one matmul, and its
+        sparse-expert and attention layers (``_transformer_layers``)."""
         weights = self._shared_fc_plan()[2]
         if weights:
             _tm.counter("executor.stacked_wgrad").inc(weights)
-        moe, rows, kernels, attention = self._transformer_layers()
-        if moe:
-            _tm.counter("executor.moe_layers").inc(moe)
-            _tm.counter("executor.moe_assignments").inc(rows)
-        if kernels:
-            _tm.counter("executor.moe_kernel_matmuls").inc(kernels)
-        if attention:
-            _tm.counter("executor.attention_layers").inc(attention)
+        held = self._transformer_layers()
+        # literal names (the telemetry catalog is read from the source), and
+        # a counter only where the graph holds such a layer
+        if held["moe_layers"]:
+            _tm.counter("executor.moe_layers").inc(held["moe_layers"])
+            _tm.counter("executor.moe_assignments").inc(
+                held["moe_assignments"])
+            _tm.counter("executor.moe_local_experts").inc(
+                held["moe_local_experts"])
+        if held["moe_kernel_matmuls"]:
+            _tm.counter("executor.moe_kernel_matmuls").inc(
+                held["moe_kernel_matmuls"])
+        if held["attention_layers"]:
+            _tm.counter("executor.attention_layers").inc(
+                held["attention_layers"])
+            _tm.counter("executor.attention_scored_pairs").inc(
+                held["attention_scored_pairs"])
+        if held["attention_window_layers"]:
+            _tm.counter("executor.attention_window_layers").inc(
+                held["attention_window_layers"])
 
     def _make_grad_core(self):
         """Shared fwd+bwd tracing core used by both the plain train_step

@@ -7,6 +7,43 @@ from .. import symbol as sym
 from .recipe import low_precision_io
 
 
+# --- pieces the sparse-expert decoders share (``afmoe.py`` too) -------------
+def linear(x, width, name):
+    """A bias-free projection of the last axis."""
+    return sym.FullyConnected(x, num_hidden=width, no_bias=True,
+                              flatten=False, name=name)
+
+
+def split_heads(x, heads, head_dim):
+    """(B, T, heads * D) -> (B, heads, T, D), the layout of RingAttention."""
+    x = sym.Reshape(x, shape=(0, 0, heads, head_dim))
+    return sym.transpose(x, axes=(0, 2, 1, 3))
+
+
+def merge_heads(a):
+    """(B, heads, T, D) -> (B, T, heads * D)."""
+    return sym.Reshape(sym.transpose(a, axes=(0, 2, 1, 3)), shape=(0, 0, -1))
+
+
+def embed_tokens(data, vocab_size, hidden_size, dtype):
+    x = sym.Embedding(data, input_dim=vocab_size, output_dim=hidden_size,
+                      name="embed")
+    return low_precision_io(x, dtype)
+
+
+def next_token_head(x, label, vocab_size, hidden_size, dtype, ignore_label):
+    """The untied head over the normed stream ``x`` and its float32
+    softmax: the rows' probabilities (B*T, vocab); rows whose label is
+    ``ignore_label`` train nothing."""
+    pred = sym.FullyConnected(sym.Reshape(x, shape=(-1, hidden_size)),
+                              num_hidden=vocab_size, no_bias=True,
+                              name="pred")
+    pred = low_precision_io(pred, dtype, out=True)
+    return sym.SoftmaxOutput(
+        pred, sym.Reshape(label, shape=(-1,)), use_ignore=True,
+        ignore_label=ignore_label, name="softmax")
+
+
 def olmoe_sym_gen(vocab_size=50304, hidden_size=2048, num_layers=16,
                   num_heads=16, num_experts=64, expert_width=1024, top_k=8,
                   rms_norm_eps=1e-5, rope_theta=10000.0, lb_coef=0.01,
@@ -24,21 +61,16 @@ def olmoe_sym_gen(vocab_size=50304, hidden_size=2048, num_layers=16,
         return sym.RMSNorm(x, eps=rms_norm_eps, name=name)
 
     def proj(x, name):
-        return sym.FullyConnected(x, num_hidden=hidden_size, no_bias=True,
-                                  flatten=False, name=name)
+        return linear(x, hidden_size, name)
 
     def heads(x, rotate):
-        # (B, T, H*D) -> (B, H, T, D), the layout of RingAttention
-        x = sym.Reshape(x, shape=(0, 0, num_heads, head_dim))
-        x = sym.transpose(x, axes=(0, 2, 1, 3))
+        x = split_heads(x, num_heads, head_dim)
         return sym.RotaryEmbedding(x, base=rope_theta) if rotate else x
 
     def sym_gen(seq_len):
         data = sym.Variable("data")
         label = sym.Variable("softmax_label")
-        x = sym.Embedding(data, input_dim=vocab_size, output_dim=hidden_size,
-                          name="embed")
-        x = low_precision_io(x, dtype)
+        x = embed_tokens(data, vocab_size, hidden_size, dtype)
         for i in range(num_layers):
             pre = f"l{i}_"
             u = norm(x, pre + "input_norm")
@@ -46,20 +78,13 @@ def olmoe_sym_gen(vocab_size=50304, hidden_size=2048, num_layers=16,
             k = heads(norm(proj(u, pre + "k"), pre + "k_norm"), True)
             v = heads(proj(u, pre + "v"), False)
             a = sym.RingAttention(q, k, v, causal=True, name=pre + "attn")
-            a = sym.Reshape(sym.transpose(a, axes=(0, 2, 1, 3)),
-                            shape=(0, 0, -1))
-            x = x + proj(a, pre + "o")
+            x = x + proj(merge_heads(a), pre + "o")
             x = x + sym.MoE(
                 norm(x, pre + "post_norm"), num_experts=num_experts,
                 num_hidden=expert_width, top_k=top_k, lb_coef=lb_coef,
                 z_coef=z_coef, name=pre + "moe")
-        x = sym.Reshape(norm(x, "final_norm"), shape=(-1, hidden_size))
-        pred = sym.FullyConnected(x, num_hidden=vocab_size, no_bias=True,
-                                  name="pred")
-        pred = low_precision_io(pred, dtype, out=True)
-        pred = sym.SoftmaxOutput(
-            pred, sym.Reshape(label, shape=(-1,)), use_ignore=True,
-            ignore_label=ignore_label, name="softmax")
+        pred = next_token_head(norm(x, "final_norm"), label, vocab_size,
+                               hidden_size, dtype, ignore_label)
         return pred, ("data",), ("softmax_label",)
 
     return sym_gen

@@ -567,6 +567,13 @@ def _ring_attention_op(ins, params, mode):
     softmax over blocks of queries on one device (``blockwise_attention``:
     exact, and linear in T where the whole score matrix is quadratic), so
     the same symbol serves single-chip and sequence-parallel runs.
+
+    The one-device path alone has ``window`` (causal: a query reads the
+    ``window`` keys ending at itself; key blocks outside the band are
+    skipped, not masked) and grouped heads: key and value (B, Hkv, T, D)
+    with Hkv dividing H, query head n reading key/value head
+    ``n // (H / Hkv)`` with no repeated copy. The ring path refuses both
+    by name.
     """
     from ..parallel.mesh import current_mesh
     from ..parallel.ring_attention import ring_attention_traced
@@ -576,7 +583,7 @@ def _ring_attention_op(ins, params, mode):
     return ring_attention_traced(
         q, k, v, current_mesh(), axis=params["axis_name"],
         causal=params["causal"], scale=scale,
-        batch_axis=params["batch_axis"] or None,
+        batch_axis=params["batch_axis"] or None, window=params["window"],
     ).astype(q.dtype)
 
 
@@ -589,6 +596,7 @@ register(
         "axis_name": Param(parse_str, "sp"),
         "batch_axis": Param(parse_str, ""),  # dp axis on combined meshes
         "scale": Param(parse_float, -1.0),  # <=0: 1/sqrt(head_dim)
+        "window": Param(parse_int, 0),  # keys a query reads; 0: all before
     },
     aliases=("_contrib_RingAttention",),
 )

@@ -318,6 +318,7 @@ _ACTS = {
     "tanh": jnp.tanh,
     "softrelu": jax.nn.softplus,
     "softsign": jax.nn.soft_sign,
+    "silu": jax.nn.silu,  # x * sigmoid(x): the SwiGLU blocks of models/afmoe
 }
 
 

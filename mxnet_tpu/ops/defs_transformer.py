@@ -16,11 +16,14 @@ the dtype of ``data``.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..base import parse_float, parse_int
+from ..base import (MXNetError, parse_bool, parse_float, parse_int,
+                    parse_str)
 from . import grouped_matmul as _gmm
 from .defs_nn import _castp, _prec
 from .registry import Param, register
@@ -163,45 +166,190 @@ def moe_kernel_matmuls(platform, data_dtype, weight_dtype, rows, hidden,
         [(hidden, width), (width, hidden)]) is not None)
 
 
+def _router(x, w_router, bias, params):
+    """(expert (N * k,) int32, weights (N, k) float32, rows an expert (E,)):
+    the ``top_k`` experts of each token and what their outputs are weighted
+    by, in float32 whatever the trunk.
+
+    ``score_func`` softmax: the experts of largest ``p = softmax(logits)``,
+    weighted by ``p``. sigmoid: ``s = sigmoid(logits)``, the experts of
+    largest ``s + expert_bias`` (the bias steers the choice only: it has no
+    gradient and is not in the weights), weighted by ``s``. Then, either
+    way: ``route_norm`` divides a token's k weights by their sum (+ 1e-20)
+    and ``route_scale`` multiplies them."""
+    k = params["top_k"]
+    n, e = x.shape[0], w_router.shape[0]
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32).T,
+                     precision=jax.lax.Precision.HIGHEST)
+    if params["score_func"] == "sigmoid":
+        if params["lb_coef"] or params["z_coef"]:
+            raise MXNetError("MoE: lb_coef and z_coef are defined on a "
+                             "softmax router, not score_func='sigmoid'")
+        scores = jax.nn.sigmoid(logits)
+        biased = scores if bias is None else scores + jax.lax.stop_gradient(
+            bias.astype(jnp.float32))
+        _, expert = jax.lax.top_k(biased, k)                  # (N, k)
+        expert = expert.reshape(-1)
+        counts = jnp.bincount(expert, length=e).astype(jnp.int32)
+    elif params["score_func"] == "softmax":
+        if bias is not None:
+            raise MXNetError("MoE: expert_bias needs score_func='sigmoid'")
+        _, expert = jax.lax.top_k(logits, k)                  # (N, k)
+        expert = expert.reshape(-1)
+        counts = jnp.bincount(expert, length=e).astype(jnp.int32)
+        logits = _attach_router_losses(
+            logits, counts.astype(jnp.float32) / n,
+            params["lb_coef"], params["z_coef"])
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise MXNetError(f"MoE: score_func {params['score_func']!r} is "
+                         "neither 'softmax' nor 'sigmoid'")
+    p = jnp.take_along_axis(scores, expert.reshape(n, k), axis=1)  # f32
+    if params["route_norm"]:
+        p = p / (jnp.sum(p, axis=-1, keepdims=True) + 1e-20)
+    if params["route_scale"] != 1.0:
+        p = p * params["route_scale"]
+    return expert, p, counts
+
+
+# A round of the held experts' rows is this many times the rows a balanced
+# router sends them, in whole row tiles of the kernels.
+_ROUND_FACTOR = 2
+_ROUND_TILE = 512
+
+
+def held_round_rows(assignments, held, experts):
+    """Rows of one round of ``MoE``'s dispatch where ``held`` of
+    ``experts`` experts live here (``_held_rounds``): ``_ROUND_FACTOR`` x
+    the balanced share of the ``assignments`` (tokens x top_k), in row
+    tiles, at most all of them. All of them where every expert is held."""
+    if held == experts:
+        return assignments
+    rows = _ROUND_FACTOR * -(-assignments * held // experts)
+    tile = _ROUND_TILE if rows >= _ROUND_TILE else 8
+    return min(assignments, -(-rows // tile) * tile)
+
+
+def _held_round(first, rows, x, tok, weight, counts, w_gate, w_up, w_down):
+    """(N, H) float32: what rows ``[first, first + rows)`` of the held
+    assignments add to the layer's output. ``tok`` / ``weight``: token and
+    routing weight of each held assignment, sorted by expert, the dead tail
+    after them; ``counts`` (L,) rows an expert over the whole list. The
+    grouped matmuls visit only the live rows; their outputs past those are
+    not written, so each is masked on both sides (forward and cotangent
+    are then zeros there, never what the buffer held)."""
+    ends = jnp.cumsum(counts)
+    here = (jnp.clip(ends, first, first + rows)
+            - jnp.clip(ends - counts, first, first + rows)).astype(jnp.int32)
+    live = (jnp.arange(rows) < jnp.sum(here))[:, None]
+    tok = jax.lax.dynamic_slice_in_dim(tok, first, rows)
+    weight = jax.lax.dynamic_slice_in_dim(weight, first, rows)
+    matmul = _expert_matmul(here, x.dtype, rows, (w_gate, w_up, w_down))
+
+    def live_matmul(r, w):
+        return jnp.where(live, matmul(jnp.where(live, r, 0), w), 0)
+
+    r = x[tok]
+    y = live_matmul(jax.nn.silu(live_matmul(r, w_gate))
+                    * live_matmul(r, w_up), w_down)
+    return jnp.zeros(x.shape, jnp.float32).at[tok].add(
+        y.astype(jnp.float32) * weight[:, None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_rounds(rows, x, weight, w_gate, w_up, w_down, tok, counts):
+    """The sum of ``_held_round`` over the rounds of ``rows`` rows that
+    hold a live row: the first always, the others in a loop whose trip
+    count is read on the device, zero unless routing has collapsed onto
+    the experts held here. Nothing is differentiated through the loop:
+    forward keeps the first round's residuals as autodiff would, backward
+    runs the same loop and recomputes each further round before its
+    cotangents, so memory and the program's size are one round's, however
+    many run."""
+    return _held_rounds_fwd(rows, x, weight, w_gate, w_up, w_down, tok,
+                            counts)[0]
+
+
+def _round_of(first, rows, tok, counts):
+    """``_held_round`` at ``first`` as a function of what it is
+    differentiated in: x, the routing weights, the three expert weights."""
+    return lambda x, weight, *w: _held_round(first, rows, x, tok, weight,
+                                             counts, *w)
+
+
+def _held_rounds_fwd(rows, x, weight, w_gate, w_up, w_down, tok, counts):
+    wrt = (x, weight, w_gate, w_up, w_down)
+    out, vjp = jax.vjp(_round_of(0, rows, tok, counts), *wrt)
+    rounds = (jnp.sum(counts) + rows - 1) // rows
+    out = jax.lax.fori_loop(
+        1, rounds,
+        lambda r, acc: acc + _round_of(r * rows, rows, tok, counts)(*wrt),
+        out)
+    return out, (vjp, wrt, tok, counts, rounds)
+
+
+def _held_rounds_bwd(rows, res, g):
+    vjp, wrt, tok, counts, rounds = res
+
+    def more(r, cts):
+        back = jax.vjp(_round_of(r * rows, rows, tok, counts), *wrt)[1]
+        return jax.tree.map(jnp.add, cts, back(g))
+
+    return jax.lax.fori_loop(1, rounds, more, vjp(g)) + (None, None)
+
+
+_held_rounds.defvjp(_held_rounds_fwd, _held_rounds_bwd)
+
+
 def _moe(ins, params, mode):
     """Sparse mixture of SiLU-gated experts, drop-free.
 
     ``data`` (..., H) is N rows of tokens. ``router_weight`` (E, H);
-    ``gate_weight`` and ``up_weight`` (E, H, F) and ``down_weight``
-    (E, F, H): expert-major, input features before output features, the
-    layout the grouped matmul reads. Each token goes to the ``top_k``
-    experts of largest ``p = softmax(router_weight . t)`` and receives
-    ``sum p_e * down_e(silu(gate_e t) * up_e t)``, the weights not
-    renormalised. The N x top_k assignments are sorted by expert and each
-    expert multiplies exactly its own rows (``_expert_matmul``): no
-    capacity, no token dropped, none computed for an expert it was not
-    routed to.
+    ``gate_weight`` and ``up_weight`` (L, H, F) and ``down_weight``
+    (L, F, H): expert-major, input features before output features, the
+    layout the grouped matmul reads; L = ``num_local_experts`` (E where it
+    is 0: every expert lives here); ``expert_bias`` (E,) where
+    ``expert_bias=True``. Each token goes to the ``top_k`` experts the
+    router gives it (``_router``) and receives ``sum w_e *
+    down_e(silu(gate_e t) * up_e t)`` over those of them that are held
+    here, experts ``[expert_offset, expert_offset + L)``. The assignments
+    are sorted by expert and each expert multiplies exactly its own rows
+    (``_expert_matmul``): no capacity, no token dropped, none computed for
+    an expert it was not routed to.
     """
-    x, w_router, w_gate, w_up, w_down = ins
+    x, w_router, w_gate, w_up, w_down = ins[:5]
+    bias = ins[5] if params["expert_bias"] else None
     k = params["top_k"]
     shape = x.shape
     x = x.reshape(-1, shape[-1])
     n, e = x.shape[0], w_router.shape[0]
+    held = w_gate.shape[0]
+    expert, p, counts = _router(x, w_router, bias, params)
 
-    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32).T,
-                     precision=jax.lax.Precision.HIGHEST)
-    _, expert = jax.lax.top_k(logits, k)                      # (N, k)
-    expert = expert.reshape(-1)
-    counts = jnp.bincount(expert, length=e).astype(jnp.int32)
-    logits = _attach_router_losses(
-        logits, counts.astype(jnp.float32) / n,
-        params["lb_coef"], params["z_coef"])
-    p = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1),
-                            expert.reshape(n, k), axis=1)     # (N, k) f32
+    if held == e and not params["expert_offset"]:
+        order = jnp.argsort(expert, stable=True)              # by expert
+        inverse = jnp.argsort(order)
+        rows = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
+        matmul = _expert_matmul(counts, x.dtype, n * k,
+                                (w_gate, w_up, w_down))
+        gate, up = matmul(rows, w_gate), matmul(rows, w_up)
+        out = matmul(jax.nn.silu(gate) * up, w_down)
+        out = _permute_rows(out, inverse, order).reshape(n, k, -1)
+        out = jnp.sum(out.astype(jnp.float32) * p[..., None], axis=1)
+        return out.astype(x.dtype).reshape(shape)
 
-    order = jnp.argsort(expert, stable=True)                  # by expert
-    inverse = jnp.argsort(order)
-    rows = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
-    matmul = _expert_matmul(counts, x.dtype, n * k, (w_gate, w_up, w_down))
-    gate, up = matmul(rows, w_gate), matmul(rows, w_up)
-    out = matmul(jax.nn.silu(gate) * up, w_down)
-    out = _permute_rows(out, inverse, order).reshape(n, k, -1)
-    out = jnp.sum(out.astype(jnp.float32) * p[..., None], axis=1)
+    # the share of the experts held here
+    local = expert - params["expert_offset"]
+    key = jnp.where(jnp.logical_and(local >= 0, local < held), local, held)
+    order = jnp.argsort(key, stable=True)       # held first, by expert
+    counts = counts[params["expert_offset"]:params["expert_offset"] + held]
+    rows = held_round_rows(n * k, held, e)
+    rounds = -(-n * k // rows)
+    tok, weight = (order // k).astype(jnp.int32), p.reshape(-1)[order]
+    if rounds * rows > n * k:   # whole rounds: more of the dead tail
+        tok, weight = (jnp.pad(a, (0, rounds * rows - n * k))
+                       for a in (tok, weight))
+    out = _held_rounds(rows, x, weight, w_gate, w_up, w_down, tok, counts)
     return out.astype(x.dtype).reshape(shape)
 
 
@@ -209,7 +357,14 @@ def _moe_fill(shapes, params):
     data = shapes[0]
     if data is not None:
         e, f, h = params["num_experts"], params["num_hidden"], data[-1]
-        for i, s in enumerate([(e, h), (e, h, f), (e, h, f), (e, f, h)], 1):
+        held = params["num_local_experts"] or e
+        if params["expert_offset"] + held > e:
+            raise MXNetError(
+                f"MoE: experts [{params['expert_offset']}, "
+                f"{params['expert_offset'] + held}) of {e}")
+        for i, s in enumerate([(e, h), (held, h, f), (held, h, f),
+                               (held, f, h)] + [(e,)] * params["expert_bias"],
+                              1):
             shapes[i] = shapes[i] or s
     return shapes
 
@@ -217,15 +372,23 @@ def _moe_fill(shapes, params):
 register(
     "MoE",
     _moe,
-    arg_names=["data", "router_weight", "gate_weight", "up_weight",
-               "down_weight"],
+    arg_names=lambda p: ["data", "router_weight", "gate_weight", "up_weight",
+                         "down_weight"] + ["expert_bias"] * p["expert_bias"],
     param_schema={
-        "num_experts": Param(parse_int),
+        "num_experts": Param(parse_int),  # the router's width
         "num_hidden": Param(parse_int),  # width of one expert
         "top_k": Param(parse_int),
         # router regularisers, attached in backward (forward unchanged)
         "lb_coef": Param(parse_float, 0.0),
         "z_coef": Param(parse_float, 0.0),
+        "score_func": Param(parse_str, "softmax"),  # or "sigmoid"
+        "route_norm": Param(parse_bool, False),
+        "route_scale": Param(parse_float, 1.0),
+        "expert_bias": Param(parse_bool, False),  # a sixth input (E,)
+        # the experts held here: [expert_offset, + num_local_experts) of
+        # num_experts; 0 = all of them
+        "num_local_experts": Param(parse_int, 0),
+        "expert_offset": Param(parse_int, 0),
     },
     fill_in_shapes=_moe_fill,
 )

@@ -104,52 +104,129 @@ def _ring_attn_shard(q, k, v, axis_name, causal, scale):
 
 
 BLOCK_Q = 512  # queries a block: its score tile is (B, H, 512, <= T) float32
+# A block's float32 score tile is written once and re-read by each of the
+# softmax's element-wise passes. On a v5e (128 MiB of fast memory) a tile of
+# 160 MiB made a window layer's forward 5.09 ms at 32 heads, T 4096, and one
+# of 72 MiB 1.64 ms (blocks of 512 / 256 queries; forward + backward 10.8 /
+# 7.5 ms; the full layer 12.9 / 9.8 at 256 / 128 MiB; PERF.md section 6,
+# PR 32), so a block is the largest whose tile stays within this.
+SCORE_TILE_BYTES = 128 << 20
 
 
-def _q_blocks(T, block_q, causal):
-    """[(first query, end of queries, end of the keys they read, mask)]:
-    the mask (queries, keys) is None where the attention is not causal."""
-    blocks = []
+def block_q_of(batch, heads, T, window=0):
+    """Queries a block: the largest of 512, 256, 128 whose float32 score
+    tile (batch x heads x block x the keys it reads: all T, or the band's
+    ``window + block``) is at most ``SCORE_TILE_BYTES``; 128 if none."""
+    for block in (BLOCK_Q, 256, 128):
+        keys = min(T, window + block) if window else T
+        if 4 * batch * heads * block * keys <= SCORE_TILE_BYTES:
+            return block
+    return 128
+
+
+def block_plan(T, block_q, causal, window=0):
+    """[(first query, end of queries, first key, end of keys)]: the keys a
+    block of queries reads. A causal block stops at its own end; under a
+    ``window`` (a query reads the keys ``i - window < j <= i``) it starts
+    at the query block that holds the first key its band touches, so the
+    key blocks outside the band are never read: skipped, not masked."""
+    plan = []
     for a in range(0, T, block_q):
         b = min(a + block_q, T)
-        end = b if causal else T
-        mask = (jnp.arange(a, b)[:, None] >= jnp.arange(end)[None, :]
-                if causal else None)
-        blocks.append((a, b, end, mask))
+        first = max(0, a - window + 1) // block_q * block_q if window else 0
+        plan.append((a, b, first, b if causal else T))
+    return plan
+
+
+def scored_pairs(T, causal, window=0, block_q=BLOCK_Q):
+    """Query-key pairs one head scores under :func:`block_plan`: the sizes
+    of the score tiles the blockwise path computes, forward (its backward
+    recomputes the same tiles)."""
+    return sum((b - a) * (end - first)
+               for a, b, first, end in block_plan(T, block_q, causal, window))
+
+
+def _q_blocks(T, block_q, causal, window=0):
+    """:func:`block_plan` with each block's mask (queries, keys), None
+    where the attention is not causal."""
+    blocks = []
+    for a, b, first, end in block_plan(T, block_q, causal, window):
+        mask = None
+        if causal:
+            mask = jnp.arange(a, b)[:, None] >= jnp.arange(first, end)[None, :]
+            if window:
+                mask = jnp.logical_and(
+                    mask, jnp.arange(a, b)[:, None]
+                    - jnp.arange(first, end)[None, :] < window)
+        blocks.append((a, b, first, end, mask))
     return blocks
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q):
+def _fold(x, kv_heads):
+    """(B, Hq, Tq, D) -> (B, Hkv, G * Tq, D): the G query heads that share
+    a key/value head laid end to end along the query axis, so one matmul a
+    key/value head serves its whole group and k and v are never repeated
+    (their gradients come out summed over the group). Identity where the
+    head counts are equal."""
+    B, H, T, D = x.shape
+    return x if H == kv_heads else x.reshape(B, kv_heads, H // kv_heads * T,
+                                             D)
+
+
+def _unfold(x, heads):
+    B, kv, GT, D = x.shape
+    return x if kv == heads else x.reshape(B, heads, GT * kv // heads, D)
+
+
+def _group_mask(mask, group):
+    return mask if mask is None or group == 1 else jnp.tile(mask, (group, 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q, window=0):
     """softmax(q k^T * scale [+ causal mask]) v on one device, a block of
-    ``block_q`` queries at a time; q, k, v (B, H, T, D), output in their
-    dtype. Memory is linear in T, forward and backward: the backward pass
-    keeps q, k, v, the output and the rows' log-sum-exp, and recomputes
-    each block's scores from them."""
-    return _blockwise_fwd(q, k, v, causal, scale, block_q)[0]
+    ``block_q`` queries at a time; q (B, H, T, D), k and v (B, Hkv, T, D)
+    with Hkv dividing H (query head n reads key/value head n // (H / Hkv)),
+    output in their dtype. ``window`` (causal only): a query reads only the
+    ``window`` keys that end at itself, and a block only the key blocks its
+    band touches (:func:`block_plan`). Memory is linear in T, forward and
+    backward: the backward pass keeps q, k, v, the output and the rows'
+    log-sum-exp, and recomputes each block's scores from them."""
+    return _blockwise_fwd(q, k, v, causal, scale, block_q, window)[0]
 
 
-def _blockwise_fwd(q, k, v, causal, scale, block_q):
+def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0):
+    if window and not causal:
+        raise MXNetError("attention: window needs causal=True")
     B, H, T, D = q.shape
+    kv = k.shape[1]
+    if H % kv or v.shape[1] != kv:
+        raise MXNetError(f"attention: {H} query heads over {kv} key and "
+                         f"{v.shape[1]} value heads")
+    group = H // kv
     outs, lses = [], []
-    for a, b, end, mask in _q_blocks(T, block_q, causal):
+    for a, b, first, end, mask in _q_blocks(T, block_q, causal, window):
+        rows = group * (b - a)
         o, m, l = _softmax_block(
-            q[:, :, a:b], k[:, :, :end], v[:, :, :end], mask, scale,
-            jnp.zeros((B, H, b - a, D), jnp.float32),
-            jnp.full((B, H, b - a), -jnp.inf, jnp.float32),
-            jnp.zeros((B, H, b - a), jnp.float32))
-        outs.append((o / l[..., None]).astype(q.dtype))
-        lses.append(m + jnp.log(l))
+            _fold(q[:, :, a:b], kv), k[:, :, first:end], v[:, :, first:end],
+            _group_mask(mask, group), scale,
+            jnp.zeros((B, kv, rows, D), jnp.float32),
+            jnp.full((B, kv, rows), -jnp.inf, jnp.float32),
+            jnp.zeros((B, kv, rows), jnp.float32))
+        outs.append(_unfold((o / l[..., None]).astype(q.dtype), H))
+        lses.append((m + jnp.log(l)).reshape(B, H, b - a))
     out = jnp.concatenate(outs, axis=2)
     return out, (q, k, v, out, jnp.concatenate(lses, axis=2))
 
 
-def _blockwise_bwd(causal, scale, block_q, res, d_out):
+def _blockwise_bwd(causal, scale, block_q, window, res, d_out):
     from ..ops.defs_tensor import matmul_precision
 
     q, k, v, out, lse = res
     prec = matmul_precision(q.dtype)
     f32 = jnp.float32
+    H, kv = q.shape[1], k.shape[1]
+    group = H // kv
 
     def dot(spec, x, y):
         return jnp.einsum(spec, x, y, precision=prec,
@@ -159,19 +236,21 @@ def _blockwise_bwd(causal, scale, block_q, res, d_out):
     dk = jnp.zeros(k.shape, f32)
     dv = jnp.zeros(v.shape, f32)
     delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
-    for a, b, end, mask in _q_blocks(q.shape[2], block_q, causal):
-        qb, kb, vb, gb = q[:, :, a:b], k[:, :, :end], v[:, :, :end], \
-            d_out[:, :, a:b]
+    for a, b, first, end, mask in _q_blocks(q.shape[2], block_q, causal,
+                                            window):
+        mask = _group_mask(mask, group)
+        qb, kb, vb, gb = _fold(q[:, :, a:b], kv), k[:, :, first:end], \
+            v[:, :, first:end], _fold(d_out[:, :, a:b], kv)
         s = dot("bhqd,bhkd->bhqk", qb, kb) * scale
-        p = jnp.exp(s - lse[:, :, a:b, None])
+        p = jnp.exp(s - _fold(lse[:, :, a:b, None], kv))
         if mask is not None:
             p = jnp.where(mask[None, None], p, 0.0)
         ds = p * (dot("bhqd,bhkd->bhqk", gb, vb)
-                  - delta[:, :, a:b, None]) * scale
+                  - _fold(delta[:, :, a:b, None], kv)) * scale
         p, ds = p.astype(q.dtype), ds.astype(q.dtype)
-        dv = dv.at[:, :, :end].add(dot("bhqk,bhqd->bhkd", p, gb))
-        dk = dk.at[:, :, :end].add(dot("bhqk,bhqd->bhkd", ds, qb))
-        dq.append(dot("bhqk,bhkd->bhqd", ds, kb).astype(q.dtype))
+        dv = dv.at[:, :, first:end].add(dot("bhqk,bhqd->bhkd", p, gb))
+        dk = dk.at[:, :, first:end].add(dot("bhqk,bhqd->bhkd", ds, qb))
+        dq.append(_unfold(dot("bhqk,bhkd->bhqd", ds, kb).astype(q.dtype), H))
     return (jnp.concatenate(dq, axis=2), dk.astype(k.dtype),
             dv.astype(v.dtype))
 
@@ -179,13 +258,30 @@ def _blockwise_bwd(causal, scale, block_q, res, d_out):
 blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
-def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None):
+def _refuse_on_the_ring(q, k, window):
+    """The ring rotates whole key/value blocks of equal head count: it has
+    neither the band's block plan nor grouped heads (ROADMAP Reach 3)."""
+    if window:
+        raise MXNetError(
+            f"RingAttention: window={window} is not supported on the "
+            "sequence-parallel ring path; run it on one device (no mesh "
+            "axis for the sequence)")
+    if q.shape[1] != k.shape[1]:
+        raise MXNetError(
+            f"RingAttention: {q.shape[1]} query heads over {k.shape[1]} "
+            "key/value heads are not supported on the sequence-parallel "
+            "ring path; run it on one device")
+
+
+def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None,
+                   window=0):
     """Sequence-parallel attention.
 
     q, k, v: jax arrays or NDArrays of shape (B, H, T, D), sharded (or to be
     sharded) along T over mesh axis ``axis``. Returns same-shaped output
     with the same sharding. With ``mesh=None`` it is
-    :func:`blockwise_attention` on one device (same math).
+    :func:`blockwise_attention` on one device (same math), which alone has
+    ``window`` and key/value heads fewer than the query heads.
     """
     from ..ndarray import NDArray
 
@@ -196,8 +292,11 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None):
         scale = 1.0 / math.sqrt(q.shape[-1])
 
     if mesh is None:
-        out = blockwise_attention(q, k, v, causal, scale)
+        out = blockwise_attention(
+            q, k, v, causal, scale,
+            block_q_of(q.shape[0], q.shape[1], q.shape[2], window), window)
         return NDArray(out) if wrap else out
+    _refuse_on_the_ring(q, k, window)
 
     from jax.sharding import NamedSharding
 
@@ -229,7 +328,7 @@ def _ring_spec(axis, batch_axis):
 
 
 def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
-                          scale=None, batch_axis=None):
+                          scale=None, batch_axis=None, window=0):
     """Jit-safe ring attention for use INSIDE a traced program (the
     symbol-level ``_contrib_RingAttention`` op): placement is expressed as
     sharding constraints (not eager ``device_put``) and the ``shard_map``
@@ -244,7 +343,10 @@ def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
         scale = 1.0 / math.sqrt(q.shape[-1])
     mesh = getattr(as_graft(mesh), "mesh", None)
     if mesh is None or axis not in mesh.axis_names:
-        return blockwise_attention(q, k, v, causal, scale)
+        return blockwise_attention(
+            q, k, v, causal, scale,
+            block_q_of(q.shape[0], q.shape[1], q.shape[2], window), window)
+    _refuse_on_the_ring(q, k, window)
     if batch_axis is not None and batch_axis not in mesh.axis_names:
         raise MXNetError(f"mesh has no axis {batch_axis!r}")
     spec = _ring_spec(axis, batch_axis)

@@ -16,6 +16,7 @@ import pytest
 
 from mxnet_tpu.ops import defs_transformer as dt
 from mxnet_tpu.ops import grouped_matmul as gm
+from mxnet_tpu.ops import registry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 V5E_VMEM = 128 << 20
@@ -303,8 +304,8 @@ def _moe_three_ways():
     ws = [jnp.asarray(rs.randn(*s) * 0.1, jnp.float32)
           for s in ((4, 128), (4, 128, 128), (4, 128, 128), (4, 128, 128))]
     head = jnp.asarray(rs.randn(64, 128), jnp.float32)
-    params = dict(num_experts=4, num_hidden=128, top_k=2, lb_coef=0.01,
-                  z_coef=0.001)
+    params = registry.get("MoE").parse_params(dict(
+        num_experts=4, num_hidden=128, top_k=2, lb_coef=0.01, z_coef=0.001))
     def run(matmul):
         def scalar(*ins):
             out = dt._moe(list(ins), params, None)
