@@ -437,8 +437,10 @@ class _CompiledGraph:
     nodes inserted by the PlaceDevice pass (graph_executor.cc:286-385).
     """
 
-    def __init__(self, symbol, node2dev=None, remat=False, layout="NCHW"):
+    def __init__(self, symbol, node2dev=None, remat=False, layout="NCHW",
+                 platform=None):
         self.symbol = symbol
+        self.platform = platform  # of the executor's context: OpMode's
         self.node2dev = node2dev or {}
         # remat (reference MXNET_BACKWARD_DO_MIRROR): wrap each op in
         # jax.checkpoint so backward recomputes op-internal values from op
@@ -587,14 +589,16 @@ class _CompiledGraph:
             if self.remat and not node.op.aux_names(params):
                 apply_fn = jax.checkpoint(
                     lambda inner, _op=node.op, _p=params, _m=OpMode(
-                        is_train=is_train, rng=node_rng, layout=op_layout
+                        is_train=is_train, rng=node_rng, layout=op_layout,
+                        platform=self.platform,
                     ): _op.apply(inner, _p, _m)
                 )
                 outs, new_aux = apply_fn(ins)
             else:
                 outs, new_aux = node.op.apply(
                     ins, params,
-                    OpMode(is_train=is_train, rng=node_rng, layout=op_layout),
+                    OpMode(is_train=is_train, rng=node_rng, layout=op_layout,
+                           platform=self.platform),
                 )
             if group:
                 for i, n in enumerate(group[1:], 1):
@@ -664,6 +668,7 @@ class Executor:
             symbol, node2dev=self._node2dev,
             remat=_env.get("MXNET_BACKWARD_DO_MIRROR"),
             layout=_lay.resolve(self._ctx),
+            platform=self._ctx.jax_device().platform,
         )
         self.arg_names = self.graph.arg_names
         self.aux_names = self.graph.aux_names
@@ -1435,10 +1440,13 @@ class Executor:
         (``ops/defs_transformer.moe_kernel_matmuls``) with this executor's
         platform and the rows of one dispatch round;
         ``attention_layers``; ``attention_window_layers``, those with a
-        ``window``; ``attention_scored_pairs``, the query-key pairs their
-        block plans score (``parallel/ring_attention.scored_pairs`` x
-        heads x batch). Shapes and types are inferred only where the graph
-        has such a node."""
+        ``window``; ``attention_kernel_layers``, those a train program runs
+        in the fused Pallas kernels, asked of the rule the op follows
+        (``parallel/ring_attention.kernel_plan``) with this executor's
+        platform; ``attention_scored_pairs``, the query-key pairs of the
+        tiles they visit (``ring_attention.pairs_scored`` x heads x
+        batch). Shapes and types are inferred only where the graph has such
+        a node."""
         if self._layer_counts is None:
             ops = [n for n in self.graph.topo if not n.is_variable]
             moe = [n for n in ops if n.op.name == "MoE"]
@@ -1446,21 +1454,21 @@ class Executor:
             counts = dict.fromkeys((
                 "moe_layers", "moe_assignments", "moe_local_experts",
                 "moe_kernel_matmuls", "attention_layers",
-                "attention_window_layers", "attention_scored_pairs"), 0)
+                "attention_window_layers", "attention_kernel_layers",
+                "attention_scored_pairs"), 0)
             if moe or attention:
                 from .ops.defs_transformer import (held_round_rows,
                                                    moe_kernel_matmuls)
-                from .parallel.ring_attention import (block_q_of,
-                                                      scored_pairs)
+                from .parallel.ring_attention import (kernel_plan,
+                                                      pairs_scored)
 
                 internals = self._symbol.get_internals()
                 _, shapes, _ = internals.infer_shape(
                     **{n: tuple(a.shape) for n, a in self.arg_dict.items()})
                 shape_of = dict(zip(internals.list_outputs(), shapes))
-                if moe:
-                    _, dtypes, _ = internals.infer_type(
-                        **{n: a.dtype for n, a in self.arg_dict.items()})
-                    dtype_of = dict(zip(internals.list_outputs(), dtypes))
+                _, dtypes, _ = internals.infer_type(
+                    **{n: a.dtype for n, a in self.arg_dict.items()})
+                dtype_of = dict(zip(internals.list_outputs(), dtypes))
                 platform = self._ctx.jax_device().platform
                 for n in moe:
                     out, p = n.name + "_output", n.params()
@@ -1476,13 +1484,18 @@ class Executor:
                         shape_of[out][-1], p["num_hidden"])
                 for n in attention:
                     p = n.params()
-                    batch, heads, seq_len, _ = shape_of[n.name + "_output"]
+                    out = n.name + "_output"
+                    # the key, named as ``list_outputs`` names an entry
+                    key, = type(internals)([n.inputs[1]]).list_outputs()
+                    kv_heads = shape_of[key][1]
+                    kernels = kernel_plan(
+                        dtype_of[out], shape_of[out], kv_heads, p["causal"],
+                        p["window"], platform)
                     counts["attention_layers"] += 1
                     counts["attention_window_layers"] += bool(p["window"])
-                    counts["attention_scored_pairs"] += batch * heads \
-                        * scored_pairs(
-                            seq_len, p["causal"], p["window"],
-                            block_q_of(batch, heads, seq_len, p["window"]))
+                    counts["attention_kernel_layers"] += kernels is not None
+                    counts["attention_scored_pairs"] += pairs_scored(
+                        shape_of[out], p["causal"], p["window"], kernels)
             self._layer_counts = counts
         return self._layer_counts
 
@@ -1510,6 +1523,9 @@ class Executor:
                 held["attention_layers"])
             _tm.counter("executor.attention_scored_pairs").inc(
                 held["attention_scored_pairs"])
+        if held["attention_kernel_layers"]:
+            _tm.counter("executor.attention_kernel_layers").inc(
+                held["attention_kernel_layers"])
         if held["attention_window_layers"]:
             _tm.counter("executor.attention_window_layers").inc(
                 held["attention_window_layers"])

@@ -566,7 +566,13 @@ def _ring_attention_op(ins, params, mode):
     (parallel/ring_attention.py); without one it is the same online
     softmax over blocks of queries on one device (``blockwise_attention``:
     exact, and linear in T where the whole score matrix is quadratic), so
-    the same symbol serves single-chip and sequence-parallel runs.
+    the same symbol serves single-chip and sequence-parallel runs. On one
+    TPU with bfloat16 operands, a head dim 128 divides and T a multiple of
+    a block, that one-device path is the fused Pallas kernels of
+    ``ops/flash_attention.py`` (forward and backward; no score tile in
+    HBM); the rule is ``ring_attention.kernel_plan``, from what is observed
+    at trace time (``mode.platform``: the executor's), and everything else
+    takes ``jax.numpy`` blocks.
 
     The one-device path alone has ``window`` (causal: a query reads the
     ``window`` keys ending at itself; key blocks outside the band are
@@ -584,6 +590,7 @@ def _ring_attention_op(ins, params, mode):
         q, k, v, current_mesh(), axis=params["axis_name"],
         causal=params["causal"], scale=scale,
         batch_axis=params["batch_axis"] or None, window=params["window"],
+        platform=mode.platform,
     ).astype(q.dtype)
 
 

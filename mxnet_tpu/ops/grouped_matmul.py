@@ -41,6 +41,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import os
+import sys
 from typing import NamedTuple, Optional
 
 import jax
@@ -474,31 +475,33 @@ def _kernel_cache_dir():
 
 
 @functools.cache
-def _source_digest():
-    with open(__file__, "rb") as f:
+def _source_digest(path):
+    with open(path, "rb") as f:
         return hashlib.sha256(f.read()).hexdigest()
 
 
-def _kernel(impl, x, y, gr, **static):
-    """``impl(x, y, gr, **static)``, a Pallas kernel, traced once a cache
-    directory and not once a process. Tracing the six kernels of a layer,
-    lowering them to Mosaic and importing Pallas to do so cost 2.8 s of
-    every process's set-up on the v5e's host (PERF.md section 6, PR 30);
-    ``jax.export`` keeps the lowered module (17 KB a kernel), and a later
-    process reads it back and calls it: no trace, no Pallas. The key holds
-    this file's text, jax's version, the device kind, the kernel, its
-    static arguments and the operands' shapes and types. A directory that
-    cannot be written only loses the saving."""
+def _kernel(impl, operands, **static):
+    """``impl(*operands, **static)``, a jitted Pallas kernel (this module's
+    or ``flash_attention``'s), traced once a cache directory and not once a
+    process. Tracing the six kernels of a layer, lowering them to Mosaic
+    and importing Pallas to do so cost 2.8 s of every process's set-up on
+    the v5e's host (PERF.md section 6, PR 30); ``jax.export`` keeps the
+    lowered module (17 KB a kernel), and a later process reads it back and
+    calls it: no trace, no Pallas. The key holds the text of the kernel's
+    module, jax's version, the device kind, the kernel, its static
+    arguments and the operands' shapes and types. A directory that cannot
+    be written only loses the saving."""
     directory = _kernel_cache_dir()
     if directory is None or static["interpret"]:
-        return impl(x, y, gr, **static)
+        return impl(*operands, **static)
     from jax import export
 
-    operands = (x, y) + tuple(gr)
+    leaves, tree = jax.tree.flatten(operands)
     key = hashlib.sha256(repr((
-        _source_digest(), jax.__version__, jax.devices()[0].device_kind,
-        impl.__name__, sorted(static.items()),
-        [(a.shape, str(a.dtype)) for a in operands])).encode()).hexdigest()
+        _source_digest(sys.modules[impl.__module__].__file__),
+        jax.__version__, jax.devices()[0].device_kind, impl.__name__,
+        sorted(static.items()), str(tree),
+        [(a.shape, str(a.dtype)) for a in leaves])).encode()).hexdigest()
     exported = _EXPORTED.get(key)
     path = os.path.join(directory, key)
     if exported is None:
@@ -507,10 +510,11 @@ def _kernel(impl, x, y, gr, **static):
                 exported = export.deserialize(bytearray(f.read()))
         except (OSError, ValueError):
             exported = export.export(
-                jax.jit(lambda x, y, *gr: impl(x, y, Groups(*gr), **static)),
+                jax.jit(lambda *leaves: impl(*jax.tree.unflatten(tree, leaves),
+                                             **static)),
                 platforms=("tpu",))(
                     *[jax.ShapeDtypeStruct(a.shape, a.dtype)
-                      for a in operands])
+                      for a in leaves])
             try:
                 os.makedirs(directory, exist_ok=True)
                 with open(f"{path}.{os.getpid()}", "wb") as f:
@@ -519,7 +523,7 @@ def _kernel(impl, x, y, gr, **static):
             except OSError:
                 pass
         _EXPORTED[key] = exported
-    return exported.call(*operands)
+    return exported.call(*leaves)
 
 
 # --- the differentiable op -------------------------------------------------
@@ -546,7 +550,7 @@ def grouped_matmul(rows, w, gr, plan, interpret=False):
     ``plan`` (a ``Plan``). ``interpret`` runs the kernels in Pallas's
     interpreter (tests on the CPU)."""
     def kernel(rows, w, gr):
-        return _kernel(_gmm, rows, w, gr, tm=plan.tm, panel=plan.tn,
+        return _kernel(_gmm, (rows, w, gr), tm=plan.tm, panel=plan.tn,
                        transposed=False, vmem_limit=plan.vmem_limit,
                        interpret=interpret)
 
@@ -562,9 +566,9 @@ def _bwd(plan, interpret, res, g):
     kw = dict(vmem_limit=plan.vmem_limit, interpret=interpret)
 
     def kernels(rows, w, gr, g):
-        return (_kernel(_gmm, g, w, gr, tm=plan.tm, panel=plan.tk,
+        return (_kernel(_gmm, (g, w, gr), tm=plan.tm, panel=plan.tk,
                         transposed=True, **kw),
-                _kernel(_tgmm, rows, g, gr, tm=plan.tmw, panel=plan.tw,
+                _kernel(_tgmm, (rows, g, gr), tm=plan.tmw, panel=plan.tw,
                         **kw).astype(w.dtype))
 
     def other(rows, w, gr, g):

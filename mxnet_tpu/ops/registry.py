@@ -59,6 +59,11 @@ class OpMode:
     # arrives channels-last and the op must lower channels-last (set only
     # for layout-aware ops — see ops/layout.py); None = logical NCHW
     layout: str = None
+    # the platform the program is lowered for ("tpu", "cpu"), where the
+    # caller knows it (an executor: its context's); None = ask the operands
+    # or jax's default backend. Read by ops whose lowering is per platform
+    # (RingAttention's Pallas kernels)
+    platform: str = None
 
 
 class Param:

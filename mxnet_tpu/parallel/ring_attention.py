@@ -13,10 +13,15 @@ Numerics: online softmax (running max + normaliser) in f32 regardless of
 input dtype, exact to within reordering — validated against full attention
 in tests/test_ring_attention.py on the 8-device CPU mesh.
 
-Without a mesh the same online-softmax step (:func:`_softmax_block`) runs
-over blocks of queries on one device (:func:`blockwise_attention`): no
-``(T, T)`` score tensor is ever held or saved for backward, and a causal
-block reads only the keys at or before its own end.
+Without a mesh the same online softmax runs over blocks of queries on one
+device (:func:`blockwise_attention`): no ``(T, T)`` score tensor is ever held
+or saved for backward, and a causal block reads only the keys at or before
+its own end. Where the rule (:func:`kernel_plan`: one TPU, a bfloat16 trunk,
+a head dim 128 divides, T a multiple of a block) says so, its forward and
+backward are the Pallas kernels of ``ops/flash_attention.py``, in which a
+score tile lives only in VMEM; otherwise (the CPU, float32, odd shapes)
+:func:`_softmax_block` in ``jax.numpy``, whose float32 score tiles XLA
+writes to HBM. The ring path is ``jax.numpy`` on every platform.
 """
 
 from __future__ import annotations
@@ -104,7 +109,9 @@ def _ring_attn_shard(q, k, v, axis_name, causal, scale):
 
 
 BLOCK_Q = 512  # queries a block: its score tile is (B, H, 512, <= T) float32
-# A block's float32 score tile is written once and re-read by each of the
+# Sizes the blocks of the ``jax.numpy`` fall-back only (the kernels keep a
+# tile in VMEM and take their tiles from ``flash_attention.plan``). There a
+# block's float32 score tile is written once and re-read by each of the
 # softmax's element-wise passes. On a v5e (128 MiB of fast memory) a tile of
 # 160 MiB made a window layer's forward 5.09 ms at 32 heads, T 4096, and one
 # of 72 MiB 1.64 ms (blocks of 512 / 256 queries; forward + backward 10.8 /
@@ -140,8 +147,8 @@ def block_plan(T, block_q, causal, window=0):
 
 def scored_pairs(T, causal, window=0, block_q=BLOCK_Q):
     """Query-key pairs one head scores under :func:`block_plan`: the sizes
-    of the score tiles the blockwise path computes, forward (its backward
-    recomputes the same tiles)."""
+    of the score tiles the ``jax.numpy`` blocks compute, forward (their
+    backward recomputes the same tiles)."""
     return sum((b - a) * (end - first)
                for a, b, first, end in block_plan(T, block_q, causal, window))
 
@@ -182,27 +189,86 @@ def _group_mask(mask, group):
     return mask if mask is None or group == 1 else jnp.tile(mask, (group, 1))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q, window=0):
-    """softmax(q k^T * scale [+ causal mask]) v on one device, a block of
-    ``block_q`` queries at a time; q (B, H, T, D), k and v (B, Hkv, T, D)
-    with Hkv dividing H (query head n reads key/value head n // (H / Hkv)),
-    output in their dtype. ``window`` (causal only): a query reads only the
-    ``window`` keys that end at itself, and a block only the key blocks its
-    band touches (:func:`block_plan`). Memory is linear in T, forward and
-    backward: the backward pass keeps q, k, v, the output and the rows'
-    log-sum-exp, and recomputes each block's scores from them."""
-    return _blockwise_fwd(q, k, v, causal, scale, block_q, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q, window=0,
+                        kernels=None, interpret=False):
+    """softmax(q k^T * scale [+ causal mask]) v on one device; q (B, H, T,
+    D), k and v (B, Hkv, T, D) with Hkv dividing H (query head n reads
+    key/value head n // (H / Hkv)), output in their dtype. ``window``
+    (causal only): a query reads only the ``window`` keys that end at
+    itself, and only the key blocks its band touches are visited. Memory is
+    linear in T, forward and backward: the backward pass keeps q, k, v, the
+    output and the rows' log-sum-exp, and recomputes the scores from them.
+
+    ``kernels`` (a ``flash_attention.Plan``, from the rule
+    :func:`kernel_plan`): the Pallas kernels, whose score tiles never leave
+    VMEM, in a program lowered for a TPU; None: ``jax.numpy`` blocks of
+    ``block_q`` queries (:func:`block_plan`). ``interpret`` runs the
+    kernels in Pallas's interpreter (tests on the CPU)."""
+    return _blockwise_fwd(q, k, v, causal, scale, block_q, window, kernels,
+                          interpret)[0]
 
 
-def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0):
+def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None):
+    """The rule of the one-device path: the kernels' tiles
+    (``ops/flash_attention.plan``) for queries ``q_shape`` (B, H, T, D) of
+    ``dtype`` over ``kv_heads`` in a program lowered for ``platform`` (the
+    executor's, through ``OpMode.platform``; None: jax's default backend)
+    in a process that holds one TPU, or None: the ``jax.numpy`` blocks (the
+    CPU, several chips, float32, a head dim 128 does not divide, T no
+    multiple of a block). The op and the executor's counters ask it with
+    the same arguments. A bare traced call (no executor, ``platform`` None)
+    assumes the default backend: a plain ``jax.jit`` for the CPU in a
+    process that holds a TPU has to say ``platform="cpu"``, or it traces
+    Mosaic calls."""
+    from ..ops import flash_attention, grouped_matmul
+
+    _, heads, T, D = q_shape
+    return flash_attention.plan(
+        platform or jax.default_backend(),
+        grouped_matmul.attached_vmem_bytes(), dtype, heads, kv_heads, T, D,
+        causal, window)
+
+
+def pairs_scored(q_shape, causal, window=0, kernels=None):
+    """Query-key pairs a layer scores, forward (backward recomputes the
+    same tiles): the tiles the kernels visit under ``kernels``, else the
+    ``jax.numpy`` blocks' (:func:`scored_pairs`), x heads x batch."""
+    batch, heads, T, _ = q_shape
+    if kernels is not None:
+        from ..ops import flash_attention
+
+        one_head = flash_attention.scored_pairs(T, kernels.bq, kernels.bk,
+                                                causal, window)
+    else:
+        one_head = scored_pairs(T, causal, window,
+                                block_q_of(batch, heads, T, window))
+    return batch * heads * one_head
+
+
+def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0, kernels=None,
+                   interpret=False):
     if window and not causal:
         raise MXNetError("attention: window needs causal=True")
-    B, H, T, D = q.shape
-    kv = k.shape[1]
+    H, kv = q.shape[1], k.shape[1]
     if H % kv or v.shape[1] != kv:
         raise MXNetError(f"attention: {H} query heads over {kv} key and "
                          f"{v.shape[1]} value heads")
+
+    if kernels is None:
+        out, lse = _blocks_fwd(q, k, v, causal, scale, block_q, window)
+    else:
+        from ..ops import flash_attention
+
+        out, lse = flash_attention.attention(q, k, v, kernels, scale, causal,
+                                             window, interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _blocks_fwd(q, k, v, causal, scale, block_q, window):
+    """(out, log-sum-exp (B, H, T) float32) by ``jax.numpy`` blocks."""
+    B, H, T, D = q.shape
+    kv = k.shape[1]
     group = H // kv
     outs, lses = [], []
     for a, b, first, end, mask in _q_blocks(T, block_q, causal, window):
@@ -215,14 +281,22 @@ def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0):
             jnp.zeros((B, kv, rows), jnp.float32))
         outs.append(_unfold((o / l[..., None]).astype(q.dtype), H))
         lses.append((m + jnp.log(l)).reshape(B, H, b - a))
-    out = jnp.concatenate(outs, axis=2)
-    return out, (q, k, v, out, jnp.concatenate(lses, axis=2))
+    return jnp.concatenate(outs, axis=2), jnp.concatenate(lses, axis=2)
 
 
-def _blockwise_bwd(causal, scale, block_q, window, res, d_out):
+def _blockwise_bwd(causal, scale, block_q, window, kernels, interpret, res,
+                   d_out):
+    if kernels is None:
+        return _blocks_bwd(causal, scale, block_q, window, *res, d_out)
+    from ..ops import flash_attention
+
+    return flash_attention.attention_grads(*res, d_out, kernels, scale, causal,
+                                           window, interpret)
+
+
+def _blocks_bwd(causal, scale, block_q, window, q, k, v, out, lse, d_out):
     from ..ops.defs_tensor import matmul_precision
 
-    q, k, v, out, lse = res
     prec = matmul_precision(q.dtype)
     f32 = jnp.float32
     H, kv = q.shape[1], k.shape[1]
@@ -292,9 +366,7 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
 
     if mesh is None:
-        out = blockwise_attention(
-            q, k, v, causal, scale,
-            block_q_of(q.shape[0], q.shape[1], q.shape[2], window), window)
+        out = _on_one_device(q, k, v, causal, scale, window)
         return NDArray(out) if wrap else out
     _refuse_on_the_ring(q, k, window)
 
@@ -327,14 +399,29 @@ def _ring_spec(axis, batch_axis):
     return P(batch_axis or None, None, axis, None)
 
 
+def _on_one_device(q, k, v, causal, scale, window, platform=None):
+    """:func:`blockwise_attention` with what the rule and ``block_q_of``
+    say for these operands; ``platform`` None: where a concrete q lives,
+    jax's default backend for a tracer."""
+    if platform is None and not isinstance(q, jax.core.Tracer):
+        platform = next(iter(q.devices())).platform
+    return blockwise_attention(
+        q, k, v, causal, scale,
+        block_q_of(q.shape[0], q.shape[1], q.shape[2], window), window,
+        kernel_plan(q.dtype, q.shape, k.shape[1], causal, window, platform))
+
+
 def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
-                          scale=None, batch_axis=None, window=0):
+                          scale=None, batch_axis=None, window=0,
+                          platform=None):
     """Jit-safe ring attention for use INSIDE a traced program (the
     symbol-level ``_contrib_RingAttention`` op): placement is expressed as
     sharding constraints (not eager ``device_put``) and the ``shard_map``
     nests inside the caller's jit. On a combined mesh (e.g. dp×sp), pass
     ``batch_axis`` so the batch dim keeps its data-parallel sharding
-    instead of being gathered/replicated over the other axes."""
+    instead of being gathered/replicated over the other axes. ``platform``:
+    what the caller's program is lowered for, where it knows
+    (:func:`kernel_plan`)."""
     from jax.sharding import NamedSharding
 
     from .mesh import as_graft
@@ -343,9 +430,7 @@ def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
         scale = 1.0 / math.sqrt(q.shape[-1])
     mesh = getattr(as_graft(mesh), "mesh", None)
     if mesh is None or axis not in mesh.axis_names:
-        return blockwise_attention(
-            q, k, v, causal, scale,
-            block_q_of(q.shape[0], q.shape[1], q.shape[2], window), window)
+        return _on_one_device(q, k, v, causal, scale, window, platform)
     _refuse_on_the_ring(q, k, window)
     if batch_axis is not None and batch_axis not in mesh.axis_names:
         raise MXNetError(f"mesh has no axis {batch_axis!r}")

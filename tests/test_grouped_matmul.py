@@ -403,3 +403,50 @@ def test_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip, matmul):
     # bfloat16 wgrad before its float32 convert
     assert compiled.memory_analysis().temp_size_in_bytes <= e * k * n * 2 \
         + (8 << 20)
+
+
+@pytest.mark.parametrize("layer", ["trinity_window", "trinity_full",
+                                   "olmoe_full", "group_of_7", "group_of_5",
+                                   "group_of_6_window"])
+def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
+                                                                layer):
+    """Mosaic accepts the fused attention kernels
+    (``ops/flash_attention.py``; their other tests are in
+    ``test_flash_attention.py``) at T 4096, head 128: 32 query heads over 4
+    key/value heads with a band of 2048 and without, and 16 over 16; groups
+    that are no power of two (28 over 4, 40 and 48 over 8: the rule's tiles
+    are G x 256 rows); no score tile among the temporaries."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    ra = importlib.import_module("mxnet_tpu.parallel.ring_attention")
+    heads, kv, window = {"trinity_window": (32, 4, 2048),
+                         "trinity_full": (32, 4, 0),
+                         "olmoe_full": (16, 16, 0),
+                         "group_of_7": (28, 4, 0), "group_of_5": (40, 8, 0),
+                         "group_of_6_window": (48, 8, 2048)}[layer]
+    t, d = 4096, 128
+    from mxnet_tpu.ops import flash_attention as fa
+
+    plan = fa.plan("tpu", V5E_VMEM, jnp.bfloat16, heads, kv, t, d, True,
+                   window)
+
+    def step(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v: ra.blockwise_attention(
+                q, k, v, True, d ** -0.5, 256, window, plan), q, k, v)
+        return (out,) + vjp(g)
+
+    def arg(h):
+        return jax.ShapeDtypeStruct((1, h, t, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(arg(heads), arg(kv), arg(kv),
+                                   arg(heads)).compile()
+    text = compiled.as_text()
+    assert "attention_fwd" in text and "attention_bwd" in text
+    # the largest temporary is the rows' float32 delta, not a score tile
+    # (heads x 256 queries x up to 4096 keys x 4 bytes = 32-128 MiB)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 8 << 20
