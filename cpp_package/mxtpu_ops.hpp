@@ -75,6 +75,14 @@ inline Symbol Cast(const std::string& symbol_name, const Symbol& data, const std
   return detail::CreateOp("Cast", symbol_name, 1, keys__, ins__, params__);
 }
 
+inline Symbol CausalConv1D(const std::string& symbol_name, const Symbol& data, int64_t kernel, const Symbol* weight = nullptr) {
+  detail::ParamMap params__;
+  params__.emplace_back("kernel", detail::str(kernel));
+  const char* keys__[] = {"data", "weight"};
+  const Symbol* ins__[] = {&data, weight};
+  return detail::CreateOp("CausalConv1D", symbol_name, 2, keys__, ins__, params__);
+}
+
 inline Symbol Concat(const std::string& symbol_name, const std::vector<const Symbol*>& data, int64_t dim = 1) {
   detail::ParamMap params__;
   params__.emplace_back("dim", detail::str(dim));
@@ -202,6 +210,14 @@ inline Symbol FullyConnected(const std::string& symbol_name, const Symbol& data,
   const char* keys__[] = {"data", "weight", "bias"};
   const Symbol* ins__[] = {&data, weight, bias};
   return detail::CreateOp("FullyConnected", symbol_name, 3, keys__, ins__, params__);
+}
+
+inline Symbol GatedDeltaRule(const std::string& symbol_name, const Symbol& query, const Symbol* key = nullptr, const Symbol* value = nullptr, const Symbol* g = nullptr, const Symbol* beta = nullptr, int64_t chunk = 64) {
+  detail::ParamMap params__;
+  params__.emplace_back("chunk", detail::str(chunk));
+  const char* keys__[] = {"query", "key", "value", "g", "beta"};
+  const Symbol* ins__[] = {&query, key, value, g, beta};
+  return detail::CreateOp("GatedDeltaRule", symbol_name, 5, keys__, ins__, params__);
 }
 
 inline Symbol GridGenerator(const std::string& symbol_name, const Symbol& data, const std::string& target_shape = "(0, 0)", const std::string& transform_type = "affine") {
@@ -452,9 +468,10 @@ inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query,
   return detail::CreateOp("RingAttention", symbol_name, 3, keys__, ins__, params__);
 }
 
-inline Symbol RotaryEmbedding(const std::string& symbol_name, const Symbol& data, double base = 10000.0) {
+inline Symbol RotaryEmbedding(const std::string& symbol_name, const Symbol& data, double base = 10000.0, int64_t rotary_dim = 0) {
   detail::ParamMap params__;
   params__.emplace_back("base", detail::str(base));
+  params__.emplace_back("rotary_dim", detail::str(rotary_dim));
   const char* keys__[] = {"data"};
   const Symbol* ins__[] = {&data};
   return detail::CreateOp("RotaryEmbedding", symbol_name, 1, keys__, ins__, params__);

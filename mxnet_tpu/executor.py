@@ -1445,20 +1445,26 @@ class Executor:
         (``parallel/ring_attention.kernel_plan``) with this executor's
         platform; ``attention_scored_pairs``, the query-key pairs of the
         tiles they visit (``ring_attention.pairs_scored`` x heads x
-        batch). Shapes and types are inferred only where the graph has such
-        a node."""
+        batch); ``linear_attention_layers``, the ``GatedDeltaRule`` nodes,
+        and ``linear_attention_chunks``, the chunks their rows are cut into
+        (batch x T / chunk a layer: the scan's trips; T a layer would mean
+        a token at a time). Shapes and types are inferred only where the
+        graph has such a node."""
         if self._layer_counts is None:
             ops = [n for n in self.graph.topo if not n.is_variable]
             moe = [n for n in ops if n.op.name == "MoE"]
             attention = [n for n in ops if n.op.name == "RingAttention"]
+            linear = [n for n in ops if n.op.name == "GatedDeltaRule"]
             counts = dict.fromkeys((
                 "moe_layers", "moe_assignments", "moe_local_experts",
                 "moe_kernel_matmuls", "attention_layers",
                 "attention_window_layers", "attention_kernel_layers",
-                "attention_scored_pairs"), 0)
-            if moe or attention:
+                "attention_scored_pairs", "linear_attention_layers",
+                "linear_attention_chunks"), 0)
+            if moe or attention or linear:
                 from .ops.defs_transformer import (held_round_rows,
                                                    moe_kernel_matmuls)
+                from .ops.gated_delta import chunks_of
                 from .parallel.ring_attention import (kernel_plan,
                                                       pairs_scored)
 
@@ -1496,6 +1502,11 @@ class Executor:
                     counts["attention_kernel_layers"] += kernels is not None
                     counts["attention_scored_pairs"] += pairs_scored(
                         shape_of[out], p["causal"], p["window"], kernels)
+                for n in linear:
+                    batch, _, T, _ = shape_of[n.name + "_output"]
+                    counts["linear_attention_layers"] += 1
+                    counts["linear_attention_chunks"] += batch * chunks_of(
+                        T, n.params()["chunk"])
             self._layer_counts = counts
         return self._layer_counts
 
@@ -1529,6 +1540,11 @@ class Executor:
         if held["attention_window_layers"]:
             _tm.counter("executor.attention_window_layers").inc(
                 held["attention_window_layers"])
+        if held["linear_attention_layers"]:
+            _tm.counter("executor.linear_attention_layers").inc(
+                held["linear_attention_layers"])
+            _tm.counter("executor.linear_attention_chunks").inc(
+                held["linear_attention_chunks"])
 
     def _make_grad_core(self):
         """Shared fwd+bwd tracing core used by both the plain train_step

@@ -145,8 +145,10 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
     """Analytic forward FLOPs **per sample** for ``symbol``.
 
     Counts Convolution, Deconvolution, FullyConnected, the fused RNN op,
-    RingAttention (a causal one at half its scores) and MoE (the router and
-    the ``top_k`` routed experts, not all of them)
+    RingAttention (a causal one at half its scores), GatedDeltaRule (its
+    recurrent form: read, write and query of a keys x values state a token
+    and value head) and MoE (the router and the ``top_k`` routed experts,
+    not all of them)
     in the published-table convention (one multiply-add = one FLOP, the
     convention behind the ResNet-50 = 4.1 GFLOPs/img figure that bench's
     MFU numbers have used since PR-3); the unrolled LSTM graphs decompose
@@ -174,9 +176,17 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
     for node_id, node in enumerate(nodes):
         op = node["op"]
         if op not in ("Convolution", "Deconvolution", "FullyConnected", "RNN",
-                      "RingAttention", "MoE"):
+                      "RingAttention", "MoE", "GatedDeltaRule"):
             continue
         attrs = node.get("attrs") or {}
+        if op == "GatedDeltaRule":
+            # k (B, Hk, T, Dk), v (B, Hv, T, Dv): S^T k, the rank-1 write
+            # and S^T q are Dk x Dv multiply-adds each
+            k = _node_shape(shape_dict, nodes, node["inputs"][1])
+            v = _node_shape(shape_dict, nodes, node["inputs"][2])
+            if k and v:
+                total += 3.0 * _prod(v) * int(k[3]) / batch
+            continue
         if op == "RingAttention":
             # q (B, H, T, D): q.k and p.v, a causal row sees half the keys
             q = _node_shape(shape_dict, nodes, node["inputs"][0])

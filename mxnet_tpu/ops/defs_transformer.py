@@ -1,6 +1,7 @@
-"""Transformer-block operators: ``RMSNorm``, ``RotaryEmbedding`` and the
-sparse-expert layer ``MoE``. (Attention is ``RingAttention`` in
-``defs_contrib.py``, whose one-device path is blockwise.)
+"""Transformer-block operators: ``RMSNorm``, ``RotaryEmbedding``, the
+sparse-expert layer ``MoE`` and the linear-attention pair ``CausalConv1D``
+and ``GatedDeltaRule`` (``gated_delta.py``). (Attention is ``RingAttention``
+in ``defs_contrib.py``, whose one-device path is blockwise.)
 
 No reference twin: MXNet 0.x has none of them. The equations are those of
 the public OLMoE model (Muennighoff et al. 2024, arXiv:2409.02060; HF
@@ -9,9 +10,10 @@ the grouped matmuls of ``MoE``: Pallas kernels (``grouped_matmul.py``) where
 its rule says they engage, ``jax.lax.ragged_dot`` otherwise.
 
 What is float32 whatever the trunk's dtype: the statistics of ``RMSNorm``,
-the angles and the rotation of ``RotaryEmbedding``, and in ``MoE`` the
-router (logits, softmax, top-k, both regularisers). Outputs come back in
-the dtype of ``data``.
+the angles and the rotation of ``RotaryEmbedding``, in ``MoE`` the
+router (logits, softmax, top-k, both regularisers), and in
+``GatedDeltaRule`` the decays, ``beta``, the chunks' triangular inverse and
+the state. Outputs come back in the dtype of ``data``.
 """
 
 from __future__ import annotations
@@ -24,6 +26,7 @@ import numpy as np
 
 from ..base import (MXNetError, parse_bool, parse_float, parse_int,
                     parse_str)
+from . import gated_delta as _gdr
 from . import grouped_matmul as _gmm
 from .defs_nn import _castp, _prec
 from .registry import Param, register
@@ -55,6 +58,8 @@ register(
 def _rotary(ins, params, mode):
     """Rotate-half rotary position embedding of ``data`` (..., T, D): the
     pair ``(i, i + D/2)`` of position ``t`` turns by ``t * base^(-2i/D)``.
+    With ``rotary_dim`` R (0: the whole head) only the first R of the D
+    turn, as a head of R would, and dims ``[R, D)`` pass through.
 
     The cos/sin tables are made on the host when the op is traced (T and D
     are static) and enter the program as constants: the frequencies in
@@ -64,6 +69,13 @@ def _rotary(ins, params, mode):
     of some thousand radians were off by 6e-3 at T = 4096 (PERF.md, PR 26).
     """
     (x,) = ins
+    if params["rotary_dim"] and params["rotary_dim"] != x.shape[-1]:
+        r = params["rotary_dim"]
+        if r % 2 or not 0 < r < x.shape[-1]:
+            raise MXNetError(f"RotaryEmbedding: rotary_dim {r} of a head of "
+                             f"{x.shape[-1]}")
+        turned = _rotary([x[..., :r]], dict(params, rotary_dim=0), mode)
+        return jnp.concatenate([turned, x[..., r:]], axis=-1)
     t, d = x.shape[-2:]
     half = d // 2
     inv_freq = (params["base"] ** (-np.arange(half, dtype=np.float64) / half)
@@ -82,7 +94,69 @@ register(
     "RotaryEmbedding",
     _rotary,
     arg_names=["data"],
-    param_schema={"base": Param(parse_float, 10000.0)},
+    param_schema={"base": Param(parse_float, 10000.0),
+                  "rotary_dim": Param(parse_int, 0)},  # 0: the whole head
+)
+
+
+# --- CausalConv1D ------------------------------------------------------------
+def _causal_conv1d(ins, params, mode):
+    """Depthwise causal convolution over time of ``data`` (B, T, C),
+    channels last as a projection leaves them: ``y_t[c] = sum_j w[c, j]
+    x_{t-K+1+j}[c]`` with ``x_{<0} = 0``, ``weight`` (C, K), no bias, then
+    SiLU: a linear-attention mixer's short convolution, the activation in
+    float32 before the one rounding. K shifted multiply-adds in float32.
+    The pad is made in ``data``'s dtype and each shifted slice cast where
+    it is used: padding a float32 copy made XLA write the four products to
+    HBM in float32 before adding them (1 x 8192 x 8192 bfloat16 on a v5e,
+    ms forward / forward + backward: 2.58 / 8.05 against 1.02 / 4.23, the
+    same bits; a grouped ``lax.conv_general_dilated`` 3.35 / 13.7; my chip
+    run, PR 34). ``Convolution`` would want (B, C, T) and a group a
+    channel."""
+    x, w = ins
+    taps = w.shape[1]
+    xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    wf = w.astype(jnp.float32)
+    t = x.shape[1]
+    out = sum(xp[:, j:j + t].astype(jnp.float32) * wf[:, j]
+              for j in range(taps))
+    return jax.nn.silu(out).astype(x.dtype)
+
+
+register(
+    "CausalConv1D",
+    _causal_conv1d,
+    arg_names=["data", "weight"],
+    param_schema={"kernel": Param(parse_int)},  # taps, the last at t
+    fill_in_shapes=lambda shapes, p: [
+        shapes[0],
+        shapes[1] or (shapes[0] and (shapes[0][-1], p["kernel"])),
+    ],
+)
+
+
+# --- GatedDeltaRule ----------------------------------------------------------
+def _gated_delta_rule(ins, params, mode):
+    """Linear attention by the gated delta rule (``gated_delta.py`` has the
+    equations and what is float32): ``query``, ``key`` (B, Hk, T, Dk),
+    ``value`` (B, Hv, T, Dv), ``g`` (the log of the decay, <= 0) and
+    ``beta`` (B, Hv, T) -> (B, Hv, T, Dv); value head n reads key head ``n
+    // (Hv / Hk)``. Each head's query and key are first divided by their
+    length (eps 1e-6) and the query by ``sqrt(Dk)``. Computed ``chunk``
+    tokens at a time; the state starts at 0 in every row and is never reset
+    inside one."""
+    q, k, v, g, beta = ins
+    q, k = _gdr.l2_normalize(q), _gdr.l2_normalize(k)
+    q = (q.astype(jnp.float32) * q.shape[-1] ** -0.5).astype(q.dtype)
+    return _gdr.chunk_gated_delta_rule(q, k, v, g, beta,
+                                       chunk=params["chunk"]).astype(v.dtype)
+
+
+register(
+    "GatedDeltaRule",
+    _gated_delta_rule,
+    arg_names=["query", "key", "value", "g", "beta"],
+    param_schema={"chunk": Param(parse_int, 64)},  # tokens, a power of two
 )
 
 
@@ -111,17 +185,22 @@ def _attach_router_losses(logits, routed_share, lb_coef, z_coef):
         return logits
     n, e = logits.shape
 
-    def penalty(z):
-        lb = e * jnp.sum(routed_share * jnp.mean(jax.nn.softmax(z, -1), 0))
+    def penalty(z, share):
+        lb = e * jnp.sum(share * jnp.mean(jax.nn.softmax(z, -1), 0))
         zl = jnp.mean(jax.nn.logsumexp(z, axis=-1) ** 2)
         return n * (lb_coef * lb + z_coef * zl)
 
+    # the share is an argument and not a closure: a traced value the
+    # backward closed over is another trace's under ``jax.checkpoint``
+    # (MXNET_BACKWARD_DO_MIRROR), which runs this forward again
     @jax.custom_vjp
-    def f(z):
+    def f(z, share):
         return z
 
-    f.defvjp(lambda z: (z, z), lambda z, g: (g + jax.grad(penalty)(z),))
-    return f(logits)
+    f.defvjp(lambda z, share: (z, (z, share)),
+             lambda res, g: (g + jax.grad(penalty)(*res),
+                             jnp.zeros_like(res[1])))
+    return f(logits, routed_share)
 
 
 def _expert_plans(platform, vmem_bytes, rows_dtype, w_dtype, m, shapes):

@@ -97,7 +97,8 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
     block of ``_KEY_BLOCKS`` dividing T of which a band holds
     ``_BAND_BLOCKS``; the widest query block of ``_POSITIONS`` dividing T
     whose tile over the group (any group: 7 query heads a key/value head
-    are 7 x 256 rows) has at most ``_ROWS`` rows, else the narrowest.
+    are 7 x 256 rows) has at most ``_ROWS`` rows, else the narrowest; if
+    that does not fit the VMEM, the next narrower query blocks.
     None = the ``jax.numpy`` blocks."""
     if platform != "tpu" or not vmem_bytes:
         return None
@@ -112,12 +113,16 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
               blocks[-1])
     group = heads // kv_heads
     fit = [b for b in _POSITIONS if T % b == 0]   # 128 does: a key block does
-    bq = next((b for b in fit if group * b <= _ROWS), fit[-1])
-    rows = group * bq
-    need = 24 * T * D + 8 * rows * D * 2 + 6 * rows * bk * 4
-    if need > vmem_bytes // 2:
-        return None
-    return Plan(bq, bk, min(vmem_bytes * 3 // 4, need + (16 << 20)))
+    widest = next((b for b in fit if group * b <= _ROWS), fit[-1])
+    # where that tile does not fit beside a long and wide head (T 8192 at
+    # head 256: 50 MB of keys, values and their gradients), the next
+    # narrower query blocks
+    for bq in (b for b in fit if b <= widest):
+        rows = group * bq
+        need = 24 * T * D + 8 * rows * D * 2 + 6 * rows * bk * 4
+        if need <= vmem_bytes // 2:
+            return Plan(bq, bk, min(vmem_bytes * 3 // 4, need + (16 << 20)))
+    return None
 
 
 def visits(T, bq, bk, causal, window=0):

@@ -32,8 +32,9 @@ MASKS = {
 }
 HEADS = {"grouped_8_to_1": (8, 1), "grouped_4_to_2": (4, 2),
          "heads_1_to_1": (2, 2)}
-# 3:1 runs under two masks only (test_kernels_with_a_group_no_power_of_two)
-ALL_HEADS = dict(HEADS, grouped_3_to_1=(3, 1))
+# 3:1 runs under two masks only (test_kernels_with_a_group_no_power_of_two),
+# 16 over 2 at a head of 256 (test_kernels_at_a_head_of_256)
+ALL_HEADS = dict(HEADS, grouped_3_to_1=(3, 1), grouped_16_to_2=(16, 2))
 TENSORS = ["output", "dq", "dk", "dv"]
 
 
@@ -58,7 +59,7 @@ def _whole_matrix(q, k, v, causal, window, scale):
 
 
 @functools.lru_cache(maxsize=None)
-def _three_ways(mask, heads, dtype="bfloat16", bq=128, bk=128):
+def _three_ways(mask, heads, dtype="bfloat16", bq=128, bk=128, d=D):
     """{tensor: (kernels', jax.numpy blocks', oracle's)} as float32 arrays,
     and the kernels' raw dtypes."""
     import jax
@@ -67,9 +68,9 @@ def _three_ways(mask, heads, dtype="bfloat16", bq=128, bk=128):
     causal, window = MASKS[mask]
     H, kv = ALL_HEADS[heads]
     keys = jax.random.split(jax.random.PRNGKey(7), 4)
-    q, k, v, g = (jax.random.normal(key, (1, h, T, D)).astype(dtype)
+    q, k, v, g = (jax.random.normal(key, (1, h, T, d)).astype(dtype)
                   for key, h in zip(keys, (H, kv, kv, H)))
-    scale = D ** -0.5
+    scale = d ** -0.5
     plan = fa.Plan(bq, bk, 32 << 20)
 
     def kernels(q, k, v):
@@ -131,6 +132,22 @@ def test_kernels_with_a_group_no_power_of_two(mask, tensor):
     got, _ = _three_ways(mask, "grouped_3_to_1")
     kernel, blocks, want = got[tensor]
     assert _rel(kernel, blocks) < 2.0 ** -6
+    assert _rel(kernel, want) < 2e-2
+
+
+@pytest.mark.parametrize("tensor", TENSORS)
+@pytest.mark.parametrize("tiles", [(128, 512), (256, 512)])
+def test_kernels_at_a_head_of_256(tiles, tensor):
+    """16 query heads over 2 key/value heads of 256 (8 x 128 or 8 x 256
+    rows a tile, two lane tiles a head), at the tiles the rule gives that
+    shape at T 8192 and 4096."""
+    got, dtypes = _three_ways("causal", "grouped_16_to_2", bq=tiles[0],
+                              bk=tiles[1], d=256)
+    kernel, blocks, want = got[tensor]
+    assert dtypes == ["bfloat16"] * 4
+    assert kernel.shape == want.shape and np.isfinite(kernel).all()
+    assert _rel(kernel, blocks) < 2.0 ** -6
+    assert _rel(kernel, want) < 1.5 * _rel(blocks, want) + 2.0 ** -8
     assert _rel(kernel, want) < 2e-2
 
 
@@ -205,6 +222,14 @@ RULE_CASES = {
         (512, 512)),
     "head_of_256": (
         ("tpu", V5E_VMEM, "bfloat16", 8, 2, 2048, 256, True, 0), (512, 512)),
+    "qwen3_next_full_layer_at_T_4096": (
+        ("tpu", V5E_VMEM, "bfloat16", 16, 2, 4096, 256, True, 0), (256, 512)),
+    # 8 x 256 rows beside 50 MB of keys, values and their gradients are
+    # 84 MB of the 67 allowed: the next narrower query block, at the limit
+    "qwen3_next_full_layer_at_T_8192": (
+        ("tpu", V5E_VMEM, "bfloat16", 16, 2, 8192, 256, True, 0), (128, 512)),
+    "head_of_256_too_long_at_any_tile": (
+        ("tpu", V5E_VMEM, "bfloat16", 16, 2, 16384, 256, True, 0), None),
     "T_only_128_divides": (
         ("tpu", V5E_VMEM, "bfloat16", 16, 16, 128 * 7, 128, True, 0),
         (128, 128)),
@@ -252,6 +277,16 @@ def test_rule_says_where_the_kernels_engage(case):
     assert t % plan.bq == 0 and t % plan.bk == 0
     assert plan.bq in (128, 256, 512)    # Mosaic's tiles; ``& (bq - 1)``
     assert plan.vmem_limit <= vmem * 3 // 4
+
+
+@pytest.mark.parametrize("layer,plan", [
+    ("trinity_window_layer_on_a_v5e", fa.Plan(256, 256, 46137344)),
+    ("trinity_full_layer_on_a_v5e", fa.Plan(256, 512, 58720256)),
+    ("olmoe_layer_on_a_v5e", fa.Plan(512, 512, 36700160))])
+def test_accepted_cells_plans_are_what_they_were(layer, plan):
+    """The tiles and the VMEM limit PR 33 measured the two accepted cells
+    at: a rule that finds tiles for a new shape leaves these alone."""
+    assert fa.plan(*RULE_CASES[layer][0]) == plan
 
 
 @pytest.mark.parametrize("group", range(1, 17))

@@ -407,7 +407,8 @@ def test_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip, matmul):
 
 @pytest.mark.parametrize("layer", ["trinity_window", "trinity_full",
                                    "olmoe_full", "group_of_7", "group_of_5",
-                                   "group_of_6_window"])
+                                   "group_of_6_window", "qwen3_next_4k",
+                                   "qwen3_next_8k"])
 def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
                                                                 layer):
     """Mosaic accepts the fused attention kernels
@@ -415,19 +416,25 @@ def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
     ``test_flash_attention.py``) at T 4096, head 128: 32 query heads over 4
     key/value heads with a band of 2048 and without, and 16 over 16; groups
     that are no power of two (28 over 4, 40 and 48 over 8: the rule's tiles
-    are G x 256 rows); no score tile among the temporaries."""
+    are G x 256 rows); 16 over 2 heads of 256 at T 4096 and 8192 (the
+    Qwen3-Next cell's full layer: 8 x 128 rows a tile at 8192, where the
+    rule's count is exactly the half of the VMEM it allows); no score tile
+    among the temporaries."""
     import importlib
 
     import jax
     import jax.numpy as jnp
 
     ra = importlib.import_module("mxnet_tpu.parallel.ring_attention")
-    heads, kv, window = {"trinity_window": (32, 4, 2048),
-                         "trinity_full": (32, 4, 0),
-                         "olmoe_full": (16, 16, 0),
-                         "group_of_7": (28, 4, 0), "group_of_5": (40, 8, 0),
-                         "group_of_6_window": (48, 8, 2048)}[layer]
-    t, d = 4096, 128
+    heads, kv, window, t, d = {
+        "trinity_window": (32, 4, 2048, 4096, 128),
+        "trinity_full": (32, 4, 0, 4096, 128),
+        "olmoe_full": (16, 16, 0, 4096, 128),
+        "group_of_7": (28, 4, 0, 4096, 128),
+        "group_of_5": (40, 8, 0, 4096, 128),
+        "group_of_6_window": (48, 8, 2048, 4096, 128),
+        "qwen3_next_4k": (16, 2, 0, 4096, 256),
+        "qwen3_next_8k": (16, 2, 0, 8192, 256)}[layer]
     from mxnet_tpu.ops import flash_attention as fa
 
     plan = fa.plan("tpu", V5E_VMEM, jnp.bfloat16, heads, kv, t, d, True,
@@ -449,4 +456,5 @@ def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
     assert "attention_fwd" in text and "attention_bwd" in text
     # the largest temporary is the rows' float32 delta, not a score tile
     # (heads x 256 queries x up to 4096 keys x 4 bytes = 32-128 MiB)
+    assert plan is not None
     assert compiled.memory_analysis().temp_size_in_bytes <= 8 << 20
