@@ -1446,9 +1446,13 @@ class Executor:
         platform; ``attention_scored_pairs``, the query-key pairs of the
         tiles they visit (``ring_attention.pairs_scored`` x heads x
         batch); ``linear_attention_layers``, the ``GatedDeltaRule`` nodes,
-        and ``linear_attention_chunks``, the chunks their rows are cut into
+        ``linear_attention_chunks``, the chunks their rows are cut into
         (batch x T / chunk a layer: the scan's trips; T a layer would mean
-        a token at a time). Shapes and types are inferred only where the
+        a token at a time), and ``linear_attention_kernel_layers``, those
+        whose chunk-local algebra a train program runs in the Pallas
+        kernels, asked of the rule the op follows
+        (``ops/gated_delta.kernel_plan``) with this executor's platform.
+        Shapes and types are inferred only where the
         graph has such a node."""
         if self._layer_counts is None:
             ops = [n for n in self.graph.topo if not n.is_variable]
@@ -1460,11 +1464,13 @@ class Executor:
                 "moe_kernel_matmuls", "attention_layers",
                 "attention_window_layers", "attention_kernel_layers",
                 "attention_scored_pairs", "linear_attention_layers",
-                "linear_attention_chunks"), 0)
+                "linear_attention_chunks", "linear_attention_kernel_layers"),
+                0)
             if moe or attention or linear:
                 from .ops.defs_transformer import (held_round_rows,
                                                    moe_kernel_matmuls)
-                from .ops.gated_delta import chunks_of
+                from .ops.gated_delta import (chunks_of,
+                                              kernel_plan as delta_kernel_plan)
                 from .parallel.ring_attention import (kernel_plan,
                                                       pairs_scored)
 
@@ -1503,10 +1509,17 @@ class Executor:
                     counts["attention_scored_pairs"] += pairs_scored(
                         shape_of[out], p["causal"], p["window"], kernels)
                 for n in linear:
-                    batch, _, T, _ = shape_of[n.name + "_output"]
+                    value = shape_of[n.name + "_output"]
+                    batch, _, T, _ = value
+                    chunk = n.params()["chunk"]
+                    query, key = type(internals)(
+                        [n.inputs[0], n.inputs[1]]).list_outputs()
                     counts["linear_attention_layers"] += 1
                     counts["linear_attention_chunks"] += batch * chunks_of(
-                        T, n.params()["chunk"])
+                        T, chunk)
+                    counts["linear_attention_kernel_layers"] += \
+                        delta_kernel_plan(dtype_of[query], shape_of[key],
+                                          value, chunk, platform) is not None
             self._layer_counts = counts
         return self._layer_counts
 
@@ -1545,6 +1558,9 @@ class Executor:
                 held["linear_attention_layers"])
             _tm.counter("executor.linear_attention_chunks").inc(
                 held["linear_attention_chunks"])
+        if held["linear_attention_kernel_layers"]:
+            _tm.counter("executor.linear_attention_kernel_layers").inc(
+                held["linear_attention_kernel_layers"])
 
     def _make_grad_core(self):
         """Shared fwd+bwd tracing core used by both the plain train_step

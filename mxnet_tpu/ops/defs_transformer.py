@@ -144,12 +144,17 @@ def _gated_delta_rule(ins, params, mode):
     // (Hv / Hk)``. Each head's query and key are first divided by their
     length (eps 1e-6) and the query by ``sqrt(Dk)``. Computed ``chunk``
     tokens at a time; the state starts at 0 in every row and is never reset
-    inside one."""
+    inside one. Where the rule says so (``gated_delta.kernel_plan``, asked
+    with the platform the program is lowered for) the chunk-local algebra
+    runs in Pallas kernels."""
     q, k, v, g, beta = ins
     q, k = _gdr.l2_normalize(q), _gdr.l2_normalize(k)
     q = (q.astype(jnp.float32) * q.shape[-1] ** -0.5).astype(q.dtype)
-    return _gdr.chunk_gated_delta_rule(q, k, v, g, beta,
-                                       chunk=params["chunk"]).astype(v.dtype)
+    kernels = _gdr.kernel_plan(q.dtype, k.shape, v.shape, params["chunk"],
+                               mode.platform)
+    return _gdr.chunk_gated_delta_rule(
+        q, k, v, g, beta, chunk=params["chunk"],
+        kernels=kernels).astype(v.dtype)
 
 
 register(

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from . import gated_delta_kernels, grouped_matmul
 from .defs_tensor import matmul_precision
 
 _HIGHEST = lax.Precision.HIGHEST
@@ -173,14 +174,37 @@ def chunks_of(T, chunk):
     return -(-T // chunk)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64):
+def kernel_plan(dtype, k_shape, v_shape, chunk, platform=None):
+    """The rule of the chunk-local algebra: the kernels' block
+    (``gated_delta_kernels.plan``) for keys ``k_shape`` (B, Hk, T, Dk) and
+    values ``v_shape`` (B, Hv, T, Dv) of ``dtype`` in a program lowered for
+    ``platform`` (the executor's, through ``OpMode.platform``; None: jax's
+    default backend) in a process that holds one TPU, or None: the
+    ``jax.numpy`` form (the CPU, several chips, a float32 trunk, a head
+    width 128 does not divide, another chunk). The op and the executor's
+    counter ask it with the same arguments."""
+    _, Hk, T, Dk = k_shape
+    return gated_delta_kernels.plan(
+        platform or jax.default_backend(),
+        grouped_matmul.attached_vmem_bytes(), dtype, Dk, v_shape[3],
+        v_shape[1] // Hk, chunk, T)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "kernels", "interpret"))
+def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, kernels=None,
+                           interpret=False):
     """o (B, Hv, T, Dv) in v's dtype: the gated delta rule of q, k (B, Hk,
     T, Dk), v (B, Hv, T, Dv) and g, beta (B, Hv, T), value head n reading
     key head ``n // (Hv / Hk)``, in chunks of ``chunk`` tokens (a power of
     two). q arrives scaled and, like k, normalised if the model does so. A
     T that is no multiple of ``chunk`` is padded with alpha = 1, beta = 0:
-    tokens that write nothing and fade nothing."""
+    tokens that write nothing and fade nothing.
+
+    ``kernels`` (a ``gated_delta_kernels.Plan``, from the rule
+    :func:`kernel_plan`): the chunk-local algebra in the Pallas kernels, T
+    padded to their whole blocks of chunks; None: ``_within_chunks``.
+    ``interpret`` runs the kernels in Pallas's interpreter (tests on the
+    CPU)."""
     if chunk & (chunk - 1):
         raise ValueError(f"gated delta rule: chunk {chunk} is no power of "
                          "two")
@@ -189,7 +213,9 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64):
     if Hv % Hk:
         raise ValueError(f"gated delta rule: {Hv} value heads over {Hk} "
                          "key heads")
-    G, N = Hv // Hk, chunks_of(T, chunk)
+    G, N = Hv // Hk, chunks_of(
+        T if kernels is None
+        else gated_delta_kernels.padded(T, chunk, kernels), chunk)
     pad = N * chunk - T
     k = k.astype(q.dtype)
     v = v.astype(q.dtype)
@@ -205,13 +231,18 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64):
         c = jnp.cumsum(g.reshape(B, Hk, G, N, chunk), axis=-1)
         beta = beta.reshape(B, Hk, G, N, chunk)
         with jax.named_scope("within_chunks"):
-            u, w = jax.checkpoint(
-                _within_chunks,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    _INVERSE))(k, v, c, beta)
-        # the chunk axis first: what the scan walks
+            if kernels is None:
+                u, w = jax.checkpoint(
+                    _within_chunks,
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        _INVERSE))(k, v, c, beta)
+            else:
+                u, w = gated_delta_kernels.within_chunks(
+                    k, v, c, beta, kernels, interpret)
+        # the chunk axis first: what the scan walks (the kernels write it so)
         chunks = (jnp.moveaxis(q, 2, 0), jnp.moveaxis(k, 2, 0),
-                  jnp.moveaxis(u, 3, 0), jnp.moveaxis(w, 3, 0),
+                  *((jnp.moveaxis(u, 3, 0), jnp.moveaxis(w, 3, 0))
+                    if kernels is None else (u, w)),
                   jnp.moveaxis(c, 3, 0))
         state = jnp.zeros((B, Hk, G, Dk, Dv), jnp.float32)
         with jax.named_scope("across_chunks"):

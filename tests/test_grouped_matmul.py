@@ -458,3 +458,42 @@ def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
     # (heads x 256 queries x up to 4096 keys x 4 bytes = 32-128 MiB)
     assert plan is not None
     assert compiled.memory_analysis().temp_size_in_bytes <= 8 << 20
+
+
+def test_gated_delta_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip):
+    """Mosaic accepts the kernels of the gated delta rule's chunk-local
+    algebra (``ops/gated_delta_kernels.py``; their other tests are in
+    ``test_gated_delta_kernels.py``) at the Qwen3-Next cell's widths: T
+    8192 in chunks of 64, 16 key over 32 value heads of 128, at the rule's
+    block; between forward and backward nothing is kept but the chunks'
+    inverses, pairs of chunks side by side with no lane padded (T x 64
+    float32 a value head)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import gated_delta_kernels as gk
+
+    b, hk, g, t, d, c = 1, 16, 2, 8192, 128, 64
+    n = t // c
+    plan = gk.plan("tpu", V5E_VMEM, jnp.bfloat16, d, d, g, c, t)
+    assert plan is not None and n % plan.chunks == 0
+
+    def step(k, v, cum, beta, du, dw):
+        out, vjp = jax.vjp(lambda *a: gk.within_chunks(*a, plan), k, v, cum,
+                           beta)
+        return out + vjp((du, dw))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        arg((b, hk, n, c, d), jnp.bfloat16),
+        arg((b, hk, g, n, c, d), jnp.bfloat16),
+        arg((b, hk, g, n, c), jnp.float32), arg((b, hk, g, n, c), jnp.float32),
+        arg((n, b, hk, g, c, d), jnp.float32),
+        arg((n, b, hk, g, c, d), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "gated_delta_chunks_fwd" in text
+    assert "gated_delta_chunks_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= b * hk * g * t * c * 4 + (1 << 20)
