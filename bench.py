@@ -317,11 +317,31 @@ def _stamp_device_recipe(record, mx, models, on_tpu, dtype):
     record["recipe"] = models.recipe.recipe_name(dtype)
 
 
+def _kernel_rows(mx, top=10):
+    """The traced device time as the ``kernels`` rows
+    ``tools/bench_compare.py`` reads (``name``, ``device_us``, ``calls``,
+    ``pct``, ``bytes``): ``profiler.device_table`` of the profile
+    directory of the trace just dumped, one row per (operator, pass), and
+    what carries no scope by its XLA kind."""
+    table = mx.profiler.device_table()
+    rows = [dict(r, name=f"{r['operator']} {r['pass']}")
+            for r in table["by_operator"] if r["operator"] != "unscoped"]
+    rows += table["unscoped"]
+    out = []
+    for r in sorted(rows, key=lambda r: -r["ms"])[:top]:
+        row = {"name": r["name"], "device_us": round(r["ms"] * 1e3, 1),
+               "calls": r["calls"], "pct": round(r["share"], 4)}
+        if "bytes" in r:
+            row["bytes"] = int(r["bytes"])
+        out.append(row)
+    return out
+
+
 def _kernel_attribution(mx, mod, batch, k=2):
     """Top-10 per-kernel device-time table for one steady-state train
     window of ``mod``: traced AFTER the timed region (attribution never
-    pollutes the measurement) with the jax device profiler and aggregated
-    by telemetry.kernel_table. Returns [] when the profiler is
+    pollutes the measurement) with the jax device profiler and read back
+    by ``_kernel_rows``. Returns [] when the profiler is
     unavailable; BENCH_KERNELS=0 skips the extra window entirely. The
     caller's timed loop just ran the same (shapes, K) program, so the
     traced window executes warm — no compile lands in the timeline."""
@@ -336,7 +356,7 @@ def _kernel_attribution(mx, mod, batch, k=2):
         mx.profiler.profiler_set_state("run")
         mod.train_window(batch, k, publish_grads=False).wait()
         trace = mx.profiler.dump_profile()
-        return mx.telemetry.kernel_table(trace) if trace else []
+        return _kernel_rows(mx) if trace else []
     except Exception as e:
         print(f"kernel attribution skipped: {e}", file=sys.stderr)
         return []
@@ -1482,8 +1502,7 @@ def main():
             record["telemetry_snapshot"] = snap_path
             # attribute per-kernel device time straight off the timeline
             # the run already paid for
-            record["kernels"] = mx.telemetry.kernel_table(trace) \
-                if trace else []
+            record["kernels"] = _kernel_rows(mx) if trace else []
             print(f"trace: {trace}  snapshot: {snap_path} {prom_path}",
                   file=sys.stderr)
         if "kernels" not in record or not record["kernels"]:

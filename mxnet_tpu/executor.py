@@ -108,6 +108,17 @@ def _split_out(vals, fill):
     return out, jnp.concatenate(segs)
 
 
+def _phase(name):
+    """The scope one of the executor's own phases lowers under
+    (``profiler.phase_scope``: the trace reader books its device time to
+    the phase, beside the graph's operators)."""
+    import jax
+
+    from . import profiler as _prof
+
+    return jax.named_scope(_prof.phase_scope(name))
+
+
 class _Packs(NamedTuple):
     """The small-parameter packs as an executor's programs see them: the
     static ``(index, offset, size, shape)`` fills of its argument and
@@ -122,8 +133,9 @@ class _Packs(NamedTuple):
 def _unpack(packs, arg_vals, arg_flat, aux_vals, aux_flat):
     """Every program's prologue: the full argument and auxiliary lists
     from what crossed the program boundary."""
-    return (_fill_packed(arg_vals, arg_flat, packs.arg_fill),
-            _fill_packed(aux_vals, aux_flat, packs.aux_fill))
+    with _phase("unpack"):
+        return (_fill_packed(arg_vals, arg_flat, packs.arg_fill),
+                _fill_packed(aux_vals, aux_flat, packs.aux_fill))
 
 
 def _repack(packs, aux_upd, grad_map=None):
@@ -133,13 +145,14 @@ def _repack(packs, aux_upd, grad_map=None):
     import jax.numpy as jnp
 
     grad_flat = None
-    if grad_map is not None and packs.grad_names:
-        grad_map = dict(grad_map)
-        grad_flat = jnp.concatenate([
-            grad_map.pop(n).astype(jnp.float32).ravel()
-            for n in packs.grad_names
-        ])
-    aux_big, aux_flat = _split_out(aux_upd, packs.aux_fill)
+    with _phase("repack"):
+        if grad_map is not None and packs.grad_names:
+            grad_map = dict(grad_map)
+            grad_flat = jnp.concatenate([
+                grad_map.pop(n).astype(jnp.float32).ravel()
+                for n in packs.grad_names
+            ])
+        aux_big, aux_flat = _split_out(aux_upd, packs.aux_fill)
     return grad_map, grad_flat, aux_big, aux_flat
 
 
@@ -314,8 +327,9 @@ def _window_of(step, n_steps, stack_pos, publish):
                 heads, prev_grads, st_leaves, st_flat, hyper, guard, stacks):
         def sub_data(i, ov):
             ov = list(ov)
-            for p, s in zip(stack_pos, stacks):
-                ov[p] = lax.dynamic_index_in_dim(s, i, 0, keepdims=False)
+            with _phase("window_data"):
+                for p, s in zip(stack_pos, stacks):
+                    ov[p] = lax.dynamic_index_in_dim(s, i, 0, keepdims=False)
             return ov
 
         def body(i, carry):
@@ -464,6 +478,8 @@ class _CompiledGraph:
                 self._rng_serial[id(node)] = serial
                 serial += 1
         self.num_rng_ops = serial
+        # op nodes the last ``evaluate`` lowered under a scope of their own
+        self.scoped_nodes = 0
 
     def shared_fc_groups(self):
         """[(weight name, nodes)] of the ``FullyConnected`` nodes, two or
@@ -503,9 +519,11 @@ class _CompiledGraph:
         caller passes it."""
         import jax
 
+        from . import profiler as _prof
         from .ops import layout as _lay
 
         nhwc = self.layout == "NHWC"
+        scoped = 0  # op nodes lowered under their own scope
         env = {}
         cl = {}  # id(node) -> per-output channels-last flags (NHWC mode)
         aux_updates = list(aux_vals)
@@ -586,20 +604,26 @@ class _CompiledGraph:
             if node.op.need_rng:
                 node_rng = jax.random.fold_in(rng, self._rng_serial[id(node)])
             op_layout = "NHWC" if node_layout == "NHWC" else None
-            if self.remat and not node.op.aux_names(params):
-                apply_fn = jax.checkpoint(
-                    lambda inner, _op=node.op, _p=params, _m=OpMode(
-                        is_train=is_train, rng=node_rng, layout=op_layout,
-                        platform=self.platform,
-                    ): _op.apply(inner, _p, _m)
-                )
-                outs, new_aux = apply_fn(ins)
-            else:
-                outs, new_aux = node.op.apply(
-                    ins, params,
-                    OpMode(is_train=is_train, rng=node_rng, layout=op_layout,
-                           platform=self.platform),
-                )
+            # the node's name on everything it lowers (forward, its
+            # transpose, its recomputation): what the trace reader books
+            # device time by (profiler.parse_scope)
+            scoped += len(group) if group else 1
+            with jax.named_scope(_prof.node_scope(
+                    node.op.name, node.name, len(group) if group else 0)):
+                if self.remat and not node.op.aux_names(params):
+                    apply_fn = jax.checkpoint(
+                        lambda inner, _op=node.op, _p=params, _m=OpMode(
+                            is_train=is_train, rng=node_rng,
+                            layout=op_layout, platform=self.platform,
+                        ): _op.apply(inner, _p, _m)
+                    )
+                    outs, new_aux = apply_fn(ins)
+                else:
+                    outs, new_aux = node.op.apply(
+                        ins, params,
+                        OpMode(is_train=is_train, rng=node_rng,
+                               layout=op_layout, platform=self.platform),
+                    )
             if group:
                 for i, n in enumerate(group[1:], 1):
                     env[id(n)] = [outs[0][i]]
@@ -632,6 +656,7 @@ class _CompiledGraph:
                         o = _lay.from_cl(o)  # monitors see logical layout
                     suffix = "_output" if i == 0 else f"_output{i}"
                     monitor(node.name + suffix, o)
+        self.scoped_nodes = scoped
         if limit is not None:
             if nhwc and last_cl:
                 last_outs = [
@@ -727,6 +752,8 @@ class Executor:
         self._fc_plan = None  # memoized _shared_fc_plan
         self._grads_crowd = None  # memoized _grads_crowd_device
         self._layer_counts = None  # memoized _transformer_layers
+        # op nodes the train programs' trace lowered under a scope
+        self._scoped_nodes = 0
         if shared_exec is not None:
             # bucketing: share compiled-function cache and memory with the
             # master executor (reference shared_exec data_pool_ reuse,
@@ -1524,9 +1551,14 @@ class Executor:
         return self._layer_counts
 
     def _count_train_launch(self):
-        """One launch of a train program, counted by what it holds: the
-        shared weights whose gradient it computes as one matmul, and its
+        """One launch of a train program, counted by what it holds: the op
+        nodes its trace lowered under their own scope (0: a path evaluates
+        the graph with no names, or the executable came from the
+        ``MXNET_AOT_CACHE`` store and was not traced here), the shared
+        weights whose gradient it computes as one matmul, and its
         sparse-expert and attention layers (``_transformer_layers``)."""
+        if self._scoped_nodes:
+            _tm.counter("executor.scoped_nodes").inc(self._scoped_nodes)
         weights = self._shared_fc_plan()[2]
         if weights:
             _tm.counter("executor.stacked_wgrad").inc(weights)
@@ -1595,6 +1627,7 @@ class Executor:
                     full[i] = v
                 outs, aux_upd = graph.evaluate(full, aux_vals, key, True,
                                                fc_batches=fc_batches)
+                self._scoped_nodes = graph.scoped_nodes
                 total = None
                 for j, o in enumerate(outs):
                     if not jnp.issubdtype(o.dtype, jnp.floating):
@@ -1620,8 +1653,9 @@ class Executor:
             wrt_vals = [arg_vals[i] for i in wrt_idx]
             grads, (outs, aux_upd) = jax.grad(loss_fn, has_aux=True)(wrt_vals)
             grad_map = dict(zip(wrt_names, grads))
-            for n in add_names:
-                grad_map[n] = grad_map[n] + prev_grads[n]
+            with _phase("accumulate"):
+                for n in add_names:
+                    grad_map[n] = grad_map[n] + prev_grads[n]
             return outs, aux_upd, grad_map
 
         return core
@@ -1911,6 +1945,8 @@ class Executor:
         same prologue and epilogue as the other two programs."""
         import jax
 
+        from . import profiler as _prof
+
         core = self._make_grad_core()
         packs = self._packs()
         packed_args = set(packs.grad_names)
@@ -1930,7 +1966,8 @@ class Executor:
                 full[i] = v
             full, full_aux = _unpack(packs, full, arg_flat, aux_vals,
                                      aux_flat)
-            st_full = _fill_packed(st_leaves, st_flat, st_fill)
+            with _phase("unpack"):
+                st_full = _fill_packed(st_leaves, st_flat, st_fill)
             outs, aux_upd, grad_map = core(
                 full, full_aux, rng, heads, prev_grads
             )
@@ -1938,67 +1975,73 @@ class Executor:
             lr_v, wd_v, t_v = hyper[0], hyper[1], hyper[2]
             sts = jax.tree_util.tree_unflatten(state_td, st_full)
             new_params, new_states = [], []
-            for i, nm in enumerate(update_names):
-                prng = jax.random.fold_in(rng_key, 0x5EED + i)
-                w, s = apply_fn(
-                    i, full[upd_idx[i]], grad_map[nm], sts[i],
-                    lr_v[i], wd_v[i], t_v[i], prng,
-                )
-                new_params.append(w)
-                new_states.append(s)
+            # one scope a parameter: a weight's update is a row of the
+            # by-node table, with whatever XLA fused into it
+            with _phase("update"):
+                for i, nm in enumerate(update_names):
+                    with jax.named_scope(_prof.param_scope(nm)):
+                        prng = jax.random.fold_in(rng_key, 0x5EED + i)
+                        w, s = apply_fn(
+                            i, full[upd_idx[i]], grad_map[nm], sts[i],
+                            lr_v[i], wd_v[i], t_v[i], prng,
+                        )
+                    new_params.append(w)
+                    new_states.append(s)
             new_guard = guard
             if guard_on:
-                # one scalar reduction per gradient, fused into the
-                # backward epilogue: any NaN/Inf element propagates to
-                # the sum (Inf-Inf=NaN included), so isfinite of the
-                # summed sums detects every non-finite gradient without
-                # an elementwise isfinite+all pass per tensor. (A
-                # finite sum overflowing f32 would skip a good batch —
-                # harmless and astronomically rare.)
-                probe = jnp.float32(0)
-                for nm in update_names:
-                    probe = probe + jnp.sum(
-                        grad_map[nm].astype(jnp.float32))
-                finite = jnp.isfinite(probe)
-                # a non-finite step keeps the OLD params, optimizer
-                # state AND aux (BN running stats already absorbed the
-                # poisoned batch in forward — roll them back too); the
-                # rng/step/t counters still advance, keeping the host's
-                # schedule mirrors coherent without a round trip
-                new_params = [
-                    jnp.where(finite, w, full[upd_idx[i]])
-                    for i, w in enumerate(new_params)
-                ]
-                new_states = [
-                    jax.tree_util.tree_map(
-                        lambda nw, ol: jnp.where(finite, nw, ol), ns, os_
-                    )
-                    for ns, os_ in zip(new_states, sts)
-                ]
-                aux_upd = [
-                    jnp.where(finite, a, o)
-                    for a, o in zip(aux_upd, full_aux)
-                ]
-                miss = jnp.where(finite, 0, 1).astype(guard.dtype)
-                new_guard = jnp.stack([
-                    guard[0] + miss,
-                    (guard[1] + miss) * miss,  # consecutive: reset on ok
-                ])
+                with _phase("guard"):
+                    # one scalar reduction per gradient, fused into the
+                    # backward epilogue: any NaN/Inf element propagates to
+                    # the sum (Inf-Inf=NaN included), so isfinite of the
+                    # summed sums detects every non-finite gradient without
+                    # an elementwise isfinite+all pass per tensor. (A
+                    # finite sum overflowing f32 would skip a good batch —
+                    # harmless and astronomically rare.)
+                    probe = jnp.float32(0)
+                    for nm in update_names:
+                        probe = probe + jnp.sum(
+                            grad_map[nm].astype(jnp.float32))
+                    finite = jnp.isfinite(probe)
+                    # a non-finite step keeps the OLD params, optimizer
+                    # state AND aux (BN running stats already absorbed the
+                    # poisoned batch in forward — roll them back too); the
+                    # rng/step/t counters still advance, keeping the host's
+                    # schedule mirrors coherent without a round trip
+                    new_params = [
+                        jnp.where(finite, w, full[upd_idx[i]])
+                        for i, w in enumerate(new_params)
+                    ]
+                    new_states = [
+                        jax.tree_util.tree_map(
+                            lambda nw, ol: jnp.where(finite, nw, ol), ns, os_
+                        )
+                        for ns, os_ in zip(new_states, sts)
+                    ]
+                    aux_upd = [
+                        jnp.where(finite, a, o)
+                        for a, o in zip(aux_upd, full_aux)
+                    ]
+                    miss = jnp.where(finite, 0, 1).astype(guard.dtype)
+                    new_guard = jnp.stack([
+                        guard[0] + miss,
+                        (guard[1] + miss) * miss,  # consecutive: reset on ok
+                    ])
             new_leaves = jax.tree_util.tree_flatten(new_states)[0]
-            new_leaves, st_flat_out = _split_out(new_leaves, st_fill)
-            # pack the small updated params back into their flat
-            arg_flat_out = None
-            if packed_args:
-                newp = dict(zip(update_names, new_params))
-                new_params = [None if nm in packed_args else w
-                              for nm, w in zip(update_names, new_params)]
-                segs = []
-                for nm in packs.grad_names:
-                    w = newp.get(nm)
-                    if w is None:  # packed but not updated: carry over
-                        w = full[arg_index[nm]]
-                    segs.append(w.astype(jnp.float32).ravel())
-                arg_flat_out = jnp.concatenate(segs)
+            with _phase("repack"):
+                new_leaves, st_flat_out = _split_out(new_leaves, st_fill)
+                # pack the small updated params back into their flat
+                arg_flat_out = None
+                if packed_args:
+                    newp = dict(zip(update_names, new_params))
+                    new_params = [None if nm in packed_args else w
+                                  for nm, w in zip(update_names, new_params)]
+                    segs = []
+                    for nm in packs.grad_names:
+                        w = newp.get(nm)
+                        if w is None:  # packed but not updated: carry over
+                            w = full[arg_index[nm]]
+                        segs.append(w.astype(jnp.float32).ravel())
+                    arg_flat_out = jnp.concatenate(segs)
             grad_map, grad_flat, aux_big, aux_flat_out = _repack(
                 packs, aux_upd, grad_map)
             # hand the next step its hyperparams without a host round

@@ -343,70 +343,6 @@ def events():
         return list(_events)
 
 
-def _load_trace(path):
-    import gzip
-
-    opener = gzip.open if str(path).endswith(".gz") else open
-    with opener(path, "rt") as f:
-        data = json.load(f)
-    if isinstance(data, list):  # bare event-array form is legal chrome JSON
-        return {"traceEvents": data}
-    return data
-
-
-def kernel_table(trace, top=10):
-    """Per-kernel device-time attribution from a Chrome trace.
-
-    ``trace`` is a path to a Chrome trace JSON (gzip ok: what
-    ``profiler.dump_profile`` writes), a loaded trace dict, or an event
-    list. The per-kernel rows are the complete events
-    (``ph == "X"``) the jax profiler tags with an ``hlo_op`` arg — one per
-    executed XLA op on the device/runtime track, on TPU and CPU alike;
-    host spans and metadata rows carry no ``hlo_op`` and are skipped.
-
-    Aggregates by kernel name and returns the ``top`` rows, each
-    ``{"name", "device_us", "calls", "pct"}`` (+ ``"bytes"`` when the
-    profiler reports bytes_accessed), sorted by device time. ``pct`` is
-    the share of *attributed* device time — with a steady-state trace of
-    whole train steps that reads as "% of the step".
-    """
-    if isinstance(trace, dict):
-        evts = trace.get("traceEvents") or []
-    elif isinstance(trace, (list, tuple)):
-        evts = trace
-    else:
-        evts = _load_trace(trace).get("traceEvents") or []
-    agg = {}
-    total = 0.0
-    for e in evts:
-        args = e.get("args") or {}
-        if e.get("ph") != "X" or "hlo_op" not in args:
-            continue
-        dur = float(e.get("dur") or 0.0)
-        total += dur
-        row = agg.setdefault(e.get("name") or args["hlo_op"],
-                             {"device_us": 0.0, "calls": 0})
-        row["device_us"] += dur
-        row["calls"] += 1
-        for k in ("bytes_accessed", "bytes accessed"):
-            if k in args:
-                try:
-                    row["bytes"] = row.get("bytes", 0) + int(
-                        float(str(args[k]).replace(",", "")))
-                except (TypeError, ValueError):
-                    pass
-    table = []
-    for name, row in sorted(agg.items(),
-                            key=lambda kv: -kv[1]["device_us"])[:top]:
-        out = {"name": name, "device_us": round(row["device_us"], 1),
-               "calls": row["calls"],
-               "pct": round(row["device_us"] / total, 4) if total else 0.0}
-        if "bytes" in row:
-            out["bytes"] = row["bytes"]
-        table.append(out)
-    return table
-
-
 # --- export ----------------------------------------------------------------
 
 def snapshot():

@@ -558,58 +558,6 @@ def test_bucketing_switch_counters():
     assert tm.counter("bucketing.compile_on_switch").value == compile_before
 
 
-# ---------------------------------------------------------------------------
-# per-kernel device-time attribution (ISSUE 18)
-# ---------------------------------------------------------------------------
-
-
-def _xevt(name, dur, hlo_op=None, extra_args=None, ph="X"):
-    args = {"hlo_op": hlo_op or name}
-    if extra_args:
-        args.update(extra_args)
-    return {"ph": ph, "name": name, "dur": dur, "ts": 0, "pid": 1, "tid": 1,
-            "args": args}
-
-
-def test_kernel_table_aggregates_and_ranks():
-    evts = [
-        _xevt("convolution.1", 100.0),
-        _xevt("convolution.1", 50.0),   # second call aggregates
-        _xevt("fusion.7", 200.0,
-              extra_args={"bytes_accessed": "1,024"}),
-        _xevt("reduce.2", 25.0),
-        # non-kernel rows must be skipped: host span (no hlo_op),
-        # metadata (ph=M), counter event
-        {"ph": "X", "name": "fit.dispatch", "dur": 999.0, "args": {}},
-        {"ph": "M", "name": "process_name", "args": {"hlo_op": "x"}},
-        {"ph": "C", "name": "mem", "args": {"hlo_op": "x"}, "dur": 5.0},
-    ]
-    table = tm.kernel_table(evts)
-    assert [r["name"] for r in table] == ["fusion.7", "convolution.1",
-                                         "reduce.2"]
-    conv = table[1]
-    assert conv["device_us"] == 150.0 and conv["calls"] == 2
-    assert table[0]["bytes"] == 1024
-    # pct is the share of ATTRIBUTED device time (host spans excluded)
-    assert table[0]["pct"] == pytest.approx(200.0 / 375.0, abs=1e-4)
-    assert sum(r["pct"] for r in table) == pytest.approx(1.0, abs=1e-3)
-
-
-def test_kernel_table_top_n_and_trace_dict(tmp_path):
-    evts = [_xevt(f"op.{i}", float(i + 1)) for i in range(15)]
-    table = tm.kernel_table({"traceEvents": evts}, top=10)
-    assert len(table) == 10
-    assert table[0]["name"] == "op.14"  # heaviest first
-    path = tmp_path / "trace.json"
-    path.write_text(json.dumps({"traceEvents": evts}))
-    assert tm.kernel_table(str(path), top=3) == table[:3]
-
-
-def test_kernel_table_empty_trace():
-    assert tm.kernel_table([]) == []
-    assert tm.kernel_table({"traceEvents": []}) == []
-
-
 def test_stacked_wgrad_counter_counts_groups_per_launch():
     """executor.stacked_wgrad rises by the program's stacked groups at each
     launch of a train program, and a graph with no shared FullyConnected
