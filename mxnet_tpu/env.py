@@ -69,8 +69,11 @@ _declare("MXNET_KVSTORE_BIGARRAY_BOUND", int, 1000000,
          "collective regardless of array size, so no server sharding "
          "threshold applies.")
 _declare("MXNET_BACKWARD_DO_MIRROR", _parse_bool, False,
-         "When true, executors run backward with jax.checkpoint-style "
-         "rematerialisation to trade compute for activation memory "
+         "When true, executors wrap each operator in jax.checkpoint: "
+         "backward recomputes each operator's interior; keeps the "
+         "residuals an operator names (ops/registry.keep: attention's "
+         "output and log-sum-exp, the gated delta rule's U, W and "
+         "inverses, MoE's routed rows); compute for activation memory "
          "(reference mirror option, graph_executor.cc:222-280).")
 _declare("MXNET_PACK_SMALL_PARAMS", _parse_bool, True,
          "Pack small f32 parameters/aux/grads/optimizer-state tensors "

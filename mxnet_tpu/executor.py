@@ -36,7 +36,7 @@ import numpy as np
 from .base import MXNetError, np_dtype
 from .context import Context, current_context
 from .ndarray import NDArray, ones as nd_ones, zeros as nd_zeros
-from .ops.registry import OpMode
+from .ops.registry import KeptResiduals, OpMode
 from . import aot as _aot
 from . import telemetry as _tm
 
@@ -458,7 +458,8 @@ class _CompiledGraph:
         self.node2dev = node2dev or {}
         # remat (reference MXNET_BACKWARD_DO_MIRROR): wrap each op in
         # jax.checkpoint so backward recomputes op-internal values from op
-        # inputs instead of storing them — FLOPs for activation memory
+        # inputs instead of storing them — FLOPs for activation memory —
+        # but for the residuals the op itself names (ops/registry.keep)
         self.remat = remat
         # device layout for the conv stack (ops/layout.py): "NHWC" re-lowers
         # Convolution/Pooling/BatchNorm channels-last at interpretation time
@@ -480,6 +481,8 @@ class _CompiledGraph:
         self.num_rng_ops = serial
         # op nodes the last ``evaluate`` lowered under a scope of their own
         self.scoped_nodes = 0
+        # those of them whose checkpoint kept a residual the op named
+        self.kept_residual_nodes = 0
 
     def shared_fc_groups(self):
         """[(weight name, nodes)] of the ``FullyConnected`` nodes, two or
@@ -524,6 +527,8 @@ class _CompiledGraph:
 
         nhwc = self.layout == "NHWC"
         scoped = 0  # op nodes lowered under their own scope
+        kept = 0  # op nodes whose checkpoint kept a residual
+        policy = KeptResiduals() if self.remat else None
         env = {}
         cl = {}  # id(node) -> per-output channels-last flags (NHWC mode)
         aux_updates = list(aux_vals)
@@ -615,9 +620,15 @@ class _CompiledGraph:
                         lambda inner, _op=node.op, _p=params, _m=OpMode(
                             is_train=is_train, rng=node_rng,
                             layout=op_layout, platform=self.platform,
-                        ): _op.apply(inner, _p, _m)
+                        ): _op.apply(inner, _p, _m),
+                        policy=policy,
                     )
+                    # under jax.grad the checkpoint is partially evaluated,
+                    # and the policy asked, as it is bound
+                    answers = policy.answers
                     outs, new_aux = apply_fn(ins)
+                    kept += policy.kept_since(answers, node.op.name, params,
+                                              ins)
                 else:
                     outs, new_aux = node.op.apply(
                         ins, params,
@@ -656,7 +667,7 @@ class _CompiledGraph:
                         o = _lay.from_cl(o)  # monitors see logical layout
                     suffix = "_output" if i == 0 else f"_output{i}"
                     monitor(node.name + suffix, o)
-        self.scoped_nodes = scoped
+        self.scoped_nodes, self.kept_residual_nodes = scoped, kept
         if limit is not None:
             if nhwc and last_cl:
                 last_outs = [
@@ -752,8 +763,10 @@ class Executor:
         self._fc_plan = None  # memoized _shared_fc_plan
         self._grads_crowd = None  # memoized _grads_crowd_device
         self._layer_counts = None  # memoized _transformer_layers
-        # op nodes the train programs' trace lowered under a scope
+        # op nodes the train programs' trace lowered under a scope, and
+        # those of them whose recomputation kept a residual the op named
         self._scoped_nodes = 0
+        self._kept_residual_nodes = 0
         if shared_exec is not None:
             # bucketing: share compiled-function cache and memory with the
             # master executor (reference shared_exec data_pool_ reuse,
@@ -1554,11 +1567,16 @@ class Executor:
         """One launch of a train program, counted by what it holds: the op
         nodes its trace lowered under their own scope (0: a path evaluates
         the graph with no names, or the executable came from the
-        ``MXNET_AOT_CACHE`` store and was not traced here), the shared
+        ``MXNET_AOT_CACHE`` store and was not traced here), those of them
+        whose per-operator recomputation (``MXNET_BACKWARD_DO_MIRROR``)
+        kept a residual the op named (``ops/registry.keep``), the shared
         weights whose gradient it computes as one matmul, and its
         sparse-expert and attention layers (``_transformer_layers``)."""
         if self._scoped_nodes:
             _tm.counter("executor.scoped_nodes").inc(self._scoped_nodes)
+        if self._kept_residual_nodes:
+            _tm.counter("executor.kept_residual_nodes").inc(
+                self._kept_residual_nodes)
         weights = self._shared_fc_plan()[2]
         if weights:
             _tm.counter("executor.stacked_wgrad").inc(weights)
@@ -1628,6 +1646,7 @@ class Executor:
                 outs, aux_upd = graph.evaluate(full, aux_vals, key, True,
                                                fc_batches=fc_batches)
                 self._scoped_nodes = graph.scoped_nodes
+                self._kept_residual_nodes = graph.kept_residual_nodes
                 total = None
                 for j, o in enumerate(outs):
                     if not jnp.issubdtype(o.dtype, jnp.floating):

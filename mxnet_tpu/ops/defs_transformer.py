@@ -29,7 +29,7 @@ from ..base import (MXNetError, parse_bool, parse_float, parse_int,
 from . import gated_delta as _gdr
 from . import grouped_matmul as _gmm
 from .defs_nn import _castp, _prec
-from .registry import Param, register
+from .registry import Param, keep, register
 
 
 # --- RMSNorm ---------------------------------------------------------------
@@ -166,16 +166,17 @@ register(
 
 
 # --- MoE -------------------------------------------------------------------
+@jax.custom_vjp
 def _permute_rows(x, perm, inverse):
     """``x[perm]`` for a permutation ``perm`` of the rows, whose gradient is
-    the gather ``g[inverse]`` and not the scatter autodiff would write."""
+    the gather ``g[inverse]`` and not the scatter autodiff would write. The
+    permutations are arguments and not a closure, like the share of
+    ``_attach_router_losses``."""
+    return x[perm]
 
-    @jax.custom_vjp
-    def f(x):
-        return x[perm]
 
-    f.defvjp(lambda x: (x[perm], None), lambda _, g: (g[inverse],))
-    return f(x)
+_permute_rows.defvjp(lambda x, perm, inverse: (x[perm], inverse),
+                     lambda inverse, g: (g[inverse], None, None))
 
 
 def _attach_router_losses(logits, routed_share, lb_coef, z_coef):
@@ -260,11 +261,16 @@ def _router(x, w_router, bias, params):
     largest ``s + expert_bias`` (the bias steers the choice only: it has no
     gradient and is not in the weights), weighted by ``s``. Then, either
     way: ``route_norm`` divides a token's k weights by their sum (+ 1e-20)
-    and ``route_scale`` multiplies them."""
+    and ``route_scale`` multiplies them. The logits (N x E float32, a
+    six-pass product), the experts, their counts and the weights as
+    gathered are kept under per-operator recomputation (``registry.keep``):
+    backward then runs neither that product nor ``top_k``'s sort, the count
+    or the gather again."""
     k = params["top_k"]
     n, e = x.shape[0], w_router.shape[0]
-    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32).T,
-                     precision=jax.lax.Precision.HIGHEST)
+    logits = keep(jnp.dot(x.astype(jnp.float32),
+                          w_router.astype(jnp.float32).T,
+                          precision=jax.lax.Precision.HIGHEST))
     if params["score_func"] == "sigmoid":
         if params["lb_coef"] or params["z_coef"]:
             raise MXNetError("MoE: lb_coef and z_coef are defined on a "
@@ -273,14 +279,14 @@ def _router(x, w_router, bias, params):
         biased = scores if bias is None else scores + jax.lax.stop_gradient(
             bias.astype(jnp.float32))
         _, expert = jax.lax.top_k(biased, k)                  # (N, k)
-        expert = expert.reshape(-1)
-        counts = jnp.bincount(expert, length=e).astype(jnp.int32)
+        expert = keep(expert.reshape(-1))
+        counts = keep(jnp.bincount(expert, length=e).astype(jnp.int32))
     elif params["score_func"] == "softmax":
         if bias is not None:
             raise MXNetError("MoE: expert_bias needs score_func='sigmoid'")
         _, expert = jax.lax.top_k(logits, k)                  # (N, k)
-        expert = expert.reshape(-1)
-        counts = jnp.bincount(expert, length=e).astype(jnp.int32)
+        expert = keep(expert.reshape(-1))
+        counts = keep(jnp.bincount(expert, length=e).astype(jnp.int32))
         logits = _attach_router_losses(
             logits, counts.astype(jnp.float32) / n,
             params["lb_coef"], params["z_coef"])
@@ -288,7 +294,8 @@ def _router(x, w_router, bias, params):
     else:
         raise MXNetError(f"MoE: score_func {params['score_func']!r} is "
                          "neither 'softmax' nor 'sigmoid'")
-    p = jnp.take_along_axis(scores, expert.reshape(n, k), axis=1)  # f32
+    # f32; a gather of N * k scalars the chip takes a millisecond over
+    p = keep(jnp.take_along_axis(scores, expert.reshape(n, k), axis=1))
     if params["route_norm"]:
         p = p / (jnp.sum(p, axis=-1, keepdims=True) + 1e-20)
     if params["route_scale"] != 1.0:
@@ -333,9 +340,12 @@ def _held_round(first, rows, x, tok, weight, counts, w_gate, w_up, w_down):
     def live_matmul(r, w):
         return jnp.where(live, matmul(jnp.where(live, r, 0), w), 0)
 
-    r = x[tok]
-    y = live_matmul(jax.nn.silu(live_matmul(r, w_gate))
-                    * live_matmul(r, w_up), w_down)
+    # the gathered rows and what each matmul's backward reads, kept under
+    # per-operator recomputation (``registry.keep``); masks, casts and the
+    # float32 product are made again from them
+    r = keep(x[tok])
+    gate, up = keep((live_matmul(r, w_gate), live_matmul(r, w_up)))
+    y = keep(live_matmul(keep(jax.nn.silu(gate) * up), w_down))
     return jnp.zeros(x.shape, jnp.float32).at[tok].add(
         y.astype(jnp.float32) * weight[:, None])
 
@@ -411,13 +421,15 @@ def _moe(ins, params, mode):
     expert, p, counts = _router(x, w_router, bias, params)
 
     if held == e and not params["expert_offset"]:
-        order = jnp.argsort(expert, stable=True)              # by expert
-        inverse = jnp.argsort(order)
-        rows = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
+        order = keep(jnp.argsort(expert, stable=True))        # by expert
+        inverse = keep(jnp.argsort(order))
+        # what the three matmuls' backward reads, kept under per-operator
+        # recomputation like the held range's first round
+        rows = keep(_permute_rows(jnp.repeat(x, k, axis=0), order, inverse))
         matmul = _expert_matmul(counts, x.dtype, n * k,
                                 (w_gate, w_up, w_down))
-        gate, up = matmul(rows, w_gate), matmul(rows, w_up)
-        out = matmul(jax.nn.silu(gate) * up, w_down)
+        gate, up = keep((matmul(rows, w_gate), matmul(rows, w_up)))
+        out = keep(matmul(keep(jax.nn.silu(gate) * up), w_down))
         out = _permute_rows(out, inverse, order).reshape(n, k, -1)
         out = jnp.sum(out.astype(jnp.float32) * p[..., None], axis=1)
         return out.astype(x.dtype).reshape(shape)
@@ -425,7 +437,7 @@ def _moe(ins, params, mode):
     # the share of the experts held here
     local = expert - params["expert_offset"]
     key = jnp.where(jnp.logical_and(local >= 0, local < held), local, held)
-    order = jnp.argsort(key, stable=True)       # held first, by expert
+    order = keep(jnp.argsort(key, stable=True))  # held first, by expert
     counts = counts[params["expert_offset"]:params["expert_offset"] + held]
     rows = held_round_rows(n * k, held, e)
     rounds = -(-n * k // rows)
@@ -433,6 +445,7 @@ def _moe(ins, params, mode):
     if rounds * rows > n * k:   # whole rounds: more of the dead tail
         tok, weight = (jnp.pad(a, (0, rounds * rows - n * k))
                        for a in (tok, weight))
+    weight = keep(weight)       # another such gather
     out = _held_rounds(rows, x, weight, w_gate, w_up, w_down, tok, counts)
     return out.astype(x.dtype).reshape(shape)
 

@@ -36,7 +36,10 @@ What backward keeps: the scan's body and the chunk-local algebra are
 chunks' inverses ``X`` (T x chunk a head: half an operand's size) and one
 state a chunk ((T / chunk) x heads x keys x values float32), never a state
 a token. ``X`` is kept because its backward is two matmuls on it and its
-forward twelve.
+forward twelve. Under the executor's per-operator recomputation
+(``MXNET_BACKWARD_DO_MIRROR``) the kernel path names ``U``, ``W`` and ``X``
+(``gated_delta_kernels._within_fwd``) and the operator's checkpoint keeps
+them; this form marks nothing, and the scan's forward runs again in both.
 """
 
 from __future__ import annotations

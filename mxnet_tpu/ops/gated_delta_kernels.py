@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import grouped_matmul as _gmm
+from .registry import keep
 
 _LANES = 128
 _HIGHEST = lax.Precision.HIGHEST
@@ -399,8 +400,11 @@ def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
 def within_chunks(k, v, c, beta, plan, interpret=False):
     """(U, W), the chunk axis first: the forward kernel at ``plan``'s block.
     N is a multiple of ``plan.chunks`` (``padded``). Backward keeps the
-    operands and the chunks' inverses. ``interpret`` runs the kernels in
-    Pallas's interpreter (tests on the CPU)."""
+    operands and the chunks' inverses; under per-operator recomputation
+    (``MXNET_BACKWARD_DO_MIRROR``) the inverses, ``U`` and ``W`` are what
+    the operator names (``registry.keep``), so that the forward kernel runs
+    once a step and not again in backward. ``interpret`` runs the kernels
+    in Pallas's interpreter (tests on the CPU)."""
     return _within_fwd(k, v, c, beta, plan, interpret)[0]
 
 
@@ -410,7 +414,10 @@ def _static(plan, interpret):
 
 
 def _within_fwd(k, v, c, beta, plan, interpret):
-    u, w, x = _gmm._kernel(_fwd, (k, v, c, beta), **_static(plan, interpret))
+    # the scan over chunks reads u and w again in its backward, this
+    # rule's backward x: kept under per-operator recomputation
+    u, w, x = keep(_gmm._kernel(_fwd, (k, v, c, beta),
+                                **_static(plan, interpret)))
     return (u, w), (k, v, c, beta, x)
 
 

@@ -66,6 +66,61 @@ class OpMode:
     platform: str = None
 
 
+# The one name ``keep`` puts on a value and the executor's per-operator
+# ``jax.checkpoint`` saves (``KeptResiduals``).
+_KEPT = "mxnet_tpu.kept_residual"
+
+
+def keep(values):
+    """``values`` (an array or a tree of them) marked as residuals that an
+    operator's backward reads and cannot have from its operands for free:
+    under ``MXNET_BACKWARD_DO_MIRROR`` the executor's per-operator
+    ``jax.checkpoint`` keeps them and recomputes everything unmarked
+    (``KeptResiduals``). Anywhere else the mark lowers to nothing. What
+    may be marked: values of the order of the operator's operands and
+    outputs, never an interior that grows with a score tile, a
+    vocabulary-wide softmax or a float32 copy of the trunk
+    (docs/architecture.md)."""
+    import jax
+    from jax.ad_checkpoint import checkpoint_name
+
+    return jax.tree.map(lambda x: checkpoint_name(x, _KEPT), values)
+
+
+class KeptResiduals:
+    """The ``jax.checkpoint`` policy that saves what ``keep`` marked and
+    nothing else, and the count of the nodes it kept something for. One
+    object serves every node of a program: jax answers a jitted function's
+    second partial evaluation under the same policy from its cache (three
+    alike layers are evaluated, and lowered, once), where a policy a node
+    cost the Qwen3-Next cell 2.5 s of set-up (PERF.md section 6, PR 39).
+    The price is that a cached answer does not ask the policy again, so
+    ``kept_since`` also counts a node alike one already counted."""
+
+    def __init__(self):
+        import jax
+
+        self._named = jax.checkpoint_policies.save_only_these_names(_KEPT)
+        self.answers = 0     # times the policy has said yes
+        self._alike = set()  # signatures of the nodes that kept something
+
+    def __call__(self, prim, *avals, **params):
+        saved = self._named(prim, *avals, **params)
+        self.answers += bool(saved)
+        return saved
+
+    def kept_since(self, answers, op_name, params, ins):
+        """Whether the node just lowered (operator ``op_name`` with
+        ``params`` over ``ins``) kept a residual: the policy said yes
+        since it had said yes ``answers`` times, or did for a node of the
+        same operator, parameters and operand types."""
+        alike = _signature("kept", op_name, params,
+                           [x.shape for x in ins], [x.dtype for x in ins])
+        if self.answers > answers and alike is not None:
+            self._alike.add(alike)
+        return self.answers > answers or alike in self._alike
+
+
 class Param:
     """One typed op parameter (analogue of a dmlc::Parameter field)."""
 

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
+from ..ops.registry import keep
 from .compat import shard_map as _shard_map
 
 
@@ -199,6 +200,9 @@ def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q, window=0,
     itself, and only the key blocks its band touches are visited. Memory is
     linear in T, forward and backward: the backward pass keeps q, k, v, the
     output and the rows' log-sum-exp, and recomputes the scores from them.
+    The last two are named (``registry.keep``): under per-operator
+    recomputation (``MXNET_BACKWARD_DO_MIRROR``) they are kept and the
+    forward does not run again in backward.
 
     ``kernels`` (a ``flash_attention.Plan``, from the rule
     :func:`kernel_plan`): the Pallas kernels, whose score tiles never leave
@@ -262,6 +266,9 @@ def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0, kernels=None,
 
         out, lse = flash_attention.attention(q, k, v, kernels, scale, causal,
                                              window, interpret)
+    # what backward reads beside the operands: under per-operator
+    # recomputation the forward is not run again for them
+    out, lse = keep((out, lse))
     return out, (q, k, v, out, lse)
 
 

@@ -8,10 +8,12 @@ Builds every cell of ``BENCHMARK.json`` (or the named ones) on the CPU from
 the benchmark's own configuration and traffic files, at the cell's shapes,
 and drives it for one warm-up cycle. Nothing is compiled or run: each
 program the executor resolves is traced and lowered, its text
-(``lowered.as_text()``: locations and name stacks left out) is hashed, and
-the program answers zeros. Prints one JSON object, ``{cell: [[sha256[:12],
-characters of text], ...]}``, the fused train programs in the order they
-were built (one a bucket). Two trees that print the same object lower the
+(``lowered.as_text()``: locations and name stacks left out, and the numbers
+jax gives private functions as it meets them, ``@argsort_22``, which follow
+what else the process traced) is hashed, and the program answers zeros.
+Prints one JSON object, ``{cell: [[sha256[:12], characters of text],
+...]}``, the fused train programs in the order they were built (one a
+bucket). Two trees that print the same object lower the
 same train programs; scopes, spans and comments do not show. ``--root``
 hashes another checkout (the parent's), one process a cell either way.
 """
@@ -22,6 +24,7 @@ import argparse
 import hashlib
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -46,7 +49,7 @@ def lowered_programs(drive, launches):
 
     def resolve(self, args):
         lowered = self.jit_fn.lower(*args)
-        text = lowered.as_text()
+        text = re.sub(r"(@\w+?)_\d+\b", r"\1", lowered.as_text())
         fused = self._counter == FUSED
         seen.append((self._counter,
                      hashlib.sha256(text.encode()).hexdigest()[:12],
