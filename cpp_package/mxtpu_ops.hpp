@@ -468,9 +468,10 @@ inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query,
   return detail::CreateOp("RingAttention", symbol_name, 3, keys__, ins__, params__);
 }
 
-inline Symbol RotaryEmbedding(const std::string& symbol_name, const Symbol& data, double base = 10000.0, int64_t rotary_dim = 0) {
+inline Symbol RotaryEmbedding(const std::string& symbol_name, const Symbol& data, double base = 10000.0, bool interleaved = false, int64_t rotary_dim = 0) {
   detail::ParamMap params__;
   params__.emplace_back("base", detail::str(base));
+  params__.emplace_back("interleaved", detail::str(interleaved));
   params__.emplace_back("rotary_dim", detail::str(rotary_dim));
   const char* keys__[] = {"data"};
   const Symbol* ins__[] = {&data};

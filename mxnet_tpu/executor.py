@@ -1485,7 +1485,13 @@ class Executor:
         (``parallel/ring_attention.kernel_plan``) with this executor's
         platform; ``attention_scored_pairs``, the query-key pairs of the
         tiles they visit (``ring_attention.pairs_scored`` x heads x
-        batch); ``linear_attention_layers``, the ``GatedDeltaRule`` nodes,
+        batch); ``attention_latent_layers``, those whose values are
+        narrower or wider than their keys (a latent-attention head: 192
+        over 128); ``attention_pair_lanes``, summed over the layers, the
+        width a pair's score contracts over plus the width its ``p.v``
+        writes, as the path hands them over
+        (``ring_attention.pair_lanes``); ``linear_attention_layers``, the
+        ``GatedDeltaRule`` nodes,
         ``linear_attention_chunks``, the chunks their rows are cut into
         (batch x T / chunk a layer: the scan's trips; T a layer would mean
         a token at a time), and ``linear_attention_kernel_layers``, those
@@ -1503,7 +1509,8 @@ class Executor:
                 "moe_layers", "moe_assignments", "moe_local_experts",
                 "moe_kernel_matmuls", "attention_layers",
                 "attention_window_layers", "attention_kernel_layers",
-                "attention_scored_pairs", "linear_attention_layers",
+                "attention_scored_pairs", "attention_latent_layers",
+                "attention_pair_lanes", "linear_attention_layers",
                 "linear_attention_chunks", "linear_attention_kernel_layers"),
                 0)
             if moe or attention or linear:
@@ -1512,6 +1519,7 @@ class Executor:
                 from .ops.gated_delta import (chunks_of,
                                               kernel_plan as delta_kernel_plan)
                 from .parallel.ring_attention import (kernel_plan,
+                                                      pair_lanes,
                                                       pairs_scored)
 
                 internals = self._symbol.get_internals()
@@ -1536,18 +1544,23 @@ class Executor:
                         shape_of[out][-1], p["num_hidden"])
                 for n in attention:
                     p = n.params()
-                    out = n.name + "_output"
-                    # the key, named as ``list_outputs`` names an entry
+                    out = shape_of[n.name + "_output"]
+                    # the key, named as ``list_outputs`` names an entry;
+                    # the output has the query's shape at the value's width
                     key, = type(internals)([n.inputs[1]]).list_outputs()
-                    kv_heads = shape_of[key][1]
+                    kv_heads, key_dim = shape_of[key][1], shape_of[key][3]
+                    query = tuple(out[:3]) + (key_dim,)
                     kernels = kernel_plan(
-                        dtype_of[out], shape_of[out], kv_heads, p["causal"],
-                        p["window"], platform)
+                        dtype_of[n.name + "_output"], query, kv_heads,
+                        p["causal"], p["window"], platform, out[3])
                     counts["attention_layers"] += 1
                     counts["attention_window_layers"] += bool(p["window"])
                     counts["attention_kernel_layers"] += kernels is not None
                     counts["attention_scored_pairs"] += pairs_scored(
-                        shape_of[out], p["causal"], p["window"], kernels)
+                        query, p["causal"], p["window"], kernels)
+                    counts["attention_latent_layers"] += key_dim != out[3]
+                    counts["attention_pair_lanes"] += pair_lanes(key_dim,
+                                                                 out[3])
                 for n in linear:
                     value = shape_of[n.name + "_output"]
                     batch, _, T, _ = value
@@ -1597,6 +1610,11 @@ class Executor:
                 held["attention_layers"])
             _tm.counter("executor.attention_scored_pairs").inc(
                 held["attention_scored_pairs"])
+            _tm.counter("executor.attention_pair_lanes").inc(
+                held["attention_pair_lanes"])
+        if held["attention_latent_layers"]:
+            _tm.counter("executor.attention_latent_layers").inc(
+                held["attention_latent_layers"])
         if held["attention_kernel_layers"]:
             _tm.counter("executor.attention_kernel_layers").inc(
                 held["attention_kernel_layers"])

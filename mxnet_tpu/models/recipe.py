@@ -188,11 +188,14 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
                 total += 3.0 * _prod(v) * int(k[3]) / batch
             continue
         if op == "RingAttention":
-            # q (B, H, T, D): q.k and p.v, a causal row sees half the keys
+            # q (B, H, T, Dk) . k over Dk and p . v (B, Hkv, T, Dv) over Dv,
+            # a causal row sees half the keys
             q = _node_shape(shape_dict, nodes, node["inputs"][0])
-            if q:
+            v = _node_shape(shape_dict, nodes, node["inputs"][2])
+            if q and v:
                 seen = 0.5 if parse_bool(attrs.get("causal", False)) else 1.0
-                total += 2.0 * seen * _prod(q) * int(q[2]) / batch
+                total += seen * _prod(q[:3]) * int(q[2]) * (
+                    int(q[3]) + int(v[3])) / batch
             continue
         if op == "MoE":
             # every row through the router, and through gate, up and down

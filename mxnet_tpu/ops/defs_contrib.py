@@ -566,9 +566,12 @@ def _ring_attention_op(ins, params, mode):
     (parallel/ring_attention.py); without one it is the same online
     softmax over blocks of queries on one device (``blockwise_attention``:
     exact, and linear in T where the whole score matrix is quadratic), so
-    the same symbol serves single-chip and sequence-parallel runs. On one
-    TPU with bfloat16 operands, a head dim 128 divides and T a multiple of
-    a block, that one-device path is the fused Pallas kernels of
+    the same symbol serves single-chip and sequence-parallel runs. Query
+    and key are (B, H, T, Dk), value (B, H, T, Dv) and the output takes the
+    value's width: Dv may differ from Dk (a latent-attention head scores
+    over 192 and weighs values of 128), on every path. On one TPU with
+    bfloat16 operands, head widths the kernels take (``flash_attention.plan``)
+    and T a multiple of a block, that one-device path is the fused Pallas kernels of
     ``ops/flash_attention.py`` (forward and backward; no score tile in
     HBM); the rule is ``ring_attention.kernel_plan``, from what is observed
     at trace time (``mode.platform``: the executor's), and everything else

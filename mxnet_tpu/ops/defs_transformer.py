@@ -56,8 +56,13 @@ register(
 
 # --- RotaryEmbedding -------------------------------------------------------
 def _rotary(ins, params, mode):
-    """Rotate-half rotary position embedding of ``data`` (..., T, D): the
-    pair ``(i, i + D/2)`` of position ``t`` turns by ``t * base^(-2i/D)``.
+    """Rotary position embedding of ``data`` (..., T, D): pair ``i`` of
+    position ``t`` turns by ``t * base^(-2i/D)``. The pair is ``(i, i +
+    D/2)`` (rotate-half), or with ``interleaved`` the neighbours ``(2i, 2i
+    + 1)`` (``rope_interleave`` of the DeepSeek-V3 family, the original
+    RoFormer pairing), turned in place. (That family's public code leaves
+    its output de-interleaved, the evens before the odds: the same
+    permutation of queries and keys, which no score sees.)
     With ``rotary_dim`` R (0: the whole head) only the first R of the D
     turn, as a head of R would, and dims ``[R, D)`` pass through.
 
@@ -85,8 +90,15 @@ def _rotary(ins, params, mode):
     cos = np.cos(angle).astype(np.float32)
     sin = np.sin(angle).astype(np.float32)
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if params["interleaved"]:
+        pairs = xf.reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = xf[..., :half], xf[..., half:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1)
     return out.astype(x.dtype)
 
 
@@ -95,7 +107,8 @@ register(
     _rotary,
     arg_names=["data"],
     param_schema={"base": Param(parse_float, 10000.0),
-                  "rotary_dim": Param(parse_int, 0)},  # 0: the whole head
+                  "rotary_dim": Param(parse_int, 0),  # 0: the whole head
+                  "interleaved": Param(parse_bool, False)},  # (2i, 2i + 1)
 )
 
 

@@ -3,11 +3,16 @@ lives only in VMEM.
 
 ``attention(q, k, v, plan, ...)`` and ``attention_grads(...)`` are the forward
 and backward of ``parallel/ring_attention.blockwise_attention`` where the rule
-(``plan``) says so: q (B, H, T, D), k and v (B, Hkv, T, D), Hkv dividing H.
-The mathematics is ``_softmax_block``'s and ``_blockwise_bwd``'s: bfloat16
-operands on the MXU with float32 accumulation; scale, max, ``exp`` and sums
-in float32; ``p`` and ``ds`` cast to the operands' dtype only for their
-matmuls; the residuals are q, k, v, the output and the rows' log-sum-exp.
+(``plan``) says so: q (B, H, T, Dk), k (B, Hkv, T, Dk) and v (B, Hkv, T, Dv),
+Hkv dividing H. There are two widths: the scores contract over the key's Dk,
+the output and ``p @ v`` run at the value's Dv (a latent-attention head
+scores over 192 = 128 + 64 rotated and weighs values of 128); the program
+pads neither (the v5e's own tiled layout stores a minor dimension of 192 in
+256 lanes). The mathematics is ``_softmax_block``'s and
+``_blockwise_bwd``'s: bfloat16 operands on the MXU with float32 accumulation;
+scale, max, ``exp`` and sums in float32; ``p`` and ``ds`` cast to the
+operands' dtype only for their matmuls; the residuals are q, k, v, the output
+and the rows' log-sum-exp.
 
 What the kernels do that the ``jax.numpy`` blocks do not:
 
@@ -86,14 +91,19 @@ class Plan(NamedTuple):
 
 
 def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
-         window=0) -> Optional[Plan]:
-    """The rule. The kernels engage where the program is lowered for one
-    TPU whose VMEM is known, the operands are bfloat16 (a float32 trunk
-    keeps the ``jax.numpy`` blocks at ``precision=HIGHEST``), the head dim
-    is a multiple of 128, the key/value heads divide the query heads, T is
-    a multiple of a key block, and what backward keeps in VMEM for one
-    key/value head (k, v, dk, dv in and out, float32 accumulators: 24 T D
-    bytes, beside the tiles) is under half of it. Tiles: the widest key
+         window=0, value_dim=None) -> Optional[Plan]:
+    """The rule. ``D`` is the width of queries and keys, ``value_dim`` that
+    of the values and the output (None: ``D``). The kernels engage where
+    the program is lowered for one TPU whose VMEM is known, the operands
+    are bfloat16 (a float32 trunk keeps the ``jax.numpy`` blocks at
+    ``precision=HIGHEST``), the value width is a multiple of 128 and the
+    key width one of 128 or of 128 plus a half (192: a half tile of lanes
+    is the narrowest Mosaic contracts over unpadded), the key/value heads
+    divide the query heads, T is a multiple of a key block, and what
+    backward keeps in VMEM for one key/value head (k and dk, v and dv in
+    and out, float32 accumulators: 12 T bytes a lane of either width as
+    the VMEM holds it, in whole tiles of 128, beside the tiles; 24 T D at
+    one width) is under half of it. Tiles: the widest key
     block of ``_KEY_BLOCKS`` dividing T of which a band holds
     ``_BAND_BLOCKS``; the widest query block of ``_POSITIONS`` dividing T
     whose tile over the group (any group: 7 query heads a key/value head
@@ -102,7 +112,10 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
     None = the ``jax.numpy`` blocks."""
     if platform != "tpu" or not vmem_bytes:
         return None
-    if jnp.dtype(dtype) != jnp.bfloat16 or D % _LANES or heads % kv_heads:
+    value_dim = value_dim or D
+    if jnp.dtype(dtype) != jnp.bfloat16 or heads % kv_heads:
+        return None
+    if value_dim % _LANES or D % _LANES not in (0, _LANES // 2) or D < _LANES:
         return None
     if window and not causal:
         return None
@@ -117,9 +130,10 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
     # where that tile does not fit beside a long and wide head (T 8192 at
     # head 256: 50 MB of keys, values and their gradients), the next
     # narrower query blocks
+    lanes = -(-D // _LANES) * _LANES + value_dim   # of both, as VMEM pads
     for bq in (b for b in fit if b <= widest):
         rows = group * bq
-        need = 24 * T * D + 8 * rows * D * 2 + 6 * rows * bk * 4
+        need = 12 * T * lanes + 8 * rows * lanes + 6 * rows * bk * 4
         if need <= vmem_bytes // 2:
             return Plan(bq, bk, min(vmem_bytes * 3 // 4, need + (16 << 20)))
     return None
@@ -190,16 +204,23 @@ def _for_the_key_blocks(first, end, i, bq, bk, rows_axis, shape, causal,
     lax.fori_loop(first, end, body, None)
 
 
-def _block_specs(group, bq, T, D):
-    """BlockSpecs over the grid (batch, key/value head, query block): a
-    query block of the head's group folded to rows, the head's whole keys
-    or values, and a row of lanes a query block (log-sum-exp, delta)."""
+def _block_specs(group, bq, T):
+    """BlockSpecs over the grid (batch, key/value head, query block):
+    ``folded(D)``, a query block of the head's group folded to rows, and
+    ``whole(D)``, the head's whole keys or values, each at the width it is
+    asked for; and a row of lanes a query block (log-sum-exp, delta)."""
     pl, _ = _gmm._pallas()
-    return (pl.BlockSpec((None, None, group, bq, D),
-                         lambda b, h, i, *_: (b, h, 0, i, 0)),
-            pl.BlockSpec((None, None, T, D), lambda b, h, i, *_: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, None, 1, group * bq),
-                         lambda b, h, i, *_: (b, h, i, 0, 0)))
+
+    def folded(D):
+        return pl.BlockSpec((None, None, group, bq, D),
+                            lambda b, h, i, *_: (b, h, 0, i, 0))
+
+    def whole(D):
+        return pl.BlockSpec((None, None, T, D),
+                            lambda b, h, i, *_: (b, h, 0, 0))
+
+    return folded, whole, pl.BlockSpec((None, None, None, 1, group * bq),
+                                       lambda b, h, i, *_: (b, h, i, 0, 0))
 
 
 # --- forward -----------------------------------------------------------------
@@ -207,10 +228,10 @@ def _block_specs(group, bq, T, D):
     "scale", "causal", "window", "bq", "bk", "vmem_limit", "interpret"))
 def _fwd(q, k, v, first, end, *, scale, causal, window, bq, bk, vmem_limit,
          interpret):
-    """(out (B, H, T, D) in q's dtype, log-sum-exp (B, H, T) float32)."""
+    """(out (B, H, T, Dv) in q's dtype, log-sum-exp (B, H, T) float32)."""
     pl, pltpu = _gmm._pallas()
     B, H, T, D = q.shape
-    kv = k.shape[1]
+    kv, Dv = k.shape[1], v.shape[-1]
     group, nq = H // kv, T // bq
     rows = group * bq
 
@@ -235,7 +256,7 @@ def _fwd(q, k, v, first, end, *, scale, causal, window, bq, bk, vmem_limit,
             alpha = jnp.exp(m_prev - m_new)
             l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1)[:, None]
             m_ref[...] = m_new
-            acc_ref[...] = jnp.tile(alpha, (1, D // _LANES)) * acc_ref[...] \
+            acc_ref[...] = jnp.tile(alpha, (1, Dv // _LANES)) * acc_ref[...] \
                 + lax.dot_general(p.astype(v_ref.dtype), v_ref[at, :],
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
@@ -243,42 +264,49 @@ def _fwd(q, k, v, first, end, *, scale, causal, window, bq, bk, vmem_limit,
         _for_the_key_blocks(first_ref[i], end_ref[i], i, bq, bk, 0,
                             (rows, bk), causal, window, step)
         l = l_ref[...]
-        o_ref[...] = (acc_ref[...] * jnp.tile(1.0 / l, (1, D // _LANES))) \
-            .astype(o_ref.dtype).reshape(group, bq, D)
+        o_ref[...] = (acc_ref[...] * jnp.tile(1.0 / l, (1, Dv // _LANES))) \
+            .astype(o_ref.dtype).reshape(group, bq, Dv)
         # the rows' log-sum-exp, from a column to a row of lanes
         lse_ref[...] = jnp.transpose(m_ref[...] + jnp.log(l))[:1, :]
 
-    folded, whole, row = _block_specs(group, bq, T, D)
+    folded, whole, row = _block_specs(group, bq, T)
     out, lse = pl.pallas_call(
         kernel,
-        out_shape=(jax.ShapeDtypeStruct((B, kv, group, T, D), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((B, kv, group, T, Dv), q.dtype),
                    jax.ShapeDtypeStruct((B, kv, nq, 1, rows), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            in_specs=[folded, whole, whole],
-            out_specs=[folded, row],
+            in_specs=[folded(D), whole(D), whole(Dv)],
+            out_specs=[folded(Dv), row],
             grid=(B, kv, nq),
             scratch_shapes=[pltpu.VMEM((rows, _LANES), jnp.float32),
                             pltpu.VMEM((rows, _LANES), jnp.float32),
-                            pltpu.VMEM((rows, D), jnp.float32)],
+                            pltpu.VMEM((rows, Dv), jnp.float32)],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
-        cost_estimate=_cost(q, k, bq, bk, causal, window, matmuls=2),
+        cost_estimate=_cost(q, k, v, bq, bk, causal, window, 1, 1),
         interpret=interpret,
         name="attention_fwd",
     )(first, end, q.reshape(B, kv, group, T, D), k, v)
-    return out.reshape(B, H, T, D), _rows_to_heads(lse, H)
+    return out.reshape(B, H, T, Dv), _rows_to_heads(lse, H)
 
 
-def _cost(q, k, bq, bk, causal, window, matmuls):
+def _cost(q, k, v, bq, bk, causal, window, over_keys, over_values):
+    """A kernel's matmuls a scored pair, at their two widths: ``over_keys``
+    run at the key's (q.k, and backward dk and dq), ``over_values`` at the
+    value's (p.v, and backward d_out.v and dv); the bytes are the tensors
+    of either width, each read or written once a matmul of its width."""
     pl, _ = _gmm._pallas()
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     pairs = B * H * scored_pairs(T, bq, bk, causal, window)
     return pl.CostEstimate(
-        flops=2 * matmuls * pairs * D, transcendentals=pairs,
-        bytes_accessed=(matmuls * q.size + 2 * matmuls * k.size)
+        flops=2 * pairs * (over_keys * D + over_values * Dv),
+        transcendentals=pairs,
+        bytes_accessed=(over_keys * (q.size + 2 * k.size) + over_values
+                        * (q.size // D * Dv + 2 * v.size))
         * q.dtype.itemsize)
 
 
@@ -306,7 +334,7 @@ def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
     """(dq, dk, dv) in the operands' dtypes."""
     pl, pltpu = _gmm._pallas()
     B, H, T, D = q.shape
-    kv = k.shape[1]
+    kv, Dv = k.shape[1], v.shape[-1]
     group, nq = H // kv, T // bq
     rows = group * bq
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
@@ -322,7 +350,7 @@ def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
         qb = q_ref[...].reshape(rows, D)
-        gb = g_ref[...].reshape(rows, D)
+        gb = g_ref[...].reshape(rows, Dv)
         row_lse, row_delta = lse_ref[...], delta_ref[...]    # (1, rows)
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -355,7 +383,7 @@ def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
             dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
-    folded, whole, row = _block_specs(group, bq, T, D)
+    folded, whole, row = _block_specs(group, bq, T)
     dq, dk, dv = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((B, kv, group, T, D), q.dtype),
@@ -363,21 +391,21 @@ def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            in_specs=[folded, whole, whole, folded, row, row],
-            out_specs=[folded, whole, whole],
+            in_specs=[folded(D), whole(D), whole(Dv), folded(Dv), row, row],
+            out_specs=[folded(D), whole(D), whole(Dv)],
             grid=(B, kv, nq),
             scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
                             pltpu.VMEM((T, D), jnp.float32),
-                            pltpu.VMEM((T, D), jnp.float32)],
+                            pltpu.VMEM((T, Dv), jnp.float32)],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
-        cost_estimate=_cost(q, k, bq, bk, causal, window, matmuls=5),
+        cost_estimate=_cost(q, k, v, bq, bk, causal, window, 3, 2),
         interpret=interpret,
         name="attention_bwd",
     )(first, end, q.reshape(B, kv, group, T, D), k, v,
-      d_out.reshape(B, kv, group, T, D), _heads_to_rows(lse, kv, bq),
+      d_out.reshape(B, kv, group, T, Dv), _heads_to_rows(lse, kv, bq),
       _heads_to_rows(delta, kv, bq))
     return dq.reshape(B, H, T, D), dk, dv
 

@@ -17,11 +17,16 @@ Without a mesh the same online softmax runs over blocks of queries on one
 device (:func:`blockwise_attention`): no ``(T, T)`` score tensor is ever held
 or saved for backward, and a causal block reads only the keys at or before
 its own end. Where the rule (:func:`kernel_plan`: one TPU, a bfloat16 trunk,
-a head dim 128 divides, T a multiple of a block) says so, its forward and
-backward are the Pallas kernels of ``ops/flash_attention.py``, in which a
+head widths the kernels take, T a multiple of a block) says so, its forward
+and backward are the Pallas kernels of ``ops/flash_attention.py``, in which a
 score tile lives only in VMEM; otherwise (the CPU, float32, odd shapes)
 :func:`_softmax_block` in ``jax.numpy``, whose float32 score tiles XLA
 writes to HBM. The ring path is ``jax.numpy`` on every platform.
+
+Two widths: queries and keys (B, H, T, Dk) are scored over Dk, values (B,
+Hkv, T, Dv) are weighed into an output (B, H, T, Dv). They are equal in
+most models; a latent-attention head (DeepSeek-V3's) has keys of 192 = 128
++ 64 rotated and values of 128. Every path here takes both, unpadded.
 """
 
 from __future__ import annotations
@@ -70,19 +75,19 @@ def _softmax_block(q, k_blk, v_blk, mask, scale, o, m, l):
 def _ring_attn_shard(q, k, v, axis_name, causal, scale):
     """Per-device body under shard_map.
 
-    q, k, v: (B, H, Tl, D) local sequence blocks.
-    Returns (B, H, Tl, D) attention outputs for the local queries.
+    q, k: (B, H, Tl, Dk), v: (B, H, Tl, Dv) local sequence blocks.
+    Returns (B, H, Tl, Dv) attention outputs for the local queries.
     """
     n = jax.lax.psum(1, axis_name)
     my_idx = jax.lax.axis_index(axis_name)
-    B, H, Tl, D = q.shape
+    B, H, Tl, _ = q.shape
     qf = q.astype(jnp.float32)
 
     # accumulators are per-device state (varying over the ring axis)
     def _vary(x):
         return jax.lax.pcast(x, axis_name, to="varying")
 
-    o = _vary(jnp.zeros((B, H, Tl, D), jnp.float32))
+    o = _vary(jnp.zeros((B, H, Tl, v.shape[-1]), jnp.float32))
     m = _vary(jnp.full((B, H, Tl), -jnp.inf, jnp.float32))
     l = _vary(jnp.zeros((B, H, Tl), jnp.float32))
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -194,8 +199,9 @@ def _group_mask(mask, group):
 def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q, window=0,
                         kernels=None, interpret=False):
     """softmax(q k^T * scale [+ causal mask]) v on one device; q (B, H, T,
-    D), k and v (B, Hkv, T, D) with Hkv dividing H (query head n reads
-    key/value head n // (H / Hkv)), output in their dtype. ``window``
+    Dk), k (B, Hkv, T, Dk) and v (B, Hkv, T, Dv) with Hkv dividing H (query
+    head n reads key/value head n // (H / Hkv)), output (B, H, T, Dv) in
+    their dtype. ``window``
     (causal only): a query reads only the ``window`` keys that end at
     itself, and only the key blocks its band touches are visited. Memory is
     linear in T, forward and backward: the backward pass keeps q, k, v, the
@@ -213,13 +219,15 @@ def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q, window=0,
                           interpret)[0]
 
 
-def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None):
+def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
+                value_dim=None):
     """The rule of the one-device path: the kernels' tiles
-    (``ops/flash_attention.plan``) for queries ``q_shape`` (B, H, T, D) of
-    ``dtype`` over ``kv_heads`` in a program lowered for ``platform`` (the
+    (``ops/flash_attention.plan``) for queries ``q_shape`` (B, H, T, Dk) of
+    ``dtype`` over ``kv_heads`` whose values are ``value_dim`` wide (None:
+    Dk) in a program lowered for ``platform`` (the
     executor's, through ``OpMode.platform``; None: jax's default backend)
     in a process that holds one TPU, or None: the ``jax.numpy`` blocks (the
-    CPU, several chips, float32, a head dim 128 does not divide, T no
+    CPU, several chips, float32, head widths the kernels do not take, T no
     multiple of a block). The op and the executor's counters ask it with
     the same arguments. A bare traced call (no executor, ``platform`` None)
     assumes the default backend: a plain ``jax.jit`` for the CPU in a
@@ -231,7 +239,16 @@ def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None):
     return flash_attention.plan(
         platform or jax.default_backend(),
         grouped_matmul.attached_vmem_bytes(), dtype, heads, kv_heads, T, D,
-        causal, window)
+        causal, window, value_dim)
+
+
+def pair_lanes(key_dim, value_dim):
+    """Lanes one query-key pair is computed over: the width its score
+    contracts over plus the width ``p.v`` writes, as the paths of
+    :func:`blockwise_attention` are handed them. Neither the ``jax.numpy``
+    blocks nor the kernels pad a width (keys of 192 over values of 128 are
+    320; a model that padded its keys to 256 would hand over 384)."""
+    return key_dim + value_dim
 
 
 def pairs_scored(q_shape, causal, window=0, kernels=None):
@@ -258,6 +275,9 @@ def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0, kernels=None,
     if H % kv or v.shape[1] != kv:
         raise MXNetError(f"attention: {H} query heads over {kv} key and "
                          f"{v.shape[1]} value heads")
+    if q.shape[-1] != k.shape[-1]:
+        raise MXNetError(f"attention: queries of {q.shape[-1]} over keys of "
+                         f"{k.shape[-1]}")
 
     if kernels is None:
         out, lse = _blocks_fwd(q, k, v, causal, scale, block_q, window)
@@ -274,8 +294,8 @@ def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0, kernels=None,
 
 def _blocks_fwd(q, k, v, causal, scale, block_q, window):
     """(out, log-sum-exp (B, H, T) float32) by ``jax.numpy`` blocks."""
-    B, H, T, D = q.shape
-    kv = k.shape[1]
+    B, H, T, _ = q.shape
+    kv, Dv = k.shape[1], v.shape[-1]
     group = H // kv
     outs, lses = [], []
     for a, b, first, end, mask in _q_blocks(T, block_q, causal, window):
@@ -283,7 +303,7 @@ def _blocks_fwd(q, k, v, causal, scale, block_q, window):
         o, m, l = _softmax_block(
             _fold(q[:, :, a:b], kv), k[:, :, first:end], v[:, :, first:end],
             _group_mask(mask, group), scale,
-            jnp.zeros((B, kv, rows, D), jnp.float32),
+            jnp.zeros((B, kv, rows, Dv), jnp.float32),
             jnp.full((B, kv, rows), -jnp.inf, jnp.float32),
             jnp.zeros((B, kv, rows), jnp.float32))
         outs.append(_unfold((o / l[..., None]).astype(q.dtype), H))
@@ -358,8 +378,8 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None,
                    window=0):
     """Sequence-parallel attention.
 
-    q, k, v: jax arrays or NDArrays of shape (B, H, T, D), sharded (or to be
-    sharded) along T over mesh axis ``axis``. Returns same-shaped output
+    q, k (B, H, T, Dk) and v (B, H, T, Dv): jax arrays or NDArrays, sharded
+    (or to be sharded) along T over mesh axis ``axis``. Returns (B, H, T, Dv)
     with the same sharding. With ``mesh=None`` it is
     :func:`blockwise_attention` on one device (same math), which alone has
     ``window`` and key/value heads fewer than the query heads.
@@ -415,7 +435,8 @@ def _on_one_device(q, k, v, causal, scale, window, platform=None):
     return blockwise_attention(
         q, k, v, causal, scale,
         block_q_of(q.shape[0], q.shape[1], q.shape[2], window), window,
-        kernel_plan(q.dtype, q.shape, k.shape[1], causal, window, platform))
+        kernel_plan(q.dtype, q.shape, k.shape[1], causal, window, platform,
+                    v.shape[-1]))
 
 
 def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,

@@ -460,6 +460,57 @@ def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes <= 8 << 20
 
 
+def test_latent_attention_kernels_compile_for_a_v5e_at_the_cell_widths(
+        one_chip):
+    """Mosaic accepts the fused attention kernels at the kanana2-30b cell's
+    layer: 32 heads, T 8192, queries and keys of 192 = 128 + 64 rotated (the
+    one rotated key broadcast into every head's key, as the model does),
+    values of 128; the rule gives tiles of 512 x 512; no float32 score tile
+    among the temporaries, which are q, k, dq and dk as the concatenations
+    write and read them (a minor dimension of 192 is stored in 256 lanes)."""
+    import importlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import flash_attention as fa
+
+    ra = importlib.import_module("mxnet_tpu.parallel.ring_attention")
+    heads, t, nope, rope, dv = 32, 8192, 128, 64, 128
+    plan = fa.plan("tpu", V5E_VMEM, jnp.bfloat16, heads, heads, t,
+                   nope + rope, True, 0, dv)
+    assert (plan.bq, plan.bk) == (512, 512)
+
+    def attend(q_nope, q_rope, k_nope, k_rope, v):
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (1, heads, t, rope))], -1)
+        return ra.blockwise_attention(q, k, v, True, (nope + rope) ** -0.5,
+                                      256, 0, plan)
+
+    def step(*args):
+        out, vjp = jax.vjp(attend, *args[:-1])
+        return (out,) + vjp(args[-1])
+
+    def arg(h, d):
+        return jax.ShapeDtypeStruct((1, h, t, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        arg(heads, nope), arg(heads, rope), arg(heads, nope), arg(1, rope),
+        arg(heads, dv), arg(heads, dv)).compile()
+    text = compiled.as_text()
+    assert "attention_fwd" in text and "attention_bwd" in text
+    # the widest array of the program is a q or a k: a block of 256
+    # queries' scores (the ``jax.numpy`` blocks') would be 4/3 of that
+    widest = max(int(np.prod([int(d) for d in dims.split(",")]))
+                 for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert widest == heads * t * (nope + rope)
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        4 * heads * t * 256 * 2 + (8 << 20)
+
+
 def test_gated_delta_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip):
     """Mosaic accepts the kernels of the gated delta rule's chunk-local
     algebra (``ops/gated_delta_kernels.py``; their other tests are in
