@@ -1494,10 +1494,12 @@ class Executor:
         ``GatedDeltaRule`` nodes,
         ``linear_attention_chunks``, the chunks their rows are cut into
         (batch x T / chunk a layer: the scan's trips; T a layer would mean
-        a token at a time), and ``linear_attention_kernel_layers``, those
-        whose chunk-local algebra a train program runs in the Pallas
-        kernels, asked of the rule the op follows
-        (``ops/gated_delta.kernel_plan``) with this executor's platform.
+        a token at a time), and ``linear_attention_kernel_layers`` and
+        ``linear_attention_scan_kernel_layers``, those whose chunk-local
+        algebra and whose scan over chunks a train program runs in the
+        Pallas kernels, asked of the rule the op follows
+        (``ops/gated_delta.kernel_plan``: one rule, a node runs all of its
+        kernels or none) with this executor's platform.
         Shapes and types are inferred only where the
         graph has such a node."""
         if self._layer_counts is None:
@@ -1511,8 +1513,8 @@ class Executor:
                 "attention_window_layers", "attention_kernel_layers",
                 "attention_scored_pairs", "attention_latent_layers",
                 "attention_pair_lanes", "linear_attention_layers",
-                "linear_attention_chunks", "linear_attention_kernel_layers"),
-                0)
+                "linear_attention_chunks", "linear_attention_kernel_layers",
+                "linear_attention_scan_kernel_layers"), 0)
             if moe or attention or linear:
                 from .ops.defs_transformer import (held_round_rows,
                                                    moe_kernel_matmuls)
@@ -1570,9 +1572,11 @@ class Executor:
                     counts["linear_attention_layers"] += 1
                     counts["linear_attention_chunks"] += batch * chunks_of(
                         T, chunk)
-                    counts["linear_attention_kernel_layers"] += \
-                        delta_kernel_plan(dtype_of[query], shape_of[key],
-                                          value, chunk, platform) is not None
+                    kernels = delta_kernel_plan(
+                        dtype_of[query], shape_of[key], value, chunk,
+                        platform) is not None
+                    counts["linear_attention_kernel_layers"] += kernels
+                    counts["linear_attention_scan_kernel_layers"] += kernels
             self._layer_counts = counts
         return self._layer_counts
 
@@ -1629,6 +1633,9 @@ class Executor:
         if held["linear_attention_kernel_layers"]:
             _tm.counter("executor.linear_attention_kernel_layers").inc(
                 held["linear_attention_kernel_layers"])
+        if held["linear_attention_scan_kernel_layers"]:
+            _tm.counter("executor.linear_attention_scan_kernel_layers").inc(
+                held["linear_attention_scan_kernel_layers"])
 
     def _make_grad_core(self):
         """Shared fwd+bwd tracing core used by both the plain train_step

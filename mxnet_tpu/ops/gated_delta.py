@@ -37,9 +37,19 @@ chunks' inverses ``X`` (T x chunk a head: half an operand's size) and one
 state a chunk ((T / chunk) x heads x keys x values float32), never a state
 a token. ``X`` is kept because its backward is two matmuls on it and its
 forward twelve. Under the executor's per-operator recomputation
-(``MXNET_BACKWARD_DO_MIRROR``) the kernel path names ``U``, ``W`` and ``X``
-(``gated_delta_kernels._within_fwd``) and the operator's checkpoint keeps
-them; this form marks nothing, and the scan's forward runs again in both.
+(``MXNET_BACKWARD_DO_MIRROR``) the kernel path names ``U``, ``W``, ``X``
+(``gated_delta_kernels._within_fwd``) and the state every chunk started
+from (``_across_fwd``), the operator's checkpoint keeps them, and neither
+forward kernel runs again; this form marks nothing, and its scan's forward
+runs again (the one state a chunk is jax's own scan residual, which no name
+reaches).
+
+Where the rule gives a plan (``kernel_plan``) both halves run in Pallas
+kernels, the chunk-local algebra and the scan over chunks
+(``gated_delta_kernels``: the same mathematics at the same precision, a
+head's state in VMEM over all of its chunks); everywhere else this file's
+``jax.numpy`` form is the operator, and it is the kernels' oracle in the
+tests.
 """
 
 from __future__ import annotations
@@ -178,14 +188,14 @@ def chunks_of(T, chunk):
 
 
 def kernel_plan(dtype, k_shape, v_shape, chunk, platform=None):
-    """The rule of the chunk-local algebra: the kernels' block
-    (``gated_delta_kernels.plan``) for keys ``k_shape`` (B, Hk, T, Dk) and
-    values ``v_shape`` (B, Hv, T, Dv) of ``dtype`` in a program lowered for
-    ``platform`` (the executor's, through ``OpMode.platform``; None: jax's
-    default backend) in a process that holds one TPU, or None: the
-    ``jax.numpy`` form (the CPU, several chips, a float32 trunk, a head
-    width 128 does not divide, another chunk). The op and the executor's
-    counter ask it with the same arguments."""
+    """The rule of the operator's kernels, the chunk-local algebra's and
+    the scan's alike: their block (``gated_delta_kernels.plan``) for keys
+    ``k_shape`` (B, Hk, T, Dk) and values ``v_shape`` (B, Hv, T, Dv) of
+    ``dtype`` in a program lowered for ``platform`` (the executor's, through
+    ``OpMode.platform``; None: jax's default backend) in a process that
+    holds one TPU, or None: the ``jax.numpy`` form (the CPU, several chips,
+    a float32 trunk, a head width 128 does not divide, another chunk). The
+    op and the executor's counters ask it with the same arguments."""
     _, Hk, T, Dk = k_shape
     return gated_delta_kernels.plan(
         platform or jax.default_backend(),
@@ -204,8 +214,9 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, kernels=None,
     tokens that write nothing and fade nothing.
 
     ``kernels`` (a ``gated_delta_kernels.Plan``, from the rule
-    :func:`kernel_plan`): the chunk-local algebra in the Pallas kernels, T
-    padded to their whole blocks of chunks; None: ``_within_chunks``.
+    :func:`kernel_plan`): the chunk-local algebra and the scan over chunks
+    in the Pallas kernels, T padded to their whole blocks of chunks; None:
+    ``_within_chunks`` and ``_chunk_step`` under ``lax.scan``.
     ``interpret`` runs the kernels in Pallas's interpreter (tests on the
     CPU)."""
     if chunk & (chunk - 1):
@@ -233,22 +244,27 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, kernels=None,
         v = v.reshape(B, Hk, G, N, chunk, Dv)
         c = jnp.cumsum(g.reshape(B, Hk, G, N, chunk), axis=-1)
         beta = beta.reshape(B, Hk, G, N, chunk)
-        with jax.named_scope("within_chunks"):
-            if kernels is None:
+        if kernels is not None:
+            with jax.named_scope("within_chunks"):
+                u, w = gated_delta_kernels.within_chunks(
+                    k, v, c, beta, kernels, interpret)
+            with jax.named_scope("across_chunks"):
+                out = gated_delta_kernels.across_chunks(
+                    q, k, u, w, c, kernels, interpret)
+        else:
+            with jax.named_scope("within_chunks"):
                 u, w = jax.checkpoint(
                     _within_chunks,
                     policy=jax.checkpoint_policies.save_only_these_names(
                         _INVERSE))(k, v, c, beta)
-            else:
-                u, w = gated_delta_kernels.within_chunks(
-                    k, v, c, beta, kernels, interpret)
-        # the chunk axis first: what the scan walks (the kernels write it so)
-        chunks = (jnp.moveaxis(q, 2, 0), jnp.moveaxis(k, 2, 0),
-                  *((jnp.moveaxis(u, 3, 0), jnp.moveaxis(w, 3, 0))
-                    if kernels is None else (u, w)),
-                  jnp.moveaxis(c, 3, 0))
-        state = jnp.zeros((B, Hk, G, Dk, Dv), jnp.float32)
-        with jax.named_scope("across_chunks"):
-            _, out = lax.scan(jax.checkpoint(_chunk_step), state, chunks)
-    out = jnp.moveaxis(out, 0, 3).reshape(B, Hv, N * chunk, Dv)
+            # the chunk axis first: what the scan walks
+            chunks = (jnp.moveaxis(q, 2, 0), jnp.moveaxis(k, 2, 0),
+                      jnp.moveaxis(u, 3, 0), jnp.moveaxis(w, 3, 0),
+                      jnp.moveaxis(c, 3, 0))
+            state = jnp.zeros((B, Hk, G, Dk, Dv), jnp.float32)
+            with jax.named_scope("across_chunks"):
+                _, out = lax.scan(jax.checkpoint(_chunk_step), state, chunks)
+    if kernels is None:
+        out = jnp.moveaxis(out, 0, 3)
+    out = out.reshape(B, Hv, N * chunk, Dv)
     return out[:, :, :T] if pad else out

@@ -1,12 +1,13 @@
-"""The gated delta rule's chunk-local algebra as Pallas TPU kernels: a chunk
-lives in VMEM from its operands to its results.
+"""The gated delta rule as Pallas TPU kernels: a chunk lives in VMEM from its
+operands to its results, and a head's state from a row's first chunk to its
+last.
 
-``within_chunks(k, v, c, beta, plan)`` is ``gated_delta._within_chunks`` where
-the rule (``plan``) says so: k (B, Hk, N, C, Dk) shared by the G value heads
-of its group, v (B, Hk, G, N, C, Dv), c and beta (B, Hk, G, N, C) float32 ->
-``U`` (N, B, Hk, G, C, Dv) float32 and ``W`` (N, B, Hk, G, C, Dk) in the
-operands' dtype, the chunk axis first as the scan over chunks walks it. The
-mathematics is ``_within_chunks``' and ``_inverse_bwd``'s::
+**The chunk-local algebra.** ``within_chunks(k, v, c, beta, plan)`` is
+``gated_delta._within_chunks`` where the rule (``plan``) says so: k (B, Hk, N,
+C, Dk) shared by the G value heads of its group, v (B, Hk, G, N, C, Dv), c and
+beta (B, Hk, G, N, C) float32 -> ``U`` (B, Hk, G, N, C, Dv) float32 and ``W``
+(B, Hk, G, N, C, Dk) in the operands' dtype. The mathematics is
+``_within_chunks``' and ``_inverse_bwd``'s::
 
     L = tril(beta (K K^T) exp(c_i - c_j), -1)       X = (I + L)^-1
     U = X (beta V)                                  W = X (beta e^c K)
@@ -18,7 +19,7 @@ float32, and every product that has ``L`` or ``X`` as an operand is a float32
 contraction at ``precision=HIGHEST``. ``X`` is by block forward substitution
 (``gated_delta._unit_lower_inverse`` says why not a product of powers).
 
-What the kernels do that the ``jax.numpy`` form does not:
+What these kernels do that the ``jax.numpy`` form does not:
 
 * A grid step takes ``plan.chunks`` chunks of one (batch, key head), forms
   ``K K^T`` once for the group and then, a value head at a time, ``L``, ``X``,
@@ -34,11 +35,27 @@ What the kernels do that the ``jax.numpy`` form does not:
   chunk at a time, 4.8 by pairs), so the ten products of the inverse are paid
   once for two chunks. ``X`` is kept as pairs too: (N / 2, C, 2C), no lane
   padded in HBM.
-* ``U`` and ``W`` are written chunk-major, as the scan over chunks reads
-  them: no ``moveaxis`` between the two halves of the operator.
 
-``plan`` is the one rule that says whether the kernels engage, as
-``flash_attention.plan`` is attention's; traced kernels are kept by
+**The scan over chunks.** ``across_chunks(q, k, U, W, c, plan)`` is
+``gated_delta._chunk_step`` under its ``lax.scan``: the outputs (B, Hk, G, N,
+C, Dv), which reshape to (B, Hv, T, Dv) with no transpose. A forward and a
+backward kernel (``gated_delta_scan_fwd`` / ``_bwd``) walk the chunks of a
+(batch, key head) in order (backward: from the last), ``plan.chunks`` a grid
+step, with the group's states (G, Dk, Dv) float32 (backward: their
+cotangents) in VMEM scratch over the whole walk. The ``lax.scan`` moves the
+states of every head out to HBM and back each of its T / C trips, writes them
+once more as its residual, and pays a ``while`` trip around a handful of small
+fusions: ten times the trip's arithmetic on a v5e (PERF.md section 6, PR 42).
+The precision is ``_chunk_step``'s and no other: the state, ``V' = U - W S``
+and every decay float32 (a difference of ``c`` masked before its ``exp``),
+every product's operands cast to the trunk's dtype, the state and the
+cotangents too, with float32 accumulation, the output rounded once. Forward
+also writes the state every chunk STARTED from ((T / C) x Dk x Dv float32 a
+value head: the size of ``X``); backward reads it, makes the chunk's ``V'``
+and ``A`` again from it, and never runs the walk forward.
+
+``plan`` is the one rule that says whether the kernels engage, all four or
+none, as ``flash_attention.plan`` is attention's; traced kernels are kept by
 ``grouped_matmul._kernel``'s store.
 """
 
@@ -57,6 +74,7 @@ from .registry import keep
 _LANES = 128
 _HIGHEST = lax.Precision.HIGHEST
 _NT = (((1,), (1,)), ((), ()))   # x @ y.T
+_TN = (((0,), (0,)), ((), ()))   # x.T @ y
 # Tokens a chunk the kernels hold: two chunks fill the 128 lanes.
 _CHUNKS = (64,)
 # Chunks a grid step: ``c`` and ``beta`` arrive a row a pair of chunks, whole
@@ -75,14 +93,17 @@ class Plan(NamedTuple):
 
 def plan(platform, vmem_bytes, dtype, Dk, Dv, group, chunk,
          T) -> Optional[Plan]:
-    """The rule. The kernels engage where the program is lowered for one
-    TPU whose VMEM is known, the operands are bfloat16 (a float32 trunk
-    keeps the ``jax.numpy`` form), ``Dk`` and ``Dv`` are multiples of 128,
-    ``chunk`` is one of ``_CHUNKS``, and what a grid step of ``_BLOCK``
-    chunks moves in the backward kernel (the larger of the two: operands,
-    cotangents, inverses and results of the group), twice for the
-    pipeline's two buffers, is under half the VMEM. T is padded to whole
-    grid steps by the caller (``padded``). None = the ``jax.numpy`` form."""
+    """The rule, of all four kernels: a node runs every one of them or
+    none. They engage where the program is lowered for one TPU whose VMEM
+    is known, the operands are bfloat16 (a float32 trunk keeps the
+    ``jax.numpy`` form), ``Dk`` and ``Dv`` are multiples of 128, ``chunk``
+    is one of ``_CHUNKS``, and what a grid step of ``_BLOCK`` chunks moves
+    in the larger of the two backward kernels (the chunk-local algebra's:
+    operands, cotangents, inverses and results of the group; the scan's:
+    q, k, ``U``, ``W``, the outputs' cotangent, a start state a chunk and
+    the five results), twice for the pipeline's two buffers, is under half
+    the VMEM. T is padded to whole grid steps by the caller (``padded``).
+    None = the ``jax.numpy`` form."""
     if platform != "tpu" or not vmem_bytes:
         return None
     if jnp.dtype(dtype) != jnp.bfloat16 or Dk % _LANES or Dv % _LANES:
@@ -90,10 +111,15 @@ def plan(platform, vmem_bytes, dtype, Dk, Dv, group, chunk,
     if chunk not in _CHUNKS:
         return None
     rows = _BLOCK * chunk
-    need = 2 * (2 * rows * Dk * 2                       # k, dk
-                + group * rows * (2 * Dv * 2 + Dk * 2   # v, dv, dw
-                                  + Dv * 4 + chunk * 4  # du, X
-                                  + 4 * 4))             # c, beta, dc, dbeta
+    within = (2 * rows * Dk * 2                       # k, dk
+              + group * rows * (2 * Dv * 2 + Dk * 2   # v, dv, dw
+                                + Dv * 4 + chunk * 4  # du, X
+                                + 4 * 4))             # c, beta, dc, dbeta
+    scan = (4 * rows * Dk * 2                         # q, k, dq, dk
+            + group * rows * (2 * Dv * 4 + 2 * Dk * 2  # u, du, w, dw
+                              + Dv * 2 + 2 * 4)        # do, c, dc
+            + group * _BLOCK * Dk * Dv * 4)            # a start state a chunk
+    need = 2 * max(within, scan) + group * Dk * Dv * 4  # the carried states
     if need > vmem_bytes // 2:
         return None
     return Plan(_BLOCK, min(vmem_bytes * 3 // 4, need + (16 << 20)))
@@ -209,33 +235,37 @@ def _keys(k_ref, i):
     return kb, [x.astype(jnp.float32) for x in kb], kk
 
 
-def _for_the_pairs(chunks, pair):
+def _for_each(count, step):
+    """``step(i)`` for i in 0 .. count - 1, in order, as one loop."""
     def body(i, carry):
-        pair(i)
+        step(i)
         return carry
 
-    lax.fori_loop(0, chunks // 2, body, None)
+    lax.fori_loop(0, count, body, None)
 
 
-def _specs(chunks, group, C, Dk, Dv):
-    """BlockSpecs over the grid (batch, key head, block of chunks): the key
-    head's rows, a value-wide and a key-wide block of the group chunk-major
-    as the scan reads them, the same the group's heads first, and a row of
-    vectors and a (C, 2C) inverse a pair of chunks."""
+def _specs(chunks, group, C, Dk, Dv, block=lambda i: i):
+    """BlockSpecs over the grid (batch, key head, block of chunks), the grid
+    step ``i`` taking the block ``block(i)`` (the scan's backward walks from
+    the last): the key head's rows, a value-wide and a key-wide block of
+    the group, a row of vectors and a (C, 2C) inverse a pair of chunks, a
+    row of vectors a chunk, and a (Dk, Dv) state a chunk."""
     pl, _ = _gmm._pallas()
     return dict(
         k=pl.BlockSpec((None, None, chunks, C, Dk),
-                       lambda b, h, i: (b, h, i, 0, 0)),
+                       lambda b, h, i: (b, h, block(i), 0, 0)),
         v=pl.BlockSpec((None, None, group, chunks, C, Dv),
-                       lambda b, h, i: (b, h, 0, i, 0, 0)),
-        u=pl.BlockSpec((chunks, None, None, group, C, Dv),
-                       lambda b, h, i: (i, b, h, 0, 0, 0)),
-        w=pl.BlockSpec((chunks, None, None, group, C, Dk),
-                       lambda b, h, i: (i, b, h, 0, 0, 0)),
+                       lambda b, h, i: (b, h, 0, block(i), 0, 0)),
+        w=pl.BlockSpec((None, None, group, chunks, C, Dk),
+                       lambda b, h, i: (b, h, 0, block(i), 0, 0)),
         vec=pl.BlockSpec((None, None, group, chunks // 2, 2 * C),
-                         lambda b, h, i: (b, h, 0, i, 0)),
+                         lambda b, h, i: (b, h, 0, block(i), 0)),
         x=pl.BlockSpec((None, None, group, chunks // 2, C, 2 * C),
-                       lambda b, h, i: (b, h, 0, i, 0, 0)))
+                       lambda b, h, i: (b, h, 0, block(i), 0, 0)),
+        c=pl.BlockSpec((None, None, group, chunks, C),
+                       lambda b, h, i: (b, h, 0, block(i), 0)),
+        s=pl.BlockSpec((None, None, group, chunks, Dk, Dv),
+                       lambda b, h, i: (b, h, 0, block(i), 0, 0)))
 
 
 def _cost(k, v, matmuls, passes):
@@ -260,8 +290,8 @@ def _pairs(x):
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
 def _fwd(k, v, c, beta, *, chunks, vmem_limit, interpret):
-    """(U, W chunk-major, X (B, Hk, G, N / 2, C, 2C) float32: the inverses
-    of a pair of chunks side by side)."""
+    """(U, W, X (B, Hk, G, N / 2, C, 2C) float32: the inverses of a pair of
+    chunks side by side)."""
     pl, pltpu = _gmm._pallas()
     B, Hk, N, C, Dk = k.shape
     G, Dv = v.shape[2], v.shape[-1]
@@ -283,21 +313,21 @@ def _fwd(k, v, c, beta, *, chunks, vmem_limit, interpret):
                         [v_ref[g, n].astype(jnp.float32) * betas[j],
                          kf[j] * (betas[j] * jnp.exp(cs[j]))], axis=1)
                     solved = _hi(x, _half(rhs, j))
-                    u_ref[n, g] = solved[:, :Dv]
-                    w_ref[n, g] = solved[:, Dv:].astype(w_ref.dtype)
+                    u_ref[g, n] = solved[:, :Dv]
+                    w_ref[g, n] = solved[:, Dv:].astype(w_ref.dtype)
 
-        _for_the_pairs(chunks, pair)
+        _for_each(chunks // 2, pair)
 
     s = _specs(chunks, G, C, Dk, Dv)
     return pl.pallas_call(
         kernel,
-        out_shape=(jax.ShapeDtypeStruct((N, B, Hk, G, C, Dv), jnp.float32),
-                   jax.ShapeDtypeStruct((N, B, Hk, G, C, Dk), k.dtype),
+        out_shape=(jax.ShapeDtypeStruct((B, Hk, G, N, C, Dv), jnp.float32),
+                   jax.ShapeDtypeStruct((B, Hk, G, N, C, Dk), k.dtype),
                    jax.ShapeDtypeStruct((B, Hk, G, N // 2, C, 2 * C),
                                         jnp.float32)),
         grid=(B, Hk, N // chunks),
         in_specs=[s["k"], s["v"], s["vec"], s["vec"]],
-        out_specs=[s["u"], s["w"], s["x"]],
+        out_specs=[s["v"], s["w"], s["x"]],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=vmem_limit),
@@ -335,7 +365,7 @@ def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
                     rise = jnp.exp(cs[j])
                     vf = v_ref[g, n].astype(jnp.float32)
                     d_solved = jnp.concatenate(
-                        [du_ref[n, g], dw_ref[n, g].astype(jnp.float32)],
+                        [du_ref[g, n], dw_ref[g, n].astype(jnp.float32)],
                         axis=1)
                     rhs = jnp.concatenate(
                         [vf * betas[j], kf[j] * (betas[j] * rise)], axis=1)
@@ -372,7 +402,7 @@ def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
                     both, kb[j], preferred_element_type=jnp.float32)).astype(
                         dk_ref.dtype)
 
-        _for_the_pairs(chunks, pair)
+        _for_each(chunks // 2, pair)
 
     s = _specs(chunks, G, C, Dk, Dv)
     dk, dv, dc, dbeta = pl.pallas_call(
@@ -382,7 +412,7 @@ def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
                    jax.ShapeDtypeStruct(_pairs(c).shape, jnp.float32),
                    jax.ShapeDtypeStruct(_pairs(c).shape, jnp.float32)),
         grid=(B, Hk, N // chunks),
-        in_specs=[s["k"], s["v"], s["vec"], s["vec"], s["x"], s["u"],
+        in_specs=[s["k"], s["v"], s["vec"], s["vec"], s["x"], s["v"],
                   s["w"]],
         out_specs=[s["k"], s["v"], s["vec"], s["vec"]],
         compiler_params=pltpu.CompilerParams(
@@ -395,12 +425,224 @@ def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
     return dk, dv, dc.reshape(c.shape), dbeta.reshape(c.shape)
 
 
+# --- the scan over chunks ----------------------------------------------------
+def _mm(a, b, dims=(((1,), (0,)), ((), ()))):
+    """A product of the trunk's dtype with float32 accumulation:
+    ``gated_delta._chunk_step``'s ``mm``, the casts made by the caller."""
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+class _Chunk(NamedTuple):
+    """Index planes of one chunk (C, C) and what they make of a row."""
+
+    eye: jax.Array
+    seen: jax.Array    # the column's token is the row's or before it
+    final: jax.Array   # (1, C): the chunk's last token
+
+    def column(self, x):
+        """A row (1, C) -> the column (C, 1), exactly."""
+        return jnp.sum(jnp.where(self.eye, x, 0.0), axis=1, keepdims=True)
+
+    def row(self, x):
+        """A column (C, 1) -> the row (1, C), exactly."""
+        return jnp.sum(jnp.where(self.eye, x, 0.0), axis=0, keepdims=True)
+
+    def decays(self, c_row):
+        """Of a head's cumulative log decay (1, C): it as a column, its
+        last entry (1, 1), and ``exp(c_i - c_j)`` where j <= i, masked
+        before the ``exp`` (``gated_delta._decay``)."""
+        c = self.column(c_row)
+        last = jnp.sum(jnp.where(self.final, c_row, 0.0), axis=1,
+                       keepdims=True)
+        decay = jnp.where(
+            self.seen, jnp.exp(jnp.where(self.seen, c - c_row, 0.0)), 0.0)
+        return c, last, decay
+
+
+def _chunk_planes(C):
+    row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    lane = lax.broadcasted_iota(jnp.int32, (1, C), 1)
+    return _Chunk(row == col, col <= row, lane == C - 1)
+
+
+def _scan_cost(q, u, matmuls, passes):
+    """``matmuls`` (C x D) x (D x D) products a chunk and value head,
+    ``passes`` over the operands and a start state a chunk."""
+    pl, _ = _gmm._pallas()
+    B, Hk, G, N, C, Dv = u.shape
+    Dk = q.shape[-1]
+    heads = B * Hk * G * N
+    return pl.CostEstimate(
+        flops=heads * matmuls * 2 * C * Dk * Dv,
+        transcendentals=heads * C * (C + 2),
+        bytes_accessed=passes * (2 * q.size * 2 + u.size * 4
+                                 + heads * C * (2 * Dk + 2 * Dv))
+        + heads * Dk * Dv * 4)
+
+
+@functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
+                                             "interpret"))
+def _scan_fwd(q, k, u, w, c, *, chunks, vmem_limit, interpret):
+    """(the outputs (B, Hk, G, N, C, Dv) in q's dtype, the state every
+    chunk STARTED from (B, Hk, G, N, Dk, Dv) float32): ``_chunk_step`` over
+    the chunks of a (batch, key head) in order, the group's states in VMEM
+    scratch from the first block of chunks to the last."""
+    pl, pltpu = _gmm._pallas()
+    B, Hk, N, C, Dk = q.shape
+    G, Dv = u.shape[2], u.shape[-1]
+    dt = q.dtype
+
+    def kernel(q_ref, k_ref, u_ref, w_ref, c_ref, o_ref, s_ref, state):
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            state[...] = jnp.zeros_like(state)
+
+        p = _chunk_planes(C)
+
+        def chunk(n):
+            qb, kb = q_ref[n], k_ref[n]
+            qf, kf = qb.astype(jnp.float32), kb.astype(jnp.float32)
+            qk = _mm(qb, kb, _NT)
+            for g in range(G):
+                cs, last, decay = p.decays(c_ref[g, pl.ds(n, 1), :])
+                s = state[g]
+                s_ref[g, n] = s
+                sb = s.astype(dt)
+                written = (u_ref[g, n] - _mm(w_ref[g, n], sb)).astype(dt)
+                out = _mm((qf * jnp.exp(cs)).astype(dt), sb) \
+                    + _mm((qk * decay).astype(dt), written)
+                o_ref[g, n] = out.astype(o_ref.dtype)
+                state[g] = s * jnp.exp(last) + _mm(
+                    (kf * jnp.exp(last - cs)).astype(dt), written, _TN)
+
+        _for_each(chunks, chunk)
+
+    s = _specs(chunks, G, C, Dk, Dv)
+    return pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct(u.shape, dt),
+                   jax.ShapeDtypeStruct((B, Hk, G, N, Dk, Dv), jnp.float32)),
+        grid=(B, Hk, N // chunks),
+        in_specs=[s["k"], s["k"], s["v"], s["w"], s["c"]],
+        out_specs=[s["v"], s["s"]],
+        scratch_shapes=[pltpu.VMEM((G, Dk, Dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        cost_estimate=_scan_cost(q, u, matmuls=4, passes=1),
+        interpret=interpret,
+        name="gated_delta_scan_fwd",
+    )(q, k, u, w, c)
+
+
+@functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
+                                             "interpret"))
+def _scan_bwd(q, k, u, w, c, states, do, *, chunks, vmem_limit, interpret):
+    """(dq, dk, dU, dW, dc) in the operands' shapes and dtypes: the chunks
+    of a (batch, key head) from the last to the first, the cotangent of
+    the group's states in VMEM scratch; a chunk's forward values are made
+    again from the state it started from. With ``V' = U - W S``, ``A = (Q
+    K^T) * decay``, ``O = (e^c Q) S + A V'`` and ``S' = e^last S + (e^(last
+    - c) K)^T V'``, every product's operands cast as forward casts them::
+
+        dV' = A^T dO + (e^(last - c) K) dS'        dU = dV'   dW = -dV' S^T
+        dS  = e^last dS' + (e^c Q)^T dO - W^T dV'
+        dA  = dO V'^T     d(Q K^T) = sum over the group of dA * decay
+        dc  = rows(dA * A) - columns(dA * A) + e^c (dO S^T . Q)
+              - e^(last - c) (V' dS'^T . K), and at the chunk's last token
+              + e^last (dS' . S) + sum(e^(last - c) (V' dS'^T . K))
+    """
+    pl, pltpu = _gmm._pallas()
+    B, Hk, N, C, Dk = q.shape
+    G, Dv = u.shape[2], u.shape[-1]
+    dt = q.dtype
+    blocks = N // chunks
+
+    def kernel(q_ref, k_ref, u_ref, w_ref, c_ref, s_ref, do_ref,
+               dq_ref, dk_ref, du_ref, dw_ref, dc_ref, dstate):
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            dstate[...] = jnp.zeros_like(dstate)
+
+        p = _chunk_planes(C)
+
+        def total(x):
+            return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0,
+                           keepdims=True)
+
+        def chunk(i):
+            n = chunks - 1 - i
+            qb, kb = q_ref[n], k_ref[n]
+            qf, kf = qb.astype(jnp.float32), kb.astype(jnp.float32)
+            qk = _mm(qb, kb, _NT)
+            dqk = jnp.zeros((C, C), jnp.float32)
+            dq = jnp.zeros((C, Dk), jnp.float32)
+            dk = jnp.zeros((C, Dk), jnp.float32)
+            for g in range(G):
+                cs, last, decay = p.decays(c_ref[g, pl.ds(n, 1), :])
+                rise, fade, gain = (jnp.exp(cs), jnp.exp(last - cs),
+                                    jnp.exp(last))
+                s, ds = s_ref[g, n], dstate[g]
+                sb, dsb = s.astype(dt), ds.astype(dt)
+                wb, gb = w_ref[g, n], do_ref[g, n]
+                written = (u_ref[g, n] - _mm(wb, sb)).astype(dt)
+                a = qk * decay
+                risen, faded = (qf * rise).astype(dt), (kf * fade).astype(dt)
+                d_risen = _mm(gb, sb, _NT)
+                d_faded = _mm(written, dsb, _NT)
+                da = _mm(gb, written, _NT)
+                d_written = _mm(a.astype(dt), gb, _TN) + _mm(faded, dsb)
+                du_ref[g, n] = d_written
+                d_written = d_written.astype(dt)
+                dw_ref[g, n] = (-_mm(d_written, sb, _NT)).astype(dw_ref.dtype)
+                dstate[g] = ds * gain + _mm(risen, gb, _TN) \
+                    - _mm(wb, d_written, _TN)
+                moved = da * a
+                d_rise = jnp.sum(d_risen * qf, axis=1, keepdims=True) * rise
+                d_fade = jnp.sum(d_faded * kf, axis=1, keepdims=True) * fade
+                d_last = total(ds * s) * gain + jnp.sum(
+                    d_fade, axis=0, keepdims=True)
+                dc_ref[g, pl.ds(n, 1), :] = p.row(
+                    d_rise - d_fade + jnp.sum(moved, axis=1, keepdims=True)) \
+                    - jnp.sum(moved, axis=0, keepdims=True) \
+                    + jnp.where(p.final, d_last, 0.0)
+                dqk = dqk + da * decay
+                dq = dq + d_risen * rise
+                dk = dk + d_faded * fade
+            dqk = dqk.astype(dt)
+            dq_ref[n] = (dq + _mm(dqk, kb)).astype(dq_ref.dtype)
+            dk_ref[n] = (dk + _mm(dqk, qb, _TN)).astype(dk_ref.dtype)
+
+        _for_each(chunks, chunk)
+
+    s = _specs(chunks, G, C, Dk, Dv, lambda i: blocks - 1 - i)
+    return pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct(q.shape, dt),
+                   jax.ShapeDtypeStruct(k.shape, dt),
+                   jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct(w.shape, w.dtype),
+                   jax.ShapeDtypeStruct(c.shape, jnp.float32)),
+        grid=(B, Hk, blocks),
+        in_specs=[s["k"], s["k"], s["v"], s["w"], s["c"], s["s"], s["v"]],
+        out_specs=[s["k"], s["k"], s["v"], s["w"], s["c"]],
+        scratch_shapes=[pltpu.VMEM((G, Dk, Dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        cost_estimate=_scan_cost(q, u, matmuls=10, passes=2),
+        interpret=interpret,
+        name="gated_delta_scan_bwd",
+    )(q, k, u, w, c, states, do)
+
+
 # --- what chunk_gated_delta_rule calls ----------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def within_chunks(k, v, c, beta, plan, interpret=False):
-    """(U, W), the chunk axis first: the forward kernel at ``plan``'s block.
-    N is a multiple of ``plan.chunks`` (``padded``). Backward keeps the
-    operands and the chunks' inverses; under per-operator recomputation
+    """(U, W): the forward kernel at ``plan``'s block. N is a multiple of
+    ``plan.chunks`` (``padded``). Backward keeps the operands and the
+    chunks' inverses; under per-operator recomputation
     (``MXNET_BACKWARD_DO_MIRROR``) the inverses, ``U`` and ``W`` are what
     the operator names (``registry.keep``), so that the forward kernel runs
     once a step and not again in backward. ``interpret`` runs the kernels
@@ -426,3 +668,27 @@ def _within_bwd(plan, interpret, res, g):
 
 
 within_chunks.defvjp(_within_fwd, _within_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def across_chunks(q, k, u, w, c, plan, interpret=False):
+    """The outputs (B, Hk, G, N, C, Dv) of the scan over chunks of q, k (B,
+    Hk, N, C, Dk), ``within_chunks``' ``U`` and ``W`` and c (B, Hk, G, N, C)
+    float32, the state 0 before a row's first chunk: the forward kernel at
+    ``plan``'s block. Backward keeps the operands and the state every chunk
+    started from, which the operator names (``registry.keep``): under
+    per-operator recomputation the forward kernel runs once a step."""
+    return _across_fwd(q, k, u, w, c, plan, interpret)[0]
+
+
+def _across_fwd(q, k, u, w, c, plan, interpret):
+    out, states = _gmm._kernel(_scan_fwd, (q, k, u, w, c),
+                               **_static(plan, interpret))
+    return out, (q, k, u, w, c, keep(states))
+
+
+def _across_bwd(plan, interpret, res, g):
+    return _gmm._kernel(_scan_bwd, (*res, g), **_static(plan, interpret))
+
+
+across_chunks.defvjp(_across_fwd, _across_bwd)

@@ -1,11 +1,13 @@
-"""The Pallas kernels of the gated delta rule's chunk-local algebra
-(``ops/gated_delta_kernels.py``) in Pallas's interpreter on the CPU, against
-the ``jax.numpy`` form they stand in for (``gated_delta._within_chunks``) and
-the token-by-token recurrence of the plain reference: head width 128, two
-value heads a key head, chunks of 64, T a whole number of grid steps (1024)
-and one that is padded (1100 -> 2048). Their compile for a described v5e sits
-with the other compile tests in ``test_grouped_matmul.py`` (one file, one
-libtpu).
+"""The Pallas kernels of the gated delta rule (``ops/gated_delta_kernels.py``:
+the chunk-local algebra and the scan over chunks) in Pallas's interpreter on
+the CPU, against the ``jax.numpy`` form they stand in for
+(``gated_delta._within_chunks``, ``_chunk_step`` under ``lax.scan``) and the
+token-by-token recurrence of the plain reference: head width 128, two value
+heads a key head, chunks of 64, T a whole number of grid steps (1024) and one
+that is padded (1100 -> 2048); the scan's own cases at two key heads and grid
+steps of two chunks, so that a few hundred tokens cross several steps. Their
+compile for a described v5e sits with the other compile tests in
+``test_grouped_matmul.py`` (one file, one libtpu).
 
 Tolerances, and why: with float32 operands the kernels and the ``jax.numpy``
 form compute the same float32 algebra in another order (pairs of chunks, the
@@ -39,13 +41,13 @@ def _inputs(t):
 
 
 @functools.lru_cache(maxsize=None)
-def _kernels_and_form(t, dtype):
-    """{tensor: (through the kernels, the ``jax.numpy`` form)}: outputs and
-    all five gradients."""
+def _kernels_and_form(t, dtype, make=_inputs, plan=PLAN):
+    """{tensor: (through the kernels at ``plan``, the ``jax.numpy`` form)}
+    of ``make(t)``: outputs and all five gradients."""
     import jax
     import jax.numpy as jnp
 
-    q, k, v, g, beta = _inputs(t)
+    q, k, v, g, beta = make(t)
     args = [jnp.asarray(x, dtype) for x in (q, k, v)] \
         + [jnp.asarray(g), jnp.asarray(beta)]
     head = jnp.asarray(np.random.RandomState(4).randn(*v.shape), dtype)
@@ -55,7 +57,7 @@ def _kernels_and_form(t, dtype):
             gd.chunk_gated_delta_rule, chunk=CHUNK, **kw), *args)
         return (out,) + vjp(head)
 
-    got, want = both(kernels=PLAN, interpret=True), both()
+    got, want = both(kernels=plan, interpret=True), both()
     return {n: (np.asarray(a, np.float32), np.asarray(b, np.float32))
             for n, a, b in zip(TENSORS, got, want)}
 
@@ -116,7 +118,9 @@ def test_every_product_with_the_inverse_is_float32_at_highest():
         lambda *a: jnp.sum(f(*a).astype(jnp.float32)),
         (0, 1, 2, 3, 4)))(*args).jaxpr) if e.primitive.name == "pallas_call"]
     assert sorted(e.params["name"] for e in calls) == [
-        "gated_delta_chunks_bwd", "gated_delta_chunks_fwd"]
+        "gated_delta_chunks_bwd", "gated_delta_chunks_fwd",
+        "gated_delta_scan_bwd", "gated_delta_scan_fwd"]
+    calls = [e for e in calls if "chunks" in e.params["name"]]
     trunk, highest = 0, 0
     for call in calls:
         for e in tq._eqns(call.params["jaxpr"]):
@@ -242,6 +246,164 @@ def test_on_the_cpu_the_op_takes_the_jax_numpy_form():
     assert exe.forward()[0].shape == inputs[2].shape
 
 
+# --- the scan over chunks in its kernels --------------------------------------
+# a grid step of two chunks: 384 tokens are three steps, 300 are padded to them
+STEP = gk.Plan(2, 64 << 20)
+
+
+def _scan_inputs(t, slow=False):
+    """Two key heads under four value heads; ``slow``: decays near 1 (a
+    state fades by under a tenth over all of T), so that what a chunk
+    reads is mostly what the chunks before it wrote."""
+    q, k, v, g, beta = tq._rule_inputs(t, key_heads=2, value_heads=4,
+                                       batch=1, dim=D)
+    return [q, k, v, g * (0.1 / t if slow else 1.0), beta]
+
+
+def _slow_scan_inputs(t):
+    return _scan_inputs(t, slow=True)
+
+
+@pytest.mark.parametrize("tensor", TENSORS)
+@pytest.mark.parametrize("dtype,tolerance", [("float32", 2e-5),
+                                             ("bfloat16", 8e-3)])
+@pytest.mark.parametrize("t", [384, 300], ids=["three_steps", "padded"])
+def test_scan_kernels_match_the_jax_numpy_form(t, dtype, tolerance, tensor):
+    got, want = _kernels_and_form(t, dtype, _scan_inputs, STEP)[tensor]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert rel(got, want) < tolerance
+
+
+@pytest.mark.parametrize("tensor", TENSORS)
+def test_scan_kernels_carry_the_state_over_chunks_and_grid_steps(tensor):
+    """Decays near 1: the form's output over the last grid step's tokens is
+    far from what the same tokens give alone (a state dropped at a grid
+    step's edge), and the kernels hold the form's answer."""
+    import jax.numpy as jnp
+
+    t, step = 384, CHUNK * STEP.chunks
+    got, want = _kernels_and_form(t, "float32", _slow_scan_inputs,
+                                  STEP)[tensor]
+    assert rel(got, want) < 2e-5
+    if tensor == "output":
+        alone = gd.chunk_gated_delta_rule(
+            *[jnp.asarray(x[:, :, t - step:])
+              for x in _slow_scan_inputs(t)], chunk=CHUNK)
+        assert rel(alone, want[:, :, t - step:]) > 0.3
+        chunk = gd.chunk_gated_delta_rule(
+            *[jnp.asarray(x[:, :, t - CHUNK:])
+              for x in _slow_scan_inputs(t)], chunk=CHUNK)
+        assert rel(chunk, want[:, :, t - CHUNK:]) > 0.3
+
+
+SCAN_TENSORS = ["output", "dq", "dk", "dU", "dW", "dc"]
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_alone(dtype):
+    """{tensor: (``across_chunks``, ``_chunk_step`` under ``lax.scan``)} on
+    the same ``U`` and ``W``: the new kernels with nothing else between."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    b, hk, g, n = 1, 2, 2, 6
+    rs = np.random.RandomState(7)
+    q, k = (rs.randn(b, hk, n, CHUNK, D) for _ in range(2))
+    q, k = (x / np.linalg.norm(x, axis=-1, keepdims=True) for x in (q, k))
+    args = [jnp.asarray(q / np.sqrt(D), dtype), jnp.asarray(k, dtype),
+            jnp.asarray(rs.randn(b, hk, g, n, CHUNK, D), jnp.float32),
+            jnp.asarray(0.1 * rs.randn(b, hk, g, n, CHUNK, D), dtype),
+            jnp.cumsum(jnp.asarray(-rs.uniform(
+                0.001, 0.05, (b, hk, g, n, CHUNK)), jnp.float32), -1)]
+    head = jnp.asarray(rs.randn(b, hk, g, n, CHUNK, D), dtype)
+
+    def form(q, k, u, w, c):
+        chunks = (jnp.moveaxis(q, 2, 0), jnp.moveaxis(k, 2, 0),
+                  jnp.moveaxis(u, 3, 0), jnp.moveaxis(w, 3, 0),
+                  jnp.moveaxis(c, 3, 0))
+        state = jnp.zeros((b, hk, g, D, D), jnp.float32)
+        return jnp.moveaxis(lax.scan(gd._chunk_step, state, chunks)[1], 0, 3)
+
+    def both(f):
+        out, vjp = jax.vjp(f, *args)
+        return (out,) + vjp(head)
+
+    got = both(lambda *a: gk.across_chunks(*a, STEP, True))
+    return {name: (np.asarray(a, np.float32), np.asarray(b, np.float32))
+            for name, a, b in zip(SCAN_TENSORS, got, both(form))}
+
+
+@pytest.mark.parametrize("tensor", SCAN_TENSORS)
+@pytest.mark.parametrize("dtype,tolerance", [("float32", 2e-5),
+                                             ("bfloat16", 8e-3)])
+def test_scan_kernels_alone_match_the_scan(dtype, tolerance, tensor):
+    got, want = _scan_alone(dtype)[tensor]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert rel(got, want) < tolerance
+
+
+def test_scan_kernels_products_are_the_trunks_with_float32_accumulation():
+    """``_chunk_step``'s precision and no other: inside both kernels of the
+    scan every ``dot_general`` has operands of the trunk's dtype (the state
+    and every cotangent cast to it, as ``mm`` casts them) and a float32
+    result, every ``exp`` is float32, and the state the forward kernel
+    carries, writes and the backward kernel reads is float32."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v, g, beta = _inputs(1024)
+    args = [jnp.asarray(x, jnp.bfloat16) for x in (q, k, v)] \
+        + [jnp.asarray(g), jnp.asarray(beta)]
+    f = functools.partial(gd.chunk_gated_delta_rule, chunk=CHUNK,
+                          kernels=PLAN, interpret=True)
+    calls = {e.params["name"]: e for e in tq._eqns(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(f(*a).astype(jnp.float32)),
+        (0, 1, 2, 3, 4)))(*args).jaxpr) if e.primitive.name == "pallas_call"}
+    products = {}
+    for name in ("gated_delta_scan_fwd", "gated_delta_scan_bwd"):
+        body = list(tq._eqns(calls[name].params["jaxpr"]))
+        assert any(e.primitive.name == "exp" for e in body)
+        for e in body:
+            if e.primitive.name == "exp":
+                assert e.outvars[0].aval.dtype == jnp.float32, e
+            if e.primitive.name == "dot_general":
+                a, b = (x.aval for x in e.invars)
+                assert a.dtype == b.dtype == jnp.bfloat16, e
+                assert e.outvars[0].aval.dtype == jnp.float32, e
+                products[name] = products.get(name, 0) + 1
+    # a chunk: Q K^T once, then a value head: W S, (e^c Q) S, A V', K^T V'
+    # forward; backward W S again, eight of the cotangents, and dq, dk
+    assert products == {"gated_delta_scan_fwd": 1 + 2 * 4,
+                        "gated_delta_scan_bwd": 1 + 2 * 9 + 2}
+    # the start states: the forward kernel's second result, float32
+    states = calls["gated_delta_scan_fwd"].outvars[1].aval
+    assert states.dtype == jnp.float32 and states.shape[-2:] == (D, D)
+    assert [x.aval.dtype for x in calls["gated_delta_scan_fwd"].params[
+        "jaxpr"].invars][-1] == jnp.float32     # the carried state (scratch)
+
+
+@pytest.mark.parametrize("case", sorted(
+    c for c, (_, engages) in RULE_CASES.items() if not engages))
+def test_without_a_plan_the_scan_is_the_while_it_was(case):
+    """Where the rule returns None the operator is the ``jax.numpy`` form:
+    its lowered text holds the ``lax.scan``'s ``while`` and no kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    (platform, vmem, dtype, dk, dv, group, chunk, t), _ = RULE_CASES[case]
+    assert gk.plan(platform, vmem, dtype, dk, dv, group, chunk, t) is None
+    t = 4 * chunk
+    shapes = [(1, 1, t, dk), (1, 1, t, dk), (1, group, t, dv),
+              (1, group, t), (1, group, t)]
+    text = jax.jit(functools.partial(
+        gd.chunk_gated_delta_rule, chunk=chunk, kernels=None)).lower(
+            *[jax.ShapeDtypeStruct(s, dtype if i < 3 else jnp.float32)
+              for i, s in enumerate(shapes)]).as_text()
+    assert "stablehlo.while" in text
+    assert "custom_call" not in text
+
+
 # --- the model through the kernels, steered here ------------------------------
 
 @pytest.mark.parametrize("mirror", ["", "1"], ids=["kept", "recomputed"])
@@ -253,8 +415,8 @@ def test_train_program_through_the_kernels_takes_its_inputs(monkeypatch,
     the ``custom_vjp`` over the pair of kernels sits in ``jax.checkpoint``
     then, and a traced value it closed over would be another trace's tracer
     (PR 34: "compiled for 158 inputs but called with 146"). The program
-    launches, counts three kernel layers, and its outputs and every parameter's
-    step are those of the ``jax.numpy`` form."""
+    launches, counts three kernel layers and three scan-kernel layers, and its
+    outputs and every parameter's step are those of the ``jax.numpy`` form."""
     from mxnet_tpu import telemetry as tm
 
     if mirror:
@@ -283,15 +445,19 @@ def test_train_program_through_the_kernels_takes_its_inputs(monkeypatch,
         mod.forward_backward(mx.io.DataBatch(data=[mx.nd.array(ids)],
                                              label=[mx.nd.array(label)]))
         mod.update()
-        counted = tm.snapshot()["executor"].get(
-            "linear_attention_kernel_layers", 0) - before.get(
-                "executor", {}).get("linear_attention_kernel_layers", 0)
+        after = tm.snapshot()["executor"]
+        counted = tuple(
+            after.get(name, 0) - before.get("executor", {}).get(name, 0)
+            for name in ("linear_attention_kernel_layers",
+                         "linear_attention_scan_kernel_layers"))
         return (counted, mod.get_outputs()[0].asnumpy(),
                 {n: a.asnumpy() for n, a in mod.get_params()[0].items()})
 
     form_count, form_out, form_params = step(False)
     count, out, now = step(True)
-    assert (form_count, count) == (0, 3)
+    # ``Executor._count_train_launch``: the chunk-local algebra and the
+    # scan over chunks, three layers each, from the one rule
+    assert (form_count, count) == ((0, 0), (3, 3))
     assert rel(out, form_out) < 1e-4
     for n, a in now.items():
         assert not np.array_equal(a, params[n]), n

@@ -541,10 +541,49 @@ def test_gated_delta_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip):
         arg((b, hk, n, c, d), jnp.bfloat16),
         arg((b, hk, g, n, c, d), jnp.bfloat16),
         arg((b, hk, g, n, c), jnp.float32), arg((b, hk, g, n, c), jnp.float32),
-        arg((n, b, hk, g, c, d), jnp.float32),
-        arg((n, b, hk, g, c, d), jnp.bfloat16)).compile()
+        arg((b, hk, g, n, c, d), jnp.float32),
+        arg((b, hk, g, n, c, d), jnp.bfloat16)).compile()
     text = compiled.as_text()
     assert "gated_delta_chunks_fwd" in text
     assert "gated_delta_chunks_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= b * hk * g * t * c * 4 + (1 << 20)
+
+
+def test_gated_delta_scan_kernels_compile_for_a_v5e_at_the_cell_widths(
+        one_chip):
+    """Mosaic accepts the two kernels of the gated delta rule's scan over
+    chunks at the Qwen3-Next cell's widths (T 8192 in chunks of 64, 16 key
+    over 32 value heads of 128) at the rule's block and VMEM limit; between
+    forward and backward nothing is kept but the state every chunk started
+    from ((T / 64) x 128 x 128 float32 a value head)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import gated_delta_kernels as gk
+
+    b, hk, g, t, d, c = 1, 16, 2, 8192, 128, 64
+    n = t // c
+    plan = gk.plan("tpu", V5E_VMEM, jnp.bfloat16, d, d, g, c, t)
+    assert plan is not None and n % plan.chunks == 0
+
+    def step(q, k, u, w, cum, do):
+        out, vjp = jax.vjp(lambda *a: gk.across_chunks(*a, plan), q, k, u, w,
+                           cum)
+        return (out,) + vjp(do)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        arg((b, hk, n, c, d), jnp.bfloat16),
+        arg((b, hk, n, c, d), jnp.bfloat16),
+        arg((b, hk, g, n, c, d), jnp.float32),
+        arg((b, hk, g, n, c, d), jnp.bfloat16),
+        arg((b, hk, g, n, c), jnp.float32),
+        arg((b, hk, g, n, c, d), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "gated_delta_scan_fwd" in text
+    assert "gated_delta_scan_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= b * hk * g * n * d * d * 4 + (4 << 20)

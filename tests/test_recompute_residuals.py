@@ -94,9 +94,11 @@ CASES = {
     "attention-window-kernels": (
         functools.partial(_attention, kernels=True, window=128), True,
         "pallas_call", 1, 2),
+    # the chunk-local algebra's kernel and the scan's: two backward kernels,
+    # with both forward kernels again or not
     "gated-delta-kernels": (
         functools.partial(_gated_delta, kernels=True), True,
-        "pallas_call", 1, 2),
+        "pallas_call", 2, 4),
     # the jax.numpy form marks nothing: its inner checkpoint is as it was
     "gated-delta-form": (_gated_delta, False, "cumsum", 2, 2),
     "moe-all-held": (_moe, True, "top_k", 0, 1),
@@ -160,14 +162,14 @@ def _counted():
     return tm.snapshot().get("executor", {}).get("kept_residual_nodes", 0)
 
 
-def _recomputed(jaxpr, primitive, inside=False):
-    """Equations of ``primitive`` in the rematerialised parts of ``jaxpr``
-    (under a differentiated ``jax.checkpoint`` equation)."""
+def _equations(jaxpr, inside=False):
+    """(equation, whether it sits in a rematerialised part: under a
+    differentiated ``jax.checkpoint`` equation) of ``jaxpr`` and its
+    sub-programs."""
     from jax._src import core as jcore
 
-    found = 0
     for eqn in jaxpr.eqns:
-        found += inside and eqn.primitive.name == primitive
+        yield eqn, inside
         below = inside or (eqn.primitive.name == "remat2"
                            and eqn.params["differentiated"])
         for value in eqn.params.values():
@@ -175,8 +177,14 @@ def _recomputed(jaxpr, primitive, inside=False):
                 if isinstance(sub, jcore.ClosedJaxpr):
                     sub = sub.jaxpr
                 if isinstance(sub, jcore.Jaxpr):
-                    found += _recomputed(sub, primitive, below)
-    return found
+                    yield from _equations(sub, below)
+
+
+def _recomputed(jaxpr, primitive):
+    """Equations of ``primitive`` in the rematerialised parts of
+    ``jaxpr``."""
+    return sum(inside and eqn.primitive.name == primitive
+               for eqn, inside in _equations(jaxpr))
 
 
 # --- (a) what the operator's checkpoint saves ---------------------------------
@@ -225,8 +233,10 @@ def test_checkpoint_saves_the_marked_values_and_nothing_the_rule_forbids(
         # the output and the rows' log-sum-exp: no (T, T) tile
         assert shapes == [(1, 4, T), (1, 4, T, 128)]
     elif name == "gated-delta-kernels":
-        # the chunks' inverses in pairs, U and W chunk-major
-        assert shapes == [(1, 1, 2, 8, 64, 128), (16, 1, 1, 2, 64, 128)]
+        # the chunks' inverses in pairs, U and W, and the state every
+        # chunk started from: half the widest operand's size in float32
+        assert shapes == [(1, 1, 2, 8, 64, 128), (1, 1, 2, 16, 64, 128),
+                          (1, 1, 2, 16, 128, 128)]
     elif marks:
         # the routed experts, the rows an expert, the sorted order
         assert {(512,), (8,)} <= set(shapes)
@@ -248,6 +258,32 @@ def test_gradient_program_holds_the_forward_once(monkeypatch, case):
                                       primitive))
             assert exe._kept_residual_nodes == int(marks and policy)
     assert counts == [kept, again]
+
+
+@pytest.mark.parametrize("policy,again", [(True, []), (False, [
+    "gated_delta_chunks_fwd", "gated_delta_scan_fwd"])],
+    ids=["kept", "no-policy"])
+def test_gated_delta_forward_kernels_run_once_by_name(monkeypatch, policy,
+                                                      again):
+    """The kernels of ``GatedDeltaRule`` by name: the gradient's program
+    holds each forward kernel once (the scan's start states, ``U``, ``W``
+    and the inverses are kept) and its rematerialised part only the two
+    backward kernels; without the policy both forward kernels are there
+    again."""
+    import jax
+
+    exe = _bind(monkeypatch, functools.partial(_gated_delta, kernels=True),
+                "1", policy)
+    fun, args = _gradient_program(exe)
+    jaxpr = jax.make_jaxpr(fun)(*args).jaxpr
+    kernels = [(eqn.params["name"], inside)
+               for eqn, inside in _equations(jaxpr)
+               if eqn.primitive.name == "pallas_call"]
+    backward = ["gated_delta_chunks_bwd", "gated_delta_scan_bwd"]
+    assert sorted(n for n, inside in kernels if inside) == sorted(
+        backward + again)
+    assert sorted(n for n, _ in kernels) == sorted(
+        backward + again + ["gated_delta_chunks_fwd", "gated_delta_scan_fwd"])
 
 
 # --- (c) the same bits ------------------------------------------------------------
