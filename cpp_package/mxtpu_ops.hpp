@@ -75,9 +75,12 @@ inline Symbol Cast(const std::string& symbol_name, const Symbol& data, const std
   return detail::CreateOp("Cast", symbol_name, 1, keys__, ins__, params__);
 }
 
-inline Symbol CausalConv1D(const std::string& symbol_name, const Symbol& data, int64_t kernel, const Symbol* weight = nullptr) {
+inline Symbol CausalConv1D(const std::string& symbol_name, const Symbol& data, int64_t kernel, const Symbol* weight = nullptr, const std::string& act_type = "silu", bool no_bias = true, int64_t num_group = 0) {
   detail::ParamMap params__;
   params__.emplace_back("kernel", detail::str(kernel));
+  params__.emplace_back("act_type", act_type);
+  params__.emplace_back("no_bias", detail::str(no_bias));
+  params__.emplace_back("num_group", detail::str(num_group));
   const char* keys__[] = {"data", "weight"};
   const Symbol* ins__[] = {&data, weight};
   return detail::CreateOp("CausalConv1D", symbol_name, 2, keys__, ins__, params__);
@@ -302,7 +305,7 @@ inline Symbol MakeLoss(const std::string& symbol_name, const Symbol& data, doubl
   return detail::CreateOp("MakeLoss", symbol_name, 1, keys__, ins__, params__);
 }
 
-inline Symbol MoE(const std::string& symbol_name, const Symbol& data, int64_t num_experts, int64_t num_hidden, int64_t top_k, const Symbol* router_weight = nullptr, const Symbol* gate_weight = nullptr, const Symbol* up_weight = nullptr, const Symbol* down_weight = nullptr, bool expert_bias = false, int64_t expert_offset = 0, double lb_coef = 0.0, int64_t num_local_experts = 0, bool route_norm = false, double route_scale = 1.0, const std::string& score_func = "softmax", double z_coef = 0.0) {
+inline Symbol MoE(const std::string& symbol_name, const Symbol& data, int64_t num_experts, int64_t num_hidden, int64_t top_k, const Symbol* router_weight = nullptr, const Symbol* gate_weight = nullptr, const Symbol* up_weight = nullptr, const Symbol* down_weight = nullptr, bool expert_bias = false, int64_t expert_offset = 0, double lb_coef = 0.0, int64_t num_local_experts = 0, bool route_norm = false, double route_scale = 1.0, const std::string& router = "weight", const std::string& score_func = "softmax", double z_coef = 0.0) {
   detail::ParamMap params__;
   params__.emplace_back("num_experts", detail::str(num_experts));
   params__.emplace_back("num_hidden", detail::str(num_hidden));
@@ -313,6 +316,7 @@ inline Symbol MoE(const std::string& symbol_name, const Symbol& data, int64_t nu
   params__.emplace_back("num_local_experts", detail::str(num_local_experts));
   params__.emplace_back("route_norm", detail::str(route_norm));
   params__.emplace_back("route_scale", detail::str(route_scale));
+  params__.emplace_back("router", router);
   params__.emplace_back("score_func", score_func);
   params__.emplace_back("z_coef", detail::str(z_coef));
   const char* keys__[] = {"data", "router_weight", "gate_weight", "up_weight", "down_weight"};

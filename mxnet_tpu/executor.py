@@ -1475,6 +1475,8 @@ class Executor:
         of ``_count_train_launch`` name it: ``moe_layers``;
         ``moe_assignments``, tokens x ``top_k`` from the bound shapes;
         ``moe_local_experts``, the experts the layers hold here;
+        ``moe_graph_routed_layers``, those whose logits are an input the
+        graph computed (``router="graph"``) and not a product inside the op;
         ``moe_kernel_matmuls``, how many expert matmuls of a train program
         run the Pallas kernels, asked of the rule the op follows
         (``ops/defs_transformer.moe_kernel_matmuls``) with this executor's
@@ -1499,7 +1501,9 @@ class Executor:
         algebra and whose scan over chunks a train program runs in the
         Pallas kernels, asked of the rule the op follows
         (``ops/gated_delta.kernel_plan``: one rule, a node runs all of its
-        kernels or none) with this executor's platform.
+        kernels or none) with this executor's platform;
+        ``conv_grouped_layers``, the ``CausalConv1D`` nodes that mix
+        channels inside groups (``num_group``) and are not depthwise.
         Shapes and types are inferred only where the
         graph has such a node."""
         if self._layer_counts is None:
@@ -1509,12 +1513,16 @@ class Executor:
             linear = [n for n in ops if n.op.name == "GatedDeltaRule"]
             counts = dict.fromkeys((
                 "moe_layers", "moe_assignments", "moe_local_experts",
-                "moe_kernel_matmuls", "attention_layers",
+                "moe_kernel_matmuls", "moe_graph_routed_layers",
+                "attention_layers",
                 "attention_window_layers", "attention_kernel_layers",
                 "attention_scored_pairs", "attention_latent_layers",
                 "attention_pair_lanes", "linear_attention_layers",
                 "linear_attention_chunks", "linear_attention_kernel_layers",
                 "linear_attention_scan_kernel_layers"), 0)
+            counts["conv_grouped_layers"] = sum(
+                n.op.name == "CausalConv1D" and n.params()["num_group"] > 0
+                for n in ops)
             if moe or attention or linear:
                 from .ops.defs_transformer import (held_round_rows,
                                                    moe_kernel_matmuls)
@@ -1539,6 +1547,7 @@ class Executor:
                     counts["moe_layers"] += 1
                     counts["moe_assignments"] += routed
                     counts["moe_local_experts"] += held
+                    counts["moe_graph_routed_layers"] += p["router"] == "graph"
                     counts["moe_kernel_matmuls"] += moe_kernel_matmuls(
                         platform, dtype_of[out],
                         self.arg_dict[n.inputs[2][0].name].dtype,
@@ -1609,6 +1618,12 @@ class Executor:
         if held["moe_kernel_matmuls"]:
             _tm.counter("executor.moe_kernel_matmuls").inc(
                 held["moe_kernel_matmuls"])
+        if held["moe_graph_routed_layers"]:
+            _tm.counter("executor.moe_graph_routed_layers").inc(
+                held["moe_graph_routed_layers"])
+        if held["conv_grouped_layers"]:
+            _tm.counter("executor.conv_grouped_layers").inc(
+                held["conv_grouped_layers"])
         if held["attention_layers"]:
             _tm.counter("executor.attention_layers").inc(
                 held["attention_layers"])

@@ -1,8 +1,9 @@
 """Model zoo — symbol builders for the reference's target workloads
 (BASELINE.json configs): MLP/LeNet (MNIST), ResNet-50 (ImageNet DP),
 VGG-16 (SSD backbone), Inception-BN, DCGAN generator/discriminator, the
-bucketed LSTM language model and the OLMoE, AFMoE (Trinity), Qwen3-Next
-and DeepSeek-V3 (latent attention) sparse-expert decoders.
+bucketed LSTM language model and the OLMoE, AFMoE (Trinity), Qwen3-Next,
+DeepSeek-V3 (latent attention) and ZAYA1 (compressed convolutional
+attention, an MLP router) sparse-expert decoders.
 
 Reference: ``example/image-classification/symbols/*.py`` and
 ``example/rnn``/``example/gan``. Builders return plain Symbols usable with
@@ -26,6 +27,7 @@ from .olmoe import olmoe_sym_gen
 from .afmoe import afmoe_sym_gen
 from .qwen3_next import qwen3_next_sym_gen
 from .deepseek_v3 import deepseek_v3_sym_gen
+from .zaya import zaya_sym_gen
 from . import ssd
 from . import zoo
 from .zoo import SCORE_SYMBOLS

@@ -25,19 +25,29 @@ def merge_heads(a):
     return sym.Reshape(sym.transpose(a, axes=(0, 2, 1, 3)), shape=(0, 0, -1))
 
 
-def embed_tokens(data, vocab_size, hidden_size, dtype):
+def _optional(**inputs):
+    """The keyword inputs that were given."""
+    return {n: v for n, v in inputs.items() if v is not None}
+
+
+def embed_tokens(data, vocab_size, hidden_size, dtype, weight=None):
+    """Rows of the embedding (``weight``: its variable, where the head
+    reads it too) in the trunk's ``dtype``."""
     x = sym.Embedding(data, input_dim=vocab_size, output_dim=hidden_size,
-                      name="embed")
+                      name="embed", **_optional(weight=weight))
     return low_precision_io(x, dtype)
 
 
-def next_token_head(x, label, vocab_size, hidden_size, dtype, ignore_label):
-    """The untied head over the normed stream ``x`` and its float32
-    softmax: the rows' probabilities (B*T, vocab); rows whose label is
-    ``ignore_label`` train nothing."""
+def next_token_head(x, label, vocab_size, hidden_size, dtype, ignore_label,
+                    weight=None):
+    """The head over the normed stream ``x`` and its float32 softmax: the
+    rows' probabilities (B*T, vocab); rows whose label is ``ignore_label``
+    train nothing. Untied, its own ``pred_weight``; tied
+    (``tie_word_embeddings``), ``weight`` is the embedding's variable,
+    whose gradient is then the sum of the two uses."""
     pred = sym.FullyConnected(sym.Reshape(x, shape=(-1, hidden_size)),
                               num_hidden=vocab_size, no_bias=True,
-                              name="pred")
+                              name="pred", **_optional(weight=weight))
     pred = low_precision_io(pred, dtype, out=True)
     return sym.SoftmaxOutput(
         pred, sym.Reshape(label, shape=(-1,)), use_ignore=True,

@@ -147,8 +147,10 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
     Counts Convolution, Deconvolution, FullyConnected, the fused RNN op,
     RingAttention (a causal one at half its scores), GatedDeltaRule (its
     recurrent form: read, write and query of a keys x values state a token
-    and value head) and MoE (the router and the ``top_k`` routed experts,
-    not all of them)
+    and value head), CausalConv1D (``kernel`` taps a channel, times the
+    channels of a group where it mixes them) and MoE (the router, where it
+    is a product inside the op and not the graph's, and the ``top_k``
+    routed experts, not all of them)
     in the published-table convention (one multiply-add = one FLOP, the
     convention behind the ResNet-50 = 4.1 GFLOPs/img figure that bench's
     MFU numbers have used since PR-3); the unrolled LSTM graphs decompose
@@ -176,9 +178,19 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
     for node_id, node in enumerate(nodes):
         op = node["op"]
         if op not in ("Convolution", "Deconvolution", "FullyConnected", "RNN",
-                      "RingAttention", "MoE", "GatedDeltaRule"):
+                      "RingAttention", "MoE", "GatedDeltaRule",
+                      "CausalConv1D"):
             continue
         attrs = node.get("attrs") or {}
+        if op == "CausalConv1D":
+            # (B, T, C): every channel reads ``kernel`` taps of itself, or
+            # of each of its group's C / g channels
+            data = _node_shape(shape_dict, nodes, node["inputs"][0])
+            if data:
+                groups = int(attrs.get("num_group", 0))
+                fan_in = int(data[-1]) // groups if groups else 1
+                total += _prod(data) * int(attrs["kernel"]) * fan_in / batch
+            continue
         if op == "GatedDeltaRule":
             # k (B, Hk, T, Dk), v (B, Hv, T, Dv): S^T k, the rank-1 write
             # and S^T q are Dk x Dv multiply-adds each
@@ -203,8 +215,11 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
             data = _node_shape(shape_dict, nodes, node["inputs"][0])
             if data:
                 h, f = int(data[-1]), int(attrs["num_hidden"])
+                # a router of the graph's is counted by its own nodes
+                router = int(attrs["num_experts"]) * (
+                    attrs.get("router", "weight") == "weight")
                 total += (_prod(data[:-1]) / batch) * h * (
-                    int(attrs["num_experts"]) + int(attrs["top_k"]) * 3 * f)
+                    router + int(attrs["top_k"]) * 3 * f)
             continue
         if op == "RNN":
             # data (T, N, C); per layer/dir: gates × h × (in + h) MACs/step

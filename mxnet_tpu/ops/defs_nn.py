@@ -319,6 +319,9 @@ _ACTS = {
     "softrelu": jax.nn.softplus,
     "softsign": jax.nn.soft_sign,
     "silu": jax.nn.silu,  # x * sigmoid(x): the SwiGLU blocks of models/afmoe
+    # x * Phi(x) through erf, the exact form (torch's nn.GELU() default),
+    # not the tanh approximation: the router MLP of models/zaya
+    "gelu": lambda x: jax.nn.gelu(x, approximate=False),
 }
 
 

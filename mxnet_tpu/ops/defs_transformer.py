@@ -113,38 +113,79 @@ register(
 
 
 # --- CausalConv1D ------------------------------------------------------------
+_CONV_ACTS = {"silu": jax.nn.silu, "none": lambda x: x}
+
+
 def _causal_conv1d(ins, params, mode):
-    """Depthwise causal convolution over time of ``data`` (B, T, C),
-    channels last as a projection leaves them: ``y_t[c] = sum_j w[c, j]
-    x_{t-K+1+j}[c]`` with ``x_{<0} = 0``, ``weight`` (C, K), no bias, then
-    SiLU: a linear-attention mixer's short convolution, the activation in
-    float32 before the one rounding. K shifted multiply-adds in float32.
+    """Causal convolution over time of ``data`` (B, T, C), channels last as
+    a projection leaves them, ``x_{<0} = 0``, the last of the ``kernel``
+    taps at t; then ``act_type`` (``silu``, or ``none``) in float32 before
+    the one rounding; ``bias`` (C,) is added first unless ``no_bias``.
+
+    Depthwise (``num_group`` 0, a linear-attention mixer's short
+    convolution): ``y_t[c] = sum_j w[c, j] x_{t-K+1+j}[c]``, ``weight``
+    (C, K). K shifted multiply-adds in float32.
     The pad is made in ``data``'s dtype and each shifted slice cast where
     it is used: padding a float32 copy made XLA write the four products to
     HBM in float32 before adding them (1 x 8192 x 8192 bfloat16 on a v5e,
     ms forward / forward + backward: 2.58 / 8.05 against 1.02 / 4.23, the
     same bits; a grouped ``lax.conv_general_dilated`` 3.35 / 13.7; my chip
     run, PR 34). ``Convolution`` would want (B, C, T) and a group a
-    channel."""
-    x, w = ins
-    taps = w.shape[1]
+    channel.
+
+    Grouped (``num_group`` g: the second convolution of compressed
+    convolutional attention, a group a head): channels mix inside each of
+    the g groups of C/g, ``y_t[h, o] = sum_j sum_i w[h, o, i, j]
+    x_{t-K+1+j}[h, i]``, ``weight`` (g, C/g, C/g, K) (torch's ``Conv1d(C,
+    C, K, groups=g)`` weight with its rows split by group). Each tap is one
+    product batched over the groups, operands in ``data``'s dtype,
+    accumulated in float32."""
+    if params["act_type"] not in _CONV_ACTS:
+        raise MXNetError(f"CausalConv1D: act_type {params['act_type']!r} is "
+                         "neither 'silu' nor 'none'")
+    x, w = ins[:2]
+    taps, t = w.shape[-1], x.shape[1]
     xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    wf = w.astype(jnp.float32)
-    t = x.shape[1]
-    out = sum(xp[:, j:j + t].astype(jnp.float32) * wf[:, j]
-              for j in range(taps))
-    return jax.nn.silu(out).astype(x.dtype)
+    if params["num_group"]:
+        g = params["num_group"]
+        xp = xp.reshape(xp.shape[:2] + (g, -1))
+        wx = _castp(w, x)
+        out = sum(jnp.einsum("bthi,hoi->btho", xp[:, j:j + t], wx[..., j],
+                             precision=_prec(x.dtype),
+                             preferred_element_type=jnp.float32)
+                  for j in range(taps)).reshape(x.shape)
+    else:
+        wf = w.astype(jnp.float32)
+        out = sum(xp[:, j:j + t].astype(jnp.float32) * wf[:, j]
+                  for j in range(taps))
+    if not params["no_bias"]:
+        out = out + ins[2].astype(jnp.float32)
+    return _CONV_ACTS[params["act_type"]](out).astype(x.dtype)
+
+
+def _causal_conv1d_fill(shapes, p):
+    data = shapes[0]
+    if data is not None:
+        c, g = data[-1], p["num_group"]
+        if g and c % g:
+            raise MXNetError(f"CausalConv1D: {c} channels in {g} groups")
+        shapes[1] = shapes[1] or (
+            (g, c // g, c // g, p["kernel"]) if g else (c, p["kernel"]))
+        if not p["no_bias"]:
+            shapes[2] = shapes[2] or (c,)
+    return shapes
 
 
 register(
     "CausalConv1D",
     _causal_conv1d,
-    arg_names=["data", "weight"],
-    param_schema={"kernel": Param(parse_int)},  # taps, the last at t
-    fill_in_shapes=lambda shapes, p: [
-        shapes[0],
-        shapes[1] or (shapes[0] and (shapes[0][-1], p["kernel"])),
-    ],
+    arg_names=lambda p: ["data", "weight"] + ["bias"] * (not p["no_bias"]),
+    param_schema={"kernel": Param(parse_int),  # taps, the last at t
+                  "act_type": Param(parse_str, "silu"),  # or "none"
+                  "no_bias": Param(parse_bool, True),
+                  # 0: depthwise; g: channels mix inside each of g groups
+                  "num_group": Param(parse_int, 0)},
+    fill_in_shapes=_causal_conv1d_fill,
 )
 
 
@@ -264,8 +305,18 @@ def moe_kernel_matmuls(platform, data_dtype, weight_dtype, rows, hidden,
         [(hidden, width), (width, hidden)]) is not None)
 
 
-def _router(x, w_router, bias, params):
-    """(expert (N * k,) int32, weights (N, k) float32, rows an expert (E,)):
+def _router_logits(x, w_router):
+    """(N, E) float32: the router that is one product inside the operator
+    (``router="weight"``), kept under per-operator recomputation."""
+    return keep(jnp.dot(x.astype(jnp.float32),
+                        w_router.astype(jnp.float32).T,
+                        precision=jax.lax.Precision.HIGHEST))
+
+
+def _router(logits, bias, params):
+    """(expert (N * k,) int32, weights (N, k) float32, rows an expert (E,))
+    from the router's ``logits`` (N, E) float32 (``_router_logits``, or an
+    input the graph computed: ``router="graph"``):
     the ``top_k`` experts of each token and what their outputs are weighted
     by, in float32 whatever the trunk.
 
@@ -280,10 +331,7 @@ def _router(x, w_router, bias, params):
     backward then runs neither that product nor ``top_k``'s sort, the count
     or the gather again."""
     k = params["top_k"]
-    n, e = x.shape[0], w_router.shape[0]
-    logits = keep(jnp.dot(x.astype(jnp.float32),
-                          w_router.astype(jnp.float32).T,
-                          precision=jax.lax.Precision.HIGHEST))
+    n, e = logits.shape
     if params["score_func"] == "sigmoid":
         if params["lb_coef"] or params["z_coef"]:
             raise MXNetError("MoE: lb_coef and z_coef are defined on a "
@@ -411,7 +459,10 @@ _held_rounds.defvjp(_held_rounds_fwd, _held_rounds_bwd)
 def _moe(ins, params, mode):
     """Sparse mixture of SiLU-gated experts, drop-free.
 
-    ``data`` (..., H) is N rows of tokens. ``router_weight`` (E, H);
+    ``data`` (..., H) is N rows of tokens. ``router_weight`` (E, H), or
+    with ``router="graph"`` ``router_logits`` (..., E) in its place: the
+    scores of a router that is a graph of its own (an MLP, a state carried
+    down the layers), taken in float32, whose gradient goes back to it;
     ``gate_weight`` and ``up_weight`` (L, H, F) and ``down_weight``
     (L, F, H): expert-major, input features before output features, the
     layout the grouped matmul reads; L = ``num_local_experts`` (E where it
@@ -424,14 +475,21 @@ def _moe(ins, params, mode):
     (``_expert_matmul``): no capacity, no token dropped, none computed for
     an expert it was not routed to.
     """
-    x, w_router, w_gate, w_up, w_down = ins[:5]
+    x, router, w_gate, w_up, w_down = ins[:5]    # its weight, or logits
     bias = ins[5] if params["expert_bias"] else None
     k = params["top_k"]
     shape = x.shape
     x = x.reshape(-1, shape[-1])
-    n, e = x.shape[0], w_router.shape[0]
+    n, e = x.shape[0], params["num_experts"]
     held = w_gate.shape[0]
-    expert, p, counts = _router(x, w_router, bias, params)
+    if params["router"] == "graph":
+        logits = router.reshape(n, e).astype(jnp.float32)
+    elif params["router"] == "weight":
+        logits = _router_logits(x, router)
+    else:
+        raise MXNetError(f"MoE: router {params['router']!r} is neither "
+                         "'weight' nor 'graph'")
+    expert, p, counts = _router(logits, bias, params)
 
     if held == e and not params["expert_offset"]:
         order = keep(jnp.argsort(expert, stable=True))        # by expert
@@ -472,7 +530,9 @@ def _moe_fill(shapes, params):
             raise MXNetError(
                 f"MoE: experts [{params['expert_offset']}, "
                 f"{params['expert_offset'] + held}) of {e}")
-        for i, s in enumerate([(e, h), (held, h, f), (held, h, f),
+        router = tuple(data[:-1]) + (e,) if params["router"] == "graph" \
+            else (e, h)
+        for i, s in enumerate([router, (held, h, f), (held, h, f),
                                (held, f, h)] + [(e,)] * params["expert_bias"],
                               1):
             shapes[i] = shapes[i] or s
@@ -482,8 +542,10 @@ def _moe_fill(shapes, params):
 register(
     "MoE",
     _moe,
-    arg_names=lambda p: ["data", "router_weight", "gate_weight", "up_weight",
-                         "down_weight"] + ["expert_bias"] * p["expert_bias"],
+    arg_names=lambda p: [
+        "data", "router_logits" if p["router"] == "graph"
+        else "router_weight", "gate_weight", "up_weight",
+        "down_weight"] + ["expert_bias"] * p["expert_bias"],
     param_schema={
         "num_experts": Param(parse_int),  # the router's width
         "num_hidden": Param(parse_int),  # width of one expert
@@ -495,6 +557,9 @@ register(
         "route_norm": Param(parse_bool, False),
         "route_scale": Param(parse_float, 1.0),
         "expert_bias": Param(parse_bool, False),  # a sixth input (E,)
+        # "weight": input 1 is router_weight (E, H), one product in here;
+        # "graph": input 1 is router_logits (..., E), computed by the graph
+        "router": Param(parse_str, "weight"),
         # the experts held here: [expert_offset, + num_local_experts) of
         # num_experts; 0 = all of them
         "num_local_experts": Param(parse_int, 0),
