@@ -313,6 +313,40 @@ def _router_logits(x, w_router):
                         precision=jax.lax.Precision.HIGHEST))
 
 
+def _chosen(expert, e):
+    """(N, k, E) bool, True at ``[n, j, expert[n, j]]``: the routing as a
+    mask over ``e`` experts. It is only ever read inside a reduction, which
+    XLA fuses it into, so no array of that size reaches memory."""
+    return expert[:, :, None] == jnp.arange(e, dtype=expert.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _select_scores(scores, expert, e):
+    """``take_along_axis(scores, expert, 1)`` (N, k) of ``scores`` (N, e)
+    as a dense select: each score is compared against the k experts of its
+    token and the one that is chosen comes out of a reduction over the
+    experts, the gathered value bit for bit. The chip's gather moves a
+    scalar an index at 7-10 ns; e compares a scalar run at the vector
+    unit's rate. The reduction is a maximum over ``-inf`` and not a sum
+    over zeros: XLA merges a sum with ``route_norm``'s sum over k into one
+    over (k, E), which adds a token's weights by expert and not by j, an
+    ulp from the gathered form. Backward is the same select the other way
+    and keeps nothing but ``expert``."""
+    return jnp.max(jnp.where(_chosen(expert, e), scores[:, None, :],
+                             -jnp.inf), axis=-1)
+
+
+def _select_scores_bwd(e, expert, g):
+    # a token's k experts are distinct: at most one term an element
+    return jnp.sum(jnp.where(_chosen(expert, e), g[:, :, None], 0),
+                   axis=1), None
+
+
+_select_scores.defvjp(
+    lambda scores, expert, e: (_select_scores(scores, expert, e), expert),
+    _select_scores_bwd)
+
+
 def _router(logits, bias, params):
     """(expert (N * k,) int32, weights (N, k) float32, rows an expert (E,))
     from the router's ``logits`` (N, E) float32 (``_router_logits``, or an
@@ -325,29 +359,33 @@ def _router(logits, bias, params):
     largest ``s + expert_bias`` (the bias steers the choice only: it has no
     gradient and is not in the weights), weighted by ``s``. Then, either
     way: ``route_norm`` divides a token's k weights by their sum (+ 1e-20)
-    and ``route_scale`` multiplies them. The logits (N x E float32, a
-    six-pass product), the experts, their counts and the weights as
-    gathered are kept under per-operator recomputation (``registry.keep``):
-    backward then runs neither that product nor ``top_k``'s sort, the count
-    or the gather again."""
+    and ``route_scale`` multiplies them. The weights and the rows an
+    expert are dense selects over (N, E) (``_select_scores``; the counts
+    sum the same compares), never a gather or a scatter-add of N * k
+    scalars. The logits (N x E float32, a six-pass product), the experts,
+    their counts and the weights as selected are kept under per-operator
+    recomputation (``registry.keep``): backward then runs neither that
+    product nor ``top_k``'s sort, the count or the select again."""
     k = params["top_k"]
     n, e = logits.shape
+
+    def choose(scores):
+        expert = jax.lax.top_k(scores, k)[1]                  # (N, k)
+        counts = jnp.sum(_chosen(expert, e), axis=(0, 1), dtype=jnp.int32)
+        return keep(expert), keep(counts)
+
     if params["score_func"] == "sigmoid":
         if params["lb_coef"] or params["z_coef"]:
             raise MXNetError("MoE: lb_coef and z_coef are defined on a "
                              "softmax router, not score_func='sigmoid'")
         scores = jax.nn.sigmoid(logits)
-        biased = scores if bias is None else scores + jax.lax.stop_gradient(
-            bias.astype(jnp.float32))
-        _, expert = jax.lax.top_k(biased, k)                  # (N, k)
-        expert = keep(expert.reshape(-1))
-        counts = keep(jnp.bincount(expert, length=e).astype(jnp.int32))
+        expert, counts = choose(
+            scores if bias is None else scores + jax.lax.stop_gradient(
+                bias.astype(jnp.float32)))
     elif params["score_func"] == "softmax":
         if bias is not None:
             raise MXNetError("MoE: expert_bias needs score_func='sigmoid'")
-        _, expert = jax.lax.top_k(logits, k)                  # (N, k)
-        expert = keep(expert.reshape(-1))
-        counts = keep(jnp.bincount(expert, length=e).astype(jnp.int32))
+        expert, counts = choose(logits)
         logits = _attach_router_losses(
             logits, counts.astype(jnp.float32) / n,
             params["lb_coef"], params["z_coef"])
@@ -355,13 +393,12 @@ def _router(logits, bias, params):
     else:
         raise MXNetError(f"MoE: score_func {params['score_func']!r} is "
                          "neither 'softmax' nor 'sigmoid'")
-    # f32; a gather of N * k scalars the chip takes a millisecond over
-    p = keep(jnp.take_along_axis(scores, expert.reshape(n, k), axis=1))
+    p = keep(_select_scores(scores, expert, e))
     if params["route_norm"]:
         p = p / (jnp.sum(p, axis=-1, keepdims=True) + 1e-20)
     if params["route_scale"] != 1.0:
         p = p * params["route_scale"]
-    return expert, p, counts
+    return expert.reshape(-1), p, counts
 
 
 # A round of the held experts' rows is this many times the rows a balanced
@@ -382,11 +419,15 @@ def held_round_rows(assignments, held, experts):
     return min(assignments, -(-rows // tile) * tile)
 
 
-def _held_round(first, rows, x, tok, weight, counts, w_gate, w_up, w_down):
+def _held_round(first, rows, x, order, weight, counts, w_gate, w_up, w_down):
     """(N, H) float32: what rows ``[first, first + rows)`` of the held
-    assignments add to the layer's output. ``tok`` / ``weight``: token and
-    routing weight of each held assignment, sorted by expert, the dead tail
-    after them; ``counts`` (L,) rows an expert over the whole list. The
+    assignments add to the layer's output. ``order``: the assignments
+    (token ``// k``, its j-th expert ``% k``) sorted by expert, the dead
+    tail and then padding after the held ones; ``weight`` (N * k,) the
+    routing weight of every assignment, unsorted; ``counts`` (L,) rows an
+    expert over the whole list. The round slices its window of ``order``
+    and gathers that window's weights alone: ``rows`` scalars, not N * k.
+    A dead or padded row's weight only has to be finite. The
     grouped matmuls visit only the live rows; their outputs past those are
     not written, so each is masked on both sides (forward and cotangent
     are then zeros there, never what the buffer held)."""
@@ -394,8 +435,9 @@ def _held_round(first, rows, x, tok, weight, counts, w_gate, w_up, w_down):
     here = (jnp.clip(ends, first, first + rows)
             - jnp.clip(ends - counts, first, first + rows)).astype(jnp.int32)
     live = (jnp.arange(rows) < jnp.sum(here))[:, None]
-    tok = jax.lax.dynamic_slice_in_dim(tok, first, rows)
-    weight = jax.lax.dynamic_slice_in_dim(weight, first, rows)
+    order = jax.lax.dynamic_slice_in_dim(order, first, rows)
+    tok = order // (weight.shape[0] // x.shape[0])            # // top_k
+    weight = keep(weight[order])
     matmul = _expert_matmul(here, x.dtype, rows, (w_gate, w_up, w_down))
 
     def live_matmul(r, w):
@@ -412,7 +454,7 @@ def _held_round(first, rows, x, tok, weight, counts, w_gate, w_up, w_down):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_rounds(rows, x, weight, w_gate, w_up, w_down, tok, counts):
+def _held_rounds(rows, x, weight, w_gate, w_up, w_down, order, counts):
     """The sum of ``_held_round`` over the rounds of ``rows`` rows that
     hold a live row: the first always, the others in a loop whose trip
     count is read on the device, zero unless routing has collapsed onto
@@ -421,33 +463,33 @@ def _held_rounds(rows, x, weight, w_gate, w_up, w_down, tok, counts):
     runs the same loop and recomputes each further round before its
     cotangents, so memory and the program's size are one round's, however
     many run."""
-    return _held_rounds_fwd(rows, x, weight, w_gate, w_up, w_down, tok,
+    return _held_rounds_fwd(rows, x, weight, w_gate, w_up, w_down, order,
                             counts)[0]
 
 
-def _round_of(first, rows, tok, counts):
+def _round_of(first, rows, order, counts):
     """``_held_round`` at ``first`` as a function of what it is
     differentiated in: x, the routing weights, the three expert weights."""
-    return lambda x, weight, *w: _held_round(first, rows, x, tok, weight,
+    return lambda x, weight, *w: _held_round(first, rows, x, order, weight,
                                              counts, *w)
 
 
-def _held_rounds_fwd(rows, x, weight, w_gate, w_up, w_down, tok, counts):
+def _held_rounds_fwd(rows, x, weight, w_gate, w_up, w_down, order, counts):
     wrt = (x, weight, w_gate, w_up, w_down)
-    out, vjp = jax.vjp(_round_of(0, rows, tok, counts), *wrt)
+    out, vjp = jax.vjp(_round_of(0, rows, order, counts), *wrt)
     rounds = (jnp.sum(counts) + rows - 1) // rows
     out = jax.lax.fori_loop(
         1, rounds,
-        lambda r, acc: acc + _round_of(r * rows, rows, tok, counts)(*wrt),
+        lambda r, acc: acc + _round_of(r * rows, rows, order, counts)(*wrt),
         out)
-    return out, (vjp, wrt, tok, counts, rounds)
+    return out, (vjp, wrt, order, counts, rounds)
 
 
 def _held_rounds_bwd(rows, res, g):
-    vjp, wrt, tok, counts, rounds = res
+    vjp, wrt, order, counts, rounds = res
 
     def more(r, cts):
-        back = jax.vjp(_round_of(r * rows, rows, tok, counts), *wrt)[1]
+        back = jax.vjp(_round_of(r * rows, rows, order, counts), *wrt)[1]
         return jax.tree.map(jnp.add, cts, back(g))
 
     return jax.lax.fori_loop(1, rounds, more, vjp(g)) + (None, None)
@@ -473,7 +515,11 @@ def _moe(ins, params, mode):
     here, experts ``[expert_offset, expert_offset + L)``. The assignments
     are sorted by expert and each expert multiplies exactly its own rows
     (``_expert_matmul``): no capacity, no token dropped, none computed for
-    an expert it was not routed to.
+    an expert it was not routed to. The routing's bookkeeping (a token's
+    k weights, the rows an expert) is dense selects over (N, E)
+    (``_router``), and where a share of the experts is held a round
+    gathers only its own window's weights (``_held_round``): nothing
+    moves N * k scalars one index at a time.
     """
     x, router, w_gate, w_up, w_down = ins[:5]    # its weight, or logits
     bias = ins[5] if params["expert_bias"] else None
@@ -512,12 +558,10 @@ def _moe(ins, params, mode):
     counts = counts[params["expert_offset"]:params["expert_offset"] + held]
     rows = held_round_rows(n * k, held, e)
     rounds = -(-n * k // rows)
-    tok, weight = (order // k).astype(jnp.int32), p.reshape(-1)[order]
     if rounds * rows > n * k:   # whole rounds: more of the dead tail
-        tok, weight = (jnp.pad(a, (0, rounds * rows - n * k))
-                       for a in (tok, weight))
-    weight = keep(weight)       # another such gather
-    out = _held_rounds(rows, x, weight, w_gate, w_up, w_down, tok, counts)
+        order = jnp.pad(order, (0, rounds * rows - n * k))
+    out = _held_rounds(rows, x, p.reshape(-1), w_gate, w_up, w_down, order,
+                       counts)
     return out.astype(x.dtype).reshape(shape)
 
 

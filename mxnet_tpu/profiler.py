@@ -228,6 +228,24 @@ def parse_scope(tf_op):
     return None
 
 
+def inner_scope(tf_op):
+    """What follows the node in a name stack that :func:`parse_scope`
+    reads as a node's: ``jit(take_along_axis)/gather`` of
+    ``jit(_step)/jvp(MoE[l1_moe])/jit(take_along_axis)/gather:``, the
+    operator's own ``jax.named_scope``s, the ``jit`` functions it calls
+    and the primitive. After the LAST part that names a node (backward
+    reads ``transpose(jvp(MoE[m]))/jvp(MoE[m])/...``), less jax's own
+    ``checkpoint`` and ``rematted_computation`` parts, which the pass
+    already says. ``""`` where the stack names no node."""
+    parts = str(tf_op).split(":", 1)[0].split("/")
+    for i in range(len(parts) - 1, -1, -1):
+        if _NODE.match(_WRAPPED.match(parts[i]).group(2)):
+            return "/".join(
+                part for part in parts[i + 1:]
+                if part != "checkpoint" and REMAT_MARK not in part)
+    return ""
+
+
 # --- the reader: a trace by operator and pass, program, host span ----------
 
 DEVICE_PLANE = "/device:TPU:"
@@ -353,7 +371,7 @@ def _rows(groups, busy_ns, steps, top):
 
 
 def reduce_trace(ops, modules=(), spans=(), window=None, top=None,
-                 graph=None):
+                 graph=None, inner=None):
     """The tables of :func:`device_table` from plain tuples, all times in
     nanoseconds on one clock.
 
@@ -364,7 +382,8 @@ def reduce_trace(ops, modules=(), spans=(), window=None, top=None,
     same device; ``spans``: ``[(name, start, duration)]`` of the host.
     ``window`` names the host span that bounds what is counted (the
     longest of that name); None counts everything. ``graph``: see
-    :func:`_scopes`."""
+    :func:`_scopes`. ``inner`` names one operator whose time is also
+    split by :func:`inner_scope` (``by_inner``)."""
     ops = list(ops)
     lo, hi = float("-inf"), float("inf")
     if window is not None:
@@ -379,7 +398,7 @@ def reduce_trace(ops, modules=(), spans=(), window=None, top=None,
     # fit.step root, which is then not in the file; its dispatch is
     steps = max(sum(1 for name, s, _ in spans if name == root
                     and lo <= s < hi) for root in STEP_SPANS)
-    by_operator, by_node, loose = {}, {}, {}
+    by_operator, by_node, by_inner, loose = {}, {}, {}, {}
     busy_ns = unscoped_ns = inherited_ns = 0.0
     for op, (self_ns, leaf), (scope, inherited) in zip(ops, own,
                                                        _scopes(ops, graph)):
@@ -390,15 +409,21 @@ def reduce_trace(ops, modules=(), spans=(), window=None, top=None,
         if scope is None:
             unscoped_ns += self_ns
             scope = (UNSCOPED, None, "other")
-            targets = ((loose, (("name", kind),)),)
+            targets = ((loose, (("name", kind),), kind),)
         elif inherited:
             inherited_ns += self_ns
         operator, node, pass_ = scope
         targets += (
-            (by_operator, (("operator", operator), ("pass", pass_))),
+            (by_operator, (("operator", operator), ("pass", pass_)), kind),
             (by_node, (("operator", operator), ("node", node),
-                       ("pass", pass_))))
-        for table, key in targets:
+                       ("pass", pass_)), kind))
+        if operator == inner:  # its rows name the instruction, serial and all
+            name = _short(op[0])
+            stack = op[1] or (graph or {}).get((op[6], name), ("",))[0]
+            targets += ((by_inner, (
+                ("inner", "" if inherited else inner_scope(stack)),
+                ("pass", pass_)), name),)
+        for table, key, xla in targets:
             g = table.setdefault(key, {"ns": 0.0, "calls": 0, "flops": 0.0,
                                        "bytes": 0.0, "xla": {}})
             g["ns"] += self_ns
@@ -406,7 +431,7 @@ def reduce_trace(ops, modules=(), spans=(), window=None, top=None,
             if leaf:  # a holder's stats count what it holds again
                 g["flops"] += flops or 0.0
                 g["bytes"] += nbytes or 0.0
-            x = g["xla"].setdefault(kind, [0.0, 0])
+            x = g["xla"].setdefault(xla, [0.0, 0])
             x[0] += self_ns
             x[1] += 1
     programs = {}
@@ -429,6 +454,8 @@ def reduce_trace(ops, modules=(), spans=(), window=None, top=None,
         "inherited_share": inherited_ns / busy_ns if busy_ns else 0.0,
         "idle": _idle(ops, spans, lo, hi, window),
     }
+    if inner is not None:
+        out["by_inner"] = _rows(by_inner, busy_ns, steps, top)
     if out["unscoped_share"] > 0.10:
         out["hint"] = STALE_CACHE_HINT
     return out
@@ -704,7 +731,7 @@ def load_xplane(path):
     return ops, modules, spans, graph
 
 
-def device_table(trace=None, window=None, top=None):
+def device_table(trace=None, window=None, top=None, inner=None):
     """A trace read back by the graph's own names.
 
     ``trace`` is an ``.xplane.pb`` or a profile directory (default: the
@@ -739,6 +766,11 @@ def device_table(trace=None, window=None, top=None):
         executor phase, and those operations by XLA kind. Above 10 %
         ``hint`` says what most likely happened: the executables came
         from a compilation cache that a tree without scopes filled.
+    ``by_inner`` (only with ``inner="<Operator>"``)
+        that operator's rows split by what follows ``Operator[node]`` in
+        the name stack (:func:`inner_scope`) and pass, all its nodes
+        together; ``xla`` there names the three instructions (serial
+        numbers and all) that hold most of a row.
     """
     if trace is None:
         trace = _state.get("logdir")
@@ -750,7 +782,7 @@ def device_table(trace=None, window=None, top=None):
         raise ValueError(f"no .xplane.pb under {trace!r}")
     ops, modules, spans, graph = load_xplane(path)
     return reduce_trace(ops, modules, spans, window=window, top=top,
-                        graph=graph)
+                        graph=graph, inner=inner)
 
 
 def _maybe_autostart():

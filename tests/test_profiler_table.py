@@ -76,6 +76,28 @@ def test_parse_scope_reads_the_chips_strings(case):
     assert prof.parse_scope(tf_op) == want
 
 
+INNER = {
+    "forward": "jit(take_along_axis)/gather",
+    "recompute": "reduce_sum",
+    "pallas_recompute":
+        "call_exported/jit(<lambda>)/jit(_fwd)/attention_fwd/pallas_call",
+    "custom_vjp_backward":
+        "transpose(jvp())/cond/branch_0_fun/call_exported/jit(<lambda>)/"
+        "jit(_tgmm)/moe_gmm_wgrad/pallas_call",
+    "update": "slice",
+    "phase": "", "no_scope": "", "argument": "", "other_program": "",
+}
+
+
+@pytest.mark.parametrize("case", sorted(INNER))
+def test_inner_scope_reads_what_follows_the_node(case):
+    """After the LAST part that names a node, less jax's ``checkpoint`` /
+    ``rematted_computation``; nothing where no part names one."""
+    tf_op = ("jit(_step)/jvp(MoE[l1_moe])/jit(take_along_axis)/gather:"
+             if case == "forward" else CHIP[case][0])
+    assert prof.inner_scope(tf_op) == INNER[case]
+
+
 def test_parse_scope_reads_its_own_grammar():
     """What ``node_scope`` / ``phase_scope`` / ``param_scope`` write, the
     parser reads back, a window's ``while`` body and a batched group
@@ -256,6 +278,39 @@ def test_reduce_takes_nested_events_out_of_their_holder():
     assert ("conv0", "forward") in node and ("conv0_weight", "update") in node
 
 
+def test_reduce_splits_one_operator_by_what_follows_its_node():
+    """``inner="Convolution"``: its rows by name stack inside the node and
+    pass, every node of it together, the instructions named serial and
+    all; an operation booked through a neighbour has no stack of its own;
+    the other operators' rows stay out, and without ``inner`` no table."""
+    top_k = "jit(_step)/jvp(Convolution[conv0])/top_k:"
+    gather = "jit(_step)/jvp(Convolution[conv1])/jit(take_along_axis)/gather:"
+    back = ("jit(_step)/transpose(jvp(Convolution[conv0]))/"
+            "jvp(Convolution[conv0])/checkpoint/jit(take_along_axis)/"
+            "scatter-add:")
+    ops = [op("sort.1", top_k, 0, 30), op("fusion.59", gather, 40, 50),
+           op("fusion.60", gather, 100, 30),
+           op("fusion.61", gather.replace("conv1", "conv0"), 140, 20),
+           op("fusion.50", back, 200, 70),
+           op("copy.3", "", 300, 5, operands="%fusion.50"),
+           op("fusion.9", UPD, 400, 10)]
+    t = prof.reduce_trace(ops, inner="Convolution")
+    rows = {(r["inner"], r["pass"]): r for r in t["by_inner"]}
+    assert set(rows) == {
+        ("top_k", "forward"), ("jit(take_along_axis)/gather", "forward"),
+        ("jit(take_along_axis)/scatter-add", "backward"), ("", "backward")}
+    row = rows["jit(take_along_axis)/gather", "forward"]
+    assert row["ms"] == pytest.approx(100e-6) and row["calls"] == 3
+    assert row["xla"][0] == ["fusion.59", pytest.approx(50e-6), 1]
+    assert rows["", "backward"]["xla"][0][0] == "copy.3"
+    assert sum(r["ms"] for r in t["by_inner"]) == pytest.approx(
+        sum(r["ms"] for r in t["by_operator"]
+            if r["operator"] == "Convolution"))
+    assert t["by_inner"][0]["inner"] == "jit(take_along_axis)/gather"
+    assert "by_inner" not in prof.reduce_trace(ops)
+    assert prof.reduce_trace(ops, inner="MoE")["by_inner"] == []
+
+
 def test_reduce_divides_by_the_windows_step_roots():
     spans = [("bench.traced_slice", 0, 1000), ("fit.step", 10, 400),
              ("fit.dispatch", 20, 50), ("fit.step", 500, 400),
@@ -407,6 +462,24 @@ def test_trace_table_prints_the_tables(cpu_trace, capsys):
     assert "Convolution" in out and "jit__step" in out and "idle" in out
     assert trace_table.main([cpu_trace, "--by", "node", "--json"]) == 0
     assert '"by_node"' in capsys.readouterr().out
+
+
+def test_trace_table_splits_an_operator_by_its_inner_scopes(cpu_trace,
+                                                            capsys):
+    """On XLA:CPU the name stacks come from the trace's HLO."""
+    import trace_table
+
+    assert trace_table.main([cpu_trace, "--by", "inner", "--operator",
+                             "Convolution"]) == 0
+    out = capsys.readouterr().out
+    assert "inside the operator" in out and "conv_general_dilated" in out
+    assert "backward" in out and "jit__step" not in out
+    table = mx.profiler.device_table(cpu_trace, inner="Convolution")
+    assert sum(r["ms"] for r in table["by_inner"]) == pytest.approx(
+        sum(r["ms"] for r in table["by_operator"]
+            if r["operator"] == "Convolution"))
+    with pytest.raises(SystemExit):
+        trace_table.main([cpu_trace, "--by", "inner"])
 
 
 def test_device_table_without_a_trace_says_so(tmp_path):

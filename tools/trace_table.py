@@ -3,12 +3,16 @@
 by XLA program, and the device's idle gaps by the span the host was in.
 
     python tools/trace_table.py <.xplane.pb or profile dir> [--window SPAN]
-        [--by operator|node|program|idle] [--top N] [--json]
+        [--by operator|node|program|idle|inner --operator NAME]
+        [--top N] [--json]
 
 Prints what ``mxnet_tpu.profiler.device_table`` returns (its docstring says
 what each number is). Times are milliseconds a step where the window holds
 steps, else milliseconds. ``--window bench.traced_slice`` is the benchmark's
 traced slice; ``--window fit.step`` one iteration of ``Module.fit``.
+``--by inner --operator MoE`` splits one operator's time by what follows
+``Operator[node]`` in the name stack: its own scopes, the ``jit`` functions
+it calls and the primitive (``jit(argsort)/sort``, ``moe_gmm/pallas_call``).
 """
 
 from __future__ import annotations
@@ -74,6 +78,23 @@ def by_node(table, out, top):
             f"{row.get('bytes', 0.0) / per / 1e9:>8.2f}")
 
 
+def by_inner(table, out, top):
+    """One line a name stack inside the operator and pass, all its nodes
+    together."""
+    out(f"{'inside the operator':<64}{'pass':<10}{'ms':>9}{'share':>7}"
+        f"{'calls':>7}  XLA instructions (name ms calls)")
+    per = max(table["steps"], 1)
+    for row in table["by_inner"][:top]:
+        name = row["inner"] or "(no name stack of its own)"
+        out(f"{name if len(name) < 64 else '..' + name[-60:]:<64}"
+            f"{row['pass']:<10}{_ms(table, row):>9.3f}"
+            f"{100 * row['share']:>6.1f}%{row['calls'] / per:>7.0f}  "
+            + ", ".join(f"{k} {ms / per:.3f} {n / per:.0f}"
+                        for k, ms, n in row["xla"]))
+    out(f"{'all':<74}"
+        f"{sum(_ms(table, r) for r in table['by_inner']):>9.3f}")
+
+
 def by_program(table, out):
     out(f"{'program':<56}{'ms':>10}{'calls':>8}")
     for row in table["by_program"]:
@@ -96,16 +117,23 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace")
     ap.add_argument("--window", default=None)
-    ap.add_argument("--by", choices=("operator", "node", "program", "idle"),
-                    default=None, help="one table (default: all but node)")
+    ap.add_argument("--by", choices=("operator", "node", "program", "idle",
+                                     "inner"),
+                    default=None,
+                    help="one table (default: all but node and inner)")
+    ap.add_argument("--operator", default=None,
+                    help="the operator --by inner splits, e.g. MoE")
     ap.add_argument("--top", type=int, default=40,
-                    help="rows of the by-node table")
+                    help="rows of the by-node and by-inner tables")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
+    if (args.by == "inner") != (args.operator is not None):
+        ap.error("--by inner and --operator NAME go together")
 
     from mxnet_tpu import profiler
 
-    table = profiler.device_table(args.trace, window=args.window)
+    table = profiler.device_table(args.trace, window=args.window,
+                                  inner=args.operator)
     if args.json:
         print(json.dumps(table))
         return 0
@@ -128,6 +156,8 @@ def main(argv=None):
                     for r in table["unscoped"][:8]))
         elif name == "node":
             by_node(table, out, args.top)
+        elif name == "inner":
+            by_inner(table, out, args.top)
         elif name == "program":
             by_program(table, out)
         else:
