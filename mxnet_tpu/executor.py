@@ -1503,7 +1503,11 @@ class Executor:
         (``ops/gated_delta.kernel_plan``: one rule, a node runs all of its
         kernels or none) with this executor's platform;
         ``conv_grouped_layers``, the ``CausalConv1D`` nodes that mix
-        channels inside groups (``num_group``) and are not depthwise.
+        channels inside groups (``num_group``) and are not depthwise, and
+        ``conv_kernel_layers``, the ``CausalConv1D`` nodes a train program
+        runs in the Pallas kernels, asked of the rule the op follows
+        (``ops/causal_conv_kernels.kernel_plan``) with this executor's
+        platform.
         Shapes and types are inferred only where the
         graph has such a node."""
         if self._layer_counts is None:
@@ -1511,6 +1515,7 @@ class Executor:
             moe = [n for n in ops if n.op.name == "MoE"]
             attention = [n for n in ops if n.op.name == "RingAttention"]
             linear = [n for n in ops if n.op.name == "GatedDeltaRule"]
+            conv = [n for n in ops if n.op.name == "CausalConv1D"]
             counts = dict.fromkeys((
                 "moe_layers", "moe_assignments", "moe_local_experts",
                 "moe_kernel_matmuls", "moe_graph_routed_layers",
@@ -1519,11 +1524,13 @@ class Executor:
                 "attention_scored_pairs", "attention_latent_layers",
                 "attention_pair_lanes", "linear_attention_layers",
                 "linear_attention_chunks", "linear_attention_kernel_layers",
-                "linear_attention_scan_kernel_layers"), 0)
+                "linear_attention_scan_kernel_layers",
+                "conv_kernel_layers"), 0)
             counts["conv_grouped_layers"] = sum(
-                n.op.name == "CausalConv1D" and n.params()["num_group"] > 0
-                for n in ops)
-            if moe or attention or linear:
+                n.params()["num_group"] > 0 for n in conv)
+            if moe or attention or linear or conv:
+                from .ops.causal_conv_kernels import (
+                    kernel_plan as conv_kernel_plan)
                 from .ops.defs_transformer import (held_round_rows,
                                                    moe_kernel_matmuls)
                 from .ops.gated_delta import (chunks_of,
@@ -1586,6 +1593,12 @@ class Executor:
                         platform) is not None
                     counts["linear_attention_kernel_layers"] += kernels
                     counts["linear_attention_scan_kernel_layers"] += kernels
+                for n in conv:
+                    # the output has the data's shape and dtype
+                    out, p = n.name + "_output", n.params()
+                    counts["conv_kernel_layers"] += conv_kernel_plan(
+                        dtype_of[out], shape_of[out], p["kernel"], platform,
+                        p["num_group"]) is not None
             self._layer_counts = counts
         return self._layer_counts
 
@@ -1624,6 +1637,9 @@ class Executor:
         if held["conv_grouped_layers"]:
             _tm.counter("executor.conv_grouped_layers").inc(
                 held["conv_grouped_layers"])
+        if held["conv_kernel_layers"]:
+            _tm.counter("executor.conv_kernel_layers").inc(
+                held["conv_kernel_layers"])
         if held["attention_layers"]:
             _tm.counter("executor.attention_layers").inc(
                 held["attention_layers"])

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..base import (MXNetError, parse_bool, parse_float, parse_int,
                     parse_str)
+from . import causal_conv_kernels as _cck
 from . import gated_delta as _gdr
 from . import grouped_matmul as _gmm
 from .defs_nn import _castp, _prec
@@ -113,7 +114,7 @@ register(
 
 
 # --- CausalConv1D ------------------------------------------------------------
-_CONV_ACTS = {"silu": jax.nn.silu, "none": lambda x: x}
+_CONV_ACTS = _cck.ACTS  # "silu", "none"
 
 
 def _causal_conv1d(ins, params, mode):
@@ -125,7 +126,14 @@ def _causal_conv1d(ins, params, mode):
     Depthwise (``num_group`` 0, a linear-attention mixer's short
     convolution): ``y_t[c] = sum_j w[c, j] x_{t-K+1+j}[c]``, ``weight``
     (C, K). K shifted multiply-adds in float32.
-    The pad is made in ``data``'s dtype and each shifted slice cast where
+    Where the rule says so (``causal_conv_kernels.kernel_plan``, asked with
+    the platform the program is lowered for: one TPU, a bfloat16 ``data``
+    of at least half its VMEM whose channels 128 divides) forward and
+    backward are one Pallas kernel each (``ops/causal_conv_kernels.py``,
+    PR 46), the same arithmetic in the same order. Everywhere else (the
+    CPU, several chips, a float32 trunk, other widths, smaller arrays) the
+    ``jax.numpy`` form below, as it was:
+    the pad is made in ``data``'s dtype and each shifted slice cast where
     it is used: padding a float32 copy made XLA write the four products to
     HBM in float32 before adding them (1 x 8192 x 8192 bfloat16 on a v5e,
     ms forward / forward + backward: 2.58 / 8.05 against 1.02 / 4.23, the
@@ -145,6 +153,11 @@ def _causal_conv1d(ins, params, mode):
                          "neither 'silu' nor 'none'")
     x, w = ins[:2]
     taps, t = w.shape[-1], x.shape[1]
+    kernels = _cck.kernel_plan(x.dtype, x.shape, taps, mode.platform,
+                               params["num_group"])
+    if kernels is not None:
+        return _cck.causal_conv(x, w, None if params["no_bias"] else ins[2],
+                                params["act_type"], kernels)
     xp = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     if params["num_group"]:
         g = params["num_group"]

@@ -1,0 +1,324 @@
+"""``CausalConv1D``'s depthwise form in its Pallas kernels
+(``mxnet_tpu/ops/causal_conv_kernels.py``), run in Pallas's interpreter on the
+CPU against the ``jax.numpy`` form the operator keeps everywhere else: the
+output and every gradient over taps, activation, bias, batch, a T that is and
+is not whole time blocks and one and several channel blocks; causality at a
+time block's edge; the zeros before a row's first token; the rule; the
+executor's counter on a small Qwen3-Next and a small ZAYA1 model, steered
+through the kernels with and without per-operator recomputation. The compile
+for a described v5e sits with the others in ``test_grouped_matmul.py``."""
+
+import numpy as np
+import pytest
+import test_qwen3_next as tq
+import test_zaya as tz
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops import causal_conv_kernels as ck
+from mxnet_tpu.ops import grouped_matmul as gm
+from mxnet_tpu.ops.defs_transformer import _causal_conv1d
+from mxnet_tpu.ops.registry import OpMode
+
+V5E_VMEM = 128 << 20
+# time blocks of 32 rows in tiles of 16: 64 rows are two grid steps of two
+# tiles, so both carries (registers, VMEM scratch) are walked
+PLAN = ck.Plan(32, 128, 16, 32 << 20)
+# (batch, T, channels): whole blocks and one channel block; a padded T, two
+# rows and three channel blocks; a T under one block
+SHAPES = {"whole_blocks": (1, 64, 128), "padded_wide": (2, 40, 384),
+          "one_short_block": (1, 24, 256)}
+
+
+def _inputs(batch, t, channels, taps, bias, seed=0):
+    import jax
+    import jax.numpy as jnp
+
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(k[0], (batch, t, channels), jnp.bfloat16),
+            jax.random.normal(k[1], (channels, taps), jnp.float32) * 0.5,
+            jax.random.normal(k[2], (channels,), jnp.float32) if bias
+            else None,
+            jax.random.normal(k[3], (batch, t, channels), jnp.bfloat16))
+
+
+def form(x, w, b, act):
+    """The operator on the CPU: the ``jax.numpy`` form."""
+    return _causal_conv1d(
+        [x, w] + ([] if b is None else [b]),
+        dict(act_type=act, no_bias=b is None, num_group=0),
+        OpMode(is_train=True, platform="cpu"))
+
+
+def kernels(x, w, b, act, plan=PLAN):
+    return ck.causal_conv(x, w, b, act, plan, True)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
+@pytest.mark.parametrize("act", ["silu", "none"])
+@pytest.mark.parametrize("taps", [2, 4])
+def test_kernels_match_the_jax_numpy_form(taps, act, bias, shape):
+    """Forward to the rounding of one bfloat16 ulp at a handful of places
+    (XLA:CPU contracts a product and a sum where the interpreter does not);
+    ``dw`` and ``dbias`` float32 sums in another order; ``dx`` rounded once
+    from float32 where the ``jax.numpy`` form's transpose adds K bfloat16
+    terms."""
+    import jax
+
+    x, w, b, dy = _inputs(*SHAPES[shape], taps, bias)
+    got, vjp = jax.vjp(lambda *a: kernels(*a, act), x, w, b)
+    want, vjp0 = jax.vjp(lambda *a: form(*a, act), x, w, b)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    off = np.asarray(got) != np.asarray(want)
+    assert off.mean() < 1e-3 and _rel(got, want) < 8e-3
+    dx, dw, db = vjp(dy)
+    dx0, dw0, db0 = vjp0(dy)
+    assert dx.dtype == x.dtype and dw.dtype == w.dtype
+    assert _rel(dx, dx0) < 1.6e-2
+    assert _rel(dw, dw0) < 2e-6
+    if bias:
+        assert db.dtype == b.dtype and _rel(db, db0) < 2e-6
+    else:
+        assert db is None
+
+
+@pytest.mark.parametrize("act", ["silu", "none"])
+@pytest.mark.parametrize("taps", [2, 4])
+def test_gradients_against_float64_arithmetic(taps, act):
+    """``dx`` closer to the exact gradient than the ``jax.numpy`` form's:
+    the kernel rounds it once."""
+    import jax
+
+    x, w, b, dy = _inputs(2, 64, 128, taps, True, seed=3)
+
+    def exact(x, w, b):
+        with jax.enable_x64():
+            import jax.numpy as jnp
+            xf, wf = jnp.asarray(x, jnp.float64), jnp.asarray(w, jnp.float64)
+
+            def f(xf, wf, bf):
+                xp = jnp.pad(xf, ((0, 0), (taps - 1, 0), (0, 0)))
+                pre = sum(xp[:, j:j + x.shape[1]] * wf[:, j]
+                          for j in range(taps)) + bf
+                return pre * jax.nn.sigmoid(pre) if act == "silu" else pre
+
+            _, vjp = jax.vjp(f, xf, wf, jnp.asarray(b, jnp.float64))
+            return vjp(jnp.asarray(dy, jnp.float64))
+
+    want = exact(x, w, b)
+    got = jax.vjp(lambda *a: kernels(*a, act), x, w, b)[1](dy)
+    old = jax.vjp(lambda *a: form(*a, act), x, w, b)[1](dy)
+    for g, o, e, limit in zip(got, old, want, (4e-3, 1e-6, 1e-6)):
+        assert _rel(g, e) < limit
+        assert _rel(g, e) <= _rel(o, e) * 1.01 + 1e-7
+
+
+@pytest.mark.parametrize("taps", [2, 4])
+def test_a_row_moves_itself_and_the_taps_after_it_across_a_block_edge(taps):
+    """A change at the last row of a time block (and of a tile) moves that
+    row and the K - 1 after it, which are the next grid step's (the next
+    tile's) first rows, and nothing else; its gradient reads the cotangent
+    of the same rows."""
+    import jax
+    import jax.numpy as jnp
+
+    x, w, b, dy = _inputs(1, 96, 128, taps, False, seed=1)
+    for t in (PLAN.time - 1, PLAN.time + PLAN.rows - 1):
+        moved = x.at[0, t].add(1.0)
+        delta = np.asarray(kernels(moved, w, b, "silu"), np.float32) \
+            - np.asarray(kernels(x, w, b, "silu"), np.float32)
+        rows = np.flatnonzero(np.abs(delta[0]).max(axis=1))
+        assert rows.tolist() == list(range(t, t + taps))
+        # dx_t reads dy_t .. dy_{t+K-1}: a cotangent at one of them reaches
+        # rows t' - K + 1 .. t' only
+        only = jnp.zeros_like(dy).at[0, t + 1].set(1.0)
+        dx = jax.vjp(lambda x: kernels(x, w, b, "silu"), x)[1](only)[0]
+        rows = np.flatnonzero(np.abs(np.asarray(dx[0], np.float32)).max(axis=1))
+        assert rows.tolist() == list(range(t + 2 - taps, t + 2))
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_the_first_rows_see_zeros_in_every_row_of_the_batch(batch):
+    """``x_{<0} = 0``: row t < K - 1 is the sum of its t + 1 last taps, in
+    the second row of a batch too (the carried rows start at zero with
+    every (batch, channel block))."""
+    taps = 4
+    x, w, _, _ = _inputs(batch, 64, 256, taps, False, seed=2)
+    got = np.asarray(kernels(x, w, None, "none"), np.float32)
+    xf, wf = np.asarray(x, np.float32), np.asarray(w)
+    for t in range(taps - 1):
+        want = sum(xf[:, t - s] * wf[:, taps - 1 - s] for s in range(t + 1))
+        assert np.allclose(got[:, t], want, rtol=1e-2, atol=1e-2), t
+    # the rows after another row's end do not reach this one
+    alone = np.asarray(kernels(x[-1:], w, None, "none"), np.float32)
+    assert np.array_equal(alone[0], got[-1])
+
+
+# --- the rule ----------------------------------------------------------------
+RULE_CASES = {
+    # dtype, x_shape, taps, platform, num_group
+    "the_qwen3_next_cell": (("bfloat16", (1, 8192, 8192), 4, "tpu"), True),
+    "zaya1_at_a_batch_of_four": (("bfloat16", (4, 8192, 1280), 2, "tpu"),
+                                 True),
+    "t_is_padded": (("bfloat16", (2, 8000, 2560), 4, "tpu"), True),
+    # 20 MiB: an array XLA can hold in the v5e's 128 MiB of VMEM
+    "the_zaya1_cell_is_under_half_the_vmem": (
+        ("bfloat16", (1, 8192, 1280), 2, "tpu"), False),
+    "just_under_half_the_vmem": (("bfloat16", (1, 8184, 4096), 4, "tpu"),
+                                 False),
+    "cpu": (("bfloat16", (1, 8192, 8192), 4, "cpu"), False),
+    "float32_trunk": (("float32", (1, 8192, 8192), 4, "tpu"), False),
+    "a_width_128_does_not_divide": (("bfloat16", (1, 8192, 8256), 4, "tpu"),
+                                    False),
+    "grouped": (("bfloat16", (4, 8192, 1280), 2, "tpu", 10), False),
+    "more_taps_than_the_carried_rows": (
+        ("bfloat16", (1, 8192, 8192), 10, "tpu"), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE_CASES))
+def test_rule_says_where_the_kernels_engage(monkeypatch, case):
+    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: V5E_VMEM)
+    args, engages = RULE_CASES[case]
+    plan = ck.kernel_plan(*args)
+    assert (plan is not None) == engages
+    if engages:
+        b, t, c = args[1]
+        assert b * t * c * 2 >= V5E_VMEM // 2
+        assert c % plan.channels == 0 and plan.channels % 128 == 0
+        assert plan.time % plan.rows == 0 and plan.rows % 16 == 0
+        blocks = -(-t // plan.time)
+        assert 0 <= blocks * plan.time - t < blocks * plan.rows
+        assert plan.vmem_limit <= V5E_VMEM * 3 // 4
+
+
+@pytest.mark.parametrize("chips,engages", [(1, True), (4, False)])
+def test_rule_with_chips_attached(monkeypatch, chips, engages):
+    """One attached v5e gives the cells' shapes a plan, four give none
+    (XLA cannot partition a Mosaic call), and a program lowered for the CPU
+    in such a process gets none."""
+    import jax
+
+    class Chip:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [Chip()] * chips)
+    cell = ("bfloat16", (1, 8192, 8192), 4)
+    assert (ck.kernel_plan(*cell) is not None) == engages
+    assert (ck.kernel_plan(*cell, "tpu") is not None) == engages
+    assert ck.kernel_plan(*cell, "cpu") is None
+
+
+def test_on_the_cpu_the_op_takes_the_jax_numpy_form():
+    import jax
+
+    assert ck.kernel_plan("bfloat16", (1, 8192, 8192), 4) is None
+    assert ck.kernel_plan("bfloat16", (1, 8192, 8192), 4, "tpu") is None
+    x, w, b, _ = _inputs(1, 64, 128, 4, False)
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda x, w: form(x, w, None, "silu"))(x, w))
+    sym = mx.sym.CausalConv1D(mx.sym.Variable("data"), kernel=4, name="conv")
+    exe = sym.bind(mx.cpu(), {"data": mx.nd.array(np.asarray(x, np.float32))
+                              .astype("bfloat16"),
+                              "conv_weight": mx.nd.array(np.asarray(w))})
+    assert exe.forward()[0].shape == x.shape
+
+
+# --- the models through the kernels, and the executor's counter ---------------
+# widths 128 divides: 2 x 64 + 128 = 256 channels under the Qwen3-Next
+# mixer's convolution, (4 + 2) x 64 = 384 under ZAYA1's first
+MODELS = {
+    "qwen3_next": (tq, dict(linear_key_head_dim=32, linear_value_head_dim=32),
+                   96, 3),
+    "zaya1": (tz, dict(num_hidden_layers=4, head_dim=64), 48, 4),
+}
+
+
+def _steer(monkeypatch):
+    """The rule as a process with one TPU would hear it for a program
+    lowered for that chip (one whose VMEM the small models' rows would
+    not fit twice), the kernels at the test's small blocks in the
+    interpreter."""
+    rule, conv = ck.kernel_plan, ck.causal_conv
+    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: 64 << 10)
+    monkeypatch.setattr(
+        ck, "kernel_plan", lambda dtype, shape, taps, platform=None, group=0:
+        rule(dtype, shape, taps, "tpu", group) and PLAN)
+    monkeypatch.setattr(
+        ck, "causal_conv", lambda x, w, b, act, plan: conv(x, w, b, act, plan,
+                                                           True))
+
+
+@pytest.mark.parametrize("mirror", ["", "1"], ids=["kept", "recomputed"])
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_train_program_through_the_kernels(monkeypatch, model, mirror):
+    """A bfloat16 model through ``Module``: on the CPU the counter stays
+    (the ``jax.numpy`` form); with the rule asked as for one TPU the program
+    launches (under ``MXNET_BACKWARD_DO_MIRROR`` the ``custom_vjp`` sits in
+    ``jax.checkpoint``: nothing traced may be closed over), counts one
+    kernel layer a depthwise convolution, none for ZAYA1's grouped second
+    convolutions, and its outputs and every parameter's step are the
+    ``jax.numpy`` form's to bfloat16 rounding."""
+    from mxnet_tpu import telemetry as tm
+
+    t, over, seq_len, layers = MODELS[model]
+    if mirror:
+        monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", mirror)
+    sym = t.tiny_sym_gen(dtype="bfloat16", **over)(seq_len)[0]
+    ids, label = t.seeded_tokens(seq_len=seq_len)
+    shapes = dict(data=(t.B, seq_len), softmax_label=(t.B, seq_len))
+    params = t.seeded_params(sym, **shapes)
+
+    def step(steered):
+        if steered:
+            _steer(monkeypatch)
+        mod = mx.mod.Module(sym, context=mx.cpu())
+        mod.bind(data_shapes=[("data", shapes["data"])],
+                 label_shapes=[("softmax_label", shapes["softmax_label"])])
+        mod.init_params(arg_params={n: mx.nd.array(a)
+                                    for n, a in params.items()},
+                        aux_params={})
+        mod.init_optimizer(optimizer="sgd",
+                           optimizer_params={"learning_rate": 0.1})
+        before = tm.snapshot().get("executor", {})
+        mod.forward_backward(mx.io.DataBatch(data=[mx.nd.array(ids)],
+                                             label=[mx.nd.array(label)]))
+        mod.update()
+        after = tm.snapshot()["executor"]
+        return (after.get("conv_kernel_layers", 0)
+                - before.get("conv_kernel_layers", 0),
+                mod.get_outputs()[0].asnumpy(),
+                {n: a.asnumpy() for n, a in mod.get_params()[0].items()})
+
+    form_count, form_out, form_params = step(False)
+    count, out, now = step(True)
+    assert (form_count, count) == (0, layers)
+    assert _rel(out, form_out) < 2e-2
+    for n, a in now.items():
+        moved = np.abs(form_params[n] - params[n]).max()
+        assert np.abs(a - form_params[n]).max() <= 0.05 * moved + 1e-6, n
+
+
+def test_a_graph_without_a_convolution_counts_none():
+    from mxnet_tpu import models, telemetry as tm
+
+    gen = models.olmoe_sym_gen(vocab_size=64, hidden_size=32, num_layers=1,
+                               num_heads=4, num_experts=4, expert_width=16,
+                               top_k=2)
+    mod = mx.mod.Module(gen(16)[0], context=mx.cpu())
+    mod.bind(data_shapes=[("data", (2, 16))],
+             label_shapes=[("softmax_label", (2, 16))])
+    mod.init_params(mx.init.Normal(0.1))
+    mod.init_optimizer(optimizer="sgd")
+    ids, label = tz.seeded_tokens(seq_len=16)
+    before = tm.snapshot().get("executor", {}).get("conv_kernel_layers", 0)
+    mod.forward_backward(mx.io.DataBatch(data=[mx.nd.array(ids)],
+                                         label=[mx.nd.array(label)]))
+    mod.update()
+    assert tm.snapshot()["executor"].get("conv_kernel_layers", 0) == before

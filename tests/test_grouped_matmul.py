@@ -587,3 +587,42 @@ def test_gated_delta_scan_kernels_compile_for_a_v5e_at_the_cell_widths(
     assert "gated_delta_scan_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= b * hk * g * n * d * d * 4 + (4 << 20)
+
+
+@pytest.mark.parametrize("cell", ["qwen3_next", "zaya1"])
+def test_causal_conv_kernels_compile_for_a_v5e_at_the_cell_widths(
+        monkeypatch, one_chip, cell):
+    """Mosaic accepts the two kernels of ``CausalConv1D``'s depthwise form
+    (``ops/causal_conv_kernels.py``; their other tests are in
+    ``test_causal_conv_kernels.py``) at the rule's blocks for the Qwen3-Next
+    cell's convolution, T 8192 over 8192 channels, 4 taps and SiLU, and for
+    ZAYA1's first, 1280 channels, 2 taps, a bias and no activation, at a
+    batch of four rows (one row is under half the VMEM: the rule keeps the
+    ``jax.numpy`` form). Between forward and backward nothing is kept: the
+    program's only temporaries are the taps turned a row a tap."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import causal_conv_kernels as ck
+
+    b, t, c, taps, act, bias = {
+        "qwen3_next": (1, 8192, 8192, 4, "silu", False),
+        "zaya1": (4, 8192, 1280, 2, "none", True)}[cell]
+    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: V5E_VMEM)
+    plan = ck.kernel_plan(jnp.bfloat16, (b, t, c), taps, "tpu")
+    assert plan is not None and t % plan.time == 0 and c % plan.channels == 0
+
+    def step(x, w, b, dy):
+        out, vjp = jax.vjp(lambda *a: ck.causal_conv(*a, act, plan), x, w, b)
+        return (out,) + vjp(dy)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        arg((b, t, c), jnp.bfloat16), arg((c, taps), jnp.float32),
+        arg((c,), jnp.float32) if bias else None,
+        arg((b, t, c), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "causal_conv_fwd" in text and "causal_conv_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
