@@ -14,7 +14,7 @@ import threading
 from dataclasses import dataclass, field
 
 from .base import MXNetError
-from .ops.registry import OpMode
+from .ops.registry import OpMode, platform_of
 
 _state = threading.local()
 
@@ -152,7 +152,8 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
             ins = []
             for nd, recorded in zip(entry.inputs, entry.input_values):
                 ins.append(env.get(id(nd), recorded))
-            mode = OpMode(is_train=train_mode, rng=entry.rng)
+            mode = OpMode(is_train=train_mode, rng=entry.rng,
+                          platform=platform_of(entry.input_values))
             outs, _aux = entry.opdef.apply(ins, entry.params, mode)
             for nd, o in zip(entry.outputs, outs):
                 env[id(nd)] = o
@@ -201,7 +202,8 @@ def grad(heads, variables, head_grads=None, retain_graph=None, create_graph=Fals
                 env.get(id(nd), rec)
                 for nd, rec in zip(entry.inputs, entry.input_values)
             ]
-            mode = OpMode(is_train=train_mode, rng=entry.rng)
+            mode = OpMode(is_train=train_mode, rng=entry.rng,
+                          platform=platform_of(entry.input_values))
             outs, _aux = entry.opdef.apply(ins, entry.params, mode)
             for nd, o in zip(entry.outputs, outs):
                 env[id(nd)] = o

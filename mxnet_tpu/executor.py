@@ -29,6 +29,7 @@ bulk execution disables itself when a monitor is installed
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import Any, NamedTuple
 
 import numpy as np
@@ -483,6 +484,9 @@ class _CompiledGraph:
         self.scoped_nodes = 0
         # those of them whose checkpoint kept a residual the op named
         self.kept_residual_nodes = 0
+        # {instrument: n}: what those nodes' ops declared a launch counts
+        # (``OpDef.launch_counts``), summed; None until an ``evaluate``
+        self.launch_counts = None
 
     def shared_fc_groups(self):
         """[(weight name, nodes)] of the ``FullyConnected`` nodes, two or
@@ -528,6 +532,7 @@ class _CompiledGraph:
         nhwc = self.layout == "NHWC"
         scoped = 0  # op nodes lowered under their own scope
         kept = 0  # op nodes whose checkpoint kept a residual
+        declared = Counter()  # what the nodes' ops declared a launch counts
         policy = KeptResiduals() if self.remat else None
         env = {}
         cl = {}  # id(node) -> per-output channels-last flags (NHWC mode)
@@ -635,6 +640,8 @@ class _CompiledGraph:
                         OpMode(is_train=is_train, rng=node_rng,
                                layout=op_layout, platform=self.platform),
                     )
+            declared.update(node.op.launch_counts(ins, outs, params,
+                                                  self.platform))
             if group:
                 for i, n in enumerate(group[1:], 1):
                     env[id(n)] = [outs[0][i]]
@@ -668,6 +675,7 @@ class _CompiledGraph:
                     suffix = "_output" if i == 0 else f"_output{i}"
                     monitor(node.name + suffix, o)
         self.scoped_nodes, self.kept_residual_nodes = scoped, kept
+        self.launch_counts = declared
         if limit is not None:
             if nhwc and last_cl:
                 last_outs = [
@@ -762,11 +770,12 @@ class Executor:
         self._guard_dev = None  # device [total, consec] non-finite counters
         self._fc_plan = None  # memoized _shared_fc_plan
         self._grads_crowd = None  # memoized _grads_crowd_device
-        self._layer_counts = None  # memoized _transformer_layers
-        # op nodes the train programs' trace lowered under a scope, and
-        # those of them whose recomputation kept a residual the op named
+        # op nodes the train programs' trace lowered under a scope, those
+        # of them whose recomputation kept a residual the op named, and
+        # what their ops declared a launch counts (None: not traced here)
         self._scoped_nodes = 0
         self._kept_residual_nodes = 0
+        self._launch_counts = None
         if shared_exec is not None:
             # bucketing: share compiled-function cache and memory with the
             # master executor (reference shared_exec data_pool_ reuse,
@@ -1470,137 +1479,36 @@ class Executor:
             self._grads_crowd = bool(limit) and held * 8 > limit
         return self._grads_crowd
 
-    def _transformer_layers(self):
-        """What the bound graph's transformer layers hold, as the counters
-        of ``_count_train_launch`` name it: ``moe_layers``;
-        ``moe_assignments``, tokens x ``top_k`` from the bound shapes;
-        ``moe_local_experts``, the experts the layers hold here;
-        ``moe_graph_routed_layers``, those whose logits are an input the
-        graph computed (``router="graph"``) and not a product inside the op;
-        ``moe_kernel_matmuls``, how many expert matmuls of a train program
-        run the Pallas kernels, asked of the rule the op follows
-        (``ops/defs_transformer.moe_kernel_matmuls``) with this executor's
-        platform and the rows of one dispatch round;
-        ``attention_layers``; ``attention_window_layers``, those with a
-        ``window``; ``attention_kernel_layers``, those a train program runs
-        in the fused Pallas kernels, asked of the rule the op follows
-        (``parallel/ring_attention.kernel_plan``) with this executor's
-        platform; ``attention_scored_pairs``, the query-key pairs of the
-        tiles they visit (``ring_attention.pairs_scored`` x heads x
-        batch); ``attention_latent_layers``, those whose values are
-        narrower or wider than their keys (a latent-attention head: 192
-        over 128); ``attention_pair_lanes``, summed over the layers, the
-        width a pair's score contracts over plus the width its ``p.v``
-        writes, as the path hands them over
-        (``ring_attention.pair_lanes``); ``linear_attention_layers``, the
-        ``GatedDeltaRule`` nodes,
-        ``linear_attention_chunks``, the chunks their rows are cut into
-        (batch x T / chunk a layer: the scan's trips; T a layer would mean
-        a token at a time), and ``linear_attention_kernel_layers`` and
-        ``linear_attention_scan_kernel_layers``, those whose chunk-local
-        algebra and whose scan over chunks a train program runs in the
-        Pallas kernels, asked of the rule the op follows
-        (``ops/gated_delta.kernel_plan``: one rule, a node runs all of its
-        kernels or none) with this executor's platform;
-        ``conv_grouped_layers``, the ``CausalConv1D`` nodes that mix
-        channels inside groups (``num_group``) and are not depthwise, and
-        ``conv_kernel_layers``, the ``CausalConv1D`` nodes a train program
-        runs in the Pallas kernels, asked of the rule the op follows
-        (``ops/causal_conv_kernels.kernel_plan``) with this executor's
-        platform.
-        Shapes and types are inferred only where the
-        graph has such a node."""
-        if self._layer_counts is None:
-            ops = [n for n in self.graph.topo if not n.is_variable]
-            moe = [n for n in ops if n.op.name == "MoE"]
-            attention = [n for n in ops if n.op.name == "RingAttention"]
-            linear = [n for n in ops if n.op.name == "GatedDeltaRule"]
-            conv = [n for n in ops if n.op.name == "CausalConv1D"]
-            counts = dict.fromkeys((
-                "moe_layers", "moe_assignments", "moe_local_experts",
-                "moe_kernel_matmuls", "moe_graph_routed_layers",
-                "attention_layers",
-                "attention_window_layers", "attention_kernel_layers",
-                "attention_scored_pairs", "attention_latent_layers",
-                "attention_pair_lanes", "linear_attention_layers",
-                "linear_attention_chunks", "linear_attention_kernel_layers",
-                "linear_attention_scan_kernel_layers",
-                "conv_kernel_layers"), 0)
-            counts["conv_grouped_layers"] = sum(
-                n.params()["num_group"] > 0 for n in conv)
-            if moe or attention or linear or conv:
-                from .ops.causal_conv_kernels import (
-                    kernel_plan as conv_kernel_plan)
-                from .ops.defs_transformer import (held_round_rows,
-                                                   moe_kernel_matmuls)
-                from .ops.gated_delta import (chunks_of,
-                                              kernel_plan as delta_kernel_plan)
-                from .parallel.ring_attention import (kernel_plan,
-                                                      pair_lanes,
-                                                      pairs_scored)
+    def _declared_from_shapes(self):
+        """What the graph's nodes declare one launch of a train program
+        counts (``OpDef.launch_counts``), asked over shapes and types
+        inferred from the bound arguments: for the launches of a program
+        whose trace did not run here (an executable read from the
+        ``MXNET_AOT_CACHE`` store, a program another executor traced into
+        the ``_jit_cache`` they share). Nothing is inferred for a graph
+        none of whose ops declares anything."""
+        import jax
 
-                internals = self._symbol.get_internals()
-                _, shapes, _ = internals.infer_shape(
-                    **{n: tuple(a.shape) for n, a in self.arg_dict.items()})
-                shape_of = dict(zip(internals.list_outputs(), shapes))
-                _, dtypes, _ = internals.infer_type(
-                    **{n: a.dtype for n, a in self.arg_dict.items()})
-                dtype_of = dict(zip(internals.list_outputs(), dtypes))
-                platform = self._ctx.jax_device().platform
-                for n in moe:
-                    out, p = n.name + "_output", n.params()
-                    routed = int(np.prod(shape_of[out][:-1])) * p["top_k"]
-                    held = p["num_local_experts"] or p["num_experts"]
-                    counts["moe_layers"] += 1
-                    counts["moe_assignments"] += routed
-                    counts["moe_local_experts"] += held
-                    counts["moe_graph_routed_layers"] += p["router"] == "graph"
-                    counts["moe_kernel_matmuls"] += moe_kernel_matmuls(
-                        platform, dtype_of[out],
-                        self.arg_dict[n.inputs[2][0].name].dtype,
-                        held_round_rows(routed, held, p["num_experts"]),
-                        shape_of[out][-1], p["num_hidden"])
-                for n in attention:
-                    p = n.params()
-                    out = shape_of[n.name + "_output"]
-                    # the key, named as ``list_outputs`` names an entry;
-                    # the output has the query's shape at the value's width
-                    key, = type(internals)([n.inputs[1]]).list_outputs()
-                    kv_heads, key_dim = shape_of[key][1], shape_of[key][3]
-                    query = tuple(out[:3]) + (key_dim,)
-                    kernels = kernel_plan(
-                        dtype_of[n.name + "_output"], query, kv_heads,
-                        p["causal"], p["window"], platform, out[3])
-                    counts["attention_layers"] += 1
-                    counts["attention_window_layers"] += bool(p["window"])
-                    counts["attention_kernel_layers"] += kernels is not None
-                    counts["attention_scored_pairs"] += pairs_scored(
-                        query, p["causal"], p["window"], kernels)
-                    counts["attention_latent_layers"] += key_dim != out[3]
-                    counts["attention_pair_lanes"] += pair_lanes(key_dim,
-                                                                 out[3])
-                for n in linear:
-                    value = shape_of[n.name + "_output"]
-                    batch, _, T, _ = value
-                    chunk = n.params()["chunk"]
-                    query, key = type(internals)(
-                        [n.inputs[0], n.inputs[1]]).list_outputs()
-                    counts["linear_attention_layers"] += 1
-                    counts["linear_attention_chunks"] += batch * chunks_of(
-                        T, chunk)
-                    kernels = delta_kernel_plan(
-                        dtype_of[query], shape_of[key], value, chunk,
-                        platform) is not None
-                    counts["linear_attention_kernel_layers"] += kernels
-                    counts["linear_attention_scan_kernel_layers"] += kernels
-                for n in conv:
-                    # the output has the data's shape and dtype
-                    out, p = n.name + "_output", n.params()
-                    counts["conv_kernel_layers"] += conv_kernel_plan(
-                        dtype_of[out], shape_of[out], p["kernel"], platform,
-                        p["num_group"]) is not None
-            self._layer_counts = counts
-        return self._layer_counts
+        nodes = [n for n in self.graph.topo
+                 if not n.is_variable and n.op.launch_instruments]
+        counts = Counter()
+        if not nodes:
+            return counts
+        internals = self._symbol.get_internals()
+        _, shapes, _ = internals.infer_shape(
+            **{n: tuple(a.shape) for n, a in self.arg_dict.items()})
+        _, dtypes, _ = internals.infer_type(
+            **{n: a.dtype for n, a in self.arg_dict.items()})
+        entry = {(id(n), i): jax.ShapeDtypeStruct(s, np_dtype(d))
+                 for (n, i), s, d in zip(internals._outputs, shapes, dtypes)}
+        for node in nodes:
+            params = node.params()
+            counts.update(node.op.launch_counts(
+                [entry[id(n), i] for n, i in node.inputs],
+                [entry[id(node), i]
+                 for i in range(node.op.num_visible_outputs(params))],
+                params, self.graph.platform))
+        return counts
 
     def _count_train_launch(self):
         """One launch of a train program, counted by what it holds: the op
@@ -1609,8 +1517,11 @@ class Executor:
         ``MXNET_AOT_CACHE`` store and was not traced here), those of them
         whose per-operator recomputation (``MXNET_BACKWARD_DO_MIRROR``)
         kept a residual the op named (``ops/registry.keep``), the shared
-        weights whose gradient it computes as one matmul, and its
-        sparse-expert and attention layers (``_transformer_layers``)."""
+        weights whose gradient it computes as one matmul, and what its
+        nodes' ops declared a launch counts (``OpDef.launch_counts``: the
+        sparse-expert, attention, linear-attention and convolution layers'
+        counters, docs/observability.md), summed while the trace lowered
+        them."""
         if self._scoped_nodes:
             _tm.counter("executor.scoped_nodes").inc(self._scoped_nodes)
         if self._kept_residual_nodes:
@@ -1619,54 +1530,11 @@ class Executor:
         weights = self._shared_fc_plan()[2]
         if weights:
             _tm.counter("executor.stacked_wgrad").inc(weights)
-        held = self._transformer_layers()
-        # literal names (the telemetry catalog is read from the source), and
-        # a counter only where the graph holds such a layer
-        if held["moe_layers"]:
-            _tm.counter("executor.moe_layers").inc(held["moe_layers"])
-            _tm.counter("executor.moe_assignments").inc(
-                held["moe_assignments"])
-            _tm.counter("executor.moe_local_experts").inc(
-                held["moe_local_experts"])
-        if held["moe_kernel_matmuls"]:
-            _tm.counter("executor.moe_kernel_matmuls").inc(
-                held["moe_kernel_matmuls"])
-        if held["moe_graph_routed_layers"]:
-            _tm.counter("executor.moe_graph_routed_layers").inc(
-                held["moe_graph_routed_layers"])
-        if held["conv_grouped_layers"]:
-            _tm.counter("executor.conv_grouped_layers").inc(
-                held["conv_grouped_layers"])
-        if held["conv_kernel_layers"]:
-            _tm.counter("executor.conv_kernel_layers").inc(
-                held["conv_kernel_layers"])
-        if held["attention_layers"]:
-            _tm.counter("executor.attention_layers").inc(
-                held["attention_layers"])
-            _tm.counter("executor.attention_scored_pairs").inc(
-                held["attention_scored_pairs"])
-            _tm.counter("executor.attention_pair_lanes").inc(
-                held["attention_pair_lanes"])
-        if held["attention_latent_layers"]:
-            _tm.counter("executor.attention_latent_layers").inc(
-                held["attention_latent_layers"])
-        if held["attention_kernel_layers"]:
-            _tm.counter("executor.attention_kernel_layers").inc(
-                held["attention_kernel_layers"])
-        if held["attention_window_layers"]:
-            _tm.counter("executor.attention_window_layers").inc(
-                held["attention_window_layers"])
-        if held["linear_attention_layers"]:
-            _tm.counter("executor.linear_attention_layers").inc(
-                held["linear_attention_layers"])
-            _tm.counter("executor.linear_attention_chunks").inc(
-                held["linear_attention_chunks"])
-        if held["linear_attention_kernel_layers"]:
-            _tm.counter("executor.linear_attention_kernel_layers").inc(
-                held["linear_attention_kernel_layers"])
-        if held["linear_attention_scan_kernel_layers"]:
-            _tm.counter("executor.linear_attention_scan_kernel_layers").inc(
-                held["linear_attention_scan_kernel_layers"])
+        if self._launch_counts is None:
+            self._launch_counts = self._declared_from_shapes()
+        for name, n in self._launch_counts.items():
+            if n:  # a counter only where the graph holds such a layer
+                _tm.counter(name).inc(n)  # graftlint: allow=telemetry-catalog(forwards the literal names the graph's ops list as OpDef.launch_instruments; tests/test_launch_counts.py holds every one to docs/observability.md)
 
     def _make_grad_core(self):
         """Shared fwd+bwd tracing core used by both the plain train_step
@@ -1703,6 +1571,7 @@ class Executor:
                                                fc_batches=fc_batches)
                 self._scoped_nodes = graph.scoped_nodes
                 self._kept_residual_nodes = graph.kept_residual_nodes
+                self._launch_counts = graph.launch_counts
                 total = None
                 for j, o in enumerate(outs):
                     if not jnp.issubdtype(o.dtype, jnp.floating):

@@ -25,7 +25,7 @@ import numpy as np
 from .base import MXNetError, np_dtype
 from .context import Context, cpu, current_context
 from .ops import registry as _reg
-from .ops.registry import OpMode
+from .ops.registry import OpMode, platform_of
 from . import random as _random
 from . import telemetry as _telemetry
 
@@ -923,6 +923,7 @@ def _make_ndarray_function(opdef, func_name):
         mode = OpMode(
             is_train=autograd.is_training(),
             rng=_random.next_key() if opdef.need_rng else None,
+            platform=platform_of(arrays),
         )
         outputs, new_aux = opdef.apply(arrays, params, mode)
         # write aux updates back into their handles (mutable aux semantics)

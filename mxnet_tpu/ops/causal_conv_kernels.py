@@ -36,7 +36,7 @@ What the kernels do that the ``jax.numpy`` form does not:
 
 ``kernel_plan`` is the one rule that says whether the kernels engage and with
 which blocks, as ``gated_delta.kernel_plan`` is the gated delta rule's;
-traced kernels are kept by ``grouped_matmul._kernel``'s store.
+traced kernels are kept by ``pallas_support._kernel``'s store.
 """
 
 from __future__ import annotations
@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import grouped_matmul as _gmm
+from . import pallas_support as _ps
 
 _LANES = 128
 # Rows carried from one tile to the next: one float32 register's sublanes,
@@ -98,9 +98,9 @@ def kernel_plan(dtype, x_shape, taps, platform=None,
     backward in 0.10 ms, under the 0.13 its bytes would take across HBM,
     and the cell's step is 1.4 ms longer with the kernels; at 128 MiB,
     Qwen3-Next's 8192 channels, 5.04 ms against the kernels' 1.28; PERF.md
-    section 6, PR 46). The op and the executor's counter ask it with the
-    same arguments."""
-    vmem = _gmm.attached_vmem_bytes()
+    section 6, PR 46). The op and its launch counts ask it with the same
+    arguments."""
+    vmem = _ps.attached_vmem_bytes()
     if (platform or jax.default_backend()) != "tpu" or not vmem:
         return None
     B, T, C = x_shape
@@ -119,7 +119,7 @@ def kernel_plan(dtype, x_shape, taps, platform=None,
 def _moved(x, by):
     """``x`` (rows, lanes) float32 with row t at row t + ``by``, the rows
     that leave at one end coming back at the other."""
-    _, pltpu = _gmm._pallas()
+    _, pltpu = _ps._pallas()
     return pltpu.roll(x, by % x.shape[0], 0) if by else x
 
 
@@ -149,7 +149,7 @@ def _padded(x, plan):
 
 
 def _cost(x, taps, passes, act):
-    pl, _ = _gmm._pallas()
+    pl, _ = _ps._pallas()
     return pl.CostEstimate(
         flops=x.size * (2 * taps + 8) * passes,
         transcendentals=x.size * (act == "silu"),
@@ -162,7 +162,7 @@ def _cost(x, taps, passes, act):
 def _fwd(x, wt, bias, *, act, time, channels, rows, vmem_limit, interpret):
     """y in x's shape and dtype: x (B, T, C) with T whole grid steps, wt (K,
     C) and bias (1, C) or None float32."""
-    pl, pltpu = _gmm._pallas()
+    pl, pltpu = _ps._pallas()
     B, T, C = x.shape
     K = wt.shape[0]
     f = ACTS[act]
@@ -222,7 +222,7 @@ def _bwd(x, wt, bias, dy, *, act, time, channels, rows, vmem_limit,
     float32): the time blocks of a (channel block, batch) from the last to
     the first. With ``act`` ``none`` the pre-activation is not made again
     and the rows before a block are not read."""
-    pl, pltpu = _gmm._pallas()
+    pl, pltpu = _ps._pallas()
     B, T, C = x.shape
     K = wt.shape[0]
     blocks = T // time
@@ -362,14 +362,14 @@ def _static(act, plan, interpret):
 
 
 def _conv_fwd(x, w, bias, act, plan, interpret):
-    y = _gmm._kernel(_fwd, (_padded(x, plan), *_operands(w, bias)),
+    y = _ps._kernel(_fwd, (_padded(x, plan), *_operands(w, bias)),
                      **_static(act, plan, interpret))
     return y[:, :x.shape[1]], (x, w, bias)
 
 
 def _conv_bwd(act, plan, interpret, res, dy):
     x, w, bias = res
-    dx, dwt, dbias = _gmm._kernel(
+    dx, dwt, dbias = _ps._kernel(
         _bwd, (_padded(x, plan), *_operands(w, bias),
                _padded(dy.astype(x.dtype), plan)),
         **_static(act, plan, interpret))

@@ -597,6 +597,43 @@ def _ring_attention_op(ins, params, mode):
     ).astype(q.dtype)
 
 
+def _ring_attention_counts(ins, outs, params, platform):
+    """A launch's counts for one node, from the one-device path's own ask
+    of its rule (``ring_attention.kernel_plan``, as ``_on_one_device`` asks
+    it): whether it has a ``window``; whether a train program runs it in the
+    fused Pallas kernels; the query-key pairs of the tiles it visits,
+    forward (backward recomputes the same): the kernels' visit list at the
+    plan's tiles where they engage, else the ``jax.numpy`` blocks'
+    (``ring_attention.scored_pairs``), x heads x batch; whether its values
+    are narrower or wider than its keys (a latent-attention head: 192 over
+    128); and the lanes a pair is computed over: the width its score
+    contracts over plus the width ``p.v`` writes, as either path is handed
+    them (neither pads a width: 192 over 128 are 320; a model that padded
+    its keys to 256 would hand over 384)."""
+    from ..parallel.ring_attention import (block_q_of, kernel_plan,
+                                           scored_pairs)
+    from . import flash_attention
+
+    q, k, v = ins
+    causal, window = params["causal"], params["window"]
+    batch, heads, T, key_dim = q.shape
+    kernels = kernel_plan(q.dtype, q.shape, k.shape[1], causal, window,
+                          platform, v.shape[-1])
+    if kernels is not None:
+        pairs = flash_attention.scored_pairs(T, kernels.bq, kernels.bk,
+                                             causal, window)
+    else:
+        pairs = scored_pairs(T, causal, window,
+                             block_q_of(batch, heads, T, window))
+    return {"executor.attention_layers": 1,
+            "executor.attention_window_layers": int(bool(window)),
+            "executor.attention_kernel_layers": int(kernels is not None),
+            "executor.attention_scored_pairs": batch * heads * pairs,
+            "executor.attention_latent_layers":
+                int(key_dim != v.shape[-1]),
+            "executor.attention_pair_lanes": key_dim + v.shape[-1]}
+
+
 register(
     "RingAttention",
     _ring_attention_op,
@@ -609,4 +646,11 @@ register(
         "window": Param(parse_int, 0),  # keys a query reads; 0: all before
     },
     aliases=("_contrib_RingAttention",),
+    launch_counts=_ring_attention_counts,
+    launch_instruments=("executor.attention_layers",
+                        "executor.attention_window_layers",
+                        "executor.attention_kernel_layers",
+                        "executor.attention_scored_pairs",
+                        "executor.attention_latent_layers",
+                        "executor.attention_pair_lanes"),
 )

@@ -5,9 +5,13 @@ in ``defs_contrib.py``, whose one-device path is blockwise.)
 
 No reference twin: MXNet 0.x has none of them. The equations are those of
 the public OLMoE model (Muennighoff et al. 2024, arXiv:2409.02060; HF
-``modeling_olmoe.py``). All three are plain jax lowered by XLA, but for
-the grouped matmuls of ``MoE``: Pallas kernels (``grouped_matmul.py``) where
-its rule says they engage, ``jax.lax.ragged_dot`` otherwise.
+``modeling_olmoe.py``). All are plain jax lowered by XLA, but where an
+operator's rule says its Pallas kernels engage, asked with the platform the
+program is lowered for (``OpMode.platform``): the grouped matmuls of ``MoE``
+(``grouped_matmul.py``, else ``jax.lax.ragged_dot``), ``GatedDeltaRule``
+and the depthwise ``CausalConv1D``. Each of the three also declares, beside
+its ``fn`` and asking the same rule with the same arguments, what one launch
+of a train program that holds it counts (``OpDef.launch_counts``).
 
 What is float32 whatever the trunk's dtype: the statistics of ``RMSNorm``,
 the angles and the rotation of ``RotaryEmbedding``, in ``MoE`` the
@@ -29,6 +33,7 @@ from ..base import (MXNetError, parse_bool, parse_float, parse_int,
 from . import causal_conv_kernels as _cck
 from . import gated_delta as _gdr
 from . import grouped_matmul as _gmm
+from . import pallas_support as _ps
 from .defs_nn import _castp, _prec
 from .registry import Param, keep, register
 
@@ -189,6 +194,18 @@ def _causal_conv1d_fill(shapes, p):
     return shapes
 
 
+def _causal_conv1d_counts(ins, outs, params, platform):
+    """A launch's counts for one node: whether it mixes channels inside
+    groups (``num_group``) and is not depthwise, and whether a train
+    program runs it in the Pallas kernels: ``_causal_conv1d``'s own ask of
+    ``causal_conv_kernels.kernel_plan``."""
+    x, w = ins[:2]
+    kernels = _cck.kernel_plan(x.dtype, x.shape, w.shape[-1], platform,
+                               params["num_group"])
+    return {"executor.conv_grouped_layers": int(params["num_group"] > 0),
+            "executor.conv_kernel_layers": int(kernels is not None)}
+
+
 register(
     "CausalConv1D",
     _causal_conv1d,
@@ -199,6 +216,9 @@ register(
                   # 0: depthwise; g: channels mix inside each of g groups
                   "num_group": Param(parse_int, 0)},
     fill_in_shapes=_causal_conv1d_fill,
+    launch_counts=_causal_conv1d_counts,
+    launch_instruments=("executor.conv_grouped_layers",
+                        "executor.conv_kernel_layers"),
 )
 
 
@@ -224,11 +244,32 @@ def _gated_delta_rule(ins, params, mode):
         kernels=kernels).astype(v.dtype)
 
 
+def _gated_delta_rule_counts(ins, outs, params, platform):
+    """A launch's counts for one node: the chunks its rows are cut into
+    (batch x T / chunk: the scan's trips; T would mean a token at a time)
+    and whether a train program runs its chunk-local algebra and its scan
+    over chunks in the Pallas kernels: ``_gated_delta_rule``'s own ask of
+    ``gated_delta.kernel_plan`` (one rule: all four kernels or none)."""
+    q, k, v = ins[:3]
+    kernels = int(_gdr.kernel_plan(q.dtype, k.shape, v.shape, params["chunk"],
+                                   platform) is not None)
+    return {"executor.linear_attention_layers": 1,
+            "executor.linear_attention_chunks":
+                v.shape[0] * _gdr.chunks_of(v.shape[2], params["chunk"]),
+            "executor.linear_attention_kernel_layers": kernels,
+            "executor.linear_attention_scan_kernel_layers": kernels}
+
+
 register(
     "GatedDeltaRule",
     _gated_delta_rule,
     arg_names=["query", "key", "value", "g", "beta"],
     param_schema={"chunk": Param(parse_int, 64)},  # tokens, a power of two
+    launch_counts=_gated_delta_rule_counts,
+    launch_instruments=("executor.linear_attention_layers",
+                        "executor.linear_attention_chunks",
+                        "executor.linear_attention_kernel_layers",
+                        "executor.linear_attention_scan_kernel_layers"),
 )
 
 
@@ -276,46 +317,36 @@ def _attach_router_losses(logits, routed_share, lb_coef, z_coef):
     return f(logits, routed_share)
 
 
-def _expert_plans(platform, vmem_bytes, rows_dtype, w_dtype, m, shapes):
-    """{(K, N): tiles} of one layer's expert matmuls where
-    ``grouped_matmul.plan`` has tiles for every one of ``shapes``, else
-    None: a layer's nine matmuls run the kernels or none does."""
-    plans = {s: _gmm.plan(platform, vmem_bytes, rows_dtype, w_dtype, m, *s)
-             for s in shapes}
+def _expert_plans(platform, rows_dtype, m, weights, vmem_bytes=None):
+    """{(K, N): tiles} of one layer's expert matmuls, ``m`` rows of
+    ``rows_dtype`` times each of ``weights`` (E, K, N) (anything with a
+    shape and a dtype), where ``grouped_matmul.plan`` has tiles for every
+    one of them, else None: a layer's nine matmuls run the kernels or none
+    does. ``platform``: what the program is lowered for (``OpMode.platform``;
+    None: jax's default backend); ``vmem_bytes``: of the one TPU the process
+    holds, read from its kind unless given. ``_expert_matmul`` and the
+    layer's launch counts ask it, with the same arguments."""
+    plans = {w.shape[1:]: _gmm.plan(
+        platform or jax.default_backend(),
+        vmem_bytes or _ps.attached_vmem_bytes(), rows_dtype,
+        weights[0].dtype, m, *w.shape[1:]) for w in weights}
     return None if None in plans.values() else plans
 
 
-def _expert_matmul(counts, rows_dtype, m, weights, vmem_bytes=None,
-                   interpret=False):
+def _expert_matmul(counts, rows_dtype, m, weights, platform=None,
+                   vmem_bytes=None, interpret=False):
     """``f(rows, w)`` for one layer: ``rows`` (M, K) sorted by expert times
     ``w`` (E, K, N), one of ``weights``, as the parameter is stored,
-    ``counts`` rows an expert. Where a TPU is attached (``vmem_bytes``:
-    read from its kind unless given) and ``_expert_plans`` has tiles, ``f``
-    is the Pallas kernels in a program lowered for the TPU (they cast a
-    weight tile in VMEM) and ``ragged_dot`` in one lowered for anything
-    else; with no TPU or no plan there is only ``_castp`` +
-    ``ragged_dot``."""
-    plans = _expert_plans(
-        "tpu", vmem_bytes or _gmm.attached_vmem_bytes(), rows_dtype,
-        weights[0].dtype, m, [w.shape[1:] for w in weights])
+    ``counts`` rows an expert. Where ``_expert_plans`` has tiles ``f`` is
+    the Pallas kernels (they cast a weight tile in VMEM), anywhere else
+    ``_castp`` + ``ragged_dot``: decided here, once, in Python."""
+    plans = _expert_plans(platform, rows_dtype, m, weights, vmem_bytes)
     if plans is None:
         return lambda rows, w: jax.lax.ragged_dot(
             rows, _castp(w, rows), counts, precision=_prec(rows.dtype))
     groups = _gmm.groups(counts, m, plans[weights[0].shape[1:]])
     return lambda rows, w: _gmm.grouped_matmul(
         rows, w, groups, plans[w.shape[1:]], interpret)
-
-
-def moe_kernel_matmuls(platform, data_dtype, weight_dtype, rows, hidden,
-                       width):
-    """How many of one ``MoE`` layer's nine expert matmuls (forward, dgrad
-    and wgrad of gate, up and down) a train program lowered for
-    ``platform`` runs in the Pallas kernels: the rule ``_expert_matmul``
-    follows, asked from outside the trace (``Executor._count_train_launch``).
-    All nine or none."""
-    return 9 * (_expert_plans(
-        platform, _gmm.attached_vmem_bytes(), data_dtype, weight_dtype, rows,
-        [(hidden, width), (width, hidden)]) is not None)
 
 
 def _router_logits(x, w_router):
@@ -432,7 +463,8 @@ def held_round_rows(assignments, held, experts):
     return min(assignments, -(-rows // tile) * tile)
 
 
-def _held_round(first, rows, x, order, weight, counts, w_gate, w_up, w_down):
+def _held_round(first, rows, platform, x, order, weight, counts, w_gate, w_up,
+                w_down):
     """(N, H) float32: what rows ``[first, first + rows)`` of the held
     assignments add to the layer's output. ``order``: the assignments
     (token ``// k``, its j-th expert ``% k``) sorted by expert, the dead
@@ -451,7 +483,8 @@ def _held_round(first, rows, x, order, weight, counts, w_gate, w_up, w_down):
     order = jax.lax.dynamic_slice_in_dim(order, first, rows)
     tok = order // (weight.shape[0] // x.shape[0])            # // top_k
     weight = keep(weight[order])
-    matmul = _expert_matmul(here, x.dtype, rows, (w_gate, w_up, w_down))
+    matmul = _expert_matmul(here, x.dtype, rows, (w_gate, w_up, w_down),
+                            platform)
 
     def live_matmul(r, w):
         return jnp.where(live, matmul(jnp.where(live, r, 0), w), 0)
@@ -466,8 +499,9 @@ def _held_round(first, rows, x, order, weight, counts, w_gate, w_up, w_down):
         y.astype(jnp.float32) * weight[:, None])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_rounds(rows, x, weight, w_gate, w_up, w_down, order, counts):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_rounds(rows, platform, x, weight, w_gate, w_up, w_down, order,
+                 counts):
     """The sum of ``_held_round`` over the rounds of ``rows`` rows that
     hold a live row: the first always, the others in a loop whose trip
     count is read on the device, zero unless routing has collapsed onto
@@ -476,33 +510,36 @@ def _held_rounds(rows, x, weight, w_gate, w_up, w_down, order, counts):
     runs the same loop and recomputes each further round before its
     cotangents, so memory and the program's size are one round's, however
     many run."""
-    return _held_rounds_fwd(rows, x, weight, w_gate, w_up, w_down, order,
-                            counts)[0]
+    return _held_rounds_fwd(rows, platform, x, weight, w_gate, w_up, w_down,
+                            order, counts)[0]
 
 
-def _round_of(first, rows, order, counts):
+def _round_of(first, rows, platform, order, counts):
     """``_held_round`` at ``first`` as a function of what it is
     differentiated in: x, the routing weights, the three expert weights."""
-    return lambda x, weight, *w: _held_round(first, rows, x, order, weight,
-                                             counts, *w)
+    return lambda x, weight, *w: _held_round(first, rows, platform, x, order,
+                                             weight, counts, *w)
 
 
-def _held_rounds_fwd(rows, x, weight, w_gate, w_up, w_down, order, counts):
+def _held_rounds_fwd(rows, platform, x, weight, w_gate, w_up, w_down, order,
+                     counts):
     wrt = (x, weight, w_gate, w_up, w_down)
-    out, vjp = jax.vjp(_round_of(0, rows, order, counts), *wrt)
+    out, vjp = jax.vjp(_round_of(0, rows, platform, order, counts), *wrt)
     rounds = (jnp.sum(counts) + rows - 1) // rows
     out = jax.lax.fori_loop(
         1, rounds,
-        lambda r, acc: acc + _round_of(r * rows, rows, order, counts)(*wrt),
+        lambda r, acc: acc + _round_of(r * rows, rows, platform, order,
+                                       counts)(*wrt),
         out)
     return out, (vjp, wrt, order, counts, rounds)
 
 
-def _held_rounds_bwd(rows, res, g):
+def _held_rounds_bwd(rows, platform, res, g):
     vjp, wrt, order, counts, rounds = res
 
     def more(r, cts):
-        back = jax.vjp(_round_of(r * rows, rows, order, counts), *wrt)[1]
+        back = jax.vjp(_round_of(r * rows, rows, platform, order, counts),
+                       *wrt)[1]
         return jax.tree.map(jnp.add, cts, back(g))
 
     return jax.lax.fori_loop(1, rounds, more, vjp(g)) + (None, None)
@@ -549,6 +586,9 @@ def _moe(ins, params, mode):
         raise MXNetError(f"MoE: router {params['router']!r} is neither "
                          "'weight' nor 'graph'")
     expert, p, counts = _router(logits, bias, params)
+    # rows of one round of the expert matmuls: what the rule is asked with,
+    # here and by the layer's launch counts
+    m = held_round_rows(n * k, held, e)
 
     if held == e and not params["expert_offset"]:
         order = keep(jnp.argsort(expert, stable=True))        # by expert
@@ -556,8 +596,8 @@ def _moe(ins, params, mode):
         # what the three matmuls' backward reads, kept under per-operator
         # recomputation like the held range's first round
         rows = keep(_permute_rows(jnp.repeat(x, k, axis=0), order, inverse))
-        matmul = _expert_matmul(counts, x.dtype, n * k,
-                                (w_gate, w_up, w_down))
+        matmul = _expert_matmul(counts, x.dtype, m, (w_gate, w_up, w_down),
+                                mode.platform)
         gate, up = keep((matmul(rows, w_gate), matmul(rows, w_up)))
         out = keep(matmul(keep(jax.nn.silu(gate) * up), w_down))
         out = _permute_rows(out, inverse, order).reshape(n, k, -1)
@@ -569,12 +609,11 @@ def _moe(ins, params, mode):
     key = jnp.where(jnp.logical_and(local >= 0, local < held), local, held)
     order = keep(jnp.argsort(key, stable=True))  # held first, by expert
     counts = counts[params["expert_offset"]:params["expert_offset"] + held]
-    rows = held_round_rows(n * k, held, e)
-    rounds = -(-n * k // rows)
-    if rounds * rows > n * k:   # whole rounds: more of the dead tail
-        order = jnp.pad(order, (0, rounds * rows - n * k))
-    out = _held_rounds(rows, x, p.reshape(-1), w_gate, w_up, w_down, order,
-                       counts)
+    rounds = -(-n * k // m)
+    if rounds * m > n * k:   # whole rounds: more of the dead tail
+        order = jnp.pad(order, (0, rounds * m - n * k))
+    out = _held_rounds(m, mode.platform, x, p.reshape(-1), w_gate, w_up,
+                       w_down, order, counts)
     return out.astype(x.dtype).reshape(shape)
 
 
@@ -594,6 +633,26 @@ def _moe_fill(shapes, params):
                               1):
             shapes[i] = shapes[i] or s
     return shapes
+
+
+def _moe_counts(ins, outs, params, platform):
+    """A launch's counts for one layer: the rows through its grouped
+    matmuls (tokens x ``top_k``), the experts held here, whether its logits
+    are an input the graph computed (``router="graph"``), and how many of
+    its nine expert matmuls (forward, dgrad and wgrad of gate, up and down)
+    a train program runs in the Pallas kernels, all nine or none: ``_moe``'s
+    own ask of ``_expert_plans``, at the rows of one round."""
+    x, weights = ins[0], tuple(ins[2:5])
+    routed = int(np.prod(x.shape[:-1])) * params["top_k"]
+    held = weights[0].shape[0]
+    m = held_round_rows(routed, held, params["num_experts"])
+    kernels = _expert_plans(platform, x.dtype, m, weights) is not None
+    return {"executor.moe_layers": 1,
+            "executor.moe_assignments": routed,
+            "executor.moe_local_experts": held,
+            "executor.moe_graph_routed_layers":
+                int(params["router"] == "graph"),
+            "executor.moe_kernel_matmuls": 9 * kernels}
 
 
 register(
@@ -623,4 +682,9 @@ register(
         "expert_offset": Param(parse_int, 0),
     },
     fill_in_shapes=_moe_fill,
+    launch_counts=_moe_counts,
+    launch_instruments=("executor.moe_layers", "executor.moe_assignments",
+                        "executor.moe_local_experts",
+                        "executor.moe_graph_routed_layers",
+                        "executor.moe_kernel_matmuls"),
 )

@@ -38,7 +38,7 @@ What the kernels do that the ``jax.numpy`` blocks do not:
 tiles, from what is observable where the op is traced: the platform its
 program is lowered for (the executor's context, ``OpMode.platform``), the one
 TPU the process holds, the operands' dtype and shapes. Traced kernels are
-kept by ``grouped_matmul._kernel``'s store.
+kept by ``pallas_support._kernel``'s store.
 """
 
 from __future__ import annotations
@@ -51,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import grouped_matmul as _gmm
+from . import pallas_support as _ps
 
 _LANES = 128
 # A masked score: finite, so that a row whose first visited block is wholly
@@ -165,7 +165,7 @@ def _for_the_key_blocks(first, end, i, bq, bk, rows_axis, shape, causal,
     band's edge cuts, else a function of the scores' tile that writes
     ``_MASKED`` where a query may not see a key. ``shape`` is the tile's,
     with its rows (G x bq, position = row mod bq) on axis ``rows_axis``."""
-    pl, _ = _gmm._pallas()
+    pl, _ = _ps._pallas()
     if bq & (bq - 1):
         raise ValueError(f"attention: {bq} positions a query block, not a "
                          "power of two")
@@ -209,7 +209,7 @@ def _block_specs(group, bq, T):
     ``folded(D)``, a query block of the head's group folded to rows, and
     ``whole(D)``, the head's whole keys or values, each at the width it is
     asked for; and a row of lanes a query block (log-sum-exp, delta)."""
-    pl, _ = _gmm._pallas()
+    pl, _ = _ps._pallas()
 
     def folded(D):
         return pl.BlockSpec((None, None, group, bq, D),
@@ -229,7 +229,7 @@ def _block_specs(group, bq, T):
 def _fwd(q, k, v, first, end, *, scale, causal, window, bq, bk, vmem_limit,
          interpret):
     """(out (B, H, T, Dv) in q's dtype, log-sum-exp (B, H, T) float32)."""
-    pl, pltpu = _gmm._pallas()
+    pl, pltpu = _ps._pallas()
     B, H, T, D = q.shape
     kv, Dv = k.shape[1], v.shape[-1]
     group, nq = H // kv, T // bq
@@ -298,7 +298,7 @@ def _cost(q, k, v, bq, bk, causal, window, over_keys, over_values):
     run at the key's (q.k, and backward dk and dq), ``over_values`` at the
     value's (p.v, and backward d_out.v and dv); the bytes are the tensors
     of either width, each read or written once a matmul of its width."""
-    pl, _ = _gmm._pallas()
+    pl, _ = _ps._pallas()
     B, H, T, D = q.shape
     Dv = v.shape[-1]
     pairs = B * H * scored_pairs(T, bq, bk, causal, window)
@@ -332,7 +332,7 @@ def _heads_to_rows(x, kv, bq):
 def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
          bk, vmem_limit, interpret):
     """(dq, dk, dv) in the operands' dtypes."""
-    pl, pltpu = _gmm._pallas()
+    pl, pltpu = _ps._pallas()
     B, H, T, D = q.shape
     kv, Dv = k.shape[1], v.shape[-1]
     group, nq = H // kv, T // bq
@@ -420,7 +420,7 @@ def _static(plan, scale, causal, window, interpret):
 def attention(q, k, v, plan, scale, causal, window=0, interpret=False):
     """(out, log-sum-exp): the forward kernel at ``plan``'s tiles."""
     first, end = visits(q.shape[2], plan.bq, plan.bk, causal, window)
-    return _gmm._kernel(_fwd, (q, k, v, jnp.asarray(first), jnp.asarray(end)),
+    return _ps._kernel(_fwd, (q, k, v, jnp.asarray(first), jnp.asarray(end)),
                         **_static(plan, scale, causal, window, interpret))
 
 
@@ -428,7 +428,7 @@ def attention_grads(q, k, v, out, lse, d_out, plan, scale, causal, window=0,
                     interpret=False):
     """(dq, dk, dv): the backward kernel, from the forward's residuals."""
     first, end = visits(q.shape[2], plan.bq, plan.bk, causal, window)
-    return _gmm._kernel(
+    return _ps._kernel(
         _bwd, (q, k, v, out, lse, d_out.astype(q.dtype), jnp.asarray(first),
                jnp.asarray(end)),
         **_static(plan, scale, causal, window, interpret))

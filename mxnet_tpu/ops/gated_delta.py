@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from . import gated_delta_kernels, grouped_matmul
+from . import gated_delta_kernels, pallas_support
 from .defs_tensor import matmul_precision
 
 _HIGHEST = lax.Precision.HIGHEST
@@ -195,11 +195,11 @@ def kernel_plan(dtype, k_shape, v_shape, chunk, platform=None):
     ``OpMode.platform``; None: jax's default backend) in a process that
     holds one TPU, or None: the ``jax.numpy`` form (the CPU, several chips,
     a float32 trunk, a head width 128 does not divide, another chunk). The
-    op and the executor's counters ask it with the same arguments."""
+    op and its launch counts ask it with the same arguments."""
     _, Hk, T, Dk = k_shape
     return gated_delta_kernels.plan(
         platform or jax.default_backend(),
-        grouped_matmul.attached_vmem_bytes(), dtype, Dk, v_shape[3],
+        pallas_support.attached_vmem_bytes(), dtype, Dk, v_shape[3],
         v_shape[1] // Hk, chunk, T)
 
 

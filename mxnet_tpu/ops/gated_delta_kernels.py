@@ -56,7 +56,7 @@ and ``A`` again from it, and never runs the walk forward.
 
 ``plan`` is the one rule that says whether the kernels engage, all four or
 none, as ``flash_attention.plan`` is attention's; traced kernels are kept by
-``grouped_matmul._kernel``'s store.
+``pallas_support._kernel``'s store.
 """
 
 from __future__ import annotations
@@ -68,7 +68,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import grouped_matmul as _gmm
+from . import pallas_support as _ps
 from .registry import keep
 
 _LANES = 128
@@ -250,7 +250,7 @@ def _specs(chunks, group, C, Dk, Dv, block=lambda i: i):
     the last): the key head's rows, a value-wide and a key-wide block of
     the group, a row of vectors and a (C, 2C) inverse a pair of chunks, a
     row of vectors a chunk, and a (Dk, Dv) state a chunk."""
-    pl, _ = _gmm._pallas()
+    pl, _ = _ps._pallas()
     return dict(
         k=pl.BlockSpec((None, None, chunks, C, Dk),
                        lambda b, h, i: (b, h, block(i), 0, 0)),
@@ -271,7 +271,7 @@ def _specs(chunks, group, C, Dk, Dv, block=lambda i: i):
 def _cost(k, v, matmuls, passes):
     """``matmuls`` (C x C) x (C x C) float32 products of six bfloat16
     passes a chunk and value head, ``passes`` over the wide operands."""
-    pl, _ = _gmm._pallas()
+    pl, _ = _ps._pallas()
     heads, C = v.size // (v.shape[-1] * v.shape[-2]), v.shape[-2]
     wide = k.shape[-1] + v.shape[-1]
     return pl.CostEstimate(
@@ -292,7 +292,7 @@ def _pairs(x):
 def _fwd(k, v, c, beta, *, chunks, vmem_limit, interpret):
     """(U, W, X (B, Hk, G, N / 2, C, 2C) float32: the inverses of a pair of
     chunks side by side)."""
-    pl, pltpu = _gmm._pallas()
+    pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = k.shape
     G, Dv = v.shape[2], v.shape[-1]
 
@@ -342,7 +342,7 @@ def _fwd(k, v, c, beta, *, chunks, vmem_limit, interpret):
                                              "interpret"))
 def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
     """(dk, dv, dc, dbeta) in the operands' shapes and dtypes."""
-    pl, pltpu = _gmm._pallas()
+    pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = k.shape
     G, Dv = v.shape[2], v.shape[-1]
 
@@ -469,7 +469,7 @@ def _chunk_planes(C):
 def _scan_cost(q, u, matmuls, passes):
     """``matmuls`` (C x D) x (D x D) products a chunk and value head,
     ``passes`` over the operands and a start state a chunk."""
-    pl, _ = _gmm._pallas()
+    pl, _ = _ps._pallas()
     B, Hk, G, N, C, Dv = u.shape
     Dk = q.shape[-1]
     heads = B * Hk * G * N
@@ -488,7 +488,7 @@ def _scan_fwd(q, k, u, w, c, *, chunks, vmem_limit, interpret):
     chunk STARTED from (B, Hk, G, N, Dk, Dv) float32): ``_chunk_step`` over
     the chunks of a (batch, key head) in order, the group's states in VMEM
     scratch from the first block of chunks to the last."""
-    pl, pltpu = _gmm._pallas()
+    pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = q.shape
     G, Dv = u.shape[2], u.shape[-1]
     dt = q.dtype
@@ -553,7 +553,7 @@ def _scan_bwd(q, k, u, w, c, states, do, *, chunks, vmem_limit, interpret):
               - e^(last - c) (V' dS'^T . K), and at the chunk's last token
               + e^last (dS' . S) + sum(e^(last - c) (V' dS'^T . K))
     """
-    pl, pltpu = _gmm._pallas()
+    pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = q.shape
     G, Dv = u.shape[2], u.shape[-1]
     dt = q.dtype
@@ -658,13 +658,13 @@ def _static(plan, interpret):
 def _within_fwd(k, v, c, beta, plan, interpret):
     # the scan over chunks reads u and w again in its backward, this
     # rule's backward x: kept under per-operator recomputation
-    u, w, x = keep(_gmm._kernel(_fwd, (k, v, c, beta),
+    u, w, x = keep(_ps._kernel(_fwd, (k, v, c, beta),
                                 **_static(plan, interpret)))
     return (u, w), (k, v, c, beta, x)
 
 
 def _within_bwd(plan, interpret, res, g):
-    return _gmm._kernel(_bwd, (*res, *g), **_static(plan, interpret))
+    return _ps._kernel(_bwd, (*res, *g), **_static(plan, interpret))
 
 
 within_chunks.defvjp(_within_fwd, _within_bwd)
@@ -682,13 +682,13 @@ def across_chunks(q, k, u, w, c, plan, interpret=False):
 
 
 def _across_fwd(q, k, u, w, c, plan, interpret):
-    out, states = _gmm._kernel(_scan_fwd, (q, k, u, w, c),
+    out, states = _ps._kernel(_scan_fwd, (q, k, u, w, c),
                                **_static(plan, interpret))
     return out, (q, k, u, w, c, keep(states))
 
 
 def _across_bwd(plan, interpret, res, g):
-    return _gmm._kernel(_scan_bwd, (*res, g), **_static(plan, interpret))
+    return _ps._kernel(_scan_bwd, (*res, g), **_static(plan, interpret))
 
 
 across_chunks.defvjp(_across_fwd, _across_bwd)

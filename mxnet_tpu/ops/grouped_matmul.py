@@ -29,24 +29,24 @@ every group that touches it (here: the run of 128-row chunks that holds the
 group's rows) and differentiates by calling itself on transposed copies.
 
 ``plan`` is the one rule that says whether the kernels engage and with
-which tiles; everything it reads is observable where the op is traced. The
-program lowered for a TPU runs the kernels and one lowered for anything
-else ``ragged_dot`` (``jax.lax.platform_dependent``). Pallas is imported
-when a kernel is first traced, and a traced kernel is kept beside jax's
-compilation cache (``_kernel``), so neither is paid by a later process.
+which tiles; everything it reads is observable where the op is traced, the
+platform its program is lowered for among it (``OpMode.platform``). With a
+plan ``MoE`` calls ``grouped_matmul``, without one ``ragged_dot``
+(``defs_transformer._expert_matmul``): one choice, made in Python where the
+op is traced. Pallas and the store of traced kernels are
+``pallas_support``'s.
 """
 
 from __future__ import annotations
 
 import functools
-import hashlib
-import os
-import sys
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from . import pallas_support as _ps
 
 _LANES = 128
 _CHUNK = 128   # rows: the grain of a matmul inside a row tile
@@ -79,39 +79,6 @@ class Plan(NamedTuple):
     vmem_limit: int
 
 
-# VMEM of one TensorCore by ``device_kind`` (jax 0.9.0's own table,
-# ``jax._src.pallas.mosaic.tpu_info``); a kind not listed gets no kernel.
-_VMEM_BYTES = {
-    "TPU v2": 16 << 20, "TPU v3": 16 << 20, "TPU v4 lite": 16 << 20,
-    "TPU v4": 16 << 20, "TPU v5 lite": 128 << 20, "TPU v5e": 128 << 20,
-    "TPU v5": 64 << 20, "TPU v5p": 64 << 20, "TPU v6 lite": 128 << 20,
-    "TPU v6e": 128 << 20, "TPU7x": 64 << 20,
-}
-
-
-def attached_vmem_bytes() -> Optional[int]:
-    """VMEM of one core of the one TPU this process holds, from its device
-    kind. None where it holds none (the CPU: nothing lowers for a TPU) or
-    several: a program over several chips is partitioned by XLA, which
-    cannot partition a Mosaic call, so until the kernels sit in a
-    ``shard_map`` (ROADMAP Reach B2) such a process keeps ``ragged_dot``."""
-    if jax.default_backend() != "tpu":
-        return None
-    devices = jax.devices()
-    return _VMEM_BYTES.get(devices[0].device_kind) if len(devices) == 1 \
-        else None
-
-
-@functools.cache
-def _pallas():
-    """``(pallas, pallas.tpu)``, imported when a kernel is first traced:
-    1.7 s on the v5e's host (PERF.md section 6, PR 30) that a process with
-    no ``MoE`` layer on a TPU, or with its kernels in the cache, never
-    pays."""
-    from jax.experimental import pallas
-    from jax.experimental.pallas import tpu
-
-    return pallas, tpu
 
 
 def _panel(width, fits):
@@ -246,7 +213,7 @@ def _for_the_groups_rows(offsets, group, m_tile, tm, piece):
     dynamic chunk: one matmul of just those chunks, so a boundary inside a
     tile costs its chunk and not the tile), ``mask`` (rows, 1) which of the
     run's rows are the group's. No call for a group with no rows."""
-    pl, _ = _pallas()
+    pl, _ = _ps._pallas()
     start, end = offsets[group], offsets[group + 1]
     r0 = m_tile * tm
     lo = jnp.maximum(start, r0) - r0
@@ -269,7 +236,7 @@ def _for_the_slabs(width, slab):
     if width <= _SLAB or width % _SLAB:
         slab(slice(None))
         return
-    pl, _ = _pallas()
+    pl, _ = _ps._pallas()
 
     def body(s, carry):
         slab(pl.ds(pl.multiple_of(s * _SLAB, _SLAB), _SLAB))
@@ -286,7 +253,7 @@ def _gmm(rows, w, gr, *, tm, panel, transposed, vmem_limit, interpret):
     stored). Grid (panels of the output width, visits); a panel of one
     expert's matrix stays in VMEM over the visits of its group while the
     next group's is in flight."""
-    pl, pltpu = _pallas()
+    pl, pltpu = _ps._pallas()
     m, depth = rows.shape
     e, k, n = w.shape
     width = k if transposed else n
@@ -395,7 +362,7 @@ def _tgmm(rows, g, gr, *, tm, panel, vmem_limit, interpret):
     """(E, K, N) in ``rows.dtype``: ``rows[group e].T @ g[group e]``,
     zeros for an expert with no rows. Grid (panels of N, visits); a
     float32 (K, panel) accumulator is written out when the group ends."""
-    pl, pltpu = _pallas()
+    pl, pltpu = _ps._pallas()
     m, k = rows.shape
     n = g.shape[1]
     e = gr.counts.shape[0]
@@ -459,102 +426,18 @@ def _tgmm(rows, g, gr, *, tm, panel, vmem_limit, interpret):
     )(gr.offsets, gr.wgrad_group_ids, gr.wgrad_m_tile_ids, rows, g)
 
 
-# --- kernels kept across processes -------------------------------------------
-_EXPORTED = {}  # key -> jax.export.Exported, this process's
-
-
-def _kernel_cache_dir():
-    """Where traced kernels are kept: a directory of jax's persistent
-    compilation cache, so they live and move with the executables
-    (``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``); None
-    where that cache is off."""
-    if not jax.config.jax_enable_compilation_cache:
-        return None
-    root = jax.config.jax_compilation_cache_dir
-    return os.path.join(root, "mxnet_tpu-kernels") if root else None
-
-
-@functools.cache
-def _source_digest(path):
-    with open(path, "rb") as f:
-        return hashlib.sha256(f.read()).hexdigest()
-
-
-def _kernel(impl, operands, **static):
-    """``impl(*operands, **static)``, a jitted Pallas kernel (this module's
-    or ``flash_attention``'s), traced once a cache directory and not once a
-    process. Tracing the six kernels of a layer, lowering them to Mosaic
-    and importing Pallas to do so cost 2.8 s of every process's set-up on
-    the v5e's host (PERF.md section 6, PR 30); ``jax.export`` keeps the
-    lowered module (17 KB a kernel), and a later process reads it back and
-    calls it: no trace, no Pallas. The key holds the text of the kernel's
-    module, jax's version, the device kind, the kernel, its static
-    arguments and the operands' shapes and types. A directory that cannot
-    be written only loses the saving."""
-    directory = _kernel_cache_dir()
-    if directory is None or static["interpret"]:
-        return impl(*operands, **static)
-    from jax import export
-
-    leaves, tree = jax.tree.flatten(operands)
-    key = hashlib.sha256(repr((
-        _source_digest(sys.modules[impl.__module__].__file__),
-        jax.__version__, jax.devices()[0].device_kind, impl.__name__,
-        sorted(static.items()), str(tree),
-        [(a.shape, str(a.dtype)) for a in leaves])).encode()).hexdigest()
-    exported = _EXPORTED.get(key)
-    path = os.path.join(directory, key)
-    if exported is None:
-        try:
-            with open(path, "rb") as f:
-                exported = export.deserialize(bytearray(f.read()))
-        except (OSError, ValueError):
-            exported = export.export(
-                jax.jit(lambda *leaves: impl(*jax.tree.unflatten(tree, leaves),
-                                             **static)),
-                platforms=("tpu",))(
-                    *[jax.ShapeDtypeStruct(a.shape, a.dtype)
-                      for a in leaves])
-            try:
-                os.makedirs(directory, exist_ok=True)
-                with open(f"{path}.{os.getpid()}", "wb") as f:
-                    f.write(exported.serialize())
-                os.replace(f"{path}.{os.getpid()}", path)
-            except OSError:
-                pass
-        _EXPORTED[key] = exported
-    return exported.call(*leaves)
 
 
 # --- the differentiable op -------------------------------------------------
-def _ragged(rows, w, gr):
-    """The other lowering: XLA's ragged_dot on the weights cast to the
-    rows' dtype (bfloat16 rows only, so the default precision)."""
-    return lax.ragged_dot(rows, w.astype(rows.dtype), gr.counts)
-
-
-def _lowered_for_a_tpu(kernels, other, interpret, *args):
-    """``kernels(*args)`` in a program lowered for a TPU, ``other(*args)``
-    in one lowered for anything else (the same trace serves both: the
-    benchmark's reference check runs on the chip machine's CPU);
-    ``interpret`` takes the kernels wherever it is lowered."""
-    if interpret:
-        return kernels(*args)
-    return lax.platform_dependent(*args, tpu=kernels, default=other)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def grouped_matmul(rows, w, gr, plan, interpret=False):
     """``rows`` (M, K) sorted by group times ``w`` (E, K, N) as stored;
     ``gr = groups(counts, M, plan)`` with ``counts.sum() == M``; tiles from
-    ``plan`` (a ``Plan``). ``interpret`` runs the kernels in Pallas's
-    interpreter (tests on the CPU)."""
-    def kernel(rows, w, gr):
-        return _kernel(_gmm, (rows, w, gr), tm=plan.tm, panel=plan.tn,
+    ``plan`` (a ``Plan``): the kernels, in a program lowered for a TPU.
+    ``interpret`` runs them in Pallas's interpreter (tests on the CPU)."""
+    return _ps._kernel(_gmm, (rows, w, gr), tm=plan.tm, panel=plan.tn,
                        transposed=False, vmem_limit=plan.vmem_limit,
                        interpret=interpret)
-
-    return _lowered_for_a_tpu(kernel, _ragged, interpret, rows, w, gr)
 
 
 def _fwd(rows, w, gr, plan, interpret):
@@ -564,19 +447,11 @@ def _fwd(rows, w, gr, plan, interpret):
 def _bwd(plan, interpret, res, g):
     rows, w, gr = res
     kw = dict(vmem_limit=plan.vmem_limit, interpret=interpret)
-
-    def kernels(rows, w, gr, g):
-        return (_kernel(_gmm, (g, w, gr), tm=plan.tm, panel=plan.tk,
+    g = g.astype(rows.dtype)
+    return (_ps._kernel(_gmm, (g, w, gr), tm=plan.tm, panel=plan.tk,
                         transposed=True, **kw),
-                _kernel(_tgmm, (rows, g, gr), tm=plan.tmw, panel=plan.tw,
-                        **kw).astype(w.dtype))
-
-    def other(rows, w, gr, g):
-        return jax.vjp(lambda rows, w: _ragged(rows, w, gr), rows, w)[1](g)
-
-    drows, dw = _lowered_for_a_tpu(kernels, other, interpret, rows, w, gr,
-                                   g.astype(rows.dtype))
-    return drows, dw, None
+            _ps._kernel(_tgmm, (rows, g, gr), tm=plan.tmw, panel=plan.tw,
+                        **kw).astype(w.dtype), None)
 
 
 grouped_matmul.defvjp(_fwd, _bwd)

@@ -59,11 +59,23 @@ class OpMode:
     # arrives channels-last and the op must lower channels-last (set only
     # for layout-aware ops — see ops/layout.py); None = logical NCHW
     layout: str = None
-    # the platform the program is lowered for ("tpu", "cpu"), where the
-    # caller knows it (an executor: its context's); None = ask the operands
-    # or jax's default backend. Read by ops whose lowering is per platform
-    # (RingAttention's Pallas kernels)
+    # the platform the program is lowered for ("tpu", "cpu"): an executor
+    # gives its context's, an imperative call that of its concrete operands
+    # (``platform_of``); None (a bare traced call) = jax's default backend.
+    # Read by the ops whose lowering is per platform: the four that have
+    # Pallas kernels (MoE, RingAttention, GatedDeltaRule, CausalConv1D)
     platform: str = None
+
+
+def platform_of(arrays):
+    """The platform the first concrete jax array of ``arrays`` lives on: an
+    imperative call's ``OpMode.platform``. None where none is concrete."""
+    from jax.core import Tracer
+
+    for a in arrays:
+        if hasattr(a, "devices") and not isinstance(a, Tracer):
+            return next(iter(a.devices())).platform
+    return None
 
 
 # The one name ``keep`` puts on a value and the executor's per-operator
@@ -155,6 +167,8 @@ class OpDef:
         mutate: Sequence = (),
         is_loss: bool = False,
         doc: str = "",
+        launch_counts: Optional[Callable] = None,
+        launch_instruments: Sequence[str] = (),
     ):
         self.name = name
         self.fn = fn
@@ -176,6 +190,15 @@ class OpDef:
         # head-grad decision in executor.backward() instead of a name list
         self.is_loss = bool(is_loss)
         self.doc = doc
+        # what one launch of a train program counts for a node of this op:
+        # ``launch_counts(ins, outs, params, platform) -> {instrument: int}``
+        # over anything with ``.shape`` and ``.dtype`` (the operands and
+        # results as the node was lowered, or ShapeDtypeStructs), asked by
+        # the executor where it lowers the node and summed over the graph;
+        # ``launch_instruments`` lists, with no graph, every name it may
+        # return (docs/observability.md catalogues them). None: nothing
+        self._launch_counts = launch_counts
+        self.launch_instruments = tuple(launch_instruments)
 
     # --- introspection ---------------------------------------------------
     def arg_names(self, params) -> list:
@@ -199,6 +222,18 @@ class OpDef:
         if callable(self._num_visible_outputs):
             return int(self._num_visible_outputs(params))
         return int(self._num_visible_outputs)
+
+    def launch_counts(self, ins, outs, params, platform) -> dict:
+        """What the op declared a launch counts for this node ({}: nothing),
+        held to the instruments it listed."""
+        if self._launch_counts is None:
+            return {}
+        counts = self._launch_counts(ins, outs, params, platform)
+        unlisted = set(counts) - set(self.launch_instruments)
+        if unlisted:
+            raise MXNetError(f"op {self.name}: counts {sorted(unlisted)} "
+                             "are not among its launch_instruments")
+        return counts
 
     # --- params ----------------------------------------------------------
     def parse_params(self, raw: dict, strict: bool = True) -> dict:

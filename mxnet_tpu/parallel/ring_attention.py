@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
-from ..ops.registry import keep
+from ..ops.registry import keep, platform_of
 from .compat import shard_map as _shard_map
 
 
@@ -228,43 +228,19 @@ def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
     executor's, through ``OpMode.platform``; None: jax's default backend)
     in a process that holds one TPU, or None: the ``jax.numpy`` blocks (the
     CPU, several chips, float32, head widths the kernels do not take, T no
-    multiple of a block). The op and the executor's counters ask it with
-    the same arguments. A bare traced call (no executor, ``platform`` None)
+    multiple of a block). The op and its launch counts
+    (``defs_contrib._ring_attention_counts``) ask it with the same
+    arguments. A bare traced call (no executor, ``platform`` None)
     assumes the default backend: a plain ``jax.jit`` for the CPU in a
     process that holds a TPU has to say ``platform="cpu"``, or it traces
     Mosaic calls."""
-    from ..ops import flash_attention, grouped_matmul
+    from ..ops import flash_attention, pallas_support
 
     _, heads, T, D = q_shape
     return flash_attention.plan(
         platform or jax.default_backend(),
-        grouped_matmul.attached_vmem_bytes(), dtype, heads, kv_heads, T, D,
+        pallas_support.attached_vmem_bytes(), dtype, heads, kv_heads, T, D,
         causal, window, value_dim)
-
-
-def pair_lanes(key_dim, value_dim):
-    """Lanes one query-key pair is computed over: the width its score
-    contracts over plus the width ``p.v`` writes, as the paths of
-    :func:`blockwise_attention` are handed them. Neither the ``jax.numpy``
-    blocks nor the kernels pad a width (keys of 192 over values of 128 are
-    320; a model that padded its keys to 256 would hand over 384)."""
-    return key_dim + value_dim
-
-
-def pairs_scored(q_shape, causal, window=0, kernels=None):
-    """Query-key pairs a layer scores, forward (backward recomputes the
-    same tiles): the tiles the kernels visit under ``kernels``, else the
-    ``jax.numpy`` blocks' (:func:`scored_pairs`), x heads x batch."""
-    batch, heads, T, _ = q_shape
-    if kernels is not None:
-        from ..ops import flash_attention
-
-        one_head = flash_attention.scored_pairs(T, kernels.bq, kernels.bk,
-                                                causal, window)
-    else:
-        one_head = scored_pairs(T, causal, window,
-                                block_q_of(batch, heads, T, window))
-    return batch * heads * one_head
 
 
 def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0, kernels=None,
@@ -430,8 +406,7 @@ def _on_one_device(q, k, v, causal, scale, window, platform=None):
     """:func:`blockwise_attention` with what the rule and ``block_q_of``
     say for these operands; ``platform`` None: where a concrete q lives,
     jax's default backend for a tracer."""
-    if platform is None and not isinstance(q, jax.core.Tracer):
-        platform = next(iter(q.devices())).platform
+    platform = platform or platform_of([q])
     return blockwise_attention(
         q, k, v, causal, scale,
         block_q_of(q.shape[0], q.shape[1], q.shape[2], window), window,

@@ -15,7 +15,7 @@ import test_zaya as tz
 
 import mxnet_tpu as mx
 from mxnet_tpu.ops import causal_conv_kernels as ck
-from mxnet_tpu.ops import grouped_matmul as gm
+from mxnet_tpu.ops import pallas_support as ps
 from mxnet_tpu.ops.defs_transformer import _causal_conv1d
 from mxnet_tpu.ops.registry import OpMode
 
@@ -183,7 +183,7 @@ RULE_CASES = {
 
 @pytest.mark.parametrize("case", sorted(RULE_CASES))
 def test_rule_says_where_the_kernels_engage(monkeypatch, case):
-    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: V5E_VMEM)
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
     args, engages = RULE_CASES[case]
     plan = ck.kernel_plan(*args)
     assert (plan is not None) == engages
@@ -246,7 +246,7 @@ def _steer(monkeypatch):
     not fit twice), the kernels at the test's small blocks in the
     interpreter."""
     rule, conv = ck.kernel_plan, ck.causal_conv
-    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: 64 << 10)
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: 64 << 10)
     monkeypatch.setattr(
         ck, "kernel_plan", lambda dtype, shape, taps, platform=None, group=0:
         rule(dtype, shape, taps, "tpu", group) and PLAN)

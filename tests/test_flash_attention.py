@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from mxnet_tpu.ops import flash_attention as fa
-from mxnet_tpu.ops import grouped_matmul as gm
+from mxnet_tpu.ops import pallas_support as ps
 
 ra = importlib.import_module("mxnet_tpu.parallel.ring_attention")
 
@@ -299,9 +299,9 @@ def test_rule_query_blocks_are_powers_of_two_for_any_group(group):
 
 
 def test_on_the_cpu_the_op_takes_the_blocks():
-    """What the op asks where it is traced: no TPU here, so no plan, and
-    no ``platform_dependent`` around the blocks."""
-    assert gm.attached_vmem_bytes() is None
+    """What the op asks where it is traced: no TPU here, so no plan: the
+    blocks, chosen in Python."""
+    assert ps.attached_vmem_bytes() is None
     assert ra.kernel_plan("bfloat16", (1, 32, 4096, 128), 4, True,
                           2048) is None
 
@@ -321,32 +321,27 @@ def test_rule_with_chips_attached(monkeypatch, chips, engages):
     assert (plan is not None) == engages
 
 
-# --- the executor's counters -------------------------------------------------
+# --- what the op declares a launch counts -------------------------------------
 
-def _bound(model, dtype):
-    """A tiny decoder of the benchmark's two architectures at a head of
-    128 and T 512, bound on the CPU: (executor, attention layers)."""
-    import mxnet_tpu as mx
-    from mxnet_tpu import models
+def _declared(q, k, v, dtype, platform, **attrs):
+    """``RingAttention``'s launch counts for one node over operands of
+    these shapes, in a program lowered for ``platform``."""
+    import jax
 
-    if model == "trinity":
-        gen = models.afmoe_sym_gen(
-            vocab_size=64, hidden_size=256, num_heads=8, num_kv_heads=1,
-            head_dim=128, num_dense_layers=1,
-            layer_types=("sliding_attention", "full_attention")
-            + ("sliding_attention",) * 3,
-            sliding_window=256, dense_width=64, expert_width=32,
-            num_experts=8, num_local_experts=2, top_k=2, dtype=dtype)
-        layers = 5
-    else:
-        gen = models.olmoe_sym_gen(
-            vocab_size=64, hidden_size=256, num_heads=2, num_layers=1,
-            num_experts=4, expert_width=32, top_k=2, dtype=dtype)
-        layers = 1
-    sym, data_names, label_names = gen(512)
-    exe = sym.simple_bind(mx.cpu(), grad_req="write", data=(1, 512),
-                          softmax_label=(1, 512))
-    return exe, layers
+    from mxnet_tpu.ops import registry
+
+    op = registry.get("RingAttention")
+    ins = [jax.ShapeDtypeStruct(s, dtype) for s in (q, k, v)]
+    return op.launch_counts(
+        ins, [jax.ShapeDtypeStruct(q[:3] + v[3:], dtype)],
+        op.parse_params(dict(causal=True, **attrs)), platform)
+
+
+# the attention layers of a tiny decoder of the benchmark's two
+# architectures at a head of 128 and T 512: (query heads, key/value heads,
+# the layers' windows)
+DECODERS = {"trinity": (8, 1, (256, 0, 256, 256, 256)),
+            "olmoe": (2, 2, (0,))}
 
 
 @pytest.mark.parametrize("model,dtype,platform,kernel_layers", [
@@ -360,19 +355,20 @@ def test_counter_rule_with_a_v5e_described(monkeypatch, model, dtype,
                                            platform, kernel_layers):
     """``executor.attention_kernel_layers``: the layers of a train program
     that run the kernels, from the rule the op follows asked with the
-    executor's platform; ``attention_scored_pairs`` follows the plan that
-    runs."""
-    from types import SimpleNamespace
-
-    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: V5E_VMEM)
-    exe, layers = _bound(model, dtype)
-    monkeypatch.setattr(
-        exe._ctx, "jax_device", lambda: SimpleNamespace(platform=platform),
-        raising=False)
-    held = exe._transformer_layers()
-    assert held["attention_layers"] == layers
-    assert held["attention_kernel_layers"] == kernel_layers
-    heads, kv = (8, 1) if model == "trinity" else (2, 2)
+    platform the op is given; ``attention_scored_pairs`` follows the plan
+    that runs."""
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
+    heads, kv, windows = DECODERS[model]
+    held = {}
+    for window in windows:
+        counts = _declared((1, heads, 512, 128), (1, kv, 512, 128),
+                           (1, kv, 512, 128), dtype, platform, window=window)
+        for name, n in counts.items():
+            held[name] = held.get(name, 0) + n
+    assert held["executor.attention_layers"] == len(windows)
+    assert held["executor.attention_kernel_layers"] == kernel_layers
+    assert held["executor.attention_window_layers"] == sum(
+        w > 0 for w in windows)
     if kernel_layers:
         def pairs(window):
             tiles = fa.plan("tpu", V5E_VMEM, dtype, heads, kv, 512, 128, True,
@@ -386,18 +382,18 @@ def test_counter_rule_with_a_v5e_described(monkeypatch, model, dtype,
         want = {"trinity": 4 * ra.scored_pairs(512, True, 256, block)
                 + ra.scored_pairs(512, True, 0, block),
                 "olmoe": ra.scored_pairs(512, True, 0, block)}[model]
-    assert held["attention_scored_pairs"] == heads * want
+    assert held["executor.attention_scored_pairs"] == heads * want
 
 
 @pytest.mark.parametrize("fused_kv", [False, True])
 def test_counter_rule_reads_the_key_whatever_feeds_it(monkeypatch, fused_kv):
-    """The key/value heads come from the key's inferred shape: a variable,
-    or entry 0 of a split (a fused kv projection), named as
-    ``Symbol.list_outputs`` names it."""
+    """The key/value heads are the key operand's as the node is lowered: a
+    variable, or entry 0 of a split (a fused kv projection). Asked over
+    the shapes the graph infers for the node's entries."""
+    import jax
     import mxnet_tpu as mx
-    from types import SimpleNamespace
 
-    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: V5E_VMEM)
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
     q = mx.sym.Variable("q")
     if fused_kv:
         kv = mx.sym.SliceChannel(mx.sym.Variable("kv"), num_outputs=2, axis=1,
@@ -408,14 +404,15 @@ def test_counter_rule_reads_the_key_whatever_feeds_it(monkeypatch, fused_kv):
         shapes = {"k": (1, 2, T, D), "v": (1, 2, T, D)}
     net = mx.sym.RingAttention(q, k, v, causal=True, name="attn")
     shapes["q"] = (1, 8, T, D)
-    exe = net.simple_bind(mx.cpu(), grad_req="write",
-                          type_dict=dict.fromkeys(shapes, "bfloat16"),
-                          **shapes)
-    monkeypatch.setattr(
-        exe._ctx, "jax_device", lambda: SimpleNamespace(platform="tpu"),
-        raising=False)
-    held = exe._transformer_layers()
-    assert held["attention_layers"] == held["attention_kernel_layers"] == 1
+    node, = [n for n in net._topo() if not n.is_variable
+             and n.op.name == "RingAttention"]
+    internals = net.get_internals()
+    _, inferred, _ = internals.infer_shape(**shapes)
+    entry = dict(zip(internals._outputs, inferred))
+    ins = [jax.ShapeDtypeStruct(entry[e], "bfloat16") for e in node.inputs]
+    held = node.op.launch_counts(ins, ins[:1], node.params(), "tpu")
+    assert held["executor.attention_layers"] \
+        == held["executor.attention_kernel_layers"] == 1
     tiles = fa.plan("tpu", V5E_VMEM, "bfloat16", 8, 2, T, D, True, 0)
-    assert held["attention_scored_pairs"] == 8 * fa.scored_pairs(
+    assert held["executor.attention_scored_pairs"] == 8 * fa.scored_pairs(
         T, tiles.bq, tiles.bk, True, 0)

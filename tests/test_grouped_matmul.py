@@ -16,6 +16,7 @@ import pytest
 
 from mxnet_tpu.ops import defs_transformer as dt
 from mxnet_tpu.ops import grouped_matmul as gm
+from mxnet_tpu.ops import pallas_support as ps
 from mxnet_tpu.ops import registry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -146,8 +147,8 @@ def test_kernels_come_back_from_the_cache_directory(tmp_path, monkeypatch):
     import jax
     import jax.numpy as jnp
 
-    monkeypatch.setattr(gm, "_kernel_cache_dir", lambda: str(tmp_path))
-    monkeypatch.setattr(gm, "_EXPORTED", {})
+    monkeypatch.setattr(ps, "_kernel_cache_dir", lambda: str(tmp_path))
+    monkeypatch.setattr(ps, "_EXPORTED", {})
     plan = gm.plan("tpu", V5E_VMEM, "bfloat16", "float32", 512, 256, 128)
     counts = jnp.asarray([100, 156, 0, 256], jnp.int32)
 
@@ -169,7 +170,7 @@ def test_kernels_come_back_from_the_cache_directory(tmp_path, monkeypatch):
     def _tgmm(*a, **k):
         raise AssertionError("traced again")
 
-    monkeypatch.setattr(gm, "_EXPORTED", {})
+    monkeypatch.setattr(ps, "_EXPORTED", {})
     monkeypatch.setattr(gm, "_gmm", _gmm)
     monkeypatch.setattr(gm, "_tgmm", _tgmm)
     again = str(jax.make_jaxpr(step)(*args))
@@ -179,7 +180,7 @@ def test_kernels_come_back_from_the_cache_directory(tmp_path, monkeypatch):
 def test_no_cache_directory_where_jaxs_cache_is_off():
     """The suite runs with jax's persistent cache off (conftest): kernels
     are traced in place and nothing is written."""
-    assert gm._kernel_cache_dir() is None
+    assert ps._kernel_cache_dir() is None
 
 
 # (platform, VMEM bytes, rows dtype, weight dtype, M, K, N) -> engages?
@@ -238,15 +239,38 @@ def test_rule_narrows_the_weight_panel_to_a_small_vmem():
     assert small is not None and small.tn < 1024 and small.tw < 1024
 
 
+def _moe_kernel_matmuls(platform, data_dtype, weight_dtype, rows, hidden,
+                         width, experts=64, top_k=8):
+    """``executor.moe_kernel_matmuls`` as ``MoE`` declares it for one layer
+    that holds every expert: ``rows`` assignments of ``hidden`` features
+    through experts of ``width``."""
+    import jax
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    ins = [struct((rows // top_k, hidden), data_dtype),
+           struct((experts, hidden), weight_dtype),
+           struct((experts, hidden, width), weight_dtype),
+           struct((experts, hidden, width), weight_dtype),
+           struct((experts, width, hidden), weight_dtype)]
+    op = registry.get("MoE")
+    params = op.parse_params(dict(num_experts=experts, num_hidden=width,
+                                  top_k=top_k))
+    counts = op.launch_counts(ins, ins[:1], params, platform)
+    assert counts["executor.moe_assignments"] == rows
+    return counts["executor.moe_kernel_matmuls"]
+
+
 def test_counter_rule_counts_nine_or_none():
-    """What ``Executor._count_train_launch`` asks: on the CPU no kernel
-    (``attached_vmem_bytes`` is None here), and the op takes ragged_dot
-    without a ``platform_dependent`` around it."""
-    assert gm.attached_vmem_bytes() is None
-    assert dt.moe_kernel_matmuls("cpu", "bfloat16", "float32",
-                                 32768, 2048, 1024) == 0
-    assert dt.moe_kernel_matmuls("tpu", "bfloat16", "float32",
-                                 32768, 2048, 1024) == 0
+    """What ``MoE`` declares a launch counts: on the CPU no kernel
+    (``attached_vmem_bytes`` is None here), and the op takes ragged_dot,
+    chosen in Python."""
+    assert ps.attached_vmem_bytes() is None
+    assert _moe_kernel_matmuls("cpu", "bfloat16", "float32",
+                               32768, 2048, 1024) == 0
+    assert _moe_kernel_matmuls("tpu", "bfloat16", "float32",
+                               32768, 2048, 1024) == 0
 
 
 @pytest.mark.parametrize("chips,kind,vmem", [
@@ -262,19 +286,19 @@ def test_attached_vmem_is_of_the_one_listed_chip(monkeypatch, chips, kind,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
         jax, "devices", lambda *a: [SimpleNamespace(device_kind=kind)] * chips)
-    assert gm.attached_vmem_bytes() == vmem
+    assert ps.attached_vmem_bytes() == vmem
 
 
 def test_counter_rule_with_a_v5e_attached(monkeypatch):
-    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: V5E_VMEM)
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
     nine = ("bfloat16", "float32", 32768, 2048, 1024)
-    assert dt.moe_kernel_matmuls("tpu", *nine) == 9
-    assert dt.moe_kernel_matmuls("cpu", *nine) == 0
-    assert dt.moe_kernel_matmuls("tpu", "float32", "float32",
-                                 32768, 2048, 1024) == 0
+    assert _moe_kernel_matmuls("tpu", *nine) == 9
+    assert _moe_kernel_matmuls("cpu", *nine) == 0
+    assert _moe_kernel_matmuls("tpu", "float32", "float32",
+                               32768, 2048, 1024) == 0
     # a hidden size no tile divides: all nine take ragged_dot
-    assert dt.moe_kernel_matmuls("tpu", "bfloat16", "float32",
-                                 32768, 2000, 1024) == 0
+    assert _moe_kernel_matmuls("tpu", "bfloat16", "float32",
+                               32768, 2000, 1024) == 0
 
 
 # --- MoE through the kernel path against the plain reference ----------------
@@ -308,7 +332,7 @@ def _moe_three_ways():
         num_experts=4, num_hidden=128, top_k=2, lb_coef=0.01, z_coef=0.001))
     def run(matmul):
         def scalar(*ins):
-            out = dt._moe(list(ins), params, None)
+            out = dt._moe(list(ins), params, registry.OpMode())
             return jnp.sum(out.astype(jnp.float32) * head), out
 
         old, dt._expert_matmul = dt._expert_matmul, matmul
@@ -323,14 +347,23 @@ def _moe_three_ways():
         out, pen = ref.moe(*ins, 2, 0.01, 0.001)
         return jnp.sum(out * head) + ins[0].shape[0] * pen, out
 
-    kernel = run(functools.partial(dt._expert_matmul, vmem_bytes=V5E_VMEM,
-                                   interpret=True))
+    expert_matmul = dt._expert_matmul
+
+    def on_a_v5e(counts, rows_dtype, m, weights, platform=None):
+        return expert_matmul(counts, rows_dtype, m, weights, "tpu", V5E_VMEM,
+                             interpret=True)
+
+    kernel = run(on_a_v5e)
     assert "pallas_call" in str(jax.make_jaxpr(
-        lambda *ins: dt._expert_matmul(
-            jnp.asarray([32] * 4, jnp.int32), tok.dtype, 128, ws[1:],
-            vmem_bytes=V5E_VMEM, interpret=True)(*ins))(
+        lambda *ins: on_a_v5e(
+            jnp.asarray([32] * 4, jnp.int32), tok.dtype, 128, ws[1:])(*ins))(
                 jnp.repeat(tok, 2, 0), ws[1]))
-    ragged = run(dt._expert_matmul)     # no TPU here: ragged_dot alone
+    # lowered for the CPU: ragged_dot alone, whatever is attached
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda *ins: expert_matmul(
+            jnp.asarray([32] * 4, jnp.int32), tok.dtype, 128, ws[1:], "cpu",
+            V5E_VMEM)(*ins))(jnp.repeat(tok, 2, 0), ws[1]))
+    ragged = run(expert_matmul)     # no TPU here: ragged_dot alone
     with jax.default_matmul_precision("highest"):
         grads, out = jax.grad(reference, argnums=tuple(range(5)),
                               has_aux=True)(tok.astype(jnp.float32), *ws)
@@ -608,7 +641,7 @@ def test_causal_conv_kernels_compile_for_a_v5e_at_the_cell_widths(
     b, t, c, taps, act, bias = {
         "qwen3_next": (1, 8192, 8192, 4, "silu", False),
         "zaya1": (4, 8192, 1280, 2, "none", True)}[cell]
-    monkeypatch.setattr(gm, "attached_vmem_bytes", lambda: V5E_VMEM)
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
     plan = ck.kernel_plan(jnp.bfloat16, (b, t, c), taps, "tpu")
     assert plan is not None and t % plan.time == 0 and c % plan.channels == 0
 

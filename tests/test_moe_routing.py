@@ -102,7 +102,8 @@ def _rounds_before_bwd(rows, res, g):
 _rounds_before.defvjp(_rounds_before_fwd, _rounds_before_bwd)
 
 
-def _held_rounds_before(rows, x, flat, w_gate, w_up, w_down, order, counts):
+def _held_rounds_before(rows, platform, x, flat, w_gate, w_up, w_down, order,
+                        counts):
     """Today's call of ``_held_rounds`` answered as ``_moe`` answered it
     before: every assignment's weight gathered into the sorted order, the
     padding zeros of ``tok`` and ``weight``."""

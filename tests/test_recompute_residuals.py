@@ -74,8 +74,11 @@ def _moe(steer, held=0, kernels=False):
                      num_hidden=128, top_k=2, num_local_experts=held,
                      lb_coef=0.01, z_coef=0.001)
     if kernels:
-        steer.setattr(dt, "_expert_matmul", functools.partial(
-            dt._expert_matmul, vmem_bytes=V5E_VMEM, interpret=True))
+        matmul = dt._expert_matmul
+        steer.setattr(
+            dt, "_expert_matmul",
+            lambda counts, dtype, m, weights, platform=None: matmul(
+                counts, dtype, m, weights, "tpu", V5E_VMEM, interpret=True))
     return sym, dict(data=(1, 256, 128)), {"data": "bfloat16"}
 
 
