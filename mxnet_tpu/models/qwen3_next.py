@@ -12,10 +12,26 @@ sigmoid gate. Defaults are Qwen3-Next-80B-A3B's published sizes."""
 
 import math
 
+import numpy as np
+
 from .. import initializer
 from .. import symbol as sym
 from .olmoe import (embed_tokens, linear, merge_heads, next_token_head,
                     split_heads)
+
+
+def log_spaced_parameter(name, shape, axis, low, high):
+    """A float32 parameter of ``shape``, by default the log of values spaced
+    evenly in the log from ``low`` to ``high`` along ``axis`` and alike
+    along the others: decays ``exp(-A dt)`` that remember from one token to
+    a thousand. (``kimi_linear.py`` takes it too.)"""
+    span = max(shape[axis] - 1, 1)
+    logs = np.array([math.log(low) + math.log(high / low) * h / span
+                     for h in range(shape[axis])])
+    logs = logs.reshape([-1 if a == axis else 1 for a in range(len(shape))])
+    return sym.Variable(name, shape=shape, dtype="float32",
+                        init=initializer.Constant(
+                            np.broadcast_to(logs, shape).tolist()))
 
 
 def qwen3_next_sym_gen(vocab_size=151936, hidden_size=2048, num_layers=48,
@@ -64,15 +80,9 @@ def qwen3_next_sym_gen(vocab_size=151936, hidden_size=2048, num_layers=48,
         return sym.transpose(sym.Cast(x, dtype="float32"), axes=(0, 2, 1))
 
     def per_head_parameter(name, low, high):
-        """(Hv, 1) float32, by default the log of values spaced evenly in
-        the log from ``low`` to ``high`` over the heads: decays ``exp(-A
-        dt)`` that remember from one token to a thousand."""
-        span = max(linear_value_heads - 1, 1)
-        logs = [[math.log(low) + math.log(high / low) * h / span]
-                for h in range(linear_value_heads)]
-        return sym.Variable(name, shape=(linear_value_heads, 1),
-                            dtype="float32",
-                            init=initializer.Constant(logs))
+        """(Hv, 1) float32, one a value head."""
+        return log_spaced_parameter(name, (linear_value_heads, 1), 0, low,
+                                    high)
 
     def delta_net(u, pre):
         qkvz = linear(u, 2 * key_width + 2 * value_width,

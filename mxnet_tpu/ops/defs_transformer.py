@@ -16,8 +16,8 @@ of a train program that holds it counts (``OpDef.launch_counts``).
 What is float32 whatever the trunk's dtype: the statistics of ``RMSNorm``,
 the angles and the rotation of ``RotaryEmbedding``, in ``MoE`` the
 router (logits, softmax, top-k, both regularisers), and in
-``GatedDeltaRule`` the decays, ``beta``, the chunks' triangular inverse and
-the state. Outputs come back in the dtype of ``data``.
+``GatedDeltaRule`` the decays (a head or a key channel), ``beta``, the
+chunks' triangular inverse and the state. Outputs come back in the dtype of ``data``.
 """
 
 from __future__ import annotations
@@ -226,19 +226,20 @@ register(
 def _gated_delta_rule(ins, params, mode):
     """Linear attention by the gated delta rule (``gated_delta.py`` has the
     equations and what is float32): ``query``, ``key`` (B, Hk, T, Dk),
-    ``value`` (B, Hv, T, Dv), ``g`` (the log of the decay, <= 0) and
-    ``beta`` (B, Hv, T) -> (B, Hv, T, Dv); value head n reads key head ``n
+    ``value`` (B, Hv, T, Dv), ``beta`` (B, Hv, T) and ``g`` (the log of
+    the decay, <= 0), (B, Hv, T) for a gate a head or (B, Hv, T, Dk) for a
+    gate a key channel -> (B, Hv, T, Dv); value head n reads key head ``n
     // (Hv / Hk)``. Each head's query and key are first divided by their
     length (eps 1e-6) and the query by ``sqrt(Dk)``. Computed ``chunk``
     tokens at a time; the state starts at 0 in every row and is never reset
     inside one. Where the rule says so (``gated_delta.kernel_plan``, asked
     with the platform the program is lowered for) the chunk-local algebra
-    runs in Pallas kernels."""
+    runs in Pallas kernels; for a gate a channel it never does."""
     q, k, v, g, beta = ins
     q, k = _gdr.l2_normalize(q), _gdr.l2_normalize(k)
     q = (q.astype(jnp.float32) * q.shape[-1] ** -0.5).astype(q.dtype)
     kernels = _gdr.kernel_plan(q.dtype, k.shape, v.shape, params["chunk"],
-                               mode.platform)
+                               mode.platform, g.ndim == 4)
     return _gdr.chunk_gated_delta_rule(
         q, k, v, g, beta, chunk=params["chunk"],
         kernels=kernels).astype(v.dtype)
@@ -249,15 +250,19 @@ def _gated_delta_rule_counts(ins, outs, params, platform):
     (batch x T / chunk: the scan's trips; T would mean a token at a time)
     and whether a train program runs its chunk-local algebra and its scan
     over chunks in the Pallas kernels: ``_gated_delta_rule``'s own ask of
-    ``gated_delta.kernel_plan`` (one rule: all four kernels or none)."""
-    q, k, v = ins[:3]
+    ``gated_delta.kernel_plan`` (one rule: all four kernels or none); and
+    whether its gate is one a key channel (``g`` of rank 4: a model
+    rewritten onto a gate a head reads 0 here)."""
+    q, k, v, g = ins[:4]
+    channel = len(g.shape) == 4
     kernels = int(_gdr.kernel_plan(q.dtype, k.shape, v.shape, params["chunk"],
-                                   platform) is not None)
+                                   platform, channel) is not None)
     return {"executor.linear_attention_layers": 1,
             "executor.linear_attention_chunks":
                 v.shape[0] * _gdr.chunks_of(v.shape[2], params["chunk"]),
             "executor.linear_attention_kernel_layers": kernels,
-            "executor.linear_attention_scan_kernel_layers": kernels}
+            "executor.linear_attention_scan_kernel_layers": kernels,
+            "executor.linear_attention_channel_gated_layers": int(channel)}
 
 
 register(
@@ -269,7 +274,8 @@ register(
     launch_instruments=("executor.linear_attention_layers",
                         "executor.linear_attention_chunks",
                         "executor.linear_attention_kernel_layers",
-                        "executor.linear_attention_scan_kernel_layers"),
+                        "executor.linear_attention_scan_kernel_layers",
+                        "executor.linear_attention_channel_gated_layers"),
 )
 
 

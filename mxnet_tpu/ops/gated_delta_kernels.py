@@ -91,8 +91,8 @@ class Plan(NamedTuple):
     vmem_limit: int
 
 
-def plan(platform, vmem_bytes, dtype, Dk, Dv, group, chunk,
-         T) -> Optional[Plan]:
+def plan(platform, vmem_bytes, dtype, Dk, Dv, group, chunk, T,
+         channel_gate=False) -> Optional[Plan]:
     """The rule, of all four kernels: a node runs every one of them or
     none. They engage where the program is lowered for one TPU whose VMEM
     is known, the operands are bfloat16 (a float32 trunk keeps the
@@ -103,8 +103,10 @@ def plan(platform, vmem_bytes, dtype, Dk, Dv, group, chunk,
     q, k, ``U``, ``W``, the outputs' cotangent, a start state a chunk and
     the five results), twice for the pipeline's two buffers, is under half
     the VMEM. T is padded to whole grid steps by the caller (``padded``).
-    None = the ``jax.numpy`` form."""
-    if platform != "tpu" or not vmem_bytes:
+    None = the ``jax.numpy`` form. A gate a key channel (``channel_gate``:
+    the decay inside the Gram matrices' sum, ``gated_delta._channel_grams``)
+    is no kernel's: always None."""
+    if platform != "tpu" or not vmem_bytes or channel_gate:
         return None
     if jnp.dtype(dtype) != jnp.bfloat16 or Dk % _LANES or Dv % _LANES:
         return None
