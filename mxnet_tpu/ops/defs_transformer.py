@@ -233,8 +233,10 @@ def _gated_delta_rule(ins, params, mode):
     length (eps 1e-6) and the query by ``sqrt(Dk)``. Computed ``chunk``
     tokens at a time; the state starts at 0 in every row and is never reset
     inside one. Where the rule says so (``gated_delta.kernel_plan``, asked
-    with the platform the program is lowered for) the chunk-local algebra
-    runs in Pallas kernels; for a gate a channel it never does."""
+    with the platform the program is lowered for and whether the gate is
+    one a channel) the chunk-local algebra and the scan over chunks run in
+    Pallas kernels, with either gate; a gate a channel's two Gram matrices
+    then do too."""
     q, k, v, g, beta = ins
     q, k = _gdr.l2_normalize(q), _gdr.l2_normalize(k)
     q = (q.astype(jnp.float32) * q.shape[-1] ** -0.5).astype(q.dtype)

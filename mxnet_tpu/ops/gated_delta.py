@@ -72,8 +72,9 @@ state a chunk ((T / chunk) x heads x keys x values float32), never a state
 a token. ``X`` is kept because its backward is two matmuls on it and its
 forward twelve. Under the executor's per-operator recomputation
 (``MXNET_BACKWARD_DO_MIRROR``) the kernel path names ``U``, ``W``, ``X``
-(``gated_delta_kernels._within_fwd``) and the state every chunk started
-from (``_across_fwd``), the operator's checkpoint keeps them, and neither
+(``gated_delta_kernels._within_fwd``), the state every chunk started
+from (``_across_fwd``) and a gate a channel's two Gram matrices
+(``_grams_rule_fwd``), the operator's checkpoint keeps them, and no
 forward kernel runs again; this form marks nothing, and its scan's forward
 runs again (the one state a chunk is jax's own scan residual, which no name
 reaches).
@@ -81,9 +82,12 @@ reaches).
 Where the rule gives a plan (``kernel_plan``) both halves run in Pallas
 kernels, the chunk-local algebra and the scan over chunks
 (``gated_delta_kernels``: the same mathematics at the same precision, a
-head's state in VMEM over all of its chunks); everywhere else this file's
-``jax.numpy`` form is the operator, and it is the kernels' oracle in the
-tests. No kernel computes a gate a channel: the rule answers None for it.
+head's state in VMEM over all of its chunks), with either gate: a gate a
+channel under the same rule (one TPU, a bfloat16 trunk, widths 128 divides,
+chunks of 64, its wider blocks under half the VMEM), its two Gram matrices
+formed in VMEM by two kernels of their own, all of the operator or none of
+it. Everywhere else this file's ``jax.numpy`` form is the operator, and it
+is the kernels' oracle in the tests.
 """
 
 from __future__ import annotations
@@ -97,6 +101,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from . import gated_delta_kernels, pallas_support
 from .defs_tensor import matmul_precision
+from .registry import keep
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -362,9 +367,10 @@ def kernel_plan(dtype, k_shape, v_shape, chunk, platform=None,
     ``dtype`` in a program lowered for ``platform`` (the executor's, through
     ``OpMode.platform``; None: jax's default backend) in a process that
     holds one TPU, or None: the ``jax.numpy`` form (the CPU, several chips,
-    a float32 trunk, a head width 128 does not divide, another chunk, a
-    gate a channel: ``channel_gate``). The op and its launch counts ask it
-    with the same arguments."""
+    a float32 trunk, a head width 128 does not divide, another chunk).
+    ``channel_gate``: the gate is one a key channel, whose blocks are wider
+    and whose plan also runs the Gram matrices' kernels. The op and its
+    launch counts ask it with the same arguments."""
     _, Hk, T, Dk = k_shape
     return gated_delta_kernels.plan(
         platform or jax.default_backend(),
@@ -385,7 +391,8 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, kernels=None,
 
     ``kernels`` (a ``gated_delta_kernels.Plan``, from the rule
     :func:`kernel_plan`): the chunk-local algebra and the scan over chunks
-    in the Pallas kernels, T padded to their whole blocks of chunks; None:
+    (and a gate a channel's Gram matrices before them) in the Pallas
+    kernels, T padded to their whole blocks of chunks; None:
     ``_within_chunks`` and ``_chunk_step`` under ``lax.scan``.
     ``interpret`` runs the kernels in Pallas's interpreter (tests on the
     CPU)."""
@@ -398,10 +405,9 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, kernels=None,
         raise ValueError(f"gated delta rule: {Hv} value heads over {Hk} "
                          "key heads")
     channel = g.ndim == 4
-    if channel and kernels is not None:
-        raise ValueError("gated delta rule: no kernel takes a gate a channel")
-    if channel and Hv != Hk:
+    if channel and Hv != Hk and kernels is None:
         # its decays make a key head's Gram matrices each value head's own
+        # (the kernels form them a value head at a time from the one key)
         q, k = (jnp.repeat(x, Hv // Hk, axis=1) for x in (q, k))
         Hk = Hv
     G, N = Hv // Hk, chunks_of(
@@ -423,12 +429,21 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, kernels=None,
         c = jnp.cumsum(g.reshape((B, Hk, G, N, chunk) + g.shape[3:]), axis=4)
         beta = beta.reshape(B, Hk, G, N, chunk)
         if kernels is not None:
+            kk = qk = None
+            if channel:
+                # as wide as an operand, and read by every kernel's backward:
+                # under per-operator recomputation kept, not summed again
+                c = keep(c)
             with jax.named_scope("within_chunks"):
+                if channel:
+                    with jax.named_scope("grams"):
+                        kk, qk = gated_delta_kernels.channel_grams(
+                            q, k, c, kernels, interpret)
                 u, w = gated_delta_kernels.within_chunks(
-                    k, v, c, beta, kernels, interpret)
+                    k, v, c, beta, kernels, interpret, kk)
             with jax.named_scope("across_chunks"):
                 out = gated_delta_kernels.across_chunks(
-                    q, k, u, w, c, kernels, interpret)
+                    q, k, u, w, c, kernels, interpret, qk)
         else:
             kk, qk = None, ()
             with jax.named_scope("within_chunks"):

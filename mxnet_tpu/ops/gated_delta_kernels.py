@@ -54,7 +54,34 @@ also writes the state every chunk STARTED from ((T / C) x Dk x Dv float32 a
 value head: the size of ``X``); backward reads it, makes the chunk's ``V'``
 and ``A`` again from it, and never runs the walk forward.
 
-``plan`` is the one rule that says whether the kernels engage, all four or
+**A gate a key channel** (``c`` (B, Hk, G, N, C, Dk): Kimi Delta Attention's
+decay, inside the Gram matrices' sum over the keys). ``channel_grams(q, k, c,
+plan)`` is ``gated_delta._channel_grams``: a forward and a backward kernel
+(``gated_delta_grams_fwd`` / ``_bwd``) form a chunk's strictly lower ``sum_d
+k_id k_jd exp(c_id - c_jd)`` and lower ``sum_d q_id k_jd exp(c_id - c_jd)``
+from three (C, Dk) tiles in VMEM, with no positive exponent and nothing (C, C,
+Dk): the diagonal blocks of 8 tokens a column at a time on the VPU in float32,
+all of a chunk's sub-chunks at once (a sub-chunk is one vector register), and
+what lies below them by LEVELS, s = 8, 16, 32: in each block of 2s tokens the
+rows ``[s, 2s)`` against the columns ``[0, s)`` as one MXU product of two
+operands decayed toward the first of those rows and rounded once, as operands
+(``_channel_grams`` takes seven products of growing width a chunk, a
+reference a sub-chunk; the levels are three products of the whole chunk under
+a mask). Backward makes every decay again from q, k and c. The two matrices
+cross HBM as pairs of chunks side by side, (N / 2, C, 2C) float32 like ``X``
+(32 MiB each a layer of the Kimi-Linear cell: 0.1 ms to write and as much to
+read, against a fusion of six kernels into three that would hold a chunk's
+operands, its inverse and its state's products in one body), and are the
+residuals the other kernels' backward reads. The other four take them as
+given: ``_fwd`` / ``_bwd`` in place of ``(K K^T) * decay``, ``_scan_fwd`` /
+``_scan_bwd`` in place of ``(Q K^T) * decay``, and return their cotangents
+for the Gram kernels' backward; ``e^c``, ``e^(last - c)`` and the state's fade
+``Diag(e^last) S`` are then (C, Dk) blocks and a column a key, and dc is (C,
+Dk) a chunk, elementwise where the scalar gate takes a row sum. A gate a head
+lowers to what it lowered to before a gate a channel had kernels: the branch
+is on the rank of ``c``, at trace.
+
+``plan`` is the one rule that says whether the kernels engage, all of them or
 none, as ``flash_attention.plan`` is attention's; traced kernels are kept by
 ``pallas_support._kernel``'s store.
 """
@@ -93,35 +120,41 @@ class Plan(NamedTuple):
 
 def plan(platform, vmem_bytes, dtype, Dk, Dv, group, chunk, T,
          channel_gate=False) -> Optional[Plan]:
-    """The rule, of all four kernels: a node runs every one of them or
+    """The rule, of all the kernels: a node runs every one of them or
     none. They engage where the program is lowered for one TPU whose VMEM
     is known, the operands are bfloat16 (a float32 trunk keeps the
     ``jax.numpy`` form), ``Dk`` and ``Dv`` are multiples of 128, ``chunk``
     is one of ``_CHUNKS``, and what a grid step of ``_BLOCK`` chunks moves
-    in the larger of the two backward kernels (the chunk-local algebra's:
+    in the largest of the backward kernels (the chunk-local algebra's:
     operands, cotangents, inverses and results of the group; the scan's:
     q, k, ``U``, ``W``, the outputs' cotangent, a start state a chunk and
     the five results), twice for the pipeline's two buffers, is under half
     the VMEM. T is padded to whole grid steps by the caller (``padded``).
-    None = the ``jax.numpy`` form. A gate a key channel (``channel_gate``:
-    the decay inside the Gram matrices' sum, ``gated_delta._channel_grams``)
-    is no kernel's: always None."""
-    if platform != "tpu" or not vmem_bytes or channel_gate:
+    None = the ``jax.numpy`` form. A gate a key channel (``channel_gate``)
+    runs the same four kernels and the Gram matrices' two under the same
+    conditions, reckoned with ``c`` and ``dc`` as (chunks, C, Dk) float32
+    blocks and a chunk's Gram matrix and its cotangent beside them."""
+    if platform != "tpu" or not vmem_bytes:
         return None
     if jnp.dtype(dtype) != jnp.bfloat16 or Dk % _LANES or Dv % _LANES:
         return None
     if chunk not in _CHUNKS:
         return None
     rows = _BLOCK * chunk
+    # c and dc a row of the group; a gate a channel: a Gram matrix and its
+    # cotangent too (the Gram kernels hold both matrices, and q, k, dq, dk)
+    gate = 2 * (Dk + chunk) * 4 if channel_gate else 2 * 4
     within = (2 * rows * Dk * 2                       # k, dk
               + group * rows * (2 * Dv * 2 + Dk * 2   # v, dv, dw
                                 + Dv * 4 + chunk * 4  # du, X
-                                + 4 * 4))             # c, beta, dc, dbeta
+                                + 2 * 4 + gate))      # beta, dbeta
     scan = (4 * rows * Dk * 2                         # q, k, dq, dk
             + group * rows * (2 * Dv * 4 + 2 * Dk * 2  # u, du, w, dw
-                              + Dv * 2 + 2 * 4)        # do, c, dc
+                              + Dv * 2 + gate)         # do
             + group * _BLOCK * Dk * Dv * 4)            # a start state a chunk
-    need = 2 * max(within, scan) + group * Dk * Dv * 4  # the carried states
+    grams = (4 * rows * Dk * 2
+             + group * rows * (gate + 2 * chunk * 4)) if channel_gate else 0
+    need = 2 * max(within, scan, grams) + group * Dk * Dv * 4  # the states
     if need > vmem_bytes // 2:
         return None
     return Plan(_BLOCK, min(vmem_bytes * 3 // 4, need + (16 << 20)))
@@ -283,30 +316,287 @@ def _cost(k, v, matmuls, passes):
         + heads * C * C * 4)
 
 
+def _given(gram):
+    """A gate a channel's Gram matrix as one more operand, or none."""
+    return () if gram is None else (gram,)
+
+
 def _pairs(x):
     """(..., N, C) -> (..., N / 2, 2C): a row a pair of chunks."""
     return x.reshape(x.shape[:-2] + (x.shape[-2] // 2, 2 * x.shape[-1]))
 
 
+# --- a gate a key channel: the two Gram matrices ------------------------------
+_SUB = 8    # tokens a sub-chunk: the sublanes of a float32 vector register
+
+
+class _Gram(NamedTuple):
+    """Index planes of a chunk's Gram matrix (C, C), of its tokens (C, 1)
+    and of a sub-chunk's (1, _SUB, 1)."""
+
+    row: jax.Array
+    col: jax.Array
+    token: jax.Array
+    sub: jax.Array
+
+    def column(self, j):
+        """Where the column is token ``j`` of the row's own sub-chunk."""
+        return self.col == (self.row & -_SUB) + j
+
+    below = _Pair.below
+
+
+def _gram_planes(C):
+    return _Gram(lax.broadcasted_iota(jnp.int32, (C, C), 0),
+                 lax.broadcasted_iota(jnp.int32, (C, C), 1),
+                 lax.broadcasted_iota(jnp.int32, (C, 1), 0),
+                 lax.broadcasted_iota(jnp.int32, (1, _SUB, 1), 1))
+
+
+def _sub_chunks(x):
+    """(C, D) -> (C / _SUB, _SUB, D): a vector register a sub-chunk."""
+    return x.reshape(x.shape[0] // _SUB, _SUB, x.shape[1])
+
+
+def _fade_from(c3, j, p):
+    """``gated_delta._fade_from`` of every sub-chunk: ``exp(c_i - c_j)``
+    for the rows ``i >= j``, 0 above, the difference masked before its
+    ``exp``."""
+    seen = p.sub >= j
+    return jnp.where(
+        seen, jnp.exp(jnp.where(seen, c3 - c3[:, j:j + 1], 0.0)), 0.0)
+
+
+def _toward(c, s, p):
+    """The decays of one level of the Gram matrices, (C, D): in each block
+    of ``2s`` tokens the rows ``[s, 2s)`` meet the columns ``[0, s)`` at
+    the reference ``r``, the first of those rows; a row's operand takes
+    ``exp(c_i - c_r)``, a column's ``exp(c_r - c_j)``, and their product
+    under the sum over the keys is ``exp(c_i - c_j)`` with neither exponent
+    positive. Also whether a token is such a row (C, 1)."""
+    C, D = c.shape
+    later = (p.token & s) != 0
+    apart = c - jnp.concatenate(
+        [jnp.broadcast_to(c[b + s:b + s + 1], (2 * s, D))
+         for b in range(0, C, 2 * s)], axis=0)
+    return jnp.exp(jnp.where(later, apart, -apart)), later
+
+
+def _chunk_grams(qb, kb, c, p):
+    """``gated_delta._channel_grams`` of one chunk in VMEM: the strictly
+    lower ``sum_d k_id k_jd exp(c_id - c_jd)`` and the lower ``sum_d q_id
+    k_jd exp(c_id - c_jd)``, (C, C) float32 each. The diagonal blocks of
+    ``_SUB`` tokens a column at a time in float32, all sub-chunks at once
+    (``_diagonal_columns``); below them by levels, s = 8, 16, 32: one
+    product of operands decayed toward a reference between them
+    (``_toward``), rounded once, as operands."""
+    C = c.shape[0]
+    dt = kb.dtype
+    qf, kf = qb.astype(jnp.float32), kb.astype(jnp.float32)
+    q3, k3, c3 = _sub_chunks(qf), _sub_chunks(kf), _sub_chunks(c)
+    kk = jnp.zeros((C, C), jnp.float32)
+    qk = jnp.zeros((C, C), jnp.float32)
+    for j in range(_SUB):
+        col = k3[:, j:j + 1] * _fade_from(c3, j, p)
+        at = p.column(j)
+        kk = kk + jnp.where(
+            at & (p.col < p.row),
+            jnp.sum(k3 * col, axis=2, keepdims=True).reshape(C, 1), 0.0)
+        qk = qk + jnp.where(
+            at, jnp.sum(q3 * col, axis=2, keepdims=True).reshape(C, 1), 0.0)
+    s = _SUB
+    while s < C:
+        toward, _ = _toward(c, s, p)
+        keys = (kf * toward).astype(dt)
+        both = _mm(jnp.concatenate([keys, (qf * toward).astype(dt)], axis=0),
+                   keys, _NT)
+        below = p.below(s)
+        kk = kk + jnp.where(below, both[:C], 0.0)
+        qk = qk + jnp.where(below, both[C:], 0.0)
+        s *= 2
+    return kk, qk
+
+
+def _chunk_grams_bwd(qb, kb, c, dkk, dqk, p):
+    """(dq, dk, dc) (C, D) float32 of ``_chunk_grams`` under the matrices'
+    cotangents (C, C): every decay is made again from q, k and c. The
+    diagonal blocks are ``gated_delta._diagonal_bwd``. A level's reference
+    gets no gradient: the product of a pair's two decays does not depend
+    on it."""
+    C = c.shape[0]
+    dt = kb.dtype
+    qf, kf = qb.astype(jnp.float32), kb.astype(jnp.float32)
+    q3, k3, c3 = _sub_chunks(qf), _sub_chunks(kf), _sub_chunks(c)
+    dq3, dk3, dc3 = (jnp.zeros_like(c3) for _ in range(3))
+
+    def column(x, at):
+        return _sub_chunks(jnp.sum(jnp.where(at, x, 0.0), axis=1,
+                                   keepdims=True))
+
+    for j in range(_SUB):
+        fade = _fade_from(c3, j, p)
+        k_j = k3[:, j:j + 1]
+        col = k_j * fade
+        at = p.column(j)
+        to_kk = column(dkk, at & (p.col < p.row))
+        to_qk = column(dqk, at)
+        d_col = (to_kk * k3 + to_qk * q3) * fade
+        d_c = d_col * k_j
+        # column j's own key and decays: row j of dk and of dc
+        own = p.sub == j
+        dq3 = dq3 + to_qk * col
+        dk3 = dk3 + to_kk * col + jnp.where(
+            own, jnp.sum(d_col, axis=1, keepdims=True), 0.0)
+        dc3 = dc3 + d_c - jnp.where(
+            own, jnp.sum(d_c, axis=1, keepdims=True), 0.0)
+    dq, dk, dc = (x.reshape(c.shape) for x in (dq3, dk3, dc3))
+    s = _SUB
+    while s < C:
+        toward, later = _toward(c, s, p)
+        keys, queries = (kf * toward).astype(dt), (qf * toward).astype(dt)
+        below = p.below(s)
+        to_kk = jnp.where(below, dkk, 0.0).astype(dt)
+        to_qk = jnp.where(below, dqk, 0.0).astype(dt)
+        d_queries = _mm(to_qk, keys)
+        d_keys = _mm(to_kk, keys) + _mm(to_kk, keys, _TN) \
+            + _mm(to_qk, queries, _TN)
+        d_toward = (d_keys * kf + d_queries * qf) * toward
+        dq = dq + d_queries * toward
+        dk = dk + d_keys * toward
+        dc = dc + jnp.where(later, d_toward, -d_toward)
+        s *= 2
+    return dq, dk, dc
+
+
+def _gram_cost(k, c, passes):
+    pl, _ = _ps._pallas()
+    C, Dk = c.shape[-2:]
+    chunks = c.size // (C * Dk)
+    return pl.CostEstimate(
+        flops=chunks * passes * (3 * 2 * 2 * C * C * Dk + 12 * _SUB * C * Dk),
+        transcendentals=chunks * passes * (_SUB + 3) * C * Dk,
+        bytes_accessed=passes * (2 * k.size * 2 + c.size * 4)
+        + chunks * 2 * C * C * 4)
+
+
+@functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
+                                             "interpret"))
+def _grams_fwd(q, k, c, *, chunks, vmem_limit, interpret):
+    """(the decayed ``K K^T``, the decayed ``Q K^T``), (B, Hk, G, N / 2, C,
+    2C) float32 each: a pair of chunks side by side, as ``_fwd`` reads the
+    first and keeps its inverses."""
+    pl, pltpu = _ps._pallas()
+    B, Hk, N, C, Dk = k.shape
+    G = c.shape[2]
+
+    def kernel(q_ref, k_ref, c_ref, kk_ref, qk_ref):
+        p = _gram_planes(C)
+
+        def pair(i):
+            for g in range(G):
+                kk, qk = zip(*[_chunk_grams(
+                    q_ref[2 * i + j], k_ref[2 * i + j], c_ref[g, 2 * i + j],
+                    p) for j in range(2)])
+                kk_ref[g, i] = jnp.concatenate(kk, axis=1)
+                qk_ref[g, i] = jnp.concatenate(qk, axis=1)
+
+        _for_each(chunks // 2, pair)
+
+    s = _specs(chunks, G, C, Dk, Dk)
+    pairs = jax.ShapeDtypeStruct((B, Hk, G, N // 2, C, 2 * C), jnp.float32)
+    return pl.pallas_call(
+        kernel,
+        out_shape=(pairs, pairs),
+        grid=(B, Hk, N // chunks),
+        in_specs=[s["k"], s["k"], s["w"]],
+        out_specs=[s["x"], s["x"]],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=vmem_limit),
+        cost_estimate=_gram_cost(k, c, passes=1),
+        interpret=interpret,
+        name="gated_delta_grams_fwd",
+    )(q, k, c)
+
+
+@functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
+                                             "interpret"))
+def _grams_bwd(q, k, c, dkk, dqk, *, chunks, vmem_limit, interpret):
+    """(dq, dk, dc) in the operands' shapes and dtypes."""
+    pl, pltpu = _ps._pallas()
+    B, Hk, N, C, Dk = k.shape
+    G = c.shape[2]
+
+    def kernel(q_ref, k_ref, c_ref, dkk_ref, dqk_ref, dq_ref, dk_ref,
+               dc_ref):
+        p = _gram_planes(C)
+
+        def pair(i):
+            for j in range(2):
+                n = 2 * i + j
+                dq = jnp.zeros((C, Dk), jnp.float32)
+                dk = jnp.zeros((C, Dk), jnp.float32)
+                for g in range(G):
+                    dq_g, dk_g, dc_ref[g, n] = _chunk_grams_bwd(
+                        q_ref[n], k_ref[n], c_ref[g, n],
+                        dkk_ref[g, i][:, j * C:(j + 1) * C],
+                        dqk_ref[g, i][:, j * C:(j + 1) * C], p)
+                    dq, dk = dq + dq_g, dk + dk_g
+                dq_ref[n] = dq.astype(dq_ref.dtype)
+                dk_ref[n] = dk.astype(dk_ref.dtype)
+
+        _for_each(chunks // 2, pair)
+
+    s = _specs(chunks, G, C, Dk, Dk)
+    return pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(c.shape, jnp.float32)),
+        grid=(B, Hk, N // chunks),
+        in_specs=[s["k"], s["k"], s["w"], s["x"], s["x"]],
+        out_specs=[s["k"], s["k"], s["w"]],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=vmem_limit),
+        cost_estimate=_gram_cost(k, c, passes=2),
+        interpret=interpret,
+        name="gated_delta_grams_bwd",
+    )(q, k, c, dkk, dqk)
+
+
 # --- forward -----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _fwd(k, v, c, beta, *, chunks, vmem_limit, interpret):
+def _fwd(k, v, c, beta, gram=None, *, chunks, vmem_limit, interpret):
     """(U, W, X (B, Hk, G, N / 2, C, 2C) float32: the inverses of a pair of
-    chunks side by side)."""
+    chunks side by side). A gate a channel: c (B, Hk, G, N, C, Dk) and
+    ``gram``, its decayed ``K K^T`` (``_grams_fwd``)."""
     pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = k.shape
     G, Dv = v.shape[2], v.shape[-1]
+    channel = gram is not None
 
-    def kernel(k_ref, v_ref, c_ref, beta_ref, u_ref, w_ref, x_ref):
+    def kernel(k_ref, v_ref, c_ref, beta_ref, *refs):
+        if channel:
+            gram_ref, *refs = refs
+        u_ref, w_ref, x_ref = refs
         p = _pair_planes(C)
 
         def pair(i):
-            _, kf, kk = _keys(k_ref, i)
             at = pl.ds(i, 1)
+            if channel:
+                kf = [k_ref[2 * i + j].astype(jnp.float32) for j in range(2)]
+            else:
+                _, kf, kk = _keys(k_ref, i)
             for g in range(G):
-                betas, beta, cs, _, kd = _pair_head(
-                    kk, c_ref[g, at, :], beta_ref[g, at, :], p)
+                if channel:
+                    betas, beta = _columns(beta_ref[g, at, :], p)
+                    cs = [c_ref[g, 2 * i + j] for j in range(2)]
+                    kd = gram_ref[g, i]
+                else:
+                    betas, beta, cs, _, kd = _pair_head(
+                        kk, c_ref[g, at, :], beta_ref[g, at, :], p)
                 x = _inverse(beta * kd, p)
                 x_ref[g, i] = x
                 for j in range(2):
@@ -328,7 +618,8 @@ def _fwd(k, v, c, beta, *, chunks, vmem_limit, interpret):
                    jax.ShapeDtypeStruct((B, Hk, G, N // 2, C, 2 * C),
                                         jnp.float32)),
         grid=(B, Hk, N // chunks),
-        in_specs=[s["k"], s["v"], s["vec"], s["vec"]],
+        in_specs=[s["k"], s["v"]] + (
+            [s["w"], s["vec"], s["x"]] if channel else [s["vec"], s["vec"]]),
         out_specs=[s["v"], s["w"], s["x"]],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
@@ -336,30 +627,46 @@ def _fwd(k, v, c, beta, *, chunks, vmem_limit, interpret):
         cost_estimate=_cost(k, v, matmuls=10, passes=1),
         interpret=interpret,
         name="gated_delta_chunks_fwd",
-    )(k, v, _pairs(c), _pairs(beta))
+    )(k, v, *((c, _pairs(beta), gram) if channel
+              else (_pairs(c), _pairs(beta))))
 
 
 # --- backward ----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
-    """(dk, dv, dc, dbeta) in the operands' shapes and dtypes."""
+def _bwd(k, v, c, beta, x, du, dw, gram=None, *, chunks, vmem_limit,
+         interpret):
+    """(dk, dv, dc, dbeta) in the operands' shapes and dtypes; a gate a
+    channel (``gram``: ``_fwd``'s): the cotangent of ``gram`` too, and dk
+    without ``K K^T``'s share, which is the Gram kernels' to give."""
     pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = k.shape
     G, Dv = v.shape[2], v.shape[-1]
+    channel = gram is not None
 
-    def kernel(k_ref, v_ref, c_ref, beta_ref, x_ref, du_ref, dw_ref,
-               dk_ref, dv_ref, dc_ref, dbeta_ref):
+    def kernel(k_ref, v_ref, c_ref, beta_ref, x_ref, du_ref, dw_ref, *refs):
+        if channel:
+            gram_ref, *refs, dgram_ref = refs
+        dk_ref, dv_ref, dc_ref, dbeta_ref = refs
         p = _pair_planes(C)
 
         def pair(i):
-            kb, kf, kk = _keys(k_ref, i)
+            if channel:
+                kf = [k_ref[2 * i + j].astype(jnp.float32) for j in range(2)]
+            else:
+                kb, kf, kk = _keys(k_ref, i)
             dk = [jnp.zeros((C, Dk), jnp.float32) for _ in range(2)]
-            dkk = jnp.zeros((C, 2 * C), jnp.float32)
+            if not channel:
+                dkk = jnp.zeros((C, 2 * C), jnp.float32)
             at = pl.ds(i, 1)
             for g in range(G):
-                betas, beta, cs, decay, kd = _pair_head(
-                    kk, c_ref[g, at, :], beta_ref[g, at, :], p)
+                if channel:
+                    betas, beta = _columns(beta_ref[g, at, :], p)
+                    cs = [c_ref[g, 2 * i + j] for j in range(2)]
+                    kd = gram_ref[g, i]
+                else:
+                    betas, beta, cs, decay, kd = _pair_head(
+                        kk, c_ref[g, at, :], beta_ref[g, at, :], p)
                 xt = x_ref[g, i].T                    # [[X0^T], [X1^T]]
                 dx, dbeta, dc = [], [], []
                 for j in range(2):
@@ -375,10 +682,18 @@ def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
                     d_rhs = _hi(xt[j * C:(j + 1) * C], d_solved)
                     dx.append(_hi(d_solved, rhs, _NT))
                     dv_rhs, dk_rhs = d_rhs[:, :Dv], d_rhs[:, Dv:]
-                    key = jnp.sum(dk_rhs * kf[j], axis=1, keepdims=True)
-                    dbeta.append(jnp.sum(dv_rhs * vf, axis=1, keepdims=True)
-                                 + rise * key)
-                    dc.append(betas[j] * rise * key)
+                    if channel:     # W = X (beta e^c . K), a key at a time
+                        key = dk_rhs * kf[j] * rise
+                        dbeta.append(
+                            jnp.sum(dv_rhs * vf, axis=1, keepdims=True)
+                            + jnp.sum(key, axis=1, keepdims=True))
+                        dc_ref[g, n] = betas[j] * key
+                    else:
+                        key = jnp.sum(dk_rhs * kf[j], axis=1, keepdims=True)
+                        dbeta.append(
+                            jnp.sum(dv_rhs * vf, axis=1, keepdims=True)
+                            + rise * key)
+                        dc.append(betas[j] * rise * key)
                     dv_ref[g, n] = (dv_rhs * betas[j]).astype(dv_ref.dtype)
                     dk[j] = dk[j] + dk_rhs * (betas[j] * rise)
                 xts = jnp.concatenate([xt[:C], xt[C:]], axis=1)
@@ -388,6 +703,12 @@ def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
                         _diagonal(xts, p)), 0.0)
                 # L = beta (K K^T) decay, the decay exp(c_i - c_j)
                 moved = dlow * kd              # dL x dL / dbeta
+                if channel:     # L = beta x ``gram``, the decay inside it
+                    dgram_ref[g, i] = dlow * beta
+                    dbeta_ref[g, at, :] = _rows(
+                        [a + b for a, b in zip(dbeta, _row_sums(moved, p))],
+                        p)
+                    continue
                 faded = moved * beta           # dL x L
                 dkk = dkk + dlow * beta * decay
                 dbeta = [a + b for a, b in zip(dbeta, _row_sums(moved, p))]
@@ -395,6 +716,10 @@ def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
                 dbeta_ref[g, at, :] = _rows(dbeta, p)
                 dc_ref[g, at, :] = _rows(dc, p) - jnp.sum(
                     faded, axis=0, keepdims=True)
+            if channel:
+                for j in range(2):
+                    dk_ref[2 * i + j] = dk[j].astype(dk_ref.dtype)
+                return
             # K K^T's own gradient, a product of the operands' dtype
             dkkt = dkk.T
             for j in range(2):
@@ -407,24 +732,29 @@ def _bwd(k, v, c, beta, x, du, dw, *, chunks, vmem_limit, interpret):
         _for_each(chunks // 2, pair)
 
     s = _specs(chunks, G, C, Dk, Dv)
-    dk, dv, dc, dbeta = pl.pallas_call(
+    gate = s["w"] if channel else s["vec"]
+    dk, dv, dc, dbeta, *dgram = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct(_pairs(c).shape, jnp.float32),
-                   jax.ShapeDtypeStruct(_pairs(c).shape, jnp.float32)),
+                   jax.ShapeDtypeStruct((c if channel else _pairs(c)).shape,
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct(_pairs(beta).shape, jnp.float32))
+        + ((jax.ShapeDtypeStruct(gram.shape, jnp.float32),) if channel
+           else ()),
         grid=(B, Hk, N // chunks),
-        in_specs=[s["k"], s["v"], s["vec"], s["vec"], s["x"], s["v"],
-                  s["w"]],
-        out_specs=[s["k"], s["v"], s["vec"], s["vec"]],
+        in_specs=[s["k"], s["v"], gate, s["vec"], s["x"], s["v"], s["w"]]
+        + [s["x"]] * channel,
+        out_specs=[s["k"], s["v"], gate, s["vec"]] + [s["x"]] * channel,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=vmem_limit),
         cost_estimate=_cost(k, v, matmuls=4, passes=2),
         interpret=interpret,
         name="gated_delta_chunks_bwd",
-    )(k, v, _pairs(c), _pairs(beta), x, du, dw)
-    return dk, dv, dc.reshape(c.shape), dbeta.reshape(c.shape)
+    )(k, v, c if channel else _pairs(c), _pairs(beta), x, du, dw,
+      *_given(gram))
+    return (dk, dv, dc.reshape(c.shape), dbeta.reshape(beta.shape), *dgram)
 
 
 # --- the scan over chunks ----------------------------------------------------
@@ -468,6 +798,30 @@ def _chunk_planes(C):
     return _Chunk(row == col, col <= row, lane == C - 1)
 
 
+def _channel_decays(c):
+    """Of a head's cumulative log decay a key channel (C, Dk): it, its last
+    row (1, Dk), and that row as a column (Dk, 1), which fades the state's
+    rows."""
+    C = c.shape[0]
+    return c, c[C - 1:], c.T[:, C - 1:]
+
+
+def _to_pair(ref, g, n, x):
+    """Chunk ``n``'s (C, C) into its half of the pair that holds it."""
+    pl, _ = _ps._pallas()
+    C = x.shape[0]
+    for j in range(2):
+        @pl.when(n % 2 == j)
+        def _(j=j):
+            ref[g, n // 2, :, j * C:(j + 1) * C] = x
+
+
+def _of_pair(pair, n):
+    """Chunk ``n``'s (C, C) of the pair (C, 2C) that holds it."""
+    C = pair.shape[0]
+    return jnp.where(n % 2 == 0, pair[:, :C], pair[:, C:])
+
+
 def _scan_cost(q, u, matmuls, passes):
     """``matmuls`` (C x D) x (D x D) products a chunk and value head,
     ``passes`` over the operands and a start state a chunk."""
@@ -485,17 +839,24 @@ def _scan_cost(q, u, matmuls, passes):
 
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _scan_fwd(q, k, u, w, c, *, chunks, vmem_limit, interpret):
+def _scan_fwd(q, k, u, w, c, gram=None, *, chunks, vmem_limit, interpret):
     """(the outputs (B, Hk, G, N, C, Dv) in q's dtype, the state every
     chunk STARTED from (B, Hk, G, N, Dk, Dv) float32): ``_chunk_step`` over
     the chunks of a (batch, key head) in order, the group's states in VMEM
-    scratch from the first block of chunks to the last."""
+    scratch from the first block of chunks to the last. A gate a channel: c
+    (B, Hk, G, N, C, Dk) and ``gram``, its decayed ``Q K^T``
+    (``_grams_fwd``)."""
     pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = q.shape
     G, Dv = u.shape[2], u.shape[-1]
     dt = q.dtype
+    channel = gram is not None
 
-    def kernel(q_ref, k_ref, u_ref, w_ref, c_ref, o_ref, s_ref, state):
+    def kernel(q_ref, k_ref, u_ref, w_ref, c_ref, *refs):
+        if channel:
+            gram_ref, *refs = refs
+        o_ref, s_ref, state = refs
+
         @pl.when(pl.program_id(2) == 0)
         def _():
             state[...] = jnp.zeros_like(state)
@@ -505,17 +866,23 @@ def _scan_fwd(q, k, u, w, c, *, chunks, vmem_limit, interpret):
         def chunk(n):
             qb, kb = q_ref[n], k_ref[n]
             qf, kf = qb.astype(jnp.float32), kb.astype(jnp.float32)
-            qk = _mm(qb, kb, _NT)
+            if not channel:
+                qk = _mm(qb, kb, _NT)
             for g in range(G):
-                cs, last, decay = p.decays(c_ref[g, pl.ds(n, 1), :])
+                if channel:
+                    cs, last, gain = _channel_decays(c_ref[g, n])
+                else:
+                    cs, last, decay = p.decays(c_ref[g, pl.ds(n, 1), :])
+                    gain = last
                 s = state[g]
                 s_ref[g, n] = s
                 sb = s.astype(dt)
                 written = (u_ref[g, n] - _mm(w_ref[g, n], sb)).astype(dt)
-                out = _mm((qf * jnp.exp(cs)).astype(dt), sb) \
-                    + _mm((qk * decay).astype(dt), written)
+                out = _mm((qf * jnp.exp(cs)).astype(dt), sb) + _mm(
+                    (_of_pair(gram_ref[g, n // 2], n) if channel
+                     else qk * decay).astype(dt), written)
                 o_ref[g, n] = out.astype(o_ref.dtype)
-                state[g] = s * jnp.exp(last) + _mm(
+                state[g] = s * jnp.exp(gain) + _mm(
                     (kf * jnp.exp(last - cs)).astype(dt), written, _TN)
 
         _for_each(chunks, chunk)
@@ -526,7 +893,8 @@ def _scan_fwd(q, k, u, w, c, *, chunks, vmem_limit, interpret):
         out_shape=(jax.ShapeDtypeStruct(u.shape, dt),
                    jax.ShapeDtypeStruct((B, Hk, G, N, Dk, Dv), jnp.float32)),
         grid=(B, Hk, N // chunks),
-        in_specs=[s["k"], s["k"], s["v"], s["w"], s["c"]],
+        in_specs=[s["k"], s["k"], s["v"], s["w"]] + (
+            [s["w"], s["x"]] if channel else [s["c"]]),
         out_specs=[s["v"], s["s"]],
         scratch_shapes=[pltpu.VMEM((G, Dk, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -535,12 +903,13 @@ def _scan_fwd(q, k, u, w, c, *, chunks, vmem_limit, interpret):
         cost_estimate=_scan_cost(q, u, matmuls=4, passes=1),
         interpret=interpret,
         name="gated_delta_scan_fwd",
-    )(q, k, u, w, c)
+    )(q, k, u, w, c, *_given(gram))
 
 
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _scan_bwd(q, k, u, w, c, states, do, *, chunks, vmem_limit, interpret):
+def _scan_bwd(q, k, u, w, c, states, do, gram=None, *, chunks, vmem_limit,
+              interpret):
     """(dq, dk, dU, dW, dc) in the operands' shapes and dtypes: the chunks
     of a (batch, key head) from the last to the first, the cotangent of
     the group's states in VMEM scratch; a chunk's forward values are made
@@ -554,15 +923,25 @@ def _scan_bwd(q, k, u, w, c, states, do, *, chunks, vmem_limit, interpret):
         dc  = rows(dA * A) - columns(dA * A) + e^c (dO S^T . Q)
               - e^(last - c) (V' dS'^T . K), and at the chunk's last token
               + e^last (dS' . S) + sum(e^(last - c) (V' dS'^T . K))
-    """
+
+    A gate a channel (``gram``: ``_scan_fwd``'s) has ``A`` given: dA is a
+    sixth result, the cotangent of ``gram``, and its shares of dq, dk and
+    dc are the Gram kernels' to give; the sums over the keys in dc's other
+    terms are not taken (``.`` is then elementwise, a key at a time)."""
     pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = q.shape
     G, Dv = u.shape[2], u.shape[-1]
     dt = q.dtype
     blocks = N // chunks
+    channel = gram is not None
 
-    def kernel(q_ref, k_ref, u_ref, w_ref, c_ref, s_ref, do_ref,
-               dq_ref, dk_ref, du_ref, dw_ref, dc_ref, dstate):
+    def kernel(q_ref, k_ref, u_ref, w_ref, c_ref, s_ref, do_ref, *refs):
+        if channel:
+            gram_ref, *refs, dgram_ref, dstate = refs
+        else:
+            *refs, dstate = refs
+        dq_ref, dk_ref, du_ref, dw_ref, dc_ref = refs
+
         @pl.when(pl.program_id(2) == 0)
         def _():
             dstate[...] = jnp.zeros_like(dstate)
@@ -577,19 +956,25 @@ def _scan_bwd(q, k, u, w, c, states, do, *, chunks, vmem_limit, interpret):
             n = chunks - 1 - i
             qb, kb = q_ref[n], k_ref[n]
             qf, kf = qb.astype(jnp.float32), kb.astype(jnp.float32)
-            qk = _mm(qb, kb, _NT)
-            dqk = jnp.zeros((C, C), jnp.float32)
+            if not channel:
+                qk = _mm(qb, kb, _NT)
+                dqk = jnp.zeros((C, C), jnp.float32)
             dq = jnp.zeros((C, Dk), jnp.float32)
             dk = jnp.zeros((C, Dk), jnp.float32)
             for g in range(G):
-                cs, last, decay = p.decays(c_ref[g, pl.ds(n, 1), :])
+                if channel:
+                    cs, last, gain = _channel_decays(c_ref[g, n])
+                else:
+                    cs, last, decay = p.decays(c_ref[g, pl.ds(n, 1), :])
+                    gain = last
                 rise, fade, gain = (jnp.exp(cs), jnp.exp(last - cs),
-                                    jnp.exp(last))
+                                    jnp.exp(gain))
                 s, ds = s_ref[g, n], dstate[g]
                 sb, dsb = s.astype(dt), ds.astype(dt)
                 wb, gb = w_ref[g, n], do_ref[g, n]
                 written = (u_ref[g, n] - _mm(wb, sb)).astype(dt)
-                a = qk * decay
+                a = _of_pair(gram_ref[g, n // 2], n) if channel \
+                    else qk * decay
                 risen, faded = (qf * rise).astype(dt), (kf * fade).astype(dt)
                 d_risen = _mm(gb, sb, _NT)
                 d_faded = _mm(written, dsb, _NT)
@@ -600,6 +985,20 @@ def _scan_bwd(q, k, u, w, c, states, do, *, chunks, vmem_limit, interpret):
                 dw_ref[g, n] = (-_mm(d_written, sb, _NT)).astype(dw_ref.dtype)
                 dstate[g] = ds * gain + _mm(risen, gb, _TN) \
                     - _mm(wb, d_written, _TN)
+                if channel:
+                    d_rise, d_fade = d_risen * rise, d_faded * fade
+                    dq, dk = dq + d_rise, dk + d_fade
+                    d_fade = d_fade * kf
+                    # the last token's row: what fades the state, a key at
+                    # a time, and every key operand's share of ``last``
+                    d_last = jnp.sum((ds * s).T, axis=0, keepdims=True) \
+                        * jnp.exp(last) + jnp.sum(d_fade, axis=0,
+                                                  keepdims=True)
+                    dc_ref[g, n] = d_rise * qf - d_fade + jnp.where(
+                        lax.broadcasted_iota(jnp.int32, (C, 1), 0) == C - 1,
+                        d_last, 0.0)
+                    _to_pair(dgram_ref, g, n, da)
+                    continue
                 moved = da * a
                 d_rise = jnp.sum(d_risen * qf, axis=1, keepdims=True) * rise
                 d_fade = jnp.sum(d_faded * kf, axis=1, keepdims=True) * fade
@@ -612,6 +1011,10 @@ def _scan_bwd(q, k, u, w, c, states, do, *, chunks, vmem_limit, interpret):
                 dqk = dqk + da * decay
                 dq = dq + d_risen * rise
                 dk = dk + d_faded * fade
+            if channel:
+                dq_ref[n] = dq.astype(dq_ref.dtype)
+                dk_ref[n] = dk.astype(dk_ref.dtype)
+                return
             dqk = dqk.astype(dt)
             dq_ref[n] = (dq + _mm(dqk, kb)).astype(dq_ref.dtype)
             dk_ref[n] = (dk + _mm(dqk, qb, _TN)).astype(dk_ref.dtype)
@@ -619,16 +1022,20 @@ def _scan_bwd(q, k, u, w, c, states, do, *, chunks, vmem_limit, interpret):
         _for_each(chunks, chunk)
 
     s = _specs(chunks, G, C, Dk, Dv, lambda i: blocks - 1 - i)
+    gate = s["w"] if channel else s["c"]
     return pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct(q.shape, dt),
                    jax.ShapeDtypeStruct(k.shape, dt),
                    jax.ShapeDtypeStruct(u.shape, u.dtype),
                    jax.ShapeDtypeStruct(w.shape, w.dtype),
-                   jax.ShapeDtypeStruct(c.shape, jnp.float32)),
+                   jax.ShapeDtypeStruct(c.shape, jnp.float32))
+        + ((jax.ShapeDtypeStruct(gram.shape, jnp.float32),) if channel
+           else ()),
         grid=(B, Hk, blocks),
-        in_specs=[s["k"], s["k"], s["v"], s["w"], s["c"], s["s"], s["v"]],
-        out_specs=[s["k"], s["k"], s["v"], s["w"], s["c"]],
+        in_specs=[s["k"], s["k"], s["v"], s["w"], gate, s["s"], s["v"]]
+        + [s["x"]] * channel,
+        out_specs=[s["k"], s["k"], s["v"], s["w"], gate] + [s["x"]] * channel,
         scratch_shapes=[pltpu.VMEM((G, Dk, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -636,61 +1043,95 @@ def _scan_bwd(q, k, u, w, c, states, do, *, chunks, vmem_limit, interpret):
         cost_estimate=_scan_cost(q, u, matmuls=10, passes=2),
         interpret=interpret,
         name="gated_delta_scan_bwd",
-    )(q, k, u, w, c, states, do)
+    )(q, k, u, w, c, states, do, *_given(gram))
 
 
 # --- what chunk_gated_delta_rule calls ----------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def within_chunks(k, v, c, beta, plan, interpret=False):
-    """(U, W): the forward kernel at ``plan``'s block. N is a multiple of
-    ``plan.chunks`` (``padded``). Backward keeps the operands and the
-    chunks' inverses; under per-operator recomputation
-    (``MXNET_BACKWARD_DO_MIRROR``) the inverses, ``U`` and ``W`` are what
-    the operator names (``registry.keep``), so that the forward kernel runs
-    once a step and not again in backward. ``interpret`` runs the kernels
-    in Pallas's interpreter (tests on the CPU)."""
-    return _within_fwd(k, v, c, beta, plan, interpret)[0]
-
-
 def _static(plan, interpret):
     return dict(chunks=plan.chunks, vmem_limit=plan.vmem_limit,
                 interpret=interpret)
 
 
-def _within_fwd(k, v, c, beta, plan, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def channel_grams(q, k, c, plan, interpret=False):
+    """A gate a key channel's two Gram matrices, the decay inside the sum
+    over the keys (``gated_delta._channel_grams``): q, k (B, Hk, N, C, Dk)
+    and c (B, Hk, G, N, C, Dk) float32 -> the strictly lower ``K K^T`` that
+    ``within_chunks`` reads and the lower ``Q K^T`` that ``across_chunks``
+    reads, (B, Hk, G, N / 2, C, 2C) float32 each, a pair of chunks side by
+    side. Backward keeps the operands and makes every decay again; the
+    matrices themselves are what the other two rules' backward reads, and
+    what the operator names (``registry.keep``)."""
+    return _grams_rule_fwd(q, k, c, plan, interpret)[0]
+
+
+def _grams_rule_fwd(q, k, c, plan, interpret):
+    return keep(_ps._kernel(_grams_fwd, (q, k, c),
+                            **_static(plan, interpret))), (q, k, c)
+
+
+def _grams_rule_bwd(plan, interpret, res, g):
+    return _ps._kernel(_grams_bwd, (*res, *g), **_static(plan, interpret))
+
+
+channel_grams.defvjp(_grams_rule_fwd, _grams_rule_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def within_chunks(k, v, c, beta, plan, interpret=False, gram=None):
+    """(U, W): the forward kernel at ``plan``'s block. N is a multiple of
+    ``plan.chunks`` (``padded``). ``gram``: None, or with c (B, Hk, G, N, C,
+    Dk) of a gate a channel ``channel_grams``' first. Backward keeps the
+    operands and the chunks' inverses; under per-operator recomputation
+    (``MXNET_BACKWARD_DO_MIRROR``) the inverses, ``U`` and ``W`` are what
+    the operator names (``registry.keep``), so that the forward kernel runs
+    once a step and not again in backward. ``interpret`` runs the kernels
+    in Pallas's interpreter (tests on the CPU)."""
+    return _within_fwd(k, v, c, beta, plan, interpret, gram)[0]
+
+
+def _within_fwd(k, v, c, beta, plan, interpret, gram):
     # the scan over chunks reads u and w again in its backward, this
     # rule's backward x: kept under per-operator recomputation
-    u, w, x = keep(_ps._kernel(_fwd, (k, v, c, beta),
+    u, w, x = keep(_ps._kernel(_fwd, (k, v, c, beta, *_given(gram)),
                                 **_static(plan, interpret)))
-    return (u, w), (k, v, c, beta, x)
+    return (u, w), (k, v, c, beta, x, gram)
 
 
 def _within_bwd(plan, interpret, res, g):
-    return _ps._kernel(_bwd, (*res, *g), **_static(plan, interpret))
+    *res, gram = res
+    grads = _ps._kernel(_bwd, (*res, *g, *_given(gram)),
+                        **_static(plan, interpret))
+    return grads if gram is not None else (*grads, None)
 
 
 within_chunks.defvjp(_within_fwd, _within_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def across_chunks(q, k, u, w, c, plan, interpret=False):
+def across_chunks(q, k, u, w, c, plan, interpret=False, gram=None):
     """The outputs (B, Hk, G, N, C, Dv) of the scan over chunks of q, k (B,
     Hk, N, C, Dk), ``within_chunks``' ``U`` and ``W`` and c (B, Hk, G, N, C)
-    float32, the state 0 before a row's first chunk: the forward kernel at
-    ``plan``'s block. Backward keeps the operands and the state every chunk
-    started from, which the operator names (``registry.keep``): under
-    per-operator recomputation the forward kernel runs once a step."""
-    return _across_fwd(q, k, u, w, c, plan, interpret)[0]
+    float32 (a gate a channel: (B, Hk, G, N, C, Dk), and ``gram`` is
+    ``channel_grams``' second; else None), the state 0 before a row's first
+    chunk: the forward kernel at ``plan``'s block. Backward keeps the
+    operands and the state every chunk started from, which the operator
+    names (``registry.keep``): under per-operator recomputation the forward
+    kernel runs once a step."""
+    return _across_fwd(q, k, u, w, c, plan, interpret, gram)[0]
 
 
-def _across_fwd(q, k, u, w, c, plan, interpret):
-    out, states = _ps._kernel(_scan_fwd, (q, k, u, w, c),
+def _across_fwd(q, k, u, w, c, plan, interpret, gram):
+    out, states = _ps._kernel(_scan_fwd, (q, k, u, w, c, *_given(gram)),
                                **_static(plan, interpret))
-    return out, (q, k, u, w, c, keep(states))
+    return out, (q, k, u, w, c, keep(states), gram)
 
 
 def _across_bwd(plan, interpret, res, g):
-    return _ps._kernel(_scan_bwd, (*res, g), **_static(plan, interpret))
+    *res, gram = res
+    grads = _ps._kernel(_scan_bwd, (*res, g, *_given(gram)),
+                        **_static(plan, interpret))
+    return grads if gram is not None else (*grads, None)
 
 
 across_chunks.defvjp(_across_fwd, _across_bwd)

@@ -11,6 +11,8 @@ product are rounded to 8 bits, the decayed ones too: held to 4e-2 of the
 largest entry against the float32 recurrence on the same rounded inputs.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -202,10 +204,39 @@ def test_nothing_chunk_by_chunk_by_keys_is_ever_held():
     assert max(sizes) <= 4 * args[0].size
 
 
-def test_the_kernels_refuse_a_gate_a_channel():
-    args = inputs(1024, "bfloat16", B=1, Hk=1, D=128)
-    with pytest.raises(ValueError, match="channel"):
-        gd.chunk_gated_delta_rule(*args, kernels=gk.Plan(gk._BLOCK, 64 << 20))
+@functools.lru_cache(maxsize=None)
+def _kernels_and_form():
+    """(outputs and the five gradients through the kernels, the same of
+    the ``jax.numpy`` form) under one cotangent: bfloat16, 200 tokens, the
+    rule's own block (T padded to one grid step of 16 chunks)."""
+    import jax
+    import jax.numpy as jnp
+
+    args = inputs(200, "bfloat16", B=1, Hk=1, D=128)
+    head = jnp.asarray(np.random.RandomState(4).randn(*args[2].shape),
+                       jnp.bfloat16)
+
+    def both(**kw):
+        out, vjp = jax.vjp(functools.partial(
+            gd.chunk_gated_delta_rule, chunk=64, **kw), *args)
+        return [np.asarray(x, np.float32) for x in (out,) + vjp(head)]
+
+    return both(kernels=gk.Plan(gk._BLOCK, 64 << 20), interpret=True), both()
+
+
+@pytest.mark.parametrize("tensor", range(6), ids=("output",) + NAMES)
+def test_the_kernels_take_a_gate_a_channel(tensor):
+    """A plan with ``g`` of rank 4 runs (the kernels in Pallas's
+    interpreter) and matches the ``jax.numpy`` form, outputs and the five
+    gradients. Both round the decayed operands of a Gram product, ``W`` and
+    the gradients to bfloat16 (one part in 256) after sums in another
+    order: 8e-3, what ``test_gated_delta_kernels.py`` holds the scalar gate
+    to; the keys' gradient is the sum of three kernels' shares, each
+    rounded, where the form rounds one sum: 1.2e-2. In float32, at 2e-5:
+    ``test_gated_delta_channel_kernels.py``."""
+    got, want = (x[tensor] for x in _kernels_and_form())
+    assert got.shape == want.shape and np.isfinite(got).all()
+    assert rel(got, want) < (1.2e-2 if tensor == 2 else 8e-3)
 
 
 # --- the rule and the counts -------------------------------------------------
@@ -214,29 +245,37 @@ QWEN = ((1, 16, 8192, 128), (1, 32, 8192, 128), 64)     # Qwen3-Next's cell
 KIMI = ((1, 32, 4096, 128), (1, 32, 4096, 128), 64)     # Kimi-Linear's
 
 
-def test_the_rule_answers_none_for_a_gate_a_channel(monkeypatch):
-    """On a described TPU: no plan for the gate a channel, whatever the
-    shapes, and still a plan for Qwen3-Next's shapes and for these with a
-    gate a head."""
+def test_the_rule_gives_a_gate_a_channel_a_plan(monkeypatch):
+    """On a described v5e: a plan for Kimi-Linear's and Qwen3-Next's shapes
+    with either gate, at the same block. None on the CPU, for a float32
+    trunk, for a head width 128 does not divide, and where the channel
+    form's blocks (``c`` and ``dc`` (chunks, C, Dk) float32, a Gram matrix
+    and its cotangent) pass half the VMEM while the scalar form's do not."""
     monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
+    for shapes in (QWEN, KIMI):
+        for channel in (False, True):
+            plan = gd.kernel_plan("bfloat16", *shapes, "tpu", channel)
+            assert plan is not None and plan.chunks == gk._BLOCK
+            assert plan.vmem_limit <= V5E_VMEM * 3 // 4
     assert gd.kernel_plan("bfloat16", *QWEN, "tpu") is not None
-    assert gd.kernel_plan("bfloat16", *QWEN, "tpu", False) is not None
-    assert gd.kernel_plan("bfloat16", *KIMI, "tpu", False) is not None
-    assert gd.kernel_plan("bfloat16", *KIMI, "tpu", True) is None
-    assert gd.kernel_plan("bfloat16", *QWEN, "tpu", True) is None
-    assert gk.plan("tpu", V5E_VMEM, "bfloat16", 128, 128, 1, 64, 4096,
+    assert gd.kernel_plan("bfloat16", *KIMI, "cpu", True) is None
+    assert gd.kernel_plan("float32", *KIMI, "tpu", True) is None
+    narrow = ((1, 32, 4096, 64), (1, 32, 4096, 128), 64)
+    assert gd.kernel_plan("bfloat16", *narrow, "tpu", True) is None
+    small = ("tpu", 16 << 20, "bfloat16", 128, 128, 1, 64, 4096)
+    assert gk.plan(*small) is not None
+    assert gk.plan(*small, True) is None
+    assert gk.plan("tpu", None, "bfloat16", 128, 128, 1, 64, 4096,
                    True) is None
-    assert gk.plan("tpu", V5E_VMEM, "bfloat16", 128, 128, 1, 64,
-                   4096) is not None
 
 
 @pytest.mark.parametrize("channel,platform,want", [
-    (True, "tpu", (1, 0)), (True, "cpu", (1, 0)),
+    (True, "tpu", (1, 1)), (True, "cpu", (1, 0)),
     (False, "tpu", (0, 1)), (False, "cpu", (0, 0))])
 def test_launch_counts(monkeypatch, channel, platform, want):
-    """What one launch of a train program counts for a node: the new
-    counter follows ``g``'s rank, and the kernels' counters read 0 beside
-    it."""
+    """What one launch of a train program counts for a node: the
+    channel-gated counter follows ``g``'s rank, and the kernels' counters
+    the rule, which gives either gate a plan on a v5e."""
     import jax
     import jax.numpy as jnp
 

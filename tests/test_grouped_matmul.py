@@ -622,6 +622,45 @@ def test_gated_delta_scan_kernels_compile_for_a_v5e_at_the_cell_widths(
         <= b * hk * g * n * d * d * 4 + (4 << 20)
 
 
+def test_channel_gated_delta_kernels_compile_for_a_v5e_at_the_cell_widths(
+        one_chip):
+    """Mosaic accepts the six kernels of the gated delta rule with a gate a
+    key channel (the Gram matrices' two, and the four above taking ``c`` a
+    channel) at the Kimi-Linear cell's widths, the whole operator forward
+    and backward: T 4096 in chunks of 64, 32 heads of 128, at the rule's
+    block and VMEM limit; the temporaries are of the order of the kept
+    residuals (``U``, ``W``, three (T x 64) float32 a head and a state a
+    chunk: 0.35 GiB) and not the ``jax.numpy`` form's 1.5 GiB (their other
+    tests are in ``test_gated_delta_channel_kernels.py``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import gated_delta as gd
+    from mxnet_tpu.ops import gated_delta_kernels as gk
+
+    b, h, t, d, c = 1, 32, 4096, 128, 64
+    plan = gk.plan("tpu", V5E_VMEM, jnp.bfloat16, d, d, 1, c, t, True)
+    assert plan is not None and (t // c) % plan.chunks == 0
+
+    def loss(*a):
+        return jnp.sum(gd.chunk_gated_delta_rule(
+            *a, chunk=c, kernels=plan).astype(jnp.float32))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide = arg((b, h, t, d), jnp.bfloat16)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        wide, wide, wide, arg((b, h, t, d), jnp.float32),
+        arg((b, h, t), jnp.float32)).compile()
+    text = compiled.as_text()
+    for kernel in ("grams", "chunks", "scan"):
+        for way in ("fwd", "bwd"):
+            assert f"gated_delta_{kernel}_{way}" in text
+    assert "while" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 640 << 20
+
+
 @pytest.mark.parametrize("cell", ["qwen3_next", "zaya1"])
 def test_causal_conv_kernels_compile_for_a_v5e_at_the_cell_widths(
         monkeypatch, one_chip, cell):

@@ -53,13 +53,14 @@ def _attention(steer, kernels=False, kv_heads=2, **params):
     return sym, shapes, {n: "bfloat16" for n in "qkv"}
 
 
-def _gated_delta(steer, kernels=False):
+def _gated_delta(steer, kernels=False, channel=False):
     names = ("query", "key", "value", "g", "beta")
     sym = mx.sym.GatedDeltaRule(*[mx.sym.Variable(n) for n in names],
                                 name="delta")
     t = 1024
     shapes = dict(query=(1, 1, t, 128), key=(1, 1, t, 128),
-                  value=(1, 2, t, 128), g=(1, 2, t), beta=(1, 2, t))
+                  value=(1, 2, t, 128), beta=(1, 2, t),
+                  g=(1, 2, t) + ((128,) if channel else ()))
     if kernels:
         rule = gd.chunk_gated_delta_rule
         steer.setattr(gd, "kernel_plan",
@@ -263,30 +264,36 @@ def test_gradient_program_holds_the_forward_once(monkeypatch, case):
     assert counts == [kept, again]
 
 
-@pytest.mark.parametrize("policy,again", [(True, []), (False, [
-    "gated_delta_chunks_fwd", "gated_delta_scan_fwd"])],
-    ids=["kept", "no-policy"])
+@pytest.mark.parametrize("channel", [False, True],
+                         ids=["a-gate-a-head", "a-gate-a-channel"])
+@pytest.mark.parametrize("policy", [True, False], ids=["kept", "no-policy"])
 def test_gated_delta_forward_kernels_run_once_by_name(monkeypatch, policy,
-                                                      again):
+                                                      channel):
     """The kernels of ``GatedDeltaRule`` by name: the gradient's program
     holds each forward kernel once (the scan's start states, ``U``, ``W``
-    and the inverses are kept) and its rematerialised part only the two
-    backward kernels; without the policy both forward kernels are there
-    again."""
+    and the inverses are kept; with a gate a channel its two Gram matrices
+    and the cumulative decays too, so the ``cumsum`` is not taken again) and its
+    rematerialised part only the backward kernels; without the policy the
+    forward kernels are there again."""
     import jax
 
-    exe = _bind(monkeypatch, functools.partial(_gated_delta, kernels=True),
-                "1", policy)
+    exe = _bind(monkeypatch, functools.partial(
+        _gated_delta, kernels=True, channel=channel), "1", policy)
     fun, args = _gradient_program(exe)
     jaxpr = jax.make_jaxpr(fun)(*args).jaxpr
     kernels = [(eqn.params["name"], inside)
                for eqn, inside in _equations(jaxpr)
                if eqn.primitive.name == "pallas_call"]
-    backward = ["gated_delta_chunks_bwd", "gated_delta_scan_bwd"]
+    halves = ["chunks", "scan"] + ["grams"] * channel
+    forward = [f"gated_delta_{half}_fwd" for half in halves]
+    backward = [f"gated_delta_{half}_bwd" for half in halves]
+    again = [] if policy else forward
     assert sorted(n for n, inside in kernels if inside) == sorted(
         backward + again)
     assert sorted(n for n, _ in kernels) == sorted(
-        backward + again + ["gated_delta_chunks_fwd", "gated_delta_scan_fwd"])
+        backward + again + forward)
+    if channel:   # the gradient's own reversed sum, and the forward's again
+        assert _recomputed(jaxpr, "cumsum") == 1 + int(not policy)
 
 
 # --- (c) the same bits ------------------------------------------------------------
