@@ -236,7 +236,8 @@ def _gated_delta_rule(ins, params, mode):
     with the platform the program is lowered for and whether the gate is
     one a channel) the chunk-local algebra and the scan over chunks run in
     Pallas kernels, with either gate; a gate a channel's two Gram matrices
-    then do too."""
+    and its running sum then do too, all six kernels under one
+    differentiation rule."""
     q, k, v, g, beta = ins
     q, k = _gdr.l2_normalize(q), _gdr.l2_normalize(k)
     q = (q.astype(jnp.float32) * q.shape[-1] ** -0.5).astype(q.dtype)

@@ -73,9 +73,9 @@ a token. ``X`` is kept because its backward is two matmuls on it and its
 forward twelve. Under the executor's per-operator recomputation
 (``MXNET_BACKWARD_DO_MIRROR``) the kernel path names ``U``, ``W``, ``X``
 (``gated_delta_kernels._within_fwd``), the state every chunk started
-from (``_across_fwd``) and a gate a channel's two Gram matrices
-(``_grams_rule_fwd``), the operator's checkpoint keeps them, and no
-forward kernel runs again; this form marks nothing, and its scan's forward
+from (``_across_fwd``) and, with a gate a channel, ``c`` and the two Gram
+matrices too (``_channel_fwd``), the operator's checkpoint keeps them, and
+no forward kernel runs again; this form marks nothing, and its scan's forward
 runs again (the one state a chunk is jax's own scan residual, which no name
 reaches).
 
@@ -85,8 +85,10 @@ kernels, the chunk-local algebra and the scan over chunks
 head's state in VMEM over all of its chunks), with either gate: a gate a
 channel under the same rule (one TPU, a bfloat16 trunk, widths 128 divides,
 chunks of 64, its wider blocks under half the VMEM), its two Gram matrices
-formed in VMEM by two kernels of their own, all of the operator or none of
-it. Everywhere else this file's ``jax.numpy`` form is the operator, and it
+formed in VMEM by two kernels of their own and all six under one
+differentiation rule (``gated_delta_kernels.channel_gated``: ``g``'s running
+sum and every sum of cotangents are taken on the kernels' tiles), all of the
+operator or none of it. Everywhere else this file's ``jax.numpy`` form is the operator, and it
 is the kernels' oracle in the tests.
 """
 
@@ -101,7 +103,6 @@ from jax.ad_checkpoint import checkpoint_name
 
 from . import gated_delta_kernels, pallas_support
 from .defs_tensor import matmul_precision
-from .registry import keep
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -391,8 +392,8 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, kernels=None,
 
     ``kernels`` (a ``gated_delta_kernels.Plan``, from the rule
     :func:`kernel_plan`): the chunk-local algebra and the scan over chunks
-    (and a gate a channel's Gram matrices before them) in the Pallas
-    kernels, T padded to their whole blocks of chunks; None:
+    (and a gate a channel's Gram matrices and its running sum before them)
+    in the Pallas kernels, T padded to their whole blocks of chunks; None:
     ``_within_chunks`` and ``_chunk_step`` under ``lax.scan``.
     ``interpret`` runs the kernels in Pallas's interpreter (tests on the
     CPU)."""
@@ -426,24 +427,22 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, kernels=None,
         q = q.reshape(B, Hk, N, chunk, Dk)
         k = k.reshape(B, Hk, N, chunk, Dk)
         v = v.reshape(B, Hk, G, N, chunk, Dv)
-        c = jnp.cumsum(g.reshape((B, Hk, G, N, chunk) + g.shape[3:]), axis=4)
+        g = g.reshape((B, Hk, G, N, chunk) + g.shape[3:])
+        # a gate a channel's kernels take the running sum themselves
+        c = None if channel and kernels is not None else jnp.cumsum(g, axis=4)
         beta = beta.reshape(B, Hk, G, N, chunk)
-        if kernels is not None:
-            kk = qk = None
-            if channel:
-                # as wide as an operand, and read by every kernel's backward:
-                # under per-operator recomputation kept, not summed again
-                c = keep(c)
+        if c is None:
+            # one rule over the six kernels: no cotangent is summed, and no
+            # running sum taken, between them
+            out = gated_delta_kernels.channel_gated(
+                q, k, v, g, beta, kernels, interpret)
+        elif kernels is not None:
             with jax.named_scope("within_chunks"):
-                if channel:
-                    with jax.named_scope("grams"):
-                        kk, qk = gated_delta_kernels.channel_grams(
-                            q, k, c, kernels, interpret)
                 u, w = gated_delta_kernels.within_chunks(
-                    k, v, c, beta, kernels, interpret, kk)
+                    k, v, c, beta, kernels, interpret)
             with jax.named_scope("across_chunks"):
                 out = gated_delta_kernels.across_chunks(
-                    q, k, u, w, c, kernels, interpret, qk)
+                    q, k, u, w, c, kernels, interpret)
         else:
             kk, qk = None, ()
             with jax.named_scope("within_chunks"):

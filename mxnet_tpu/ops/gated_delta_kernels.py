@@ -54,32 +54,52 @@ also writes the state every chunk STARTED from ((T / C) x Dk x Dv float32 a
 value head: the size of ``X``); backward reads it, makes the chunk's ``V'``
 and ``A`` again from it, and never runs the walk forward.
 
-**A gate a key channel** (``c`` (B, Hk, G, N, C, Dk): Kimi Delta Attention's
-decay, inside the Gram matrices' sum over the keys). ``channel_grams(q, k, c,
-plan)`` is ``gated_delta._channel_grams``: a forward and a backward kernel
-(``gated_delta_grams_fwd`` / ``_bwd``) form a chunk's strictly lower ``sum_d
-k_id k_jd exp(c_id - c_jd)`` and lower ``sum_d q_id k_jd exp(c_id - c_jd)``
-from three (C, Dk) tiles in VMEM, with no positive exponent and nothing (C, C,
-Dk): the diagonal blocks of 8 tokens a column at a time on the VPU in float32,
-all of a chunk's sub-chunks at once (a sub-chunk is one vector register), and
-what lies below them by LEVELS, s = 8, 16, 32: in each block of 2s tokens the
-rows ``[s, 2s)`` against the columns ``[0, s)`` as one MXU product of two
-operands decayed toward the first of those rows and rounded once, as operands
+**A gate a key channel** (``g`` (B, Hk, G, N, C, Dk): Kimi Delta Attention's
+decay, inside the Gram matrices' sum over the keys) runs six kernels under
+ONE differentiation rule, ``channel_gated(q, k, v, g, beta, plan)``, so that
+nothing that only joins them is computed, summed or cast in ``jax.numpy``.
+
+The two more are the Gram matrices' (``gated_delta_grams_fwd`` / ``_bwd``,
+``gated_delta._channel_grams``): a chunk's strictly lower ``sum_d k_id k_jd
+exp(c_id - c_jd)`` and lower ``sum_d q_id k_jd exp(c_id - c_jd)`` from three
+(C, Dk) tiles in VMEM, with no positive exponent and nothing (C, C, Dk): the
+diagonal blocks of 8 tokens a column at a time on the VPU in float32, all of
+a chunk's sub-chunks at once (a sub-chunk is one vector register), and what
+lies below them by LEVELS, s = 8, 16, 32: in each block of 2s tokens the rows
+``[s, 2s)`` against the columns ``[0, s)`` as one MXU product of two operands
+decayed toward the first of those rows and rounded once, as operands
 (``_channel_grams`` takes seven products of growing width a chunk, a
 reference a sub-chunk; the levels are three products of the whole chunk under
-a mask). Backward makes every decay again from q, k and c. The two matrices
-cross HBM as pairs of chunks side by side, (N / 2, C, 2C) float32 like ``X``
-(32 MiB each a layer of the Kimi-Linear cell: 0.1 ms to write and as much to
-read, against a fusion of six kernels into three that would hold a chunk's
-operands, its inverse and its state's products in one body), and are the
-residuals the other kernels' backward reads. The other four take them as
-given: ``_fwd`` / ``_bwd`` in place of ``(K K^T) * decay``, ``_scan_fwd`` /
-``_scan_bwd`` in place of ``(Q K^T) * decay``, and return their cotangents
-for the Gram kernels' backward; ``e^c``, ``e^(last - c)`` and the state's fade
-``Diag(e^last) S`` are then (C, Dk) blocks and a column a key, and dc is (C,
-Dk) a chunk, elementwise where the scalar gate takes a row sum. A gate a head
-lowers to what it lowered to before a gate a channel had kernels: the branch
-is on the rank of ``c``, at trace.
+a mask). Backward makes every decay again from q, k and c. The other four
+take the matrices as given: ``_fwd`` / ``_bwd`` in place of ``(K K^T) *
+decay``, ``_scan_fwd`` / ``_scan_bwd`` in place of ``(Q K^T) * decay``;
+``e^c``, ``e^(last - c)`` and the state's fade ``Diag(e^last) S`` are then (C,
+Dk) blocks and a column a key, and dc is (C, Dk) a chunk, elementwise where
+the scalar gate takes a row sum.
+
+What crosses HBM between the six, and in which order they run. Forward:
+``_grams_fwd`` reads q, k and ``g`` and takes ``c``, g's running sum from the
+chunk's first token, on the (C, Dk) tile where it is first read (float32
+additions: log2(C) steps of a roll over the sublanes and an add,
+``_running_sum``); it writes ``c`` once, and the two matrices as pairs of
+chunks side by side, (N / 2, C, 2C) float32 like ``X`` (32 MiB each a layer of
+the Kimi-Linear cell). ``_fwd`` reads k, v, ``c``, beta and the first matrix
+and writes ``U``, ``W`` and ``X``; ``_scan_fwd`` reads q, k, ``U``, ``W``,
+``c`` and the second and writes the outputs and a start state a chunk. All
+seven (``c``, both matrices, ``U``, ``W``, ``X``, the states) are the
+residuals, and what the operator names (``registry.keep``). Backward runs
+``_scan_bwd``, then ``_bwd``, then ``_grams_bwd``: the scan's hands on dU,
+dW, the second matrix's cotangent and its shares of dq, dk and dc; ``_bwd``
+takes that dk and dc as operands and writes them back, its own shares added
+in float32 on the tile, into the buffers they came in
+(``input_output_aliases``), with dv, dbeta and the first matrix's cotangent;
+``_grams_bwd`` takes dq, dk and dc the same way, adds the matrices' shares,
+and turns the summed dc into dg by the running sum up the tile's rows, the
+transpose of forward's. dq and dk cross from kernel to kernel in the trunk's
+dtype, rounded as each rule's own result was when ``jax.numpy`` added them;
+dc in float32. A gate a head lowers to what it lowered to before a gate a
+channel had kernels (``within_chunks`` and ``across_chunks``, a rule each,
+its ``cumsum`` outside): the branch is on the rank of ``g``, at trace.
 
 ``plan`` is the one rule that says whether the kernels engage, all of them or
 none, as ``flash_attention.plan`` is attention's; traced kernels are kept by
@@ -141,10 +161,12 @@ def plan(platform, vmem_bytes, dtype, Dk, Dv, group, chunk, T,
     if chunk not in _CHUNKS:
         return None
     rows = _BLOCK * chunk
-    # c and dc a row of the group; a gate a channel: a Gram matrix and its
-    # cotangent too (the Gram kernels hold both matrices, and q, k, dq, dk)
-    gate = 2 * (Dk + chunk) * 4 if channel_gate else 2 * 4
-    within = (2 * rows * Dk * 2                       # k, dk
+    # c and dc a row of the group; a gate a channel: dc as it comes and as
+    # it leaves, a Gram matrix and its cotangent (the Gram kernels hold
+    # both matrices, and q, k, dq, dk), and the dq and dk that come
+    gate = (3 * Dk + 2 * chunk) * 4 if channel_gate else 2 * 4
+    shares = rows * Dk * 2 if channel_gate else 0
+    within = (2 * rows * Dk * 2 + shares              # k, dk
               + group * rows * (2 * Dv * 2 + Dk * 2   # v, dv, dw
                                 + Dv * 4 + chunk * 4  # du, X
                                 + 2 * 4 + gate))      # beta, dbeta
@@ -152,7 +174,7 @@ def plan(platform, vmem_bytes, dtype, Dk, Dv, group, chunk, T,
             + group * rows * (2 * Dv * 4 + 2 * Dk * 2  # u, du, w, dw
                               + Dv * 2 + gate)         # do
             + group * _BLOCK * Dk * Dv * 4)            # a start state a chunk
-    grams = (4 * rows * Dk * 2
+    grams = (4 * rows * Dk * 2 + 2 * shares
              + group * rows * (gate + 2 * chunk * 4)) if channel_gate else 0
     need = 2 * max(within, scan, grams) + group * Dk * Dv * 4  # the states
     if need > vmem_bytes // 2:
@@ -316,11 +338,6 @@ def _cost(k, v, matmuls, passes):
         + heads * C * C * 4)
 
 
-def _given(gram):
-    """A gate a channel's Gram matrix as one more operand, or none."""
-    return () if gram is None else (gram,)
-
-
 def _pairs(x):
     """(..., N, C) -> (..., N / 2, 2C): a row a pair of chunks."""
     return x.reshape(x.shape[:-2] + (x.shape[-2] // 2, 2 * x.shape[-1]))
@@ -356,6 +373,21 @@ def _gram_planes(C):
 def _sub_chunks(x):
     """(C, D) -> (C / _SUB, _SUB, D): a vector register a sub-chunk."""
     return x.reshape(x.shape[0] // _SUB, _SUB, x.shape[1])
+
+
+def _running_sum(x, p, reverse=False):
+    """The inclusive running sum down a tile's rows, (C, D) float32 (up
+    them: ``reverse``, its transpose), float32 additions: log2(C) steps of
+    a roll over the sublanes, a mask and an add."""
+    _, pltpu = _ps._pallas()
+    C = x.shape[0]
+    s = 1
+    while s < C:
+        shift, seen = (C - s, p.token < C - s) if reverse \
+            else (s, p.token >= s)
+        x = x + jnp.where(seen, pltpu.roll(x, shift, 0), 0.0)
+        s *= 2
+    return x
 
 
 def _fade_from(c3, j, p):
@@ -481,24 +513,30 @@ def _gram_cost(k, c, passes):
 
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _grams_fwd(q, k, c, *, chunks, vmem_limit, interpret):
-    """(the decayed ``K K^T``, the decayed ``Q K^T``), (B, Hk, G, N / 2, C,
-    2C) float32 each: a pair of chunks side by side, as ``_fwd`` reads the
-    first and keeps its inverses."""
+def _grams_fwd(q, k, g, *, chunks, vmem_limit, interpret):
+    """(c, the decayed ``K K^T``, the decayed ``Q K^T``) of the log decay
+    ``g`` (B, Hk, G, N, C, Dk) float32: ``c`` its running sum from each
+    chunk's first token, in g's shape, taken on the chunk's tile where it
+    is first read; the matrices (B, Hk, G, N / 2, C, 2C) float32 each, a
+    pair of chunks side by side, as ``_fwd`` reads the first and keeps its
+    inverses."""
     pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = k.shape
-    G = c.shape[2]
+    G = g.shape[2]
 
-    def kernel(q_ref, k_ref, c_ref, kk_ref, qk_ref):
+    def kernel(q_ref, k_ref, g_ref, c_ref, kk_ref, qk_ref):
         p = _gram_planes(C)
 
         def pair(i):
-            for g in range(G):
-                kk, qk = zip(*[_chunk_grams(
-                    q_ref[2 * i + j], k_ref[2 * i + j], c_ref[g, 2 * i + j],
-                    p) for j in range(2)])
-                kk_ref[g, i] = jnp.concatenate(kk, axis=1)
-                qk_ref[g, i] = jnp.concatenate(qk, axis=1)
+            for h in range(G):
+                grams = []
+                for n in (2 * i, 2 * i + 1):
+                    c = _running_sum(g_ref[h, n], p)
+                    c_ref[h, n] = c
+                    grams.append(_chunk_grams(q_ref[n], k_ref[n], c, p))
+                kk, qk = zip(*grams)
+                kk_ref[h, i] = jnp.concatenate(kk, axis=1)
+                qk_ref[h, i] = jnp.concatenate(qk, axis=1)
 
         _for_each(chunks // 2, pair)
 
@@ -506,42 +544,50 @@ def _grams_fwd(q, k, c, *, chunks, vmem_limit, interpret):
     pairs = jax.ShapeDtypeStruct((B, Hk, G, N // 2, C, 2 * C), jnp.float32)
     return pl.pallas_call(
         kernel,
-        out_shape=(pairs, pairs),
+        out_shape=(jax.ShapeDtypeStruct(g.shape, jnp.float32), pairs, pairs),
         grid=(B, Hk, N // chunks),
         in_specs=[s["k"], s["k"], s["w"]],
-        out_specs=[s["x"], s["x"]],
+        out_specs=[s["w"], s["x"], s["x"]],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=vmem_limit),
-        cost_estimate=_gram_cost(k, c, passes=1),
+        cost_estimate=_gram_cost(k, g, passes=1),
         interpret=interpret,
         name="gated_delta_grams_fwd",
-    )(q, k, c)
+    )(q, k, g)
 
 
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _grams_bwd(q, k, c, dkk, dqk, *, chunks, vmem_limit, interpret):
-    """(dq, dk, dc) in the operands' shapes and dtypes."""
+def _grams_bwd(q, k, c, dkk, dqk, dq, dk, dc, *, chunks, vmem_limit,
+               interpret):
+    """(dq, dk, dg) in the operands' shapes and dtypes, the last of
+    backward's kernels: ``dq``, ``dk`` and ``dc`` arrive holding the other
+    kernels' shares (``_scan_bwd``'s and ``_bwd``'s) and leave, in the
+    same buffers, with the matrices' added in float32 on the tile; ``dc``
+    summed leaves as dg, its running sum up the chunk's rows (the
+    transpose of ``_grams_fwd``'s)."""
     pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = k.shape
     G = c.shape[2]
 
-    def kernel(q_ref, k_ref, c_ref, dkk_ref, dqk_ref, dq_ref, dk_ref,
-               dc_ref):
+    def kernel(q_ref, k_ref, c_ref, dkk_ref, dqk_ref, dq_in, dk_in, dc_in,
+               dq_ref, dk_ref, dg_ref):
         p = _gram_planes(C)
 
         def pair(i):
             for j in range(2):
                 n = 2 * i + j
-                dq = jnp.zeros((C, Dk), jnp.float32)
-                dk = jnp.zeros((C, Dk), jnp.float32)
+                dq = dq_in[n].astype(jnp.float32)
+                dk = dk_in[n].astype(jnp.float32)
                 for g in range(G):
-                    dq_g, dk_g, dc_ref[g, n] = _chunk_grams_bwd(
+                    dq_g, dk_g, dc_g = _chunk_grams_bwd(
                         q_ref[n], k_ref[n], c_ref[g, n],
                         dkk_ref[g, i][:, j * C:(j + 1) * C],
                         dqk_ref[g, i][:, j * C:(j + 1) * C], p)
                     dq, dk = dq + dq_g, dk + dk_g
+                    dg_ref[g, n] = _running_sum(dc_in[g, n] + dc_g, p,
+                                                reverse=True)
                 dq_ref[n] = dq.astype(dq_ref.dtype)
                 dk_ref[n] = dk.astype(dk_ref.dtype)
 
@@ -554,28 +600,30 @@ def _grams_bwd(q, k, c, dkk, dqk, *, chunks, vmem_limit, interpret):
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(c.shape, jnp.float32)),
         grid=(B, Hk, N // chunks),
-        in_specs=[s["k"], s["k"], s["w"], s["x"], s["x"]],
+        in_specs=[s["k"], s["k"], s["w"], s["x"], s["x"], s["k"], s["k"],
+                  s["w"]],
         out_specs=[s["k"], s["k"], s["w"]],
+        input_output_aliases={5: 0, 6: 1, 7: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=vmem_limit),
         cost_estimate=_gram_cost(k, c, passes=2),
         interpret=interpret,
         name="gated_delta_grams_bwd",
-    )(q, k, c, dkk, dqk)
+    )(q, k, c, dkk, dqk, dq, dk, dc)
 
 
 # --- forward -----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _fwd(k, v, c, beta, gram=None, *, chunks, vmem_limit, interpret):
+def _fwd(k, v, c, beta, *given, chunks, vmem_limit, interpret):
     """(U, W, X (B, Hk, G, N / 2, C, 2C) float32: the inverses of a pair of
-    chunks side by side). A gate a channel: c (B, Hk, G, N, C, Dk) and
-    ``gram``, its decayed ``K K^T`` (``_grams_fwd``)."""
+    chunks side by side). A gate a channel: c (B, Hk, G, N, C, Dk) and one
+    more operand, ``given`` = (its decayed ``K K^T``: ``_grams_fwd``'s)."""
     pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = k.shape
     G, Dv = v.shape[2], v.shape[-1]
-    channel = gram is not None
+    channel = bool(given)
 
     def kernel(k_ref, v_ref, c_ref, beta_ref, *refs):
         if channel:
@@ -627,36 +675,38 @@ def _fwd(k, v, c, beta, gram=None, *, chunks, vmem_limit, interpret):
         cost_estimate=_cost(k, v, matmuls=10, passes=1),
         interpret=interpret,
         name="gated_delta_chunks_fwd",
-    )(k, v, *((c, _pairs(beta), gram) if channel
+    )(k, v, *((c, _pairs(beta), *given) if channel
               else (_pairs(c), _pairs(beta))))
 
 
 # --- backward ----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _bwd(k, v, c, beta, x, du, dw, gram=None, *, chunks, vmem_limit,
-         interpret):
-    """(dk, dv, dc, dbeta) in the operands' shapes and dtypes; a gate a
-    channel (``gram``: ``_fwd``'s): the cotangent of ``gram`` too, and dk
-    without ``K K^T``'s share, which is the Gram kernels' to give."""
+def _bwd(k, v, c, beta, x, du, dw, *given, chunks, vmem_limit, interpret):
+    """(dk, dv, dc, dbeta) in the operands' shapes and dtypes. A gate a
+    channel gives three more operands, ``given`` = (the Gram matrix
+    ``_fwd`` read; dk, dc: ``_scan_bwd``'s shares), and has that matrix's
+    cotangent too: dk and dc leave in the buffers they came in, with this
+    kernel's shares added in float32 on the tile, dk without ``K K^T``'s,
+    which is the Gram kernels' to add."""
     pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = k.shape
     G, Dv = v.shape[2], v.shape[-1]
-    channel = gram is not None
+    channel = bool(given)
 
     def kernel(k_ref, v_ref, c_ref, beta_ref, x_ref, du_ref, dw_ref, *refs):
         if channel:
-            gram_ref, *refs, dgram_ref = refs
+            gram_ref, dk_in, dc_in, *refs, dgram_ref = refs
         dk_ref, dv_ref, dc_ref, dbeta_ref = refs
         p = _pair_planes(C)
 
         def pair(i):
             if channel:
                 kf = [k_ref[2 * i + j].astype(jnp.float32) for j in range(2)]
+                dk = [dk_in[2 * i + j].astype(jnp.float32) for j in range(2)]
             else:
                 kb, kf, kk = _keys(k_ref, i)
-            dk = [jnp.zeros((C, Dk), jnp.float32) for _ in range(2)]
-            if not channel:
+                dk = [jnp.zeros((C, Dk), jnp.float32) for _ in range(2)]
                 dkk = jnp.zeros((C, 2 * C), jnp.float32)
             at = pl.ds(i, 1)
             for g in range(G):
@@ -687,7 +737,7 @@ def _bwd(k, v, c, beta, x, du, dw, gram=None, *, chunks, vmem_limit,
                         dbeta.append(
                             jnp.sum(dv_rhs * vf, axis=1, keepdims=True)
                             + jnp.sum(key, axis=1, keepdims=True))
-                        dc_ref[g, n] = betas[j] * key
+                        dc_ref[g, n] = dc_in[g, n] + betas[j] * key
                     else:
                         key = jnp.sum(dk_rhs * kf[j], axis=1, keepdims=True)
                         dbeta.append(
@@ -740,20 +790,20 @@ def _bwd(k, v, c, beta, x, du, dw, gram=None, *, chunks, vmem_limit,
                    jax.ShapeDtypeStruct((c if channel else _pairs(c)).shape,
                                         jnp.float32),
                    jax.ShapeDtypeStruct(_pairs(beta).shape, jnp.float32))
-        + ((jax.ShapeDtypeStruct(gram.shape, jnp.float32),) if channel
+        + ((jax.ShapeDtypeStruct(given[0].shape, jnp.float32),) if channel
            else ()),
         grid=(B, Hk, N // chunks),
         in_specs=[s["k"], s["v"], gate, s["vec"], s["x"], s["v"], s["w"]]
-        + [s["x"]] * channel,
+        + [s["x"], s["k"], gate] * channel,
         out_specs=[s["k"], s["v"], gate, s["vec"]] + [s["x"]] * channel,
+        input_output_aliases={8: 0, 9: 2} if channel else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=vmem_limit),
         cost_estimate=_cost(k, v, matmuls=4, passes=2),
         interpret=interpret,
         name="gated_delta_chunks_bwd",
-    )(k, v, c if channel else _pairs(c), _pairs(beta), x, du, dw,
-      *_given(gram))
+    )(k, v, c if channel else _pairs(c), _pairs(beta), x, du, dw, *given)
     return (dk, dv, dc.reshape(c.shape), dbeta.reshape(beta.shape), *dgram)
 
 
@@ -839,18 +889,18 @@ def _scan_cost(q, u, matmuls, passes):
 
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _scan_fwd(q, k, u, w, c, gram=None, *, chunks, vmem_limit, interpret):
+def _scan_fwd(q, k, u, w, c, *given, chunks, vmem_limit, interpret):
     """(the outputs (B, Hk, G, N, C, Dv) in q's dtype, the state every
     chunk STARTED from (B, Hk, G, N, Dk, Dv) float32): ``_chunk_step`` over
     the chunks of a (batch, key head) in order, the group's states in VMEM
     scratch from the first block of chunks to the last. A gate a channel: c
-    (B, Hk, G, N, C, Dk) and ``gram``, its decayed ``Q K^T``
-    (``_grams_fwd``)."""
+    (B, Hk, G, N, C, Dk) and one more operand, ``given`` = (its decayed ``Q
+    K^T``: ``_grams_fwd``'s)."""
     pl, pltpu = _ps._pallas()
     B, Hk, N, C, Dk = q.shape
     G, Dv = u.shape[2], u.shape[-1]
     dt = q.dtype
-    channel = gram is not None
+    channel = bool(given)
 
     def kernel(q_ref, k_ref, u_ref, w_ref, c_ref, *refs):
         if channel:
@@ -903,12 +953,12 @@ def _scan_fwd(q, k, u, w, c, gram=None, *, chunks, vmem_limit, interpret):
         cost_estimate=_scan_cost(q, u, matmuls=4, passes=1),
         interpret=interpret,
         name="gated_delta_scan_fwd",
-    )(q, k, u, w, c, *_given(gram))
+    )(q, k, u, w, c, *given)
 
 
 @functools.partial(jax.jit, static_argnames=("chunks", "vmem_limit",
                                              "interpret"))
-def _scan_bwd(q, k, u, w, c, states, do, gram=None, *, chunks, vmem_limit,
+def _scan_bwd(q, k, u, w, c, states, do, *given, chunks, vmem_limit,
               interpret):
     """(dq, dk, dU, dW, dc) in the operands' shapes and dtypes: the chunks
     of a (batch, key head) from the last to the first, the cotangent of
@@ -924,8 +974,8 @@ def _scan_bwd(q, k, u, w, c, states, do, gram=None, *, chunks, vmem_limit,
               - e^(last - c) (V' dS'^T . K), and at the chunk's last token
               + e^last (dS' . S) + sum(e^(last - c) (V' dS'^T . K))
 
-    A gate a channel (``gram``: ``_scan_fwd``'s) has ``A`` given: dA is a
-    sixth result, the cotangent of ``gram``, and its shares of dq, dk and
+    A gate a channel (``given``: ``_scan_fwd``'s) has ``A`` given: dA is a
+    sixth result, the cotangent of that operand, and its shares of dq, dk and
     dc are the Gram kernels' to give; the sums over the keys in dc's other
     terms are not taken (``.`` is then elementwise, a key at a time)."""
     pl, pltpu = _ps._pallas()
@@ -933,7 +983,7 @@ def _scan_bwd(q, k, u, w, c, states, do, gram=None, *, chunks, vmem_limit,
     G, Dv = u.shape[2], u.shape[-1]
     dt = q.dtype
     blocks = N // chunks
-    channel = gram is not None
+    channel = bool(given)
 
     def kernel(q_ref, k_ref, u_ref, w_ref, c_ref, s_ref, do_ref, *refs):
         if channel:
@@ -1030,7 +1080,7 @@ def _scan_bwd(q, k, u, w, c, states, do, gram=None, *, chunks, vmem_limit,
                    jax.ShapeDtypeStruct(u.shape, u.dtype),
                    jax.ShapeDtypeStruct(w.shape, w.dtype),
                    jax.ShapeDtypeStruct(c.shape, jnp.float32))
-        + ((jax.ShapeDtypeStruct(gram.shape, jnp.float32),) if channel
+        + ((jax.ShapeDtypeStruct(given[0].shape, jnp.float32),) if channel
            else ()),
         grid=(B, Hk, blocks),
         in_specs=[s["k"], s["k"], s["v"], s["w"], gate, s["s"], s["v"]]
@@ -1043,7 +1093,7 @@ def _scan_bwd(q, k, u, w, c, states, do, gram=None, *, chunks, vmem_limit,
         cost_estimate=_scan_cost(q, u, matmuls=10, passes=2),
         interpret=interpret,
         name="gated_delta_scan_bwd",
-    )(q, k, u, w, c, states, do, *_given(gram))
+    )(q, k, u, w, c, states, do, *given)
 
 
 # --- what chunk_gated_delta_rule calls ----------------------------------------
@@ -1052,86 +1102,95 @@ def _static(plan, interpret):
                 interpret=interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def channel_grams(q, k, c, plan, interpret=False):
-    """A gate a key channel's two Gram matrices, the decay inside the sum
-    over the keys (``gated_delta._channel_grams``): q, k (B, Hk, N, C, Dk)
-    and c (B, Hk, G, N, C, Dk) float32 -> the strictly lower ``K K^T`` that
-    ``within_chunks`` reads and the lower ``Q K^T`` that ``across_chunks``
-    reads, (B, Hk, G, N / 2, C, 2C) float32 each, a pair of chunks side by
-    side. Backward keeps the operands and makes every decay again; the
-    matrices themselves are what the other two rules' backward reads, and
-    what the operator names (``registry.keep``)."""
-    return _grams_rule_fwd(q, k, c, plan, interpret)[0]
-
-
-def _grams_rule_fwd(q, k, c, plan, interpret):
-    return keep(_ps._kernel(_grams_fwd, (q, k, c),
-                            **_static(plan, interpret))), (q, k, c)
-
-
-def _grams_rule_bwd(plan, interpret, res, g):
-    return _ps._kernel(_grams_bwd, (*res, *g), **_static(plan, interpret))
-
-
-channel_grams.defvjp(_grams_rule_fwd, _grams_rule_bwd)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def within_chunks(k, v, c, beta, plan, interpret=False, gram=None):
-    """(U, W): the forward kernel at ``plan``'s block. N is a multiple of
-    ``plan.chunks`` (``padded``). ``gram``: None, or with c (B, Hk, G, N, C,
-    Dk) of a gate a channel ``channel_grams``' first. Backward keeps the
+def within_chunks(k, v, c, beta, plan, interpret=False):
+    """(U, W) with a gate a head: the forward kernel at ``plan``'s block. N
+    is a multiple of ``plan.chunks`` (``padded``). Backward keeps the
     operands and the chunks' inverses; under per-operator recomputation
     (``MXNET_BACKWARD_DO_MIRROR``) the inverses, ``U`` and ``W`` are what
     the operator names (``registry.keep``), so that the forward kernel runs
     once a step and not again in backward. ``interpret`` runs the kernels
     in Pallas's interpreter (tests on the CPU)."""
-    return _within_fwd(k, v, c, beta, plan, interpret, gram)[0]
+    return _within_fwd(k, v, c, beta, plan, interpret)[0]
 
 
-def _within_fwd(k, v, c, beta, plan, interpret, gram):
+def _within_fwd(k, v, c, beta, plan, interpret):
     # the scan over chunks reads u and w again in its backward, this
     # rule's backward x: kept under per-operator recomputation
-    u, w, x = keep(_ps._kernel(_fwd, (k, v, c, beta, *_given(gram)),
+    u, w, x = keep(_ps._kernel(_fwd, (k, v, c, beta),
                                 **_static(plan, interpret)))
-    return (u, w), (k, v, c, beta, x, gram)
+    return (u, w), (k, v, c, beta, x)
 
 
 def _within_bwd(plan, interpret, res, g):
-    *res, gram = res
-    grads = _ps._kernel(_bwd, (*res, *g, *_given(gram)),
-                        **_static(plan, interpret))
-    return grads if gram is not None else (*grads, None)
+    return _ps._kernel(_bwd, (*res, *g), **_static(plan, interpret))
 
 
 within_chunks.defvjp(_within_fwd, _within_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def across_chunks(q, k, u, w, c, plan, interpret=False, gram=None):
-    """The outputs (B, Hk, G, N, C, Dv) of the scan over chunks of q, k (B,
-    Hk, N, C, Dk), ``within_chunks``' ``U`` and ``W`` and c (B, Hk, G, N, C)
-    float32 (a gate a channel: (B, Hk, G, N, C, Dk), and ``gram`` is
-    ``channel_grams``' second; else None), the state 0 before a row's first
-    chunk: the forward kernel at ``plan``'s block. Backward keeps the
-    operands and the state every chunk started from, which the operator
-    names (``registry.keep``): under per-operator recomputation the forward
+def across_chunks(q, k, u, w, c, plan, interpret=False):
+    """The outputs (B, Hk, G, N, C, Dv) of the scan over chunks with a gate
+    a head: q, k (B, Hk, N, C, Dk), ``within_chunks``' ``U`` and ``W`` and c
+    (B, Hk, G, N, C) float32, the state 0 before a row's first chunk: the
+    forward kernel at ``plan``'s block. Backward keeps the operands and the
+    state every chunk started from, which the operator names
+    (``registry.keep``): under per-operator recomputation the forward
     kernel runs once a step."""
-    return _across_fwd(q, k, u, w, c, plan, interpret, gram)[0]
+    return _across_fwd(q, k, u, w, c, plan, interpret)[0]
 
 
-def _across_fwd(q, k, u, w, c, plan, interpret, gram):
-    out, states = _ps._kernel(_scan_fwd, (q, k, u, w, c, *_given(gram)),
+def _across_fwd(q, k, u, w, c, plan, interpret):
+    out, states = _ps._kernel(_scan_fwd, (q, k, u, w, c),
                                **_static(plan, interpret))
-    return out, (q, k, u, w, c, keep(states), gram)
+    return out, (q, k, u, w, c, keep(states))
 
 
 def _across_bwd(plan, interpret, res, g):
-    *res, gram = res
-    grads = _ps._kernel(_scan_bwd, (*res, g, *_given(gram)),
-                        **_static(plan, interpret))
-    return grads if gram is not None else (*grads, None)
+    return _ps._kernel(_scan_bwd, (*res, g), **_static(plan, interpret))
 
 
 across_chunks.defvjp(_across_fwd, _across_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def channel_gated(q, k, v, g, beta, plan, interpret=False):
+    """The outputs (B, Hk, G, N, C, Dv) with a gate a key channel, all six
+    kernels under this one rule: q, k (B, Hk, N, C, Dk), v (B, Hk, G, N, C,
+    Dv), the log decay g (B, Hk, G, N, C, Dk) and beta (B, Hk, G, N, C),
+    both float32; N a multiple of ``plan.chunks`` (``padded``). Forward
+    runs ``_grams_fwd`` (which takes g's running sum ``c``), ``_fwd`` and
+    ``_scan_fwd``. Backward keeps the operands and what the kernels made
+    of them, ``c``, the two Gram matrices, ``U``, ``W``, the chunks'
+    inverses and the state every chunk started from: all the operator
+    names (``registry.keep``), so that under per-operator recomputation
+    (``MXNET_BACKWARD_DO_MIRROR``) no forward kernel runs again. It runs
+    ``_scan_bwd``, ``_bwd`` and ``_grams_bwd`` in that order, each handed
+    the dq, dk and dc of those before it to add its own to on the tile,
+    and the last turns dc into dg: no cotangent is summed, and no running
+    sum taken, outside a kernel."""
+    return _channel_fwd(q, k, v, g, beta, plan, interpret)[0]
+
+
+def _channel_fwd(q, k, v, g, beta, plan, interpret):
+    static = _static(plan, interpret)
+    c, kk, qk = keep(_ps._kernel(_grams_fwd, (q, k, g), **static))
+    u, w, x = keep(_ps._kernel(_fwd, (k, v, c, beta, kk), **static))
+    out, states = _ps._kernel(_scan_fwd, (q, k, u, w, c, qk), **static)
+    return out, (q, k, v, beta, c, kk, qk, u, w, x, keep(states))
+
+
+def _channel_bwd(plan, interpret, res, do):
+    q, k, v, beta, c, kk, qk, u, w, x, states = res
+    static = _static(plan, interpret)
+    dq, dk, du, dw, dc, dqk = _ps._kernel(
+        _scan_bwd, (q, k, u, w, c, states, do, qk), **static)
+    dk, dv, dc, dbeta, dkk = _ps._kernel(
+        _bwd, (k, v, c, beta, x, du, dw, kk, dk, dc), **static)
+    dq, dk, dg = _ps._kernel(
+        _grams_bwd, (q, k, c, dkk, dqk, dq, dk, dc), **static)
+    return dq, dk, dv, dg, dbeta
+
+
+channel_gated.defvjp(_channel_fwd, _channel_bwd)

@@ -34,6 +34,23 @@ jax.config.update("jax_platforms", "cpu")
 
 _DIST_PROBE = None  # None = not probed yet; True/False = cached verdict
 
+# Seconds a test file took in the driver's tier-1 run on PR 49's tree (its
+# junit times summed a file; every file over 100 s). ``--dist loadfile``
+# hands files to the workers in collection order, and alphabetical order
+# starts the heaviest last: six workers ended at 1448 s where their 7024 s of
+# tests, evenly loaded, are 1171. Heaviest first, a file's own items together
+# and in their order; a file not named here keeps its place after them.
+_FILE_SECONDS = {
+    "test_qwen3_next.py": 714, "test_kimi_linear.py": 702,
+    "test_trinity.py": 666, "test_zaya.py": 556, "test_bench_smoke.py": 523,
+    "test_kanana2.py": 389, "test_causal_conv_kernels.py": 285,
+    "test_recompute_residuals.py": 274, "test_olmoe.py": 209,
+    "test_moe_routing.py": 194, "test_gated_delta_kernels.py": 189,
+    "test_gated_delta_channel.py": 166, "test_flash_attention.py": 164,
+    "test_model_zoo.py": 136, "test_elastic_checkpoint.py": 130,
+    "test_grouped_matmul.py": 115,
+}
+
 
 def _dist_collectives_supported():
     """Probe (once per session): can this backend execute a CROSS-PROCESS
@@ -84,7 +101,8 @@ def pytest_collection_modifyitems(config, items):
     @pytest.mark.aot_serialization when compiled executables cannot
     serialize (probed via mxnet_tpu.aot), @pytest.mark.dist_multiprocess
     when cross-process collectives cannot execute (probed via a 2-rank
-    launch)."""
+    launch). Then the files that take longest go first (``_FILE_SECONDS``):
+    a stable sort, the same in every xdist worker."""
     import pytest
 
     marked = [item for item in items
@@ -106,6 +124,8 @@ def pytest_collection_modifyitems(config, items):
                    "(XLA:CPU); probed via a 2-rank dist_sync allreduce")
         for item in dist_marked:
             item.add_marker(skip)
+
+    items.sort(key=lambda item: -_FILE_SECONDS.get(item.path.name, 0))
 
 
 @pytest.fixture(autouse=True)

@@ -44,16 +44,32 @@ def _strong(t):
     return q, k, v, jnp.where(np.arange(D) % 2 == 0, -1.6, g), beta
 
 
+T_PADDED = 200      # of STEP's 128 tokens a grid step: a tail of 56 padded
+
 CASES = {
     "float32_two_steps": lambda: inputs(256, B=1, Hk=1, group=2, D=D),
     "float32_strongest_decays": lambda: _strong(256),
+    "float32_padded_tail": lambda: inputs(T_PADDED, B=1, Hk=1, group=2, D=D),
 }
+
+
+def _padded(args):
+    """The operands as ``chunk_gated_delta_rule`` pads them to STEP's whole
+    grid steps: tokens that write nothing (beta 0) and fade nothing (g 0)."""
+    import jax.numpy as jnp
+
+    pad = gk.padded(args[0].shape[2], CHUNK, STEP) - args[0].shape[2]
+    return tuple(jnp.pad(x, ((0, 0), (0, 0), (0, pad))
+                         + ((0, 0),) * (x.ndim - 3)) for x in args)
 
 
 @functools.lru_cache(maxsize=None)
 def _kernels_and_form(case):
     """{tensor: (through the kernels, the ``jax.numpy`` form)}: outputs and
-    all five gradients under one random cotangent."""
+    all five gradients under one random cotangent; the running sum ``c``
+    that the Gram matrices' forward kernel takes of ``g`` on its tile
+    against ``jnp.cumsum``; and of a case with a padded tail the gate's
+    gradient there, from the same operands padded by hand, against 0."""
     import jax
     import jax.numpy as jnp
 
@@ -61,26 +77,47 @@ def _kernels_and_form(case):
     head = jnp.asarray(np.random.RandomState(4).randn(*args[2].shape),
                        args[2].dtype)
 
-    def both(**kw):
+    def both(args, head, **kw):
         out, vjp = jax.vjp(functools.partial(
             gd.chunk_gated_delta_rule, chunk=CHUNK, **kw), *args)
         return (out,) + vjp(head)
 
-    got, want = both(kernels=STEP, interpret=True), both()
+    got = both(args, head, kernels=STEP, interpret=True)
+    tensors = dict(zip(TENSORS, zip(got, both(args, head))))
+    q, k, v, g, beta = _padded(args)
+    B, Hk, T, _ = q.shape
+    chunks = (B, Hk, T // CHUNK, CHUNK, D)
+    g = g.reshape(B, Hk, -1, T // CHUNK, CHUNK, D)
+    tensors["c"] = (gk._grams_fwd(
+        q.reshape(chunks), k.reshape(chunks), g, chunks=STEP.chunks,
+        vmem_limit=STEP.vmem_limit, interpret=True)[0], jnp.cumsum(g, axis=4))
+    if T != args[0].shape[2]:
+        tail = both(_padded(args), _padded((head,))[0], kernels=STEP,
+                    interpret=True)[4][:, :, args[0].shape[2]:]
+        tensors["dg_of_the_padded_tail"] = (tail, jnp.zeros_like(tail))
     return {n: (np.asarray(a, np.float32), np.asarray(b, np.float32))
-            for n, a, b in zip(TENSORS, got, want)}
+            for n, (a, b) in tensors.items()}
 
 
-@pytest.mark.parametrize("tensor", TENSORS)
-@pytest.mark.parametrize("case", sorted(CASES))
+# ``c``: float32 sums in another order; a padded tail's ``dg``: exact zeros
+TOLERANCES = {"c": 1e-6, "dg_of_the_padded_tail": 0.0}
+
+
+@pytest.mark.parametrize("case,tensor", [
+    (case, tensor) for case in sorted(CASES)
+    for tensor in TENSORS + ["c"] + ["dg_of_the_padded_tail"]
+    * case.endswith("padded_tail")])
 def test_kernels_match_the_jax_numpy_form(case, tensor):
     """``dg`` a channel is held to what ``test_gated_delta_kernels.py``
     holds the scalar gate's to; no inf and no nan at decays that a positive
-    exponent anywhere would overflow."""
+    exponent anywhere would overflow. All six kernels stand under one rule
+    (``gated_delta_kernels.channel_gated``): the gradients are what its
+    backward hands from kernel to kernel and sums on the tile. (A bfloat16
+    trunk with a padded tail: ``test_gated_delta_channel.py``.)"""
     got, want = _kernels_and_form(case)[tensor]
     assert got.shape == want.shape
     assert np.isfinite(got).all()
-    assert rel(got, want) < 2e-5
+    assert rel(got, want) <= TOLERANCES.get(tensor, 2e-5)
 
 
 def test_the_six_kernels_and_their_precision():
@@ -126,25 +163,59 @@ def test_the_six_kernels_and_their_precision():
             assert products == 2 * 3 * (1 if name.endswith("fwd") else 4)
 
 
-def test_backward_keeps_the_gram_matrices_and_a_state_a_chunk():
+@pytest.mark.parametrize("gate", ["a_channel", "a_channel_recomputed",
+                                  "a_head_recomputed"])
+def test_backward_keeps_the_gram_matrices_and_a_state_a_chunk(gate):
     """What backward keeps with the kernels on: the operands, ``U``, ``W``,
     the chunks' inverses and the two Gram matrices (T x chunk a head each,
     pairs of chunks side by side) and one state a chunk; nothing (chunk,
-    chunk, Dk)."""
+    chunk, Dk). ``recomputed``: under the executor's per-operator
+    ``jax.checkpoint`` with its policy (``MXNET_BACKWARD_DO_MIRROR=1``) what
+    the one rule of a gate a channel names is what the three rules named,
+    ``c`` (the Gram kernel's own result now), both Gram matrices, ``U``,
+    ``W``, the inverses and the states, and nothing else is saved; a gate
+    a head names what it named, with its four kernels and its ``cumsum``
+    outside them."""
     import jax
     import jax.numpy as jnp
+    from jax._src.ad_checkpoint import saved_residuals
+    from test_qwen3_next import _eqns
+
+    from mxnet_tpu.ops import registry
 
     t = 256
-    args = CASES["float32_two_steps"]()
-    _, vjp = jax.vjp(functools.partial(
-        gd.chunk_gated_delta_rule, chunk=CHUNK, kernels=STEP,
-        interpret=True), *args)
-    kept = [x for x in jax.tree.leaves(vjp) if hasattr(x, "shape")]
+    q, k, v, g, beta = CASES["float32_two_steps"]()
+    if gate.startswith("a_head"):
+        g = g[..., 0]
+    rule = functools.partial(gd.chunk_gated_delta_rule, chunk=CHUNK,
+                             kernels=STEP, interpret=True)
     pairs = (1, 1, 2, t // CHUNK // 2, CHUNK, 2 * CHUNK)
-    assert [x.dtype for x in kept if x.shape == pairs] == [jnp.float32] * 3
-    states = [x for x in kept if x.shape[-2:] == (D, D)]
-    assert [x.shape for x in states] == [(1, 1, 2, t // CHUNK, D, D)]
-    assert max(x.size for x in kept if x is not states[0]) <= 2 * t * D
+    states = (1, 1, 2, t // CHUNK, D, D)
+    wide = (1, 1, 2, t // CHUNK, CHUNK, D)
+    if gate == "a_channel":
+        _, vjp = jax.vjp(rule, q, k, v, g, beta)
+        kept = [x for x in jax.tree.leaves(vjp) if hasattr(x, "shape")]
+        assert [x.dtype for x in kept if x.shape == pairs] == [jnp.float32] * 3
+        assert [x.shape for x in kept if x.shape[-2:] == (D, D)] == [states]
+        assert max(x.size for x in kept if x.shape != states) <= 2 * t * D
+        return
+    named = sorted(
+        aval.shape for aval, why in saved_residuals(jax.checkpoint(
+            rule, policy=registry.KeptResiduals()), q, k, v, g, beta)
+        if "from the argument" not in why)
+    channel = gate.startswith("a_channel")
+    # the inverses (and the two matrices), U and W (and c), the states
+    assert named == sorted([pairs] * (3 if channel else 1)
+                           + [wide] * (3 if channel else 2) + [states])
+    eqns = list(_eqns(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(rule(*a)), (0, 1, 2, 3, 4)))(
+            q, k, v, g, beta).jaxpr))
+    halves = ["chunks", "scan"] + ["grams"] * channel
+    assert sorted(e.params["name"] for e in eqns
+                  if e.primitive.name == "pallas_call") == sorted(
+        f"gated_delta_{half}_{way}" for half in halves
+        for way in ("fwd", "bwd"))
+    assert sum(e.primitive.name == "cumsum" for e in eqns) == 2 * (not channel)
 
 
 @pytest.mark.parametrize("mirror", ["0", "1"])

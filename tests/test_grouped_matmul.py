@@ -10,6 +10,7 @@ chip: a compile that passes is not a chip run).
 import functools
 import importlib.util
 import os
+import re
 
 import numpy as np
 import pytest
@@ -502,7 +503,6 @@ def test_latent_attention_kernels_compile_for_a_v5e_at_the_cell_widths(
     among the temporaries, which are q, k, dq and dk as the concatenations
     write and read them (a minor dimension of 192 is stored in 256 lanes)."""
     import importlib
-    import re
 
     import jax
     import jax.numpy as jnp
@@ -631,7 +631,8 @@ def test_channel_gated_delta_kernels_compile_for_a_v5e_at_the_cell_widths(
     block and VMEM limit; the temporaries are of the order of the kept
     residuals (``U``, ``W``, three (T x 64) float32 a head and a state a
     chunk: 0.35 GiB) and not the ``jax.numpy`` form's 1.5 GiB (their other
-    tests are in ``test_gated_delta_channel_kernels.py``)."""
+    tests are in ``test_gated_delta_channel_kernels.py``). Between the
+    kernels XLA only reshapes: no running sum and no sum of cotangents."""
     import jax
     import jax.numpy as jnp
 
@@ -650,14 +651,23 @@ def test_channel_gated_delta_kernels_compile_for_a_v5e_at_the_cell_widths(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     wide = arg((b, h, t, d), jnp.bfloat16)
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         wide, wide, wide, arg((b, h, t, d), jnp.float32),
-        arg((b, h, t), jnp.float32)).compile()
+        arg((b, h, t), jnp.float32))
+    # one rule over the six: the gate's running sum, its transpose and the
+    # sums of the kernels' dc, dq and dk are taken on the kernels' tiles,
+    # so the program around them holds no reduce-window and adds nothing
+    # of a chunked operand's shape
+    joins = lowered.as_text()
+    assert "reduce_window" not in joins and "cumsum" not in joins
+    assert not re.findall(
+        rf"stablehlo\.add.*tensor<{b}x{h}(x1)?x{t // c}x{c}x{d}x", joins)
+    compiled = lowered.compile()
     text = compiled.as_text()
     for kernel in ("grams", "chunks", "scan"):
         for way in ("fwd", "bwd"):
             assert f"gated_delta_{kernel}_{way}" in text
-    assert "while" not in text
+    assert "while" not in text and "reduce-window" not in text
     assert compiled.memory_analysis().temp_size_in_bytes <= 640 << 20
 
 
