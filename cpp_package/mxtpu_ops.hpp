@@ -260,6 +260,15 @@ inline Symbol LRN(const std::string& symbol_name, const Symbol& data, int64_t ns
   return detail::CreateOp("LRN", symbol_name, 1, keys__, ins__, params__);
 }
 
+inline Symbol LayerNorm(const std::string& symbol_name, const Symbol& data, const Symbol* gamma = nullptr, const Symbol* beta = nullptr, int64_t axis = -1, double eps = 1e-05) {
+  detail::ParamMap params__;
+  params__.emplace_back("axis", detail::str(axis));
+  params__.emplace_back("eps", detail::str(eps));
+  const char* keys__[] = {"data", "gamma", "beta"};
+  const Symbol* ins__[] = {&data, gamma, beta};
+  return detail::CreateOp("LayerNorm", symbol_name, 3, keys__, ins__, params__);
+}
+
 inline Symbol LeakyReLU(const std::string& symbol_name, const Symbol& data, const std::string& act_type = "leaky", double lower_bound = 0.125, double slope = 0.25, double upper_bound = 0.334) {
   detail::ParamMap params__;
   params__.emplace_back("act_type", act_type);
@@ -460,12 +469,14 @@ inline Symbol Reshape(const std::string& symbol_name, const Symbol& data, const 
   return detail::CreateOp("Reshape", symbol_name, 1, keys__, ins__, params__);
 }
 
-inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query, const Symbol* key = nullptr, const Symbol* value = nullptr, const std::string& axis_name = "sp", const std::string& batch_axis = "", bool causal = false, double scale = -1.0, int64_t window = 0) {
+inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query, const Symbol* key = nullptr, const Symbol* value = nullptr, const std::string& axis_name = "sp", const std::string& batch_axis = "", bool causal = false, double index_loss_coef = 0.0, double scale = -1.0, int64_t select_top_k = 0, int64_t window = 0) {
   detail::ParamMap params__;
   params__.emplace_back("axis_name", axis_name);
   if (!batch_axis.empty()) params__.emplace_back("batch_axis", batch_axis);
   params__.emplace_back("causal", detail::str(causal));
+  params__.emplace_back("index_loss_coef", detail::str(index_loss_coef));
   params__.emplace_back("scale", detail::str(scale));
+  params__.emplace_back("select_top_k", detail::str(select_top_k));
   params__.emplace_back("window", detail::str(window));
   const char* keys__[] = {"query", "key", "value"};
   const Symbol* ins__[] = {&query, key, value};

@@ -3,8 +3,9 @@
 VGG-16 (SSD backbone), Inception-BN, DCGAN generator/discriminator, the
 bucketed LSTM language model and the OLMoE, AFMoE (Trinity), Qwen3-Next,
 DeepSeek-V3 (latent attention), ZAYA1 (compressed convolutional attention,
-an MLP router) and Kimi Linear (a delta rule gated a key channel, latent
-attention without positions) sparse-expert decoders.
+an MLP router), Kimi Linear (a delta rule gated a key channel, latent
+attention without positions) and Keye-VL-2.0 (the text decoder: attention over
+the keys a learned indexer selects) sparse-expert decoders.
 
 Reference: ``example/image-classification/symbols/*.py`` and
 ``example/rnn``/``example/gan``. Builders return plain Symbols usable with
@@ -30,6 +31,7 @@ from .qwen3_next import qwen3_next_sym_gen
 from .deepseek_v3 import deepseek_v3_sym_gen
 from .zaya import zaya_sym_gen
 from .kimi_linear import kimi_linear_sym_gen
+from .keye_vl2 import keye_vl2_sym_gen
 from . import ssd
 from . import zoo
 from .zoo import SCORE_SYMBOLS

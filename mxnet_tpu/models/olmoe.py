@@ -14,10 +14,13 @@ def linear(x, width, name):
                               flatten=False, name=name)
 
 
-def split_heads(x, heads, head_dim):
-    """(B, T, heads * D) -> (B, heads, T, D), the layout of RingAttention."""
+def split_heads(x, heads, head_dim, norm=None):
+    """(B, T, heads * D) -> (B, heads, T, D), the layout of RingAttention.
+    ``norm``: applied to (B, T, heads, D) first, so over the D of each
+    head with one gain for all of them (the per-head q/k norm of the
+    AFMoE and Qwen3-MoE families)."""
     x = sym.Reshape(x, shape=(0, 0, heads, head_dim))
-    return sym.transpose(x, axes=(0, 2, 1, 3))
+    return sym.transpose(norm(x) if norm else x, axes=(0, 2, 1, 3))
 
 
 def merge_heads(a):

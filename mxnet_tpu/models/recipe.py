@@ -145,7 +145,8 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
     """Analytic forward FLOPs **per sample** for ``symbol``.
 
     Counts Convolution, Deconvolution, FullyConnected, the fused RNN op,
-    RingAttention (a causal one at half its scores), GatedDeltaRule (its
+    RingAttention (a causal one at half its scores; under ``select_top_k``
+    the pairs each query keeps and the pairs its indexer scores), GatedDeltaRule (its
     recurrent form: read, write and query of a keys x values state a token
     and value head), CausalConv1D (``kernel`` taps a channel, times the
     channels of a group where it mixes them) and MoE (the router, where it
@@ -204,7 +205,20 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
             # a causal row sees half the keys
             q = _node_shape(shape_dict, nodes, node["inputs"][0])
             v = _node_shape(shape_dict, nodes, node["inputs"][2])
-            if q and v:
+            top_k = int(attrs.get("select_top_k", 0))
+            if q and v and top_k > 0:
+                # a selection: query t keeps min(t + 1, top_k) keys, and the
+                # indexer (index_query (B, J, T, Di), one key head) scores
+                # every earlier one
+                t, kept = int(q[2]), min(top_k, int(q[2]))
+                iq = _node_shape(shape_dict, nodes, node["inputs"][3])
+                total += _prod(q[:2]) * (
+                    kept * (kept + 1) // 2 + (t - kept) * kept) * (
+                        int(q[3]) + int(v[3])) / batch
+                if iq:
+                    total += _prod(iq[:2]) * (t * (t + 1) // 2) * int(
+                        iq[3]) / batch
+            elif q and v:
                 seen = 0.5 if parse_bool(attrs.get("causal", False)) else 1.0
                 total += seen * _prod(q[:3]) * int(q[2]) * (
                     int(q[3]) + int(v[3])) / batch

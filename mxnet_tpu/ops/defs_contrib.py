@@ -583,17 +583,37 @@ def _ring_attention_op(ins, params, mode):
     with Hkv dividing H, query head n reading key/value head
     ``n // (H / Hkv)`` with no repeated copy. The ring path refuses both
     by name.
+
+    ``select_top_k`` K > 0 (causal, no window, one device): three further
+    inputs, ``index_query`` (B, J, T, Di), ``index_key`` (B, 1, T, Di) and
+    ``index_weight`` (B, J, T), an indexer (DeepSeek-V3.2's): query ``t``
+    keeps the ``min(K, t + 1)`` earlier positions of largest ``I[t, s] =
+    sum_j index_weight[j, t] relu(index_query[j, t] . index_key[s])``
+    (float32; scores equal to the K-th are all kept, so a row whose K-th and
+    next scores are equal keeps more than K) and the softmax runs
+    over those alone (``ring_attention.selected_attention``: the
+    ``jax.numpy`` blocks; the kernels' rule answers None for a selection
+    and the ring refuses it by name). The choice passes no gradient. The
+    index inputs learn from a term of their own that backward attaches, as
+    ``MoE`` attaches its router's: ``index_loss_coef`` x the sum over rows
+    of ``KL(P || softmax_S(I))``, ``P`` the mean over the query heads of
+    the kept keys' probabilities, a constant; query, key and value never
+    see it. Where K >= T the output is the causal attention's bit for bit.
     """
     from ..parallel.mesh import current_mesh
     from ..parallel.ring_attention import ring_attention_traced
 
-    q, k, v = ins
+    q, k, v = ins[:3]
     scale = params["scale"] if params["scale"] > 0 else None
+    select = None
+    if params["select_top_k"] > 0:
+        select = tuple(ins[3:6]) + (params["select_top_k"],
+                                    params["index_loss_coef"])
     return ring_attention_traced(
         q, k, v, current_mesh(), axis=params["axis_name"],
         causal=params["causal"], scale=scale,
         batch_axis=params["batch_axis"] or None, window=params["window"],
-        platform=mode.platform,
+        platform=mode.platform, select=select,
     ).astype(q.dtype)
 
 
@@ -609,23 +629,42 @@ def _ring_attention_counts(ins, outs, params, platform):
     128); and the lanes a pair is computed over: the width its score
     contracts over plus the width ``p.v`` writes, as either path is handed
     them (neither pads a width: 192 over 128 are 320; a model that padded
-    its keys to 256 would hand over 384)."""
+    its keys to 256 would hand over 384). Under a selection
+    (``select_top_k``) also: the pairs the softmax keeps, query ``t`` its
+    ``min(t + 1, K)``, x heads x batch; the pairs the indexer scores, its
+    heads x the causal triangle x batch; and the scored pairs are those of
+    the selected walk's tiles (``ring_attention.selected_scored_pairs``),
+    so the gap between scored and selected pairs is what is computed and
+    masked away."""
     from ..parallel.ring_attention import (block_q_of, kernel_plan,
-                                           scored_pairs)
+                                           scored_pairs, select_block_q,
+                                           selected_scored_pairs)
     from . import flash_attention
 
-    q, k, v = ins
+    q, k, v = ins[:3]
     causal, window = params["causal"], params["window"]
+    top_k = params["select_top_k"]
     batch, heads, T, key_dim = q.shape
     kernels = kernel_plan(q.dtype, q.shape, k.shape[1], causal, window,
-                          platform, v.shape[-1])
+                          platform, v.shape[-1], top_k)
     if kernels is not None:
         pairs = flash_attention.scored_pairs(T, kernels.bq, kernels.bk,
                                              causal, window)
+    elif top_k > 0:
+        pairs = selected_scored_pairs(T, select_block_q(batch, heads, T),
+                                      top_k)
     else:
         pairs = scored_pairs(T, causal, window,
                              block_q_of(batch, heads, T, window))
-    return {"executor.attention_layers": 1,
+    kept = min(top_k, T)
+    selected = {} if top_k <= 0 else {
+        "executor.attention_selected_layers": 1,
+        "executor.attention_selected_pairs":
+            batch * heads * (kept * (kept + 1) // 2 + (T - kept) * kept),
+        "executor.attention_index_pairs":
+            batch * ins[3].shape[1] * (T * (T + 1) // 2)}
+    return {**selected,
+            "executor.attention_layers": 1,
             "executor.attention_window_layers": int(bool(window)),
             "executor.attention_kernel_layers": int(kernels is not None),
             "executor.attention_scored_pairs": batch * heads * pairs,
@@ -637,13 +676,20 @@ def _ring_attention_counts(ins, outs, params, platform):
 register(
     "RingAttention",
     _ring_attention_op,
-    arg_names=["query", "key", "value"],
+    arg_names=lambda p: ["query", "key", "value"] + [
+        "index_query", "index_key", "index_weight"
+    ] * (p["select_top_k"] > 0),
     param_schema={
         "causal": Param(parse_bool, False),
         "axis_name": Param(parse_str, "sp"),
         "batch_axis": Param(parse_str, ""),  # dp axis on combined meshes
         "scale": Param(parse_float, -1.0),  # <=0: 1/sqrt(head_dim)
         "window": Param(parse_int, 0),  # keys a query reads; 0: all before
+        # keys a query keeps of those before it, chosen by the indexer's
+        # three further inputs; 0: no selection and no such inputs
+        "select_top_k": Param(parse_int, 0),
+        # weighs the indexer's own term, attached in backward
+        "index_loss_coef": Param(parse_float, 0.0),
     },
     aliases=("_contrib_RingAttention",),
     launch_counts=_ring_attention_counts,
@@ -652,5 +698,8 @@ register(
                         "executor.attention_kernel_layers",
                         "executor.attention_scored_pairs",
                         "executor.attention_latent_layers",
-                        "executor.attention_pair_lanes"),
+                        "executor.attention_pair_lanes",
+                        "executor.attention_selected_layers",
+                        "executor.attention_selected_pairs",
+                        "executor.attention_index_pairs"),
 )

@@ -60,6 +60,40 @@ register(
 )
 
 
+# --- LayerNorm -------------------------------------------------------------
+def _layer_norm(ins, params, mode):
+    """``(x - mean) * rsqrt(var + eps) * gamma + beta`` over ``axis``
+    (MXNet's later ``LayerNorm``: ``axis``, ``eps``, a gain and a bias of
+    that axis' length); the statistics in float32 as ``RMSNorm``'s."""
+    x, gamma, beta = ins
+    axis = params["axis"] % x.ndim
+    along = [1] * x.ndim
+    along[axis] = x.shape[axis]
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=axis, keepdims=True)
+    centred = xf - mean
+    var = jnp.mean(centred * centred, axis=axis, keepdims=True)
+    out = centred * jax.lax.rsqrt(var + params["eps"]) \
+        * gamma.astype(jnp.float32).reshape(along) \
+        + beta.astype(jnp.float32).reshape(along)
+    return out.astype(x.dtype)
+
+
+def _layer_norm_fill(shapes, p):
+    width = shapes[0] and (shapes[0][p["axis"] % len(shapes[0])],)
+    return [shapes[0], shapes[1] or width, shapes[2] or width]
+
+
+register(
+    "LayerNorm",
+    _layer_norm,
+    arg_names=["data", "gamma", "beta"],
+    param_schema={"axis": Param(parse_int, -1),
+                  "eps": Param(parse_float, 1e-5)},
+    fill_in_shapes=_layer_norm_fill,
+)
+
+
 # --- RotaryEmbedding -------------------------------------------------------
 def _rotary(ins, params, mode):
     """Rotary position embedding of ``data`` (..., T, D): pair ``i`` of

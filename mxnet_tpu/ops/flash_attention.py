@@ -91,7 +91,7 @@ class Plan(NamedTuple):
 
 
 def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
-         window=0, value_dim=None) -> Optional[Plan]:
+         window=0, value_dim=None, select_top_k=0) -> Optional[Plan]:
     """The rule. ``D`` is the width of queries and keys, ``value_dim`` that
     of the values and the output (None: ``D``). The kernels engage where
     the program is lowered for one TPU whose VMEM is known, the operands
@@ -109,8 +109,11 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
     whose tile over the group (any group: 7 query heads a key/value head
     are 7 x 256 rows) has at most ``_ROWS`` rows, else the narrowest; if
     that does not fit the VMEM, the next narrower query blocks.
+    A selection (``select_top_k``: each query keeps its own keys, chosen
+    from scores computed in the program) is no visit list of whole key
+    blocks: the kernels do not take one.
     None = the ``jax.numpy`` blocks."""
-    if platform != "tpu" or not vmem_bytes:
+    if platform != "tpu" or not vmem_bytes or select_top_k:
         return None
     value_dim = value_dim or D
     if jnp.dtype(dtype) != jnp.bfloat16 or heads % kv_heads:

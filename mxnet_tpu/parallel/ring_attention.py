@@ -27,6 +27,13 @@ Two widths: queries and keys (B, H, T, Dk) are scored over Dk, values (B,
 Hkv, T, Dv) are weighed into an output (B, H, T, Dv). They are equal in
 most models; a latent-attention head (DeepSeek-V3's) has keys of 192 = 128
 + 64 rotated and values of 128. Every path here takes both, unpadded.
+
+A selection (:func:`selected_attention`; DeepSeek-V3.2's sparse attention):
+a small indexer scores every earlier token for every query, each query
+keeps its ``top_k`` best and the softmax runs over those alone. The mask is
+then computed in the program, a row at a time, and differs for every query:
+only the ``jax.numpy`` blocks of the one-device path take it; the ring and
+the kernels refuse it by name.
 """
 
 from __future__ import annotations
@@ -49,7 +56,8 @@ def _softmax_block(q, k_blk, v_blk, mask, scale, o, m, l):
     row maximum ``m`` and normaliser ``l`` (B, H, Tq), all float32.
 
     float32 operands multiply at HIGHEST precision; bfloat16 operands take
-    the MXU's native passes and accumulate in float32. ``mask`` (Tq, Tk)
+    the MXU's native passes and accumulate in float32. ``mask`` (Tq, Tk),
+    or (B, 1, Tq, Tk) where it differs by row of the batch (a selection),
     is True where a query may see a key, or None.
     """
     from ..ops.defs_tensor import matmul_precision
@@ -58,7 +66,7 @@ def _softmax_block(q, k_blk, v_blk, mask, scale, o, m, l):
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k_blk, precision=prec,
                    preferred_element_type=jnp.float32) * scale
     if mask is not None:
-        s = jnp.where(mask[None, None], s, -jnp.inf)
+        s = jnp.where(_over_heads(mask), s, -jnp.inf)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     # guard fully-masked rows (m_new == -inf)
     m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -70,6 +78,12 @@ def _softmax_block(q, k_blk, v_blk, mask, scale, o, m, l):
         "bhqk,bhkd->bhqd", p.astype(v_blk.dtype), v_blk, precision=prec,
         preferred_element_type=jnp.float32)
     return o, m_new, l
+
+
+def _over_heads(mask):
+    """A (Tq, Tk) mask against (B, H, Tq, Tk) scores; one that has a batch
+    axis already, (B, 1, Tq, Tk), as it is."""
+    return mask[None, None] if mask.ndim == 2 else mask
 
 
 def _ring_attn_shard(q, k, v, axis_name, causal, scale):
@@ -220,7 +234,7 @@ def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q, window=0,
 
 
 def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
-                value_dim=None):
+                value_dim=None, select_top_k=0):
     """The rule of the one-device path: the kernels' tiles
     (``ops/flash_attention.plan``) for queries ``q_shape`` (B, H, T, Dk) of
     ``dtype`` over ``kv_heads`` whose values are ``value_dim`` wide (None:
@@ -228,7 +242,8 @@ def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
     executor's, through ``OpMode.platform``; None: jax's default backend)
     in a process that holds one TPU, or None: the ``jax.numpy`` blocks (the
     CPU, several chips, float32, head widths the kernels do not take, T no
-    multiple of a block). The op and its launch counts
+    multiple of a block, a selection: ``select_top_k`` keys a query,
+    chosen in the program). The op and its launch counts
     (``defs_contrib._ring_attention_counts``) ask it with the same
     arguments. A bare traced call (no executor, ``platform`` None)
     assumes the default backend: a plain ``jax.jit`` for the CPU in a
@@ -240,7 +255,7 @@ def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
     return flash_attention.plan(
         platform or jax.default_backend(),
         pallas_support.attached_vmem_bytes(), dtype, heads, kv_heads, T, D,
-        causal, window, value_dim)
+        causal, window, value_dim, select_top_k)
 
 
 def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0, kernels=None,
@@ -321,7 +336,7 @@ def _blocks_bwd(causal, scale, block_q, window, q, k, v, out, lse, d_out):
         s = dot("bhqd,bhkd->bhqk", qb, kb) * scale
         p = jnp.exp(s - _fold(lse[:, :, a:b, None], kv))
         if mask is not None:
-            p = jnp.where(mask[None, None], p, 0.0)
+            p = jnp.where(_over_heads(mask), p, 0.0)
         ds = p * (dot("bhqd,bhkd->bhqk", gb, vb)
                   - _fold(delta[:, :, a:b, None], kv)) * scale
         p, ds = p.astype(q.dtype), ds.astype(q.dtype)
@@ -335,9 +350,319 @@ def _blocks_bwd(causal, scale, block_q, window, q, k, v, out, lse, d_out):
 blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
-def _refuse_on_the_ring(q, k, window):
+# --- a selection: each query keeps the keys its indexer scores highest -----
+
+# Queries that share one extent of keys under a selection. Inside a span the
+# blocks of queries are the steps of ONE loop (``lax.map`` / ``lax.scan``), so
+# a row of 16 384 at blocks of 32 lowers 8 bodies a pass and not 512; every
+# block of a span reads the keys up to the span's end, masked past its own
+# diagonal: half a span more keys a query than the triangle, on average.
+SELECT_SPAN = 2048
+# Under a selection a block holds the main heads' float32 score tile AND the
+# indexer's, each read by several element-wise passes (the mask, the 32
+# passes of the threshold's bisection, the KL term). Measured on a v5e at the
+# Keye cell's shapes (32 over 4 heads of 128, 16 index heads of 64, T 16 384,
+# keep 2048; a layer alone, forward / forward + backward, PERF.md section 6,
+# PR 51): blocks of 256 queries 117.7 / 340.4 ms, 128 105.5 / 288.9, 64 61.3
+# / 244.1, 32 48.9 / 166.2, 16 58.3 / 191.6: the largest block whose main
+# tile over all T keys is within this, half of ``SCORE_TILE_BYTES``.
+SELECT_TILE_BYTES = 64 << 20
+
+
+def select_block_q(batch, heads, T):
+    """Queries a block under a selection: the largest of 512 ... 32 whose
+    float32 score tile (batch x heads x block x T) is at most
+    ``SELECT_TILE_BYTES``; 32 if none."""
+    for block in (BLOCK_Q, 256, 128, 64, 32):
+        if 4 * batch * heads * block * T <= SELECT_TILE_BYTES:
+            return block
+    return 32
+
+
+def select_plan(T, block_q, span=SELECT_SPAN):
+    """[(first query, end of queries and of keys, queries a block)] of the
+    walk under a selection: spans of ``span`` queries (whole blocks of
+    ``block_q``) whose blocks all read the keys ``[0, end)``."""
+    span = max(span // block_q, 1) * block_q
+    return [(a, min(a + span, T), math.gcd(min(a + span, T) - a, block_q))
+            for a in range(0, T, span)]
+
+
+def selected_scored_pairs(T, block_q, top_k, span=SELECT_SPAN):
+    """Query-key pairs one head scores under a selection, forward: the
+    tiles of :func:`select_plan`, or where ``top_k >= T`` (nothing to
+    select: the dense causal blocks run) those of :func:`block_plan`."""
+    if top_k >= T:
+        return scored_pairs(T, True, 0, block_q)
+    return sum((b - a) * b for a, b, _ in select_plan(T, block_q, span))
+
+
+def index_scores(iq, ik, iw):
+    """The indexer's scores ``I[b, t, s] = sum_j iw[b, j, t] * relu(iq[b, j,
+    t] . ik[b, 0, s])``, (B, Tq, Tk) float32: ``iq`` (B, J, Tq, Di) over the
+    ONE key head ``ik`` (B, 1, Tk, Di), the heads weighed by ``iw`` (B, J,
+    Tq). The products are exact whatever the trunk (float32 operands at
+    ``HIGHEST``, bfloat16 ones on the MXU's native pass: a product of two
+    bfloat16 numbers is a float32 number) and are summed in float32, as a
+    router's logits are: the choice made from them is discrete. The sum
+    over the heads is element-wise, not a matmul at the default precision."""
+    from ..ops.defs_tensor import matmul_precision
+
+    s = jnp.einsum("bjqd,bkd->bjqk", iq, ik[:, 0],
+                   precision=matmul_precision(iq.dtype),
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(s) * iw.astype(jnp.float32)[..., None], axis=1)
+
+
+def kth_largest(x, k):
+    """The k-th largest of each row of float32 ``x`` (..., n), n >= k,
+    exactly, by bisection on its bits: a float's bit pattern, its sign bit
+    set if it is positive and every bit flipped if not, orders as the float
+    does, and 32 passes of compare and count find the largest pattern that
+    at least k elements reach. A pass is one fused comparison and sum over
+    the row; ``lax.top_k`` at k = 2048 of 16 384 sorts it (a layer's forward
+    at the Keye cell's shapes 181 ms against 106 on a v5e; PERF.md section
+    6, PR 51). -inf where fewer than k elements are above it."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    ordered = jnp.where(bits >= top, ~bits, bits | top)
+
+    def narrow(i, found):
+        tried = found | (top >> i.astype(jnp.uint32))
+        reach = jnp.sum(ordered >= tried[..., None], axis=-1)
+        return jnp.where(reach >= k, tried, found)
+
+    found = jax.lax.fori_loop(0, 32, narrow,
+                              jnp.zeros(x.shape[:-1], jnp.uint32))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(found >= top, found ^ top, ~found), jnp.float32)
+
+
+def _causal(first, n_queries, n_keys):
+    """(1, Tq, n_keys) bool: the keys at or before each of ``n_queries``
+    positions from ``first`` on."""
+    rows = first + jnp.arange(n_queries)
+    return (rows[:, None] >= jnp.arange(n_keys)[None, :])[None]
+
+
+def _selection(index, tau, causal):
+    """(B, Tq, Tk) bool: the keys a block of queries keeps: the earlier keys
+    (``causal``, :func:`_causal`) whose ``index`` reaches the row's threshold
+    ``tau`` (B, Tq) (-inf: every earlier key). ``lax.top_k``'s set wherever
+    the row's k-th and (k+1)-th scores differ; a row whose scores at the
+    threshold are equal keeps them all, so more than k keys (rare: an
+    indexer of J heads scores an exact 0 on a pair in 2^J)."""
+    return jnp.logical_and(causal, index >= tau[..., None])
+
+
+def _threshold(index, causal, top_k):
+    """(B, Tq) float32: each row's ``top_k``-th largest causal score, -inf
+    where the row has no more than ``top_k`` earlier keys."""
+    if index.shape[-1] <= top_k:
+        return jnp.full(index.shape[:2], -jnp.inf, jnp.float32)
+    return kth_largest(jnp.where(causal, index, -jnp.inf), top_k)
+
+
+def _rows_of(x, first, count):
+    return jax.lax.dynamic_slice_in_dim(x, first, count, axis=2)
+
+
+def _joined(blocks, axis=2):
+    """(n, B, H, bq, ...) as a loop stacked its blocks -> (B, H, n * bq,
+    ...)."""
+    x = jnp.moveaxis(blocks, 0, axis)
+    return x.reshape(x.shape[:axis] + (-1,) + x.shape[axis + 2:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def selected_attention(q, k, v, iq, ik, iw, scale, block_q, top_k,
+                       loss_coef=0.0, span=SELECT_SPAN):
+    """Causal attention in which each query keeps ``top_k`` keys:
+    ``softmax_{s in S_t}(q_t . k_s * scale) v_s`` with ``S_t`` the
+    ``min(top_k, t + 1)`` positions ``s <= t`` of largest
+    :func:`index_scores` ``I[t, s]`` (``iq`` (B, J, T, Di), ``ik`` (B, 1, T,
+    Di), ``iw`` (B, J, T): DeepSeek-V3.2's lightning indexer). q, k, v and
+    the output as :func:`blockwise_attention`'s.
+
+    A block of queries at a time: the block's ``I`` in float32, each row's
+    ``top_k``-th largest, the mask ``causal & (I >= that)``
+    (:func:`_selection`: ``lax.top_k``'s set where the row's scores at the
+    threshold differ), the softmax under the mask. No (T, T) array
+    outlives a block, forward or backward: backward keeps the operands, the
+    output, the rows' log-sum-exp and thresholds (B, T), and recomputes
+    ``I`` and the mask. The choice passes no gradient.
+
+    ``loss_coef``: backward ADDS to the index operands the gradient of
+    ``loss_coef * sum_{b, t} KL(P[b, t] || softmax_{S_t}(I[b, t]))``, ``P``
+    the mean over the query heads of the probabilities above, a constant
+    (the sparse stage's indexer loss; a sum over the rows, on the scale of
+    ``SoftmaxOutput``'s gradient, as ``MoE``'s router terms are):
+    ``loss_coef * (softmax_S(I) - P)`` on ``S_t`` pushed through ``I``.
+    That is all the index operands ever receive: q, k and v get the
+    gradient of the output alone, the index operands of this term alone.
+
+    Where ``top_k >= T`` nothing is selected: output and the gradients of
+    q, k, v are :func:`blockwise_attention`'s ``jax.numpy`` blocks bit for
+    bit, and the index operands still learn from ``P``."""
+    return _selected_fwd(q, k, v, iq, ik, iw, scale, block_q, top_k,
+                         loss_coef, span)[0]
+
+
+def _check_selection(q, iq, ik, iw):
+    B, _, T, _ = q.shape
+    if (iq.ndim != 4 or iq.shape[0] != B or iq.shape[2] != T
+            or ik.shape != (B, 1, T, iq.shape[-1])
+            or iw.shape != iq.shape[:3]):
+        raise MXNetError(
+            f"attention: index_query {iq.shape}, index_key {ik.shape} and "
+            f"index_weight {iw.shape} are not (B, J, T, Di), (B, 1, T, Di) "
+            f"and (B, J, T) for queries {q.shape}")
+
+
+def _selected_fwd(q, k, v, iq, ik, iw, scale, block_q, top_k, loss_coef,
+                  span):
+    _check_selection(q, iq, ik, iw)
+    B, H, T, _ = q.shape
+    kv, Dv = k.shape[1], v.shape[-1]
+    group = H // kv
+    if top_k >= T:
+        out, lse = _blocks_fwd(q, k, v, True, scale, block_q, 0)
+        tau = jnp.full((B, T), -jnp.inf, jnp.float32)
+    else:
+        outs, lses, taus = [], [], []
+        for a, b, bq in select_plan(T, block_q, span):
+            keys, values, index_keys = k[:, :, :b], v[:, :, :b], ik[:, :, :b]
+
+            def block(first):     # traced at once, by this span's lax.map
+                with jax.named_scope("attention.select"):
+                    index = index_scores(_rows_of(iq, first, bq), index_keys,
+                                         _rows_of(iw, first, bq))
+                    causal = _causal(first, bq, b)
+                    tau = _threshold(index, causal, top_k)
+                    kept = _selection(index, tau, causal)
+                rows = group * bq
+                o, m, l = _softmax_block(
+                    _fold(_rows_of(q, first, bq), kv), keys, values,
+                    jnp.tile(kept, (1, group, 1))[:, None], scale,
+                    jnp.zeros((B, kv, rows, Dv), jnp.float32),
+                    jnp.full((B, kv, rows), -jnp.inf, jnp.float32),
+                    jnp.zeros((B, kv, rows), jnp.float32))
+                return (_unfold((o / l[..., None]).astype(q.dtype), H),
+                        (m + jnp.log(l)).reshape(B, H, bq), tau)
+
+            o, e, t = jax.lax.map(block, jnp.arange(a, b, bq))
+            outs.append(_joined(o))
+            lses.append(_joined(e))
+            taus.append(_joined(t, 1))
+        out, lse, tau = (jnp.concatenate(x, axis=ax) for x, ax in
+                         ((outs, 2), (lses, 2), (taus, 1)))
+    out, lse, tau = keep((out, lse, tau))
+    return out, (q, k, v, iq, ik, iw, out, lse, tau)
+
+
+def _selected_walk(scale, block_q, loss_coef, span, q, k, v, iq, ik, iw, out,
+                   lse, tau, d_out):
+    """Gradients of q, k, v and of the index operands by one walk over the
+    blocks of :func:`select_plan`: each block recomputes its ``I`` and its
+    mask from the kept thresholds, its scores from the kept log-sum-exp,
+    and, where ``loss_coef`` is not 0, forms ``P`` from the same
+    probabilities for the indexer's term."""
+    from ..ops.defs_tensor import matmul_precision
+
+    prec = matmul_precision(q.dtype)
+    f32 = jnp.float32
+    B, H, T, _ = q.shape
+    kv = k.shape[1]
+    group = H // kv
+
+    def dot(spec, x, y):
+        return jnp.einsum(spec, x, y, precision=prec,
+                          preferred_element_type=f32)
+
+    delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
+    dk, dv = jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32)
+    d_ik = jnp.zeros(ik.shape, f32)
+    dqs, d_iqs, d_iws = [], [], []
+    for a, b, bq in select_plan(T, block_q, span):
+        keys, values, index_keys = k[:, :, :b], v[:, :, :b], ik[:, :, :b]
+
+        def block(carry, first):  # traced at once, by this span's lax.scan
+            dk, dv, d_ik = carry
+            iq_b, iw_b = _rows_of(iq, first, bq), _rows_of(iw, first, bq)
+            with jax.named_scope("attention.select"):
+                if loss_coef:
+                    index, pull = jax.vjp(index_scores, iq_b, index_keys,
+                                          iw_b)
+                else:
+                    index = index_scores(iq_b, index_keys, iw_b)
+                kept = _selection(
+                    index, jax.lax.dynamic_slice_in_dim(tau, first, bq, 1),
+                    _causal(first, bq, b))
+            qb, gb = (_fold(_rows_of(x, first, bq), kv) for x in (q, d_out))
+            s = dot("bhqd,bhkd->bhqk", qb, keys) * scale
+            p = jnp.exp(s - _fold(_rows_of(lse, first, bq)[..., None], kv))
+            p = jnp.where(jnp.tile(kept, (1, group, 1))[:, None], p, 0.0)
+            ds = p * (dot("bhqd,bhkd->bhqk", gb, values)
+                      - _fold(_rows_of(delta, first, bq)[..., None], kv)
+                      ) * scale
+            pc, ds = p.astype(q.dtype), ds.astype(q.dtype)
+            dv = dv + dot("bhqk,bhqd->bhkd", pc, gb)
+            dk = dk + dot("bhqk,bhqd->bhkd", ds, qb)
+            dq = _unfold(dot("bhqk,bhkd->bhqd", ds, keys).astype(q.dtype), H)
+            if not loss_coef:
+                return (dk, dv, d_ik), (dq,)
+            with jax.named_scope("attention.select"):
+                # the heads' mean probability of each kept key, a constant
+                target = jnp.sum(p.reshape(B, H, bq, b), axis=1) / H
+                given = jax.nn.softmax(
+                    jnp.where(kept, index, -jnp.inf), axis=-1)
+                d_iq, d_keys, d_iw = pull(loss_coef * (given - target))
+            return (dk, dv, d_ik + d_keys.astype(f32)), (dq, d_iq, d_iw)
+
+        carry = tuple(jnp.zeros(x.shape, f32)
+                      for x in (keys, values, index_keys))
+        carry, got = jax.lax.scan(block, carry, jnp.arange(a, b, bq))
+        dk = dk.at[:, :, :b].add(carry[0])
+        dv = dv.at[:, :, :b].add(carry[1])
+        d_ik = d_ik.at[:, :, :b].add(carry[2])
+        dqs.append(_joined(got[0]))
+        if loss_coef:
+            d_iqs.append(_joined(got[1]))
+            d_iws.append(_joined(got[2]))
+    index_grads = (jnp.concatenate(d_iqs, axis=2).astype(iq.dtype),
+                   d_ik.astype(ik.dtype),
+                   jnp.concatenate(d_iws, axis=2).astype(iw.dtype)) \
+        if loss_coef else tuple(jnp.zeros_like(x) for x in (iq, ik, iw))
+    return (jnp.concatenate(dqs, axis=2), dk.astype(k.dtype),
+            dv.astype(v.dtype)) + index_grads
+
+
+def _selected_bwd(scale, block_q, top_k, loss_coef, span, res, d_out):
+    q, k, v, iq, ik, iw, out, lse, _ = res
+    if top_k < q.shape[2]:
+        return _selected_walk(scale, block_q, loss_coef, span, *res, d_out)
+    # nothing was selected: the dense blocks, and the indexer's term alone
+    # from the walk
+    main = _blocks_bwd(True, scale, block_q, 0, q, k, v, out, lse, d_out)
+    if not loss_coef:
+        return main + tuple(jnp.zeros_like(x) for x in (iq, ik, iw))
+    return main + _selected_walk(scale, block_q, loss_coef, span, *res,
+                                 d_out)[3:]
+
+
+selected_attention.defvjp(_selected_fwd, _selected_bwd)
+
+
+def _refuse_on_the_ring(q, k, window, select=None):
     """The ring rotates whole key/value blocks of equal head count: it has
-    neither the band's block plan nor grouped heads (ROADMAP Reach 3)."""
+    neither the band's block plan nor grouped heads (ROADMAP Reach 3), and
+    a query's ``select_top_k`` best keys are chosen over the whole row,
+    which no device of the ring holds."""
+    if select is not None:
+        raise MXNetError(
+            f"RingAttention: select_top_k={select[3]} is not supported on "
+            "the sequence-parallel ring path; run it on one device (no mesh "
+            "axis for the sequence)")
     if window:
         raise MXNetError(
             f"RingAttention: window={window} is not supported on the "
@@ -351,14 +676,16 @@ def _refuse_on_the_ring(q, k, window):
 
 
 def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None,
-                   window=0):
+                   window=0, select=None):
     """Sequence-parallel attention.
 
     q, k (B, H, T, Dk) and v (B, H, T, Dv): jax arrays or NDArrays, sharded
     (or to be sharded) along T over mesh axis ``axis``. Returns (B, H, T, Dv)
     with the same sharding. With ``mesh=None`` it is
     :func:`blockwise_attention` on one device (same math), which alone has
-    ``window`` and key/value heads fewer than the query heads.
+    ``window``, key/value heads fewer than the query heads and ``select``
+    (jax arrays ``(index_query, index_key, index_weight, top_k,
+    loss_coef)``: :func:`selected_attention`).
     """
     from ..ndarray import NDArray
 
@@ -369,9 +696,9 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
 
     if mesh is None:
-        out = _on_one_device(q, k, v, causal, scale, window)
+        out = _on_one_device(q, k, v, causal, scale, window, select=select)
         return NDArray(out) if wrap else out
-    _refuse_on_the_ring(q, k, window)
+    _refuse_on_the_ring(q, k, window, select)
 
     from jax.sharding import NamedSharding
 
@@ -402,10 +729,22 @@ def _ring_spec(axis, batch_axis):
     return P(batch_axis or None, None, axis, None)
 
 
-def _on_one_device(q, k, v, causal, scale, window, platform=None):
+def _on_one_device(q, k, v, causal, scale, window, platform=None,
+                   select=None):
     """:func:`blockwise_attention` with what the rule and ``block_q_of``
     say for these operands; ``platform`` None: where a concrete q lives,
-    jax's default backend for a tracer."""
+    jax's default backend for a tracer. Under a selection
+    :func:`selected_attention` at ``select_block_q``'s blocks: the rule has
+    no kernels for one."""
+    if select is not None:
+        if not causal or window:
+            raise MXNetError("attention: select_top_k needs causal=True and "
+                             "no window")
+        iq, ik, iw, top_k, loss_coef = select
+        return selected_attention(
+            q, k, v, iq, ik, iw, scale,
+            select_block_q(q.shape[0], q.shape[1], q.shape[2]), top_k,
+            loss_coef, SELECT_SPAN)
     platform = platform or platform_of([q])
     return blockwise_attention(
         q, k, v, causal, scale,
@@ -416,7 +755,7 @@ def _on_one_device(q, k, v, causal, scale, window, platform=None):
 
 def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
                           scale=None, batch_axis=None, window=0,
-                          platform=None):
+                          platform=None, select=None):
     """Jit-safe ring attention for use INSIDE a traced program (the
     symbol-level ``_contrib_RingAttention`` op): placement is expressed as
     sharding constraints (not eager ``device_put``) and the ``shard_map``
@@ -424,7 +763,7 @@ def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
     ``batch_axis`` so the batch dim keeps its data-parallel sharding
     instead of being gathered/replicated over the other axes. ``platform``:
     what the caller's program is lowered for, where it knows
-    (:func:`kernel_plan`)."""
+    (:func:`kernel_plan`); ``select``: as :func:`ring_attention`'s."""
     from jax.sharding import NamedSharding
 
     from .mesh import as_graft
@@ -433,8 +772,9 @@ def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
         scale = 1.0 / math.sqrt(q.shape[-1])
     mesh = getattr(as_graft(mesh), "mesh", None)
     if mesh is None or axis not in mesh.axis_names:
-        return _on_one_device(q, k, v, causal, scale, window, platform)
-    _refuse_on_the_ring(q, k, window)
+        return _on_one_device(q, k, v, causal, scale, window, platform,
+                              select)
+    _refuse_on_the_ring(q, k, window, select)
     if batch_axis is not None and batch_axis not in mesh.axis_names:
         raise MXNetError(f"mesh has no axis {batch_axis!r}")
     spec = _ring_spec(axis, batch_axis)

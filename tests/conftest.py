@@ -35,7 +35,9 @@ jax.config.update("jax_platforms", "cpu")
 _DIST_PROBE = None  # None = not probed yet; True/False = cached verdict
 
 # Seconds a test file took in the driver's tier-1 run on PR 49's tree (its
-# junit times summed a file; every file over 100 s). ``--dist loadfile``
+# junit times summed a file; every file over 100 s; ``test_keye_vl2.py`` a
+# builder's reading under six workers at PR 51, 159 s where
+# ``test_olmoe.py`` read 150, on this table's scale). ``--dist loadfile``
 # hands files to the workers in collection order, and alphabetical order
 # starts the heaviest last: six workers ended at 1448 s where their 7024 s of
 # tests, evenly loaded, are 1171. Heaviest first, a file's own items together
@@ -45,7 +47,7 @@ _FILE_SECONDS = {
     "test_trinity.py": 666, "test_zaya.py": 556, "test_bench_smoke.py": 523,
     "test_kanana2.py": 389, "test_causal_conv_kernels.py": 285,
     "test_recompute_residuals.py": 274, "test_olmoe.py": 209,
-    "test_moe_routing.py": 194, "test_gated_delta_kernels.py": 189,
+    "test_keye_vl2.py": 200, "test_moe_routing.py": 194, "test_gated_delta_kernels.py": 189,
     "test_gated_delta_channel.py": 166, "test_flash_attention.py": 164,
     "test_model_zoo.py": 136, "test_elastic_checkpoint.py": 130,
     "test_grouped_matmul.py": 115,
