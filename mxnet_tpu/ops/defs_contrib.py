@@ -591,9 +591,13 @@ def _ring_attention_op(ins, params, mode):
     sum_j index_weight[j, t] relu(index_query[j, t] . index_key[s])``
     (float32; scores equal to the K-th are all kept, so a row whose K-th and
     next scores are equal keeps more than K) and the softmax runs
-    over those alone (``ring_attention.selected_attention``: the
-    ``jax.numpy`` blocks; the kernels' rule answers None for a selection
-    and the ring refuses it by name). The choice passes no gradient. The
+    over those alone (``ring_attention.selected_attention`` is the
+    specification and the ``jax.numpy`` blocks; where the kernels' rule
+    answers a plan, one TPU with a bfloat16 trunk and indexer, it runs as
+    ``ring_attention.selected_kernels``: the thresholds found in VMEM, the
+    kept pairs handed to the fused kernels as int8 tiles, no float32 tile
+    of queries by keys in HBM; the ring refuses it by name). The choice
+    passes no gradient. The
     index inputs learn from a term of their own that backward attaches, as
     ``MoE`` attaches its router's: ``index_loss_coef`` x the sum over rows
     of ``KL(P || softmax_S(I))``, ``P`` the mean over the query heads of
@@ -633,7 +637,8 @@ def _ring_attention_counts(ins, outs, params, platform):
     (``select_top_k``) also: the pairs the softmax keeps, query ``t`` its
     ``min(t + 1, K)``, x heads x batch; the pairs the indexer scores, its
     heads x the causal triangle x batch; and the scored pairs are those of
-    the selected walk's tiles (``ring_attention.selected_scored_pairs``),
+    the kernels' causal visit list at the plan's tiles where they engage,
+    else of the selected walk's (``ring_attention.selected_scored_pairs``),
     so the gap between scored and selected pairs is what is computed and
     masked away."""
     from ..parallel.ring_attention import (block_q_of, kernel_plan,
@@ -646,7 +651,8 @@ def _ring_attention_counts(ins, outs, params, platform):
     top_k = params["select_top_k"]
     batch, heads, T, key_dim = q.shape
     kernels = kernel_plan(q.dtype, q.shape, k.shape[1], causal, window,
-                          platform, v.shape[-1], top_k)
+                          platform, v.shape[-1], top_k,
+                          ins[3] if top_k > 0 else None)
     if kernels is not None:
         pairs = flash_attention.scored_pairs(T, kernels.bq, kernels.bk,
                                              causal, window)

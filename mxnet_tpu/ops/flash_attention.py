@@ -34,6 +34,32 @@ What the kernels do that the ``jax.numpy`` blocks do not:
   the band are never visited, and only a block the diagonal or the band's
   edge cuts applies a mask.
 
+A selection (``select_top_k``: each query keeps the keys a small indexer
+scores highest; ``ring_attention.selected_attention`` is the specification)
+runs in the same two kernels and two more, and no (queries x keys) array in
+float32, the main heads' or the indexer's, reaches HBM either:
+
+* ``select`` (a block of ``bq`` queries a grid step) forms the block's index
+  scores a key block at a time into a row buffer in VMEM (bq x T, as int32
+  keys that order as the floats do), finds each row's ``top_k``-th largest
+  there by ``kth_largest``'s 32 passes of compare and count, and writes the
+  rows' thresholds, the log-sum-exp of their kept index scores and the
+  block's kept pairs as int8 (T x T a batch row, transient: written once and
+  read once a key/value head).
+* ``attention`` / ``attention_grads`` take those pairs as one more operand
+  (``kept``): a query block's tile of them (bq x T int8) rides beside the
+  block, every visited key block is masked by its slice, the same for the G
+  heads of the group and for every key/value head. Without the operand the
+  kernels trace to what they were: the branch is Python's.
+* ``index_grads`` runs first in backward: it forms the index scores again,
+  writes the kept pairs from the kept thresholds KEYS by queries (the
+  backward kernel's tiles are transposed), and pulls the indexer's own term
+  back through them. That term needs ``P``, the mean probability over ALL
+  the query heads, which the backward kernel sees a key/value head at a time:
+  this kernel forms every head's scores of a tile again (q against every
+  key/value head's keys in VMEM, the kept log-sum-exp; no ``p.v``) and sums
+  them in float32 on the tile.
+
 ``plan`` is the one rule that says whether the kernels engage and with which
 tiles, from what is observable where the op is traced: the platform its
 program is lowered for (the executor's context, ``OpMode.platform``), the one
@@ -91,7 +117,8 @@ class Plan(NamedTuple):
 
 
 def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
-         window=0, value_dim=None, select_top_k=0) -> Optional[Plan]:
+         window=0, value_dim=None, select_top_k=0,
+         index=None) -> Optional[Plan]:
     """The rule. ``D`` is the width of queries and keys, ``value_dim`` that
     of the values and the output (None: ``D``). The kernels engage where
     the program is lowered for one TPU whose VMEM is known, the operands
@@ -109,11 +136,23 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
     whose tile over the group (any group: 7 query heads a key/value head
     are 7 x 256 rows) has at most ``_ROWS`` rows, else the narrowest; if
     that does not fit the VMEM, the next narrower query blocks.
-    A selection (``select_top_k``: each query keeps its own keys, chosen
-    from scores computed in the program) is no visit list of whole key
-    blocks: the kernels do not take one.
+    A selection (``select_top_k`` keys a query, chosen by the indexer
+    ``index`` = (dtype, heads J, width Di)) is four kernels (``select``,
+    the forward and backward above reading its kept tiles, ``index_grads``)
+    and engages where the same operands would without one, causal and with
+    no window, and the index operands are the trunk's bfloat16 with Di a
+    half tile of lanes or more: the first query block, then the widest key
+    block, at which the forward and backward hold their kept tiles (int8,
+    bq x T, twice) in the same half, and at which what the other two hold
+    (``_select_bytes``) is, with the 16 MiB every kernel leaves Mosaic,
+    within the three quarters ``vmem_limit`` is capped at.
     None = the ``jax.numpy`` blocks."""
-    if platform != "tpu" or not vmem_bytes or select_top_k:
+    if platform != "tpu" or not vmem_bytes:
+        return None
+    if select_top_k and (
+            not causal or window or index is None
+            or jnp.dtype(index[0]) != jnp.bfloat16
+            or index[2] % (_LANES // 2)):
         return None
     value_dim = value_dim or D
     if jnp.dtype(dtype) != jnp.bfloat16 or heads % kv_heads:
@@ -134,12 +173,36 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
     # head 256: 50 MB of keys, values and their gradients), the next
     # narrower query blocks
     lanes = -(-D // _LANES) * _LANES + value_dim   # of both, as VMEM pads
+    selecting = 0 < select_top_k < T
     for bq in (b for b in fit if b <= widest):
         rows = group * bq
-        need = 12 * T * lanes + 8 * rows * lanes + 6 * rows * bk * 4
-        if need <= vmem_bytes // 2:
-            return Plan(bq, bk, min(vmem_bytes * 3 // 4, need + (16 << 20)))
+        for keys in (blocks[blocks.index(bk):] if selecting else (bk,)):
+            need = 12 * T * lanes + 8 * rows * lanes + 6 * rows * keys * 4 \
+                + 2 * bq * T * selecting
+            most = max(need, _select_bytes(kv_heads, group, T, D, index, bq,
+                                           keys)) if select_top_k else need
+            if need <= vmem_bytes // 2 \
+                    and most + (16 << 20) <= vmem_bytes * 3 // 4:
+                return Plan(bq, keys,
+                            min(vmem_bytes * 3 // 4, most + (16 << 20)))
     return None
+
+
+def _select_bytes(kv_heads, group, T, D, index, bq, bk):
+    """What the larger of ``_select`` and ``_index_grads`` holds in VMEM at
+    these tiles (a width under 128 lanes takes 128): the first its row
+    buffer (bq x T keys of 4 bytes), the index keys and its kept tiles
+    twice; the second every key/value head's keys twice, the index keys and
+    their gradient twice each and once in float32, its kept tiles twice,
+    the index heads' tiles (float32 and bfloat16) and four tiles of the
+    main heads' scores."""
+    _, J, Di = index
+    lanes = -(-D // _LANES) * _LANES
+    wide = -(-Di // _LANES) * _LANES
+    select = 4 * bq * T + 4 * T * wide + 2 * bq * T
+    grads = 4 * kv_heads * T * lanes + 12 * T * wide + 2 * bq * T \
+        + 6 * J * bk * bq + 16 * bk * group * bq
+    return max(select, grads)
 
 
 def visits(T, bq, bk, causal, window=0):
@@ -161,14 +224,25 @@ def scored_pairs(T, bq, bk, causal, window=0):
     return int(bq * bk * (end - first).sum())
 
 
+def _loop(first, end, step, carry=None):
+    """``carry = step(j, carry)`` for ``first <= j < end``, in order, as one
+    loop whose steps store into the kernel's refs."""
+    return lax.fori_loop(first, end, lambda j, carry: step(j, carry), carry)
+
+
 def _for_the_key_blocks(first, end, i, bq, bk, rows_axis, shape, causal,
-                        window, step):
+                        window, step, kept=None):
     """``step(j, mask)`` for each key block ``first <= j < end`` of query
     block ``i``: ``mask`` is None for a block neither the diagonal nor the
     band's edge cuts, else a function of the scores' tile that writes
     ``_MASKED`` where a query may not see a key. ``shape`` is the tile's,
-    with its rows (G x bq, position = row mod bq) on axis ``rows_axis``."""
+    with its rows (G x bq, position = row mod bq) on axis ``rows_axis``.
+    Under a selection ``kept(j)`` is that function for key block ``j``
+    (the kept tiles hold the diagonal too) and no tile goes unmasked."""
     pl, _ = _ps._pallas()
+    if kept is not None:
+        _loop(first, end, lambda j, _: step(j, kept(j)))
+        return
     if bq & (bq - 1):
         raise ValueError(f"attention: {bq} positions a query block, not a "
                          "power of two")
@@ -226,20 +300,30 @@ def _block_specs(group, bq, T):
                                        lambda b, h, i, *_: (b, h, i, 0, 0))
 
 
+def _kept_tile(tile, group=1):
+    """Whether a pair is kept, from a tile of int8 to the scores' lanes,
+    ``group`` times side by side."""
+    return jnp.tile(tile.astype(jnp.int32).astype(jnp.float32),
+                    (1, group)) > 0
+
+
 # --- forward -----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=(
     "scale", "causal", "window", "bq", "bk", "vmem_limit", "interpret"))
-def _fwd(q, k, v, first, end, *, scale, causal, window, bq, bk, vmem_limit,
-         interpret):
-    """(out (B, H, T, Dv) in q's dtype, log-sum-exp (B, H, T) float32)."""
+def _fwd(q, k, v, first, end, kept=None, *, scale, causal, window, bq, bk,
+         vmem_limit, interpret):
+    """(out (B, H, T, Dv) in q's dtype, log-sum-exp (B, H, T) float32).
+    ``kept`` (B, T, T) int8, queries by keys: under a selection, what
+    ``_select`` wrote: 1 where a query keeps a key."""
     pl, pltpu = _ps._pallas()
     B, H, T, D = q.shape
     kv, Dv = k.shape[1], v.shape[-1]
     group, nq = H // kv, T // bq
     rows = group * bq
 
-    def kernel(first_ref, end_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-               m_ref, l_ref, acc_ref):
+    def kernel(first_ref, end_ref, q_ref, k_ref, v_ref, *refs):
+        kept_ref = refs[0] if kept is not None else None
+        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[kept is not None:]
         i = pl.program_id(2)
         qb = q_ref[...].reshape(rows, D)
         m_ref[...] = jnp.full_like(m_ref, _MASKED)
@@ -264,8 +348,17 @@ def _fwd(q, k, v, first, end, *, scale, causal, window, bq, bk, vmem_limit,
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
+        def kept_of(j):
+            # a tile of positions by keys, the same for every head of the
+            # group
+            ok = _kept_tile(
+                kept_ref[:, pl.ds(pl.multiple_of(j * bk, bk), bk)])
+            return lambda s: jnp.where(
+                ok[None], s.reshape(group, bq, bk), _MASKED).reshape(rows, bk)
+
         _for_the_key_blocks(first_ref[i], end_ref[i], i, bq, bk, 0,
-                            (rows, bk), causal, window, step)
+                            (rows, bk), causal, window, step,
+                            None if kept is None else kept_of)
         l = l_ref[...]
         o_ref[...] = (acc_ref[...] * jnp.tile(1.0 / l, (1, Dv // _LANES))) \
             .astype(o_ref.dtype).reshape(group, bq, Dv)
@@ -279,7 +372,9 @@ def _fwd(q, k, v, first, end, *, scale, causal, window, bq, bk, vmem_limit,
                    jax.ShapeDtypeStruct((B, kv, nq, 1, rows), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            in_specs=[folded(D), whole(D), whole(Dv)],
+            in_specs=[folded(D), whole(D), whole(Dv)] + [pl.BlockSpec(
+                (None, bq, T), lambda b, h, i, *_: (b, i, 0))] * (
+                    kept is not None),
             out_specs=[folded(Dv), row],
             grid=(B, kv, nq),
             scratch_shapes=[pltpu.VMEM((rows, _LANES), jnp.float32),
@@ -292,7 +387,8 @@ def _fwd(q, k, v, first, end, *, scale, causal, window, bq, bk, vmem_limit,
         cost_estimate=_cost(q, k, v, bq, bk, causal, window, 1, 1),
         interpret=interpret,
         name="attention_fwd",
-    )(first, end, q.reshape(B, kv, group, T, D), k, v)
+    )(first, end, q.reshape(B, kv, group, T, D), k, v,
+      *(() if kept is None else (kept,)))
     return out.reshape(B, H, T, Dv), _rows_to_heads(lse, H)
 
 
@@ -332,9 +428,11 @@ def _heads_to_rows(x, kv, bq):
 # --- backward ----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=(
     "scale", "causal", "window", "bq", "bk", "vmem_limit", "interpret"))
-def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
-         bk, vmem_limit, interpret):
-    """(dq, dk, dv) in the operands' dtypes."""
+def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
+         window, bq, bk, vmem_limit, interpret):
+    """(dq, dk, dv) in the operands' dtypes. ``kept`` (B, T, T) int8, KEYS
+    by queries as this kernel's tiles are: under a selection, what
+    ``_index_grads`` wrote."""
     pl, pltpu = _ps._pallas()
     B, H, T, D = q.shape
     kv, Dv = k.shape[1], v.shape[-1]
@@ -344,7 +442,10 @@ def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
                     axis=-1)
 
     def kernel(first_ref, end_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
-               delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+               delta_ref, *refs):
+        kept_ref = refs[0] if kept is not None else None
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = \
+            refs[kept is not None:]
         i = pl.program_id(2)
 
         @pl.when(i == 0)
@@ -377,8 +478,16 @@ def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
             dq_acc[...] += lax.dot_general(
                 ds, kb, _TN, preferred_element_type=jnp.float32)
 
+        def kept_of(j):
+            # a tile of keys by positions, the same for every head of the
+            # group
+            ok = _kept_tile(
+                kept_ref[pl.ds(pl.multiple_of(j * bk, bk), bk), :], group)
+            return lambda s: jnp.where(ok, s, _MASKED)
+
         _for_the_key_blocks(first_ref[i], end_ref[i], i, bq, bk, 1,
-                            (bk, rows), causal, window, step)
+                            (bk, rows), causal, window, step,
+                            None if kept is None else kept_of)
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype).reshape(group, bq, D)
 
         @pl.when(i == nq - 1)
@@ -394,7 +503,9 @@ def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            in_specs=[folded(D), whole(D), whole(Dv), folded(Dv), row, row],
+            in_specs=[folded(D), whole(D), whole(Dv), folded(Dv), row, row]
+            + [pl.BlockSpec((None, T, bq), lambda b, h, i, *_: (b, 0, i))] * (
+                kept is not None),
             out_specs=[folded(D), whole(D), whole(Dv)],
             grid=(B, kv, nq),
             scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
@@ -409,8 +520,314 @@ def _bwd(q, k, v, out, lse, d_out, first, end, *, scale, causal, window, bq,
         name="attention_bwd",
     )(first, end, q.reshape(B, kv, group, T, D), k, v,
       d_out.reshape(B, kv, group, T, Dv), _heads_to_rows(lse, kv, bq),
-      _heads_to_rows(delta, kv, bq))
+      _heads_to_rows(delta, kv, bq), *(() if kept is None else (kept,)))
     return dq.reshape(B, H, T, D), dk, dv
+
+
+# --- a selection: the threshold of a row, and the indexer's gradient -----------
+_LOWEST = -(1 << 31)            # under every key: a pair no query may see
+_KEY_OF_MINUS_INF = -(1 << 31) + (1 << 23) - 1
+
+
+def _key(x):
+    """float32 -> int32 that orders as the floats do (a float's bits, those
+    under the sign flipped where it is negative), and back: the map is its
+    own inverse on the bits. Mosaic compares signed integers only."""
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits >= 0, bits, bits ^ jnp.int32(0x7fffffff))
+
+
+def _value(key):
+    return lax.bitcast_convert_type(
+        jnp.where(key >= 0, key, key ^ jnp.int32(0x7fffffff)), jnp.float32)
+
+
+def _lanes_of(j, bk):
+    """Key block ``j`` of a ref of positions by keys, 128 lanes at a time."""
+    pl, _ = _ps._pallas()
+    return [(slice(None), pl.ds(pl.multiple_of(j * bk + c * _LANES, _LANES),
+                                _LANES)) for c in range(bk // _LANES)]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "top_k", "bq", "bk", "write", "vmem_limit", "interpret"))
+def _select(iq, ik, iw, end, *, top_k, bq, bk, write, vmem_limit, interpret):
+    """(thresholds (B, T), log-sum-exp of the kept index scores (B, T), both
+    float32, kept (B, T, T) int8 queries by keys where ``write``) of the
+    indexer ``iq`` (B, J, T, Di), ``ik`` (B, 1, T, Di), ``iw`` (B, J, T):
+    ``ring_attention.index_scores``, ``_threshold`` and ``_selection`` a
+    block of ``bq`` queries a grid step. The block's scores are formed a key
+    block at a time into a row buffer in VMEM (bq x T, as ordered keys);
+    the ``top_k``-th largest of a row is found there by ``kth_largest``'s 32
+    passes of compare and count, over the block's causal extent alone and
+    not at all where no row of the block has more than ``top_k`` keys."""
+    pl, pltpu = _ps._pallas()
+    B, J, T, Di = iq.shape
+    nq, nk = T // bq, T // bk
+    f32, i32 = jnp.float32, jnp.int32
+
+    def kernel(end_ref, iq_ref, ik_ref, iw_ref, tau_ref, lse_ref, *refs):
+        kept_ref, buf = refs if write else (None,) + refs
+        i = pl.program_id(1)
+        n = end_ref[i]
+        weight = iw_ref[...].astype(f32)                        # (bq, J)
+        apart = lax.broadcasted_iota(i32, (bq, bk), 0) \
+            - lax.broadcasted_iota(i32, (bq, bk), 1)
+
+        def form(j, top):
+            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            keys = ik_ref[at, :]
+            index = jnp.zeros((bq, bk), f32)
+            for h in range(J):
+                index = index + weight[:, h:h + 1] * jnp.maximum(
+                    lax.dot_general(iq_ref[h], keys, _NT,
+                                    preferred_element_type=f32), 0.0)
+            key = jnp.where(apart >= j * bk - i * bq, _key(index), _LOWEST)
+            buf[:, at] = key
+            return jnp.maximum(top, key.max(axis=-1, keepdims=True))
+
+        top = _loop(0, n, form, jnp.full((bq, 1), _LOWEST, i32))
+
+        def narrow(bit, found):     # the largest pattern top_k keys reach
+            tried = found | lax.shift_left(i32(1), 31 - bit)
+            probe = tried ^ i32(_LOWEST)     # unsigned order, signed compare
+
+            def count(j, reach):
+                for at in _lanes_of(j, bk):
+                    reach = reach + jnp.where(buf[at] >= probe, 1, 0)
+                return reach
+
+            reach = lax.fori_loop(0, n, count, jnp.zeros((bq, _LANES), i32))
+            return jnp.where(reach.sum(axis=-1, keepdims=True) >= top_k,
+                             tried, found)
+
+        found = lax.fori_loop(0, jnp.where((i + 1) * bq > top_k, 32, 0),
+                              narrow, jnp.zeros((bq, _LANES), i32))
+        # minus infinity for a row of no more than top_k keys
+        tau = jnp.where(
+            i * bq + lax.broadcasted_iota(i32, (bq, _LANES), 0) >= top_k,
+            jnp.maximum(found ^ i32(_LOWEST), _KEY_OF_MINUS_INF),
+            _KEY_OF_MINUS_INF)
+        highest = _value(top)
+
+        def emit(j, total):
+            for at in _lanes_of(j, bk):
+                key = buf[at]
+                kept = key >= tau
+                total = total + jnp.where(
+                    kept, jnp.exp(_value(key) - highest), 0.0)
+                if write:
+                    kept_ref[at] = jnp.where(kept, 1, 0).astype(jnp.int8)
+            return total
+
+        total = _loop(0, n, emit, jnp.zeros((bq, _LANES), f32))
+        if write:
+            def none(j, carry):
+                for at in _lanes_of(j, bk):
+                    kept_ref[at] = jnp.zeros((bq, _LANES), jnp.int8)
+                return carry
+
+            _loop(n, nk, none)
+        # from a column to a row of lanes
+        tau_ref[...] = jnp.transpose(_value(tau))[:1, :]
+        lse_ref[...] = jnp.transpose(jnp.broadcast_to(
+            highest + jnp.log(total.sum(axis=-1, keepdims=True)),
+            (bq, _LANES)))[:1, :]
+
+    row = pl.BlockSpec((None, None, 1, bq), lambda b, i, *_: (b, i, 0, 0))
+    got = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((B, nq, 1, bq), f32),) * 2 + (
+            jax.ShapeDtypeStruct((B, T, T), jnp.int8),) * write,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            in_specs=[pl.BlockSpec((None, J, bq, Di),
+                                   lambda b, i, *_: (b, 0, i, 0)),
+                      pl.BlockSpec((None, None, T, Di),
+                                   lambda b, i, *_: (b, 0, 0, 0)),
+                      pl.BlockSpec((None, bq, J),
+                                   lambda b, i, *_: (b, i, 0))],
+            out_specs=[row, row] + [pl.BlockSpec(
+                (None, bq, T), lambda b, i, *_: (b, i, 0))] * write,
+            grid=(B, nq),
+            scratch_shapes=[pltpu.VMEM((bq, T), i32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        cost_estimate=_index_cost(iq, bq, bk, 1, 2 * 32 + 8),
+        interpret=interpret,
+        name="attention_select",
+    )(end, iq, ik, jnp.swapaxes(iw, 1, 2))
+    return (got[0].reshape(B, T), got[1].reshape(B, T)) + tuple(got[2:])
+
+
+def _index_cost(iq, bq, bk, matmuls, passes, q=None):
+    """An index kernel's work: ``matmuls`` products over Di a scored pair
+    and index head, ``passes`` element operations a scored pair; with ``q``
+    also the main heads' scores of every pair, formed again for ``P``."""
+    pl, _ = _ps._pallas()
+    B, J, T, Di = iq.shape
+    pairs = B * scored_pairs(T, bq, bk, True)
+    heads, width, size = (0, 0, 0) if q is None else (
+        q.shape[1], q.shape[-1], q.size)
+    return pl.CostEstimate(
+        flops=pairs * (2 * J * Di * matmuls + 3 * J + passes
+                       + heads * (2 * width + 4)),
+        transcendentals=pairs * (1 + heads),
+        bytes_accessed=2 * (iq.size + size) * iq.dtype.itemsize)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "coef", "bq", "bk", "write", "vmem_limit", "interpret"))
+def _index_grads(q, k, iq, ik, iw, lse, tau, index_lse, end, *, scale, coef,
+                 bq, bk, write, vmem_limit, interpret):
+    """((d_iq, d_ik, d_iw) where ``coef``, kept (B, T, T) int8 KEYS by
+    queries where ``write``): a block of ``bq`` queries a grid step, its
+    tiles keys by positions as ``_bwd``'s. A tile's index scores are formed
+    again from the operands, its kept pairs from the kept thresholds
+    ``tau``; where ``coef``, ``P`` of the tile is summed in float32 over ALL
+    the query heads (their scores formed again from q, every key/value
+    head's keys in VMEM, and the kept log-sum-exp ``lse``; no ``p.v``), and
+    ``coef * (softmax_S(I) - P)`` (``index_lse``: the kept scores'
+    log-sum-exp) is pulled back through ``I`` there: cast to the operands'
+    dtype for its two matmuls as ``ds`` is, ``d_ik`` accumulated in float32
+    over the query blocks as ``dk`` is."""
+    pl, pltpu = _ps._pallas()
+    B, J, T, Di = iq.shape
+    H, kv, D = q.shape[1], k.shape[1], q.shape[-1]
+    group, nq = H // kv, T // bq
+    rows = group * bq
+    f32 = jnp.float32
+
+    def kernel(end_ref, *refs):
+        refs = list(refs)
+        iq_ref, ik_ref, iw_ref, tau_ref = refs[:4]
+        del refs[:4]
+        if coef:
+            q_ref, k_ref, lse_ref, index_lse_ref = refs[:4]
+            del refs[:4]
+            d_iq_ref, d_ik_ref, d_iw_ref = refs[:3]
+            del refs[:3]
+        if write:
+            kept_ref = refs.pop(0)
+        if coef:
+            z_ref, dz_ref, d_iq_acc, d_ik_acc, d_iw_acc = refs
+        i = pl.program_id(1)
+        weight = iw_ref[...].astype(f32)                         # (J, bq)
+        row_tau = tau_ref[...]                                   # (1, bq)
+        apart = lax.broadcasted_iota(jnp.int32, (bk, bq), 1) \
+            - lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+        if coef:
+            d_iq_acc[...] = jnp.zeros_like(d_iq_acc)
+            d_iw_acc[...] = jnp.zeros_like(d_iw_acc)
+
+            @pl.when(i == 0)
+            def _():
+                d_ik_acc[...] = jnp.zeros_like(d_ik_acc)
+
+        def step(j, carry):
+            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            keys = ik_ref[at, :]
+            index = jnp.zeros((bk, bq), f32)
+            for h in range(J):
+                z = lax.dot_general(keys, iq_ref[h], _NT,
+                                    preferred_element_type=f32)
+                if coef:
+                    z_ref[h] = z
+                index = index + weight[h:h + 1, :] * jnp.maximum(z, 0.0)
+            kept = jnp.logical_and(apart >= j * bk - i * bq,
+                                   index >= row_tau)
+            if write:
+                kept_ref[at, :] = jnp.where(kept, 1, 0).astype(jnp.int8)
+            if not coef:
+                return carry
+            # the heads' summed probability of each pair, a constant
+            target = jnp.zeros((bk, bq), f32)
+            for h in range(kv):
+                p = jnp.exp(lax.dot_general(
+                    k_ref[h, at, :], q_ref[h].reshape(rows, D), _NT,
+                    preferred_element_type=f32) * scale - lse_ref[h])
+                for g in range(group):
+                    target = target + p[:, g * bq:(g + 1) * bq]
+            d_index = jnp.where(
+                kept, coef * (jnp.exp(index - index_lse_ref[...])
+                              - target / H), 0.0)
+            for h in range(J):
+                z = z_ref[h]
+                d_iw_acc[h:h + 1, :] += jnp.sum(
+                    d_index * jnp.maximum(z, 0.0), axis=0, keepdims=True)
+                dz_ref[:, h * bq:(h + 1) * bq] = jnp.where(
+                    z > 0, d_index * weight[h:h + 1, :], 0.0
+                ).astype(dz_ref.dtype)
+            dz = dz_ref[...]
+            d_ik_acc[at, :] += jnp.dot(dz, iq_ref[...].reshape(J * bq, Di),
+                                       preferred_element_type=f32)
+            d_iq_acc[...] += lax.dot_general(dz, keys, _TN,
+                                             preferred_element_type=f32)
+            return carry
+
+        _loop(0, end_ref[i], step)
+        if write:
+            def none(j, carry):
+                at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+                kept_ref[at, :] = jnp.zeros((bk, bq), jnp.int8)
+                return carry
+
+            _loop(end_ref[i], T // bk, none)
+        if coef:
+            d_iq_ref[...] = d_iq_acc[...].astype(d_iq_ref.dtype).reshape(
+                J, bq, Di)
+            d_iw_ref[...] = d_iw_acc[...].astype(d_iw_ref.dtype)
+
+            @pl.when(i == nq - 1)
+            def _():
+                d_ik_ref[...] = d_ik_acc[...].astype(d_ik_ref.dtype)
+
+    def block(at, *shape):      # query block i along axis ``at``
+        return pl.BlockSpec((None,) + shape, lambda b, i, *_: (b,) + tuple(
+            i if n == at else 0 for n in range(len(shape))))
+
+    whole_keys = pl.BlockSpec((None, None, T, Di),
+                              lambda b, i, *_: (b, 0, 0, 0))
+    row = pl.BlockSpec((None, None, 1, bq), lambda b, i, *_: (b, i, 0, 0))
+    in_specs = [block(1, J, bq, Di), whole_keys, block(1, J, bq), row]
+    operands = [iq, ik, iw, tau.reshape(B, nq, 1, bq)]
+    out_shape, out_specs, scratch = [], [], []
+    if coef:
+        in_specs += [
+            block(2, kv, group, bq, D),
+            pl.BlockSpec((None, kv, T, D), lambda b, i, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((None, kv, None, 1, rows),
+                         lambda b, i, *_: (b, 0, i, 0, 0)), row]
+        operands += [q.reshape(B, kv, group, T, D), k,
+                     _heads_to_rows(lse, kv, bq),
+                     index_lse.reshape(B, nq, 1, bq)]
+        out_shape += [jax.ShapeDtypeStruct(x.shape, x.dtype)
+                      for x in (iq, ik, iw)]
+        out_specs += [block(1, J, bq, Di), whole_keys, block(1, J, bq)]
+        scratch = [pltpu.VMEM((J, bk, bq), f32),
+                   pltpu.VMEM((bk, J * bq), iq.dtype),
+                   pltpu.VMEM((J * bq, Di), f32), pltpu.VMEM((T, Di), f32),
+                   pltpu.VMEM((J, bq), f32)]
+    if write:
+        out_shape.append(jax.ShapeDtypeStruct((B, T, T), jnp.int8))
+        out_specs.append(block(1, T, bq))
+    got = pl.pallas_call(
+        kernel,
+        out_shape=tuple(out_shape),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, in_specs=in_specs, out_specs=out_specs,
+            grid=(B, nq), scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        cost_estimate=_index_cost(iq, bq, bk, 3, 12, q) if coef
+        else _index_cost(iq, bq, bk, 1, 3),
+        interpret=interpret,
+        name="attention_index_bwd",
+    )(end, *operands)
+    return tuple(got)
 
 
 # --- what blockwise_attention calls -------------------------------------------
@@ -420,18 +837,55 @@ def _static(plan, scale, causal, window, interpret):
                 interpret=interpret)
 
 
-def attention(q, k, v, plan, scale, causal, window=0, interpret=False):
-    """(out, log-sum-exp): the forward kernel at ``plan``'s tiles."""
+def attention(q, k, v, plan, scale, causal, window=0, interpret=False,
+              kept=None):
+    """(out, log-sum-exp): the forward kernel at ``plan``'s tiles; under a
+    selection over ``select``'s ``kept`` pairs."""
     first, end = visits(q.shape[2], plan.bq, plan.bk, causal, window)
-    return _ps._kernel(_fwd, (q, k, v, jnp.asarray(first), jnp.asarray(end)),
-                        **_static(plan, scale, causal, window, interpret))
+    return _ps._kernel(
+        _fwd, (q, k, v, jnp.asarray(first), jnp.asarray(end))
+        + (() if kept is None else (kept,)),
+        **_static(plan, scale, causal, window, interpret))
 
 
 def attention_grads(q, k, v, out, lse, d_out, plan, scale, causal, window=0,
-                    interpret=False):
-    """(dq, dk, dv): the backward kernel, from the forward's residuals."""
+                    interpret=False, kept=None):
+    """(dq, dk, dv): the backward kernel, from the forward's residuals;
+    under a selection over ``index_grads``'s ``kept`` pairs."""
     first, end = visits(q.shape[2], plan.bq, plan.bk, causal, window)
     return _ps._kernel(
         _bwd, (q, k, v, out, lse, d_out.astype(q.dtype), jnp.asarray(first),
-               jnp.asarray(end)),
+               jnp.asarray(end)) + (() if kept is None else (kept,)),
         **_static(plan, scale, causal, window, interpret))
+
+
+def select(iq, ik, iw, plan, top_k, interpret=False):
+    """(thresholds (B, T), the kept index scores' log-sum-exp (B, T), kept
+    (B, T, T) int8 queries by keys, or None where ``top_k >= T``: every
+    earlier key is kept and the dense kernels run): the select kernel at
+    ``plan``'s tiles."""
+    T = iq.shape[2]
+    _, end = visits(T, plan.bq, plan.bk, True)
+    got = _ps._kernel(
+        _select, (iq, ik, iw, jnp.asarray(end)), top_k=int(top_k),
+        bq=plan.bq, bk=plan.bk, write=top_k < T, vmem_limit=plan.vmem_limit,
+        interpret=interpret)
+    return got if top_k < T else got + (None,)
+
+
+def index_grads(q, k, iq, ik, iw, lse, tau, index_lse, plan, scale, top_k,
+                coef, interpret=False):
+    """((d_iq, d_ik, d_iw), kept (B, T, T) int8 KEYS by queries): the
+    indexer's kernel of backward at ``plan``'s tiles. The gradients are
+    zeros where ``coef`` is 0, ``kept`` None where ``top_k >= T``."""
+    T = iq.shape[2]
+    zeros = tuple(jnp.zeros_like(x) for x in (iq, ik, iw))
+    if not coef and top_k >= T:
+        return zeros, None
+    _, end = visits(T, plan.bq, plan.bk, True)
+    got = _ps._kernel(
+        _index_grads, (q, k, iq, ik, iw, lse, tau, index_lse,
+                       jnp.asarray(end)),
+        scale=float(scale), coef=float(coef), bq=plan.bq, bk=plan.bk,
+        write=top_k < T, vmem_limit=plan.vmem_limit, interpret=interpret)
+    return (got[:3] if coef else zeros), (got[-1] if top_k < T else None)

@@ -31,9 +31,13 @@ most models; a latent-attention head (DeepSeek-V3's) has keys of 192 = 128
 A selection (:func:`selected_attention`; DeepSeek-V3.2's sparse attention):
 a small indexer scores every earlier token for every query, each query
 keeps its ``top_k`` best and the softmax runs over those alone. The mask is
-then computed in the program, a row at a time, and differs for every query:
-only the ``jax.numpy`` blocks of the one-device path take it; the ring and
-the kernels refuse it by name.
+then computed in the program, a row at a time, and differs for every query.
+The one-device path takes it: where the rule says so (one TPU, a bfloat16
+trunk and indexer) in the kernels (:func:`selected_kernels`: the row's
+threshold is found in VMEM and the kept pairs reach the attention kernels as
+int8 tiles), else in ``jax.numpy`` blocks (:func:`selected_attention`, which
+is also the specification and the tests' oracle). The ring refuses it by
+name.
 """
 
 from __future__ import annotations
@@ -234,7 +238,7 @@ def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q, window=0,
 
 
 def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
-                value_dim=None, select_top_k=0):
+                value_dim=None, select_top_k=0, index_query=None):
     """The rule of the one-device path: the kernels' tiles
     (``ops/flash_attention.plan``) for queries ``q_shape`` (B, H, T, Dk) of
     ``dtype`` over ``kv_heads`` whose values are ``value_dim`` wide (None:
@@ -242,8 +246,9 @@ def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
     executor's, through ``OpMode.platform``; None: jax's default backend)
     in a process that holds one TPU, or None: the ``jax.numpy`` blocks (the
     CPU, several chips, float32, head widths the kernels do not take, T no
-    multiple of a block, a selection: ``select_top_k`` keys a query,
-    chosen in the program). The op and its launch counts
+    multiple of a block; under a selection, ``select_top_k`` keys a query
+    chosen by an indexer whose queries are ``index_query`` (B, J, T, Di),
+    an indexer of another dtype or too narrow). The op and its launch counts
     (``defs_contrib._ring_attention_counts``) ask it with the same
     arguments. A bare traced call (no executor, ``platform`` None)
     assumes the default backend: a plain ``jax.jit`` for the CPU in a
@@ -255,7 +260,9 @@ def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
     return flash_attention.plan(
         platform or jax.default_backend(),
         pallas_support.attached_vmem_bytes(), dtype, heads, kv_heads, T, D,
-        causal, window, value_dim, select_top_k)
+        causal, window, value_dim, select_top_k,
+        None if index_query is None else (
+            index_query.dtype, index_query.shape[1], index_query.shape[3]))
 
 
 def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0, kernels=None,
@@ -358,6 +365,10 @@ blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
 # block of a span reads the keys up to the span's end, masked past its own
 # diagonal: half a span more keys a query than the triangle, on average.
 SELECT_SPAN = 2048
+# What :func:`selected_attention`'s ``jax.numpy`` blocks run at: the programs
+# the rule gives no kernels (the CPU, a float32 trunk or indexer, several
+# chips, an index width or a T the tiles do not take); since PR 52 the Keye
+# cell's bfloat16 layers on one TPU run :func:`selected_kernels` instead.
 # Under a selection a block holds the main heads' float32 score tile AND the
 # indexer's, each read by several element-wise passes (the mask, the 32
 # passes of the threshold's bisection, the KL term). Measured on a v5e at the
@@ -653,6 +664,59 @@ def _selected_bwd(scale, block_q, top_k, loss_coef, span, res, d_out):
 selected_attention.defvjp(_selected_fwd, _selected_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def selected_kernels(q, k, v, iq, ik, iw, scale, top_k, loss_coef, kernels,
+                     interpret=False):
+    """:func:`selected_attention` in the Pallas kernels of
+    ``ops/flash_attention.py`` at the tiles ``kernels`` (a ``Plan`` of the
+    rule :func:`kernel_plan`): no (queries x keys) array in float32, the
+    main heads' or the indexer's, reaches HBM. Forward: ``select`` forms a
+    query block's index scores into a row buffer in VMEM, finds each row's
+    threshold there and writes the block's kept pairs as int8 (T x T a
+    batch row, transient), which the attention kernel applies to every tile
+    it visits. Backward: ``index_grads`` forms the index scores again,
+    writes the kept pairs from the kept thresholds, keys by queries as the
+    backward kernel's tiles are, and pulls the indexer's term back through
+    them (``P`` from all the query heads' scores, formed again there in
+    float32); then the backward kernel over those pairs. Kept beside the
+    operands: the output, the rows' log-sum-exp, thresholds and the kept
+    index scores' log-sum-exp. Where ``top_k >= T`` the dense kernels run
+    as for causal attention, and the indexer's term is added."""
+    return _selected_kernels_fwd(q, k, v, iq, ik, iw, scale, top_k,
+                                 loss_coef, kernels, interpret)[0]
+
+
+def _selected_kernels_fwd(q, k, v, iq, ik, iw, scale, top_k, loss_coef,
+                          kernels, interpret):
+    from ..ops import flash_attention
+
+    _check_selection(q, iq, ik, iw)
+    with jax.named_scope("attention.select"):
+        tau, index_lse, kept = flash_attention.select(iq, ik, iw, kernels,
+                                                      top_k, interpret)
+    out, lse = flash_attention.attention(q, k, v, kernels, scale, True, 0,
+                                         interpret, kept)
+    out, lse, tau, index_lse = keep((out, lse, tau, index_lse))
+    return out, (q, k, v, iq, ik, iw, out, lse, tau, index_lse)
+
+
+def _selected_kernels_bwd(scale, top_k, loss_coef, kernels, interpret, res,
+                          d_out):
+    from ..ops import flash_attention
+
+    q, k, v, iq, ik, iw, out, lse, tau, index_lse = res
+    with jax.named_scope("attention.select"):
+        index_grads, kept = flash_attention.index_grads(
+            q, k, iq, ik, iw, lse, tau, index_lse, kernels, scale, top_k,
+            loss_coef, interpret)
+    return flash_attention.attention_grads(
+        q, k, v, out, lse, d_out, kernels, scale, True, 0, interpret,
+        kept) + tuple(index_grads)
+
+
+selected_kernels.defvjp(_selected_kernels_fwd, _selected_kernels_bwd)
+
+
 def _refuse_on_the_ring(q, k, window, select=None):
     """The ring rotates whole key/value blocks of equal head count: it has
     neither the band's block plan nor grouped heads (ROADMAP Reach 3), and
@@ -734,18 +798,23 @@ def _on_one_device(q, k, v, causal, scale, window, platform=None,
     """:func:`blockwise_attention` with what the rule and ``block_q_of``
     say for these operands; ``platform`` None: where a concrete q lives,
     jax's default backend for a tracer. Under a selection
-    :func:`selected_attention` at ``select_block_q``'s blocks: the rule has
-    no kernels for one."""
+    :func:`selected_kernels` where the rule says so, else
+    :func:`selected_attention` at ``select_block_q``'s blocks."""
+    platform = platform or platform_of([q])
     if select is not None:
         if not causal or window:
             raise MXNetError("attention: select_top_k needs causal=True and "
                              "no window")
         iq, ik, iw, top_k, loss_coef = select
+        kernels = kernel_plan(q.dtype, q.shape, k.shape[1], True, 0,
+                              platform, v.shape[-1], top_k, iq)
+        if kernels is not None:
+            return selected_kernels(q, k, v, iq, ik, iw, scale, top_k,
+                                    loss_coef, kernels)
         return selected_attention(
             q, k, v, iq, ik, iw, scale,
             select_block_q(q.shape[0], q.shape[1], q.shape[2]), top_k,
             loss_coef, SELECT_SPAN)
-    platform = platform or platform_of([q])
     return blockwise_attention(
         q, k, v, causal, scale,
         block_q_of(q.shape[0], q.shape[1], q.shape[2], window), window,

@@ -544,6 +544,53 @@ def test_latent_attention_kernels_compile_for_a_v5e_at_the_cell_widths(
         4 * heads * t * 256 * 2 + (8 << 20)
 
 
+@pytest.mark.parametrize("top_k", [2048, 16384])
+def test_selected_attention_kernels_compile_for_a_v5e_at_the_cell_widths(
+        one_chip, top_k):
+    """Mosaic accepts the four kernels of a selection at the Keye cell's
+    layer (32 over 4 heads of 128, 16 index heads of 64, T 16 384, keep
+    2048; the rule's tiles 128 x 256) and, where nothing is selected, the
+    dense kernels at theirs with the indexer's two. No (queries x keys)
+    array in float32 is among the program's arrays, forward or backward:
+    the widest is the kept pairs in int8 (T x T, forward's then
+    backward's), the temporaries are those and the rows' float32 delta."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import flash_attention as fa
+
+    ra = importlib.import_module("mxnet_tpu.parallel.ring_attention")
+    heads, kv, t, d, j, di = 32, 4, 16384, 128, 16, 64
+    plan = fa.plan("tpu", V5E_VMEM, jnp.bfloat16, heads, kv, t, d, True, 0,
+                   d, top_k, (jnp.bfloat16, j, di))
+    assert (plan.bq, plan.bk) == ((128, 256) if top_k < t else (128, 512))
+
+    def step(*args):
+        out, vjp = jax.vjp(lambda *a: ra.selected_kernels(
+            *a, d ** -0.5, top_k, 1.0, plan), *args[:-1])
+        return (out,) + vjp(args[-1])
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        arg(1, heads, t, d), arg(1, kv, t, d), arg(1, kv, t, d),
+        arg(1, j, t, di), arg(1, 1, t, di), arg(1, j, t),
+        arg(1, heads, t, d)).compile()
+    text = compiled.as_text()
+    for name in ("attention_select", "attention_fwd", "attention_index_bwd",
+                 "attention_bwd"):
+        assert name in text
+    floats = max(int(np.prod([int(n) for n in dims.split(",")]))
+                 for dims in re.findall(r"(?:f32|bf16)\[([0-9,]+)\]", text))
+    assert floats == heads * t * d
+    assert (f"s8[1,{t},{t}]" in text) == (top_k < t)
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        t * t * (top_k < t) + (96 << 20)
+
+
 def test_gated_delta_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip):
     """Mosaic accepts the kernels of the gated delta rule's chunk-local
     algebra (``ops/gated_delta_kernels.py``; their other tests are in

@@ -343,7 +343,7 @@ def test_the_indexers_gradient_is_the_closed_form():
         assert not exe.grad_dict[n].asnumpy().any(), n
 
 
-def test_the_ring_and_the_kernels_refuse_a_selection_by_name():
+def test_the_ring_refuses_a_selection_by_name_and_the_rule_takes_one():
     import jax.numpy as jnp
 
     from mxnet_tpu import parallel
@@ -360,12 +360,18 @@ def test_the_ring_and_the_kernels_refuse_a_selection_by_name():
     with pytest.raises(MXNetError, match="index_key"):
         ra.ring_attention(q, k, v, mesh=None, causal=True,
                           select=(iq, ik[:, :, :8], iw, 8, 1.0))
-    # the rule, with everything else the kernels take
+    # the rule: the kernels take a selection whose indexer they are told
+    # (tests/test_selected_kernels.py has its cases); the process holds no
+    # TPU here, so ``kernel_plan`` answers None whatever it is asked
     args = ("tpu", 128 << 20, jnp.bfloat16, 32, 4, 16384, 128)
-    assert flash_attention.plan(*args) is not None
+    assert tuple(flash_attention.plan(*args))[:2] == (128, 512)
+    assert tuple(flash_attention.plan(
+        *args, select_top_k=2048, index=(jnp.bfloat16, 16, 64)))[:2] == \
+        (128, 256)
     assert flash_attention.plan(*args, select_top_k=2048) is None
     assert ra.kernel_plan(jnp.bfloat16, (1, 32, 16384, 128), 4, True, 0,
-                          "tpu", 128, select_top_k=2048) is None
+                          "tpu", 128, select_top_k=2048,
+                          index_query=iq) is None
 
 
 @pytest.mark.parametrize("batch,t,top_k", [(2, 32, 8), (1, 4096, 2048)])
@@ -393,7 +399,7 @@ def test_launch_counts_are_the_closed_forms(batch, t, top_k):
     block = ra.select_block_q(batch, heads, t)
     assert got["executor.attention_scored_pairs"] == batch * heads * sum(
         (b - a) * b for a, b, _ in ra.select_plan(t, block))
-    assert got["executor.attention_kernel_layers"] == 0
+    assert got["executor.attention_kernel_layers"] == 0     # the CPU
     dense = op.launch_counts(ins[:3], None, dict(params, select_top_k=0),
                              "cpu")
     assert not [n for n in dense if "selected" in n or "index" in n]
@@ -731,7 +737,7 @@ def test_counters_under_recomputation(monkeypatch):
     assert delta("attention_index_pairs") == 2 * B * TINY["sa_config"][
         "indexer_num_heads"] * T * (T + 1) // 2
     assert delta("attention_scored_pairs") == 2 * B * 8 * T * T
-    assert delta("attention_kernel_layers") == 0
+    assert delta("attention_kernel_layers") == 0       # the CPU's blocks
     assert delta("moe_local_experts") == 2 * 4
     assert delta("kept_residual_nodes") == 4     # two attention, two MoE
 

@@ -53,6 +53,22 @@ def _attention(steer, kernels=False, kv_heads=2, **params):
     return sym, shapes, {n: "bfloat16" for n in "qkv"}
 
 
+def _selected_attention(steer):
+    """``RingAttention`` under a selection in the four kernels of
+    ``flash_attention`` (interpreted)."""
+    names = ("q", "k", "v", "iq", "ik", "iw")
+    sym = mx.sym.RingAttention(*[mx.sym.Variable(n) for n in names],
+                               causal=True, select_top_k=64,
+                               index_loss_coef=1.0, name="attention")
+    shapes = dict(q=(1, 4, T, 128), k=(1, 2, T, 128), v=(1, 2, T, 128),
+                  iq=(1, 4, T, 64), ik=(1, 1, T, 64), iw=(1, 4, T))
+    selected = ra.selected_kernels
+    steer.setattr(ra, "kernel_plan",
+                  lambda *a, **kw: fa.Plan(128, 128, 64 << 20))
+    steer.setattr(ra, "selected_kernels", lambda *a: selected(*a, True))
+    return sym, shapes, {n: "bfloat16" for n in names}
+
+
 def _gated_delta(steer, kernels=False, channel=False):
     names = ("query", "key", "value", "g", "beta")
     sym = mx.sym.GatedDeltaRule(*[mx.sym.Variable(n) for n in names],
@@ -98,6 +114,10 @@ CASES = {
     "attention-window-kernels": (
         functools.partial(_attention, kernels=True, window=128), True,
         "pallas_call", 1, 2),
+    # the indexer's backward kernel and the attention's, with the select
+    # and the forward kernel again or not
+    "attention-select-kernels": (_selected_attention, True, "pallas_call",
+                                 2, 4),
     # the chunk-local algebra's kernel and the scan's: two backward kernels,
     # with both forward kernels again or not
     "gated-delta-kernels": (
@@ -233,7 +253,10 @@ def test_checkpoint_saves_the_marked_values_and_nothing_the_rule_forbids(
             or "jitted function" in why, why
         assert aval.size * aval.dtype.itemsize <= 4 * widest, (aval, why)
     shapes = sorted({aval.shape for aval, _ in kept})
-    if name.startswith("attention"):
+    if name == "attention-select-kernels":
+        # and the rows' thresholds and the kept index scores' log-sum-exp
+        assert shapes == [(1, 4, T), (1, 4, T, 128), (1, T)]
+    elif name.startswith("attention"):
         # the output and the rows' log-sum-exp: no (T, T) tile
         assert shapes == [(1, 4, T), (1, 4, T, 128)]
     elif name == "gated-delta-kernels":
