@@ -54,6 +54,8 @@ import math
 import os
 import pickle
 import threading
+import weakref
+from typing import NamedTuple
 
 from . import env as _env
 from . import telemetry as _tm
@@ -63,8 +65,8 @@ _CACHE_FORMAT = 2  # bump to invalidate every persisted executable
 _SUFFIX = ".aotx"
 
 __all__ = [
-    "AOTProgram", "DonatedCallError", "cache_enabled", "cache_dir", "digest",
-    "load", "store",
+    "AOTProgram", "DonatedCallError", "ProgramMemory", "memory_table",
+    "cache_enabled", "cache_dir", "digest", "load", "store",
     "supports_serialization", "choose_train_window", "train_window_setting",
     "choose_dispatch_depth", "dispatch_depth_setting",
     "TrainWindowScheduler",
@@ -249,7 +251,51 @@ def store(key_digest, compiled):
 class DonatedCallError(MXNetError):
     """A donating executable failed after it was called: the buffers it
     was given are consumed, so the call can be neither retried nor rolled
-    back, and what they held must be restored from outside."""
+    back, and what they held must be restored from outside. Where the
+    backend ran out of memory, ``memory`` is :func:`memory_table`'s text
+    (else empty) and the message ends in it."""
+
+    def __init__(self, message, memory=""):
+        super().__init__(message + memory)
+        self.memory = memory
+
+
+class ProgramMemory(NamedTuple):
+    """What one executable needs of its device, in bytes, as XLA's
+    ``memory_analysis()`` gives it (on a mesh: one device's share)."""
+    argument: int  # every argument, donated or not
+    output: int    # every output, those written over a donated argument too
+    alias: int     # the outputs that reuse a donated argument
+    temp: int      # XLA's temporaries: activations, residuals, scratch
+    code: int      # generated code
+
+    @property
+    def kept_output(self):
+        """What a launch allocates anew and hands its caller."""
+        return self.output - self.alias
+
+    @property
+    def footprint(self):
+        return self.argument + self.kept_output + self.temp + self.code
+
+
+def _memory_of(executable):
+    """``executable``'s :class:`ProgramMemory`, or None where the backend
+    (or a deserialised executable) gives no analysis: never a zero that
+    reads as a measurement. One host call, no device work."""
+    try:
+        m = executable.memory_analysis()
+        return ProgramMemory(
+            int(m.argument_size_in_bytes), int(m.output_size_in_bytes),
+            int(m.alias_size_in_bytes), int(m.temp_size_in_bytes),
+            int(m.generated_code_size_in_bytes))
+    except Exception:
+        return None
+
+
+# every AOTProgram that holds an executable, for memory_table(); weak, so a
+# dropped executor's programs leave with it
+_resolved = weakref.WeakSet()
 
 
 class AOTProgram:
@@ -271,17 +317,28 @@ class AOTProgram:
     failure of the executable's call raises :class:`DonatedCallError`.
     ``on_compile(lowered, compiled, args)`` is called after each real
     compile (not after a cache read, which lowers nothing).
+
+    ``label`` names the program in :func:`memory_table` (the caller knows
+    the kind); ``memory`` is the resolved executable's
+    :class:`ProgramMemory` (None before, and where it gives no analysis)
+    and ``launches`` counts the calls of its executable.
     """
 
     __slots__ = ("jit_fn", "key_digest", "executable", "donates",
-                 "on_compile", "_counter", "_span", "_fallback", "_lock")
+                 "on_compile", "label", "memory", "_launches", "_counter",
+                 "_span", "_fallback", "_lock", "__weakref__")
 
     def __init__(self, jit_fn, key_digest=None,
                  compile_counter="aot.trace_compile",
-                 compile_span="aot.compile", donates=False, on_compile=None):
+                 compile_span="aot.compile", donates=False, on_compile=None,
+                 label=None):
         self.jit_fn = jit_fn
         self.key_digest = key_digest
         self.executable = None
+        self.label = label
+        self.memory = None
+        # this program's own, in no registry: launches come from any thread
+        self._launches = _tm.Counter("launches")
         self.donates = donates
         self.on_compile = on_compile
         self._counter = compile_counter
@@ -289,37 +346,43 @@ class AOTProgram:
         self._fallback = False
         self._lock = threading.Lock()
 
+    @property
+    def launches(self):
+        return self._launches.value
+
     def _resolve(self, args):
         with self._lock:
             if self.executable is not None or self._fallback:
                 return self.executable
-            loaded = load(self.key_digest)
-            if loaded is not None:
-                self.executable = loaded
-                return loaded
-            try:
-                _tm.counter(self._counter).inc()  # graftlint: allow=telemetry-catalog(forwards a constructor-chosen literal: executor.jit_compile, executor.fused_plan_compile or aot.trace_compile, all catalogued)
-                with _tm.span(self._span):  # graftlint: allow=telemetry-catalog(forwards a constructor-chosen literal: executor.jit_build or aot.compile, both catalogued)
-                    with _tm.span("executor.trace_lower"):
-                        lowered = self.jit_fn.lower(*args)
-                    # jax's persistent cache answers inside compile(): a
-                    # read is this layer's work too
-                    with _tm.span("executor.compile"):
-                        compiled = lowered.compile()
-            except Exception:
-                if self.donates:
-                    raise  # nothing was donated; the jit path would donate
-                # tracing raised (e.g. a graph-contract error) or AOT
-                # lowering is unsupported here: let the jit path surface
-                # the same behaviour
-                _tm.counter("aot.compile_fallback").inc()
-                self._fallback = True
-                return None
-            if self.on_compile is not None:
-                self.on_compile(lowered, compiled, args)
-            store(self.key_digest, compiled)
-            self.executable = compiled
-            return compiled
+            executable = load(self.key_digest)
+            if executable is None:
+                try:
+                    _tm.counter(self._counter).inc()  # graftlint: allow=telemetry-catalog(forwards a constructor-chosen literal: executor.jit_compile, executor.fused_plan_compile or aot.trace_compile, all catalogued)
+                    with _tm.span(self._span):  # graftlint: allow=telemetry-catalog(forwards a constructor-chosen literal: executor.jit_build or aot.compile, both catalogued)
+                        with _tm.span("executor.trace_lower"):
+                            lowered = self.jit_fn.lower(*args)
+                        # jax's persistent cache answers inside compile():
+                        # a read is this layer's work too
+                        with _tm.span("executor.compile"):
+                            executable = lowered.compile()
+                except Exception:
+                    if self.donates:
+                        raise  # nothing was donated; the jit path would
+                    # tracing raised (e.g. a graph-contract error) or AOT
+                    # lowering is unsupported here: let the jit path
+                    # surface the same behaviour
+                    _tm.counter("aot.compile_fallback").inc()
+                    self._fallback = True
+                    return None
+                if self.on_compile is not None:
+                    self.on_compile(lowered, executable, args)
+                store(self.key_digest, executable)
+            # in hand, by a compile or a read from either cache: keep with
+            # it what it needs of the device (one host call, at set-up)
+            self.executable = executable
+            self.memory = _memory_of(executable)
+            _resolved.add(self)
+            return executable
 
     def ensure_compiled(self, args):
         """Resolve the executable (load or compile) without executing.
@@ -334,14 +397,19 @@ class AOTProgram:
                 exe = self._resolve(args)
             if exe is None:
                 return self.jit_fn(*args)
+        self._launches.inc()
         try:
             with _tm.span("executor.launch"):
                 return exe(*args)
         except Exception as e:
             if self.donates:
+                # out of memory: say what holds it, while it still does
+                full = "RESOURCE_EXHAUSTED" in str(e)
                 raise DonatedCallError(
                     "a donating executable failed after it was called; the "
-                    "buffers donated to it are consumed") from e
+                    "buffers donated to it are consumed",
+                    memory="\n" + memory_table()["text"] if full else "",
+                ) from e
             # aval mismatch (an argument changed device/layout in a way the
             # executable rejects) — the jit path handles it; stop using AOT
             # for this program rather than paying a failed call per step
@@ -350,6 +418,67 @@ class AOTProgram:
                 self.executable = None
                 self._fallback = True
             return self.jit_fn(*args)
+
+
+# --- the memory table --------------------------------------------------------
+
+_DEVICE_STATS = ("bytes_in_use", "bytes_reserved", "peak_bytes_in_use",
+                 "peak_bytes_reserved", "bytes_limit")
+
+
+def memory_table():
+    """What holds the devices' memory, from the host alone.
+
+    ``programs``: one row for every :class:`AOTProgram` of this process
+    that holds an executable, heaviest first: ``label``, the five numbers
+    of its :class:`ProgramMemory` and ``kept_output_bytes`` /
+    ``footprint_bytes`` (every one None where the executable gave no
+    analysis), ``launches``. ``devices``: each local device's own
+    ``memory_stats()`` (None where the backend keeps none: the CPU).
+    ``text``: both, in GiB (MiB where nothing reaches one), for people
+    and for the message of an out-of-memory error. A program's arguments
+    and kept outputs are its caller's arrays, counted in a device's
+    ``bytes_in_use``; its temporaries are the executable's own, which the
+    TPU holds in ``bytes_reserved`` while the program is loaded
+    (docs/observability.md, "Where the memory goes").
+    """
+    import jax
+
+    programs = []
+    for prog in list(_resolved):
+        m = prog.memory
+        row = {"label": prog.label or "unlabelled", "launches": prog.launches}
+        for field in ProgramMemory._fields + ("kept_output", "footprint"):
+            row[field + "_bytes"] = None if m is None else getattr(m, field)
+        programs.append(row)
+    programs.sort(key=lambda r: (-(r["footprint_bytes"] or 0), r["label"]))
+    devices = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        devices.append({"device": str(d), **{
+            k: stats.get(k) for k in _DEVICE_STATS}})
+
+    # GiB where anything shown reaches one (a chip's programs), MiB below
+    # (a test's): no row of a small model reads 0.000
+    shown = [r["footprint_bytes"] or 0 for r in programs] + [
+        d["bytes_limit"] or 0 for d in devices]
+    unit, size = ("GiB", 1 << 30) if max(shown, default=0) >> 30 else (
+        "MiB", 1 << 20)
+
+    def cell(v):
+        return "None" if v is None else f"{v / size:.3f}"
+
+    cols = ("argument", "kept_output", "alias", "temp", "code", "footprint")
+    lines = [f"programs resolved in this process ({unit} a device; "
+             "kept_output = output - alias):",
+             "  " + " ".join(f"{c:>11}" for c in cols) + "  launches  label"]
+    lines += ["  " + " ".join(f"{cell(r[c + '_bytes']):>11}" for c in cols)
+              + f"  {r['launches']:>8}  {r['label']}" for r in programs]
+    lines.append(f"devices ({unit}; memory_stats()):")
+    lines += [f"  {d['device']}: " + ", ".join(
+        f"{k} {cell(d[k])}" for k in _DEVICE_STATS) for d in devices]
+    return {"programs": programs, "devices": devices,
+            "text": "\n".join(lines)}
 
 
 # --- adaptive train-window scheduler ---------------------------------------

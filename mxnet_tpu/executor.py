@@ -29,6 +29,7 @@ bulk execution disables itself when a monitor is installed
 
 from __future__ import annotations
 
+import threading
 from collections import Counter
 from typing import Any, NamedTuple
 
@@ -118,6 +119,25 @@ def _phase(name):
     from . import profiler as _prof
 
     return jax.named_scope(_prof.phase_scope(name))
+
+
+def _share_bytes(handles):
+    """Bytes one device holds of these NDArrays, from their shapes and
+    types: an array sharded over a mesh counts its shard. Host arithmetic;
+    a handle whose array is not there yet counts whole."""
+    total = 0
+    for h in handles:
+        shape = h.shape
+        sharding = getattr(h._d, "sharding", None)
+        if sharding is not None:
+            shape = sharding.shard_shape(shape)
+        total += int(np.prod(shape)) * np_dtype(h.dtype).itemsize
+    return total
+
+
+# held while Executor._note_train_memory sets its gauges, so that all of
+# them stay ONE program's when two threads launch train programs at once
+_HEAVIEST_LOCK = threading.Lock()
 
 
 class _Packs(NamedTuple):
@@ -307,6 +327,8 @@ class _TrainPlan(NamedTuple):
     upd_idx: list        # positions of the updated arguments
     other_idx: list      # positions of the rest
     st_pack: Any         # pack of the small optimizer-state leaves, or None
+    grad_bytes: int      # the gradients it publishes (0: none), a device's
+    state_bytes: int     # updated parameters + optimizer + aux states
 
 
 def _unpublished(out):
@@ -770,6 +792,7 @@ class Executor:
         self._guard_dev = None  # device [total, consec] non-finite counters
         self._fc_plan = None  # memoized _shared_fc_plan
         self._grads_crowd = None  # memoized _grads_crowd_device
+        self._held_memo = None  # memoized _held_bytes
         # op nodes the train programs' trace lowered under a scope, those
         # of them whose recomputation kept a residual the op named, and
         # what their ops declared a launch counts (None: not traced here)
@@ -1259,7 +1282,6 @@ class Executor:
                      current_mesh())
         fn = self._jit_cache.get(cache_key)
         if fn is not None:
-            _tm.counter("executor.jit_cache_hit").inc()
             return fn
         packs = self._packs()
         graph = self.graph
@@ -1314,6 +1336,9 @@ class Executor:
                 # surfacing; deserialized warm starts don't count
                 compile_counter="executor.jit_compile",
                 compile_span="executor.jit_build",
+                label=self._program_label(
+                    "train step" if kind == "train_step" else
+                    "forward (train)" if is_train else "forward"),
             )
         self._jit_cache[cache_key] = fn
         return fn
@@ -1470,14 +1495,31 @@ class Executor:
         (the CPU). The sizes behind the eighth: docs/architecture.md."""
         if self._grads_crowd is None:
             limit = (self._ctx.memory_stats() or {}).get("bytes_limit")
-            # a gradient has its argument's shape and dtype; its own
-            # handle may be a scheduled backward, which a read would run
-            held = sum(
-                int(np.prod(self.arg_dict[n].shape))
-                * np_dtype(self.arg_dict[n].dtype).itemsize
-                for n in self._wrt_names)
-            self._grads_crowd = bool(limit) and held * 8 > limit
+            self._grads_crowd = (bool(limit)
+                                 and self._held_bytes()[0] * 8 > limit)
         return self._grads_crowd
+
+    def _held_bytes(self):
+        """``(one set of this executor's gradients, its auxiliary states)``,
+        a device's share in bytes. A gradient has its argument's shape,
+        dtype and sharding; its own handle may be a scheduled backward,
+        which a read would run."""
+        if self._held_memo is None:
+            self._held_memo = (
+                _share_bytes(self.arg_dict[n] for n in self._wrt_names),
+                _share_bytes(self.aux_dict.values()))
+        return self._held_memo
+
+    def _program_label(self, kind):
+        """``kind`` and the shapes of the first and the last input no
+        gradient is taken of (the batch and its labels: what tells one
+        bucket's program from another's), for ``aot.memory_table``'s
+        rows."""
+        fed = [n for n in self.arg_names if self.grad_req[n] == "null"]
+        shown = fed if len(fed) < 3 else [fed[0], None, fed[-1]]
+        return f"{kind} [" + ", ".join(
+            "..." if n is None else f"{n}{tuple(self.arg_dict[n].shape)}"
+            for n in shown) + "]"
 
     def _declared_from_shapes(self):
         """What the graph's nodes declare one launch of a train program
@@ -1535,6 +1577,36 @@ class Executor:
         for name, n in self._launch_counts.items():
             if n:  # a counter only where the graph holds such a layer
                 _tm.counter(name).inc(n)  # graftlint: allow=telemetry-catalog(forwards the literal names the graph's ops list as OpDef.launch_instruments; tests/test_launch_counts.py holds every one to docs/observability.md)
+
+    @staticmethod
+    def _note_train_memory(program, grad_bytes, state_bytes):
+        """The memory ledger's gauges, at the launch of a train program:
+        what ``program``'s executable needs of a device (``aot.AOTProgram.
+        memory``, read once where it was resolved), the gradients it
+        publishes and the training state it carries, set together where it
+        is the heaviest train program launched since the last
+        ``telemetry.reset()``, so that the six describe ONE program
+        however many a step switches between; a reset zeroes them and the
+        next launch sets them again. A few host reads a launch, no device
+        call. A program with no analysis (an interpreted one, a backend
+        that gives none) sets nothing."""
+        mem = getattr(program, "memory", None)
+        if mem is None:
+            return
+        gauges = (_tm.gauge("executor.program_argument_bytes"),
+                  _tm.gauge("executor.program_kept_output_bytes"),
+                  _tm.gauge("executor.program_temp_bytes"),
+                  _tm.gauge("executor.program_code_bytes"))
+        if mem.footprint <= sum(g.value for g in gauges):
+            return
+        with _HEAVIEST_LOCK:
+            if mem.footprint <= sum(g.value for g in gauges):
+                return
+            for g, v in zip(gauges, (mem.argument, mem.kept_output,
+                                     mem.temp, mem.code)):
+                g.set(v)
+            _tm.gauge("executor.published_grad_bytes").set(grad_bytes)
+            _tm.gauge("executor.train_state_bytes").set(state_bytes)
 
     def _make_grad_core(self):
         """Shared fwd+bwd tracing core used by both the plain train_step
@@ -1805,15 +1877,21 @@ class Executor:
                 self._bwd_aux, getattr(self, "_bwd_aux_flat", None),
                 self._bwd_rng, head_grads, self._bwd_prev,
             )
-        self._finish_backward(outs, aux_upd, aux_flat_out, grad_map,
-                              grad_flat, next_step)
+        # it updates nothing: the auxiliary states are all it carries
+        self._finish_backward(
+            outs, aux_upd, aux_flat_out, grad_map, grad_flat, next_step,
+            program=fn, grad_bytes=self._held_bytes()[0],
+            state_bytes=self._held_bytes()[1])
 
     def _finish_backward(self, outs, aux, aux_flat, grads, grad_flat,
-                         next_step, n_steps=1):
+                         next_step, n_steps=1, *, program, grad_bytes,
+                         state_bytes):
         """The scheduled backward ran, alone or inside a fused program of
         ``n_steps`` steps: adopt its outputs, aux states, gradients (None:
-        not published) and step counter."""
+        not published) and step counter. ``program`` launched it, and
+        published ``grad_bytes`` and carried ``state_bytes`` a device."""
         self._count_train_launch()
+        self._note_train_memory(program, grad_bytes, state_bytes)
         self._accept_next_step(
             next_step,
             getattr(self, "_bwd_rng_val", self._step) + (n_steps - 1))
@@ -2045,8 +2123,16 @@ class Executor:
             compile_counter="executor.fused_plan_compile",
             compile_span="executor.jit_build",
             donates=True, on_compile=_record_fused_hlo,
+            label=self._program_label(
+                "fused update" if key.n_steps == 1
+                else f"fused window x{key.n_steps}"),
         )
-        return _TrainPlan(key, program, upd_idx, other_idx, st_pack)
+        return _TrainPlan(
+            key, program, upd_idx, other_idx, st_pack,
+            grad_bytes=self._held_bytes()[0] if key.publish else 0,
+            state_bytes=self._held_bytes()[1] + _share_bytes(
+                [self.arg_dict[n] for n in key.update_names]
+                + list(state_handles)))
 
     def _stage_train_args(self, plan, state_handles, lrs, wds, ts,
                           stack_vals):
@@ -2235,10 +2321,13 @@ class Executor:
             raise _aot.DonatedCallError(
                 "fused train step failed after buffer donation; executor "
                 "parameters were invalidated — re-initialize via "
-                "set_params()/load before continuing") from e.__cause__
+                "set_params()/load before continuing",
+                memory=e.memory) from e.__cause__
         self._guard_dev = out.guard
-        self._finish_backward(out.outs, out.aux, out.aux_flat, out.grads,
-                              out.grad_flat, out.step, n_steps)
+        self._finish_backward(
+            out.outs, out.aux, out.aux_flat, out.grads, out.grad_flat,
+            out.step, n_steps, program=plan.program,
+            grad_bytes=plan.grad_bytes, state_bytes=plan.state_bytes)
         # the window consumed n_steps rng values; advance the host counter
         # past them (forward() already took +1) so the device mirror stays
         # warm and the next forward doesn't rewind into consumed streams

@@ -23,6 +23,8 @@ mapping (SURVEY.md §5): the jax/XLA profiler captures the device trace
   the ``telemetry.span`` the host was in. :func:`reduce_trace` is its
   arithmetic, pure over plain tuples; :func:`load_xplane` is the thin part
   that opens the file. ``tools/trace_table.py`` prints the tables.
+* **Memory.** :func:`memory_table` is the same question asked of memory:
+  every resolved program's bytes beside the device's own statistics.
 
 ``dump_profile`` also honours the reference's file contract by extracting
 the chrome-trace JSON out of the captured run and writing it to
@@ -783,6 +785,18 @@ def device_table(trace=None, window=None, top=None, inner=None):
     ops, modules, spans, graph = load_xplane(path)
     return reduce_trace(ops, modules, spans, window=window, top=top,
                         graph=graph, inner=inner)
+
+
+def memory_table():
+    """What holds the devices' memory: one row for every program resolved
+    in this process (arguments, kept outputs, temporaries, code,
+    footprint, launches) beside each device's own ``memory_stats()``;
+    ``print(memory_table()["text"])``. No trace and no device call:
+    :func:`mxnet_tpu.aot.memory_table` keeps it, where the executables
+    are; docs/observability.md, "Where the memory goes"."""
+    from . import aot as _aot
+
+    return _aot.memory_table()
 
 
 def _maybe_autostart():

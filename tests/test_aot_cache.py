@@ -147,7 +147,6 @@ def test_module_compile_warms_all_programs():
     m.forward(b, is_train=False)
     _ = m.get_outputs()[0].asnumpy()
     assert tm.counter("executor.jit_compile").value == compiles
-    assert tm.counter("executor.jit_cache_hit").value >= 2
 
 
 def test_bucketing_compile_warms_buckets_in_parallel():
@@ -182,7 +181,6 @@ def test_bucketing_compile_warms_buckets_in_parallel():
         mod.forward(batch, is_train=False)
         _ = mod.get_outputs()[0].asnumpy()
     assert tm.counter("executor.jit_compile").value == 0
-    assert tm.counter("executor.jit_cache_hit").value >= 2
 
 
 # --- adaptive train-window scheduler ---------------------------------------

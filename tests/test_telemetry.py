@@ -467,7 +467,7 @@ def test_kvstore_counters():
     assert tm.counter("kvstore.pull_bytes").value == 64
 
 
-def test_executor_jit_cache_counters():
+def test_executor_jit_compile_counts_compiles_not_launches():
     d = mx.sym.Variable("data")
     net = mx.sym.FullyConnected(d, num_hidden=4, name="fc")
     exe = net.simple_bind(ctx=mx.cpu(), data=(2, 3), grad_req="null")
@@ -480,7 +480,6 @@ def test_executor_jit_cache_counters():
     exe.forward(is_train=False, data=mx.nd.array(np.ones((2, 3), np.float32)))
     _ = exe.outputs[0].shape
     assert tm.counter("executor.jit_compile").value == compiles  # no recompile
-    assert tm.counter("executor.jit_cache_hit").value >= 1
 
 
 def test_sync_counters_count_blocking_reads():
