@@ -978,10 +978,11 @@ class Executor:
                            force=True)
 
     def _mark_grads_unpublished(self):
-        """After a no-publish training window the gradient buffers were
-        dead-coded out of the program; the old handles would silently serve
-        a PREVIOUS step's values, so every wrt handle raises loudly until
-        the next publishing step overwrites it."""
+        """After a fused step or window that published nothing (every one
+        ``fit`` runs) the gradient buffers were dead-coded out of the
+        program; the old handles would silently serve a PREVIOUS step's
+        values, so every wrt handle raises loudly until the next publishing
+        step overwrites it."""
         for n in self._wrt_names:
             h = self.grad_dict.get(n)
             if h is None:
@@ -999,12 +1000,13 @@ class Executor:
 
             def thunk(n=n):
                 raise MXNetError(
-                    f"gradient '{n}' was not published: the last training "
-                    "window ran with publish_grads=False (pipelined "
-                    "dispatch elides the per-window f32 gradient "
-                    "publication), or update() left out gradients that "
-                    "take over an eighth of the device's memory. Read "
-                    "them after backward() and before update(), or run "
+                    f"gradient '{n}' was not published: the last step ran "
+                    "inside fit(), whose fused steps and windows return no "
+                    "gradients (publish_grads=False: nothing in fit reads "
+                    "them), or update() left out gradients that take over "
+                    "an eighth of the device's memory. Read them after "
+                    "backward() and before update(), or drive the loop by "
+                    "hand with update(publish_grads=True) / "
                     "train_window(..., publish_grads=True).")
 
             if shape is not None:
@@ -1483,12 +1485,14 @@ class Executor:
     def _grads_crowd_device(self):
         """True where one set of this executor's gradients is more than an
         eighth of its device's memory: the default of a fused step whose
-        caller did not say (``Module.update()``, which cannot know whether
-        its gradients will be read) is then to leave them out of what it
-        returns. Published, a step's gradients live beside the next step's
-        (a fourth float32 copy of a model whose weights and Adam moments
-        already fill most of a chip, twice over) for a reader who, in
-        ``fit``, never comes; left out, they are consumed by the update
+        caller did not say is then to leave them out of what it returns.
+        Only a hand-written loop still reaches this (``Module.update()``
+        with no word, which cannot know whether its gradients will be
+        read, and the serial fallback of ``train_window``): ``fit`` says
+        ``publish_grads=False`` on every step and never asks. Published, a
+        step's gradients live beside the next step's (a fourth float32
+        copy of a model whose weights and Adam moments already fill most
+        of a chip, twice over); left out, they are consumed by the update
         where they are computed, and ``grad_dict`` raises until a
         ``backward()`` that is read runs. An explicit ``publish_grads`` is
         always honoured. False where the device does not report its memory
@@ -2262,12 +2266,14 @@ class Executor:
         ``i`` (real epoch windows). The window requires plain ``write``
         gradients and no explicit head gradients.
 
-        ``publish_grads=False`` drops the gradients from what the program
-        returns (XLA dead-codes their f32 casts and the concatenation);
+        ``publish_grads=False`` (what ``fit`` passes on every step and
+        window) drops the gradients from what the program returns: each
+        then has one consumer, the update, so XLA writes no float32 copy
+        of it (nor the casts and the concatenation of the small ones);
         reading ``grad_dict`` then raises MXNetError until the next
-        publishing step runs. ``None`` (what ``Module.update()`` passes)
-        publishes unless one set of gradients is over an eighth of the
-        device's memory (``_grads_crowd_device``).
+        publishing step runs. ``None`` (what ``Module.update()`` called by
+        hand passes) publishes unless one set of gradients is over an
+        eighth of the device's memory (``_grads_crowd_device``).
         """
         if not getattr(self, "_bwd_scheduled", False):
             raise MXNetError(
@@ -2292,8 +2298,9 @@ class Executor:
             # the trace (see _materialize_forward)
             getattr(self, "_bwd_mesh", current_mesh()),
             n_steps, stack_names, self._nonfinite_guard_on(),
-            # the caller's word where it gave one; update() gives none, and
-            # then gradients are published unless they crowd the device
+            # the caller's word where it gave one (fit: never); update() by
+            # hand gives none, and then gradients are published unless they
+            # crowd the device
             (not self._grads_crowd_device() if publish_grads is None
              else bool(publish_grads)))
         plan = self._fused_plan.get(key)

@@ -364,6 +364,14 @@ class BaseModule:
         superseded), so a killed job relaunched by ``tools/launch.py
         --max-restarts`` continues mid-training instead of restarting.
         ``None`` consults ``MXNET_CHECKPOINT_DIR``.
+
+        ``fit`` reads no gradients, so where a step (or a window of them)
+        runs as one fused program that program returns none: a
+        ``batch_end_callback`` that reads ``grad_dict`` raises
+        ``MXNetError``. To see gradients, install a ``monitor`` (the step
+        then runs unfused), or drive the loop by hand: read them after
+        ``backward()`` and before ``update()``, or call
+        ``update(publish_grads=True)``.
         """
         assert num_epoch is not None, "please specify number of epochs"
 
@@ -549,7 +557,7 @@ class BaseModule:
                                 for b in chunk:
                                     with _tm.span("fit.dispatch"):
                                         self.forward_backward(b)
-                                        self.update()
+                                        self._update_unread()
                                     ahead.observe(self._step_token())
                                     with _tm.span("fit.metric"):
                                         self.update_metric(eval_metric, b.label)
@@ -625,7 +633,7 @@ class BaseModule:
                         with _tm.span("fit.dispatch"):
                             self.forward_backward(data_batch)
                             try:
-                                self.update()
+                                self._update_unread()
                             except ElasticServerLost as e:
                                 # the elastic coordinator restarted and lost
                                 # its store: re-seed it from this survivor's
@@ -636,7 +644,7 @@ class BaseModule:
                                     raise
                                 self.logger.warning("fit: %s", e)
                                 self._elastic_reseed()  # graftlint: allow=host-sync(coordinator-restart recovery — a one-shot re-seed of the restarted store is a deliberate cold fence)
-                                self.update()
+                                self._update_unread()
                         ahead.observe(self._step_token())
                         # fetch + stage the successor while this step's results
                         # are still in flight (the device computes under the
@@ -836,6 +844,13 @@ class BaseModule:
 
     def update_metric(self, eval_metric, labels):
         raise NotImplementedError()
+
+    def _update_unread(self):
+        """``update()`` for the caller who knows that nobody reads this
+        step's gradients: ``fit``. A module whose step is one fused program
+        then leaves them out of what the program returns
+        (``Module.update(publish_grads=False)``)."""
+        self.update()
 
     def _step_token(self):
         """A device scalar that the last dispatched step's program returned,

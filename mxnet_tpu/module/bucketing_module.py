@@ -360,10 +360,15 @@ class BucketingModule(BaseModule):
         self._require(bound=True, params=True)
         self._curr_module.backward(out_grads=out_grads)
 
-    def update(self):
+    def update(self, publish_grads=None):
+        """The current bucket's ``Module.update``, ``publish_grads`` and
+        all."""
         self._require(bound=True, params=True, optimizer=True)
         self._params_dirty = True
-        self._curr_module.update()
+        self._curr_module.update(publish_grads=publish_grads)
+
+    def _update_unread(self):
+        self.update(publish_grads=False)
 
     def get_outputs(self, merge_multi_context=True):
         self._require(bound=True, params=True)

@@ -480,9 +480,12 @@ class Module(BaseModule):
         Where the step runs as one fused program, ``publish_grads`` says
         whether that program returns its gradients for a later read of
         ``grad_dict``: True or False is honoured; None, for a caller who
-        cannot know, publishes them unless one set is over an eighth of
-        the device's memory (``Executor._grads_crowd_device``). The
-        unfused path always leaves them readable."""
+        cannot know (a hand-written loop), publishes them unless one set
+        is over an eighth of the device's memory
+        (``Executor._grads_crowd_device``). ``fit`` knows, and says False
+        on every step (``_update_unread``): a read of ``grad_dict`` after
+        one of its steps raises. The unfused path (a monitor installed, an
+        optimizer that cannot be traced) always leaves them readable."""
         self._require(bound=True, params=True, optimizer=True)
         self._params_dirty = True
         if self._fusable_update():
@@ -509,6 +512,9 @@ class Module(BaseModule):
                 updater=self._updater, num_device=1,
                 kvstore=self._kvstore, param_names=self._exec_group.param_names,
             )
+
+    def _update_unread(self):
+        self.update(publish_grads=False)
 
     def train_window(self, data_batch, n_steps=1, batches=None,
                      publish_grads=True):
