@@ -198,6 +198,17 @@ inline Symbol Embedding(const std::string& symbol_name, const Symbol& data, int6
   return detail::CreateOp("Embedding", symbol_name, 2, keys__, ins__, params__);
 }
 
+inline Symbol ExitSoftmaxOutput(const std::string& symbol_name, const Symbol& exit1, int64_t num_exits, const Symbol* gate1 = nullptr, const Symbol* label = nullptr, double beta = 0.0, double ignore_label = -1.0, bool use_ignore = false) {
+  detail::ParamMap params__;
+  params__.emplace_back("num_exits", detail::str(num_exits));
+  params__.emplace_back("beta", detail::str(beta));
+  params__.emplace_back("ignore_label", detail::str(ignore_label));
+  params__.emplace_back("use_ignore", detail::str(use_ignore));
+  const char* keys__[] = {"exit1", "gate1", "label"};
+  const Symbol* ins__[] = {&exit1, gate1, label};
+  return detail::CreateOp("ExitSoftmaxOutput", symbol_name, 3, keys__, ins__, params__);
+}
+
 inline Symbol Flatten(const std::string& symbol_name, const Symbol& data) {
   detail::ParamMap params__;
   const char* keys__[] = {"data"};

@@ -533,6 +533,22 @@ class _CompiledGraph:
             and len({self.node2dev.get(id(n)) for n in nodes}) == 1
         ]
 
+    def shared_weight_reads(self, names):
+        """The op nodes that read an argument of ``names`` which some other
+        node reads too: the time steps of an unrolled recurrent cell, the
+        passes of a looped stack, a tied head. A node that reads two such
+        arguments (a weight and its bias) is one node."""
+        readers = Counter()
+        for node in self.topo:
+            if not node.is_variable:
+                readers.update({i.name for (i, _ix) in node.inputs
+                                if i.is_variable and not i.is_aux
+                                and i.name in names})
+        return sum(
+            1 for node in self.topo if not node.is_variable and any(
+                readers[i.name] > 1 for (i, _ix) in node.inputs
+                if i.is_variable and not i.is_aux))
+
     def evaluate(self, arg_vals, aux_vals, rng, is_train, monitor=None,
                  limit=None, monitor_all=False, fc_batches=None):
         """Run the graph. Returns (head_outputs, aux_updates_list).
@@ -791,6 +807,7 @@ class Executor:
         self._sym_sha_cache = None  # memoized symbol-graph digest
         self._guard_dev = None  # device [total, consec] non-finite counters
         self._fc_plan = None  # memoized _shared_fc_plan
+        self._shared_reads = None  # memoized graph.shared_weight_reads
         self._grads_crowd = None  # memoized _grads_crowd_device
         self._held_memo = None  # memoized _held_bytes
         # op nodes the train programs' trace lowered under a scope, those
@@ -1563,7 +1580,9 @@ class Executor:
         ``MXNET_AOT_CACHE`` store and was not traced here), those of them
         whose per-operator recomputation (``MXNET_BACKWARD_DO_MIRROR``)
         kept a residual the op named (``ops/registry.keep``), the shared
-        weights whose gradient it computes as one matmul, and what its
+        weights whose gradient it computes as one matmul, the nodes that
+        read a parameter another node reads too (each casts the master
+        where it uses it; their gradients are added in float32), and what its
         nodes' ops declared a launch counts (``OpDef.launch_counts``: the
         sparse-expert, attention, linear-attention and convolution layers'
         counters, docs/observability.md), summed while the trace lowered
@@ -1576,6 +1595,12 @@ class Executor:
         weights = self._shared_fc_plan()[2]
         if weights:
             _tm.counter("executor.stacked_wgrad").inc(weights)
+        if self._shared_reads is None:
+            self._shared_reads = self.graph.shared_weight_reads(
+                set(self._wrt_names))
+        if self._shared_reads:
+            _tm.counter("executor.shared_weight_reads").inc(
+                self._shared_reads)
         if self._launch_counts is None:
             self._launch_counts = self._declared_from_shapes()
         for name, n in self._launch_counts.items():

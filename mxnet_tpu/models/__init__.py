@@ -5,7 +5,9 @@ bucketed LSTM language model and the OLMoE, AFMoE (Trinity), Qwen3-Next,
 DeepSeek-V3 (latent attention), ZAYA1 (compressed convolutional attention,
 an MLP router), Kimi Linear (a delta rule gated a key channel, latent
 attention without positions) and Keye-VL-2.0 (the text decoder: attention over
-the keys a learned indexer selects) sparse-expert decoders.
+the keys a learned indexer selects) sparse-expert decoders, and Ouro (a
+looped dense decoder: one stack run four times over the same weights, an
+exit after every pass).
 
 Reference: ``example/image-classification/symbols/*.py`` and
 ``example/rnn``/``example/gan``. Builders return plain Symbols usable with
@@ -32,6 +34,7 @@ from .deepseek_v3 import deepseek_v3_sym_gen
 from .zaya import zaya_sym_gen
 from .kimi_linear import kimi_linear_sym_gen
 from .keye_vl2 import keye_vl2_sym_gen
+from .ouro import ouro_sym_gen
 from . import ssd
 from . import zoo
 from .zoo import SCORE_SYMBOLS

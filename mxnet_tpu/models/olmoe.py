@@ -8,10 +8,12 @@ from .recipe import low_precision_io
 
 
 # --- pieces the sparse-expert decoders share (``afmoe.py`` too) -------------
-def linear(x, width, name):
-    """A bias-free projection of the last axis."""
+def linear(x, width, name, weight=None):
+    """A bias-free projection of the last axis (``weight``: its variable,
+    where several nodes read one)."""
     return sym.FullyConnected(x, num_hidden=width, no_bias=True,
-                              flatten=False, name=name)
+                              flatten=False, name=name,
+                              **_optional(weight=weight))
 
 
 def split_heads(x, heads, head_dim, norm=None):

@@ -771,6 +771,184 @@ register(
 )
 
 
+# --- ExitSoftmaxOutput: a mixture over the exits of a looped model ----------
+def _exit_log_shares(scores):
+    """``log p`` (rows, R) of the exit distribution of ``scores`` (rows, R),
+    float32: with ``lam_t = sigmoid(score_t)``, ``p_t = lam_t prod_{j<t}
+    (1 - lam_j)`` for t < R and ``p_R = prod_{j<R} (1 - lam_j)``, so every
+    row sums to 1 and the last score is not read. In logs, so that a
+    saturated gate gives a large negative number and never ``log 0``."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-scores[:, :-1]), axis=1)
+    stay = jnp.pad(stay, ((0, 0), (1, 0)))  # log S_(t-1), S_0 = 1
+    leave = jax.nn.log_sigmoid(scores[:, :-1])
+    return jnp.concatenate([leave + stay[:, :-1], stay[:, -1:]], axis=1)
+
+
+def _exit_operands(ins, params):
+    """(exits' logits, gate scores, label) of ``ExitSoftmaxOutput``'s inputs,
+    held to the shapes the operator defines."""
+    r = params["num_exits"]
+    if r < 2:
+        raise MXNetError(
+            f"ExitSoftmaxOutput: num_exits={r}: the mixture needs at least 2 "
+            "exits (one exit is SoftmaxOutput)")
+    if len(ins) != 2 * r + 1:
+        raise MXNetError(f"ExitSoftmaxOutput: num_exits={r} takes {r} exits, "
+                         f"{r} gates and a label, got {len(ins)} inputs")
+    exits, gates, label = ins[:r], ins[r:2 * r], ins[2 * r]
+    if exits[0].ndim == 0:
+        # ``infer_type``'s probe hands every operand over as a scalar: one
+        # row of one class
+        return ([x.reshape(1, 1) for x in exits],
+                [g.reshape(1, 1) for g in gates], label.reshape(1))
+    shape = tuple(exits[0].shape)
+    if len(shape) != 2 or any(tuple(x.shape) != shape for x in exits):
+        raise MXNetError(
+            "ExitSoftmaxOutput: every exit is (rows, classes) logits of one "
+            f"shape, got {[tuple(x.shape) for x in exits]}")
+    if any(tuple(g.shape) != (shape[0], 1) for g in gates):
+        raise MXNetError(
+            f"ExitSoftmaxOutput: a gate is (rows, 1) = ({shape[0]}, 1) "
+            f"scores, got {[tuple(g.shape) for g in gates]}")
+    if tuple(label.shape) != (shape[0],):
+        raise MXNetError(
+            f"ExitSoftmaxOutput: the label is one class id a row, "
+            f"({shape[0]},), got {tuple(label.shape)}")
+    return exits, gates, label
+
+
+def _exit_softmax_output(ins, params, mode):
+    """The loss layer of a looped model that may leave after any of its R
+    passes (Ouro, Zhu et al. 2025, arXiv:2510.25741, Stage I): exit ``t``
+    has logits ``z_t`` and a gate score ``s_t``; the scores make the exit
+    distribution ``p`` (:func:`_exit_log_shares`); and the objective a row
+    is the expected cross-entropy less ``beta`` times the entropy of ``p``::
+
+        J = sum_t p_t l_t + beta sum_t p_t log p_t,  l_t = -log softmax(z_t)[y]
+
+    Forward gives ``softmax(z_R)`` in float32, what the model predicts when
+    it never leaves early. Backward ignores the head gradient, as every
+    loss layer does, and writes ``dJ/dz_t = p_t (softmax(z_t) - onehot(y))``
+    and ``dJ/ds`` through ``dJ/dp_t = l_t + beta (log p_t + 1)``, summed
+    over the rows whose label is not ``ignore_label`` (``use_ignore``); the
+    last gate gets none. Softmaxes, ``log p`` and the sums are float32
+    whatever the logits' dtype. What backward keeps beyond its operands is
+    two float32 numbers a row and exit (the log-sum-exp and the label's
+    logit), named for the per-operator recomputation: each exit's softmax
+    is made again from its logits, in their dtype, where its gradient is
+    written, and no float32 (rows, classes) tensor is kept."""
+    from .registry import keep
+
+    exits, gates, label = _exit_operands(ins, params)
+    beta = params["beta"]
+    use_ignore, ignore_label = params["use_ignore"], params["ignore_label"]
+
+    def rows_stats(z, li):
+        """(log-sum-exp, the label's logit) a row, one pass over ``z``."""
+        z = z.astype(jnp.float32)
+        hit = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1) == li[:, None]
+        return (jax.scipy.special.logsumexp(z, axis=-1),
+                jnp.sum(jnp.where(hit, z, 0.0), axis=-1))
+
+    def forward(zs, l):
+        li = l.astype(jnp.int32)
+        stats = [rows_stats(z, li) for z in zs]
+        out = jnp.exp(zs[-1].astype(jnp.float32) - stats[-1][0][:, None])
+        return out, (jnp.stack([s[0] for s in stats], 1),
+                     jnp.stack([s[1] for s in stats], 1))
+
+    @jax.custom_vjp
+    def f(zs, ss, l):
+        return forward(zs, l)[0]
+
+    def fwd(zs, ss, l):
+        out, (lse, picked) = forward(zs, l)
+        return out, (zs, ss, l, keep((lse, picked)))
+
+    def bwd(res, g):
+        zs, ss, l, (lse, picked) = res
+        li = l.astype(jnp.int32)
+        valid = (l != ignore_label).astype(jnp.float32) if use_ignore \
+            else jnp.ones(l.shape, jnp.float32)
+        nll = lse - picked  # (rows, R)
+        scores = jnp.concatenate(ss, axis=1).astype(jnp.float32)
+
+        def objective(scores):
+            logp = _exit_log_shares(scores)
+            p = jnp.exp(logp)
+            return jnp.sum(valid[:, None] * p * (nll + beta * logp)), p
+
+        d_scores, p = jax.grad(objective, has_aux=True)(scores)
+        weight = valid[:, None] * p
+        d_zs = []
+        for t, z in enumerate(zs):
+            hit = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1) \
+                == li[:, None]
+            soft = jnp.exp(z.astype(jnp.float32) - lse[:, t:t + 1])
+            d_zs.append((weight[:, t:t + 1]
+                         * (soft - hit.astype(jnp.float32))).astype(z.dtype))
+        d_ss = [d_scores[:, t:t + 1].astype(s.dtype)
+                for t, s in enumerate(ss)]
+        return d_zs, d_ss, jnp.zeros_like(l)
+
+    f.defvjp(fwd, bwd)
+    out = f(list(exits), list(gates), label)
+    return out.reshape(()) if ins[0].ndim == 0 else out
+
+
+def _exit_softmax_output_args(p):
+    r = p["num_exits"]
+    return [f"exit{t}" for t in range(1, r + 1)] \
+        + [f"gate{t}" for t in range(1, r + 1)] + ["label"]
+
+
+def _exit_softmax_output_fill(shapes, params):
+    r = params["num_exits"]
+    known = next((s for s in shapes[:r] if s is not None), None)
+    if known is not None and len(shapes) == 2 * r + 1:
+        for i in range(r):
+            shapes[i] = shapes[i] or tuple(known)
+            shapes[r + i] = shapes[r + i] or (known[0], 1)
+        shapes[2 * r] = shapes[2 * r] or (known[0],)
+    return shapes
+
+
+def _exit_softmax_output_dtypes(in_dtypes, params):
+    """The exits' and gates' dtype is the trunk's; the label keeps its own
+    (float32 unless said): class ids do not fit the trunk's bfloat16."""
+    r = params["num_exits"]
+    trunk = next((d for d in in_dtypes[:2 * r] if d is not None), "float32")
+    return [d if d is not None else trunk for d in in_dtypes[:2 * r]] \
+        + [d if d is not None else "float32" for d in in_dtypes[2 * r:]]
+
+
+def _exit_softmax_output_counts(ins, outs, params, platform):
+    """A launch's counts for one node: its exits, and the rows each of them
+    reads."""
+    r = params["num_exits"]
+    return {"executor.exit_loss_heads": r,
+            "executor.exit_loss_rows": r * int(ins[0].shape[0])}
+
+
+register(
+    "ExitSoftmaxOutput",
+    _exit_softmax_output,
+    arg_names=_exit_softmax_output_args,
+    param_schema={
+        "num_exits": Param(parse_int),
+        "beta": Param(parse_float, 0.0),
+        "use_ignore": Param(parse_bool, False),
+        "ignore_label": Param(parse_float, -1.0),
+    },
+    fill_in_shapes=_exit_softmax_output_fill,
+    infer_dtype=_exit_softmax_output_dtypes,
+    is_loss=True,
+    launch_counts=_exit_softmax_output_counts,
+    launch_instruments=("executor.exit_loss_heads",
+                        "executor.exit_loss_rows"),
+)
+
+
 # --- losses ----------------------------------------------------------------
 def _make_loss(ins, params, mode):
     (data,) = ins

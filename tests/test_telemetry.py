@@ -572,9 +572,14 @@ def test_stacked_wgrad_counter_counts_groups_per_launch():
         exe.grad_dict["l0_i2h_weight"].asnumpy()
         assert tm.counter("executor.stacked_wgrad").value == \
             launches * n_groups
+        # beside it, the nodes that read a weight some other node reads too:
+        # an i2h and an h2h node a layer and time step (2 x 5 x 2)
+        assert tm.counter("executor.shared_weight_reads").value == \
+            launches * 20
     exe.forward(is_train=False)  # no gradient, no count
     exe.outputs[0].asnumpy()
     assert tm.counter("executor.stacked_wgrad").value == 2 * n_groups
+    assert tm.counter("executor.shared_weight_reads").value == 2 * 20
 
     net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=4,
                                 name="fc")
@@ -585,3 +590,4 @@ def test_stacked_wgrad_counter_counts_groups_per_launch():
     plain.grad_dict["fc_weight"].asnumpy()
     assert swc.n_stacked(plain) == 0
     assert tm.counter("executor.stacked_wgrad").value == 2 * n_groups
+    assert tm.counter("executor.shared_weight_reads").value == 2 * 20
