@@ -542,19 +542,37 @@ def _held_round(first, rows, platform, x, order, weight, counts, w_gate, w_up,
         y.astype(jnp.float32) * weight[:, None])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _held_rounds(rows, platform, x, weight, w_gate, w_up, w_down, order,
                  counts):
     """The sum of ``_held_round`` over the rounds of ``rows`` rows that
-    hold a live row: the first always, the others in a loop whose trip
-    count is read on the device, zero unless routing has collapsed onto
-    the experts held here. Nothing is differentiated through the loop:
-    forward keeps the first round's residuals as autodiff would, backward
-    runs the same loop and recomputes each further round before its
-    cotangents, so memory and the program's size are one round's, however
-    many run."""
-    return _held_rounds_fwd(rows, platform, x, weight, w_gate, w_up, w_down,
-                            order, counts)[0]
+    hold a live row, ``order`` a whole number of rounds long. One round
+    (``held_round_rows`` gave every assignment): no loop is traced, the
+    round is differentiated as it stands and its residuals are the ones
+    it marks. More: ``_looped_rounds``, the first round and a loop over
+    the others whose trip count is read on the device and is zero unless
+    routing has collapsed onto the experts held here."""
+    if order.shape[0] == rows:
+        return _held_round(0, rows, platform, x, order, weight, counts,
+                           w_gate, w_up, w_down)
+    return _looped_rounds(rows, platform, x, weight, w_gate, w_up, w_down,
+                          order, counts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _looped_rounds(rows, platform, x, weight, w_gate, w_up, w_down, order,
+                   counts):
+    """The first round and a loop over the further live ones. Nothing is
+    differentiated through the loop: forward keeps the first round's
+    residuals as autodiff would, backward recomputes each further round
+    before its cotangents, so memory and the program's size are one
+    round's, however many run. Backward's loop sits under a branch that
+    the first round's cotangents pass through where no further round is
+    live, the expert weights' in the dtype their wgrads were made in: a
+    loop's operands are buffers in memory, and a float32 copy of a
+    bfloat16 wgrad written for a loop that does not run is 6 bytes a
+    held parameter that the optimizer's fusion would not have moved."""
+    return _looped_rounds_fwd(rows, platform, x, weight, w_gate, w_up,
+                              w_down, order, counts)[0]
 
 
 def _round_of(first, rows, platform, order, counts):
@@ -564,31 +582,49 @@ def _round_of(first, rows, platform, order, counts):
                                              weight, counts, *w)
 
 
-def _held_rounds_fwd(rows, platform, x, weight, w_gate, w_up, w_down, order,
-                     counts):
+def _looped_rounds_fwd(rows, platform, x, weight, w_gate, w_up, w_down,
+                       order, counts):
     wrt = (x, weight, w_gate, w_up, w_down)
     out, vjp = jax.vjp(_round_of(0, rows, platform, order, counts), *wrt)
     rounds = (jnp.sum(counts) + rows - 1) // rows
-    out = jax.lax.fori_loop(
-        1, rounds,
-        lambda r, acc: acc + _round_of(r * rows, rows, platform, order,
-                                       counts)(*wrt),
-        out)
+
+    def further(out):
+        return jax.lax.fori_loop(
+            1, rounds,
+            lambda r, acc: acc + _round_of(r * rows, rows, platform, order,
+                                           counts)(*wrt),
+            out)
+
+    out = jax.lax.cond(rounds > 1, further, lambda out: out, out)
     return out, (vjp, wrt, order, counts, rounds)
 
 
-def _held_rounds_bwd(rows, platform, res, g):
+def _looped_rounds_bwd(rows, platform, res, g):
     vjp, wrt, order, counts, rounds = res
+    # a wgrad is made in the rows' dtype (the kernel writes it, ``_castp``'s
+    # transpose rounds to it) and widened to the weight's: narrowing it
+    # back is exact, and the narrow one is what crosses the branch
+    wide = [w.dtype for w in wrt]
+    made = wide[:2] + [min(d, wrt[0].dtype, key=lambda d: d.itemsize)
+                       for d in wide[2:]]
+
+    def cast(cts, dtypes):
+        return tuple(c.astype(d) for c, d in zip(cts, dtypes))
 
     def more(r, cts):
         back = jax.vjp(_round_of(r * rows, rows, platform, order, counts),
                        *wrt)[1]
         return jax.tree.map(jnp.add, cts, back(g))
 
-    return jax.lax.fori_loop(1, rounds, more, vjp(g)) + (None, None)
+    def further(cts):   # summed in the weights' dtype, rounded once
+        return cast(jax.lax.fori_loop(1, rounds, more, cast(cts, wide)), made)
+
+    cts = jax.lax.cond(rounds > 1, further, lambda cts: cts,
+                       cast(vjp(g), made))
+    return cast(cts, wide) + (None, None)
 
 
-_held_rounds.defvjp(_held_rounds_fwd, _held_rounds_bwd)
+_looped_rounds.defvjp(_looped_rounds_fwd, _looped_rounds_bwd)
 
 
 def _moe(ins, params, mode):
@@ -612,7 +648,11 @@ def _moe(ins, params, mode):
     k weights, the rows an expert) is dense selects over (N, E)
     (``_router``), and where a share of the experts is held a round
     gathers only its own window's weights (``_held_round``): nothing
-    moves N * k scalars one index at a time.
+    moves N * k scalars one index at a time. What is traced: every expert
+    held, one pass over all the rows; a held range whose round
+    (``held_round_rows``) is every assignment, that one round and no loop;
+    any other held range, the first round and a loop over the further
+    ones (``_held_rounds``).
     """
     x, router, w_gate, w_up, w_down = ins[:5]    # its weight, or logits
     bias = ins[5] if params["expert_bias"] else None
@@ -681,7 +721,9 @@ def _moe_fill(shapes, params):
 def _moe_counts(ins, outs, params, platform):
     """A launch's counts for one layer: the rows through its grouped
     matmuls (tokens x ``top_k``), the experts held here, whether its logits
-    are an input the graph computed (``router="graph"``), and how many of
+    are an input the graph computed (``router="graph"``), whether one round
+    holds every assignment (every expert held, or ``held_round_rows`` gave
+    them all: ``_moe`` traces no loop over rounds), and how many of
     its nine expert matmuls (forward, dgrad and wgrad of gate, up and down)
     a train program runs in the Pallas kernels, all nine or none: ``_moe``'s
     own ask of ``_expert_plans``, at the rows of one round."""
@@ -695,6 +737,7 @@ def _moe_counts(ins, outs, params, platform):
             "executor.moe_local_experts": held,
             "executor.moe_graph_routed_layers":
                 int(params["router"] == "graph"),
+            "executor.moe_one_round_layers": int(m == routed),
             "executor.moe_kernel_matmuls": 9 * kernels}
 
 
@@ -729,5 +772,6 @@ register(
     launch_instruments=("executor.moe_layers", "executor.moe_assignments",
                         "executor.moe_local_experts",
                         "executor.moe_graph_routed_layers",
+                        "executor.moe_one_round_layers",
                         "executor.moe_kernel_matmuls"),
 )

@@ -30,7 +30,7 @@ def test_the_four_kernel_families_declare_and_nobody_else():
                  if op.launch_instruments}
     assert declaring == set(DECLARING)
     assert sum(len(registry.get(n).launch_instruments)
-               for n in DECLARING) == 23
+               for n in DECLARING) == 24
 
 
 @pytest.mark.parametrize("op", DECLARING)

@@ -2,7 +2,10 @@
 counts, the held round's own window of weights) against a test-local copy of
 the forms it had: ``take_along_axis``, ``bincount`` and a gather of all
 N * k weights. Same bits, forward and backward, and no gather or scatter of
-N * k indices left in a lowered train step."""
+N * k indices left in a lowered train step. And the held range's rounds
+against a copy of the loop they were: one static round traces no loop, the
+further rounds of the others sit under a branch that the first round's
+wgrads cross in the dtype they were made in."""
 
 import functools
 import re
@@ -15,6 +18,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import executor as ex
 from mxnet_tpu.ops import defs_transformer as dt
+from mxnet_tpu.ops import registry
 from mxnet_tpu.ops.registry import keep
 
 SWITCH = "MXNET_BACKWARD_DO_MIRROR"
@@ -106,12 +110,20 @@ def _held_rounds_before(rows, platform, x, flat, w_gate, w_up, w_down, order,
                         counts):
     """Today's call of ``_held_rounds`` answered as ``_moe`` answered it
     before: every assignment's weight gathered into the sorted order, the
-    padding zeros of ``tok`` and ``weight``."""
+    padding zeros of ``tok`` and ``weight``. One static round is answered
+    with no loop, as ``_held_rounds`` answers it: XLA:CPU adds the rows'
+    two cotangents (the router's product, the round's scatter) in a fused
+    product's accumulator on one side of a loop's edge and not on the
+    other, an ulp apart, and the loop against the round alone is held
+    without ``jax.jit`` below."""
     nk = flat.shape[0]
     pad = order.shape[0] - nk
     order = order[:nk]
     tok = jnp.pad(order // (nk // x.shape[0]), (0, pad))
     weight = keep(jnp.pad(flat[order], (0, pad)))
+    if nk == rows:
+        return _held_round_before(0, rows, x, tok, weight, counts, w_gate,
+                                  w_up, w_down)
     return _rounds_before(rows, x, weight, w_gate, w_up, w_down, tok, counts)
 
 
@@ -135,7 +147,8 @@ LAYOUTS = {
     "all-held": (3, E, 0, False),
     # 120 assignments, rounds of 64: two rounds' worth, 8 padded rows
     "held-range-padded-tail": (3, 4, 8, False),
-    # 80 assignments in rounds of 40: no padding, the tail all dead rows
+    # 80 assignments, twice the balanced share of 8 of 16 experts: one round
+    # of all of them, no padding, the tail all dead rows
     "held-range-whole-rounds": (2, 8, 0, False),
     # most tokens' three experts are held here: over 64 live rows, two rounds
     "collapsed-two-rounds": (3, 4, 8, True),
@@ -294,3 +307,198 @@ def test_train_step_moves_no_scalar_an_assignment(monkeypatch, held):
     before = _indexed(_train_step_text(held, True, monkeypatch))
     old = {f[0] for f in before if f[1] == nk and f[2] == 1}
     assert old == {"gather", "scatter"}, before
+
+
+# --- the held range's rounds: nothing parameter-sized in a loop that does not run --
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_rounds_looped(rows, platform, x, weight, w_gate, w_up, w_down,
+                        order, counts):
+    """``_held_rounds`` as it was: a loop over the further rounds whatever
+    their static count, forward and backward, the backward's started by the
+    first round's cotangents in the weights' dtype."""
+    return _held_rounds_looped_fwd(rows, platform, x, weight, w_gate, w_up,
+                                   w_down, order, counts)[0]
+
+
+def _held_rounds_looped_fwd(rows, platform, x, weight, w_gate, w_up, w_down,
+                            order, counts):
+    wrt = (x, weight, w_gate, w_up, w_down)
+    out, vjp = jax.vjp(dt._round_of(0, rows, platform, order, counts), *wrt)
+    rounds = (jnp.sum(counts) + rows - 1) // rows
+    out = jax.lax.fori_loop(
+        1, rounds, lambda r, acc: acc + dt._round_of(
+            r * rows, rows, platform, order, counts)(*wrt), out)
+    return out, (vjp, wrt, order, counts, rounds)
+
+
+def _held_rounds_looped_bwd(rows, platform, res, g):
+    vjp, wrt, order, counts, rounds = res
+
+    def more(r, cts):
+        back = jax.vjp(dt._round_of(r * rows, rows, platform, order, counts),
+                       *wrt)[1]
+        return jax.tree.map(jnp.add, cts, back(g))
+
+    return jax.lax.fori_loop(1, rounds, more, vjp(g)) + (None, None)
+
+
+_held_rounds_looped.defvjp(_held_rounds_looped_fwd, _held_rounds_looped_bwd)
+
+# name: (layout, static rounds, live rounds)
+ROUNDS = {
+    "one-static-round": ("held-range-whole-rounds", 1, 1),
+    "two-static-rounds": ("held-range-padded-tail", 2, 1),
+    "two-live-rounds": ("collapsed-two-rounds", 2, 2),
+}
+MOE_INPUTS = ["out", "x", "r", "g", "u", "o"]
+
+
+def _layer(steer, rounds, rows_dtype, looped):
+    """(the scalar function of a layer's five inputs, the inputs): rows of
+    ``rows_dtype`` over float32 masters, ``MoE`` called as the executor calls
+    it; ``looped``: with the loop as it was."""
+    top_k, held, first, collapsed = LAYOUTS[ROUNDS[rounds][0]]
+    if looped:
+        steer.setattr(dt, "_held_rounds", _held_rounds_looped)
+    params = registry.get("MoE").parse_params(dict(
+        num_experts=E, num_hidden=F, top_k=top_k, num_local_experts=held,
+        expert_offset=first, route_norm=True, lb_coef=0.01))
+    ins = [jnp.asarray(a) for a in _inputs(False, held, first, False,
+                                           collapsed)]
+    ins[0] = ins[0].astype(rows_dtype)
+    nk, m = N * top_k, dt.held_round_rows(N * top_k, held, E)
+    assert -(-nk // m) == ROUNDS[rounds][1]
+    routed = dt._router(dt._router_logits(ins[0], ins[1]), None, params)[2]
+    assert -(-int(jnp.sum(routed[first:first + held])) // m) \
+        == ROUNDS[rounds][2]
+    head = jnp.asarray(np.random.RandomState(6).randn(N, H), jnp.float32)
+
+    def scalar(*ins):
+        out = dt._moe(list(ins), params, registry.OpMode())
+        return jnp.sum(out.astype(jnp.float32) * head), out
+
+    return scalar, ins
+
+
+@pytest.mark.parametrize("rows_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rounds", list(ROUNDS))
+def test_rounds_give_the_bits_of_the_loop(monkeypatch, rounds, rows_dtype):
+    """Output and all five gradients. One live round: the loop's bits, at one
+    static round and at several. Two live rounds: the loop's bits too, but
+    for the three expert weights under bfloat16 rows, whose float32 sum over
+    the rounds is rounded once to the dtype a round's wgrad is made in, as a
+    kernel rounds the sum over one round's rows."""
+    sides = []
+    for looped in (False, True):
+        with monkeypatch.context() as steer:
+            scalar, ins = _layer(steer, rounds, rows_dtype, looped)
+            with jax.disable_jit():
+                grads, out = jax.grad(
+                    scalar, argnums=tuple(range(5)), has_aux=True)(*ins)
+            sides.append([np.asarray(a, np.float32)
+                          for a in [out] + list(grads)])
+    rounded = rounds == "two-live-rounds" and rows_dtype == "bfloat16"
+    for name, a, b in zip(MOE_INPUTS, *sides):
+        assert np.abs(b).max() > 0, name
+        if rounded and name in "guo":
+            assert not np.array_equal(a, b), name   # the test sees the sum
+            np.testing.assert_allclose(a, b, rtol=2.0 ** -8, atol=0,
+                                       err_msg=name)
+        else:
+            np.testing.assert_array_equal(a, b, name)
+
+
+def _grad_text(steer, rounds, rows_dtype, looped):
+    scalar, ins = _layer(steer, rounds, rows_dtype, looped)
+    return jax.jit(jax.value_and_grad(
+        lambda *ins: scalar(*ins)[0], argnums=tuple(range(5)))).lower(
+            *ins).as_text()
+
+
+def _closes(text, at):
+    """Index past the parenthesis that closes the one opening at ``at``."""
+    depth = 0
+    for i in range(at, len(text)):
+        depth += (text[i] == "(") - (text[i] == ")")
+        if not depth:
+            return i + 1
+    raise AssertionError("unbalanced")
+
+
+def _loops_and_branches(text):
+    """([operand types of every ``stablehlo.while`` outside a branch],
+    [result types of every ``stablehlo.case``]) of a lowered text."""
+    branches, results = [], []
+    for m in re.finditer(r'"stablehlo\.(?:case|if)"\(', text):
+        index = _closes(text, m.end() - 1)        # (index) ({regions})
+        end = _closes(text, text.index("(", index))
+        branches.append((m.start(), end))
+        results.append(re.findall(
+            r"tensor<[^>]*>", text[end:text.index("\n", end)].split("->")[1]))
+    loops = []
+    for m in re.finditer(r"stablehlo\.while\(", text):
+        if not any(a < m.start() < b for a, b in branches):
+            end = _closes(text, m.end() - 1)
+            loops.append(re.findall(r"tensor<[^>]*>",
+                                    text[end:text.index("\n", end)]))
+    return loops, results
+
+
+def test_one_static_round_traces_no_loop(monkeypatch):
+    with monkeypatch.context() as steer:
+        now = _grad_text(steer, "one-static-round", "bfloat16", False)
+    assert "stablehlo.while" not in now and "stablehlo.case" not in now
+    with monkeypatch.context() as steer:
+        before = _grad_text(steer, "one-static-round", "bfloat16", True)
+    assert before.count("stablehlo.while") == 2     # what this test sees
+
+
+@pytest.mark.parametrize("rows_dtype,crossing", [("bfloat16", "bf16"),
+                                                 ("float32", "f32")])
+def test_wgrads_cross_the_branch_in_the_rows_dtype(monkeypatch, rows_dtype,
+                                                   crossing):
+    """Several static rounds: the loops over the further ones sit under
+    branches, no loop outside one takes an operand of an expert weight's
+    size, and the backward's branch gives the three expert weights'
+    cotangents in the rows' dtype, never a float32 widening of bfloat16
+    wgrads."""
+    held = LAYOUTS[ROUNDS["two-static-rounds"][0]][1]
+    weights = [f"tensor<{held}x{H}x{F}x", f"tensor<{held}x{F}x{H}x"]
+
+    def parameter_sized(types):
+        return [t for t in types if t.startswith(tuple(weights))]
+
+    with monkeypatch.context() as steer:
+        loops, results = _loops_and_branches(
+            _grad_text(steer, "two-static-rounds", rows_dtype, False))
+    assert not [t for loop in loops for t in parameter_sized(loop)], loops
+    backward = [r for r in results if parameter_sized(r)]
+    assert len(results) == 2 and len(backward) == 1
+    assert sorted(parameter_sized(backward[0])) == sorted(
+        [weights[0] + crossing + ">"] * 2 + [weights[1] + crossing + ">"])
+    with monkeypatch.context() as steer:
+        loops, results = _loops_and_branches(
+            _grad_text(steer, "two-static-rounds", rows_dtype, True))
+    assert not results and len(loops) == 2          # what this test sees
+    assert all(any(t.endswith("xf32>") for t in parameter_sized(loop))
+               for loop in loops), loops
+
+
+@pytest.mark.parametrize("held,top_k,one_round", [
+    (0, 3, 1),     # every expert held: one pass over all the rows
+    (8, 2, 1),     # 8 of 16 at twice the balanced share: every assignment
+    (4, 3, 0),     # 4 of 16: rounds of 64 of the 120 assignments
+], ids=["all-held", "one-static-round", "two-static-rounds"])
+def test_launch_counts_say_which_layers_trace_no_loop(held, top_k,
+                                                      one_round):
+    op = registry.get("MoE")
+    params = op.parse_params(dict(num_experts=E, num_hidden=F, top_k=top_k,
+                                  num_local_experts=held))
+    local = held or E
+    ins = [jax.ShapeDtypeStruct(s, jnp.float32)
+           for s in ((N, H), (E, H), (local, H, F), (local, H, F),
+                     (local, F, H))]
+    counts = op.launch_counts(ins, [ins[0]], params, "cpu")
+    assert counts["executor.moe_one_round_layers"] == one_round
+    assert counts["executor.moe_layers"] == 1

@@ -134,6 +134,11 @@ CASES = {
     "moe-held-range-kernels": (
         functools.partial(_moe, held=2, kernels=True), True,
         "pallas_call", 15, 18),
+    # 4 of 8 experts: one round holds every assignment and no loop is
+    # traced, so the round alone, differentiated as it stands
+    "moe-one-round-kernels": (
+        functools.partial(_moe, held=4, kernels=True), True,
+        "pallas_call", 6, 9),
 }
 
 
