@@ -53,6 +53,18 @@ inline Symbol BilinearSampler(const std::string& symbol_name, const Symbol& data
   return detail::CreateOp("BilinearSampler", symbol_name, 2, keys__, ins__, params__);
 }
 
+inline Symbol BlockDiffusionNoise(const std::string& symbol_name, const Symbol& data, int64_t block, int64_t mask_id, double eps = 0.001, int64_t pad_id = 0, int64_t seed = -1) {
+  detail::ParamMap params__;
+  params__.emplace_back("block", detail::str(block));
+  params__.emplace_back("mask_id", detail::str(mask_id));
+  params__.emplace_back("eps", detail::str(eps));
+  params__.emplace_back("pad_id", detail::str(pad_id));
+  params__.emplace_back("seed", detail::str(seed));
+  const char* keys__[] = {"data"};
+  const Symbol* ins__[] = {&data};
+  return detail::CreateOp("BlockDiffusionNoise", symbol_name, 1, keys__, ins__, params__);
+}
+
 inline Symbol BlockGrad(const std::string& symbol_name, const Symbol& data) {
   detail::ParamMap params__;
   const char* keys__[] = {"data"};
@@ -480,11 +492,12 @@ inline Symbol Reshape(const std::string& symbol_name, const Symbol& data, const 
   return detail::CreateOp("Reshape", symbol_name, 1, keys__, ins__, params__);
 }
 
-inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query, const Symbol* key = nullptr, const Symbol* value = nullptr, const std::string& axis_name = "sp", const std::string& batch_axis = "", bool causal = false, double index_loss_coef = 0.0, double scale = -1.0, int64_t select_top_k = 0, int64_t window = 0) {
+inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query, const Symbol* key = nullptr, const Symbol* value = nullptr, const std::string& axis_name = "sp", const std::string& batch_axis = "", bool causal = false, int64_t diffusion_block = 0, double index_loss_coef = 0.0, double scale = -1.0, int64_t select_top_k = 0, int64_t window = 0) {
   detail::ParamMap params__;
   params__.emplace_back("axis_name", axis_name);
   if (!batch_axis.empty()) params__.emplace_back("batch_axis", batch_axis);
   params__.emplace_back("causal", detail::str(causal));
+  params__.emplace_back("diffusion_block", detail::str(diffusion_block));
   params__.emplace_back("index_loss_coef", detail::str(index_loss_coef));
   params__.emplace_back("scale", detail::str(scale));
   params__.emplace_back("select_top_k", detail::str(select_top_k));
@@ -549,7 +562,7 @@ inline Symbol SliceChannel(const std::string& symbol_name, const Symbol& data, i
   return detail::CreateOp("SliceChannel", symbol_name, 1, keys__, ins__, params__);
 }
 
-inline Symbol Softmax(const std::string& symbol_name, const Symbol& data, const Symbol* label = nullptr, double grad_scale = 1.0, double ignore_label = -1.0, bool multi_output = false, const std::string& normalization = "null", bool out_grad = false, bool preserve_shape = false, bool use_ignore = false) {
+inline Symbol Softmax(const std::string& symbol_name, const Symbol& data, const Symbol* label = nullptr, double grad_scale = 1.0, double ignore_label = -1.0, bool multi_output = false, const std::string& normalization = "null", bool out_grad = false, bool preserve_shape = false, bool sample_weight = false, bool use_ignore = false) {
   detail::ParamMap params__;
   params__.emplace_back("grad_scale", detail::str(grad_scale));
   params__.emplace_back("ignore_label", detail::str(ignore_label));
@@ -557,6 +570,7 @@ inline Symbol Softmax(const std::string& symbol_name, const Symbol& data, const 
   params__.emplace_back("normalization", normalization);
   params__.emplace_back("out_grad", detail::str(out_grad));
   params__.emplace_back("preserve_shape", detail::str(preserve_shape));
+  params__.emplace_back("sample_weight", detail::str(sample_weight));
   params__.emplace_back("use_ignore", detail::str(use_ignore));
   const char* keys__[] = {"data", "label"};
   const Symbol* ins__[] = {&data, label};
@@ -571,7 +585,7 @@ inline Symbol SoftmaxActivation(const std::string& symbol_name, const Symbol& da
   return detail::CreateOp("SoftmaxActivation", symbol_name, 1, keys__, ins__, params__);
 }
 
-inline Symbol SoftmaxOutput(const std::string& symbol_name, const Symbol& data, const Symbol* label = nullptr, double grad_scale = 1.0, double ignore_label = -1.0, bool multi_output = false, const std::string& normalization = "null", bool out_grad = false, bool preserve_shape = false, bool use_ignore = false) {
+inline Symbol SoftmaxOutput(const std::string& symbol_name, const Symbol& data, const Symbol* label = nullptr, double grad_scale = 1.0, double ignore_label = -1.0, bool multi_output = false, const std::string& normalization = "null", bool out_grad = false, bool preserve_shape = false, bool sample_weight = false, bool use_ignore = false) {
   detail::ParamMap params__;
   params__.emplace_back("grad_scale", detail::str(grad_scale));
   params__.emplace_back("ignore_label", detail::str(ignore_label));
@@ -579,6 +593,7 @@ inline Symbol SoftmaxOutput(const std::string& symbol_name, const Symbol& data, 
   params__.emplace_back("normalization", normalization);
   params__.emplace_back("out_grad", detail::str(out_grad));
   params__.emplace_back("preserve_shape", detail::str(preserve_shape));
+  params__.emplace_back("sample_weight", detail::str(sample_weight));
   params__.emplace_back("use_ignore", detail::str(use_ignore));
   const char* keys__[] = {"data", "label"};
   const Symbol* ins__[] = {&data, label};

@@ -5,9 +5,10 @@ bucketed LSTM language model and the OLMoE, AFMoE (Trinity), Qwen3-Next,
 DeepSeek-V3 (latent attention), ZAYA1 (compressed convolutional attention,
 an MLP router), Kimi Linear (a delta rule gated a key channel, latent
 attention without positions) and Keye-VL-2.0 (the text decoder: attention over
-the keys a learned indexer selects) sparse-expert decoders, and Ouro (a
+the keys a learned indexer selects) sparse-expert decoders, Ouro (a
 looped dense decoder: one stack run four times over the same weights, an
-exit after every pass).
+exit after every pass) and SDAR (a sparse-expert decoder trained as a
+block-diffusion model: every row read twice, a noised copy and a clean one).
 
 Reference: ``example/image-classification/symbols/*.py`` and
 ``example/rnn``/``example/gan``. Builders return plain Symbols usable with
@@ -35,6 +36,7 @@ from .zaya import zaya_sym_gen
 from .kimi_linear import kimi_linear_sym_gen
 from .keye_vl2 import keye_vl2_sym_gen
 from .ouro import ouro_sym_gen
+from .sdar import sdar_sym_gen
 from . import ssd
 from . import zoo
 from .zoo import SCORE_SYMBOLS

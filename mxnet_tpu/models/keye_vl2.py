@@ -25,6 +25,42 @@ from .olmoe import (embed_tokens, linear, merge_heads, next_token_head,
                     split_heads)
 
 
+def qwen3_moe_block(x, pre, attention, *, hidden_size, num_heads,
+                    num_kv_heads, head_dim, num_experts, expert_width, top_k,
+                    route_norm, num_local_experts, expert_offset,
+                    rms_norm_eps, rope_theta, lb_coef):
+    """One pre-norm block of the Qwen3-MoE decoder on the stream ``x`` (B,
+    T, hidden), its nodes named ``pre`` + ...: q, k, v bias-free over
+    grouped key/value heads, a per-head RMS norm of queries and keys (one
+    gain of ``head_dim`` each), rotate-half rotary positions over the whole
+    head, ``attention(q, k, v, u)`` (``u`` the block's normed input) for
+    the heads' output (B, heads, T, head_dim), then the mixture. What
+    Keye-VL-2.0 and SDAR share; they differ in ``attention``."""
+
+    def norm(z, name):
+        return sym.RMSNorm(z, eps=rms_norm_eps, name=name)
+
+    def heads(z, count, name):
+        return sym.RotaryEmbedding(
+            split_heads(z, count, head_dim, lambda y: norm(y, name)),
+            base=rope_theta)
+
+    u = norm(x, pre + "input_norm")
+    q = heads(linear(u, num_heads * head_dim, pre + "q"), num_heads,
+              pre + "q_norm")
+    k = heads(linear(u, num_kv_heads * head_dim, pre + "k"), num_kv_heads,
+              pre + "k_norm")
+    v = split_heads(linear(u, num_kv_heads * head_dim, pre + "v"),
+                    num_kv_heads, head_dim)
+    x = x + linear(merge_heads(attention(q, k, v, u)), hidden_size,
+                   pre + "o")
+    return x + sym.MoE(
+        norm(x, pre + "post_norm"), num_experts=num_experts,
+        num_hidden=expert_width, top_k=top_k, route_norm=route_norm,
+        lb_coef=lb_coef, num_local_experts=num_local_experts,
+        expert_offset=expert_offset, name=pre + "moe")
+
+
 def keye_vl2_sym_gen(vocab_size=151936, hidden_size=2048, num_layers=48,
                      num_heads=32, num_kv_heads=4, head_dim=128,
                      num_experts=128, expert_width=768, top_k=8,
@@ -41,16 +77,16 @@ def keye_vl2_sym_gen(vocab_size=151936, hidden_size=2048, num_layers=48,
     ``vocab_size`` is its slice. ``index_top_k`` 0 is the dense model (no
     indexer in the graph). ``dtype`` is the trunk's; parameters stay
     float32."""
-
-    def norm(x, name):
-        return sym.RMSNorm(x, eps=rms_norm_eps, name=name)
+    block = dict(
+        hidden_size=hidden_size, num_heads=num_heads,
+        num_kv_heads=num_kv_heads, head_dim=head_dim,
+        num_experts=num_experts, expert_width=expert_width, top_k=top_k,
+        route_norm=route_norm, num_local_experts=num_local_experts,
+        expert_offset=expert_offset, rms_norm_eps=rms_norm_eps,
+        rope_theta=rope_theta, lb_coef=lb_coef)
 
     def rotary(x):
         return sym.RotaryEmbedding(x, base=rope_theta)
-
-    def heads(x, count, name):
-        return rotary(split_heads(x, count, head_dim,
-                                  lambda z: norm(z, name)))
 
     def indexer(u, pre):
         """(index_query (B, J, T, Di), index_key (B, 1, T, Di), index_weight
@@ -67,31 +103,21 @@ def keye_vl2_sym_gen(vocab_size=151936, hidden_size=2048, num_layers=48,
                            axes=(0, 2, 1))
         return iq, ik, iw * (index_heads * index_head_dim) ** -0.5
 
+    def attention(pre):
+        return lambda q, k, v, u: sym.RingAttention(
+            q, k, v, *(indexer(u, pre) if index_top_k else ()), causal=True,
+            select_top_k=index_top_k, index_loss_coef=index_loss_coef,
+            name=pre + "attn")
+
     def sym_gen(seq_len):
         data = sym.Variable("data")
         label = sym.Variable("softmax_label")
         x = embed_tokens(data, vocab_size, hidden_size, dtype)
         for i in range(num_layers):
-            pre = f"l{i}_"
-            u = norm(x, pre + "input_norm")
-            q = heads(linear(u, num_heads * head_dim, pre + "q"), num_heads,
-                      pre + "q_norm")
-            k = heads(linear(u, num_kv_heads * head_dim, pre + "k"),
-                      num_kv_heads, pre + "k_norm")
-            v = split_heads(linear(u, num_kv_heads * head_dim, pre + "v"),
-                            num_kv_heads, head_dim)
-            select = indexer(u, pre) if index_top_k else ()
-            a = sym.RingAttention(
-                q, k, v, *select, causal=True, select_top_k=index_top_k,
-                index_loss_coef=index_loss_coef, name=pre + "attn")
-            x = x + linear(merge_heads(a), hidden_size, pre + "o")
-            x = x + sym.MoE(
-                norm(x, pre + "post_norm"), num_experts=num_experts,
-                num_hidden=expert_width, top_k=top_k, route_norm=route_norm,
-                lb_coef=lb_coef, num_local_experts=num_local_experts,
-                expert_offset=expert_offset, name=pre + "moe")
-        pred = next_token_head(norm(x, "final_norm"), label, vocab_size,
-                               hidden_size, dtype, ignore_label)
+            x = qwen3_moe_block(x, f"l{i}_", attention(f"l{i}_"), **block)
+        pred = next_token_head(
+            sym.RMSNorm(x, eps=rms_norm_eps, name="final_norm"), label,
+            vocab_size, hidden_size, dtype, ignore_label)
         return pred, ("data",), ("softmax_label",)
 
     return sym_gen

@@ -146,7 +146,9 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
 
     Counts Convolution, Deconvolution, FullyConnected, the fused RNN op,
     RingAttention (a causal one at half its scores; under ``select_top_k``
-    the pairs each query keeps and the pairs its indexer scores), GatedDeltaRule (its
+    the pairs each query keeps and the pairs its indexer scores; under
+    ``diffusion_block`` the T (T + block) pairs the two copies of a row
+    keep), GatedDeltaRule (its
     recurrent form: read, write and query of a keys x values state a token
     and value head), CausalConv1D (``kernel`` taps a channel, times the
     channels of a group where it mixes them) and MoE (the router, where it
@@ -206,7 +208,14 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
             q = _node_shape(shape_dict, nodes, node["inputs"][0])
             v = _node_shape(shape_dict, nodes, node["inputs"][2])
             top_k = int(attrs.get("select_top_k", 0))
-            if q and v and top_k > 0:
+            block = int(attrs.get("diffusion_block", 0))
+            if q and v and block > 0:
+                # block diffusion: the batch is two copies of q[0] / 2 rows,
+                # which keep T (T + block) pairs a head between them
+                t = int(q[2])
+                total += (int(q[0]) // 2) * int(q[1]) * t * (t + block) * (
+                    int(q[3]) + int(v[3])) / batch
+            elif q and v and top_k > 0:
                 # a selection: query t keeps min(t + 1, top_k) keys, and the
                 # indexer (index_query (B, J, T, Di), one key head) scores
                 # every earlier one

@@ -603,6 +603,20 @@ def _ring_attention_op(ins, params, mode):
     of ``KL(P || softmax_S(I))``, ``P`` the mean over the query heads of
     the kept keys' probabilities, a constant; query, key and value never
     see it. Where K >= T the output is the causal attention's bit for bit.
+
+    ``diffusion_block`` Bd > 0 (causal, one device, neither ``window`` nor
+    ``select_top_k``: refused by name, as the ring path refuses the mode):
+    the attention of a block-diffusion training step (Arriola et al. 2025,
+    arXiv:2503.09573). The batch axis holds two copies of every row, the
+    NOISED ones first and the CLEAN ones after, (2B, H, T, D), row r and
+    row r + B the same text at the same positions; with b(i) = i // Bd a
+    noised query sees the noised keys of its own block (both directions)
+    and the clean keys of the blocks before it, a clean query the clean
+    keys of its own block and before, and each row's softmax runs over all
+    it sees (``ring_attention.diffusion_attention``: the table, the
+    ``jax.numpy`` specification, and the fused kernels with the block-cut
+    diagonal where their rule engages; tiles the mask empties are not
+    visited on either path). 0: off, and the operator traces to what it was.
     """
     from ..parallel.mesh import current_mesh
     from ..parallel.ring_attention import ring_attention_traced
@@ -618,6 +632,8 @@ def _ring_attention_op(ins, params, mode):
         causal=params["causal"], scale=scale,
         batch_axis=params["batch_axis"] or None, window=params["window"],
         platform=mode.platform, select=select,
+        **({"diffusion_block": params["diffusion_block"]}
+           if params["diffusion_block"] > 0 else {}),
     ).astype(q.dtype)
 
 
@@ -640,9 +656,19 @@ def _ring_attention_counts(ins, outs, params, platform):
     the kernels' causal visit list at the plan's tiles where they engage,
     else of the selected walk's (``ring_attention.selected_scored_pairs``),
     so the gap between scored and selected pairs is what is computed and
-    masked away."""
-    from ..parallel.ring_attention import (block_q_of, kernel_plan,
-                                           scored_pairs, select_block_q,
+    masked away. Under ``diffusion_block`` the batch is the two copies of
+    ``batch / 2`` rows: the rule is asked for one copy's rows, the scored
+    pairs are those of the two block-cut causal walks (the kernels' visit
+    list twice, or ``ring_attention.diffusion_scored_pairs``) and of the
+    noised copy's own blocks, the kept pairs are the exact ``T (T +
+    Bd)`` a head and row (``ring_attention.diffusion_kept_pairs``), and the
+    trunk rows are the rows x positions of the queries the node is handed,
+    both copies (over ``executor.diffusion_noised_rows`` and the layers:
+    the trunk rows a token costs, whatever the model's builder made of it)."""
+    from ..parallel.ring_attention import (block_q_of, diffusion_kept_pairs,
+                                           diffusion_scored_pairs,
+                                           kernel_plan, scored_pairs,
+                                           select_block_q,
                                            selected_scored_pairs)
     from . import flash_attention
 
@@ -650,10 +676,18 @@ def _ring_attention_counts(ins, outs, params, platform):
     causal, window = params["causal"], params["window"]
     top_k = params["select_top_k"]
     batch, heads, T, key_dim = q.shape
-    kernels = kernel_plan(q.dtype, q.shape, k.shape[1], causal, window,
-                          platform, v.shape[-1], top_k,
-                          ins[3] if top_k > 0 else None)
-    if kernels is not None:
+    block = params.get("diffusion_block", 0)
+    if block > 0:
+        trunk_rows = batch * T
+        batch //= 2     # the rows: each is there twice
+    kernels = kernel_plan(q.dtype, (batch,) + tuple(q.shape[1:]), k.shape[1],
+                          causal, window, platform, v.shape[-1], top_k,
+                          ins[3] if top_k > 0 else None, block)
+    if block > 0:
+        pairs = diffusion_scored_pairs(T, block, block_q_of(batch, heads, T)) \
+            if kernels is None else T * block + 2 * \
+            flash_attention.scored_pairs(T, kernels.bq, kernels.bk, True)
+    elif kernels is not None:
         pairs = flash_attention.scored_pairs(T, kernels.bq, kernels.bk,
                                              causal, window)
     elif top_k > 0:
@@ -669,7 +703,12 @@ def _ring_attention_counts(ins, outs, params, platform):
             batch * heads * (kept * (kept + 1) // 2 + (T - kept) * kept),
         "executor.attention_index_pairs":
             batch * ins[3].shape[1] * (T * (T + 1) // 2)}
-    return {**selected,
+    diffusion = {} if block <= 0 else {
+        "executor.attention_diffusion_layers": 1,
+        "executor.attention_kept_pairs":
+            batch * heads * diffusion_kept_pairs(T, block),
+        "executor.diffusion_trunk_rows": trunk_rows}
+    return {**selected, **diffusion,
             "executor.attention_layers": 1,
             "executor.attention_window_layers": int(bool(window)),
             "executor.attention_kernel_layers": int(kernels is not None),
@@ -696,6 +735,9 @@ register(
         "select_top_k": Param(parse_int, 0),
         # weighs the indexer's own term, attached in backward
         "index_loss_coef": Param(parse_float, 0.0),
+        # positions a block of the block-diffusion mask over a batch of
+        # noised copies then clean ones; 0: off
+        "diffusion_block": Param(parse_int, 0),
     },
     aliases=("_contrib_RingAttention",),
     launch_counts=_ring_attention_counts,
@@ -707,5 +749,8 @@ register(
                         "executor.attention_pair_lanes",
                         "executor.attention_selected_layers",
                         "executor.attention_selected_pairs",
-                        "executor.attention_index_pairs"),
+                        "executor.attention_index_pairs",
+                        "executor.attention_diffusion_layers",
+                        "executor.attention_kept_pairs",
+                        "executor.diffusion_trunk_rows"),
 )

@@ -695,8 +695,15 @@ def _softmax_output(ins, params, mode):
     grad_scale`` with optional ignore-label masking and batch/valid
     normalisation. Encoded with jax.custom_vjp so executor backward() with no
     out_grads reproduces the loss-layer semantics exactly.
+
+    ``sample_weight`` (beyond the reference): a third input ``weight`` of the
+    label's shape weighs each row's loss, so its gradient is ``(p -
+    onehot(label)) * weight`` (0 where the weight is 0 or the label is
+    ignored): the 1/t-weighted loss on the masked tokens of a diffusion
+    step, whose weights ``BlockDiffusionNoise`` makes. The weight receives
+    no gradient. Off, the operator traces to what it was.
     """
-    data, label = ins
+    data, label = ins[:2]
     multi = params["multi_output"]
     preserve = params["preserve_shape"]
     grad_scale = params["grad_scale"]
@@ -712,15 +719,15 @@ def _softmax_output(ins, params, mode):
         return jax.nn.softmax(d.reshape(d.shape[0], -1), axis=-1).reshape(d.shape)
 
     @jax.custom_vjp
-    def f(d, l):
+    def f(d, l, *w):
         return forward(d)
 
-    def fwd(d, l):
+    def fwd(d, l, *w):
         out = forward(d)
-        return out, (out, l)
+        return out, (out, l) + w
 
     def bwd(res, g):
-        out, l = res
+        out, l = res[:2]
         axis = 1 if multi else out.ndim - 1
         li = l.astype(jnp.int32)
         onehot = jax.nn.one_hot(li, out.shape[axis], axis=axis, dtype=out.dtype)
@@ -729,15 +736,19 @@ def _softmax_output(ins, params, mode):
         if use_ignore:
             valid = (l != ignore_label).astype(out.dtype)
             grad = grad * jnp.expand_dims(valid, axis)
+        for w in res[2:]:
+            grad = grad * jnp.expand_dims(
+                w.reshape(l.shape).astype(out.dtype), axis)
         scale = grad_scale
         if normalization == "batch":
             grad = grad / out.shape[0]
         elif normalization == "valid":
             grad = grad / jnp.maximum(jnp.sum(valid), 1.0)
-        return grad * scale, jnp.zeros_like(l)
+        return (grad * scale, jnp.zeros_like(l)) + tuple(
+            jnp.zeros_like(w) for w in res[2:])
 
     f.defvjp(fwd, bwd)
-    return f(data, label)
+    return f(data, label, *ins[2:])
 
 
 def _softmax_output_fill(shapes, params):
@@ -749,13 +760,15 @@ def _softmax_output_fill(shapes, params):
             shapes[1] = tuple(data[:-1])
         else:
             shapes[1] = (data[0],)
+    if len(shapes) > 2 and shapes[2] is None and shapes[1] is not None:
+        shapes[2] = tuple(shapes[1])
     return shapes
 
 
 register(
     "SoftmaxOutput",
     _softmax_output,
-    arg_names=["data", "label"],
+    arg_names=lambda p: ["data", "label"] + ["weight"] * p["sample_weight"],
     param_schema={
         "grad_scale": Param(parse_float, 1.0),
         "ignore_label": Param(parse_float, -1.0),
@@ -764,10 +777,96 @@ register(
         "preserve_shape": Param(parse_bool, False),
         "normalization": Param(parse_str, "null"),
         "out_grad": Param(parse_bool, False),
+        # a third input, a weight a row of the loss
+        "sample_weight": Param(parse_bool, False),
     },
     fill_in_shapes=_softmax_output_fill,
     aliases=("Softmax",),
     is_loss=True,
+)
+
+
+# --- BlockDiffusionNoise: the forward process of a block-diffusion step -----
+def diffusion_noise(key, ids, block, eps):
+    """(mask (rows, T) bool, t (rows, T) float32) of the forward process
+    over token ids ``ids`` (rows, T), by two draws from ``key``::
+
+        k_t, k_m = jax.random.split(key)
+        t = eps + (1 - eps) * jax.random.uniform(k_t, (rows, T // block))
+        m = jax.random.uniform(k_m, (rows, T)) < repeat(t, block, axis=1)
+
+    one noise level ``t`` in [eps, 1) a block of ``block`` positions, and a
+    position masked with probability its block's ``t`` (the linear
+    schedule), independently. A plain reference that writes the same lines
+    draws the same noise."""
+    rows, T = ids.shape
+    k_t, k_m = jax.random.split(key)
+    t = eps + (1.0 - eps) * jax.random.uniform(k_t, (rows, T // block),
+                                               jnp.float32)
+    t = jnp.repeat(t, block, axis=1)
+    return jax.random.uniform(k_m, (rows, T), jnp.float32) < t, t
+
+
+def _block_diffusion_noise(ins, params, mode):
+    """The noise of a block-diffusion training step (Arriola et al. 2025,
+    arXiv:2503.09573, over the masked diffusion of Sahoo et al. 2024,
+    arXiv:2406.07524), drawn inside the program: token ids ``data`` (B, T)
+    in; out the noised ids (a masked position holds ``mask_id``), the mask
+    m (1 where masked) and the loss weights m / t, each (B, T) in ``data``'s
+    dtype (:func:`diffusion_noise`: t uniform in [eps, 1) a block of
+    ``block`` positions, m Bernoulli(t) a position). A position whose id is
+    ``pad_id`` is never masked and weighs nothing. No output passes a
+    gradient.
+
+    ``seed`` < 0 (the default, and what ``fit`` runs): the executor's
+    stream, new noise every step. ``seed`` >= 0: the key is
+    ``jax.random.fold_in(jax.random.PRNGKey(seed), 0)``, the same noise
+    every call, which a reference can draw too. Not in training: no mask,
+    the clean ids, weights 0."""
+    (ids,) = ins
+    block = params["block"]
+    if ids.ndim == 0:   # ``infer_type``'s probe
+        return [ids, jnp.zeros_like(ids), jnp.zeros_like(ids)]
+    if ids.ndim != 2 or block < 1 or ids.shape[1] % block:
+        raise MXNetError(
+            f"BlockDiffusionNoise: data {tuple(ids.shape)} is not (rows, T) "
+            f"with T a multiple of block={block}")
+    if not mode.is_train:
+        return [ids, jnp.zeros_like(ids), jnp.zeros_like(ids)]
+    key = mode.rng if params["seed"] < 0 else jax.random.fold_in(
+        jax.random.PRNGKey(params["seed"]), 0)
+    mask, t = diffusion_noise(key, ids, block, params["eps"])
+    mask = jnp.logical_and(mask, ids != params["pad_id"])
+    noised = jnp.where(mask, jnp.asarray(params["mask_id"], ids.dtype), ids)
+    weight = jnp.where(mask, 1.0 / t, 0.0)
+    return [jax.lax.stop_gradient(x.astype(ids.dtype))
+            for x in (noised, mask, weight)]
+
+
+def _block_diffusion_noise_counts(ins, outs, params, platform):
+    """A launch's counts for one node: the token positions it noises. (What
+    the trunk makes of them is the trunk's to say: ``RingAttention`` under
+    ``diffusion_block`` counts ``executor.diffusion_trunk_rows`` from the rows
+    it is handed.)"""
+    return {"executor.diffusion_noised_rows":
+            int(ins[0].shape[0]) * int(ins[0].shape[1])}
+
+
+register(
+    "BlockDiffusionNoise",
+    _block_diffusion_noise,
+    arg_names=["data"],
+    param_schema={
+        "block": Param(parse_int),
+        "mask_id": Param(parse_int),
+        "eps": Param(parse_float, 1e-3),
+        "pad_id": Param(parse_int, 0),
+        "seed": Param(parse_int, -1),
+    },
+    need_rng=True,
+    num_outputs=3,
+    launch_counts=_block_diffusion_noise_counts,
+    launch_instruments=("executor.diffusion_noised_rows",),
 )
 
 

@@ -118,7 +118,7 @@ class Plan(NamedTuple):
 
 def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
          window=0, value_dim=None, select_top_k=0,
-         index=None) -> Optional[Plan]:
+         index=None, diffusion_block=0) -> Optional[Plan]:
     """The rule. ``D`` is the width of queries and keys, ``value_dim`` that
     of the values and the output (None: ``D``). The kernels engage where
     the program is lowered for one TPU whose VMEM is known, the operands
@@ -146,8 +146,17 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
     bq x T, twice) in the same half, and at which what the other two hold
     (``_select_bytes``) is, with the 16 MiB every kernel leaves Mosaic,
     within the three quarters ``vmem_limit`` is capped at.
+    ``diffusion_block`` (the block-diffusion mask: the causal walk with its
+    diagonal cut by blocks of so many positions) engages where plain causal
+    attention over the same operands would and the block is a power of two
+    that divides every tile (at most 128).
     None = the ``jax.numpy`` blocks."""
     if platform != "tpu" or not vmem_bytes:
+        return None
+    if diffusion_block and (
+            not causal or window or select_top_k
+            or diffusion_block & (diffusion_block - 1)
+            or diffusion_block > _LANES):
         return None
     if select_top_k and (
             not causal or window or index is None
@@ -231,14 +240,18 @@ def _loop(first, end, step, carry=None):
 
 
 def _for_the_key_blocks(first, end, i, bq, bk, rows_axis, shape, causal,
-                        window, step, kept=None):
+                        window, step, kept=None, diffusion=None):
     """``step(j, mask)`` for each key block ``first <= j < end`` of query
     block ``i``: ``mask`` is None for a block neither the diagonal nor the
     band's edge cuts, else a function of the scores' tile that writes
     ``_MASKED`` where a query may not see a key. ``shape`` is the tile's,
     with its rows (G x bq, position = row mod bq) on axis ``rows_axis``.
     Under a selection ``kept(j)`` is that function for key block ``j``
-    (the kept tiles hold the diagonal too) and no tile goes unmasked."""
+    (the kept tiles hold the diagonal too) and no tile goes unmasked.
+    ``diffusion`` = (block, strict): the diagonal is cut by blocks of
+    ``block`` positions (a power of two that divides both tiles) and not by
+    position: a query sees the keys up to the end of its own block, or
+    (``strict``) only those before its block."""
     pl, _ = _ps._pallas()
     if kept is not None:
         _loop(first, end, lambda j, _: step(j, kept(j)))
@@ -246,11 +259,17 @@ def _for_the_key_blocks(first, end, i, bq, bk, rows_axis, shape, causal,
     if bq & (bq - 1):
         raise ValueError(f"attention: {bq} positions a query block, not a "
                          "power of two")
+    edge = 0    # of a tile's first row: the last key it sees, less its own
     if causal:
-        # position of the row less position of the key, at i = j = 0
-        apart = (lax.broadcasted_iota(jnp.int32, shape, rows_axis)
-                 & (bq - 1)) \
-            - lax.broadcasted_iota(jnp.int32, shape, 1 - rows_axis)
+        # the last key a row sees, as a position of its own tile
+        last = lax.broadcasted_iota(jnp.int32, shape, rows_axis) & (bq - 1)
+        if diffusion is not None:
+            block, strict = diffusion
+            last = (last & ~(block - 1)) - 1 if strict \
+                else last | (block - 1)
+            edge = -1 if strict else block - 1
+        # that position less the position of the key, at i = j = 0
+        apart = last - lax.broadcasted_iota(jnp.int32, shape, 1 - rows_axis)
 
     def body(j, carry):
         if not causal:
@@ -258,7 +277,8 @@ def _for_the_key_blocks(first, end, i, bq, bk, rows_axis, shape, causal,
             return carry
         # query positions i*bq .. i*bq + bq - 1, keys j*bk .. j*bk + bk - 1
         shift = i * bq - j * bk
-        cut = shift < bk - 1                       # a key after a query
+        # a key of the block after the last one its first row sees
+        cut = (shift + edge if edge else shift) < bk - 1
         if window:
             cut = jnp.logical_or(cut, shift + bq - 1 >= window)
 
@@ -309,12 +329,16 @@ def _kept_tile(tile, group=1):
 
 # --- forward -----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "window", "bq", "bk", "vmem_limit", "interpret"))
+    "scale", "causal", "window", "bq", "bk", "vmem_limit", "interpret",
+    "diffusion"))
 def _fwd(q, k, v, first, end, kept=None, *, scale, causal, window, bq, bk,
-         vmem_limit, interpret):
+         vmem_limit, interpret, diffusion=None):
     """(out (B, H, T, Dv) in q's dtype, log-sum-exp (B, H, T) float32).
     ``kept`` (B, T, T) int8, queries by keys: under a selection, what
-    ``_select`` wrote: 1 where a query keeps a key."""
+    ``_select`` wrote: 1 where a query keeps a key. ``diffusion``:
+    ``_for_the_key_blocks``'s; a row that sees no key at all (``strict``,
+    the first block) comes back with a log-sum-exp near ``_MASKED``, which
+    the caller's merge with the row's other keys weighs at 0."""
     pl, pltpu = _ps._pallas()
     B, H, T, D = q.shape
     kv, Dv = k.shape[1], v.shape[-1]
@@ -358,7 +382,7 @@ def _fwd(q, k, v, first, end, kept=None, *, scale, causal, window, bq, bk,
 
         _for_the_key_blocks(first_ref[i], end_ref[i], i, bq, bk, 0,
                             (rows, bk), causal, window, step,
-                            None if kept is None else kept_of)
+                            None if kept is None else kept_of, diffusion)
         l = l_ref[...]
         o_ref[...] = (acc_ref[...] * jnp.tile(1.0 / l, (1, Dv // _LANES))) \
             .astype(o_ref.dtype).reshape(group, bq, Dv)
@@ -427,12 +451,15 @@ def _heads_to_rows(x, kv, bq):
 
 # --- backward ----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "window", "bq", "bk", "vmem_limit", "interpret"))
+    "scale", "causal", "window", "bq", "bk", "vmem_limit", "interpret",
+    "diffusion"))
 def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
-         window, bq, bk, vmem_limit, interpret):
+         window, bq, bk, vmem_limit, interpret, diffusion=None):
     """(dq, dk, dv) in the operands' dtypes. ``kept`` (B, T, T) int8, KEYS
     by queries as this kernel's tiles are: under a selection, what
-    ``_index_grads`` wrote."""
+    ``_index_grads`` wrote. ``diffusion``: ``_for_the_key_blocks``'s; ``out``
+    and ``lse`` are then the rows' over ALL the keys they see (the caller's
+    merge), so the gradients are those of the joint softmax."""
     pl, pltpu = _ps._pallas()
     B, H, T, D = q.shape
     kv, Dv = k.shape[1], v.shape[-1]
@@ -487,7 +514,7 @@ def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
 
         _for_the_key_blocks(first_ref[i], end_ref[i], i, bq, bk, 1,
                             (bk, rows), causal, window, step,
-                            None if kept is None else kept_of)
+                            None if kept is None else kept_of, diffusion)
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype).reshape(group, bq, D)
 
         @pl.when(i == nq - 1)
@@ -831,32 +858,38 @@ def _index_grads(q, k, iq, ik, iw, lse, tau, index_lse, end, *, scale, coef,
 
 
 # --- what blockwise_attention calls -------------------------------------------
-def _static(plan, scale, causal, window, interpret):
+def _static(plan, scale, causal, window, interpret, diffusion=None):
+    """The kernels' static arguments; ``diffusion`` only where it is set, so
+    that every other call is keyed (``pallas_support._kernel``) as it was."""
     return dict(scale=float(scale), causal=bool(causal), window=int(window),
                 bq=plan.bq, bk=plan.bk, vmem_limit=plan.vmem_limit,
-                interpret=interpret)
+                interpret=interpret,
+                **({} if diffusion is None else {"diffusion": diffusion}))
 
 
 def attention(q, k, v, plan, scale, causal, window=0, interpret=False,
-              kept=None):
+              kept=None, diffusion=None):
     """(out, log-sum-exp): the forward kernel at ``plan``'s tiles; under a
-    selection over ``select``'s ``kept`` pairs."""
+    selection over ``select``'s ``kept`` pairs; ``diffusion`` = (block,
+    strict): the causal walk with its diagonal cut by blocks (q may be
+    another copy of the row than k and v)."""
     first, end = visits(q.shape[2], plan.bq, plan.bk, causal, window)
     return _ps._kernel(
         _fwd, (q, k, v, jnp.asarray(first), jnp.asarray(end))
         + (() if kept is None else (kept,)),
-        **_static(plan, scale, causal, window, interpret))
+        **_static(plan, scale, causal, window, interpret, diffusion))
 
 
 def attention_grads(q, k, v, out, lse, d_out, plan, scale, causal, window=0,
-                    interpret=False, kept=None):
+                    interpret=False, kept=None, diffusion=None):
     """(dq, dk, dv): the backward kernel, from the forward's residuals;
-    under a selection over ``index_grads``'s ``kept`` pairs."""
+    under a selection over ``index_grads``'s ``kept`` pairs; ``diffusion``
+    as :func:`attention`'s."""
     first, end = visits(q.shape[2], plan.bq, plan.bk, causal, window)
     return _ps._kernel(
         _bwd, (q, k, v, out, lse, d_out.astype(q.dtype), jnp.asarray(first),
                jnp.asarray(end)) + (() if kept is None else (kept,)),
-        **_static(plan, scale, causal, window, interpret))
+        **_static(plan, scale, causal, window, interpret, diffusion))
 
 
 def select(iq, ik, iw, plan, top_k, interpret=False):

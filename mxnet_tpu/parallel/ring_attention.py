@@ -38,6 +38,14 @@ threshold is found in VMEM and the kept pairs reach the attention kernels as
 int8 tiles), else in ``jax.numpy`` blocks (:func:`selected_attention`, which
 is also the specification and the tests' oracle). The ring refuses it by
 name.
+
+Block diffusion (:func:`diffusion_attention`; Arriola et al. 2025,
+arXiv:2503.09573): the batch holds two copies of every row, a noised one and
+a clean one; attention is bidirectional inside a block of positions and
+causal across blocks, and the noised copy reads its own block and the clean
+copy's earlier ones. Three parts, each a walk that visits no tile the mask
+empties, joined by their log-sum-exp. One device only; the ring refuses it
+by name.
 """
 
 from __future__ import annotations
@@ -238,7 +246,8 @@ def blockwise_attention(q, k, v, causal, scale, block_q=BLOCK_Q, window=0,
 
 
 def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
-                value_dim=None, select_top_k=0, index_query=None):
+                value_dim=None, select_top_k=0, index_query=None,
+                diffusion_block=0):
     """The rule of the one-device path: the kernels' tiles
     (``ops/flash_attention.plan``) for queries ``q_shape`` (B, H, T, Dk) of
     ``dtype`` over ``kv_heads`` whose values are ``value_dim`` wide (None:
@@ -248,7 +257,8 @@ def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
     CPU, several chips, float32, head widths the kernels do not take, T no
     multiple of a block; under a selection, ``select_top_k`` keys a query
     chosen by an indexer whose queries are ``index_query`` (B, J, T, Di),
-    an indexer of another dtype or too narrow). The op and its launch counts
+    an indexer of another dtype or too narrow; under ``diffusion_block`` a
+    block that is no power of two or wider than 128). The op and its launch counts
     (``defs_contrib._ring_attention_counts``) ask it with the same
     arguments. A bare traced call (no executor, ``platform`` None)
     assumes the default backend: a plain ``jax.jit`` for the CPU in a
@@ -262,7 +272,8 @@ def kernel_plan(dtype, q_shape, kv_heads, causal, window=0, platform=None,
         pallas_support.attached_vmem_bytes(), dtype, heads, kv_heads, T, D,
         causal, window, value_dim, select_top_k,
         None if index_query is None else (
-            index_query.dtype, index_query.shape[1], index_query.shape[3]))
+            index_query.dtype, index_query.shape[1], index_query.shape[3]),
+        diffusion_block)
 
 
 def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0, kernels=None,
@@ -292,19 +303,37 @@ def _blockwise_fwd(q, k, v, causal, scale, block_q, window=0, kernels=None,
 
 def _blocks_fwd(q, k, v, causal, scale, block_q, window):
     """(out, log-sum-exp (B, H, T) float32) by ``jax.numpy`` blocks."""
+    return _walk_fwd(_q_blocks(q.shape[2], block_q, causal, window), scale,
+                     q, k, v, q.dtype)
+
+
+def _walk_fwd(blocks, scale, q, k, v, dtype, empty_rows=False):
+    """(out (B, H, T, Dv) in ``dtype``, log-sum-exp (B, H, T) float32) of
+    one walk over ``blocks`` (:func:`_walk_grads`'s). ``empty_rows``: the
+    masks may leave a row no key (the noised copy's first block on the
+    clean one); such a row gives 0 and -inf, as a block with no key at all
+    does. (Static, and off for a causal walk, whose every row sees itself:
+    its lowered text is the one it had.)"""
     B, H, T, _ = q.shape
     kv, Dv = k.shape[1], v.shape[-1]
     group = H // kv
+    f32 = jnp.float32
     outs, lses = [], []
-    for a, b, first, end, mask in _q_blocks(T, block_q, causal, window):
+    for a, b, first, end, mask in blocks:
+        if end <= first:
+            outs.append(jnp.zeros((B, H, b - a, Dv), dtype))
+            lses.append(jnp.full((B, H, b - a), -jnp.inf, f32))
+            continue
         rows = group * (b - a)
         o, m, l = _softmax_block(
             _fold(q[:, :, a:b], kv), k[:, :, first:end], v[:, :, first:end],
             _group_mask(mask, group), scale,
-            jnp.zeros((B, kv, rows, Dv), jnp.float32),
-            jnp.full((B, kv, rows), -jnp.inf, jnp.float32),
-            jnp.zeros((B, kv, rows), jnp.float32))
-        outs.append(_unfold((o / l[..., None]).astype(q.dtype), H))
+            jnp.zeros((B, kv, rows, Dv), f32),
+            jnp.full((B, kv, rows), -jnp.inf, f32),
+            jnp.zeros((B, kv, rows), f32))
+        if empty_rows:   # o is 0 and m -inf there already: not 0 / 0
+            l = jnp.where(l > 0, l, 1.0)
+        outs.append(_unfold((o / l[..., None]).astype(dtype), H))
         lses.append((m + jnp.log(l)).reshape(B, H, b - a))
     return jnp.concatenate(outs, axis=2), jnp.concatenate(lses, axis=2)
 
@@ -320,6 +349,20 @@ def _blockwise_bwd(causal, scale, block_q, window, kernels, interpret, res,
 
 
 def _blocks_bwd(causal, scale, block_q, window, q, k, v, out, lse, d_out):
+    return _walk_grads(
+        lambda: _q_blocks(q.shape[2], block_q, causal, window), scale, q, k,
+        v, out, lse, d_out)
+
+
+def _walk_grads(blocks, scale, q, k, v, out, lse, d_out):
+    """(dq, dk, dv) of one walk over ``blocks()`` = [(first query, end of
+    queries, first key, end of keys, mask or None)] (a function, so that
+    the masks are traced where the walk starts, as they always were: a
+    causal layer's lowered text is the one it had): the scores of each
+    block are formed again from the rows' log-sum-exp ``lse``, which with
+    ``out`` may be over MORE keys than this walk's (a row that another walk
+    reads too): the gradients are then this walk's part of the joint
+    softmax's."""
     from ..ops.defs_tensor import matmul_precision
 
     prec = matmul_precision(q.dtype)
@@ -335,8 +378,10 @@ def _blocks_bwd(causal, scale, block_q, window, q, k, v, out, lse, d_out):
     dk = jnp.zeros(k.shape, f32)
     dv = jnp.zeros(v.shape, f32)
     delta = jnp.sum(d_out.astype(f32) * out.astype(f32), axis=-1)
-    for a, b, first, end, mask in _q_blocks(q.shape[2], block_q, causal,
-                                            window):
+    for a, b, first, end, mask in blocks():
+        if end <= first:    # a block that sees no key of this walk
+            dq.append(jnp.zeros((q.shape[0], H, b - a, q.shape[3]), q.dtype))
+            continue
         mask = _group_mask(mask, group)
         qb, kb, vb, gb = _fold(q[:, :, a:b], kv), k[:, :, first:end], \
             v[:, :, first:end], _fold(d_out[:, :, a:b], kv)
@@ -355,6 +400,221 @@ def _blocks_bwd(causal, scale, block_q, window, q, k, v, out, lse, d_out):
 
 
 blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
+
+
+# --- block diffusion: a noised copy of every row reads a clean one ----------
+
+def diffusion_kept_pairs(T, block):
+    """Query-key pairs one head keeps over the two copies of a row of ``T``
+    positions in blocks of ``block``: the clean copy's ``T (T + block) /
+    2`` (block b sees blocks 0..b), the noised copy's ``T (T - block) / 2``
+    of the clean one and ``T block`` of its own."""
+    return T * (T + block)
+
+
+def diffusion_plan(T, block_q, block, strict):
+    """[(first query, end of queries, 0, end of keys)] of one causal walk
+    whose diagonal is cut by blocks of ``block`` positions: a block of
+    queries reads the keys up to the end of its last query's block, or
+    (``strict``: the noised copy on the clean one) up to the start of it,
+    which for the first queries is no key at all."""
+    plan = []
+    for a in range(0, T, block_q):
+        b = min(a + block_q, T)
+        start = (b - 1) // block * block
+        plan.append((a, b, 0, start if strict else start + block))
+    return plan
+
+
+def diffusion_scored_pairs(T, block, block_q=BLOCK_Q):
+    """Query-key pairs one head scores over the two copies of a row under
+    the ``jax.numpy`` walks, forward: the tiles of :func:`diffusion_plan`,
+    both of them, and the noised copy's little squares."""
+    return sum((b - a) * end for strict in (False, True)
+               for a, b, _, end in diffusion_plan(T, block_q, block, strict)
+               ) + T * block
+
+
+def _diffusion_blocks(T, block_q, block, strict):
+    """:func:`diffusion_plan` with each block's mask (queries, keys)."""
+    blocks = []
+    for a, b, first, end in diffusion_plan(T, block_q, block, strict):
+        rows = jnp.arange(a, b)[:, None] // block
+        keys = jnp.arange(first, end)[None, :] // block
+        blocks.append((a, b, first, end,
+                       keys < rows if strict else keys <= rows))
+    return blocks
+
+
+def _own_scores(q, k, scale, block):
+    """(B, Hkv, G, T / block, block, block) float32: every block of
+    ``block`` positions of q (B, H, T, D) against the same block of k (B,
+    Hkv, T, D), and the two operands as they were cut."""
+    from ..ops.defs_tensor import matmul_precision
+
+    B, H, T, D = q.shape
+    kv = k.shape[1]
+    qb = q.reshape(B, kv, H // kv, T // block, block, D)
+    kb = k.reshape(B, kv, T // block, block, D)
+    s = jnp.einsum("bhgnqd,bhnkd->bhgnqk", qb, kb,
+                   precision=matmul_precision(q.dtype),
+                   preferred_element_type=jnp.float32) * scale
+    return s, qb, kb
+
+
+def _in_blocks(x, like):
+    """Rows' numbers (B, H, T) as :func:`_own_scores` lays its rows out,
+    with a last axis of one."""
+    return x.reshape(like.shape[:5] + (1,))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def diffusion_attention(q, k, v, scale, block, block_q=BLOCK_Q, kernels=None,
+                        interpret=False):
+    """Attention of a block-diffusion training step (Arriola et al. 2025,
+    arXiv:2503.09573; SDAR, arXiv:2510.06303). The batch axis holds two
+    copies of every row: q (2B, H, T, Dk), k (2B, Hkv, T, Dk), v (2B, Hkv,
+    T, Dv), rows ``[0, B)`` the NOISED copies and rows ``[B, 2B)`` the
+    CLEAN ones, row r and row r + B the same text at the same positions.
+    With b(i) = i // ``block``, a query at position i sees a key at j iff
+
+    ============  ==============  ===============
+    query \\ key   noised          clean
+    ============  ==============  ===============
+    noised        b(j) == b(i)    b(j) < b(i)
+    clean         never           b(j) <= b(i)
+    ============  ==============  ===============
+
+    and a row's softmax runs over everything it sees, of both copies
+    together. Output (2B, H, T, Dv) in the operands' dtype. ``T`` is a
+    multiple of ``block``.
+
+    Three parts, none of which scores a tile the mask empties: the clean
+    copy on itself and the noised copy on the clean one are causal walks
+    whose diagonal is cut by block (:func:`diffusion_plan`; ``kernels``, a
+    ``flash_attention.Plan`` of the rule :func:`kernel_plan`: the fused
+    kernels with that in-tile mask, q of one copy over k and v of the
+    other; None: ``jax.numpy`` blocks of ``block_q`` queries, the
+    specification); the noised copy on its own block is T / block little
+    squares a head in ``jax.numpy``, ``block / T`` of the pairs. The two
+    parts of a noised row meet by their log-sum-exp. Backward hands every
+    part the rows' JOINED output and log-sum-exp, so each part's gradients
+    are exact under the joint softmax; the clean copy's ``dk`` and ``dv``
+    are the sum over both copies' queries. Kept for backward beside the
+    operands: the output and the log-sum-exp (``registry.keep``)."""
+    return _diffusion_fwd(q, k, v, scale, block, block_q, kernels,
+                          interpret)[0]
+
+
+def _check_diffusion(q, k, v, block):
+    H, kv = q.shape[1], k.shape[1]
+    if H % kv or v.shape[1] != kv or q.shape[-1] != k.shape[-1]:
+        raise MXNetError(f"attention: queries {q.shape} over keys {k.shape} "
+                         f"and values {v.shape}")
+    if q.shape[0] % 2 or k.shape[0] != q.shape[0] \
+            or v.shape[0] != q.shape[0]:
+        raise MXNetError(
+            f"attention: diffusion_block={block} reads the batch axis as the "
+            f"noised copies then the clean ones, an even count: got "
+            f"{q.shape[0]} rows of queries, {k.shape[0]} of keys")
+    if block < 1 or q.shape[2] % block:
+        raise MXNetError(f"attention: diffusion_block={block} does not "
+                         f"divide {q.shape[2]} positions")
+
+
+def _diffusion_walks(q, k, v, scale, block, block_q, kernels, interpret):
+    """((out, lse) of the clean copy on itself, (out, lse) of the noised
+    copy on the clean one), each (B, H, T, Dv) and (B, H, T) float32."""
+    half = q.shape[0] // 2
+    T = q.shape[2]
+    got = []
+    for rows, strict in ((q[half:], False), (q[:half], True)):
+        if kernels is None:
+            got.append(_walk_fwd(
+                _diffusion_blocks(T, block_q, block, strict), scale, rows,
+                k[half:], v[half:], jnp.float32, empty_rows=strict))
+        else:
+            from ..ops import flash_attention
+
+            got.append(flash_attention.attention(
+                rows, k[half:], v[half:], kernels, scale, True, 0, interpret,
+                diffusion=(block, strict)))
+    return got
+
+
+def _diffusion_fwd(q, k, v, scale, block, block_q, kernels, interpret):
+    from ..ops.defs_tensor import matmul_precision
+
+    _check_diffusion(q, k, v, block)
+    half = q.shape[0] // 2
+    f32 = jnp.float32
+    (clean, clean_lse), (early, early_lse) = _diffusion_walks(
+        q, k, v, scale, block, block_q, kernels, interpret)
+    with jax.named_scope("attention.own_block"):
+        s, _, _ = _own_scores(q[:half], k[:half], scale, block)
+        lse = jnp.logaddexp(early_lse, jax.nn.logsumexp(s, axis=-1).reshape(
+            early_lse.shape))
+        p = jnp.exp(s - _in_blocks(lse, s))
+        vb = v[:half].reshape(s.shape[:2] + s.shape[3:5] + v.shape[-1:])
+        own = jnp.einsum("bhgnqk,bhnkd->bhgnqd", p.astype(v.dtype), vb,
+                         precision=matmul_precision(v.dtype),
+                         preferred_element_type=f32).reshape(early.shape)
+        noised = own + early.astype(f32) * jnp.exp(early_lse - lse)[..., None]
+    out = jnp.concatenate([noised.astype(q.dtype), clean.astype(q.dtype)])
+    lse = jnp.concatenate([lse, clean_lse])
+    out, lse = keep((out, lse))
+    return out, (q, k, v, out, lse)
+
+
+def _diffusion_bwd(scale, block, block_q, kernels, interpret, res, d_out):
+    from ..ops.defs_tensor import matmul_precision
+
+    q, k, v, out, lse = res
+    half, T = q.shape[0] // 2, q.shape[2]
+    f32 = jnp.float32
+    d_out = d_out.astype(q.dtype)
+    grads = []
+    for rows, strict in ((slice(half, None), False), (slice(0, half), True)):
+        if kernels is None:
+            grads.append(_walk_grads(
+                lambda strict=strict: _diffusion_blocks(
+                    T, block_q, block, strict), scale,
+                q[rows], k[half:], v[half:], out[rows], lse[rows],
+                d_out[rows]))
+        else:
+            from ..ops import flash_attention
+
+            grads.append(flash_attention.attention_grads(
+                q[rows], k[half:], v[half:], out[rows], lse[rows],
+                d_out[rows], kernels, scale, True, 0, interpret,
+                diffusion=(block, strict)))
+    (dq_clean, dk_clean, dv_clean), (dq_early, dk_early, dv_early) = grads
+    with jax.named_scope("attention.own_block"):
+        prec = matmul_precision(q.dtype)
+
+        def dot(spec, x, y):
+            return jnp.einsum(spec, x, y, precision=prec,
+                              preferred_element_type=f32)
+
+        s, qb, kb = _own_scores(q[:half], k[:half], scale, block)
+        vb = v[:half].reshape(kb.shape[:4] + v.shape[-1:])
+        gb = d_out[:half].reshape(qb.shape[:5] + v.shape[-1:])
+        delta = jnp.sum(d_out[:half].astype(f32) * out[:half].astype(f32),
+                        axis=-1)
+        p = jnp.exp(s - _in_blocks(lse[:half], s))
+        ds = p * (dot("bhgnqd,bhnkd->bhgnqk", gb, vb)
+                  - _in_blocks(delta, s)) * scale
+        p, ds = p.astype(q.dtype), ds.astype(q.dtype)
+        dq_own = dot("bhgnqk,bhnkd->bhgnqd", ds, kb).reshape(q[:half].shape)
+        dk_own = dot("bhgnqk,bhgnqd->bhnkd", ds, qb).reshape(k[:half].shape)
+        dv_own = dot("bhgnqk,bhgnqd->bhnkd", p, gb).reshape(v[:half].shape)
+        dq_noised = (dq_early.astype(f32) + dq_own).astype(q.dtype)
+    return (jnp.concatenate([dq_noised, dq_clean]),
+            jnp.concatenate([dk_own.astype(k.dtype), dk_clean + dk_early]),
+            jnp.concatenate([dv_own.astype(v.dtype), dv_clean + dv_early]))
+
+
+diffusion_attention.defvjp(_diffusion_fwd, _diffusion_bwd)
 
 
 # --- a selection: each query keeps the keys its indexer scores highest -----
@@ -717,11 +977,17 @@ def _selected_kernels_bwd(scale, top_k, loss_coef, kernels, interpret, res,
 selected_kernels.defvjp(_selected_kernels_fwd, _selected_kernels_bwd)
 
 
-def _refuse_on_the_ring(q, k, window, select=None):
+def _refuse_on_the_ring(q, k, window, select=None, diffusion_block=0):
     """The ring rotates whole key/value blocks of equal head count: it has
-    neither the band's block plan nor grouped heads (ROADMAP Reach 3), and
-    a query's ``select_top_k`` best keys are chosen over the whole row,
-    which no device of the ring holds."""
+    neither the band's block plan nor grouped heads (ROADMAP Reach 3), a
+    query's ``select_top_k`` best keys are chosen over the whole row, which
+    no device of the ring holds, and under ``diffusion_block`` the noised
+    copy reads another row's keys than its own."""
+    if diffusion_block:
+        raise MXNetError(
+            f"RingAttention: diffusion_block={diffusion_block} is not "
+            "supported on the sequence-parallel ring path; run it on one "
+            "device (no mesh axis for the sequence)")
     if select is not None:
         raise MXNetError(
             f"RingAttention: select_top_k={select[3]} is not supported on "
@@ -740,16 +1006,18 @@ def _refuse_on_the_ring(q, k, window, select=None):
 
 
 def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None,
-                   window=0, select=None):
+                   window=0, select=None, diffusion_block=0):
     """Sequence-parallel attention.
 
     q, k (B, H, T, Dk) and v (B, H, T, Dv): jax arrays or NDArrays, sharded
     (or to be sharded) along T over mesh axis ``axis``. Returns (B, H, T, Dv)
     with the same sharding. With ``mesh=None`` it is
     :func:`blockwise_attention` on one device (same math), which alone has
-    ``window``, key/value heads fewer than the query heads and ``select``
+    ``window``, key/value heads fewer than the query heads, ``select``
     (jax arrays ``(index_query, index_key, index_weight, top_k,
-    loss_coef)``: :func:`selected_attention`).
+    loss_coef)``: :func:`selected_attention`) and ``diffusion_block``
+    (:func:`diffusion_attention`: the batch axis is the noised copies then
+    the clean ones).
     """
     from ..ndarray import NDArray
 
@@ -760,9 +1028,10 @@ def ring_attention(q, k, v, mesh=None, axis="sp", causal=False, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
 
     if mesh is None:
-        out = _on_one_device(q, k, v, causal, scale, window, select=select)
+        out = _on_one_device(q, k, v, causal, scale, window, select=select,
+                             diffusion_block=diffusion_block)
         return NDArray(out) if wrap else out
-    _refuse_on_the_ring(q, k, window, select)
+    _refuse_on_the_ring(q, k, window, select, diffusion_block)
 
     from jax.sharding import NamedSharding
 
@@ -794,13 +1063,26 @@ def _ring_spec(axis, batch_axis):
 
 
 def _on_one_device(q, k, v, causal, scale, window, platform=None,
-                   select=None):
+                   select=None, diffusion_block=0):
     """:func:`blockwise_attention` with what the rule and ``block_q_of``
     say for these operands; ``platform`` None: where a concrete q lives,
     jax's default backend for a tracer. Under a selection
     :func:`selected_kernels` where the rule says so, else
-    :func:`selected_attention` at ``select_block_q``'s blocks."""
+    :func:`selected_attention` at ``select_block_q``'s blocks; under
+    ``diffusion_block`` :func:`diffusion_attention`, whose rule and blocks
+    are asked for ONE copy's rows."""
     platform = platform or platform_of([q])
+    if diffusion_block:
+        if not causal or window or select is not None:
+            raise MXNetError(
+                f"attention: diffusion_block={diffusion_block} needs "
+                "causal=True, and takes neither window nor select_top_k")
+        copy = (q.shape[0] // 2,) + tuple(q.shape[1:])
+        return diffusion_attention(
+            q, k, v, scale, diffusion_block,
+            block_q_of(copy[0], copy[1], copy[2]),
+            kernel_plan(q.dtype, copy, k.shape[1], True, 0, platform,
+                        v.shape[-1], diffusion_block=diffusion_block))
     if select is not None:
         if not causal or window:
             raise MXNetError("attention: select_top_k needs causal=True and "
@@ -824,7 +1106,7 @@ def _on_one_device(q, k, v, causal, scale, window, platform=None,
 
 def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
                           scale=None, batch_axis=None, window=0,
-                          platform=None, select=None):
+                          platform=None, select=None, diffusion_block=0):
     """Jit-safe ring attention for use INSIDE a traced program (the
     symbol-level ``_contrib_RingAttention`` op): placement is expressed as
     sharding constraints (not eager ``device_put``) and the ``shard_map``
@@ -832,7 +1114,8 @@ def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
     ``batch_axis`` so the batch dim keeps its data-parallel sharding
     instead of being gathered/replicated over the other axes. ``platform``:
     what the caller's program is lowered for, where it knows
-    (:func:`kernel_plan`); ``select``: as :func:`ring_attention`'s."""
+    (:func:`kernel_plan`); ``select`` and ``diffusion_block``: as
+    :func:`ring_attention`'s."""
     from jax.sharding import NamedSharding
 
     from .mesh import as_graft
@@ -842,8 +1125,8 @@ def ring_attention_traced(q, k, v, mesh, axis="sp", causal=False,
     mesh = getattr(as_graft(mesh), "mesh", None)
     if mesh is None or axis not in mesh.axis_names:
         return _on_one_device(q, k, v, causal, scale, window, platform,
-                              select)
-    _refuse_on_the_ring(q, k, window, select)
+                              select, diffusion_block)
+    _refuse_on_the_ring(q, k, window, select, diffusion_block)
     if batch_axis is not None and batch_axis not in mesh.axis_names:
         raise MXNetError(f"mesh has no axis {batch_axis!r}")
     spec = _ring_spec(axis, batch_axis)

@@ -50,7 +50,10 @@ _FILE_SECONDS = {
     "test_keye_vl2.py": 200, "test_moe_routing.py": 194, "test_gated_delta_kernels.py": 189,
     "test_gated_delta_channel.py": 166, "test_flash_attention.py": 164,
     "test_model_zoo.py": 136, "test_elastic_checkpoint.py": 130,
-    "test_grouped_matmul.py": 115,
+    "test_grouped_matmul.py": 115, "test_ouro.py": 115,
+    "test_gated_delta_channel_kernels.py": 87, "test_sdar.py": 80,
+    "test_selected_kernels.py": 57, "test_diffusion_kernels.py": 35,
+    "test_fit_publishes_nothing.py": 26, "test_memory_ledger.py": 10,
 }
 
 

@@ -3,8 +3,9 @@
 launch of a train program that holds it counts, and lists the instruments
 it may name; the executor sums what the nodes say while it lowers them and
 knows no operator by name. No Pallas interpreter and no Mosaic compile
-here: the four kernel families' rules have their own files (the fifth
-declaring operator, ``ExitSoftmaxOutput``, has no kernel and no rule)."""
+here: the four kernel families' rules have their own files (the other two
+declaring operators, ``ExitSoftmaxOutput`` and ``BlockDiffusionNoise``, have
+no kernel and no rule)."""
 
 import os
 
@@ -19,18 +20,19 @@ from mxnet_tpu.ops import pallas_support as ps
 from mxnet_tpu.ops import registry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DECLARING = ("CausalConv1D", "ExitSoftmaxOutput", "GatedDeltaRule", "MoE",
-             "RingAttention")
+DECLARING = ("BlockDiffusionNoise", "CausalConv1D", "ExitSoftmaxOutput",
+             "GatedDeltaRule", "MoE", "RingAttention")
 
 
 def test_the_four_kernel_families_declare_and_nobody_else():
     """And, since PR 55, the loss layer of a looped model: its exits and
-    their rows."""
+    their rows; since PR 57 the noise of a block-diffusion step: its rows
+    (and three more names of ``RingAttention``'s under that mode)."""
     declaring = {name for name, op in registry.canonical_ops().items()
                  if op.launch_instruments}
     assert declaring == set(DECLARING)
     assert sum(len(registry.get(n).launch_instruments)
-               for n in DECLARING) == 24
+               for n in DECLARING) == 28
 
 
 @pytest.mark.parametrize("op", DECLARING)

@@ -1,0 +1,697 @@
+"""SDAR at a tiny size on the CPU (hidden 64, 8 query heads over 2 key/value
+heads of 16, blocks of 4 positions, 4 of 16 experts held from id 4, top-2, T
+32, vocabulary 64, 2 layers, float32) against the plain reference
+``benchmark/reference/sdar-30b-a3b.py``: the block-diffusion mode of
+``RingAttention`` against a dense softmax under the explicit (2L, 2L) mask,
+the noise operator, the weighted loss, the whole first step, and what ties a
+training step to generation.
+
+Tolerances, and why: program and reference both compute in float32 and
+differ by the order of their sums (three walks joined by their log-sum-exp
+against one masked softmax, experts' rows sorted), so a tensor agrees to
+``F32_TENSOR_TOLERANCE``. ``TOLERANCES`` are what the bfloat16 trunk is held
+to on the chip (the mutations that they must fail are run there, PERF.md
+section 6; at 64 features a bfloat16 trunk is off by more than they allow).
+
+Slow parts: the whole-model tests compile the reference's chain a layer at a
+time (about 20 s each on one worker); the interpreter-mode kernels are in
+``tests/test_diffusion_kernels.py``.
+"""
+
+import importlib.util
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+import mxnet_tpu.parallel.ring_attention  # noqa: F401 (the module, below)
+from mxnet_tpu.base import MXNetError
+
+ra = sys.modules["mxnet_tpu.parallel.ring_attention"]
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NAME = "sdar-30b-a3b"
+TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=2,
+            num_attention_heads=8, num_key_value_heads=2, head_dim=16,
+            num_experts=4, num_experts_published=16, expert_offset=4,
+            moe_intermediate_size=32, num_experts_per_tok=2,
+            norm_topk_prob=True, router_aux_loss_coef=0.001, block_length=4,
+            noise_eps=1e-3, check_noise_seed=7, rms_norm_eps=1e-6,
+            rope_theta=1e6, compute_dtype="float32")
+B, T = 2, 32
+
+
+def _load(kind):
+    path = os.path.join(ROOT, "benchmark", kind, NAME + ".py")
+    spec = importlib.util.spec_from_file_location(f"sdar_{kind}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return _load("reference")
+
+
+def tiny_sym_gen(seeded=True, **over):
+    return _load("configs").sym_gen(dict(TINY, **over), mx,
+                                    0.0 if seeded else None)[0]
+
+
+def seeded_params(sym, seed=0, **shapes):
+    """normal(0, 0.3) weights (at 64 features that is what makes every
+    branch of the tiny model matter) and gains normal(1, 0.1)."""
+    rs = np.random.RandomState(seed)
+    arg_shapes, _, _ = sym.infer_shape(**shapes)
+    out = {}
+    for name, shape in zip(sym.list_arguments(), arg_shapes):
+        if name in shapes:
+            continue
+        gain = name.endswith("_gamma")
+        out[name] = (rs.randn(*shape) * (0.1 if gain else 0.3)
+                     + (1.0 if gain else 0.0)).astype(np.float32)
+    return out
+
+
+def seeded_tokens(seed=1, batch=B, seq_len=T, vocab=TINY["vocab_size"],
+                  pads=2):
+    """Ids 1..vocab-1 with ``pads`` pad positions at the end of row 0, and
+    the next-token labels an iterator would feed."""
+    rs = np.random.RandomState(seed)
+    ids = rs.randint(1, vocab, size=(batch, seq_len)).astype(np.float32)
+    if pads:
+        ids[0, -pads:] = 0
+    label = np.concatenate([ids[:, 1:], np.zeros((batch, 1), np.float32)], 1)
+    return ids, label
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
+
+
+def bind_op(sym, names, inputs):
+    return sym.bind(mx.cpu(), {n: mx.nd.array(a) for n, a in
+                               zip(names, inputs)},
+                    args_grad={n: mx.nd.zeros(a.shape) for n, a in
+                               zip(names, inputs)})
+
+
+# --- RingAttention(diffusion_block=) -------------------------------------------
+
+def table_mask(length, block):
+    """(2L, 2L) bool of the sequence [noised copy, clean copy], from the
+    table: noised on noised the same block, noised on clean the earlier
+    blocks, clean on noised never, clean on clean its block and earlier."""
+    at = np.arange(2 * length)
+    copy, blk = at // length, at % length // block
+    cq, ck, bq, bk = copy[:, None], copy[None, :], blk[:, None], blk[None, :]
+    return np.where(cq == 0, np.where(ck == 0, bk == bq, bk < bq),
+                    np.where(ck == 0, False, bk <= bq))
+
+
+def dense_attention(q, k, v, scale, block):
+    """The two copies laid end to end, one softmax under the explicit
+    mask; q (2B, H, L, Dk) as the operator takes it."""
+    import jax
+    import jax.numpy as jnp
+
+    half, heads, length = q.shape[0] // 2, q.shape[1], q.shape[2]
+
+    def one_sequence(x):
+        return jnp.concatenate([x[:half], x[half:]], axis=2)
+
+    group = heads // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", one_sequence(q),
+                   jnp.repeat(one_sequence(k), group, axis=1),
+                   precision="highest") * scale
+    mask = jnp.asarray(table_mask(length, block))
+    p = jax.nn.softmax(jnp.where(mask[None, None], s, -jnp.inf), -1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p,
+                     jnp.repeat(one_sequence(v), group, axis=1),
+                     precision="highest")
+    return jnp.concatenate([out[:, :, :length], out[:, :, length:]], axis=0)
+
+
+def _qkv(seed, rows, heads, kv, length, dk, dv):
+    rs = np.random.RandomState(seed)
+    return [rs.randn(*s).astype(np.float32) for s in (
+        (rows, heads, length, dk), (rows, kv, length, dk),
+        (rows, kv, length, dv), (rows, heads, length, dv))]
+
+
+@pytest.mark.parametrize("length,block,heads,kv,dk,dv,block_q", [
+    (24, 4, 4, 2, 8, 8, 8),        # three query blocks a walk
+    (24, 1, 2, 2, 8, 4, 16),       # blocks of one position: causal + own
+    (16, 16, 4, 1, 8, 8, 8),       # one block: the noised copy sees itself
+    (20, 4, 2, 1, 12, 8, 8),       # L no multiple of a query block
+    (36, 4, 4, 2, 8, 8, 512),      # one query block
+])
+def test_diffusion_attention_is_the_masked_softmax_and_its_gradients(
+        length, block, heads, kv, dk, dv, block_q):
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v, g = map(jnp.asarray, _qkv(3, 4, heads, kv, length, dk, dv))
+    scale = dk ** -0.5
+    walk = jax.jit(lambda *a: ra.diffusion_attention(*a, scale, block,
+                                                     block_q))
+    got = walk(q, k, v)
+    want = dense_attention(q, k, v, scale, block)
+    assert rel(got, want) < 1e-5
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(walk(*a) * g),
+                             (0, 1, 2)))(q, k, v)
+    wants = jax.jit(jax.grad(lambda *a: jnp.sum(dense_attention(
+        *a, scale, block) * g), (0, 1, 2)))(q, k, v)
+    for name, a, b in zip("qkv", grads, wants):
+        assert rel(a, b) < 1e-5, name
+    # the clean copy never reads the noised one
+    other = walk(q.at[:2].add(1.0), k.at[:2].add(1.0), v.at[:2].add(1.0))
+    assert np.array_equal(np.asarray(other[2:]), np.asarray(got[2:]))
+    # and the counts are the mask's
+    mask = table_mask(length, block)
+    assert ra.diffusion_kept_pairs(length, block) == int(mask.sum())
+    scored = ra.diffusion_scored_pairs(length, block, block_q)
+    assert int(mask.sum()) <= scored < 4 * length * length or block == length
+
+
+@pytest.mark.parametrize("length,block", [(24, 4), (520, 8)])
+def test_the_operator_runs_the_mode_and_is_what_it_was_when_off(length,
+                                                                 block):
+    """Through the symbol, forward and the three gradients (at 520 positions
+    two query blocks of 512, the second a short one); with
+    ``diffusion_block=0`` the operator's trace is today's."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import registry
+
+    q, k, v, g = _qkv(5, 2, 4, 2, length, 8, 8)
+    names = ["q", "k", "v"]
+    sym = mx.sym.RingAttention(*map(mx.sym.Variable, names), causal=True,
+                               diffusion_block=block)
+    exe = bind_op(sym, names, [q, k, v])
+    out = exe.forward(is_train=True)[0].asnumpy()
+    exe.backward(mx.nd.array(g))
+    want = dense_attention(*map(jnp.asarray, (q, k, v)), 8 ** -0.5, block)
+    assert rel(out, want) < 1e-5
+    wants = jax.grad(lambda *a: jnp.sum(dense_attention(
+        *a, 8 ** -0.5, block) * g), (0, 1, 2))(*map(jnp.asarray, (q, k, v)))
+    for n, b in zip(names, wants):
+        assert rel(exe.grad_dict[n].asnumpy(), b) < 2e-5, n
+    if length > 24:
+        return
+    # off: the same jaxpr as the plain one-device call, and the same bits
+    op = registry.get("RingAttention")
+    params = op.parse_params(dict(causal=True, diffusion_block=0))
+    mode = registry.OpMode(is_train=True, platform="cpu")
+    ins = list(map(jnp.asarray, (q, k, v)))
+    off = jax.make_jaxpr(lambda *a: op.fn(list(a), params, mode))(*ins)
+    plain = jax.make_jaxpr(lambda *a: ra._on_one_device(
+        *a, True, 8 ** -0.5, 0, "cpu").astype(a[0].dtype))(*ins)
+    assert str(off) == str(plain)
+    assert "diffusion_block" not in mx.sym.RingAttention(
+        *map(mx.sym.Variable, names), causal=True).tojson()
+
+
+def test_the_mode_refuses_what_it_cannot_do_by_name():
+    import jax.numpy as jnp
+
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import flash_attention
+
+    q, k, v, _ = map(jnp.asarray, _qkv(7, 4, 4, 4, 16, 8, 8))
+    mesh = parallel.make_mesh({"sp": 2})
+    with pytest.raises(MXNetError, match="diffusion_block=4 is not supported "
+                       "on the sequence-parallel ring"):
+        ra.ring_attention(q, k, v, mesh=mesh, causal=True, diffusion_block=4)
+    for bad in (dict(causal=False), dict(causal=True, window=8),
+                dict(causal=True, select=(q, k[:, :1], q[..., 0], 4, 0.0))):
+        with pytest.raises(MXNetError, match="diffusion_block=4 needs "
+                           "causal=True, and takes neither window nor "
+                           "select_top_k"):
+            ra.ring_attention(q, k, v, mesh=None, diffusion_block=4, **bad)
+    with pytest.raises(MXNetError, match="an even count"):
+        ra.ring_attention(q[:3], k[:3], v[:3], causal=True, diffusion_block=4)
+    with pytest.raises(MXNetError, match="diffusion_block=5 does not divide "
+                       "16 positions"):
+        ra.ring_attention(q, k, v, causal=True, diffusion_block=5)
+    # the rule: the kernels take a block that is a power of two within a
+    # tile, with neither a window nor a selection
+    args = ("tpu", 128 << 20, jnp.bfloat16, 32, 4, 8192, 128)
+    plain = flash_attention.plan(*args)
+    assert flash_attention.plan(*args, diffusion_block=4) == plain
+    assert tuple(plain)[:2] == (256, 512)
+    assert flash_attention.plan(*args, diffusion_block=6) is None
+    assert flash_attention.plan(*args, diffusion_block=256) is None
+    assert flash_attention.plan(*args, window=2048,
+                                diffusion_block=4) is None
+    assert flash_attention.plan("cpu", 0, *args[2:],
+                                diffusion_block=4) is None
+
+
+@pytest.mark.parametrize("rows,length,block", [(2, 32, 4), (1, 8192, 4)])
+def test_launch_counts_are_the_closed_forms(rows, length, block):
+    """What one launch of a node counts under the mode: its layers, the
+    exact pairs the mask keeps, the pairs of the tiles its walks visit; and
+    neither of the two new names without it."""
+    import jax
+
+    from mxnet_tpu.ops import registry
+
+    heads = 8
+    ins = [jax.ShapeDtypeStruct(s, np.float32) for s in (
+        (2 * rows, heads, length, 16), (2 * rows, 2, length, 16),
+        (2 * rows, 2, length, 16))]
+    op = registry.get("RingAttention")
+    params = op.parse_params(dict(causal=True, diffusion_block=block))
+    got = op.launch_counts(ins, None, params, "cpu")
+    assert got["executor.attention_layers"] == 1
+    assert got["executor.attention_diffusion_layers"] == 1
+    assert got["executor.attention_kept_pairs"] == \
+        rows * heads * length * (length + block)
+    block_q = ra.block_q_of(rows, heads, length)
+    assert got["executor.attention_scored_pairs"] == rows * heads * \
+        ra.diffusion_scored_pairs(length, block, block_q)
+    assert got["executor.attention_kernel_layers"] == 0     # the CPU
+    assert got["executor.attention_pair_lanes"] == 32
+    plain = op.launch_counts(ins, None, op.parse_params(dict(causal=True)),
+                             "cpu")
+    assert not [n for n in plain if "diffusion" in n or "kept" in n]
+    assert set(got) | set(plain) <= set(op.launch_instruments)
+    if length == 8192:
+        # the cell's layer at the kernels' tiles (256 positions x 512 keys):
+        # two causal walks and the little squares over L (L + 4)
+        from mxnet_tpu.ops import flash_attention
+
+        scored = 2 * flash_attention.scored_pairs(length, 256, 512, True) \
+            + length * block
+        assert 1.0 < scored / (length * (length + block)) < 1.1
+
+
+# --- BlockDiffusionNoise -----------------------------------------------------------
+
+def noise_exe(ids, **params):
+    sym = mx.sym.BlockDiffusionNoise(mx.sym.Variable("data"),
+                                     mask_id=63, **params)
+    return sym.bind(mx.cpu(), {"data": mx.nd.array(ids)})
+
+
+def test_the_noise_is_the_two_documented_draws(ref):
+    import jax
+    import jax.numpy as jnp
+
+    ids, _ = seeded_tokens()
+    exe = noise_exe(ids, block=4, seed=7)
+    first = [o.asnumpy() for o in exe.forward(is_train=True)]
+    again = [o.asnumpy() for o in exe.forward(is_train=True)]
+    for a, b in zip(first, again):       # held to a seed: the same
+        assert np.array_equal(a, b)
+    xt, m, t = ref.noise(jax, TINY, jnp.asarray(ids))
+    assert np.array_equal(first[0], np.asarray(xt))
+    assert np.array_equal(first[1], np.asarray(m, np.float32))
+    assert rel(first[2], ref.loss_weight(m, t)) < 1e-6
+    assert 0 < first[1].sum() < ids.size
+    # masked positions hold MASK, the others their own id; pads are never
+    # masked and weigh nothing
+    assert np.array_equal(first[0], np.where(first[1] > 0, 63, ids))
+    assert not first[1][ids == 0].any() and not first[2][ids == 0].any()
+    # 1/t is one number a block
+    for row_w, row_m in zip(first[2].reshape(B, -1, 4),
+                            first[1].reshape(B, -1, 4)):
+        for w, hit in zip(row_w, row_m):
+            assert len(set(w[hit > 0])) <= 1
+            assert all(1.0 <= x <= 1000.0 for x in w[hit > 0])
+    # not in training: the clean ids, no mask, no weight
+    plain = [o.asnumpy() for o in exe.forward(is_train=False)]
+    assert np.array_equal(plain[0], ids)
+    assert not plain[1].any() and not plain[2].any()
+    # no seed: the executor's stream, another draw every call
+    free = noise_exe(ids, block=4)
+    draws = [free.forward(is_train=True)[1].asnumpy() for _ in range(3)]
+    assert not np.array_equal(draws[0], draws[1])
+    assert not np.array_equal(draws[1], draws[2])
+    with pytest.raises(MXNetError, match="multiple of block=5"):
+        noise_exe(ids, block=5).forward(is_train=True)[0].asnumpy()
+
+
+def test_the_mask_rate_follows_the_blocks_level():
+    """A long row in ONE block: its rate of masked positions is within 4
+    sigma of its t, whatever t it drew; and over many blocks t is uniform."""
+    length = 4096
+    ids = np.ones((8, length), np.float32)
+    _, m, w = [o.asnumpy() for o in
+               noise_exe(ids, block=length, seed=3).forward(is_train=True)]
+    for row_m, row_w in zip(m, w):
+        t = 1.0 / row_w[row_m > 0][0]
+        sigma = np.sqrt(t * (1 - t) / length)
+        assert abs(row_m.mean() - t) < 4 * sigma + 1e-9, (row_m.mean(), t)
+    _, m, w = [o.asnumpy() for o in noise_exe(
+        np.ones((8, length), np.float32), block=4, seed=4).forward(
+            is_train=True)]
+    t = 1.0 / w[m > 0]
+    assert 0.001 <= t.min() and t.max() < 1.0
+    assert abs(m.mean() - 0.5) < 0.02      # E[t] = 1/2
+
+
+# --- the weighted loss -------------------------------------------------------------
+
+def test_the_weighted_loss_is_the_gradient_of_the_written_objective():
+    import jax
+    import jax.numpy as jnp
+
+    rs = np.random.RandomState(2)
+    z = rs.randn(12, 9).astype(np.float32)
+    y = rs.randint(0, 9, size=12).astype(np.float32)
+    y[3] = 0
+    w = (rs.rand(12) * (rs.rand(12) > 0.4) * 5).astype(np.float32)
+    names = ["z", "y", "w"]
+    sym = mx.sym.SoftmaxOutput(*map(mx.sym.Variable, names), use_ignore=True,
+                               ignore_label=0, sample_weight=True)
+    assert sym.list_arguments() == names
+    assert sym.infer_shape(z=z.shape)[0] == [(12, 9), (12,), (12,)]
+    exe = bind_op(sym, names, [z, y, w])
+    out = exe.forward(is_train=True)[0].asnumpy()
+    exe.backward()
+    assert rel(out, jax.nn.softmax(jnp.asarray(z), -1)) < 1e-6
+
+    def objective(z):
+        nll = -jnp.take_along_axis(jax.nn.log_softmax(z, -1),
+                                   jnp.asarray(y, jnp.int32)[:, None], 1)
+        return jnp.sum(jnp.where(y != 0, w * nll[:, 0], 0.0))
+
+    assert rel(exe.grad_dict["z"].asnumpy(),
+               jax.grad(objective)(jnp.asarray(z))) < 1e-5
+    assert not exe.grad_dict["w"].asnumpy().any()
+    assert not exe.grad_dict["z"].asnumpy()[w == 0].any()
+    # off, the operator takes two inputs and is what it was
+    plain = mx.sym.SoftmaxOutput(mx.sym.Variable("z"), mx.sym.Variable("y"),
+                                 use_ignore=True, ignore_label=0)
+    assert plain.list_arguments() == ["z", "y"]
+    exe0 = bind_op(plain, names[:2], [z, y])
+    exe0.forward(is_train=True)
+    exe0.backward()
+    exe1 = bind_op(sym, names, [z, y, np.ones(12, np.float32)])
+    exe1.forward(is_train=True)
+    exe1.backward()
+    assert np.array_equal(exe0.grad_dict["z"].asnumpy(),
+                          exe1.grad_dict["z"].asnumpy())
+
+
+# --- the held range ------------------------------------------------------------------
+
+def test_the_shares_add_up_to_the_uncut_layer(ref):
+    """The share test: what the 4 shares of 4 experts (the cell's 8 of 16)
+    add, each routing over all 16 and renormalising over its 2, is the
+    uncut reference's mixture; one share alone is not."""
+    import jax
+    import jax.numpy as jnp
+
+    rs = np.random.RandomState(5)
+    tok = rs.randn(48, 64).astype(np.float32)
+    router = (rs.randn(16, 64) * 0.3).astype(np.float32)
+    ws = [(rs.randn(*s) * 0.3).astype(np.float32)
+          for s in ((16, 64, 32), (16, 64, 32), (16, 32, 64))]
+    names = ["d", "r", "g", "u", "o"]
+    total, shares = 0.0, []
+    for first in range(0, 16, 4):
+        sym = mx.sym.MoE(*map(mx.sym.Variable, names), num_experts=16,
+                         num_hidden=32, top_k=2, route_norm=True,
+                         num_local_experts=4, expert_offset=first)
+        exe = bind_op(sym, names, [tok, router] + [
+            w[first:first + 4] for w in ws])
+        shares.append(exe.forward()[0].asnumpy())
+        total = total + shares[-1]
+    with jax.default_matmul_precision("highest"):
+        w = {"moe_router_weight": router, "moe_gate_weight": ws[0],
+             "moe_up_weight": ws[1], "moe_down_weight": ws[2]}
+        w = {n: jnp.asarray(a) for n, a in w.items()}
+        uncut, _ = ref.moe(dict(TINY, expert_offset=0), jnp.asarray(tok), w)
+        held = {n: a if n == "moe_router_weight" else a[4:8]
+                for n, a in w.items()}
+        share, _ = ref.moe(TINY, jnp.asarray(tok), held)
+    assert rel(total, uncut) < 1e-5
+    assert rel(shares[1], share) < 1e-5
+    assert rel(shares[1], uncut) > 1e-1
+
+
+# --- the whole model -------------------------------------------------------------------
+
+def bound(sym, params, ids, label):
+    exe = sym.simple_bind(mx.cpu(), data=ids.shape, softmax_label=label.shape)
+    for n, a in params.items():
+        exe.arg_dict[n][:] = a
+    exe.arg_dict["data"][:] = ids
+    exe.arg_dict["softmax_label"][:] = label
+    return exe
+
+
+def program_first_step(sym, params, ids, label):
+    """(probabilities, {name: gradient / rows}) of one forward/backward."""
+    exe = bound(sym, params, ids, label)
+    prob = exe.forward(is_train=True)[0].asnumpy()
+    exe.backward()
+    return prob, {n: exe.grad_dict[n].asnumpy() / ids.size for n in params}
+
+
+@pytest.fixture(scope="module")
+def first_step(ref):
+    """The tiny model's first step, program and reference."""
+    import jax
+    import jax.numpy as jnp
+
+    sym = tiny_sym_gen()(T)[0]
+    ids, label = seeded_tokens()
+    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
+    prob, grads = program_first_step(sym, params, ids, label)
+    leaves = {n: jnp.asarray(a) for n, a in params.items()}
+    probe, trained, want = ref.value_and_grads(
+        jax, TINY, leaves, jnp.asarray(ids), jnp.asarray(label))
+    return dict(sym=sym, ids=ids, label=label, leaves=leaves, prob=prob,
+                grads=grads, probe=float(probe), trained=float(trained),
+                want=want)
+
+
+def test_output_objective_and_every_gradient_match_the_reference(ref,
+                                                                 first_step):
+    import jax
+    import jax.numpy as jnp
+
+    s = first_step
+    ids, leaves = jnp.asarray(s["ids"]), s["leaves"]
+    assert s["sym"].list_arguments()[:2] == ["data", "softmax_label"]
+    assert s["prob"].shape == (B * T, TINY["vocab_size"])
+    xt, m, t = ref.noise(jax, TINY, ids)
+    hidden = ref.training_hidden(jax, TINY, leaves, xt, ids)
+    scores = ref.logits(jax, TINY, leaves, hidden[:, :T])
+    assert rel(s["prob"], jax.nn.softmax(scores, -1)) \
+        < ref.F32_TENSOR_TOLERANCE
+    # the driver's probe and the weighted cross-entropy, from the OUTPUT
+    rows = np.arange(B * T)
+    picked = s["prob"][rows, s["label"].reshape(-1).astype(int)]
+    assert -np.mean(np.log(picked)) == pytest.approx(s["probe"], rel=1e-5)
+    own = -np.log(s["prob"][rows, s["ids"].reshape(-1).astype(int)])
+    weight = np.asarray(ref.loss_weight(m, t)).reshape(-1)
+    trained = np.sum(np.where(s["ids"].reshape(-1) != 0, weight * own, 0.0))
+    assert trained / (B * T) == pytest.approx(s["trained"], rel=1e-5)
+    assert s["trained"] > 0
+    # every leaf's gradient, and the reference's chain against autodiff
+    assert set(s["want"]) == set(s["grads"])
+    with jax.default_matmul_precision("highest"):
+        whole = jax.jit(jax.grad(lambda p: ref.objective(
+            jax, TINY, p, ids)))(leaves)
+    for n in sorted(s["grads"]):
+        assert np.asarray(s["want"][n]).any(), n
+        assert rel(s["want"][n], whole[n]) < 5e-5, n
+        assert rel(s["grads"][n], s["want"][n]) < ref.F32_TENSOR_TOLERANCE, n
+    got = {"loss": -float(np.mean(np.log(picked))),
+           "grad_norm": float(np.sqrt(sum(
+               np.sum(np.asarray(g, np.float64) ** 2)
+               for g in s["grads"].values())))}
+    want = ref.first_step(jax, TINY, leaves, ids, jnp.asarray(s["label"]))
+    for key, tol in ref.F32_TOLERANCES.items():
+        assert got[key] == pytest.approx(want[key], rel=10 * tol), key
+
+
+def _wrong_mask(noised_on_noised, noised_on_clean, clean_on_noised,
+                clean_on_clean):
+    """A ``diffusion_mask`` for the reference from four rules of (query's
+    position, key's position, block length)."""
+    def mask(cfg, rows, length):
+        import jax.numpy as jnp
+
+        bd = cfg["block_length"]
+        keys = jnp.arange(2 * length)
+        noised_q = (rows // length == 0)[:, None]
+        noised_k = (keys // length == 0)[None, :]
+        p, c = (rows % length)[:, None], (keys % length)[None, :]
+        return jnp.where(
+            noised_q,
+            jnp.where(noised_k, noised_on_noised(p, c, bd),
+                      noised_on_clean(p, c, bd)),
+            jnp.where(noised_k, clean_on_noised(p, c, bd),
+                      clean_on_clean(p, c, bd)))
+    return mask
+
+
+def _same(p, c, bd):
+    return c // bd == p // bd
+
+
+def _before(p, c, bd):
+    return c // bd < p // bd
+
+
+def _upto(p, c, bd):
+    return c // bd <= p // bd
+
+
+def _never(p, c, bd):
+    return (c < 0) & (p < 0)
+
+
+WRONG_MASKS = {
+    # target leakage: a masked position sees its own clean token
+    "the_noised_copy_also_reads_its_own_clean_block":
+        _wrong_mask(_same, _upto, _never, _upto),
+    "the_clean_copy_also_reads_the_noised_block":
+        _wrong_mask(_same, _before, _same, _upto),
+    "causal_inside_a_block":
+        _wrong_mask(lambda p, c, bd: _same(p, c, bd) & (c <= p), _before,
+                    _never, lambda p, c, bd: c <= p),
+}
+
+
+def test_the_written_rules_are_the_references_mask(ref):
+    import jax.numpy as jnp
+
+    rows = jnp.arange(2 * T)
+    right = _wrong_mask(_same, _before, _never, _upto)(TINY, rows, T)
+    assert np.array_equal(right, ref.diffusion_mask(TINY, rows, T))
+
+
+@pytest.mark.parametrize("name", sorted(WRONG_MASKS))
+def test_a_wrong_mask_moves_an_attention_leaf_past_the_limit(
+        ref, first_step, monkeypatch, name):
+    """The chip's check holds ONE norm over every leaf (the benchmark's
+    driver forms the program's side), which the head and the experts
+    dominate: at published widths the leak reads 6.9e-4 there, under its
+    limit (PERF.md section 7). The comparison a leaf at a time, which
+    :func:`test_output_objective_and_every_gradient_match_the_reference`
+    makes, is the one that sees each of them: against a reference with the
+    wrong mask the program's q / k / v / o gradients are off by far more
+    than their limit."""
+    import jax
+    import jax.numpy as jnp
+
+    s = first_step
+    monkeypatch.setattr(ref, "diffusion_mask", WRONG_MASKS[name])
+    _, _, wrong = ref.value_and_grads(
+        jax, TINY, s["leaves"], jnp.asarray(s["ids"]),
+        jnp.asarray(s["label"]))
+    attention = [n for n in s["grads"]
+                 if n.endswith(("_q_weight", "_k_weight", "_v_weight",
+                                "_o_weight"))]
+    assert len(attention) == 4 * TINY["num_hidden_layers"]
+    assert max(rel(s["grads"][n], wrong[n]) for n in attention) \
+        > 30 * ref.F32_TENSOR_TOLERANCE
+
+
+def test_a_training_step_is_what_generation_computes(ref, first_step):
+    """What ties training to generation: the noised copy's distribution for
+    block b is a plain forward of the same weights on ``[x0[: b Bd], xt[b Bd
+    : (b + 1) Bd]]`` under the block-causal mask (what a denoising step
+    computes over the cache of the finished blocks), and the clean copy's
+    hidden rows are a forward of ``x0`` alone under it (that cache)."""
+    import jax
+    import jax.numpy as jnp
+
+    s = first_step
+    ids, leaves = jnp.asarray(s["ids"]), s["leaves"]
+    bd = TINY["block_length"]
+    xt, _, _ = ref.noise(jax, TINY, ids)
+    prob = s["prob"].reshape(B, T, -1)
+    for b in (0, 1, T // bd // 2, T // bd - 1):
+        upto = (b + 1) * bd
+        mixed = jnp.concatenate([ids[:, :b * bd], xt[:, b * bd:upto]], 1)
+        hidden = ref.block_causal_hidden(jax, TINY, leaves, mixed)
+        want = jax.nn.softmax(ref.logits(
+            jax, TINY, leaves, hidden[:, -bd:]), -1).reshape(B, bd, -1)
+        assert rel(prob[:, b * bd:upto], want) < ref.F32_TENSOR_TOLERANCE, b
+    both = ref.training_hidden(jax, TINY, leaves, xt, ids)
+    alone = ref.block_causal_hidden(jax, TINY, leaves, ids)
+    assert rel(both[:, T:], alone) < 1e-5
+    assert rel(both[:, :T], alone) > 1e-2      # the noised rows are not
+
+
+def test_fit_draws_fresh_noise_every_step_and_counts_its_rows(monkeypatch):
+    """Through ``Module.fit`` under the cell's switch, one batch
+    over and over at learning rate 0: the output differs from step to step
+    (the noise alone moves it), and a launched train program counts its
+    diffusion layers, their kept pairs and the two kinds of rows."""
+    from mxnet_tpu import telemetry as tm
+
+    monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
+    ids, label = seeded_tokens(pads=0)
+    it = mx.io.NDArrayIter(np.tile(ids, (4, 1)), np.tile(label, (4, 1)),
+                           batch_size=B, label_name="softmax_label")
+    mod = mx.mod.Module(tiny_sym_gen(seeded=False)(T)[0], context=[mx.cpu()])
+    seen = []
+    before = tm.snapshot()
+    mod.fit(it, num_epoch=1, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.0},
+            initializer=mx.init.Normal(0.3),
+            eval_metric=mx.metric.Perplexity(0),
+            batch_end_callback=lambda p: seen.append(
+                mod.get_outputs()[0].asnumpy()))
+    after = tm.snapshot()
+    assert len(seen) == 4
+    for a, b in zip(seen, seen[1:]):
+        assert not np.array_equal(a, b)
+        assert np.isfinite(a).all()
+
+    def delta(name):
+        return after["executor"].get(name, 0) - before.get(
+            "executor", {}).get(name, 0)
+
+    steps = 4
+    assert delta("attention_layers") == 2 * steps
+    assert delta("attention_diffusion_layers") == 2 * steps
+    assert delta("attention_kept_pairs") == 2 * steps * B * 8 * T * (T + 4)
+    assert delta("attention_scored_pairs") == 2 * steps * B * 8 * \
+        ra.diffusion_scored_pairs(T, 4, ra.block_q_of(B, 8, T))
+    assert delta("diffusion_noised_rows") == steps * B * T
+    # counted by each attention layer from the rows it is handed
+    assert delta("diffusion_trunk_rows") == 2 * steps * 2 * B * T
+    assert delta("moe_local_experts") == 2 * 4 * steps
+    assert delta("kept_residual_nodes") == 4 * steps  # 2 attention, 2 MoE
+
+
+def test_estimate_flops_and_the_parameter_count_at_published_widths():
+    """The configuration's count is ``infer_shape``'s, and
+    ``models.recipe.estimate_flops`` counts the pairs the mask KEEPS over
+    the two copies, not a causal half of the doubled batch."""
+    import json
+
+    from mxnet_tpu.models import recipe
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           NAME + ".json")) as f:
+        cfg = json.load(f)
+    builder = _load("configs")
+    t = 8192
+    sym = builder.sym_gen(cfg, mx)[0](t)[0]
+    assert len(sym.list_arguments()) - 2 == 4 * 12 + 3
+    arg_shapes, _, _ = sym.infer_shape(data=(1, t), softmax_label=(1, t))
+    count = sum(int(np.prod(s)) for n, s in zip(sym.list_arguments(),
+                                                arg_shapes)
+                if n not in ("data", "softmax_label"))
+    assert count == cfg["parameters"] == 456346624
+    macs = recipe.estimate_flops(sym, data=(1, t), softmax_label=(1, t)) / t
+    # the estimator counts every assignment of the router's (two trunk rows
+    # a token), the builder the share that lands on the experts held here
+    routed = 4 * 2 * (8 - 8 * cfg["num_experts"] / 128) * 3 * 2048 * 768
+    assert macs - routed == pytest.approx(
+        builder.forward_macs_per_token(cfg), rel=1e-9)
