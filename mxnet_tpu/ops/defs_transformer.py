@@ -8,10 +8,16 @@ the public OLMoE model (Muennighoff et al. 2024, arXiv:2409.02060; HF
 ``modeling_olmoe.py``). All are plain jax lowered by XLA, but where an
 operator's rule says its Pallas kernels engage, asked with the platform the
 program is lowered for (``OpMode.platform``): the grouped matmuls of ``MoE``
-(``grouped_matmul.py``, else ``jax.lax.ragged_dot``), ``GatedDeltaRule``
-and the depthwise ``CausalConv1D``. Each of the three also declares, beside
-its ``fn`` and asking the same rule with the same arguments, what one launch
-of a train program that holds it counts (``OpDef.launch_counts``).
+(``grouped_matmul.py``, else ``jax.lax.ragged_dot``), ``GatedDeltaRule``,
+the depthwise ``CausalConv1D`` and ``RotaryEmbedding`` (``rotary_kernels.py``:
+one TPU, a bfloat16 ``data`` of at least half the chip's VMEM, the size from
+which a v5e no longer holds the array between XLA's fusions, whose heads of
+128 turn whole in rotate-half pairs; PERF.md section 6, PR 59). Each of the
+four also declares, beside its ``fn`` and asking the same rule with the same
+arguments, what one launch of a train program that holds it counts
+(``OpDef.launch_counts``). Where the whole head turns ``RotaryEmbedding``
+is differentiated by no autodiff, in either form: its backward is the
+rotation by the negated angle.
 
 What is float32 whatever the trunk's dtype: the statistics of ``RMSNorm``,
 the angles and the rotation of ``RotaryEmbedding``, in ``MoE`` the
@@ -34,6 +40,7 @@ from . import causal_conv_kernels as _cck
 from . import gated_delta as _gdr
 from . import grouped_matmul as _gmm
 from . import pallas_support as _ps
+from . import rotary_kernels as _rk
 from .defs_nn import _castp, _prec
 from .registry import Param, keep, register
 
@@ -95,6 +102,74 @@ register(
 
 
 # --- RotaryEmbedding -------------------------------------------------------
+@functools.lru_cache(maxsize=16)
+def _rotary_tables(t, half, base, lanes=False):
+    """cos and sin (t, half) float32 of the angles ``t * base^(-i/half)``,
+    made on the host (``_rotary``'s docstring says in which precision);
+    ``lanes``: as the kernel reads them (``rotary_kernels.lane_tables``).
+    Kept, read-only, for the last few lengths and bases: every node of a
+    program asks for its layer's tables again, once a direction, and the
+    float64 cosines of a (16 384, 64) table take a host core 40 ms (made a
+    node and a direction, 2.6 s of the Keye-VL-2.0 cell's set-up and 4.9 of
+    the Ouro cell's: PERF.md section 6, PR 59)."""
+    if lanes:
+        tables = _rk.lane_tables(*_rotary_tables(t, half, base))
+    else:
+        inv_freq = (base ** (-np.arange(half, dtype=np.float64) / half)
+                    ).astype(np.float32)
+        angle = (np.arange(t, dtype=np.float32)[:, None] * inv_freq[None, :]
+                 ).astype(np.float64)
+        tables = (np.cos(angle).astype(np.float32),
+                  np.sin(angle).astype(np.float32))
+    for table in tables:
+        table.setflags(write=False)
+    return tables
+
+
+def _turned(x, base, interleaved, back, kernels):
+    """Every head of x (..., T, D) turned whole, ``back``: by the negated
+    angles (the sine's terms change sign; the tables are forward's, so a
+    program holds one pair a length and base); in the Pallas kernel at the
+    blocks ``kernels`` or, None, as ``jax.numpy``: the array float32, cut at
+    the half (or into neighbours), four products, joined again, one
+    rounding."""
+    t, d = x.shape[-2:]
+    half = d // 2
+    if kernels is not None:
+        return _rk.turn(x, *_rotary_tables(t, half, base, True), back,
+                        kernels)
+    cos, sin = _rotary_tables(t, half, base)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        pairs = xf.reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = xf[..., :half], xf[..., half:]
+    if back:
+        out = [x1 * cos + x2 * sin, x2 * cos - x1 * sin]
+    else:
+        out = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if interleaved:
+        out = jnp.stack(out, axis=-1).reshape(x.shape)
+    else:
+        out = jnp.concatenate(out, axis=-1)
+    return out.astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _rotate(x, base, interleaved, back, kernels):
+    """``_turned`` under a derivative of its own: the rotation is linear and
+    orthogonal, so its pull-back is itself with ``back`` flipped, applied
+    to the cotangent, and keeps nothing."""
+    return _turned(x, base, interleaved, back, kernels)
+
+
+_rotate.defvjp(
+    lambda x, *how: (_rotate(x, *how), None),
+    lambda base, interleaved, back, kernels, _, g: (
+        _rotate(g, base, interleaved, not back, kernels),))
+
+
 def _rotary(ins, params, mode):
     """Rotary position embedding of ``data`` (..., T, D): pair ``i`` of
     position ``t`` turns by ``t * base^(-2i/D)``. The pair is ``(i, i +
@@ -112,34 +187,59 @@ def _rotary(ins, params, mode):
     position (the published model's arithmetic), its cosine and sine
     through float64. On the v5e a float32 ``power`` and ``sin`` of an angle
     of some thousand radians were off by 6e-3 at T = 4096 (PERF.md, PR 26).
-    """
+
+    Where the whole head turns, backward is the operator itself at the
+    negated angle (``_rotate``, a ``custom_vjp``: ``dx1 = dy1 cos + dy2
+    sin``, ``dx2 = dy2 cos - dy1 sin``), rotate-half and ``interleaved``
+    alike: the same float32 products of the same tables, one add and one
+    rounding as forward, no residual. (Autodiff's transpose of the slices
+    and the join was pads, slices and an add, array passes that cost more
+    than forward did: PERF.md section 6, PR 59.) A partial ``rotary_dim``
+    stays with autodiff: alone on the chip its nodes too read faster under
+    the derivative (Qwen3-Next's 64 MiB queries 1.76 -> 0.53 ms forward +
+    backward), but that cell's step read 0.8% SLOWER with it, in three
+    forms of it: with the rotation's backward another program, XLA no
+    longer holds the head's input in VMEM for the head's weight gradient,
+    which costs 1.6 ms for the 0.06 the two nodes gain (PERF.md section 6,
+    PR 59).
+
+    Where the rule says so (``rotary_kernels.kernel_plan``, asked with the
+    platform the program is lowered for: one TPU, a bfloat16 ``data`` of at
+    least half its VMEM whose heads of 128 turn whole in rotate-half pairs)
+    either direction is one Pallas kernel (``ops/rotary_kernels.py``: the
+    same arithmetic, the array across HBM once). Half the VMEM is 64 MiB on
+    a v5e: measured there, the cells' 128 MiB queries read 2.44 / 4.82 ms
+    forward / forward + backward in the ``jax.numpy`` form (5.62 under
+    autodiff) against the kernel's 0.45 / 0.88, and their 16 MiB keys run
+    in the form at what the kernel reads, held in VMEM between XLA's
+    fusions (``rotary_kernels.kernel_plan`` has every size; PERF.md section
+    6, PR 59). Everywhere else (the CPU, several chips, a float32 trunk,
+    ``rotary_dim``, ``interleaved``, heads of 64 or 192, smaller arrays) the
+    ``jax.numpy`` form, as it was."""
     (x,) = ins
-    if params["rotary_dim"] and params["rotary_dim"] != x.shape[-1]:
-        r = params["rotary_dim"]
-        if r % 2 or not 0 < r < x.shape[-1]:
-            raise MXNetError(f"RotaryEmbedding: rotary_dim {r} of a head of "
-                             f"{x.shape[-1]}")
-        turned = _rotary([x[..., :r]], dict(params, rotary_dim=0), mode)
-        return jnp.concatenate([turned, x[..., r:]], axis=-1)
-    t, d = x.shape[-2:]
-    half = d // 2
-    inv_freq = (params["base"] ** (-np.arange(half, dtype=np.float64) / half)
-                ).astype(np.float32)
-    angle = (np.arange(t, dtype=np.float32)[:, None] * inv_freq[None, :]
-             ).astype(np.float64)
-    cos = np.cos(angle).astype(np.float32)
-    sin = np.sin(angle).astype(np.float32)
-    xf = x.astype(jnp.float32)
-    if params["interleaved"]:
-        pairs = xf.reshape(x.shape[:-1] + (half, 2))
-        x1, x2 = pairs[..., 0], pairs[..., 1]
-        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                        axis=-1).reshape(x.shape)
-    else:
-        x1, x2 = xf[..., :half], xf[..., half:]
-        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                              axis=-1)
-    return out.astype(x.dtype)
+    r = params["rotary_dim"]
+    kernels = _rk.kernel_plan(x.dtype, x.shape, r, params["interleaved"],
+                              mode.platform)
+    if not r or r == x.shape[-1]:
+        return _rotate(x, params["base"], params["interleaved"], False,
+                       kernels)
+    if r % 2 or not 0 < r < x.shape[-1]:
+        raise MXNetError(f"RotaryEmbedding: rotary_dim {r} of a head of "
+                         f"{x.shape[-1]}")
+    turned = _turned(x[..., :r], params["base"], params["interleaved"],
+                     False, None)
+    return jnp.concatenate([turned, x[..., r:]], axis=-1)
+
+
+def _rotary_counts(ins, outs, params, platform):
+    """A launch's counts for one node: itself, and whether a train program
+    runs it in the Pallas kernel: ``_rotary``'s own ask of
+    ``rotary_kernels.kernel_plan``."""
+    (x,) = ins
+    kernels = _rk.kernel_plan(x.dtype, x.shape, params["rotary_dim"],
+                              params["interleaved"], platform)
+    return {"executor.rotary_nodes": 1,
+            "executor.rotary_kernel_nodes": int(kernels is not None)}
 
 
 register(
@@ -149,6 +249,9 @@ register(
     param_schema={"base": Param(parse_float, 10000.0),
                   "rotary_dim": Param(parse_int, 0),  # 0: the whole head
                   "interleaved": Param(parse_bool, False)},  # (2i, 2i + 1)
+    launch_counts=_rotary_counts,
+    launch_instruments=("executor.rotary_nodes",
+                        "executor.rotary_kernel_nodes"),
 )
 
 

@@ -755,3 +755,31 @@ def test_causal_conv_kernels_compile_for_a_v5e_at_the_cell_widths(
     text = compiled.as_text()
     assert "causal_conv_fwd" in text and "causal_conv_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
+
+
+@pytest.mark.parametrize("cell", ["sdar", "keye_vl2"])
+def test_rotary_kernel_compiles_for_a_v5e_at_the_cell_widths(
+        monkeypatch, one_chip, cell):
+    """Mosaic accepts ``RotaryEmbedding``'s kernel (``ops/rotary_kernels.py``;
+    its other tests are in ``test_rotary_kernels.py``) at the rule's blocks
+    for the queries of the SDAR cell, two trunk rows of 8192 over 32 heads
+    of 128, and of the Keye-VL-2.0 cell, one row of 16 384: the operator
+    lowered for the chip, forward and its derivative, which is the same
+    kernel twice. Between the two nothing is kept: the program has no
+    temporary."""
+    import jax
+    import jax.numpy as jnp
+
+    shape = {"sdar": (2, 32, 8192, 128), "keye_vl2": (1, 32, 16384, 128)}[cell]
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
+    params = dict(base=1e6, rotary_dim=0, interleaved=False)
+    mode = registry.OpMode(is_train=True, platform="tpu")
+
+    def step(x, dy):
+        out, vjp = jax.vjp(lambda x: dt._rotary([x], params, mode), x)
+        return (out,) + vjp(dy)
+
+    arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(step).lower(arg, arg).compile()
+    assert compiled.as_text().count("rotary_turn") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20

@@ -3,7 +3,8 @@
 launch of a train program that holds it counts, and lists the instruments
 it may name; the executor sums what the nodes say while it lowers them and
 knows no operator by name. No Pallas interpreter and no Mosaic compile
-here: the four kernel families' rules have their own files (the other two
+here: the kernel families' rules (five since PR 59's ``RotaryEmbedding``)
+have their own files (the other two
 declaring operators, ``ExitSoftmaxOutput`` and ``BlockDiffusionNoise``, have
 no kernel and no rule)."""
 
@@ -21,18 +22,20 @@ from mxnet_tpu.ops import registry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DECLARING = ("BlockDiffusionNoise", "CausalConv1D", "ExitSoftmaxOutput",
-             "GatedDeltaRule", "MoE", "RingAttention")
+             "GatedDeltaRule", "MoE", "RingAttention", "RotaryEmbedding")
 
 
 def test_the_four_kernel_families_declare_and_nobody_else():
     """And, since PR 55, the loss layer of a looped model: its exits and
     their rows; since PR 57 the noise of a block-diffusion step: its rows
-    (and three more names of ``RingAttention``'s under that mode)."""
+    (and three more names of ``RingAttention``'s under that mode); since PR
+    59 ``RotaryEmbedding``, a fifth family of one kernel: its nodes, and
+    those of them in the kernel."""
     declaring = {name for name, op in registry.canonical_ops().items()
                  if op.launch_instruments}
     assert declaring == set(DECLARING)
     assert sum(len(registry.get(n).launch_instruments)
-               for n in DECLARING) == 28
+               for n in DECLARING) == 30
 
 
 @pytest.mark.parametrize("op", DECLARING)
