@@ -23,14 +23,13 @@ from mxnet_tpu import models
 
 
 def get_symbol(network, **kwargs):
-    # single source of truth shared with bench.py's BENCH_MODE=score —
-    # see mxnet_tpu/models/zoo.py
+    # the registry: mxnet_tpu/models/zoo.py
     return models.zoo.get_symbol(network, num_classes=1000, **kwargs)
 
 
 def score(network, batch_size, image_shape=(3, 224, 224), dtype="float32",
           iters=20, warmup=3, fold_bn=False):
-    """img/s for forward-only inference, device-fetch fenced like bench.py.
+    """img/s for forward-only inference, fenced by a device fetch.
 
     ``fold_bn`` applies the deployment-time BatchNorm fold
     (mx.contrib.fold_batchnorm) before scoring — ~+20% on ResNet-50/TPU.

@@ -62,9 +62,6 @@ ROOTS = {
     "mxnet_tpu/serving/server.py": {
         "ModelServer.submit",
     },
-    "bench.py": {
-        "main.run_steps",
-    },
 }
 
 _SYNC_ATTRS = {"asnumpy", "wait_to_read", "block_until_ready", "item"}

@@ -312,10 +312,9 @@ class TreeContext:
 # file collection + suite driver
 # --------------------------------------------------------------------------
 
-#: tree scope: the framework package plus the bench entrypoint. Tools and
-#: tests stay out — they are allowed to sync, read environs and poke locks.
+#: tree scope: the framework package. Tools and tests stay out — they are
+#: allowed to sync, read environs and poke locks.
 _SCOPE_DIRS = ("mxnet_tpu",)
-_SCOPE_FILES = ("bench.py",)
 
 
 def default_files(root):
@@ -328,10 +327,6 @@ def default_files(root):
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     files.append(os.path.join(dirpath, fn))
-    for f in _SCOPE_FILES:
-        full = os.path.join(root, f)
-        if os.path.exists(full):
-            files.append(full)
     return files
 
 

@@ -560,7 +560,7 @@ class TrainWindowScheduler:
     accumulate, then :func:`choose_train_window` locks K for the rest of
     training (lr schedules and metric updates move to window granularity,
     matching ``train_window`` semantics). A telemetry ``reset()`` during
-    the probe (bench.py's compile-epoch reset) restarts it. The decision
+    the probe (a caller discarding its compile epoch) restarts it. The decision
     is published on the ``fit.train_window_k`` gauge.
 
     The scheduler also owns the pipelined-dispatch depth (how many

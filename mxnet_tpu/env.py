@@ -507,9 +507,7 @@ _declare("MXNET_XLA_FLAGS", str, "",
          "'xla_latency_hiding_scheduler=true,xla_tpu_scoped_vmem_limit_kib="
          "65536'. Feeds the AOT env fingerprint and the executable "
          "digests, so persisted AOT caches never serve a program compiled "
-         "under different flags. Sweep candidates with BENCH_SWEEP=xla "
-         "before adopting a winner (docs/benchmarks.md, Device-side "
-         "tuning).")
+         "under different flags.")
 _declare("MXNET_CONV_LAYOUT", str, "auto",
          "Device layout for the 2-D conv stack: 'NCHW' keeps the "
          "reference layout end to end; 'NHWC' lowers Convolution/Pooling/"

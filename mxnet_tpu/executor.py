@@ -219,8 +219,7 @@ def _compiler_options():
     debug-option overrides are typed); a key the backend does not know is
     the user's error, and XLA says so. The flags feed the AOT digests and
     the cache env fingerprint, so a persisted executable never serves a
-    program compiled under different flags. ``BENCH_SWEEP=xla`` (bench.py)
-    sweeps candidate flag sets before a winner is adopted.
+    program compiled under different flags.
     """
     from . import env
 

@@ -84,9 +84,8 @@ member of a live group):
 ==================================  =========================================
 
 Serving-path faults (the chaos harness for ``mxnet_tpu/serving``; same
-``MXNET_FI_ATTEMPT``/``MXNET_FI_RANK`` gating, read per call so a test —
-or ``bench.py BENCH_CHAOS=1`` — can kill and revive a replica at runtime
-by mutating ``os.environ``):
+``MXNET_FI_ATTEMPT``/``MXNET_FI_RANK`` gating, read per call so a test
+can kill and revive a replica at runtime by mutating ``os.environ``):
 
 ==================================  =========================================
 ``MXNET_FI_SERVE_RAISE_REPLICA``    comma-separated replica ids whose

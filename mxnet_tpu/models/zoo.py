@@ -1,10 +1,8 @@
 """Canonical model-zoo registry for the inference score sweep.
 
-Single source of truth for the symbol list swept by ``BENCH_MODE=score``
-(bench.py) and ``examples/benchmark_score.py`` — the reference's
+The symbol list swept by ``examples/benchmark_score.py`` — the reference's
 ``example/image-classification/benchmark_score.py`` sweeps the same span
-(alexnet → inception-resnet-v2 / resnet-200).  Keeping the list here means
-the bench mode and the example cannot drift apart.
+(alexnet → inception-resnet-v2 / resnet-200).
 """
 
 # The 14 zoo symbols of the published perf table, in sweep order.

@@ -551,9 +551,7 @@ class BaseModule:
                                 # epoch tail shorter than K: dispatch single
                                 # steps — a partial window would trace (and
                                 # persist) an extra fused program shape per
-                                # tail size that runs once per epoch (the
-                                # same cost bench.py's whole-window warmup
-                                # avoids)
+                                # tail size that runs once per epoch
                                 for b in chunk:
                                     with _tm.span("fit.dispatch"):
                                         self.forward_backward(b)

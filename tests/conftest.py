@@ -6,6 +6,7 @@ chip itself is exercised by ``chip_smoke.py``, never by pytest. Must happen
 before the jax backend initialises.
 """
 
+import json
 import os
 
 os.environ["XLA_FLAGS"] = (
@@ -34,27 +35,19 @@ jax.config.update("jax_platforms", "cpu")
 
 _DIST_PROBE = None  # None = not probed yet; True/False = cached verdict
 
-# Seconds a test file took in the driver's tier-1 run on PR 49's tree (its
-# junit times summed a file; every file over 100 s; ``test_keye_vl2.py`` a
-# builder's reading under six workers at PR 51, 159 s where
-# ``test_olmoe.py`` read 150, on this table's scale). ``--dist loadfile``
-# hands files to the workers in collection order, and alphabetical order
-# starts the heaviest last: six workers ended at 1448 s where their 7024 s of
-# tests, evenly loaded, are 1171. Heaviest first, a file's own items together
-# and in their order; a file not named here keeps its place after them.
-_FILE_SECONDS = {
-    "test_qwen3_next.py": 714, "test_kimi_linear.py": 702,
-    "test_trinity.py": 666, "test_zaya.py": 556, "test_bench_smoke.py": 523,
-    "test_kanana2.py": 389, "test_causal_conv_kernels.py": 285,
-    "test_recompute_residuals.py": 274, "test_olmoe.py": 209,
-    "test_keye_vl2.py": 200, "test_moe_routing.py": 194, "test_gated_delta_kernels.py": 189,
-    "test_gated_delta_channel.py": 166, "test_flash_attention.py": 164,
-    "test_model_zoo.py": 136, "test_elastic_checkpoint.py": 130,
-    "test_grouped_matmul.py": 115, "test_ouro.py": 115,
-    "test_gated_delta_channel_kernels.py": 87, "test_sdar.py": 80,
-    "test_selected_kernels.py": 57, "test_diffusion_kernels.py": 35,
-    "test_fit_publishes_nothing.py": 26, "test_memory_ledger.py": 10,
-}
+# Seconds each test file takes, written by ``tools/test_durations.py`` from
+# a tier-1 run's junit. ``--dist loadfile`` hands files to the workers in
+# collection order, and alphabetical order starts the heaviest last: six
+# workers ended at 1448 s where their 7024 s of tests, evenly loaded, are
+# 1171 (PR 49). Heaviest first, a file's own items together and in their
+# order; ``tests/test_collection_order.py`` holds the table to the files.
+DURATIONS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "durations.json")
+
+
+def _file_seconds():
+    with open(DURATIONS) as f:
+        return json.load(f)
 
 
 def _dist_collectives_supported():
@@ -106,8 +99,8 @@ def pytest_collection_modifyitems(config, items):
     @pytest.mark.aot_serialization when compiled executables cannot
     serialize (probed via mxnet_tpu.aot), @pytest.mark.dist_multiprocess
     when cross-process collectives cannot execute (probed via a 2-rank
-    launch). Then the files that take longest go first (``_FILE_SECONDS``):
-    a stable sort, the same in every xdist worker."""
+    launch). Then the files that take longest go first
+    (``durations.json``): a stable sort, the same in every xdist worker."""
     import pytest
 
     marked = [item for item in items
@@ -130,7 +123,8 @@ def pytest_collection_modifyitems(config, items):
         for item in dist_marked:
             item.add_marker(skip)
 
-    items.sort(key=lambda item: -_FILE_SECONDS.get(item.path.name, 0))
+    seconds = _file_seconds()
+    items.sort(key=lambda item: -seconds.get(item.path.name, 0))
 
 
 @pytest.fixture(autouse=True)

@@ -330,6 +330,8 @@ def test_speedometer_device_pending_safe(caplog):
     metric accumulating; once landed it logs real (never nan) values."""
     import logging as _logging
 
+    import jax
+
     from mxnet_tpu.callback import Speedometer
 
     class Param:
@@ -359,7 +361,8 @@ def test_speedometer_device_pending_safe(caplog):
     assert not any("Train-" in r.message for r in caplog.records)
     assert any("samples/sec" in r.message for r in caplog.records)
 
-    p.eval_metric = m  # is_ready by now on CPU; normal log+reset path
+    jax.block_until_ready(m._dev_sum)  # landed: the log+reset path
+    p.eval_metric = m
     s2 = Speedometer(batch_size=32, frequent=1)
     with caplog.at_level(_logging.INFO):
         p.nbatch = 1
@@ -462,7 +465,14 @@ def test_fit_window_metrics_match_per_batch_path(monkeypatch):
 def test_fit_rollback_guard_caps_dispatch_depth(monkeypatch):
     """MXNET_NONFINITE_GUARD=rollback must fence every boundary: the
     dispatch-depth gauge reports the policy cap at 1 and at most one
-    window is ever in flight."""
+    window is ever in flight. ``skip`` decides on the device: it caps
+    nothing and reads nothing back."""
+    monkeypatch.setenv("MXNET_NONFINITE_GUARD", "skip")
+    _, syncs = _run_fit_windows(monkeypatch, 6, depth=2)
+    assert tm.gauge("fit.dispatch_depth").value == 2
+    assert tm.gauge("fit.windows_in_flight").max >= 2
+    assert syncs == dict(dict.fromkeys(_SYNC_COUNTERS, 0),
+                         **{"metric.drain_sync": 2})    # one an epoch
     monkeypatch.setenv("MXNET_NONFINITE_GUARD", "rollback")
     _run_fit_windows(monkeypatch, 6, depth=2)
     assert tm.gauge("fit.dispatch_depth").value == 1

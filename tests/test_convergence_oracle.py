@@ -1,22 +1,22 @@
 """Deterministic convergence oracle (VERDICT weak item 6).
 
-A fixed synthetic dataset + fixed seeds trains a small net; the per-epoch
-cross-entropy trajectory is pinned against a recorded oracle. This guards
-END-TO-END numerics (initializers → conv/FC forward → softmax backward →
-momentum SGD → metric) the way the reference's trainer smoke tests pin
-final accuracy (``tests/python/train/test_mlp.py``) — any silent numeric
-regression in the stack shifts the trajectory.
+A fixed synthetic dataset and parameters from a fixed ``numpy`` seed train a
+small net; the per-epoch cross-entropy trajectory is held to a float64
+``numpy`` implementation, in this file, of the same 2-16-2 tanh network,
+softmax cross-entropy and momentum SGD on the same data. This guards
+END-TO-END numerics (FC forward → softmax backward → momentum SGD → metric)
+the way the reference's trainer smoke tests pin final accuracy
+(``tests/python/train/test_mlp.py``) — any silent numeric regression in the
+stack shifts the trajectory. The initialiser is left out of the chain on
+purpose: its draws follow jax's generator and with it the toolchain, which
+is what kept a recorded trajectory red.
 """
 
 import numpy as np
 
 import mxnet_tpu as mx
 
-# recorded on the XLA:CPU backend (f32); per-epoch mean cross-entropy.
-# Re-pinned after a jax/jaxlib toolchain bump shifted epoch 0 by ~0.04
-# (verified bit-identical across repeat runs before re-recording).
-_ORACLE = [0.267695, 0.107534, 0.088275, 0.034695, 0.022904, 0.015806,
-           0.007040, 0.005197]
+EPOCHS, BATCH, LR, MOMENTUM = 8, 32, 0.5, 0.9
 
 
 def _dataset():
@@ -29,8 +29,48 @@ def _dataset():
     return X, cls.astype(np.float32)
 
 
+def _parameters():
+    """He-normal weights (fan-in), zero biases."""
+    rng = np.random.RandomState(99)
+    return {"fc1_weight": rng.randn(16, 2) * np.sqrt(2.0 / 2),
+            "fc1_bias": np.zeros(16),
+            "fc2_weight": rng.randn(2, 16) * np.sqrt(2.0 / 16),
+            "fc2_bias": np.zeros(2)}
+
+
+def _reference_trajectory(X, Y, params, momentum=MOMENTUM):
+    """Per-epoch mean cross-entropy, each batch read before its update, of
+    the network trained in float64: ``SoftmaxOutput``'s gradient ``p -
+    onehot`` over the batch, ``v <- momentum v - lr g``, ``w <- w + v``."""
+    w = {n: np.asarray(a, np.float64) for n, a in params.items()}
+    v = {n: np.zeros_like(a) for n, a in w.items()}
+    labels = Y.astype(int)
+    traj = []
+    for _ in range(EPOCHS):
+        total = 0.0
+        for at in range(0, len(X), BATCH):
+            x, lab = X[at:at + BATCH].astype(np.float64), labels[at:at + BATCH]
+            h = np.tanh(x @ w["fc1_weight"].T + w["fc1_bias"])
+            z = h @ w["fc2_weight"].T + w["fc2_bias"]
+            p = np.exp(z - z.max(1, keepdims=True))
+            p /= p.sum(1, keepdims=True)
+            total += -np.log(p[np.arange(len(lab)), lab]).sum()
+            dz = p.copy()
+            dz[np.arange(len(lab)), lab] -= 1.0
+            dz /= len(lab)
+            dh = (dz @ w["fc2_weight"]) * (1.0 - h * h)
+            grads = {"fc2_weight": dz.T @ h, "fc2_bias": dz.sum(0),
+                     "fc1_weight": dh.T @ x, "fc1_bias": dh.sum(0)}
+            for n, g in grads.items():
+                v[n] = momentum * v[n] - LR * g
+                w[n] = w[n] + v[n]
+        traj.append(float(total / len(X)))
+    return traj
+
+
 def test_training_trajectory_matches_oracle():
     X, Y = _dataset()
+    params = _parameters()
     data = mx.sym.Variable("data")
     h = mx.sym.Activation(
         mx.sym.FullyConnected(data, num_hidden=16, name="fc1"),
@@ -40,17 +80,16 @@ def test_training_trajectory_matches_oracle():
         mx.sym.FullyConnected(h, num_hidden=2, name="fc2"), name="softmax"
     )
     mod = mx.mod.Module(net, context=mx.cpu())
-    it = mx.io.NDArrayIter(X, Y, batch_size=32)
+    it = mx.io.NDArrayIter(X, Y, batch_size=BATCH)
     mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
-    mx.random.seed(99)
-    mod.init_params(initializer=mx.init.Xavier(
-        rnd_type="gaussian", factor_type="in", magnitude=2))
+    mod.init_params(arg_params={n: mx.nd.array(a.astype(np.float32))
+                                for n, a in params.items()}, aux_params={})
     mod.init_optimizer(optimizer="sgd",
-                       optimizer_params={"learning_rate": 0.5,
-                                         "momentum": 0.9})
+                       optimizer_params={"learning_rate": LR,
+                                         "momentum": MOMENTUM})
     ce = mx.metric.CrossEntropy()
     traj = []
-    for _ in range(len(_ORACLE)):
+    for _ in range(EPOCHS):
         it.reset()
         ce.reset()
         for b in it:
@@ -58,12 +97,18 @@ def test_training_trajectory_matches_oracle():
             mod.update()
             mod.update_metric(ce, b.label)
         traj.append(float(ce.get()[1]))
+    oracle = _reference_trajectory(X, Y, params)
     # early epochs are numerically stable; late epochs sit in a flat
     # minimum where tiny float differences drift, so tolerance widens
-    for i, (got, want) in enumerate(zip(traj, _ORACLE)):
+    for i, (got, want) in enumerate(zip(traj, oracle)):
         tol = 0.02 if i < 3 else 0.05
         assert abs(got - want) < tol, (
             f"epoch {i}: loss {got:.6f} deviates from oracle {want:.6f} "
-            f"(full: {traj})"
+            f"(full: {traj} against {oracle})"
         )
     assert traj[-1] < 0.08, f"did not converge: {traj}"
+    # the oracle sees what it is there to see: without its momentum it is
+    # no longer this trajectory
+    plain = _reference_trajectory(X, Y, params, momentum=0.0)
+    assert any(abs(a - b) >= (0.02 if i < 3 else 0.05)
+               for i, (a, b) in enumerate(zip(traj, plain)))

@@ -15,6 +15,7 @@ import functools
 
 import numpy as np
 import pytest
+from model_cases import rel
 
 import mxnet_tpu as mx
 from mxnet_tpu.ops import gated_delta as gd
@@ -66,11 +67,6 @@ def inputs(T, dtype="float32", B=2, Hk=3, group=1, D=32, seed=0, low=0.001,
     beta = rs.uniform(0, 1, (B, Hk * group, T))
     return tuple(jnp.asarray(x, dtype) for x in (q, k, v)) + (
         jnp.asarray(g, jnp.float32), jnp.asarray(beta, jnp.float32))
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
 
 
 def both_ways(args, chunk=64):

@@ -91,7 +91,7 @@ def test_alike_keys_do_not_break_the_kernels_inverse():
     g, beta = 0.01 * g, 0.9 + 0.1 * beta
     args = [jnp.asarray(x) for x in (q, k, v, g, beta)]
     with jax.default_matmul_precision("highest"):
-        want = tq._token_by_token(tq._load("reference"), *args)
+        want = tq._token_by_token(tq.mc.load("reference", tq.NAME), *args)
     got = gd.chunk_gated_delta_rule(*args, chunk=CHUNK, kernels=PLAN,
                                     interpret=True)
     assert rel(got, want) < 2e-5

@@ -18,15 +18,17 @@ key, the rotated dims in the softmax scale, the renormalisation or the route
 scale moves the loss or the gradient norm by more than they allow.
 """
 
-import importlib.util
+import functools
+import json
 import os
 
+import model_cases as mc
 import numpy as np
 import pytest
+from model_cases import bind_op, misses, rel
 
 import mxnet_tpu as mx
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "kanana-2-30b-a3b"
 TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=3,
             first_k_dense_replace=1, num_attention_heads=4,
@@ -40,58 +42,27 @@ TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=3,
 B, T = 2, 16
 
 
-def _load(kind, name=NAME):
-    path = os.path.join(ROOT, "benchmark", kind, name + ".py")
-    spec = importlib.util.spec_from_file_location(f"kanana2_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("reference")
+    return mc.load("reference", NAME)
 
 
 def tiny_sym_gen(dtype="float32", **over):
     cfg = dict(TINY, compute_dtype=dtype, **over)
-    return _load("configs").sym_gen(cfg, mx)[0]
+    return mc.load("configs", NAME).sym_gen(cfg, mx)[0]
 
 
-def seeded_params(sym, seed=0, **shapes):
-    """normal(0, 0.3) weights (at 64 features that is what makes every
-    branch of the tiny model matter), gains normal(1, 0.1) and a selection
-    bias normal(0, 0.2): one that changes which experts are chosen."""
-    rs = np.random.RandomState(seed)
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    out = {}
-    for name, shape in zip(sym.list_arguments(), arg_shapes):
-        if name in shapes:
-            continue
-        gain = name.endswith("_gamma")
-        scale = 0.2 if name.endswith("_expert_bias") else 0.1 if gain else 0.3
-        out[name] = (rs.randn(*shape) * scale
-                     + (1.0 if gain else 0.0)).astype(np.float32)
-    return out
+def scale_rule(name):
+    """The common rule, and a selection bias normal(0, 0.2): one that
+    changes which experts are chosen."""
+    if name.endswith("_expert_bias"):
+        return 0.2, 0.0
+    return mc.gains_and_weights(name)
 
 
-def seeded_tokens(seed=1, batch=B, seq_len=T, vocab=TINY["vocab_size"]):
-    rs = np.random.RandomState(seed)
-    ids = rs.randint(1, vocab, size=(batch, seq_len)).astype(np.float32)
-    label = np.concatenate([ids[:, 1:], np.zeros((batch, 1), np.float32)], 1)
-    return ids, label
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
-
-
-def bind_op(sym, names, inputs):
-    return sym.bind(mx.cpu(), {n: mx.nd.array(a) for n, a in
-                               zip(names, inputs)},
-                    args_grad={n: mx.nd.zeros(a.shape) for n, a in
-                               zip(names, inputs)})
+seeded_params = functools.partial(mc.seeded_params, rule=scale_rule)
+seeded_tokens = functools.partial(mc.seeded_tokens, batch=B, seq_len=T,
+                                  vocab=TINY["vocab_size"])
 
 
 # --- the share -------------------------------------------------------------------
@@ -153,23 +124,6 @@ def test_the_shares_add_up_to_the_uncut_layer(ref):
 
 # --- the whole model -------------------------------------------------------------
 
-def bound(sym, params, ids, label):
-    exe = sym.simple_bind(mx.cpu(), data=ids.shape, softmax_label=label.shape)
-    for n, a in params.items():
-        exe.arg_dict[n][:] = a
-    exe.arg_dict["data"][:] = ids
-    exe.arg_dict["softmax_label"][:] = label
-    return exe
-
-
-def program_first_step(sym, params, ids, label):
-    """(probabilities, {name: gradient / rows}) of one forward/backward."""
-    exe = bound(sym, params, ids, label)
-    prob = exe.forward(is_train=True)[0].asnumpy()
-    exe.backward()
-    return prob, {n: exe.grad_dict[n].asnumpy() / ids.size for n in params}
-
-
 def test_model_logits_and_every_gradient_match_the_reference(ref):
     import jax
     import jax.numpy as jnp
@@ -177,7 +131,7 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
     sym = tiny_sym_gen()(T)[0]
     ids, label = seeded_tokens()
     params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    prob, grads = program_first_step(sym, params, ids, label)
+    prob, grads = mc.program_first_step(sym, params, ids, label)
     leaves = {n: jnp.asarray(a) for n, a in params.items()}
     scores = ref.logits(jax, TINY, leaves, jnp.asarray(ids))
     assert rel(prob, jax.nn.softmax(scores, -1)) < ref.F32_TENSOR_TOLERANCE
@@ -186,8 +140,8 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
     assert set(want) == set(grads)
     # the reference's layer-at-a-time chain is autodiff of its whole loss
     with jax.default_matmul_precision("highest"):
-        whole = jax.grad(lambda p: ref.losses(
-            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0])(leaves)
+        whole = jax.jit(jax.grad(lambda p: ref.losses(
+            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0]))(leaves)
     for n in sorted(grads):
         assert rel(want[n], whole[n]) < 1e-5 or not np.asarray(
             whole[n]).any(), n
@@ -196,23 +150,6 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
             assert not grads[n].any() and not np.asarray(want[n]).any()
         else:
             assert rel(grads[n], want[n]) < ref.F32_TENSOR_TOLERANCE, n
-
-
-def first_step_of_program(sym, params, ids, label):
-    """What the benchmark's driver reads: loss from the probabilities,
-    gradient norm over rows."""
-    prob, grads = program_first_step(sym, params, ids, label)
-    lab = label.reshape(-1).astype(int)
-    picked = prob[np.arange(lab.size), lab]
-    return {"loss": float(-np.mean(np.log(np.maximum(picked, 1e-30)))),
-            "grad_norm": float(np.sqrt(sum(
-                np.sum(np.square(g, dtype=np.float64))
-                for g in grads.values())))}
-
-
-def misses(got, want, tolerances):
-    return [k for k, tol in tolerances.items()
-            if abs(got[k] - want[k]) / abs(want[k]) > tol]
 
 
 def _no_latent_norm(ref, mp):
@@ -283,64 +220,56 @@ def _no_positions(ref, mp):
     mp.setattr(ref, "rotary", lambda x, theta: x)
 
 
+@pytest.fixture(scope="module")
+def first_step(ref):
+    """Four seeded rows through the float32 program and the plain
+    reference, once for the tests of the tolerances."""
+    sym = tiny_sym_gen()(T)[0]
+    ids, label = seeded_tokens(batch=4)
+    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
+    return mc.first_step_case(ref, TINY, sym, params, ids, label)
+
+
 @pytest.mark.parametrize("mutation", [
     _no_latent_norm, _rotate_half_pairing, _rotated_key_not_shared,
     _scale_of_the_nope_dims, _no_renormalisation, _no_route_scale,
     _no_selection_bias, _no_shared_experts, _no_positions])
-def test_tolerances_fail_a_wrong_layer(ref, monkeypatch, mutation):
+def test_tolerances_fail_a_wrong_layer(ref, monkeypatch, first_step,
+                                       mutation):
     """Against a reference that leaves a piece out, the program misses even
     the bfloat16 trunk's TOLERANCES; against the plain one it is inside the
     float32 ones."""
-    import jax
-    import jax.numpy as jnp
-
-    sym = tiny_sym_gen()(T)[0]
-    ids, label = seeded_tokens(batch=4)
-    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(sym, params, ids, label)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jax, TINY, leaves, jnp.asarray(ids), jnp.asarray(label))
-    assert not misses(got, ref.first_step(*args), ref.F32_TOLERANCES)
+    got = first_step.got
+    assert not misses(got, first_step.want, ref.F32_TOLERANCES)
     mutation(ref, monkeypatch)
-    assert misses(got, ref.first_step(*args), ref.TOLERANCES)
+    assert misses(got, ref.first_step(*first_step.args), ref.TOLERANCES)
 
 
-def test_float32_tolerances_fail_a_bfloat16_trunk(ref):
+def test_float32_tolerances_fail_a_bfloat16_trunk(ref, first_step):
     """The bfloat16 trunk is outside the float32 tolerances. (That it is
     inside TOLERANCES is a statement about published widths, checked on
     the chip by the benchmark's driver.)"""
-    import jax
-    import jax.numpy as jnp
-
-    ids, label = seeded_tokens(batch=4)
-    sym32 = tiny_sym_gen()(T)[0]
-    params = seeded_params(sym32, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(tiny_sym_gen("bfloat16")(T)[0], params, ids,
-                                label)
-    want = ref.first_step(jax, TINY, {n: jnp.asarray(a) for n, a in
-                                      params.items()},
-                          jnp.asarray(ids), jnp.asarray(label))
-    assert misses(got, want, ref.F32_TOLERANCES) == ["loss", "grad_norm"]
+    got = mc.first_step_of_program(
+        tiny_sym_gen("bfloat16")(T)[0], first_step.params, first_step.ids,
+        first_step.label)
+    assert misses(got, first_step.want, ref.F32_TOLERANCES) == [
+        "loss", "grad_norm"]
 
 
-def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch):
+def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch,
+                                                 first_step):
     """The precision below the bfloat16 the configuration states: this
     reference with float8_e4m3fn weights and projection inputs misses the
     limit the check rests on (at published widths, 1 x 8192 tokens, a
     builder's scratch run read 1.2e-4 on the loss, inside its limit, and
     0.78 on ``grad_norm``: PERF.md section 6, PR 41)."""
-    import jax
     import jax.numpy as jnp
 
     def f8(x):
         return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
 
-    ids, label = seeded_tokens(batch=4)
-    sym = tiny_sym_gen()(T)[0]
-    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jnp.asarray(ids), jnp.asarray(label))
-    want = ref.first_step(jax, TINY, leaves, *args)
+    jax, _, leaves, *args = first_step.args
+    want = first_step.want
     plain = ref.project
     monkeypatch.setattr(ref, "project", lambda x, w: plain(f8(x), w))
     low = {n: a if n.endswith(("_gamma", "_expert_bias")) else f8(a)
@@ -485,13 +414,11 @@ def test_estimate_flops_counts_the_scores_at_both_widths():
     """``models.recipe.estimate_flops`` on the published configuration
     against the builder's count of what this chip computes: ``p.v`` at the
     values' 128."""
-    import json
-
     from mxnet_tpu.models import recipe
 
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
+    with open(os.path.join(mc.ROOT, "benchmark", "configs", NAME + ".json")) as f:
         cfg = json.load(f)
-    builder = _load("configs")
+    builder = mc.load("configs", NAME)
     t = 8192
     sym = builder.sym_gen(cfg, mx)[0](t)[0]
     arg_shapes, _, _ = sym.infer_shape(data=(1, t), softmax_label=(1, t))

@@ -21,19 +21,20 @@ on the chip; each mechanism of the sparse attention left out moves the
 gradient norm by more than they allow.
 """
 
-import importlib.util
+import functools
 import os
 import sys
 
+import model_cases as mc
 import numpy as np
 import pytest
+from model_cases import bind_op, misses, rel
 
 import mxnet_tpu as mx
 import mxnet_tpu.parallel.ring_attention  # noqa: F401 (the module, below)
 from mxnet_tpu.base import MXNetError
 
 ra = sys.modules["mxnet_tpu.parallel.ring_attention"]
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "keye-vl-2.0-30b-a3b"
 TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=2,
             num_attention_heads=8, num_key_value_heads=2, head_dim=16,
@@ -49,17 +50,9 @@ INDEX_LEAVES = ("index_q_weight", "index_k_weight", "index_k_norm_gamma",
                 "index_k_norm_beta", "index_w_weight")
 
 
-def _load(kind):
-    path = os.path.join(ROOT, "benchmark", kind, NAME + ".py")
-    spec = importlib.util.spec_from_file_location(f"keye_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("reference")
+    return mc.load("reference", NAME)
 
 
 def tiny_cfg(**over):
@@ -70,42 +63,13 @@ def tiny_cfg(**over):
 
 
 def tiny_sym_gen(dtype="float32", **over):
-    return _load("configs").sym_gen(
+    return mc.load("configs", NAME).sym_gen(
         dict(tiny_cfg(**over), compute_dtype=dtype), mx)[0]
 
 
-def seeded_params(sym, seed=0, **shapes):
-    """normal(0, 0.3) weights (at 64 features that is what makes every
-    branch of the tiny model matter) and gains normal(1, 0.1)."""
-    rs = np.random.RandomState(seed)
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    out = {}
-    for name, shape in zip(sym.list_arguments(), arg_shapes):
-        if name in shapes:
-            continue
-        gain = name.endswith("_gamma")
-        out[name] = (rs.randn(*shape) * (0.1 if gain else 0.3)
-                     + (1.0 if gain else 0.0)).astype(np.float32)
-    return out
-
-
-def seeded_tokens(seed=1, batch=B, seq_len=T, vocab=TINY["vocab_size"]):
-    rs = np.random.RandomState(seed)
-    ids = rs.randint(1, vocab, size=(batch, seq_len)).astype(np.float32)
-    label = np.concatenate([ids[:, 1:], np.zeros((batch, 1), np.float32)], 1)
-    return ids, label
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
-
-
-def bind_op(sym, names, inputs):
-    return sym.bind(mx.cpu(), {n: mx.nd.array(a) for n, a in
-                               zip(names, inputs)},
-                    args_grad={n: mx.nd.zeros(a.shape) for n, a in
-                               zip(names, inputs)})
+seeded_params = mc.seeded_params
+seeded_tokens = functools.partial(mc.seeded_tokens, batch=B, seq_len=T,
+                                  vocab=TINY["vocab_size"])
 
 
 # --- LayerNorm ---------------------------------------------------------------
@@ -458,23 +422,6 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(ref):
 
 # --- the whole model ---------------------------------------------------------
 
-def bound(sym, params, ids, label):
-    exe = sym.simple_bind(mx.cpu(), data=ids.shape, softmax_label=label.shape)
-    for n, a in params.items():
-        exe.arg_dict[n][:] = a
-    exe.arg_dict["data"][:] = ids
-    exe.arg_dict["softmax_label"][:] = label
-    return exe
-
-
-def program_first_step(sym, params, ids, label):
-    """(probabilities, {name: gradient / rows}) of one forward/backward."""
-    exe = bound(sym, params, ids, label)
-    prob = exe.forward(is_train=True)[0].asnumpy()
-    exe.backward()
-    return prob, {n: exe.grad_dict[n].asnumpy() / ids.size for n in params}
-
-
 @pytest.mark.parametrize("t,blocks", [(32, None), (48, (16, 32))])
 def test_model_logits_and_every_gradient_match_the_reference(
         ref, monkeypatch, t, blocks):
@@ -490,7 +437,7 @@ def test_model_logits_and_every_gradient_match_the_reference(
     sym = tiny_sym_gen()(t)[0]
     ids, label = seeded_tokens(seq_len=t)
     params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    prob, grads = program_first_step(sym, params, ids, label)
+    prob, grads = mc.program_first_step(sym, params, ids, label)
     leaves = {n: jnp.asarray(a) for n, a in params.items()}
     _, want = ref.value_and_grads(jax, TINY, leaves, jnp.asarray(ids),
                                   jnp.asarray(label))
@@ -514,12 +461,11 @@ def test_the_language_model_does_not_see_the_indexers_loss(first_step):
     """With the KL coefficient at 0 and at 1 every leaf outside the indexer
     gets the same gradient, bit for bit, and the probabilities are the
     same; the indexer's five leaves a layer get nothing at 0."""
-    prob1, with_loss = first_step["prob"], first_step["grads"]
-    _, _, leaves, ids, label = first_step["args"]
-    params = {n: np.asarray(a) for n, a in leaves.items()}
-    prob0, without = program_first_step(
+    prob1, with_loss = first_step.prob, first_step.grads
+    params = first_step.params
+    prob0, without = mc.program_first_step(
         tiny_sym_gen(num_hidden_layers=1, index_loss_coef=0.0)(T)[0], params,
-        np.asarray(ids), np.asarray(label))
+        first_step.ids, first_step.label)
     assert np.array_equal(prob0, prob1)
     for n in params:
         if n.split("_", 1)[1] in INDEX_LEAVES:
@@ -528,50 +474,21 @@ def test_the_language_model_does_not_see_the_indexers_loss(first_step):
             assert np.array_equal(with_loss[n], without[n]), n
 
 
-def first_step_of_program(sym, params, ids, label, keep=None):
-    """What the benchmark's driver reads: loss from the probabilities,
-    gradient norm over rows (``keep``: a list that receives the
-    probabilities and the gradients they came from)."""
-    prob, grads = program_first_step(sym, params, ids, label)
-    if keep is not None:
-        keep.extend((prob, grads))
-    lab = label.reshape(-1).astype(int)
-    picked = prob[np.arange(lab.size), lab]
-    return {"loss": float(-np.mean(np.log(np.maximum(picked, 1e-30)))),
-            "grad_norm": float(np.sqrt(sum(
-                np.sum(np.square(g, dtype=np.float64))
-                for g in grads.values())))}
-
-
-def misses(got, want, tolerances):
-    return [k for k, tol in tolerances.items()
-            if abs(got[k] - want[k]) / abs(want[k]) > tol]
-
-
 @pytest.fixture(scope="module")
-def first_step():
-    """``got``: what the one-layer program's first step reads on four
-    seeded rows, with the ``prob`` and ``grads`` it came from; ``args``: the
-    reference's arguments for the same, and ``want``: what the plain
-    reference reads. One bind and one plain reference for the tests of the
+def first_step(ref):
+    """The one-layer program's first step on four seeded rows and the plain
+    reference's: one bind and one plain reference for the tests of the
     tolerances."""
-    import jax
-    import jax.numpy as jnp
-
     sym = tiny_sym_gen(num_hidden_layers=1)(T)[0]
     ids, label = seeded_tokens(batch=4)
     # the seed of the weights: a target from one head of 8 moves this size's
     # ``grad_norm`` by 1.6% to 3.2% over seeds 0-3 (0.49 at published widths)
     params = seeded_params(sym, seed=2, data=ids.shape,
                            softmax_label=label.shape)
-    kept = []
-    got = first_step_of_program(sym, params, ids, label, kept)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jax, ONE, leaves, jnp.asarray(ids), jnp.asarray(label))
+    case = mc.first_step_case(ref, ONE, sym, params, ids, label)
     # against the plain reference the program is inside the float32 limits
-    want = _load("reference").first_step(*args)
-    assert not misses(got, want, _load("reference").F32_TOLERANCES)
-    return dict(got=got, args=args, prob=kept[0], grads=kept[1], want=want)
+    assert not misses(case.got, case.want, ref.F32_TOLERANCES)
+    return case
 
 
 def _no_selection(ref, mp):
@@ -609,21 +526,19 @@ def test_tolerances_fail_a_wrong_layer(ref, monkeypatch, first_step,
     of the block) out, the program misses even the bfloat16 trunk's
     TOLERANCES; against the plain one it is inside the float32 ones (the
     fixture holds that, once)."""
-    got, args = first_step["got"], first_step["args"]
     mutation(ref, monkeypatch)
-    assert misses(got, ref.first_step(*args), ref.TOLERANCES)
+    assert misses(first_step.got, ref.first_step(*first_step.args),
+                  ref.TOLERANCES)
 
 
 def test_float32_tolerances_fail_a_bfloat16_trunk(ref, first_step):
     """The bfloat16 trunk is outside the float32 tolerances. (That it is
     inside TOLERANCES is a statement about published widths, checked on
     the chip by the benchmark's driver.)"""
-    _, _, leaves, ids, label = first_step["args"]
-    got = first_step_of_program(
+    got = mc.first_step_of_program(
         tiny_sym_gen("bfloat16", num_hidden_layers=1)(T)[0],
-        {n: np.asarray(a) for n, a in leaves.items()}, np.asarray(ids),
-        np.asarray(label))
-    assert misses(got, first_step["want"], ref.F32_TOLERANCES) == [
+        first_step.params, first_step.ids, first_step.label)
+    assert misses(got, first_step.want, ref.F32_TOLERANCES) == [
         "loss", "grad_norm"]
 
 
@@ -639,8 +554,8 @@ def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch,
     def f8(x):
         return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
 
-    jax, cfg, leaves, ids, label = first_step["args"]
-    want = first_step["want"]
+    jax, cfg, leaves, ids, label = first_step.args
+    want = first_step.want
     plain = ref.project
     monkeypatch.setattr(ref, "project", lambda x, w: plain(f8(x), w))
     low = {n: a if n.endswith(("_gamma", "_beta")) else f8(a)
@@ -751,10 +666,10 @@ def test_estimate_flops_and_the_parameter_count_at_published_widths():
 
     from mxnet_tpu.models import recipe
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
+    with open(os.path.join(mc.ROOT, "benchmark", "configs",
                            NAME + ".json")) as f:
         cfg = json.load(f)
-    builder = _load("configs")
+    builder = mc.load("configs", NAME)
     t = 16384
     sym = builder.sym_gen(cfg, mx)[0](t)[0]
     assert len(sym.list_arguments()) - 2 == 4 * 17 + 3

@@ -20,18 +20,18 @@ chunks, rotated 'rope' dims, or a left-out convolution, norm, gate or
 renormalisation moves the loss or the gradient norm by more than they allow.
 """
 
-import importlib.util
+import functools
 import json
 import os
 
+import model_cases as mc
 import numpy as np
 import pytest
-from test_kanana2 import (_moe_inputs, bind_op, bound, first_step_of_program,
-                          misses, program_first_step, rel, seeded_tokens)
+from model_cases import bind_op, misses, rel
+from test_kanana2 import _moe_inputs
 
 import mxnet_tpu as mx
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "kimi-linear-48b-a3b"
 TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=5,
             linear_attn_config=dict(kda_layers=[1, 2, 3, 5],
@@ -47,47 +47,33 @@ TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=5,
 B, T = 2, 16
 
 
-def _load(kind, name=NAME):
-    path = os.path.join(ROOT, "benchmark", kind, name + ".py")
-    spec = importlib.util.spec_from_file_location(f"kimi_linear_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("reference")
+    return mc.load("reference", NAME)
 
 
 def tiny_sym_gen(dtype="float32", **over):
     cfg = dict(TINY, compute_dtype=dtype, **over)
-    return _load("configs").sym_gen(cfg, mx)[0]
+    return mc.load("configs", NAME).sym_gen(cfg, mx)[0]
 
 
-def seeded_params(sym, seed=0, **shapes):
-    """normal(0, 0.3) weights (at 64 features that is what makes every
-    branch of the tiny model matter), gains normal(1, 0.1), a selection
-    bias normal(0, 0.2) (one that changes which experts are chosen),
-    ``A_log`` uniform over [0, ln 16] and ``dt_bias`` uniform over [ln
-    0.001, ln 0.5] a channel: decays from nothing to half a token."""
-    rs = np.random.RandomState(seed)
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    out = {}
-    for name, shape in zip(sym.list_arguments(), arg_shapes):
-        if name in shapes:
-            continue
-        if name.endswith("_A_log"):
-            out[name] = rs.uniform(0.0, np.log(16.0), shape)
-        elif name.endswith("_dt_bias"):
-            out[name] = rs.uniform(np.log(0.001), np.log(0.5), shape)
-        else:
-            gain = name.endswith("_gamma")
-            scale = 0.2 if name.endswith("_expert_bias") \
-                else 0.1 if gain else 0.3
-            out[name] = rs.randn(*shape) * scale + (1.0 if gain else 0.0)
-        out[name] = out[name].astype(np.float32)
-    return out
+def scale_rule(name):
+    """The common rule, a selection bias normal(0, 0.2) (one that changes
+    which experts are chosen), ``A_log`` uniform over [0, ln 16] and
+    ``dt_bias`` uniform over [ln 0.001, ln 0.5] a channel: decays from
+    nothing to half a token."""
+    if name.endswith("_A_log"):
+        return "uniform", 0.0, np.log(16.0)
+    if name.endswith("_dt_bias"):
+        return "uniform", np.log(0.001), np.log(0.5)
+    if name.endswith("_expert_bias"):
+        return 0.2, 0.0
+    return mc.gains_and_weights(name)
+
+
+seeded_params = functools.partial(mc.seeded_params, rule=scale_rule)
+seeded_tokens = functools.partial(mc.seeded_tokens, batch=B, seq_len=T,
+                                  vocab=TINY["vocab_size"])
 
 
 # --- the layer pattern -----------------------------------------------------------
@@ -243,7 +229,7 @@ def test_model_logits_and_every_gradient_match_the_reference(ref, seq_len):
     sym = tiny_sym_gen()(seq_len)[0]
     ids, label = seeded_tokens(seq_len=seq_len)
     params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    prob, grads = program_first_step(sym, params, ids, label)
+    prob, grads = mc.program_first_step(sym, params, ids, label)
     leaves = {n: jnp.asarray(a) for n, a in params.items()}
     scores = ref.logits(jax, TINY, leaves, jnp.asarray(ids))
     assert rel(prob, jax.nn.softmax(scores, -1)) < ref.F32_TENSOR_TOLERANCE
@@ -252,8 +238,8 @@ def test_model_logits_and_every_gradient_match_the_reference(ref, seq_len):
     assert set(want) == set(grads)
     # the reference's layer-at-a-time chain is autodiff of its whole loss
     with jax.default_matmul_precision("highest"):
-        whole = jax.grad(lambda p: ref.losses(
-            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0])(leaves)
+        whole = jax.jit(jax.grad(lambda p: ref.losses(
+            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0]))(leaves)
     for n in sorted(grads):
         assert rel(want[n], whole[n]) < 3e-5 or not np.asarray(
             whole[n]).any(), n
@@ -363,24 +349,17 @@ def _no_shared_expert(ref, mp):
 
 @pytest.fixture(scope="module")
 def first_steps(ref):
-    """(the program's first step, the plain reference's, the reference's
-    arguments) at T 128, two chunks of 64, so that a state dropped between
-    them shows: computed once for all the mutations."""
-    import jax
-    import jax.numpy as jnp
-
+    """The program's first step and the plain reference's at T 128, two
+    chunks of 64, so that a state dropped between them shows: computed once
+    for all the mutations."""
     sym = tiny_sym_gen()(128)[0]
     ids, label = seeded_tokens(batch=2, seq_len=128)
     params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(sym, params, ids, label)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jax, TINY, leaves, jnp.asarray(ids), jnp.asarray(label))
-    return got, ref.first_step(*args), args
+    return mc.first_step_case(ref, TINY, sym, params, ids, label)
 
 
 def test_float32_tolerances_hold_the_program(first_steps, ref):
-    got, want, _ = first_steps
-    assert not misses(got, want, ref.F32_TOLERANCES)
+    assert not misses(first_steps.got, first_steps.want, ref.F32_TOLERANCES)
 
 
 @pytest.mark.parametrize("mutation", [
@@ -394,45 +373,42 @@ def test_tolerances_fail_a_wrong_layer(first_steps, ref, monkeypatch,
     """Against a reference that leaves a piece out, the program misses even
     the bfloat16 trunk's TOLERANCES (against the plain one it is inside the
     float32 ones: the test above)."""
-    got, _, args = first_steps
     mutation(ref, monkeypatch)
-    assert misses(got, ref.first_step(*args), ref.TOLERANCES)
+    assert misses(first_steps.got, ref.first_step(*first_steps.args),
+                  ref.TOLERANCES)
 
 
-def test_float32_tolerances_fail_a_bfloat16_trunk(ref):
+@pytest.fixture(scope="module")
+def four_rows(ref):
+    """(params, ids, label, the reference's arguments, the plain reference's
+    reading) of four seeded rows of T 16, once for the two tests below."""
+    ids, label = seeded_tokens(batch=4)
+    params = seeded_params(tiny_sym_gen()(T)[0], data=ids.shape,
+                           softmax_label=label.shape)
+    args = mc.reference_args(TINY, params, ids, label)
+    return params, ids, label, args, ref.first_step(*args)
+
+
+def test_float32_tolerances_fail_a_bfloat16_trunk(ref, four_rows):
     """The bfloat16 trunk is outside the float32 tolerances. (That it is
     inside TOLERANCES is a statement about published widths, checked on
     the chip by the benchmark's driver.)"""
-    import jax
-    import jax.numpy as jnp
-
-    ids, label = seeded_tokens(batch=4)
-    sym32 = tiny_sym_gen()(T)[0]
-    params = seeded_params(sym32, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(tiny_sym_gen("bfloat16")(T)[0], params, ids,
-                                label)
-    want = ref.first_step(jax, TINY, {n: jnp.asarray(a) for n, a in
-                                      params.items()},
-                          jnp.asarray(ids), jnp.asarray(label))
+    params, ids, label, _, want = four_rows
+    got = mc.first_step_of_program(tiny_sym_gen("bfloat16")(T)[0], params,
+                                   ids, label)
     assert misses(got, want, ref.F32_TOLERANCES) == ["loss", "grad_norm"]
 
 
-def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch):
+def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch, four_rows):
     """The precision below the bfloat16 the configuration states: this
     reference with float8_e4m3fn weights and projection inputs misses the
     limit the check rests on."""
-    import jax
     import jax.numpy as jnp
 
     def f8(x):
         return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
 
-    ids, label = seeded_tokens(batch=4)
-    sym = tiny_sym_gen()(T)[0]
-    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jnp.asarray(ids), jnp.asarray(label))
-    want = ref.first_step(jax, TINY, leaves, *args)
+    (jax, _, leaves, *args), want = four_rows[3], four_rows[4]
     plain = ref.project
     monkeypatch.setattr(ref, "project", lambda x, w: plain(f8(x), w))
     low = {n: a if n.endswith(("_gamma", "_expert_bias", "_A_log",
@@ -598,9 +574,9 @@ def test_estimate_flops_and_the_parameter_count_of_the_published_cut():
     parameters of the cut against the configuration's table."""
     from mxnet_tpu.models import recipe
 
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
+    with open(os.path.join(mc.ROOT, "benchmark", "configs", NAME + ".json")) as f:
         cfg = json.load(f)
-    builder = _load("configs")
+    builder = mc.load("configs", NAME)
     t = 4096
     sym = builder.sym_gen(cfg, mx)[0](t)[0]
     arg_shapes, _, _ = sym.infer_shape(data=(1, t), softmax_label=(1, t))

@@ -12,23 +12,12 @@ import importlib
 
 import numpy as np
 import pytest
+from model_cases import bind_op, rel
 
 import mxnet_tpu as mx
 from mxnet_tpu.base import MXNetError
 
 ra = importlib.import_module("mxnet_tpu.parallel.ring_attention")
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
-
-
-def bind_op(sym, names, inputs):
-    return sym.bind(mx.cpu(), {n: mx.nd.array(a) for n, a in
-                               zip(names, inputs)},
-                    args_grad={n: mx.nd.zeros(a.shape) for n, a in
-                               zip(names, inputs)})
 
 
 def _dense_attention(q, k, v, scale, causal=True):

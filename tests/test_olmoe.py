@@ -13,16 +13,18 @@ renormalising the top-k weights, or leaving out a q/k norm or the rotary
 embedding moves the gradient norm by more than they allow (mutations a-c).
 """
 
-import importlib.util
+import functools
 import os
 
+import model_cases as mc
 import numpy as np
 import pytest
+from model_cases import misses, rel
 
 import mxnet_tpu as mx
 from mxnet_tpu import models
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NAME = "olmoe-1b-7b"
 TINY = dict(vocab_size=64, hidden_size=32, num_hidden_layers=2,
             num_attention_heads=2, num_experts=8, intermediate_size=16,
             num_experts_per_tok=2, rms_norm_eps=1e-5, rope_theta=10000.0,
@@ -30,17 +32,9 @@ TINY = dict(vocab_size=64, hidden_size=32, num_hidden_layers=2,
 B, T = 2, 16
 
 
-def _load(kind, name="olmoe-1b-7b"):
-    path = os.path.join(ROOT, "benchmark", kind, name + ".py")
-    spec = importlib.util.spec_from_file_location(f"olmoe_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("reference")
+    return mc.load("reference", NAME)
 
 
 def tiny_sym_gen(dtype="float32", **over):
@@ -54,41 +48,9 @@ def tiny_sym_gen(dtype="float32", **over):
         z_coef=cfg["router_z_loss_coef"], dtype=dtype)
 
 
-def seeded_params(sym, seed=0, **shapes):
-    """normal(0, 0.3) weights (larger than the configuration's 0.02: at 32
-    features that is what makes every branch of the tiny model matter) and
-    gains normal(1, 0.1)."""
-    rs = np.random.RandomState(seed)
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    out = {}
-    for name, shape in zip(sym.list_arguments(), arg_shapes):
-        if name in shapes:
-            continue
-        gain = name.endswith("_gamma")
-        out[name] = (rs.randn(*shape) * (0.1 if gain else 0.3)
-                     + (1.0 if gain else 0.0)).astype(np.float32)
-    return out
-
-
-def seeded_tokens(seed=1, batch=B, seq_len=T, vocab=TINY["vocab_size"]):
-    rs = np.random.RandomState(seed)
-    ids = rs.randint(1, vocab, size=(batch, seq_len)).astype(np.float32)
-    label = np.concatenate([ids[:, 1:], np.zeros((batch, 1), np.float32)], 1)
-    return ids, label
-
-
-def bound(sym, params, ids, label):
-    exe = sym.simple_bind(mx.cpu(), data=ids.shape, softmax_label=label.shape)
-    for n, a in params.items():
-        exe.arg_dict[n][:] = a
-    exe.arg_dict["data"][:] = ids
-    exe.arg_dict["softmax_label"][:] = label
-    return exe
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
+seeded_params = mc.seeded_params
+seeded_tokens = functools.partial(mc.seeded_tokens, batch=B, seq_len=T,
+                                  vocab=TINY["vocab_size"])
 
 
 # --- each op against the reference's function for it ------------------------
@@ -232,15 +194,6 @@ def test_moe_is_drop_free_when_every_token_takes_the_same_experts(ref):
 
 # --- the whole model ---------------------------------------------------------
 
-def program_first_step(sym, params, ids, label):
-    """(probabilities, {name: gradient / rows}) of one forward/backward."""
-    exe = bound(sym, params, ids, label)
-    prob = exe.forward(is_train=True)[0].asnumpy()
-    exe.backward()
-    rows = ids.size
-    return prob, {n: exe.grad_dict[n].asnumpy() / rows for n in params}
-
-
 def test_model_logits_and_every_gradient_match_the_reference(ref):
     import jax
     import jax.numpy as jnp
@@ -248,7 +201,7 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
     sym = tiny_sym_gen()(T)[0]
     ids, label = seeded_tokens()
     params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    prob, grads = program_first_step(sym, params, ids, label)
+    prob, grads = mc.program_first_step(sym, params, ids, label)
     leaves = {n: jnp.asarray(a) for n, a in params.items()}
     scores = ref.logits(jax, TINY, leaves, jnp.asarray(ids))
     assert rel(prob, jax.nn.softmax(scores, -1)) < ref.F32_TENSOR_TOLERANCE
@@ -257,23 +210,6 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
     assert set(want) == set(grads)
     for n in sorted(grads):
         assert rel(grads[n], want[n]) < ref.F32_TENSOR_TOLERANCE, n
-
-
-def first_step_of_program(sym, params, ids, label):
-    """What the benchmark's driver reads: loss from the probabilities,
-    gradient norm over rows."""
-    prob, grads = program_first_step(sym, params, ids, label)
-    lab = label.reshape(-1).astype(int)
-    picked = prob[np.arange(lab.size), lab]
-    return {"loss": float(-np.mean(np.log(np.maximum(picked, 1e-30)))),
-            "grad_norm": float(np.sqrt(sum(
-                np.sum(np.square(g, dtype=np.float64))
-                for g in grads.values())))}
-
-
-def misses(got, want, tolerances):
-    return [k for k, tol in tolerances.items()
-            if abs(got[k] - want[k]) / abs(want[k]) > tol]
 
 
 def _drop_an_expert(ref, mp):
@@ -306,43 +242,39 @@ def _no_rotary(ref, mp):
     mp.setattr(ref, "rotary", lambda x, theta: x)
 
 
-@pytest.mark.parametrize("mutation", [_drop_an_expert, _renormalise,
-                                      _no_qk_norm, _no_rotary])
-def test_tolerances_fail_a_wrong_layer(ref, monkeypatch, mutation):
-    """(a)-(c): against a reference that leaves a piece out, the program
-    misses even the bfloat16 trunk's TOLERANCES; against the plain one it
-    is inside the float32 ones."""
-    import jax
-    import jax.numpy as jnp
-
+@pytest.fixture(scope="module")
+def first_step(ref):
+    """Four seeded rows through the float32 program and the plain
+    reference, once for the tests of the tolerances."""
     sym = tiny_sym_gen()(T)[0]
     ids, label = seeded_tokens(batch=4)
     params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(sym, params, ids, label)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jax, TINY, leaves, jnp.asarray(ids), jnp.asarray(label))
-    assert not misses(got, ref.first_step(*args), ref.F32_TOLERANCES)
+    return mc.first_step_case(ref, TINY, sym, params, ids, label)
+
+
+@pytest.mark.parametrize("mutation", [_drop_an_expert, _renormalise,
+                                      _no_qk_norm, _no_rotary])
+def test_tolerances_fail_a_wrong_layer(ref, monkeypatch, first_step,
+                                       mutation):
+    """(a)-(c): against a reference that leaves a piece out, the program
+    misses even the bfloat16 trunk's TOLERANCES; against the plain one it
+    is inside the float32 ones."""
+    got = first_step.got
+    assert not misses(got, first_step.want, ref.F32_TOLERANCES)
     mutation(ref, monkeypatch)
-    assert misses(got, ref.first_step(*args), ref.TOLERANCES)
+    assert misses(got, ref.first_step(*first_step.args), ref.TOLERANCES)
 
 
-def test_float32_tolerances_fail_a_bfloat16_trunk(ref):
+def test_float32_tolerances_fail_a_bfloat16_trunk(ref, first_step):
     """(d): the bfloat16 trunk is outside the float32 tolerances. (That it
     is inside TOLERANCES is a statement about published widths, checked on
     the chip by the benchmark's driver: at 32 features bfloat16 is off by
     more, 1.5e-3 in the loss.)"""
-    import jax
-    import jax.numpy as jnp
-
-    ids, label = seeded_tokens(batch=4)
-    sym32 = tiny_sym_gen()(T)[0]
-    params = seeded_params(sym32, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(tiny_sym_gen("bfloat16")(T)[0], params, ids,
-                                label)
-    want = ref.first_step(jax, TINY, {n: jnp.asarray(a) for n, a in
-                                      params.items()},
-                          jnp.asarray(ids), jnp.asarray(label))
-    assert misses(got, want, ref.F32_TOLERANCES) == ["loss", "grad_norm"]
+    got = mc.first_step_of_program(
+        tiny_sym_gen("bfloat16")(T)[0], first_step.params, first_step.ids,
+        first_step.label)
+    assert misses(got, first_step.want, ref.F32_TOLERANCES) == [
+        "loss", "grad_norm"]
 
 
 def test_three_adam_steps_through_fit_follow_the_reference(ref):
@@ -443,10 +375,10 @@ def test_estimate_flops_counts_attention_and_routed_experts():
 
     from mxnet_tpu.models import recipe
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
+    with open(os.path.join(mc.ROOT, "benchmark", "configs",
                            "olmoe-1b-7b.json")) as f:
         cfg = json.load(f)
-    builder = _load("configs")
+    builder = mc.load("configs", NAME)
     sym = builder.sym_gen(cfg, mx)[0](4096)[0]
     macs = recipe.estimate_flops(sym, data=(1, 4096),
                                  softmax_label=(1, 4096)) / 4096

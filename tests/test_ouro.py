@@ -15,17 +15,18 @@ or a last exit that reads its gate moves the gradient norm by more than they
 allow, and so does the reference computed in float8.
 """
 
-import importlib.util
+import functools
 import json
 import os
 
+import model_cases as mc
 import numpy as np
 import pytest
+from model_cases import misses, rel
 
 import mxnet_tpu as mx
 from mxnet_tpu.base import MXNetError
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "ouro-2.6b"
 TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=2,
             num_attention_heads=4, num_key_value_heads=4, head_dim=16,
@@ -34,17 +35,9 @@ TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=2,
 B, T = 4, 32
 
 
-def _load(kind):
-    path = os.path.join(ROOT, "benchmark", kind, NAME + ".py")
-    spec = importlib.util.spec_from_file_location(f"ouro_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("reference")
+    return mc.load("reference", NAME)
 
 
 def tiny(passes=4, **over):
@@ -52,41 +45,26 @@ def tiny(passes=4, **over):
 
 
 def tiny_sym_gen(cfg, dtype="float32"):
-    return _load("configs").sym_gen(dict(cfg, compute_dtype=dtype), mx)[0]
+    return mc.load("configs", NAME).sym_gen(
+        dict(cfg, compute_dtype=dtype), mx)[0]
 
 
-def seeded_params(sym, seed=0, **shapes):
+def scale_rule(name):
     """normal(0, 0.2) weights (at 64 features that is what makes every
     branch of the tiny model matter), gains normal(1, 0.1), and a gate of
     normal(0, 2) weights and a bias near -0.7: exits of unlike shares, and
     a gate through which enough of the gradient reaches the trunk that the
     entropy term shows in the gradient's norm (at normal(0, 0.5) dropping
     beta moves it by 1.3e-3, inside the bfloat16 limit)."""
-    rs = np.random.RandomState(seed)
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    out = {}
-    for name, shape in zip(sym.list_arguments(), arg_shapes):
-        if name in shapes:
-            continue
-        gain = name.endswith("_gamma")
-        scale = 0.1 if gain else 0.2
-        if name.startswith("early_exit_gate"):
-            scale = 2.0 if name.endswith("_weight") else 0.5
-        shift = 1.0 if gain else -0.7 if name.endswith("gate_bias") else 0.0
-        out[name] = (rs.randn(*shape) * scale + shift).astype(np.float32)
-    return out
+    if name.startswith("early_exit_gate"):
+        return (2.0, 0.0) if name.endswith("_weight") else (
+            0.5, -0.7 if name.endswith("gate_bias") else 0.0)
+    return mc.gains_and_weights(name, weight=0.2)
 
 
-def seeded_tokens(seed=1, batch=B, seq_len=T, vocab=TINY["vocab_size"]):
-    rs = np.random.RandomState(seed)
-    ids = rs.randint(1, vocab, size=(batch, seq_len)).astype(np.float32)
-    label = np.concatenate([ids[:, 1:], np.zeros((batch, 1), np.float32)], 1)
-    return ids, label
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
+seeded_params = functools.partial(mc.seeded_params, rule=scale_rule)
+seeded_tokens = functools.partial(mc.seeded_tokens, batch=B, seq_len=T,
+                                  vocab=TINY["vocab_size"])
 
 
 # --- the loss layer alone ----------------------------------------------------
@@ -227,18 +205,17 @@ class Step:
     def first_step(self):
         """What the benchmark's driver reads: loss from the probabilities,
         gradient norm over rows."""
-        lab = self.label.reshape(-1).astype(int)
-        picked = self.prob[np.arange(lab.size), lab]
-        return {"loss": float(-np.mean(np.log(np.maximum(picked, 1e-30)))),
-                "grad_norm": float(np.sqrt(sum(
-                    np.sum(np.square(g, dtype=np.float64))
-                    for g in self.grads.values())))}
+        return mc.reading(self.prob, self.grads, self.label)
 
     def leaves(self):
-        import jax.numpy as jnp
+        return mc.reference_args(self.cfg, self.params, self.ids,
+                                 self.label)[2:]
 
-        return ({n: jnp.asarray(a) for n, a in self.params.items()},
-                jnp.asarray(self.ids), jnp.asarray(self.label))
+    @functools.cached_property
+    def want(self):
+        """The plain reference's reading of the same step, evaluated once."""
+        return mc.load("reference", NAME).first_step(*mc.reference_args(
+            self.cfg, self.params, self.ids, self.label))
 
 
 @pytest.fixture(scope="module")
@@ -287,13 +264,7 @@ def test_every_exits_logits_and_every_gradient_match_the_reference(
     for n in shared + tuple(sorted(set(grads) - set(shared))):
         assert np.asarray(grads[n]).any(), n
         assert rel(step.grads[n], grads[n]) < ref.F32_TENSOR_TOLERANCE, n
-    assert not ref_misses(step.first_step(), ref.first_step(
-        jax, step.cfg, leaves, ids, label), ref.F32_TOLERANCES)
-
-
-def ref_misses(got, want, tolerances):
-    return [k for k, tol in tolerances.items()
-            if abs(got[k] - want[k]) / abs(want[k]) > tol]
+    assert not misses(step.first_step(), step.want, ref.F32_TOLERANCES)
 
 
 def _a_pass_left_out(ref, mp):
@@ -349,7 +320,7 @@ def test_tolerances_fail_a_wrong_layer_and_a_float8_reference(
     ce, grads, _ = ref.value_and_grads(jax, step.cfg, *step.leaves())
     want = {"loss": float(ce), "grad_norm": float(jnp.sqrt(sum(
         jnp.sum(g ** 2) for g in grads.values())))}
-    missed = ref_misses(step.first_step(), want, ref.TOLERANCES)
+    missed = misses(step.first_step(), want, ref.TOLERANCES)
     if seen_by == "grad_norm":
         assert "grad_norm" in missed, mutation.__name__
     else:
@@ -368,16 +339,13 @@ def test_float32_tolerances_fail_a_bfloat16_trunk_and_counts_under_recompute(
     program counts its exits, their rows, the attention of every layer
     APPLICATION and the nodes that read a shared weight; every attention
     node and the loss keep the residuals they name."""
-    import jax
-
     from mxnet_tpu import telemetry as tm
 
     monkeypatch.setenv("MXNET_BACKWARD_DO_MIRROR", "1")
     before = tm.snapshot().get("executor", {})
     low = Step(4, "bfloat16")
     after = tm.snapshot()["executor"]
-    want = ref.first_step(jax, low.cfg, *steps(4).leaves())
-    assert ref_misses(low.first_step(), want, ref.F32_TOLERANCES) \
+    assert misses(low.first_step(), steps(4).want, ref.F32_TOLERANCES) \
         == ["loss", "grad_norm"]
 
     def delta(name):
@@ -519,13 +487,13 @@ def test_estimate_flops_and_the_parameter_count_at_published_widths(
     for the gate's 2048 a row and exit and the causal diagonal."""
     from mxnet_tpu.models import recipe
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
+    with open(os.path.join(mc.ROOT, "benchmark", "configs",
                            NAME + ".json")) as f:
         cfg = json.load(f)
     if layers == cfg["num_hidden_layers"]:
         assert cfg["parameters"] == count
     cfg = dict(cfg, num_hidden_layers=layers)
-    builder = _load("configs")
+    builder = mc.load("configs", NAME)
     t = 4096
     sym = builder.sym_gen(cfg, mx)[0](t)[0]
     assert len(sym.list_arguments()) - 2 == 11 * layers + 5

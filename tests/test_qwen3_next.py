@@ -18,16 +18,16 @@ miss those.
 """
 
 import functools
-import importlib.util
 import os
 
+import model_cases as mc
 import numpy as np
 import pytest
+from model_cases import bind_op, misses, rel
 
 import mxnet_tpu as mx
 from mxnet_tpu.ops import gated_delta as gd
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "qwen3-next-80b-a3b"
 TINY = dict(vocab_size=64, hidden_size=32, num_hidden_layers=4,
             full_attention_interval=4, num_attention_heads=4,
@@ -42,66 +42,32 @@ TINY = dict(vocab_size=64, hidden_size=32, num_hidden_layers=4,
 B, T = 2, 128
 
 
-@functools.lru_cache(maxsize=None)
-def _load(kind):
-    path = os.path.join(ROOT, "benchmark", kind, NAME + ".py")
-    spec = importlib.util.spec_from_file_location(f"qwen3_next_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("reference")
+    return mc.load("reference", NAME)
 
 
 def tiny_sym_gen(dtype="float32", **over):
     cfg = dict(TINY, compute_dtype=dtype, **over)
-    return _load("configs").sym_gen(cfg, mx)[0]
+    return mc.load("configs", NAME).sym_gen(cfg, mx)[0]
 
 
-def seeded_params(sym, seed=0, **shapes):
-    """normal(0, 0.3) weights (at 32 features that is what makes every
-    branch of the tiny model matter; 0.05 into the decay's projection, so
-    that a chunk's summed log-decay stays in the hundreds as a trained
-    model's does), gains normal(1, 0.1), and the decay's two parameters
-    over the configuration's ranges."""
-    rs = np.random.RandomState(seed)
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    out = {}
-    for name, shape in zip(sym.list_arguments(), arg_shapes):
-        if name in shapes:
-            continue
-        if name.endswith("_A_log"):
-            out[name] = rs.uniform(0, np.log(16), shape)
-        elif name.endswith("_dt_bias"):
-            out[name] = rs.uniform(np.log(0.001), np.log(0.1), shape)
-        else:
-            gain = name.endswith("_gamma")
-            scale = 0.1 if gain else 0.05 if "in_proj_ba" in name else 0.3
-            out[name] = rs.randn(*shape) * scale + (1.0 if gain else 0.0)
-        out[name] = out[name].astype(np.float32)
-    return out
+def scale_rule(name):
+    """The common rule; 0.05 into the decay's projection, so that a chunk's
+    summed log-decay stays in the hundreds as a trained model's does, and
+    the decay's two parameters over the configuration's ranges."""
+    if name.endswith("_A_log"):
+        return "uniform", 0, np.log(16)
+    if name.endswith("_dt_bias"):
+        return "uniform", np.log(0.001), np.log(0.1)
+    if "in_proj_ba" in name:
+        return 0.05, 0.0
+    return mc.gains_and_weights(name)
 
 
-def seeded_tokens(seed=1, batch=B, seq_len=T, vocab=TINY["vocab_size"]):
-    rs = np.random.RandomState(seed)
-    ids = rs.randint(1, vocab, size=(batch, seq_len)).astype(np.float32)
-    label = np.concatenate([ids[:, 1:], np.zeros((batch, 1), np.float32)], 1)
-    return ids, label
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
-
-
-def bind_op(sym, names, inputs):
-    return sym.bind(mx.cpu(), {n: mx.nd.array(a) for n, a in
-                               zip(names, inputs)},
-                    args_grad={n: mx.nd.zeros(a.shape) for n, a in
-                               zip(names, inputs)})
+seeded_params = functools.partial(mc.seeded_params, rule=scale_rule)
+seeded_tokens = functools.partial(mc.seeded_tokens, batch=B, seq_len=T,
+                                  vocab=TINY["vocab_size"])
 
 
 # --- the gated delta rule: chunks against tokens ------------------------------
@@ -137,7 +103,7 @@ def _chunks_and_tokens(t, key_heads, chunk=64):
     import jax
     import jax.numpy as jnp
 
-    ref = _load("reference")
+    ref = mc.load("reference", NAME)
     inputs = [jnp.asarray(x) for x in _rule_inputs(t, key_heads)]
     head = jnp.asarray(np.random.RandomState(4).randn(
         *inputs[2].shape).astype(np.float32))
@@ -479,49 +445,6 @@ def test_the_shares_add_up_to_the_uncut_layer(ref):
 
 # --- the whole model ----------------------------------------------------------
 
-def bound(sym, params, ids, label):
-    exe = sym.simple_bind(mx.cpu(), data=ids.shape, softmax_label=label.shape)
-    for n, a in params.items():
-        exe.arg_dict[n][:] = a
-    exe.arg_dict["data"][:] = ids
-    exe.arg_dict["softmax_label"][:] = label
-    return exe
-
-
-def program_first_step(sym, params, ids, label):
-    """(probabilities, {name: gradient / rows}) of one forward/backward."""
-    exe = bound(sym, params, ids, label)
-    prob = exe.forward(is_train=True)[0].asnumpy()
-    exe.backward()
-    return prob, {n: exe.grad_dict[n].asnumpy() / ids.size for n in params}
-
-
-def first_step_of_program(sym, params, ids, label):
-    """What the benchmark's driver reads: loss from the probabilities,
-    gradient norm over rows."""
-    prob, grads = program_first_step(sym, params, ids, label)
-    lab = label.reshape(-1).astype(int)
-    picked = prob[np.arange(lab.size), lab]
-    return {"loss": float(-np.mean(np.log(np.maximum(picked, 1e-30)))),
-            "grad_norm": float(np.sqrt(sum(
-                np.sum(np.square(g, dtype=np.float64))
-                for g in grads.values())))}
-
-
-@functools.lru_cache(maxsize=None)
-def _tiny_case():
-    """(ids, label, params, the float32 program's first step)."""
-    sym = tiny_sym_gen()(T)[0]
-    ids, label = seeded_tokens()
-    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    return ids, label, params, first_step_of_program(sym, params, ids, label)
-
-
-def misses(got, want, tolerances):
-    return [k for k, tol in tolerances.items()
-            if abs(got[k] - want[k]) / abs(want[k]) > tol]
-
-
 def test_the_period_is_three_linear_layers_and_one_full():
     args = tiny_sym_gen()(T)[0].list_arguments()
     for i in range(3):
@@ -531,14 +454,23 @@ def test_the_period_is_three_linear_layers_and_one_full():
     assert all(f"l{i}_shared_expert_gate_weight" in args for i in range(4))
 
 
-def test_model_logits_and_every_gradient_match_the_reference(ref):
+@pytest.fixture(scope="module")
+def first_step(ref):
+    """The seeded rows through the float32 program and the plain reference,
+    once for the whole-model tests."""
+    sym = tiny_sym_gen()(T)[0]
+    ids, label = seeded_tokens()
+    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
+    return mc.first_step_case(ref, TINY, sym, params, ids, label)
+
+
+def test_model_logits_and_every_gradient_match_the_reference(ref, first_step):
     import jax
     import jax.numpy as jnp
 
-    sym = tiny_sym_gen()(T)[0]
-    ids, label, params, _ = _tiny_case()
-    prob, grads = program_first_step(sym, params, ids, label)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
+    ids, label = first_step.ids, first_step.label
+    prob, grads = first_step.prob, first_step.grads
+    leaves = first_step.args[2]
     scores = ref.logits(jax, TINY, leaves, jnp.asarray(ids))
     assert rel(prob, jax.nn.softmax(scores, -1)) < ref.F32_TENSOR_TOLERANCE
     _, want = ref.value_and_grads(jax, TINY, leaves, jnp.asarray(ids),
@@ -546,8 +478,8 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
     assert set(want) == set(grads)
     # the reference's layer-at-a-time chain is autodiff of its whole loss
     with jax.default_matmul_precision("highest"):
-        whole = jax.grad(lambda p: ref.losses(
-            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0])(leaves)
+        whole = jax.jit(jax.grad(lambda p: ref.losses(
+            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0]))(leaves)
     for n in sorted(grads):
         assert rel(want[n], whole[n]) < 1e-4, n
         assert np.asarray(want[n]).any(), n
@@ -621,18 +553,14 @@ MUTATIONS = [_state_not_carried, _no_decay, _beta_one, _no_l2norm,
 
 
 @pytest.mark.parametrize("mutation", MUTATIONS)
-def test_float32_tolerances_fail_a_wrong_layer(ref, monkeypatch, mutation):
+def test_float32_tolerances_fail_a_wrong_layer(ref, monkeypatch, first_step,
+                                               mutation):
     """Against the plain reference the program is inside the float32
     tolerances; against one that leaves a piece out it is not."""
-    import jax
-    import jax.numpy as jnp
-
-    ids, label, params, got = _tiny_case()
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jax, TINY, leaves, jnp.asarray(ids), jnp.asarray(label))
-    assert not misses(got, ref.first_step(*args), ref.F32_TOLERANCES)
+    got = first_step.got
+    assert not misses(got, first_step.want, ref.F32_TOLERANCES)
     mutation(ref, monkeypatch)
-    assert "grad_norm" in misses(got, ref.first_step(*args),
+    assert "grad_norm" in misses(got, ref.first_step(*first_step.args),
                                  ref.F32_TOLERANCES)
 
 
@@ -669,8 +597,8 @@ def _published_case(seq_len):
 
     import jax.numpy as jnp
 
-    builder = _load("configs")
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
+    builder = mc.load("configs", NAME)
+    with open(os.path.join(mc.ROOT, "benchmark", "configs", NAME + ".json")) as f:
         cfg = json.load(f)
     sym = builder.sym_gen(cfg, mx)[0](seq_len)[0]
     shapes, _, _ = sym.infer_shape(data=(1, seq_len),
@@ -690,7 +618,8 @@ def _published_case(seq_len):
 
 
 @pytest.mark.parametrize("size", ["tiny", "published"])
-def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch, size):
+def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch, first_step,
+                                                 size):
     """``TOLERANCES`` lie above the bfloat16 trunk's error (the chip's
     readings, in the reference's docstring) and below the next precision
     down: the reference with float8 weights and matmul inputs, against
@@ -698,36 +627,27 @@ def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch, size):
     published widths over a short row (256 tokens; the docstring's reading
     is this test's at 2048)."""
     import jax
-    import jax.numpy as jnp
 
     if size == "tiny":
-        ids, label = seeded_tokens()
-        params = seeded_params(tiny_sym_gen()(T)[0], data=ids.shape,
-                               softmax_label=label.shape)
-        cfg, params = TINY, {n: jnp.asarray(a) for n, a in params.items()}
-        ids, label = jnp.asarray(ids), jnp.asarray(label)
+        _, cfg, params, ids, label = first_step.args
+        want = first_step.want
     else:
         cfg, params, ids, label = _published_case(256)
-    want = ref.first_step(jax, cfg, params, ids, label)
+        want = ref.first_step(jax, cfg, params, ids, label)
     f8 = _float8(ref, monkeypatch)
     got = ref.first_step(jax, cfg, _float8_weights(f8, params), ids, label)
     assert "grad_norm" in misses(got, want, ref.TOLERANCES), (got, want)
 
 
-def test_float32_tolerances_fail_a_bfloat16_trunk(ref):
+def test_float32_tolerances_fail_a_bfloat16_trunk(ref, first_step):
     """The bfloat16 trunk is outside the float32 tolerances. (That it is
     inside TOLERANCES is a statement about published widths, checked on
     the chip by the benchmark's driver.)"""
-    import jax
-    import jax.numpy as jnp
-
-    ids, label, params, _ = _tiny_case()
-    got = first_step_of_program(tiny_sym_gen("bfloat16")(T)[0], params, ids,
-                                label)
-    want = ref.first_step(jax, TINY, {n: jnp.asarray(a) for n, a in
-                                      params.items()},
-                          jnp.asarray(ids), jnp.asarray(label))
-    assert misses(got, want, ref.F32_TOLERANCES) == ["loss", "grad_norm"]
+    got = mc.first_step_of_program(
+        tiny_sym_gen("bfloat16")(T)[0], first_step.params, first_step.ids,
+        first_step.label)
+    assert misses(got, first_step.want, ref.F32_TOLERANCES) == [
+        "loss", "grad_norm"]
 
 
 @pytest.mark.parametrize("mirror", ["", "1"], ids=["kept", "recomputed"])
@@ -844,10 +764,10 @@ def test_estimate_flops_is_near_the_builders_count():
 
     from mxnet_tpu.models import recipe
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
+    with open(os.path.join(mc.ROOT, "benchmark", "configs",
                            NAME + ".json")) as f:
         cfg = json.load(f)
-    builder = _load("configs")
+    builder = mc.load("configs", NAME)
     t = max(cfg["buckets"])
     sym = builder.sym_gen(cfg, mx)[0](t)[0]
     assert len(sym.list_arguments()) - 2 == 3 * 17 + 16 + 3

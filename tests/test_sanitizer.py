@@ -257,33 +257,38 @@ def test_hold_tracking_reports_long_hold(armed, monkeypatch):
 
 # ------------------------------------------------------------- overhead
 
-def test_overhead_smoke():
-    """Steady-state sanitized acquire/release stays within 10x of a bare
-    lock — the bound the fast path (no stack capture, edges seen) is
-    designed for. Median of several trials to shrug off CI noise."""
-    n = 20_000
+def test_overhead_smoke(monkeypatch):
+    """Steady-state sanitized acquire/release stays on the fast path its
+    cost bound (within 10x of a bare lock) is designed for: once a pair's
+    edge has been seen, no stack is captured and the order graph is not
+    walked (its mutex is never taken). Counted, not timed."""
+    calls = []
 
-    def cycle_time(lock):
-        acquire, release = lock.acquire, lock.release
-        best = float("inf")
-        for _ in range(5):
-            t0 = time.perf_counter()
-            for _ in range(n):
-                acquire()
-                release()
-            best = min(best, time.perf_counter() - t0)
-        return best
+    def counted(name):
+        real = getattr(sanitizer, name)
 
-    bare = cycle_time(threading.Lock())
+        def call(*args, **kwargs):
+            calls.append(name)
+            return real(*args, **kwargs)
+        monkeypatch.setattr(sanitizer, name, call)
 
+    counted("_stack")
+    counted("_record_edges")
     sanitizer.install()
     try:
-        sanitized = cycle_time(threading.Lock())
+        outer, inner = threading.Lock(), threading.Lock()
+
+        def cycle():
+            with outer:
+                with inner:
+                    pass
+
+        cycle()             # the first-seen edge outer -> inner
+        assert calls == ["_stack", "_record_edges"]
+        del calls[:]
+        for _ in range(20_000):
+            cycle()
+        assert not calls
     finally:
         sanitizer.uninstall()
         sanitizer.reset()
-
-    ratio = sanitized / bare
-    assert ratio < 10.0, (
-        f"sanitized acquire/release {sanitized / n * 1e9:.0f}ns vs bare "
-        f"{bare / n * 1e9:.0f}ns — {ratio:.1f}x exceeds the 10x budget")

@@ -18,19 +18,20 @@ time (about 20 s each on one worker); the interpreter-mode kernels are in
 ``tests/test_diffusion_kernels.py``.
 """
 
-import importlib.util
+import functools
 import os
 import sys
 
+import model_cases as mc
 import numpy as np
 import pytest
+from model_cases import bind_op, rel
 
 import mxnet_tpu as mx
 import mxnet_tpu.parallel.ring_attention  # noqa: F401 (the module, below)
 from mxnet_tpu.base import MXNetError
 
 ra = sys.modules["mxnet_tpu.parallel.ring_attention"]
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "sdar-30b-a3b"
 TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=2,
             num_attention_heads=8, num_key_value_heads=2, head_dim=16,
@@ -42,61 +43,20 @@ TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=2,
 B, T = 2, 32
 
 
-def _load(kind):
-    path = os.path.join(ROOT, "benchmark", kind, NAME + ".py")
-    spec = importlib.util.spec_from_file_location(f"sdar_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("reference")
+    return mc.load("reference", NAME)
 
 
 def tiny_sym_gen(seeded=True, **over):
-    return _load("configs").sym_gen(dict(TINY, **over), mx,
-                                    0.0 if seeded else None)[0]
+    return mc.load("configs", NAME).sym_gen(dict(TINY, **over), mx,
+                                            0.0 if seeded else None)[0]
 
 
-def seeded_params(sym, seed=0, **shapes):
-    """normal(0, 0.3) weights (at 64 features that is what makes every
-    branch of the tiny model matter) and gains normal(1, 0.1)."""
-    rs = np.random.RandomState(seed)
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    out = {}
-    for name, shape in zip(sym.list_arguments(), arg_shapes):
-        if name in shapes:
-            continue
-        gain = name.endswith("_gamma")
-        out[name] = (rs.randn(*shape) * (0.1 if gain else 0.3)
-                     + (1.0 if gain else 0.0)).astype(np.float32)
-    return out
-
-
-def seeded_tokens(seed=1, batch=B, seq_len=T, vocab=TINY["vocab_size"],
-                  pads=2):
-    """Ids 1..vocab-1 with ``pads`` pad positions at the end of row 0, and
-    the next-token labels an iterator would feed."""
-    rs = np.random.RandomState(seed)
-    ids = rs.randint(1, vocab, size=(batch, seq_len)).astype(np.float32)
-    if pads:
-        ids[0, -pads:] = 0
-    label = np.concatenate([ids[:, 1:], np.zeros((batch, 1), np.float32)], 1)
-    return ids, label
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
-
-
-def bind_op(sym, names, inputs):
-    return sym.bind(mx.cpu(), {n: mx.nd.array(a) for n, a in
-                               zip(names, inputs)},
-                    args_grad={n: mx.nd.zeros(a.shape) for n, a in
-                               zip(names, inputs)})
+seeded_params = mc.seeded_params
+# two pad positions at the end of row 0
+seeded_tokens = functools.partial(mc.seeded_tokens, batch=B, seq_len=T,
+                                  vocab=TINY["vocab_size"], pads=2)
 
 
 # --- RingAttention(diffusion_block=) -------------------------------------------
@@ -439,23 +399,6 @@ def test_the_shares_add_up_to_the_uncut_layer(ref):
 
 # --- the whole model -------------------------------------------------------------------
 
-def bound(sym, params, ids, label):
-    exe = sym.simple_bind(mx.cpu(), data=ids.shape, softmax_label=label.shape)
-    for n, a in params.items():
-        exe.arg_dict[n][:] = a
-    exe.arg_dict["data"][:] = ids
-    exe.arg_dict["softmax_label"][:] = label
-    return exe
-
-
-def program_first_step(sym, params, ids, label):
-    """(probabilities, {name: gradient / rows}) of one forward/backward."""
-    exe = bound(sym, params, ids, label)
-    prob = exe.forward(is_train=True)[0].asnumpy()
-    exe.backward()
-    return prob, {n: exe.grad_dict[n].asnumpy() / ids.size for n in params}
-
-
 @pytest.fixture(scope="module")
 def first_step(ref):
     """The tiny model's first step, program and reference."""
@@ -465,7 +408,7 @@ def first_step(ref):
     sym = tiny_sym_gen()(T)[0]
     ids, label = seeded_tokens()
     params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    prob, grads = program_first_step(sym, params, ids, label)
+    prob, grads = mc.program_first_step(sym, params, ids, label)
     leaves = {n: jnp.asarray(a) for n, a in params.items()}
     probe, trained, want = ref.value_and_grads(
         jax, TINY, leaves, jnp.asarray(ids), jnp.asarray(label))
@@ -677,10 +620,10 @@ def test_estimate_flops_and_the_parameter_count_at_published_widths():
 
     from mxnet_tpu.models import recipe
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
+    with open(os.path.join(mc.ROOT, "benchmark", "configs",
                            NAME + ".json")) as f:
         cfg = json.load(f)
-    builder = _load("configs")
+    builder = mc.load("configs", NAME)
     t = 8192
     sym = builder.sym_gen(cfg, mx)[0](t)[0]
     assert len(sym.list_arguments()) - 2 == 4 * 12 + 3

@@ -15,16 +15,19 @@ the renormalisation, the window or the embedding scale moves the gradient
 norm by more than they allow.
 """
 
-import importlib.util
+import functools
+import json
 import os
 
+import model_cases as mc
 import numpy as np
 import pytest
+from model_cases import bind_op, misses, rel
 
 import mxnet_tpu as mx
 from mxnet_tpu.base import MXNetError
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NAME = "trinity-mini"
 SLIDING, FULL = "sliding_attention", "full_attention"
 TINY = dict(vocab_size=64, hidden_size=32, num_dense_layers=1,
             layer_types=[SLIDING, SLIDING, FULL, SLIDING],
@@ -37,58 +40,27 @@ TINY = dict(vocab_size=64, hidden_size=32, num_dense_layers=1,
 B, T = 2, 16
 
 
-def _load(kind, name="trinity-mini"):
-    path = os.path.join(ROOT, "benchmark", kind, name + ".py")
-    spec = importlib.util.spec_from_file_location(f"trinity_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("reference")
+    return mc.load("reference", NAME)
 
 
 def tiny_sym_gen(dtype="float32", **over):
     cfg = dict(TINY, compute_dtype=dtype, **over)
-    return _load("configs").sym_gen(cfg, mx)[0]
+    return mc.load("configs", NAME).sym_gen(cfg, mx)[0]
 
 
-def seeded_params(sym, seed=0, **shapes):
-    """normal(0, 0.3) weights (at 32 features that is what makes every
-    branch of the tiny model matter), gains normal(1, 0.1) and a selection
-    bias normal(0, 0.2): one that changes which experts are chosen."""
-    rs = np.random.RandomState(seed)
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    out = {}
-    for name, shape in zip(sym.list_arguments(), arg_shapes):
-        if name in shapes:
-            continue
-        gain = name.endswith("_gamma")
-        scale = 0.2 if name.endswith("_expert_bias") else 0.1 if gain else 0.3
-        out[name] = (rs.randn(*shape) * scale
-                     + (1.0 if gain else 0.0)).astype(np.float32)
-    return out
+def scale_rule(name):
+    """The common rule, and a selection bias normal(0, 0.2): one that
+    changes which experts are chosen."""
+    if name.endswith("_expert_bias"):
+        return 0.2, 0.0
+    return mc.gains_and_weights(name)
 
 
-def seeded_tokens(seed=1, batch=B, seq_len=T, vocab=TINY["vocab_size"]):
-    rs = np.random.RandomState(seed)
-    ids = rs.randint(1, vocab, size=(batch, seq_len)).astype(np.float32)
-    label = np.concatenate([ids[:, 1:], np.zeros((batch, 1), np.float32)], 1)
-    return ids, label
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
-
-
-def bind_op(sym, names, inputs):
-    return sym.bind(mx.cpu(), {n: mx.nd.array(a) for n, a in
-                               zip(names, inputs)},
-                    args_grad={n: mx.nd.zeros(a.shape) for n, a in
-                               zip(names, inputs)})
+seeded_params = functools.partial(mc.seeded_params, rule=scale_rule)
+seeded_tokens = functools.partial(mc.seeded_tokens, batch=B, seq_len=T,
+                                  vocab=TINY["vocab_size"])
 
 
 # --- attention: a window over grouped key/value heads ------------------------
@@ -375,23 +347,6 @@ def test_moe_refuses_what_it_does_not_define():
 
 # --- the whole model ---------------------------------------------------------
 
-def bound(sym, params, ids, label):
-    exe = sym.simple_bind(mx.cpu(), data=ids.shape, softmax_label=label.shape)
-    for n, a in params.items():
-        exe.arg_dict[n][:] = a
-    exe.arg_dict["data"][:] = ids
-    exe.arg_dict["softmax_label"][:] = label
-    return exe
-
-
-def program_first_step(sym, params, ids, label):
-    """(probabilities, {name: gradient / rows}) of one forward/backward."""
-    exe = bound(sym, params, ids, label)
-    prob = exe.forward(is_train=True)[0].asnumpy()
-    exe.backward()
-    return prob, {n: exe.grad_dict[n].asnumpy() / ids.size for n in params}
-
-
 def test_model_logits_and_every_gradient_match_the_reference(ref):
     import jax
     import jax.numpy as jnp
@@ -399,7 +354,7 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
     sym = tiny_sym_gen()(T)[0]
     ids, label = seeded_tokens()
     params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    prob, grads = program_first_step(sym, params, ids, label)
+    prob, grads = mc.program_first_step(sym, params, ids, label)
     leaves = {n: jnp.asarray(a) for n, a in params.items()}
     scores = ref.logits(jax, TINY, leaves, jnp.asarray(ids))
     assert rel(prob, jax.nn.softmax(scores, -1)) < ref.F32_TENSOR_TOLERANCE
@@ -408,8 +363,8 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
     assert set(want) == set(grads)
     # the reference's layer-at-a-time chain is autodiff of its whole loss
     with jax.default_matmul_precision("highest"):
-        whole = jax.grad(lambda p: ref.losses(
-            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0])(leaves)
+        whole = jax.jit(jax.grad(lambda p: ref.losses(
+            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0]))(leaves)
     for n in sorted(grads):
         assert rel(want[n], whole[n]) < 1e-5 or not np.asarray(
             whole[n]).any(), n
@@ -418,23 +373,6 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
             assert not grads[n].any() and not np.asarray(want[n]).any()
         else:
             assert rel(grads[n], want[n]) < ref.F32_TENSOR_TOLERANCE, n
-
-
-def first_step_of_program(sym, params, ids, label):
-    """What the benchmark's driver reads: loss from the probabilities,
-    gradient norm over rows."""
-    prob, grads = program_first_step(sym, params, ids, label)
-    lab = label.reshape(-1).astype(int)
-    picked = prob[np.arange(lab.size), lab]
-    return {"loss": float(-np.mean(np.log(np.maximum(picked, 1e-30)))),
-            "grad_norm": float(np.sqrt(sum(
-                np.sum(np.square(g, dtype=np.float64))
-                for g in grads.values())))}
-
-
-def misses(got, want, tolerances):
-    return [k for k, tol in tolerances.items()
-            if abs(got[k] - want[k]) / abs(want[k]) > tol]
 
 
 def _no_output_gate(ref, mp):
@@ -493,44 +431,40 @@ def _kv_heads_not_grouped(ref, mp):
         q, jnp.roll(k, 1, axis=1), v, window))
 
 
+@pytest.fixture(scope="module")
+def first_step(ref):
+    """Four seeded rows through the float32 program and the plain
+    reference, once for the tests of the tolerances."""
+    sym = tiny_sym_gen()(T)[0]
+    ids, label = seeded_tokens(batch=4)
+    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
+    return mc.first_step_case(ref, TINY, sym, params, ids, label)
+
+
 @pytest.mark.parametrize("mutation", [
     _no_output_gate, _no_post_norms, _no_selection_bias,
     _no_route_scale, _no_renormalisation, _no_window, _rotary_on_every_layer,
     _no_embedding_scale, _no_shared_expert, _kv_heads_not_grouped])
-def test_tolerances_fail_a_wrong_layer(ref, monkeypatch, mutation):
+def test_tolerances_fail_a_wrong_layer(ref, monkeypatch, first_step,
+                                       mutation):
     """Against a reference that leaves a piece out, the program misses even
     the bfloat16 trunk's TOLERANCES; against the plain one it is inside the
     float32 ones."""
-    import jax
-    import jax.numpy as jnp
-
-    sym = tiny_sym_gen()(T)[0]
-    ids, label = seeded_tokens(batch=4)
-    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(sym, params, ids, label)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jax, TINY, leaves, jnp.asarray(ids), jnp.asarray(label))
-    assert not misses(got, ref.first_step(*args), ref.F32_TOLERANCES)
+    got = first_step.got
+    assert not misses(got, first_step.want, ref.F32_TOLERANCES)
     mutation(ref, monkeypatch)
-    assert misses(got, ref.first_step(*args), ref.TOLERANCES)
+    assert misses(got, ref.first_step(*first_step.args), ref.TOLERANCES)
 
 
-def test_float32_tolerances_fail_a_bfloat16_trunk(ref):
+def test_float32_tolerances_fail_a_bfloat16_trunk(ref, first_step):
     """The bfloat16 trunk is outside the float32 tolerances. (That it is
     inside TOLERANCES is a statement about published widths, checked on
     the chip by the benchmark's driver.)"""
-    import jax
-    import jax.numpy as jnp
-
-    ids, label = seeded_tokens(batch=4)
-    sym32 = tiny_sym_gen()(T)[0]
-    params = seeded_params(sym32, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(tiny_sym_gen("bfloat16")(T)[0], params, ids,
-                                label)
-    want = ref.first_step(jax, TINY, {n: jnp.asarray(a) for n, a in
-                                      params.items()},
-                          jnp.asarray(ids), jnp.asarray(label))
-    assert misses(got, want, ref.F32_TOLERANCES) == ["loss", "grad_norm"]
+    got = mc.first_step_of_program(
+        tiny_sym_gen("bfloat16")(T)[0], first_step.params, first_step.ids,
+        first_step.label)
+    assert misses(got, first_step.want, ref.F32_TOLERANCES) == [
+        "loss", "grad_norm"]
 
 
 def test_three_adam_steps_through_fit_follow_the_reference(ref):
@@ -633,14 +567,12 @@ def test_checkpoint_round_trip_and_counters(tmp_path):
 def test_estimate_flops_is_near_the_builders_count():
     """``models.recipe.estimate_flops`` on the published configuration
     against the builder's count of what this chip computes."""
-    import json
-
     from mxnet_tpu.models import recipe
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "trinity-mini.json")) as f:
+    with open(os.path.join(mc.ROOT, "benchmark", "configs",
+                           NAME + ".json")) as f:
         cfg = json.load(f)
-    builder = _load("configs")
+    builder = mc.load("configs", NAME)
     sym = builder.sym_gen(cfg, mx)[0](4096)[0]
     assert len(sym.list_arguments()) - 2 == 5 * 11 + 3 + 4 * 8 + 3
     arg_shapes, _, _ = sym.infer_shape(data=(1, 4096),

@@ -1,9 +1,9 @@
 """Whole-zoo fast path (ISSUE 13): every BASELINE workload through the
 modern stack.
 
-Pins, per workload, the two invariants the scoreboard advertises —
-counter-verified on the framework's own telemetry, mirroring
-tests/test_async_pipeline.py:
+Pins, for each of the six BASELINE workloads (one parametrised test), the
+two invariants of the fast path — counter-verified on the framework's own
+telemetry, mirroring tests/test_async_pipeline.py:
 
 * ZERO steady-state compiles: once a workload's programs are warm,
   ``executor.jit_compile`` (AOT forward/train-step builds) and
@@ -41,6 +41,11 @@ def _sync_counts():
     return {name: tm.counter(name).value for name in _SYNC_COUNTERS}
 
 
+def _outputs_of(boundary):
+    return lambda: [np.asarray(o._data, np.float32)
+                    for o in boundary.outputs]
+
+
 def _compiles():
     return (tm.counter("executor.jit_compile").value,
             tm.counter("executor.fused_plan_compile").value)
@@ -70,7 +75,7 @@ def _lstm_fixture(bs=4, hidden=16, vocab=50, buckets=(6, 10), k=2):
     return mod, chunks
 
 
-def test_bucketed_lstm_zero_steady_compiles_zero_syncs():
+def _steady_lstm(monkeypatch):
     """After one warmup epoch over the bucket mix, a steady epoch of
     grouped K-batch windows issues no compiles and no per-batch host
     syncs — switch_bucket is a pure cache pick."""
@@ -78,25 +83,20 @@ def test_bucketed_lstm_zero_steady_compiles_zero_syncs():
     tm.reset()
     for ch in chunks:
         mod.train_window(None, batches=ch, publish_grads=False).wait()
-    jit_warm, plan_warm = _compiles()
     # the warmup epoch proves the compile counter fires (one fused plan
     # per (bucket, group size) pair) — without this the steady assert
-    # below could pass vacuously with a dead counter
-    assert plan_warm > 0
+    # could pass vacuously with a dead counter
+    assert _compiles()[1] > 0
     windows_warm = tm.counter("bucketing.window").value
     assert windows_warm > 0
 
     tm.reset()
     for ch in chunks:
-        mod.train_window(None, batches=ch, publish_grads=False).wait()
-    jit_steady, plan_steady = _compiles()
-    assert (jit_steady, plan_steady) == (0, 0), (
-        f"steady-state epoch recompiled: jit={jit_steady} "
-        f"fused_plan={plan_steady}")
+        last = mod.train_window(None, batches=ch, publish_grads=False)
+        last.wait()
     assert tm.counter("executor.fused_plan_hit").value == windows_warm
     assert tm.counter("bucketing.window").value == windows_warm
-    assert _sync_counts() == {name: 0 for name in _SYNC_COUNTERS}, (
-        _sync_counts())
+    return 0, _outputs_of(last)
 
 
 # ---------------------------------------------------------------------------
@@ -162,23 +162,21 @@ def test_dcgan_fused_step_matches_reference_loop():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
-def test_dcgan_steady_windows_zero_compiles_zero_syncs():
+def _steady_dcgan(monkeypatch):
     gan = _gan_fixture()
     real = mx.nd.array(
         np.random.RandomState(5).rand(_GAN_BS, 3, 64, 64).astype(np.float32))
     tm.reset()
     gan.train_window(real, 2).wait()
-    _, plan_warm = _compiles()
-    assert plan_warm > 0  # the plan-compile counter fires on warmup
+    assert _compiles()[1] > 0  # the plan-compile counter fires on warmup
 
     tm.reset()
     for _ in range(3):
-        gan.train_window(real, 2).wait()
-    assert _compiles() == (0, 0)
+        last = gan.train_window(real, 2)
+        last.wait()
     assert tm.counter("executor.fused_plan_hit").value == 3
     assert tm.counter("gan.window").value == 3
-    assert _sync_counts() == {name: 0 for name in _SYNC_COUNTERS}, (
-        _sync_counts())
+    return 0, _outputs_of(last)
 
 
 # ---------------------------------------------------------------------------
@@ -190,8 +188,7 @@ def _mini_ssd_train_sym(num_classes=2):
     Group, verbatim from models/ssd.py's get_symbol_train tail) on a
     3-conv trunk: the fit-window invariants exercise the SAME detection
     path — in-graph target assignment, hard negative mining, the Group of
-    heterogeneous losses — without the VGG16 compile bill, which the
-    bench suite smoke already pays for the real SSD-VGG16."""
+    heterogeneous losses — without the VGG16 compile bill."""
     s = mx.sym
     body = s.Variable("data")
     feats = []
@@ -221,14 +218,51 @@ def _mini_ssd_train_sym(num_classes=2):
     return s.Group([cls_prob, loc_loss, cls_label])
 
 
-def test_ssd_fit_window_branch_no_steady_syncs(monkeypatch):
-    """The multi-loss SSD Group rides fit's fused-window pipeline: the
-    steady epoch (after the compile epoch is discarded) must issue zero
-    compiles and zero per-batch host syncs, with the device-resident Loss
-    metric draining once per epoch."""
+def _steady_fit(monkeypatch, net, data, label, label_name="softmax_label",
+                metric="acc", **optimizer_params):
+    """``Module.fit`` over fused 2-step windows, two in flight, for two
+    epochs of four batches: the compile epoch is discarded, and the device
+    metric drains once, at the steady epoch's end."""
     monkeypatch.setenv("MXNET_TRAIN_WINDOW", "2")
     monkeypatch.setenv("MXNET_DISPATCH_DEPTH", "2")
     monkeypatch.setenv("MXNET_DEVICE_PREFETCH", "1")
+    it = mx.io.NDArrayIter({"data": data}, {label_name: label},
+                           batch_size=len(data) // 4,
+                           last_batch_handle="discard")
+    mod = mx.mod.Module(net, data_names=("data",), label_names=(label_name,),
+                        context=mx.cpu())
+    warm = []
+
+    def epoch_cb(epoch, sym=None, arg=None, aux=None):
+        if epoch == 0:
+            warm.append(_compiles())
+            tm.reset()
+
+    metric = mx.metric.create(metric)
+    tm.reset()
+    mod.fit(it, eval_metric=metric, optimizer="sgd",
+            optimizer_params=dict(momentum=0.9, **optimizer_params),
+            initializer=mx.init.Xavier(), num_epoch=2,
+            epoch_end_callback=epoch_cb)
+    assert warm[0][1] > 0  # the plan-compile counter fired while warming
+    return 1, lambda: [metric.get()[1]]
+
+
+def _steady_classifier(build, shape, classes, **build_kw):
+    def steady(monkeypatch):
+        rng = np.random.RandomState(0)
+        n = 4 * shape[0]
+        return _steady_fit(
+            monkeypatch, build(num_classes=classes, **build_kw),
+            rng.uniform(-1, 1, (n,) + shape[1:]).astype(np.float32),
+            rng.randint(0, classes, (n,)).astype(np.float32),
+            learning_rate=0.01)
+    return steady
+
+
+def _steady_ssd(monkeypatch):
+    """The multi-loss SSD Group rides fit's fused-window pipeline, with the
+    device-resident Loss metric."""
     bs, size, max_obj = 2, 32, 3
     rng = np.random.RandomState(0)
     n = bs * 4
@@ -237,28 +271,35 @@ def test_ssd_fit_window_branch_no_steady_syncs(monkeypatch):
     for i in range(n):
         x1, y1 = rng.uniform(0, 0.4, 2)
         label[i, 0] = [rng.randint(0, 2), x1, y1, x1 + 0.4, y1 + 0.4]
-    it = mx.io.NDArrayIter({"data": data}, {"label": label}, batch_size=bs,
-                           last_batch_handle="discard")
-    net = _mini_ssd_train_sym(num_classes=2)
-    mod = mx.mod.Module(net, data_names=("data",), label_names=("label",),
-                        context=mx.cpu())
+    return _steady_fit(monkeypatch, _mini_ssd_train_sym(num_classes=2), data,
+                       label, label_name="label",
+                       metric=mx.metric.Loss(name="ssd_loss"),
+                       learning_rate=0.002)
 
-    def epoch_cb(epoch, sym=None, arg=None, aux=None):
-        if epoch == 0:
-            tm.reset()  # discard the compile epoch, as bench fit does
 
-    metric = mx.metric.Loss(name="ssd_loss")
-    mod.fit(it, eval_metric=metric, optimizer="sgd",
-            optimizer_params={"learning_rate": 0.002, "momentum": 0.9},
-            initializer=mx.init.Xavier(), num_epoch=2,
-            epoch_end_callback=epoch_cb)
-    assert _compiles() == (0, 0), "steady SSD epoch recompiled"
-    counts = _sync_counts()
-    assert counts["ndarray.asnumpy"] == 0
-    assert counts["ndarray.wait_to_read"] == 0
-    assert counts["metric.numpy_fallback"] == 0
-    assert counts["metric.drain_sync"] == 1  # the per-epoch get only
-    assert np.isfinite(metric.get()[1])
+_STEADY = {
+    "mlp": _steady_classifier(models.mlp, (8, 784), 10),
+    "lenet": _steady_classifier(models.lenet, (8, 1, 28, 28), 10),
+    "resnet-50": _steady_classifier(models.resnet, (2, 3, 64, 64), 10,
+                                    num_layers=50, image_shape="3,64,64"),
+    "lstm-ptb": _steady_lstm,
+    "ssd": _steady_ssd,
+    "dcgan": _steady_dcgan,
+}
+
+
+@pytest.mark.parametrize("workload", sorted(_STEADY))
+def test_steady_state_compiles_nothing_and_syncs_nothing(workload,
+                                                         monkeypatch):
+    """Every BASELINE workload, once its programs are warm: no compile, no
+    per-batch host sync (a ``fit`` drains its device metric once an
+    epoch), finite outputs."""
+    drains, outputs = _STEADY[workload](monkeypatch)
+    assert _compiles() == (0, 0), f"steady {workload} recompiled"
+    assert _sync_counts() == dict(dict.fromkeys(_SYNC_COUNTERS, 0),
+                                  **{"metric.drain_sync": drains})
+    for out in outputs():
+        assert np.all(np.isfinite(out)), workload
 
 
 # ---------------------------------------------------------------------------
@@ -344,6 +385,13 @@ def test_flops_estimator_grouped_depthwise():
                         image_shape="3,224,224")
     g = recipe.estimate_flops(rx, data=(1, 3, 224, 224))
     assert g == pytest.approx(4.2305e9, rel=0.02), g
+
+
+def test_score_symbol_list_is_shared():
+    """examples/benchmark_score.py sweeps the registry
+    (models.SCORE_SYMBOLS), not a list of its own."""
+    with open(os.path.join(_ROOT, "examples", "benchmark_score.py")) as f:
+        assert "SCORE_SYMBOLS" in f.read()
 
 
 def test_zoo_registry_covers_published_table():

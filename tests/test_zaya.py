@@ -19,15 +19,17 @@ the routing weight moves the loss or the gradient norm by more than they
 allow.
 """
 
-import importlib.util
+import functools
+import json
 import os
 
+import model_cases as mc
 import numpy as np
 import pytest
+from model_cases import misses, rel
 
 import mxnet_tpu as mx
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "zaya1-8b"
 TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=3,
             num_attention_heads=4, num_key_value_heads=2, head_dim=16,
@@ -40,54 +42,30 @@ TINY = dict(vocab_size=64, hidden_size=64, num_hidden_layers=3,
 B, T = 2, 16
 
 
-def _load(kind, name=NAME):
-    path = os.path.join(ROOT, "benchmark", kind, name + ".py")
-    spec = importlib.util.spec_from_file_location(f"zaya1_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("reference")
+    return mc.load("reference", NAME)
 
 
 def tiny_sym_gen(dtype="float32", **over):
     cfg = dict(TINY, compute_dtype=dtype, **over)
-    return _load("configs").sym_gen(cfg, mx)[0]
+    return mc.load("configs", NAME).sym_gen(cfg, mx)[0]
 
 
-def seeded_params(sym, seed=3, **shapes):
-    """normal(0, 0.3) weights, biases and residual betas (at 64 features
-    that is what makes every branch of the tiny model matter); norm gains,
-    residual scales and temperatures normal(1, 0.1); the carry's gamma
-    normal(0.5, 0.1). Seed 3: one whose router sends tokens to the held
-    experts in every layer (a random MLP router at 16 features can send a
-    layer's every token elsewhere, and the layer then trains nothing)."""
-    rs = np.random.RandomState(seed)
-    arg_shapes, _, _ = sym.infer_shape(**shapes)
-    out = {}
-    for name, shape in zip(sym.list_arguments(), arg_shapes):
-        if name in shapes:
-            continue
-        gain = name.endswith("_gamma")
-        mean = 0.5 if name.endswith("_carry_gamma") else 1.0 if gain else 0.0
-        out[name] = (rs.randn(*shape) * (0.1 if gain else 0.3)
-                     + mean).astype(np.float32)
-    return out
+def scale_rule(name):
+    """The common rule (biases and residual betas are weights here), with
+    the carry's gamma normal(0.5, 0.1)."""
+    if name.endswith("_carry_gamma"):
+        return 0.1, 0.5
+    return mc.gains_and_weights(name)
 
 
-def seeded_tokens(seed=1, batch=B, seq_len=T, vocab=TINY["vocab_size"]):
-    rs = np.random.RandomState(seed)
-    ids = rs.randint(1, vocab, size=(batch, seq_len)).astype(np.float32)
-    label = np.concatenate([ids[:, 1:], np.zeros((batch, 1), np.float32)], 1)
-    return ids, label
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
+# seed 3: one whose router sends tokens to the held experts in every layer
+# (a random MLP router at 16 features can send a layer's every token
+# elsewhere, and the layer then trains nothing)
+seeded_params = functools.partial(mc.seeded_params, rule=scale_rule, seed=3)
+seeded_tokens = functools.partial(mc.seeded_tokens, batch=B, seq_len=T,
+                                  vocab=TINY["vocab_size"])
 
 
 # --- the share -------------------------------------------------------------------
@@ -134,23 +112,6 @@ def test_the_shares_add_up_to_the_uncut_layer(ref, held):
 
 # --- the whole model -------------------------------------------------------------
 
-def bound(sym, params, ids, label):
-    exe = sym.simple_bind(mx.cpu(), data=ids.shape, softmax_label=label.shape)
-    for n, a in params.items():
-        exe.arg_dict[n][:] = a
-    exe.arg_dict["data"][:] = ids
-    exe.arg_dict["softmax_label"][:] = label
-    return exe
-
-
-def program_first_step(sym, params, ids, label):
-    """(probabilities, {name: gradient / rows}) of one forward/backward."""
-    exe = bound(sym, params, ids, label)
-    prob = exe.forward(is_train=True)[0].asnumpy()
-    exe.backward()
-    return prob, {n: exe.grad_dict[n].asnumpy() / ids.size for n in params}
-
-
 def test_model_logits_and_every_gradient_match_the_reference(ref):
     import jax
     import jax.numpy as jnp
@@ -159,7 +120,7 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
     ids, label = seeded_tokens()
     params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
     assert "pred_weight" not in params and "embed_weight" in params
-    prob, grads = program_first_step(sym, params, ids, label)
+    prob, grads = mc.program_first_step(sym, params, ids, label)
     leaves = {n: jnp.asarray(a) for n, a in params.items()}
     scores = ref.logits(jax, TINY, leaves, jnp.asarray(ids))
     assert rel(prob, jax.nn.softmax(scores, -1)) < ref.F32_TENSOR_TOLERANCE
@@ -168,29 +129,12 @@ def test_model_logits_and_every_gradient_match_the_reference(ref):
     assert set(want) == set(grads)
     # the reference's layer-at-a-time chain is autodiff of its whole loss
     with jax.default_matmul_precision("highest"):
-        whole = jax.grad(lambda p: ref.losses(
-            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0])(leaves)
+        whole = jax.jit(jax.grad(lambda p: ref.losses(
+            jax, TINY, p, jnp.asarray(ids), jnp.asarray(label))[0]))(leaves)
     for n in sorted(grads):
         assert rel(want[n], whole[n]) < 1e-5, n
         assert np.asarray(want[n]).any(), n
         assert rel(grads[n], want[n]) < ref.F32_TENSOR_TOLERANCE, n
-
-
-def first_step_of_program(sym, params, ids, label):
-    """What the benchmark's driver reads: loss from the probabilities,
-    gradient norm over rows."""
-    prob, grads = program_first_step(sym, params, ids, label)
-    lab = label.reshape(-1).astype(int)
-    picked = prob[np.arange(lab.size), lab]
-    return {"loss": float(-np.mean(np.log(np.maximum(picked, 1e-30)))),
-            "grad_norm": float(np.sqrt(sum(
-                np.sum(np.square(g, dtype=np.float64))
-                for g in grads.values())))}
-
-
-def misses(got, want, tolerances):
-    return [k for k, tol in tolerances.items()
-            if abs(got[k] - want[k]) / abs(want[k]) > tol]
 
 
 def _no_depthwise_tap(ref, mp):
@@ -246,63 +190,55 @@ def _no_routing_weight(ref, mp):
         plain(logits, k) > 0).astype(logits.dtype))
 
 
+@pytest.fixture(scope="module")
+def first_step(ref):
+    """Four seeded rows through the float32 program and the plain
+    reference, once for the tests of the tolerances."""
+    sym = tiny_sym_gen()(T)[0]
+    ids, label = seeded_tokens(batch=4)
+    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
+    return mc.first_step_case(ref, TINY, sym, params, ids, label)
+
+
 @pytest.mark.parametrize("mutation", [
     _no_depthwise_tap, _no_grouped_tap, _no_qk_mean, _no_value_shift,
     _no_key_temperature, _whole_head_rotated, _no_carry,
     _one_residual_scale_left_out, _no_routing_weight])
-def test_tolerances_fail_a_wrong_layer(ref, monkeypatch, mutation):
+def test_tolerances_fail_a_wrong_layer(ref, monkeypatch, first_step,
+                                       mutation):
     """Against a reference that leaves a piece out, the program misses even
     the bfloat16 trunk's TOLERANCES; against the plain one it is inside the
     float32 ones."""
-    import jax
-    import jax.numpy as jnp
-
-    sym = tiny_sym_gen()(T)[0]
-    ids, label = seeded_tokens(batch=4)
-    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(sym, params, ids, label)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jax, TINY, leaves, jnp.asarray(ids), jnp.asarray(label))
-    assert not misses(got, ref.first_step(*args), ref.F32_TOLERANCES)
+    got = first_step.got
+    assert not misses(got, first_step.want, ref.F32_TOLERANCES)
     mutation(ref, monkeypatch)
-    assert misses(got, ref.first_step(*args), ref.TOLERANCES)
+    assert misses(got, ref.first_step(*first_step.args), ref.TOLERANCES)
 
 
-def test_float32_tolerances_fail_a_bfloat16_trunk(ref):
+def test_float32_tolerances_fail_a_bfloat16_trunk(ref, first_step):
     """The bfloat16 trunk is outside the float32 tolerances. (That it is
     inside TOLERANCES is a statement about published widths, checked on
     the chip by the benchmark's driver.)"""
-    import jax
-    import jax.numpy as jnp
-
-    ids, label = seeded_tokens(batch=4)
-    sym32 = tiny_sym_gen()(T)[0]
-    params = seeded_params(sym32, data=ids.shape, softmax_label=label.shape)
-    got = first_step_of_program(tiny_sym_gen("bfloat16")(T)[0], params, ids,
-                                label)
-    want = ref.first_step(jax, TINY, {n: jnp.asarray(a) for n, a in
-                                      params.items()},
-                          jnp.asarray(ids), jnp.asarray(label))
-    assert misses(got, want, ref.F32_TOLERANCES) == ["loss", "grad_norm"]
+    got = mc.first_step_of_program(
+        tiny_sym_gen("bfloat16")(T)[0], first_step.params, first_step.ids,
+        first_step.label)
+    assert misses(got, first_step.want, ref.F32_TOLERANCES) == [
+        "loss", "grad_norm"]
 
 
-def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch):
+def test_tolerances_fail_the_reference_in_float8(ref, monkeypatch,
+                                                 first_step):
     """The precision below the bfloat16 the configuration states: this
     reference with float8_e4m3fn weights and projection inputs misses the
     limit the check rests on (PERF.md section 6, PR 44, has the reading at
     published widths)."""
-    import jax
     import jax.numpy as jnp
 
     def f8(x):
         return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
 
-    ids, label = seeded_tokens(batch=4)
-    sym = tiny_sym_gen()(T)[0]
-    params = seeded_params(sym, data=ids.shape, softmax_label=label.shape)
-    leaves = {n: jnp.asarray(a) for n, a in params.items()}
-    args = (jnp.asarray(ids), jnp.asarray(label))
-    want = ref.first_step(jax, TINY, leaves, *args)
+    jax, _, leaves, *args = first_step.args
+    want = first_step.want
     plain = ref.project
     monkeypatch.setattr(ref, "project",
                         lambda x, w, b=None: plain(f8(x), w, b))
@@ -465,13 +401,11 @@ def test_estimate_flops_counts_the_convolutions_and_no_router_twice():
     convolutions (2 taps a channel; 2 x 128 a channel inside a head), the
     router's four products as the graph's ``FullyConnected`` nodes and not
     again inside ``MoE``."""
-    import json
-
     from mxnet_tpu.models import recipe
 
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
+    with open(os.path.join(mc.ROOT, "benchmark", "configs", NAME + ".json")) as f:
         cfg = json.load(f)
-    builder = _load("configs")
+    builder = mc.load("configs", NAME)
     t = 8192
     sym = builder.sym_gen(cfg, mx)[0](t)[0]
     arg_shapes, _, _ = sym.infer_shape(data=(1, t), softmax_label=(1, t))

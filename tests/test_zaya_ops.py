@@ -7,20 +7,9 @@ its plain reference is ``test_zaya.py``.)"""
 
 import numpy as np
 import pytest
+from model_cases import bind_op, rel
 
 import mxnet_tpu as mx
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
-
-
-def bind_op(sym, names, inputs):
-    return sym.bind(mx.cpu(), {n: mx.nd.array(a) for n, a in
-                               zip(names, inputs)},
-                    args_grad={n: mx.nd.zeros(a.shape) for n, a in
-                               zip(names, inputs)})
 
 
 # --- MoE(router="graph") ---------------------------------------------------------

@@ -75,8 +75,8 @@ def main(argv=None):
                          "donated fused train executable is cached")
     ap.add_argument("--window", type=int, default=0, metavar="K",
                     help="with --step, also run a K-step training window in "
-                         "both variants — repeat-batch (bench.py train "
-                         "mode) and stacked-batches (Module.fit's "
+                         "both variants — repeat-batch (train_window(batch, "
+                         "K)) and stacked-batches (Module.fit's "
                          "MXNET_TRAIN_WINDOW loop) — caching both window "
                          "executables")
     ap.add_argument("--optimizer", default="sgd")
@@ -129,12 +129,12 @@ def main(argv=None):
             )
             k = max(1, args.window)
             if k > 1:
-                # both window program variants: repeat-batch (bench.py's
-                # train mode, train_window(batch, K)) AND stacked-batches
+                # both window program variants: repeat-batch
+                # (train_window(batch, K)) AND stacked-batches
                 # (what Module.fit's MXNET_TRAIN_WINDOW loop dispatches —
                 # its data_stacks give the plan a different signature).
-                # publish_grads=False matches the steady-state loops (fit
-                # pipeline + bench): the publish flag is part of the plan
+                # publish_grads=False matches the steady-state fit
+                # pipeline: the publish flag is part of the plan
                 # key AND the cache digest, so warming the publishing
                 # variant would leave the real training loop compiling
                 mod.train_window(batch, k, publish_grads=False)
