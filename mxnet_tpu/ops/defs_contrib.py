@@ -660,7 +660,10 @@ def _ring_attention_counts(ins, outs, params, platform):
     ``batch / 2`` rows: the rule is asked for one copy's rows, the scored
     pairs are those of the two block-cut causal walks (the kernels' visit
     list twice, or ``ring_attention.diffusion_scored_pairs``) and of the
-    noised copy's own blocks, the kept pairs are the exact ``T (T +
+    noised copy's own blocks: where the kernels engage a query block's own
+    tile inside the strict walk's kernels (``T x bq`` a head, most of it
+    masked; ``executor.attention_own_tile_layers`` counts the layer), else
+    the ``jax.numpy`` squares (``T x Bd``); the kept pairs are the exact ``T (T +
     Bd)`` a head and row (``ring_attention.diffusion_kept_pairs``), and the
     trunk rows are the rows x positions of the queries the node is handed,
     both copies (over ``executor.diffusion_noised_rows`` and the layers:
@@ -685,7 +688,7 @@ def _ring_attention_counts(ins, outs, params, platform):
                           ins[3] if top_k > 0 else None, block)
     if block > 0:
         pairs = diffusion_scored_pairs(T, block, block_q_of(batch, heads, T)) \
-            if kernels is None else T * block + 2 * \
+            if kernels is None else T * kernels.bq + 2 * \
             flash_attention.scored_pairs(T, kernels.bq, kernels.bk, True)
     elif kernels is not None:
         pairs = flash_attention.scored_pairs(T, kernels.bq, kernels.bk,
@@ -705,6 +708,7 @@ def _ring_attention_counts(ins, outs, params, platform):
             batch * ins[3].shape[1] * (T * (T + 1) // 2)}
     diffusion = {} if block <= 0 else {
         "executor.attention_diffusion_layers": 1,
+        "executor.attention_own_tile_layers": int(kernels is not None),
         "executor.attention_kept_pairs":
             batch * heads * diffusion_kept_pairs(T, block),
         "executor.diffusion_trunk_rows": trunk_rows}
@@ -751,6 +755,7 @@ register(
                         "executor.attention_selected_pairs",
                         "executor.attention_index_pairs",
                         "executor.attention_diffusion_layers",
+                        "executor.attention_own_tile_layers",
                         "executor.attention_kept_pairs",
                         "executor.diffusion_trunk_rows"),
 )

@@ -60,6 +60,17 @@ float32, the main heads' or the indexer's, reaches HBM either:
   key/value head's keys in VMEM, the kept log-sum-exp; no ``p.v``) and sums
   them in float32 on the tile.
 
+The block-diffusion mask (``diffusion=``; ``ring_attention.diffusion_attention``
+is the specification) is the causal walk with its diagonal cut by blocks of
+positions, q of one copy of a row over k and v of the other. On the strict
+walk (the noised copy on the clean one) the kernels take the noised copy's
+OWN keys and values as two more operands (``own``): a query block's ``bq`` of
+them ride beside it and are one more tile of the block's loop, masked to
+``b(key) == b(row)``, so a noised row's softmax over both copies is formed
+once, in VMEM; backward writes that tile's ``dk`` and ``dv`` as two more
+results, once a grid step. Without the operands the kernels trace to what
+they were.
+
 ``plan`` is the one rule that says whether the kernels engage and with which
 tiles, from what is observable where the op is traced: the platform its
 program is lowered for (the executor's context, ``OpMode.platform``), the one
@@ -305,7 +316,9 @@ def _block_specs(group, bq, T):
     """BlockSpecs over the grid (batch, key/value head, query block):
     ``folded(D)``, a query block of the head's group folded to rows, and
     ``whole(D)``, the head's whole keys or values, each at the width it is
-    asked for; and a row of lanes a query block (log-sum-exp, delta)."""
+    asked for; a row of lanes a query block (log-sum-exp, delta); and
+    ``mine(D)``, the ``bq`` keys or values of the query block's own
+    positions (of the head's (T, D))."""
     pl, _ = _ps._pallas()
 
     def folded(D):
@@ -316,8 +329,13 @@ def _block_specs(group, bq, T):
         return pl.BlockSpec((None, None, T, D),
                             lambda b, h, i, *_: (b, h, 0, 0))
 
-    return folded, whole, pl.BlockSpec((None, None, None, 1, group * bq),
-                                       lambda b, h, i, *_: (b, h, i, 0, 0))
+    def mine(D):
+        return pl.BlockSpec((None, None, bq, D),
+                            lambda b, h, i, *_: (b, h, i, 0))
+
+    return folded, whole, pl.BlockSpec(
+        (None, None, None, 1, group * bq),
+        lambda b, h, i, *_: (b, h, i, 0, 0)), mine
 
 
 def _kept_tile(tile, group=1):
@@ -327,19 +345,43 @@ def _kept_tile(tile, group=1):
                     (1, group)) > 0
 
 
+def _own_block(shape, rows_axis, bq, block):
+    """The mask of the noised copy's own tile: a query block's rows (G x bq
+    on axis ``rows_axis``, position = row mod bq) against the keys of its
+    own ``bq`` positions see each other where they share a block of
+    ``block`` positions, before or after: two positions of one tile share a
+    block iff they differ under its bits alone."""
+    apart = (lax.broadcasted_iota(jnp.int32, shape, rows_axis) & (bq - 1)) \
+        ^ lax.broadcasted_iota(jnp.int32, shape, 1 - rows_axis)
+    return lambda s: jnp.where(apart < block, s, _MASKED)
+
+
+def _check_own(own, diffusion):
+    if own is not None and (diffusion is None or not diffusion[1]):
+        raise ValueError("attention: own keys and values ride the strict "
+                         f"block-diffusion walk alone, not {diffusion}")
+
+
 # --- forward -----------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=(
     "scale", "causal", "window", "bq", "bk", "vmem_limit", "interpret",
     "diffusion"))
-def _fwd(q, k, v, first, end, kept=None, *, scale, causal, window, bq, bk,
-         vmem_limit, interpret, diffusion=None):
+def _fwd(q, k, v, first, end, kept=None, own=None, *, scale, causal, window,
+         bq, bk, vmem_limit, interpret, diffusion=None):
     """(out (B, H, T, Dv) in q's dtype, log-sum-exp (B, H, T) float32).
     ``kept`` (B, T, T) int8, queries by keys: under a selection, what
     ``_select`` wrote: 1 where a query keeps a key. ``diffusion``:
-    ``_for_the_key_blocks``'s; a row that sees no key at all (``strict``,
-    the first block) comes back with a log-sum-exp near ``_MASKED``, which
-    the caller's merge with the row's other keys weighs at 0."""
+    ``_for_the_key_blocks``'s. ``own`` (the strict walk alone): the keys (B,
+    Hkv, T, Dk) and values (B, Hkv, T, Dv) of the queries' OWN copy of the
+    row; a query block's ``bq`` of them ride beside it, and after the walk
+    over k and v the block scores that one tile more under
+    :func:`_own_block`'s mask, into the same running max, sum and
+    accumulator: the rows' softmax is formed once over both copies and the
+    output and log-sum-exp come out joint. Without ``own`` a row that sees
+    no key at all (``strict``, the first block) comes back with a
+    log-sum-exp near ``_MASKED``."""
     pl, pltpu = _ps._pallas()
+    _check_own(own, diffusion)
     B, H, T, D = q.shape
     kv, Dv = k.shape[1], v.shape[-1]
     group, nq = H // kv, T // bq
@@ -347,30 +389,33 @@ def _fwd(q, k, v, first, end, kept=None, *, scale, causal, window, bq, bk,
 
     def kernel(first_ref, end_ref, q_ref, k_ref, v_ref, *refs):
         kept_ref = refs[0] if kept is not None else None
-        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[kept is not None:]
+        own_refs = refs[kept is not None:][:2]      # where ``own``
+        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[-5:]
         i = pl.program_id(2)
         qb = q_ref[...].reshape(rows, D)
         m_ref[...] = jnp.full_like(m_ref, _MASKED)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        def step(j, mask):
-            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
-            s = lax.dot_general(qb, k_ref[at, :], _NT,
+        def tile(keys, values, at, mask):
+            s = lax.dot_general(qb, keys[at, :], _NT,
                                 preferred_element_type=jnp.float32) * scale
             if mask is not None:
                 s = mask(s)
             # m and l are kept across the 128 lanes of a row
             m_prev = m_ref[...]
             m_new = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
-            p = jnp.exp(s - jnp.tile(m_new, (1, bk // _LANES)))
+            p = jnp.exp(s - jnp.tile(m_new, (1, s.shape[1] // _LANES)))
             alpha = jnp.exp(m_prev - m_new)
             l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1)[:, None]
             m_ref[...] = m_new
             acc_ref[...] = jnp.tile(alpha, (1, Dv // _LANES)) * acc_ref[...] \
-                + lax.dot_general(p.astype(v_ref.dtype), v_ref[at, :],
+                + lax.dot_general(p.astype(values.dtype), values[at, :],
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
+
+        def step(j, mask):
+            tile(k_ref, v_ref, pl.ds(pl.multiple_of(j * bk, bk), bk), mask)
 
         def kept_of(j):
             # a tile of positions by keys, the same for every head of the
@@ -383,13 +428,16 @@ def _fwd(q, k, v, first, end, kept=None, *, scale, causal, window, bq, bk,
         _for_the_key_blocks(first_ref[i], end_ref[i], i, bq, bk, 0,
                             (rows, bk), causal, window, step,
                             None if kept is None else kept_of, diffusion)
+        if own is not None:
+            tile(*own_refs, slice(None),
+                 _own_block((rows, bq), 0, bq, diffusion[0]))
         l = l_ref[...]
         o_ref[...] = (acc_ref[...] * jnp.tile(1.0 / l, (1, Dv // _LANES))) \
             .astype(o_ref.dtype).reshape(group, bq, Dv)
         # the rows' log-sum-exp, from a column to a row of lanes
         lse_ref[...] = jnp.transpose(m_ref[...] + jnp.log(l))[:1, :]
 
-    folded, whole, row = _block_specs(group, bq, T)
+    folded, whole, row, mine = _block_specs(group, bq, T)
     out, lse = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((B, kv, group, T, Dv), q.dtype),
@@ -398,7 +446,8 @@ def _fwd(q, k, v, first, end, kept=None, *, scale, causal, window, bq, bk,
             num_scalar_prefetch=2,
             in_specs=[folded(D), whole(D), whole(Dv)] + [pl.BlockSpec(
                 (None, bq, T), lambda b, h, i, *_: (b, i, 0))] * (
-                    kept is not None),
+                    kept is not None)
+            + [mine(D), mine(Dv)] * (own is not None),
             out_specs=[folded(Dv), row],
             grid=(B, kv, nq),
             scratch_shapes=[pltpu.VMEM((rows, _LANES), jnp.float32),
@@ -408,28 +457,33 @@ def _fwd(q, k, v, first, end, kept=None, *, scale, causal, window, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
-        cost_estimate=_cost(q, k, v, bq, bk, causal, window, 1, 1),
+        cost_estimate=_cost(q, k, v, bq, bk, causal, window, 1, 1,
+                            own is not None),
         interpret=interpret,
         name="attention_fwd",
     )(first, end, q.reshape(B, kv, group, T, D), k, v,
-      *(() if kept is None else (kept,)))
+      *(() if kept is None else (kept,)), *(own or ()))
     return out.reshape(B, H, T, Dv), _rows_to_heads(lse, H)
 
 
-def _cost(q, k, v, bq, bk, causal, window, over_keys, over_values):
+def _cost(q, k, v, bq, bk, causal, window, over_keys, over_values,
+          own=False):
     """A kernel's matmuls a scored pair, at their two widths: ``over_keys``
     run at the key's (q.k, and backward dk and dq), ``over_values`` at the
     value's (p.v, and backward d_out.v and dv); the bytes are the tensors
-    of either width, each read or written once a matmul of its width."""
+    of either width, each read or written once a matmul of its width (the
+    ``own`` operands are k's and v's size again). ``own``: a query block
+    scores the ``bq`` keys of its own positions too."""
     pl, _ = _ps._pallas()
     B, H, T, D = q.shape
     Dv = v.shape[-1]
-    pairs = B * H * scored_pairs(T, bq, bk, causal, window)
+    pairs = B * H * (scored_pairs(T, bq, bk, causal, window) + T * bq * own)
     return pl.CostEstimate(
         flops=2 * pairs * (over_keys * D + over_values * Dv),
         transcendentals=pairs,
-        bytes_accessed=(over_keys * (q.size + 2 * k.size) + over_values
-                        * (q.size // D * Dv + 2 * v.size))
+        bytes_accessed=(over_keys * (q.size + 2 * k.size * (1 + own))
+                        + over_values * (q.size // D * Dv
+                                         + 2 * v.size * (1 + own)))
         * q.dtype.itemsize)
 
 
@@ -453,14 +507,21 @@ def _heads_to_rows(x, kv, bq):
 @functools.partial(jax.jit, static_argnames=(
     "scale", "causal", "window", "bq", "bk", "vmem_limit", "interpret",
     "diffusion"))
-def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
-         window, bq, bk, vmem_limit, interpret, diffusion=None):
+def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, own=None, *,
+         scale, causal, window, bq, bk, vmem_limit, interpret,
+         diffusion=None):
     """(dq, dk, dv) in the operands' dtypes. ``kept`` (B, T, T) int8, KEYS
     by queries as this kernel's tiles are: under a selection, what
     ``_index_grads`` wrote. ``diffusion``: ``_for_the_key_blocks``'s; ``out``
-    and ``lse`` are then the rows' over ALL the keys they see (the caller's
-    merge), so the gradients are those of the joint softmax."""
+    and ``lse`` are then the rows' over ALL the keys they see, so the
+    gradients are those of the joint softmax. ``own``: ``_fwd``'s; the
+    query block's own tile adds to its ``dq``, and its ``dk`` and ``dv``
+    come back as two more results, (dq, dk, dv, dk_own, dv_own): a block
+    of ``bq`` own keys is seen by its one query block, so they are written
+    once a grid step from the tile's float32 products and not
+    accumulated."""
     pl, pltpu = _ps._pallas()
+    _check_own(own, diffusion)
     B, H, T, D = q.shape
     kv, Dv = k.shape[1], v.shape[-1]
     group, nq = H // kv, T // bq
@@ -471,8 +532,9 @@ def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
     def kernel(first_ref, end_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
                delta_ref, *refs):
         kept_ref = refs[0] if kept is not None else None
-        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = \
-            refs[kept is not None:]
+        own_refs = refs[kept is not None:][:2]      # where ``own``
+        dq_ref, dk_ref, dv_ref, *own_out, dq_acc, dk_acc, dv_acc = \
+            refs[(kept is not None) + 2 * (own is not None):]
         i = pl.program_id(2)
 
         @pl.when(i == 0)
@@ -485,9 +547,7 @@ def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
         row_lse, row_delta = lse_ref[...], delta_ref[...]    # (1, rows)
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-        def step(j, mask):
-            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
-            kb, vb = k_ref[at, :], v_ref[at, :]
+        def weights(kb, vb, mask):
             # tiles are (keys, rows): dv and dk come out of plain matmuls
             s = lax.dot_general(kb, qb, _NT,
                                 preferred_element_type=jnp.float32) * scale
@@ -497,7 +557,12 @@ def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
             ds = p * (lax.dot_general(vb, gb, _NT,
                                       preferred_element_type=jnp.float32)
                       - row_delta) * scale
-            p, ds = p.astype(qb.dtype), ds.astype(qb.dtype)
+            return p.astype(qb.dtype), ds.astype(qb.dtype)
+
+        def step(j, mask):
+            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            kb, vb = k_ref[at, :], v_ref[at, :]
+            p, ds = weights(kb, vb, mask)
             dv_acc[at, :] += jnp.dot(p, gb,
                                      preferred_element_type=jnp.float32)
             dk_acc[at, :] += jnp.dot(ds, qb,
@@ -515,6 +580,19 @@ def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
         _for_the_key_blocks(first_ref[i], end_ref[i], i, bq, bk, 1,
                             (bk, rows), causal, window, step,
                             None if kept is None else kept_of, diffusion)
+        if own is not None:
+            kb = own_refs[0][...]
+            p, ds = weights(kb, own_refs[1][...],
+                            _own_block((bq, rows), 1, bq, diffusion[0]))
+            dk_own_ref, dv_own_ref = own_out
+            dv_own_ref[...] = jnp.dot(
+                p, gb, preferred_element_type=jnp.float32
+            ).astype(dv_own_ref.dtype)
+            dk_own_ref[...] = jnp.dot(
+                ds, qb, preferred_element_type=jnp.float32
+            ).astype(dk_own_ref.dtype)
+            dq_acc[...] += lax.dot_general(
+                ds, kb, _TN, preferred_element_type=jnp.float32)
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype).reshape(group, bq, D)
 
         @pl.when(i == nq - 1)
@@ -522,18 +600,22 @@ def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
             dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
-    folded, whole, row = _block_specs(group, bq, T)
-    dq, dk, dv = pl.pallas_call(
+    folded, whole, row, mine = _block_specs(group, bq, T)
+    dq, *rest = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((B, kv, group, T, D), q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype))
+        + (() if own is None else tuple(
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in own)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             in_specs=[folded(D), whole(D), whole(Dv), folded(Dv), row, row]
             + [pl.BlockSpec((None, T, bq), lambda b, h, i, *_: (b, 0, i))] * (
-                kept is not None),
-            out_specs=[folded(D), whole(D), whole(Dv)],
+                kept is not None)
+            + [mine(D), mine(Dv)] * (own is not None),
+            out_specs=[folded(D), whole(D), whole(Dv)]
+            + [mine(D), mine(Dv)] * (own is not None),
             grid=(B, kv, nq),
             scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
                             pltpu.VMEM((T, D), jnp.float32),
@@ -542,13 +624,15 @@ def _bwd(q, k, v, out, lse, d_out, first, end, kept=None, *, scale, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit),
-        cost_estimate=_cost(q, k, v, bq, bk, causal, window, 3, 2),
+        cost_estimate=_cost(q, k, v, bq, bk, causal, window, 3, 2,
+                            own is not None),
         interpret=interpret,
         name="attention_bwd",
     )(first, end, q.reshape(B, kv, group, T, D), k, v,
       d_out.reshape(B, kv, group, T, Dv), _heads_to_rows(lse, kv, bq),
-      _heads_to_rows(delta, kv, bq), *(() if kept is None else (kept,)))
-    return dq.reshape(B, H, T, D), dk, dv
+      _heads_to_rows(delta, kv, bq), *(() if kept is None else (kept,)),
+      *(own or ()))
+    return (dq.reshape(B, H, T, D), *rest)
 
 
 # --- a selection: the threshold of a row, and the indexer's gradient -----------
@@ -867,28 +951,39 @@ def _static(plan, scale, causal, window, interpret, diffusion=None):
                 **({} if diffusion is None else {"diffusion": diffusion}))
 
 
+def _optional(kept, own):
+    """The kernels' trailing operands, each only where it is set (the same
+    reason)."""
+    if own is not None:
+        return kept, tuple(own)
+    return () if kept is None else (kept,)
+
+
 def attention(q, k, v, plan, scale, causal, window=0, interpret=False,
-              kept=None, diffusion=None):
+              kept=None, diffusion=None, own=None):
     """(out, log-sum-exp): the forward kernel at ``plan``'s tiles; under a
     selection over ``select``'s ``kept`` pairs; ``diffusion`` = (block,
     strict): the causal walk with its diagonal cut by blocks (q may be
-    another copy of the row than k and v)."""
+    another copy of the row than k and v); ``own`` = (keys, values) of q's
+    own copy, under ``strict``: each query also sees its own block of
+    them, in the same softmax."""
     first, end = visits(q.shape[2], plan.bq, plan.bk, causal, window)
     return _ps._kernel(
         _fwd, (q, k, v, jnp.asarray(first), jnp.asarray(end))
-        + (() if kept is None else (kept,)),
+        + _optional(kept, own),
         **_static(plan, scale, causal, window, interpret, diffusion))
 
 
 def attention_grads(q, k, v, out, lse, d_out, plan, scale, causal, window=0,
-                    interpret=False, kept=None, diffusion=None):
+                    interpret=False, kept=None, diffusion=None, own=None):
     """(dq, dk, dv): the backward kernel, from the forward's residuals;
     under a selection over ``index_grads``'s ``kept`` pairs; ``diffusion``
-    as :func:`attention`'s."""
+    and ``own`` as :func:`attention`'s; with ``own`` (dq, dk, dv, dk_own,
+    dv_own)."""
     first, end = visits(q.shape[2], plan.bq, plan.bk, causal, window)
     return _ps._kernel(
         _bwd, (q, k, v, out, lse, d_out.astype(q.dtype), jnp.asarray(first),
-               jnp.asarray(end)) + (() if kept is None else (kept,)),
+               jnp.asarray(end)) + _optional(kept, own),
         **_static(plan, scale, causal, window, interpret, diffusion))
 
 

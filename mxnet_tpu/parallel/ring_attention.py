@@ -43,9 +43,10 @@ Block diffusion (:func:`diffusion_attention`; Arriola et al. 2025,
 arXiv:2503.09573): the batch holds two copies of every row, a noised one and
 a clean one; attention is bidirectional inside a block of positions and
 causal across blocks, and the noised copy reads its own block and the clean
-copy's earlier ones. Three parts, each a walk that visits no tile the mask
-empties, joined by their log-sum-exp. One device only; the ring refuses it
-by name.
+copy's earlier ones. Two causal walks cut by block, which visit no tile the
+mask empties, and the noised copy's own blocks: one more tile of the strict
+walk's kernels where they engage, else little ``jax.numpy`` squares joined
+to it by their log-sum-exp. One device only; the ring refuses it by name.
 """
 
 from __future__ import annotations
@@ -491,16 +492,22 @@ def diffusion_attention(q, k, v, scale, block, block_q=BLOCK_Q, kernels=None,
 
     Three parts, none of which scores a tile the mask empties: the clean
     copy on itself and the noised copy on the clean one are causal walks
-    whose diagonal is cut by block (:func:`diffusion_plan`; ``kernels``, a
-    ``flash_attention.Plan`` of the rule :func:`kernel_plan`: the fused
-    kernels with that in-tile mask, q of one copy over k and v of the
-    other; None: ``jax.numpy`` blocks of ``block_q`` queries, the
-    specification); the noised copy on its own block is T / block little
-    squares a head in ``jax.numpy``, ``block / T`` of the pairs. The two
-    parts of a noised row meet by their log-sum-exp. Backward hands every
-    part the rows' JOINED output and log-sum-exp, so each part's gradients
-    are exact under the joint softmax; the clean copy's ``dk`` and ``dv``
-    are the sum over both copies' queries. Kept for backward beside the
+    whose diagonal is cut by block (:func:`diffusion_plan`), and the noised
+    copy on its own block. ``kernels`` None: ``jax.numpy`` blocks of
+    ``block_q`` queries, the specification; the own blocks are T / block
+    little squares a head, ``block / T`` of the pairs, and the two parts of
+    a noised row meet by their log-sum-exp; backward hands every part the
+    rows' JOINED output and log-sum-exp, so each part's gradients are exact
+    under the joint softmax. ``kernels`` (a ``flash_attention.Plan`` of the
+    rule :func:`kernel_plan`): the fused kernels with that in-tile mask, q
+    of one copy over k and v of the other, and the strict walk's take the
+    noised copy's own keys and values beside each query block and score
+    them as one more masked tile of the block (``flash_attention._fwd``,
+    ``own=``): a noised row's softmax is formed once, jointly, inside the
+    kernel, backward's ``dq`` gathers from both copies there and the noised
+    copy's ``dk`` and ``dv`` are the kernel's; no square and no join is
+    traced. Either way the clean copy's ``dk`` and ``dv`` are the sum over
+    both copies' queries. Kept for backward beside the
     operands: the output and the log-sum-exp (``registry.keep``)."""
     return _diffusion_fwd(q, k, v, scale, block, block_q, kernels,
                           interpret)[0]
@@ -524,7 +531,10 @@ def _check_diffusion(q, k, v, block):
 
 def _diffusion_walks(q, k, v, scale, block, block_q, kernels, interpret):
     """((out, lse) of the clean copy on itself, (out, lse) of the noised
-    copy on the clean one), each (B, H, T, Dv) and (B, H, T) float32."""
+    copy), each (B, H, T, Dv) and (B, H, T) float32. The noised copy's are
+    over the clean keys alone under the ``jax.numpy`` blocks, and over
+    everything it sees under ``kernels`` (its own blocks are one more tile
+    of the strict walk's kernel)."""
     half = q.shape[0] // 2
     T = q.shape[2]
     got = []
@@ -538,7 +548,8 @@ def _diffusion_walks(q, k, v, scale, block, block_q, kernels, interpret):
 
             got.append(flash_attention.attention(
                 rows, k[half:], v[half:], kernels, scale, True, 0, interpret,
-                diffusion=(block, strict)))
+                diffusion=(block, strict),
+                own=(k[:half], v[:half]) if strict else None))
     return got
 
 
@@ -548,18 +559,21 @@ def _diffusion_fwd(q, k, v, scale, block, block_q, kernels, interpret):
     _check_diffusion(q, k, v, block)
     half = q.shape[0] // 2
     f32 = jnp.float32
-    (clean, clean_lse), (early, early_lse) = _diffusion_walks(
+    (clean, clean_lse), (noised, lse) = _diffusion_walks(
         q, k, v, scale, block, block_q, kernels, interpret)
-    with jax.named_scope("attention.own_block"):
-        s, _, _ = _own_scores(q[:half], k[:half], scale, block)
-        lse = jnp.logaddexp(early_lse, jax.nn.logsumexp(s, axis=-1).reshape(
-            early_lse.shape))
-        p = jnp.exp(s - _in_blocks(lse, s))
-        vb = v[:half].reshape(s.shape[:2] + s.shape[3:5] + v.shape[-1:])
-        own = jnp.einsum("bhgnqk,bhnkd->bhgnqd", p.astype(v.dtype), vb,
-                         precision=matmul_precision(v.dtype),
-                         preferred_element_type=f32).reshape(early.shape)
-        noised = own + early.astype(f32) * jnp.exp(early_lse - lse)[..., None]
+    if kernels is None:
+        early, early_lse = noised, lse
+        with jax.named_scope("attention.own_block"):
+            s, _, _ = _own_scores(q[:half], k[:half], scale, block)
+            lse = jnp.logaddexp(early_lse, jax.nn.logsumexp(
+                s, axis=-1).reshape(early_lse.shape))
+            p = jnp.exp(s - _in_blocks(lse, s))
+            vb = v[:half].reshape(s.shape[:2] + s.shape[3:5] + v.shape[-1:])
+            own = jnp.einsum("bhgnqk,bhnkd->bhgnqd", p.astype(v.dtype), vb,
+                             precision=matmul_precision(v.dtype),
+                             preferred_element_type=f32).reshape(early.shape)
+            noised = own + early.astype(f32) \
+                * jnp.exp(early_lse - lse)[..., None]
     out = jnp.concatenate([noised.astype(q.dtype), clean.astype(q.dtype)])
     lse = jnp.concatenate([lse, clean_lse])
     out, lse = keep((out, lse))
@@ -587,28 +601,35 @@ def _diffusion_bwd(scale, block, block_q, kernels, interpret, res, d_out):
             grads.append(flash_attention.attention_grads(
                 q[rows], k[half:], v[half:], out[rows], lse[rows],
                 d_out[rows], kernels, scale, True, 0, interpret,
-                diffusion=(block, strict)))
-    (dq_clean, dk_clean, dv_clean), (dq_early, dk_early, dv_early) = grads
-    with jax.named_scope("attention.own_block"):
-        prec = matmul_precision(q.dtype)
+                diffusion=(block, strict),
+                own=(k[:half], v[:half]) if strict else None))
+    (dq_clean, dk_clean, dv_clean), (dq_noised, dk_early, dv_early,
+                                     *own) = grads
+    if kernels is None:
+        dq_early = dq_noised
+        with jax.named_scope("attention.own_block"):
+            prec = matmul_precision(q.dtype)
 
-        def dot(spec, x, y):
-            return jnp.einsum(spec, x, y, precision=prec,
-                              preferred_element_type=f32)
+            def dot(spec, x, y):
+                return jnp.einsum(spec, x, y, precision=prec,
+                                  preferred_element_type=f32)
 
-        s, qb, kb = _own_scores(q[:half], k[:half], scale, block)
-        vb = v[:half].reshape(kb.shape[:4] + v.shape[-1:])
-        gb = d_out[:half].reshape(qb.shape[:5] + v.shape[-1:])
-        delta = jnp.sum(d_out[:half].astype(f32) * out[:half].astype(f32),
-                        axis=-1)
-        p = jnp.exp(s - _in_blocks(lse[:half], s))
-        ds = p * (dot("bhgnqd,bhnkd->bhgnqk", gb, vb)
-                  - _in_blocks(delta, s)) * scale
-        p, ds = p.astype(q.dtype), ds.astype(q.dtype)
-        dq_own = dot("bhgnqk,bhnkd->bhgnqd", ds, kb).reshape(q[:half].shape)
-        dk_own = dot("bhgnqk,bhgnqd->bhnkd", ds, qb).reshape(k[:half].shape)
-        dv_own = dot("bhgnqk,bhgnqd->bhnkd", p, gb).reshape(v[:half].shape)
-        dq_noised = (dq_early.astype(f32) + dq_own).astype(q.dtype)
+            s, qb, kb = _own_scores(q[:half], k[:half], scale, block)
+            vb = v[:half].reshape(kb.shape[:4] + v.shape[-1:])
+            gb = d_out[:half].reshape(qb.shape[:5] + v.shape[-1:])
+            delta = jnp.sum(d_out[:half].astype(f32) * out[:half].astype(f32),
+                            axis=-1)
+            p = jnp.exp(s - _in_blocks(lse[:half], s))
+            ds = p * (dot("bhgnqd,bhnkd->bhgnqk", gb, vb)
+                      - _in_blocks(delta, s)) * scale
+            p, ds = p.astype(q.dtype), ds.astype(q.dtype)
+            dq_own = dot("bhgnqk,bhnkd->bhgnqd", ds, kb).reshape(
+                q[:half].shape)
+            own = [
+                dot("bhgnqk,bhgnqd->bhnkd", ds, qb).reshape(k[:half].shape),
+                dot("bhgnqk,bhgnqd->bhnkd", p, gb).reshape(v[:half].shape)]
+            dq_noised = (dq_early.astype(f32) + dq_own).astype(q.dtype)
+    dk_own, dv_own = own
     return (jnp.concatenate([dq_noised, dq_clean]),
             jnp.concatenate([dk_own.astype(k.dtype), dk_clean + dk_early]),
             jnp.concatenate([dv_own.astype(v.dtype), dv_clean + dv_early]))

@@ -5,8 +5,9 @@ other), in Pallas's interpreter on the CPU: bfloat16, one small shape,
 forward and backward, against the ``jax.numpy`` blocks of
 ``ring_attention.diffusion_attention`` (which ``tests/test_sdar.py`` holds to
 a dense softmax under the explicit mask); their visit list against a
-brute-force count of the tiles the mask leaves something in; and that the
-kernels trace to what they were when the mode is off. The compile for a
+brute-force count of the tiles the mask leaves something in; that the
+kernel path traces none of the ``jax.numpy`` squares; and that the kernels
+trace to what they were when the mode is off. The compile for a
 described v5e is the chip's (the cell's traced run)."""
 
 import sys
@@ -34,18 +35,11 @@ def _f32(x):
     return np.asarray(x, np.float32)
 
 
-@pytest.mark.parametrize("bq,bk,block", [(128, 256, 4), (256, 128, 16)])
-def test_kernels_match_the_blocks_forward_and_backward(bq, bk, block):
-    """Both walks (the clean copy on itself, the noised copy on the clean
-    one, whose first block sees no clean key at all) and the join with the
-    noised copy's own blocks; the clean copy's ``dk`` and ``dv`` gather from
-    both copies' queries."""
+def _both_paths(q, k, v, g, scale, block, plan):
+    """((out, (dq, dk, dv)) through the kernels in the interpreter, the
+    same through the ``jax.numpy`` blocks)."""
     import jax
     import jax.numpy as jnp
-
-    q, k, v, g = _operands(1, 2, 2, 1, 512, 128)
-    scale = 128 ** -0.5
-    plan = fa.Plan(bq, bk, 64 << 20)
 
     def run(kernels):
         f = lambda *a: ra.diffusion_attention(  # noqa: E731
@@ -55,18 +49,101 @@ def test_kernels_match_the_blocks_forward_and_backward(bq, bk, block):
             (f(*a) * g).astype(jnp.float32)), (0, 1, 2))(q, k, v)
         return out, grads
 
-    out, grads = run(plan)
-    want, wants = run(None)
-    assert np.max(np.abs(_f32(out) - _f32(want))) < 0.02
-    for name, a, b in zip("qkv", grads, wants):
-        err = np.max(np.abs(_f32(a) - _f32(b)))
-        assert err < 0.01 * max(np.max(np.abs(_f32(b))), 1.0), (name, err)
+    return run(plan), run(None)
+
+
+# the two tilings; a query block of block * 64 positions; and rows of ONE
+# query block, none of whose noised queries' first block sees a clean key
+@pytest.mark.parametrize("length,bq,bk,block", [
+    (512, 128, 256, 4), (512, 256, 128, 16), (512, 256, 128, 4),
+    (256, 256, 128, 128)])
+def test_kernels_match_the_blocks_forward_and_backward(length, bq, bk, block):
+    """Both walks (the clean copy on itself, the noised copy on the clean
+    one and on its own blocks, one more tile of the same kernels) against
+    the ``jax.numpy`` blocks: the output and ``dq``, ``dk``, ``dv`` of BOTH
+    copies, each copy held to its own limit (the noised copy's ``dk`` and
+    ``dv`` come from the kernel's own tile; the clean copy's gather from
+    both copies' queries)."""
+    q, k, v, g = _operands(1, 2, 2, 1, length, 128)
+    scale = 128 ** -0.5
+    plan = fa.Plan(bq, bk, 64 << 20)
+    (out, grads), (want, wants) = _both_paths(q, k, v, g, scale, block, plan)
+    for copy in (slice(0, 1), slice(1, 2)):     # noised, clean
+        assert np.max(np.abs(_f32(out[copy]) - _f32(want[copy]))) < 0.02
+        for name, a, b in zip("qkv", grads, wants):
+            a, b = _f32(a[copy]), _f32(b[copy])
+            err = np.max(np.abs(a - b))
+            assert np.max(np.abs(b)) > 0.1, (name, copy)   # something to hold
+            assert err < 0.01 * max(np.max(np.abs(b)), 1.0), (name, copy, err)
     # the strict walk alone: rows of the first block come back with a
-    # log-sum-exp no real score reaches, so the join weighs them at 0
+    # log-sum-exp no real score reaches; with their own copy's keys beside
+    # them every row has a real one, the joint one of the two parts
+    alone = dict(diffusion=(block, True))
     _, lse = fa.attention(q[:1], k[1:], v[1:], plan, scale, True, 0, True,
-                          diffusion=(block, True))
+                          **alone)
     assert np.all(np.asarray(lse)[:, :, :block] < -1e37)
     assert np.all(np.abs(np.asarray(lse)[:, :, block:]) < 1e3)
+    _, joint = fa.attention(q[:1], k[1:], v[1:], plan, scale, True, 0, True,
+                            own=(k[:1], v[:1]), **alone)
+    assert np.all(np.abs(np.asarray(joint)) < 1e3)
+    import jax
+
+    s, _, _ = ra._own_scores(q[:1], k[:1], scale, block)
+    mine = np.asarray(jax.nn.logsumexp(s, axis=-1)).reshape(joint.shape)
+    assert np.allclose(np.asarray(joint)[:, :, :block], mine[:, :, :block],
+                       atol=2e-2)
+    assert np.allclose(np.asarray(joint)[:, :, block:], np.logaddexp(
+        np.asarray(lse), mine)[:, :, block:], atol=2e-2)
+
+
+def test_own_keys_ride_the_strict_walk_alone():
+    q, k, v, _ = _operands(3, 1, 2, 1, 256, 128)
+    plan = fa.Plan(128, 128, 64 << 20)
+    for diffusion in (None, (4, False)):
+        with pytest.raises(ValueError, match="strict"):
+            fa.attention(q, k, v, plan, 0.1, True, 0, True, own=(k, v),
+                         diffusion=diffusion)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (a ``jit``, a ``custom_vjp``,
+    a ``pallas_call``'s body) included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("on_kernels", [True, False])
+def test_the_kernel_path_traces_no_little_squares(on_kernels):
+    """Forward and backward under the kernels hold no ``attention.own_block``
+    scope and no ``logaddexp``: the own block is in the strict walk's
+    ``pallas_call``s, which take two operands and (backward) two results
+    more than the clean walk's. The ``jax.numpy`` blocks hold both."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v, g = _operands(4, 2, 2, 1, 256, 128)
+    plan = fa.Plan(128, 128, 64 << 20) if on_kernels else None
+    f = lambda *a: jnp.sum((ra.diffusion_attention(  # noqa: E731
+        *a, 0.1, 4, 128, plan, on_kernels) * g).astype(jnp.float32))
+    traced = jax.make_jaxpr(jax.value_and_grad(f, (0, 1, 2)))(q, k, v)
+    eqns = list(_equations(traced.jaxpr))
+    squares = any("attention.own_block" in str(e.source_info.name_stack)
+                  for e in eqns)
+    assert squares == (not on_kernels)
+    assert ("logaddexp" in str(traced)) == (not on_kernels)
+    # clean walk, strict walk: first, end, q, k, v (+ own k, v) forward;
+    # + d_out, lse, delta backward, whose results are dq, dk, dv (+ the own
+    # dk, dv)
+    calls = sorted((e.params["name"], len(e.invars), len(e.outvars))
+                   for e in eqns if e.primitive.name == "pallas_call")
+    assert calls == ([
+        ("attention_bwd", 8, 3), ("attention_bwd", 10, 5),
+        ("attention_fwd", 5, 2), ("attention_fwd", 7, 2)]
+        if on_kernels else [])
 
 
 @pytest.mark.parametrize("length,bq,bk,block", [
@@ -92,11 +169,69 @@ def test_the_visit_list_is_the_tiles_the_mask_leaves_something_in(
     # where a whole key tile lies inside the query tile's last block
     assert np.array_equal(extra, visited & (first_k == last_q))
     assert extra.any() == (block >= bk)
-    scored = 2 * fa.scored_pairs(length, bq, bk, True) + length * block
+    # and the noised copy's own blocks: a tile of bq keys a query block
+    scored = 2 * fa.scored_pairs(length, bq, bk, True) + length * bq
     kept_pairs = ra.diffusion_kept_pairs(length, block)
     assert kept_pairs <= scored
-    if length == 8192:      # the cell's layer: under the issue's 1.35
+    if length == 8192:      # the cell's layer: 1.093
         assert scored / kept_pairs < 1.1
+
+
+def _off_the_mode(case):
+    """The jaxpr of ``jax.grad`` through the kernels (``pallas_call`` bodies
+    included) with ``diffusion`` None, as text."""
+    import jax
+    import jax.numpy as jnp
+
+    plan = fa.Plan(128, 128, 64 << 20)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+    total = lambda out: jnp.sum(out.astype(jnp.float32))  # noqa: E731
+    if case == "selection":
+        operands = (shape(1, 2, 256, 128), shape(1, 1, 256, 128),
+                    shape(1, 1, 256, 128), shape(1, 2, 256, 64),
+                    shape(1, 1, 256, 64), shape(1, 2, 256))
+        f = lambda *a: total(ra.selected_kernels(  # noqa: E731
+            *a, 0.1, 64, 0.5, plan, True))
+    else:
+        window, d, dv = {"causal": (0, 128, 128), "window": (128, 128, 128),
+                         "latent": (0, 192, 128)}[case]
+        operands = (shape(1, 2, 256, d), shape(1, 1, 256, d),
+                    shape(1, 1, 256, dv))
+        f = lambda *a: total(ra.blockwise_attention(  # noqa: E731
+            *a, True, 0.1, 128, window, plan, True))
+    return str(jax.make_jaxpr(jax.grad(f, tuple(range(len(operands)))))(
+        *operands))
+
+
+# sha256 of ``_off_the_mode(case)`` at the commit before the own tile (PR 60's
+# tree, jax 0.9.0): dense causal, a window, the latent widths 192 / 128, and
+# ``kept`` under a selection (four kernels)
+_BEFORE_THE_OWN_TILE = {
+    "causal":
+        "e04b83631e0e3fab23f41632da670e3b9f713254644266f22c7c6a14eb7f040c",
+    "window":
+        "58fd428cf83631f5044c192c4b7b497a9898d507fb5c5325299e984fd33b2b17",
+    "latent":
+        "ddf0bd0ec041639eac3fd8b7bdc28cfde642dc511fe0171a0070d4f047096be3",
+    "selection":
+        "c84dabb12367956d96c3631008b5ac9bbb4097d628fd612cc306a011418cf929",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BEFORE_THE_OWN_TILE))
+def test_off_the_mode_the_gradient_traces_to_what_it_was(case):
+    """With ``diffusion`` None the optional operands are absent and the
+    kernels' jaxprs, forward and backward, are the parent's to the digest:
+    what the other eight kernel cells (the Keye-VL-2.0 cell's ``kept``
+    among them) run did not move."""
+    import hashlib
+
+    import jax
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests are of jax 0.9.0's printed jaxprs")
+    assert hashlib.sha256(_off_the_mode(case).encode()).hexdigest() \
+        == _BEFORE_THE_OWN_TILE[case]
 
 
 def test_off_the_kernels_trace_to_what_they_were():
@@ -116,6 +251,14 @@ def test_off_the_kernels_trace_to_what_they_were():
     on = jax.make_jaxpr(lambda *a: fa._fwd(
         *a, first, end, diffusion=(4, False), **static))(q, k, v)
     assert str(off) == str(same) != str(on)
+    strict = jax.make_jaxpr(lambda *a: fa._fwd(
+        *a, first, end, diffusion=(4, True), **static))(q, k, v)
+    own = jax.make_jaxpr(lambda *a: fa._fwd(
+        *a, first, end, None, (a[1], a[2]), diffusion=(4, True), **static))(
+            q, k, v)
+    assert str(strict) != str(own)
+    assert fa._optional(None, None) == () and fa._optional(k, None) == (k,)
+    assert fa._optional(None, [k, v]) == (None, (k, v))
     assert "diffusion" not in fa._static(fa.Plan(128, 128, 1), 0.1, True, 0,
                                          False)
     assert fa._static(fa.Plan(128, 128, 1), 0.1, True, 0, False,

@@ -494,6 +494,50 @@ def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes <= 8 << 20
 
 
+def test_diffusion_attention_kernels_compile_for_a_v5e_at_the_cell_widths(
+        one_chip):
+    """Mosaic accepts the fused attention kernels under the block-diffusion
+    mask at the SDAR cell's layer (two copies of a row of 8192, 32 heads
+    over 4 of 128, blocks of 4: tiles of 256 positions x 512 keys), the
+    strict walk's with the noised copy's own keys and values beside each
+    query block and their ``dk`` / ``dv`` as two more results; the rule's
+    VMEM count did not move for them; no ``jax.numpy`` square is left in
+    the program."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import flash_attention as fa
+
+    ra = importlib.import_module("mxnet_tpu.parallel.ring_attention")
+    heads, kv, t, d, block = 32, 4, 8192, 128, 4
+    plan = fa.plan("tpu", V5E_VMEM, jnp.bfloat16, heads, kv, t, d, True,
+                   diffusion_block=block)
+    assert plan == fa.plan("tpu", V5E_VMEM, jnp.bfloat16, heads, kv, t, d,
+                           True)
+    assert tuple(plan)[:2] == (256, 512)
+
+    def step(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v: ra.diffusion_attention(
+                q, k, v, d ** -0.5, block, 512, plan), q, k, v)
+        return (out,) + vjp(g)
+
+    def arg(h):
+        return jax.ShapeDtypeStruct((2, h, t, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(arg(heads), arg(kv), arg(kv),
+                                   arg(heads)).compile()
+    text = compiled.as_text()
+    assert text.count("attention_fwd") >= 2 and "attention_bwd" in text
+    assert "own_block" not in text
+    # the halves cut and joined (bfloat16 copies of q, out and d_out) and the
+    # rows' float32 delta: no float32 (B, H, T, Dv) temporary of the join
+    assert compiled.memory_analysis().temp_size_in_bytes <= 400 << 20
+
+
 def test_latent_attention_kernels_compile_for_a_v5e_at_the_cell_widths(
         one_chip):
     """Mosaic accepts the fused attention kernels at the kanana2-30b cell's

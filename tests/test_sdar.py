@@ -236,19 +236,55 @@ def test_launch_counts_are_the_closed_forms(rows, length, block):
     assert got["executor.attention_scored_pairs"] == rows * heads * \
         ra.diffusion_scored_pairs(length, block, block_q)
     assert got["executor.attention_kernel_layers"] == 0     # the CPU
+    assert got["executor.attention_own_tile_layers"] == 0   # its squares
     assert got["executor.attention_pair_lanes"] == 32
     plain = op.launch_counts(ins, None, op.parse_params(dict(causal=True)),
                              "cpu")
     assert not [n for n in plain if "diffusion" in n or "kept" in n]
     assert set(got) | set(plain) <= set(op.launch_instruments)
-    if length == 8192:
-        # the cell's layer at the kernels' tiles (256 positions x 512 keys):
-        # two causal walks and the little squares over L (L + 4)
-        from mxnet_tpu.ops import flash_attention
+    assert "executor.attention_own_tile_layers" not in plain
 
-        scored = 2 * flash_attention.scored_pairs(length, 256, 512, True) \
-            + length * block
-        assert 1.0 < scored / (length * (length + block)) < 1.1
+
+def test_launch_counts_on_the_kernels_count_the_own_tile(monkeypatch):
+    """The cell's layer where the rule gives kernels (one v5e, a bfloat16
+    trunk: tiles of 256 positions x 512 keys): the noised copy's own block
+    is a tile of the strict walk's kernels, so the layer counts one own-tile
+    layer (4 a step over the cell's four) and its scored pairs are two
+    causal walks and ``L x 256`` of own tiles, over the ``L (L + 4)`` kept:
+    1.093, where the ``jax.numpy`` squares' ``L x 4`` read 1.0625."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import flash_attention, pallas_support, registry
+
+    monkeypatch.setattr(pallas_support, "attached_vmem_bytes",
+                        lambda: 128 << 20)
+    rows, heads, length, block = 1, 32, 8192, 4
+    ins = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
+        (2 * rows, heads, length, 128), (2 * rows, 4, length, 128),
+        (2 * rows, 4, length, 128))]
+    op = registry.get("RingAttention")
+    params = op.parse_params(dict(causal=True, diffusion_block=block))
+    got = op.launch_counts(ins, None, params, "tpu")
+    assert got["executor.attention_kernel_layers"] == 1
+    assert got["executor.attention_diffusion_layers"] == 1
+    assert got["executor.attention_own_tile_layers"] == 1
+    walks = 2 * flash_attention.scored_pairs(length, 256, 512, True)
+    assert got["executor.attention_scored_pairs"] == rows * heads * (
+        walks + length * 256)
+    kept = got["executor.attention_kept_pairs"]
+    assert kept == rows * heads * length * (length + block)
+    assert 1.09 < got["executor.attention_scored_pairs"] / kept < 1.1
+    assert 1.06 < rows * heads * (walks + length * block) / kept < 1.07
+    # a float32 trunk on the same chip: the squares
+    off = op.launch_counts(
+        [jax.ShapeDtypeStruct(x.shape, jnp.float32) for x in ins], None,
+        params, "tpu")
+    assert off["executor.attention_kernel_layers"] == 0
+    assert off["executor.attention_own_tile_layers"] == 0
+    assert off["executor.attention_scored_pairs"] == rows * heads * \
+        ra.diffusion_scored_pairs(length, block,
+                                  ra.block_q_of(rows, heads, length))
 
 
 # --- BlockDiffusionNoise -----------------------------------------------------------
@@ -602,6 +638,7 @@ def test_fit_draws_fresh_noise_every_step_and_counts_its_rows(monkeypatch):
     steps = 4
     assert delta("attention_layers") == 2 * steps
     assert delta("attention_diffusion_layers") == 2 * steps
+    assert delta("attention_own_tile_layers") == 0    # the CPU's squares
     assert delta("attention_kept_pairs") == 2 * steps * B * 8 * T * (T + 4)
     assert delta("attention_scored_pairs") == 2 * steps * B * 8 * \
         ra.diffusion_scored_pairs(T, 4, ra.block_q_of(B, 8, T))
