@@ -507,11 +507,17 @@ inline Symbol RingAttention(const std::string& symbol_name, const Symbol& query,
   return detail::CreateOp("RingAttention", symbol_name, 3, keys__, ins__, params__);
 }
 
-inline Symbol RotaryEmbedding(const std::string& symbol_name, const Symbol& data, double base = 10000.0, bool interleaved = false, int64_t rotary_dim = 0) {
+inline Symbol RotaryEmbedding(const std::string& symbol_name, const Symbol& data, double attention_factor = 0.0, double base = 10000.0, double beta_fast = 32.0, double beta_slow = 1.0, double factor = 1.0, bool interleaved = false, int64_t original_max_position = 0, int64_t rotary_dim = 0, const std::string& scaling = "") {
   detail::ParamMap params__;
+  params__.emplace_back("attention_factor", detail::str(attention_factor));
   params__.emplace_back("base", detail::str(base));
+  params__.emplace_back("beta_fast", detail::str(beta_fast));
+  params__.emplace_back("beta_slow", detail::str(beta_slow));
+  params__.emplace_back("factor", detail::str(factor));
   params__.emplace_back("interleaved", detail::str(interleaved));
+  params__.emplace_back("original_max_position", detail::str(original_max_position));
   params__.emplace_back("rotary_dim", detail::str(rotary_dim));
+  if (!scaling.empty()) params__.emplace_back("scaling", scaling);
   const char* keys__[] = {"data"};
   const Symbol* ins__[] = {&data};
   return detail::CreateOp("RotaryEmbedding", symbol_name, 1, keys__, ins__, params__);

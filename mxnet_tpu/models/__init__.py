@@ -7,8 +7,10 @@ an MLP router), Kimi Linear (a delta rule gated a key channel, latent
 attention without positions) and Keye-VL-2.0 (the text decoder: attention over
 the keys a learned indexer selects) sparse-expert decoders, Ouro (a
 looped dense decoder: one stack run four times over the same weights, an
-exit after every pass) and SDAR (a sparse-expert decoder trained as a
-block-diffusion model: every row read twice, a noised copy and a clean one).
+exit after every pass), SDAR (a sparse-expert decoder trained as a
+block-diffusion model: every row read twice, a noised copy and a clean one)
+and Mellum 2 (a sparse-expert decoder whose window and full layers each turn
+by their own rotary schedule, the full ones by YaRN's).
 
 Reference: ``example/image-classification/symbols/*.py`` and
 ``example/rnn``/``example/gan``. Builders return plain Symbols usable with
@@ -37,6 +39,7 @@ from .kimi_linear import kimi_linear_sym_gen
 from .keye_vl2 import keye_vl2_sym_gen
 from .ouro import ouro_sym_gen
 from .sdar import sdar_sym_gen
+from .mellum import mellum_sym_gen
 from . import ssd
 from . import zoo
 from .zoo import SCORE_SYMBOLS

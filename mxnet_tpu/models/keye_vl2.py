@@ -28,14 +28,17 @@ from .olmoe import (embed_tokens, linear, merge_heads, next_token_head,
 def qwen3_moe_block(x, pre, attention, *, hidden_size, num_heads,
                     num_kv_heads, head_dim, num_experts, expert_width, top_k,
                     route_norm, num_local_experts, expert_offset,
-                    rms_norm_eps, rope_theta, lb_coef):
+                    rms_norm_eps, rotary, lb_coef):
     """One pre-norm block of the Qwen3-MoE decoder on the stream ``x`` (B,
     T, hidden), its nodes named ``pre`` + ...: q, k, v bias-free over
     grouped key/value heads, a per-head RMS norm of queries and keys (one
     gain of ``head_dim`` each), rotate-half rotary positions over the whole
-    head, ``attention(q, k, v, u)`` (``u`` the block's normed input) for
+    head by THIS layer's schedule (``rotary``: the keywords of
+    ``RotaryEmbedding``, ``dict(base=theta)`` where every layer turns
+    alike), ``attention(q, k, v, u)`` (``u`` the block's normed input) for
     the heads' output (B, heads, T, head_dim), then the mixture. What
-    Keye-VL-2.0 and SDAR share; they differ in ``attention``."""
+    Keye-VL-2.0, SDAR and Mellum share; they differ in ``attention``, and
+    Mellum's layers in ``rotary`` by their kind."""
 
     def norm(z, name):
         return sym.RMSNorm(z, eps=rms_norm_eps, name=name)
@@ -43,7 +46,7 @@ def qwen3_moe_block(x, pre, attention, *, hidden_size, num_heads,
     def heads(z, count, name):
         return sym.RotaryEmbedding(
             split_heads(z, count, head_dim, lambda y: norm(y, name)),
-            base=rope_theta)
+            **rotary)
 
     u = norm(x, pre + "input_norm")
     q = heads(linear(u, num_heads * head_dim, pre + "q"), num_heads,
@@ -83,7 +86,7 @@ def keye_vl2_sym_gen(vocab_size=151936, hidden_size=2048, num_layers=48,
         num_experts=num_experts, expert_width=expert_width, top_k=top_k,
         route_norm=route_norm, num_local_experts=num_local_experts,
         expert_offset=expert_offset, rms_norm_eps=rms_norm_eps,
-        rope_theta=rope_theta, lb_coef=lb_coef)
+        rotary=dict(base=rope_theta), lb_coef=lb_coef)
 
     def rotary(x):
         return sym.RotaryEmbedding(x, base=rope_theta)

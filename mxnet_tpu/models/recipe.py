@@ -145,7 +145,8 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
     """Analytic forward FLOPs **per sample** for ``symbol``.
 
     Counts Convolution, Deconvolution, FullyConnected, the fused RNN op,
-    RingAttention (a causal one at half its scores; under ``select_top_k``
+    RingAttention (a causal one at half its scores, one with a ``window``
+    at its band's pairs; under ``select_top_k``
     the pairs each query keeps and the pairs its indexer scores; under
     ``diffusion_block`` the T (T + block) pairs the two copies of a row
     keep), GatedDeltaRule (its
@@ -203,6 +204,8 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
                 total += 3.0 * _prod(v) * int(k[3]) / batch
             continue
         if op == "RingAttention":
+            from ..parallel.ring_attention import kept_pairs
+
             # q (B, H, T, Dk) . k over Dk and p . v (B, Hkv, T, Dv) over Dv,
             # a causal row sees half the keys
             q = _node_shape(shape_dict, nodes, node["inputs"][0])
@@ -219,14 +222,18 @@ def estimate_flops(symbol, batch=None, **shape_kwargs):
                 # a selection: query t keeps min(t + 1, top_k) keys, and the
                 # indexer (index_query (B, J, T, Di), one key head) scores
                 # every earlier one
-                t, kept = int(q[2]), min(top_k, int(q[2]))
+                t = int(q[2])
                 iq = _node_shape(shape_dict, nodes, node["inputs"][3])
-                total += _prod(q[:2]) * (
-                    kept * (kept + 1) // 2 + (t - kept) * kept) * (
-                        int(q[3]) + int(v[3])) / batch
+                total += _prod(q[:2]) * kept_pairs(t, top_k) * (
+                    int(q[3]) + int(v[3])) / batch
                 if iq:
                     total += _prod(iq[:2]) * (t * (t + 1) // 2) * int(
                         iq[3]) / batch
+            elif q and v and 0 < int(attrs.get("window", 0)) < int(q[2]):
+                # a band: query t reads its min(t + 1, window) keys
+                total += _prod(q[:2]) * kept_pairs(
+                    int(q[2]), int(attrs["window"])) * (
+                        int(q[3]) + int(v[3])) / batch
             elif q and v:
                 seen = 0.5 if parse_bool(attrs.get("causal", False)) else 1.0
                 total += seen * _prod(q[:3]) * int(q[2]) * (

@@ -61,7 +61,7 @@ def sdar_sym_gen(vocab_size=151936, hidden_size=2048, num_layers=48,
         expert_offset=expert_offset, rms_norm_eps=rms_norm_eps,
         # ``MoE`` puts its balance term on the scale of ITS rows, two a
         # token here; the objective's is a token's
-        rope_theta=rope_theta, lb_coef=lb_coef / 2)
+        rotary=dict(base=rope_theta), lb_coef=lb_coef / 2)
 
     def attention(pre):
         return lambda q, k, v, u: sym.RingAttention(

@@ -640,7 +640,12 @@ def _ring_attention_op(ins, params, mode):
 def _ring_attention_counts(ins, outs, params, platform):
     """A launch's counts for one node, from the one-device path's own ask
     of its rule (``ring_attention.kernel_plan``, as ``_on_one_device`` asks
-    it): whether it has a ``window``; whether a train program runs it in the
+    it): whether it has a ``window``, and then the exact pairs its band
+    keeps (query ``t`` its ``min(t + 1, window)``, x heads x batch) beside
+    the pairs its tiles score, so that their ratio is what the band's edges
+    cost: the blocks a band cuts are scored whole
+    (``executor.attention_band_kept_pairs`` / ``_scored_pairs``; a full
+    layer adds to neither); whether a train program runs it in the
     fused Pallas kernels; the query-key pairs of the tiles it visits,
     forward (backward recomputes the same): the kernels' visit list at the
     plan's tiles where they engage, else the ``jax.numpy`` blocks'
@@ -670,8 +675,8 @@ def _ring_attention_counts(ins, outs, params, platform):
     the trunk rows a token costs, whatever the model's builder made of it)."""
     from ..parallel.ring_attention import (block_q_of, diffusion_kept_pairs,
                                            diffusion_scored_pairs,
-                                           kernel_plan, scored_pairs,
-                                           select_block_q,
+                                           kept_pairs, kernel_plan,
+                                           scored_pairs, select_block_q,
                                            selected_scored_pairs)
     from . import flash_attention
 
@@ -699,11 +704,15 @@ def _ring_attention_counts(ins, outs, params, platform):
     else:
         pairs = scored_pairs(T, causal, window,
                              block_q_of(batch, heads, T, window))
-    kept = min(top_k, T)
+
+    band = {} if window <= 0 else {
+        "executor.attention_band_kept_pairs":
+            batch * heads * kept_pairs(T, window),
+        "executor.attention_band_scored_pairs": batch * heads * pairs}
     selected = {} if top_k <= 0 else {
         "executor.attention_selected_layers": 1,
         "executor.attention_selected_pairs":
-            batch * heads * (kept * (kept + 1) // 2 + (T - kept) * kept),
+            batch * heads * kept_pairs(T, top_k),
         "executor.attention_index_pairs":
             batch * ins[3].shape[1] * (T * (T + 1) // 2)}
     diffusion = {} if block <= 0 else {
@@ -712,7 +721,7 @@ def _ring_attention_counts(ins, outs, params, platform):
         "executor.attention_kept_pairs":
             batch * heads * diffusion_kept_pairs(T, block),
         "executor.diffusion_trunk_rows": trunk_rows}
-    return {**selected, **diffusion,
+    return {**band, **selected, **diffusion,
             "executor.attention_layers": 1,
             "executor.attention_window_layers": int(bool(window)),
             "executor.attention_kernel_layers": int(kernels is not None),
@@ -749,6 +758,8 @@ register(
                         "executor.attention_window_layers",
                         "executor.attention_kernel_layers",
                         "executor.attention_scored_pairs",
+                        "executor.attention_band_kept_pairs",
+                        "executor.attention_band_scored_pairs",
                         "executor.attention_latent_layers",
                         "executor.attention_pair_lanes",
                         "executor.attention_selected_layers",

@@ -29,6 +29,8 @@ chunks' triangular inverse and the state. Outputs come back in the dtype of ``da
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -102,43 +104,115 @@ register(
 
 
 # --- RotaryEmbedding -------------------------------------------------------
+class _Schedule(NamedTuple):
+    """What a node's tables are made from: pair ``i`` of a head of ``2 half``
+    turns by ``t * f_i`` and cos and sin carry the amplitude ``a``. With
+    ``scaling`` "" ``f_i = base^(-i/half)`` and ``a = 1`` (the other fields
+    are then left at these defaults, so that every such node of a ``base``
+    asks for the same tables). "yarn" (Peng et al. 2023, arXiv:2309.00071;
+    ``transformers``' ``_compute_yarn_parameters`` with ``truncate``): with
+    ``d = 2 half``, ``L = original_max_position``, ``c(r) = d ln(L / (2 pi
+    r)) / (2 ln base)``, ``low = max(floor(c(beta_fast)), 0)``, ``high =
+    min(ceil(c(beta_slow)), d - 1)`` and ``ramp_i = clip((i - low) / (high -
+    low), 0, 1)``: ``f_i = (1 - ramp_i) base^(-i/half) + ramp_i
+    base^(-i/half) / factor``, the fast pairs as they were trained and the
+    slow ones stretched ``factor`` times, and ``a = attention_factor`` (0:
+    ``0.1 ln(factor) + 1``)."""
+
+    base: float
+    scaling: str = ""
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
+
+    def inv_freq(self, half):
+        """``f_i`` (half,) float64."""
+        plain = self.base ** (-np.arange(half, dtype=np.float64) / half)
+        if not self.scaling:
+            return plain
+        d = 2 * half
+
+        def pair_of(rotations):     # the pair that turns so often over L
+            return d * math.log(self.original_max_position / (
+                rotations * 2 * math.pi)) / (2 * math.log(self.base))
+
+        low = max(math.floor(pair_of(self.beta_fast)), 0)
+        high = min(math.ceil(pair_of(self.beta_slow)), d - 1)
+        high += 0.001 * (low == high)
+        ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                       / (high - low), 0.0, 1.0)
+        return (1.0 - ramp) * plain + ramp * plain / self.factor
+
+    def amplitude(self):
+        if not self.scaling:
+            return 1.0
+        return self.attention_factor or 0.1 * math.log(self.factor) + 1.0
+
+
+def _schedule(params):
+    """A node's :class:`_Schedule`, or an ``MXNetError`` for one the
+    operator does not define."""
+    scaling = params["scaling"]
+    if not scaling:
+        return _Schedule(params["base"])
+    if scaling != "yarn":
+        raise MXNetError(f"RotaryEmbedding: scaling {scaling!r} (\"\" or "
+                         "\"yarn\")")
+    schedule = _Schedule(params["base"], scaling, params["factor"],
+                         params["original_max_position"],
+                         params["beta_fast"], params["beta_slow"],
+                         params["attention_factor"])
+    if schedule.factor < 1.0 or schedule.original_max_position <= 0 \
+            or not 0.0 < schedule.beta_slow <= schedule.beta_fast \
+            or schedule.attention_factor < 0.0:
+        raise MXNetError(f"RotaryEmbedding: {schedule}")
+    return schedule
+
+
 @functools.lru_cache(maxsize=16)
-def _rotary_tables(t, half, base, lanes=False):
-    """cos and sin (t, half) float32 of the angles ``t * base^(-i/half)``,
-    made on the host (``_rotary``'s docstring says in which precision);
-    ``lanes``: as the kernel reads them (``rotary_kernels.lane_tables``).
-    Kept, read-only, for the last few lengths and bases: every node of a
-    program asks for its layer's tables again, once a direction, and the
-    float64 cosines of a (16 384, 64) table take a host core 40 ms (made a
-    node and a direction, 2.6 s of the Keye-VL-2.0 cell's set-up and 4.9 of
-    the Ouro cell's: PERF.md section 6, PR 59)."""
+def _rotary_tables(t, half, schedule, lanes=False):
+    """``a cos`` and ``a sin`` (t, half) float32 of the angles ``t * f_i``
+    of ``schedule`` (a :class:`_Schedule`: the frequencies and the
+    amplitude), made on the host (``_rotary``'s docstring says in which
+    precision); ``lanes``: as the kernel reads them
+    (``rotary_kernels.lane_tables``). Kept, read-only, under the whole
+    schedule: every node of a program asks for its layer's tables again,
+    once a direction, and the float64 cosines of a (16 384, 64) table take
+    a host core 40 ms (made a node and a direction, 2.6 s of the
+    Keye-VL-2.0 cell's set-up and 4.9 of the Ouro cell's: PERF.md section
+    6, PR 59). The depth is for one program's tables: its lengths
+    (buckets) x its head widths (a model's heads and its indexer's) x its
+    schedules (a model whose window and full layers turn by two) x the two
+    layouts; sixteen hold two of each."""
     if lanes:
-        tables = _rk.lane_tables(*_rotary_tables(t, half, base))
+        tables = _rk.lane_tables(*_rotary_tables(t, half, schedule))
     else:
-        inv_freq = (base ** (-np.arange(half, dtype=np.float64) / half)
-                    ).astype(np.float32)
+        inv_freq = schedule.inv_freq(half).astype(np.float32)
         angle = (np.arange(t, dtype=np.float32)[:, None] * inv_freq[None, :]
                  ).astype(np.float64)
-        tables = (np.cos(angle).astype(np.float32),
-                  np.sin(angle).astype(np.float32))
+        a = schedule.amplitude()
+        tables = ((a * np.cos(angle)).astype(np.float32),
+                  (a * np.sin(angle)).astype(np.float32))
     for table in tables:
         table.setflags(write=False)
     return tables
 
 
-def _turned(x, base, interleaved, back, kernels):
-    """Every head of x (..., T, D) turned whole, ``back``: by the negated
-    angles (the sine's terms change sign; the tables are forward's, so a
-    program holds one pair a length and base); in the Pallas kernel at the
-    blocks ``kernels`` or, None, as ``jax.numpy``: the array float32, cut at
-    the half (or into neighbours), four products, joined again, one
-    rounding."""
+def _turned(x, schedule, interleaved, back, kernels):
+    """Every head of x (..., T, D) turned whole by ``schedule``'s tables,
+    ``back``: by the negated angles (the sine's terms change sign; the
+    tables are forward's, so a program holds one pair a length and
+    schedule); in the Pallas kernel at the blocks ``kernels`` or, None, as
+    ``jax.numpy``: the array float32, cut at the half (or into neighbours),
+    four products, joined again, one rounding."""
     t, d = x.shape[-2:]
     half = d // 2
     if kernels is not None:
-        return _rk.turn(x, *_rotary_tables(t, half, base, True), back,
+        return _rk.turn(x, *_rotary_tables(t, half, schedule, True), back,
                         kernels)
-    cos, sin = _rotary_tables(t, half, base)
+    cos, sin = _rotary_tables(t, half, schedule)
     xf = x.astype(jnp.float32)
     if interleaved:
         pairs = xf.reshape(x.shape[:-1] + (half, 2))
@@ -157,36 +231,48 @@ def _turned(x, base, interleaved, back, kernels):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def _rotate(x, base, interleaved, back, kernels):
-    """``_turned`` under a derivative of its own: the rotation is linear and
-    orthogonal, so its pull-back is itself with ``back`` flipped, applied
-    to the cotangent, and keeps nothing."""
-    return _turned(x, base, interleaved, back, kernels)
+def _rotate(x, schedule, interleaved, back, kernels):
+    """``_turned`` under a derivative of its own: the operator is linear, a
+    pair's ``a R(theta)`` with the amplitude ``a`` in the tables, whose
+    transpose is ``a R(-theta)``: its pull-back is itself with ``back``
+    flipped over the same tables, applied to the cotangent, and keeps
+    nothing. (Orthogonal only at ``a = 1``: it is the transpose that the
+    pull-back needs, not the inverse.)"""
+    return _turned(x, schedule, interleaved, back, kernels)
 
 
 _rotate.defvjp(
     lambda x, *how: (_rotate(x, *how), None),
-    lambda base, interleaved, back, kernels, _, g: (
-        _rotate(g, base, interleaved, not back, kernels),))
+    lambda schedule, interleaved, back, kernels, _, g: (
+        _rotate(g, schedule, interleaved, not back, kernels),))
 
 
 def _rotary(ins, params, mode):
     """Rotary position embedding of ``data`` (..., T, D): pair ``i`` of
-    position ``t`` turns by ``t * base^(-2i/D)``. The pair is ``(i, i +
-    D/2)`` (rotate-half), or with ``interleaved`` the neighbours ``(2i, 2i
-    + 1)`` (``rope_interleave`` of the DeepSeek-V3 family, the original
-    RoFormer pairing), turned in place. (That family's public code leaves
-    its output de-interleaved, the evens before the odds: the same
-    permutation of queries and keys, which no score sees.)
+    position ``t`` turns by ``t * base^(-2i/D)``, positions 0..T-1. The pair
+    is ``(i, i + D/2)`` (rotate-half), or with ``interleaved`` the
+    neighbours ``(2i, 2i + 1)`` (``rope_interleave`` of the DeepSeek-V3
+    family, the original RoFormer pairing), turned in place. (That family's
+    public code leaves its output de-interleaved, the evens before the odds:
+    the same permutation of queries and keys, which no score sees.)
     With ``rotary_dim`` R (0: the whole head) only the first R of the D
     turn, as a head of R would, and dims ``[R, D)`` pass through.
+    ``scaling="yarn"`` turns the pairs by another schedule of frequencies
+    (the geometric ones blended, pair by pair, with the same divided by
+    ``factor``, from ``original_max_position``, ``beta_fast`` and
+    ``beta_slow``) and multiplies cos and sin by ``attention_factor`` (0:
+    ``0.1 ln(factor) + 1``), so that a score of two turned vectors carries
+    its square: :class:`_Schedule` has the equations. The amplitude is in
+    the tables and nowhere else: the arithmetic below, the derivative and
+    the kernel are the same for every schedule.
 
     The cos/sin tables are made on the host when the op is traced (T and D
     are static) and enter the program as constants: the frequencies in
     float64 rounded to float32, the angle their float32 product with the
     position (the published model's arithmetic), its cosine and sine
-    through float64. On the v5e a float32 ``power`` and ``sin`` of an angle
-    of some thousand radians were off by 6e-3 at T = 4096 (PERF.md, PR 26).
+    through float64, times the amplitude there, one rounding. On the v5e a
+    float32 ``power`` and ``sin`` of an angle of some thousand radians were
+    off by 6e-3 at T = 4096 (PERF.md, PR 26).
 
     Where the whole head turns, backward is the operator itself at the
     negated angle (``_rotate``, a ``custom_vjp``: ``dx1 = dy1 cos + dy2
@@ -218,28 +304,30 @@ def _rotary(ins, params, mode):
     ``jax.numpy`` form, as it was."""
     (x,) = ins
     r = params["rotary_dim"]
+    schedule = _schedule(params)
     kernels = _rk.kernel_plan(x.dtype, x.shape, r, params["interleaved"],
                               mode.platform)
     if not r or r == x.shape[-1]:
-        return _rotate(x, params["base"], params["interleaved"], False,
-                       kernels)
+        return _rotate(x, schedule, params["interleaved"], False, kernels)
     if r % 2 or not 0 < r < x.shape[-1]:
         raise MXNetError(f"RotaryEmbedding: rotary_dim {r} of a head of "
                          f"{x.shape[-1]}")
-    turned = _turned(x[..., :r], params["base"], params["interleaved"],
-                     False, None)
+    turned = _turned(x[..., :r], schedule, params["interleaved"], False,
+                     None)
     return jnp.concatenate([turned, x[..., r:]], axis=-1)
 
 
 def _rotary_counts(ins, outs, params, platform):
-    """A launch's counts for one node: itself, and whether a train program
-    runs it in the Pallas kernel: ``_rotary``'s own ask of
-    ``rotary_kernels.kernel_plan``."""
+    """A launch's counts for one node: itself, whether a train program
+    runs it in the Pallas kernel (``_rotary``'s own ask of
+    ``rotary_kernels.kernel_plan``), and whether its schedule is another
+    than the geometric one."""
     (x,) = ins
     kernels = _rk.kernel_plan(x.dtype, x.shape, params["rotary_dim"],
                               params["interleaved"], platform)
     return {"executor.rotary_nodes": 1,
-            "executor.rotary_kernel_nodes": int(kernels is not None)}
+            "executor.rotary_kernel_nodes": int(kernels is not None),
+            "executor.rotary_scaled_nodes": int(bool(params["scaling"]))}
 
 
 register(
@@ -248,10 +336,19 @@ register(
     arg_names=["data"],
     param_schema={"base": Param(parse_float, 10000.0),
                   "rotary_dim": Param(parse_int, 0),  # 0: the whole head
-                  "interleaved": Param(parse_bool, False)},  # (2i, 2i + 1)
+                  "interleaved": Param(parse_bool, False),  # (2i, 2i + 1)
+                  # the schedule of frequencies and the amplitude
+                  # (``_Schedule``): "" the geometric one, or "yarn"
+                  "scaling": Param(parse_str, ""),
+                  "factor": Param(parse_float, 1.0),
+                  "original_max_position": Param(parse_int, 0),
+                  "beta_fast": Param(parse_float, 32.0),
+                  "beta_slow": Param(parse_float, 1.0),
+                  "attention_factor": Param(parse_float, 0.0)},
     launch_counts=_rotary_counts,
     launch_instruments=("executor.rotary_nodes",
-                        "executor.rotary_kernel_nodes"),
+                        "executor.rotary_kernel_nodes",
+                        "executor.rotary_scaled_nodes"),
 )
 
 

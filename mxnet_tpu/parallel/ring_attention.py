@@ -186,6 +186,15 @@ def scored_pairs(T, causal, window=0, block_q=BLOCK_Q):
                for a, b, first, end in block_plan(T, block_q, causal, window))
 
 
+def kept_pairs(T, n):
+    """Query-key pairs one head keeps over ``T`` causal queries that each
+    keep at most ``n`` keys, exactly: a band of ``n`` (``window``) or a
+    selection of ``n`` (``select_top_k``); query ``t`` its ``min(t + 1,
+    n)``."""
+    n = min(n, T)
+    return n * (n + 1) // 2 + (T - n) * n
+
+
 def _q_blocks(T, block_q, causal, window=0):
     """:func:`block_plan` with each block's mask (queries, keys), None
     where the attention is not causal."""

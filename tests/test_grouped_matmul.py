@@ -442,7 +442,8 @@ def test_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip, matmul):
 @pytest.mark.parametrize("layer", ["trinity_window", "trinity_full",
                                    "olmoe_full", "group_of_7", "group_of_5",
                                    "group_of_6_window", "qwen3_next_4k",
-                                   "qwen3_next_8k"])
+                                   "qwen3_next_8k", "mellum2_window",
+                                   "mellum2_full"])
 def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
                                                                 layer):
     """Mosaic accepts the fused attention kernels
@@ -452,8 +453,11 @@ def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
     that are no power of two (28 over 4, 40 and 48 over 8: the rule's tiles
     are G x 256 rows); 16 over 2 heads of 256 at T 4096 and 8192 (the
     Qwen3-Next cell's full layer: 8 x 128 rows a tile at 8192, where the
-    rule's count is exactly the half of the VMEM it allows); no score tile
-    among the temporaries."""
+    rule's count is exactly the half of the VMEM it allows); 32 over 4 at T
+    16 384 under a band of 1024 keys, where the rule takes its narrowest key
+    block (256 x 128), and without one, where a tile of 256 positions no
+    longer fits beside the keys and the rule gives 128 x 512 (the Mellum2
+    cell's window and full layers); no score tile among the temporaries."""
     import importlib
 
     import jax
@@ -468,11 +472,15 @@ def test_attention_kernels_compile_for_a_v5e_at_the_cell_widths(one_chip,
         "group_of_5": (40, 8, 0, 4096, 128),
         "group_of_6_window": (48, 8, 2048, 4096, 128),
         "qwen3_next_4k": (16, 2, 0, 4096, 256),
-        "qwen3_next_8k": (16, 2, 0, 8192, 256)}[layer]
+        "qwen3_next_8k": (16, 2, 0, 8192, 256),
+        "mellum2_window": (32, 4, 1024, 16384, 128),
+        "mellum2_full": (32, 4, 0, 16384, 128)}[layer]
     from mxnet_tpu.ops import flash_attention as fa
 
     plan = fa.plan("tpu", V5E_VMEM, jnp.bfloat16, heads, kv, t, d, True,
                    window)
+    if layer.startswith("mellum2"):
+        assert (plan.bq, plan.bk) == ((256, 128) if window else (128, 512))
 
     def step(q, k, v, g):
         out, vjp = jax.vjp(
@@ -801,22 +809,28 @@ def test_causal_conv_kernels_compile_for_a_v5e_at_the_cell_widths(
     assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
 
 
-@pytest.mark.parametrize("cell", ["sdar", "keye_vl2"])
+@pytest.mark.parametrize("cell", ["sdar", "keye_vl2", "mellum2_full"])
 def test_rotary_kernel_compiles_for_a_v5e_at_the_cell_widths(
         monkeypatch, one_chip, cell):
     """Mosaic accepts ``RotaryEmbedding``'s kernel (``ops/rotary_kernels.py``;
     its other tests are in ``test_rotary_kernels.py``) at the rule's blocks
     for the queries of the SDAR cell, two trunk rows of 8192 over 32 heads
-    of 128, and of the Keye-VL-2.0 cell, one row of 16 384: the operator
-    lowered for the chip, forward and its derivative, which is the same
+    of 128, and of the Keye-VL-2.0 cell, one row of 16 384 (the Mellum2
+    cell's too: its full layer's, turned by the YaRN schedule, which is
+    other numbers in the same tables): the operator lowered for the chip, forward and its derivative, which is the same
     kernel twice. Between the two nothing is kept: the program has no
     temporary."""
     import jax
     import jax.numpy as jnp
 
-    shape = {"sdar": (2, 32, 8192, 128), "keye_vl2": (1, 32, 16384, 128)}[cell]
+    shape = {"sdar": (2, 32, 8192, 128), "keye_vl2": (1, 32, 16384, 128),
+             "mellum2_full": (1, 32, 16384, 128)}[cell]
     monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
-    params = dict(base=1e6, rotary_dim=0, interleaved=False)
+    schedule = dict(
+        base=5e5, scaling="yarn", factor=16.0, original_max_position=8192,
+        attention_factor=1.2772588722239782) if cell == "mellum2_full" \
+        else dict(base=1e6)
+    params = registry.get("RotaryEmbedding").parse_params(schedule)
     mode = registry.OpMode(is_train=True, platform="tpu")
 
     def step(x, dy):

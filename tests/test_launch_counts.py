@@ -31,12 +31,14 @@ def test_the_four_kernel_families_declare_and_nobody_else():
     (and three more names of ``RingAttention``'s under that mode); since PR
     59 ``RotaryEmbedding``, a fifth family of one kernel: its nodes, and
     those of them in the kernel; since PR 61 ``RingAttention``'s layers whose
-    own block (of the block-diffusion mask) is a tile of the kernels."""
+    own block (of the block-diffusion mask) is a tile of the kernels; since
+    PR 62 ``RotaryEmbedding``'s nodes of a scaled schedule and the kept and
+    scored pairs of ``RingAttention``'s window layers."""
     declaring = {name for name, op in registry.canonical_ops().items()
                  if op.launch_instruments}
     assert declaring == set(DECLARING)
     assert sum(len(registry.get(n).launch_instruments)
-               for n in DECLARING) == 31
+               for n in DECLARING) == 34
 
 
 @pytest.mark.parametrize("op", DECLARING)

@@ -4,8 +4,14 @@ operator as it stood before either: ``oracle`` below is that ``jax.numpy``
 form, differentiated by jax. Forward of the three forms to the bit; the
 derivative (the rotation by the negated angle) against ``jax.vjp`` of the
 oracle; the kernel in Pallas's interpreter against the form in both
-directions; the rule; the two counts of a bound train program. The compile
-for a described v5e sits with the others in ``test_grouped_matmul.py``."""
+directions; the rule; the three counts of a bound train program. A schedule
+of frequencies other than the geometric one and an amplitude (``scaling=
+"yarn"``) are cases of the same tests: the oracle makes its tables from the
+published formula itself. The compile for a described v5e sits with the
+others in ``test_grouped_matmul.py``."""
+
+import hashlib
+import math
 
 import numpy as np
 import pytest
@@ -24,14 +30,68 @@ CPU = registry.OpMode(is_train=True, platform="cpu")
 # two row blocks of two tiles, so the loop inside a block and the tables'
 # second block are both walked
 PLAN = rk.Plan(2, 32, 16, 32 << 20)
-FORMS = {
+# Mellum2's full layers (its ``rope_parameters.full_attention``), and a
+# schedule small enough that the ramp rises inside a head of 64 over 40
+# positions, its amplitude the default ``0.1 ln(factor) + 1``
+PUBLISHED_YARN = dict(base=500000.0, scaling="yarn", factor=16.0,
+                      original_max_position=8192, beta_fast=32.0,
+                      beta_slow=1.0, attention_factor=1.2772588722239782)
+SMALL_YARN = dict(base=100.0, scaling="yarn", factor=4.0,
+                  original_max_position=16, beta_fast=2.0, beta_slow=0.25)
+FORMS = {form: registry.get("RotaryEmbedding").parse_params(raw)
+         for form, raw in {
     "rotate_half": dict(base=10000.0, rotary_dim=0, interleaved=False),
     "interleaved": dict(base=1e6, rotary_dim=0, interleaved=True),
     "partial": dict(base=1e7, rotary_dim=16, interleaved=False),
     "partial_interleaved": dict(base=1e7, rotary_dim=16, interleaved=True),
     "rotary_dim_names_the_whole_head": dict(base=1e6, rotary_dim=64,
                                             interleaved=False),
-}
+    "yarn": PUBLISHED_YARN,
+    "yarn_interleaved": dict(SMALL_YARN, interleaved=True),
+    "yarn_partial": dict(SMALL_YARN, rotary_dim=32),
+}.items()}
+
+
+def yarn_inv_freq(half, base, factor, original_max_position, beta_fast,
+                  beta_slow):
+    """``transformers``' ``_compute_yarn_parameters`` (``truncate`` true)
+    in float64, a pair at a time."""
+    d = 2 * half
+
+    def pair_of(rotations):
+        return d * math.log(original_max_position / (
+            rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    out = []
+    for i in range(half):
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        plain = base ** (-i / half)
+        out.append((1.0 - ramp) * plain + ramp * plain / factor)
+    return np.array(out, np.float64), (low, high)
+
+
+def oracle_tables(t, half, params):
+    """cos and sin (t, half) float32 in the operator's stated precision,
+    from the formula and not from the operator."""
+    if params["scaling"]:
+        inv_freq, _ = yarn_inv_freq(
+            half, params["base"], params["factor"],
+            params["original_max_position"], params["beta_fast"],
+            params["beta_slow"])
+        a = params["attention_factor"] \
+            or 0.1 * math.log(params["factor"]) + 1.0
+    else:
+        inv_freq = params["base"] ** (-np.arange(half, dtype=np.float64)
+                                      / half)
+        a = 1.0
+    angle = (np.arange(t, dtype=np.float32)[:, None]
+             * inv_freq.astype(np.float32)[None, :]).astype(np.float64)
+    return ((a * np.cos(angle)).astype(np.float32),
+            (a * np.sin(angle)).astype(np.float32))
 
 
 def oracle(x, params):
@@ -44,12 +104,7 @@ def oracle(x, params):
         return jnp.concatenate([turned, x[..., r:]], axis=-1)
     t, d = x.shape[-2:]
     half = d // 2
-    inv_freq = (params["base"] ** (-np.arange(half, dtype=np.float64) / half)
-                ).astype(np.float32)
-    angle = (np.arange(t, dtype=np.float32)[:, None] * inv_freq[None, :]
-             ).astype(np.float64)
-    cos = np.cos(angle).astype(np.float32)
-    sin = np.sin(angle).astype(np.float32)
+    cos, sin = oracle_tables(t, half, params)
     xf = x.astype(jnp.float32)
     if params["interleaved"]:
         pairs = xf.reshape(x.shape[:-1] + (half, 2))
@@ -117,7 +172,8 @@ def test_forward_is_the_oracles_bits_and_backward_its_pull_back(form, dtype):
                   - np.asarray(jdx0, np.float32)).max() <= ulp * scale
 
 
-@pytest.mark.parametrize("form", ["rotate_half", "partial_interleaved"])
+@pytest.mark.parametrize("form", ["rotate_half", "partial_interleaved",
+                                  "yarn"])
 def test_the_derivative_is_linear_and_can_be_taken_again(form):
     """The pull-back of the pull-back is the operator: the rotation by the
     angle negated twice."""
@@ -132,6 +188,94 @@ def test_the_derivative_is_linear_and_can_be_taken_again(form):
     again = jax.vjp(pulled, g)[1](x)[0]
     assert np.array_equal(np.asarray(again),
                           np.asarray(dt._rotary([x], params, CPU)))
+
+
+# sha256 of the printed jaxpr of output and pull-back at the commit before
+# the operator had a schedule (PR 61's tree, jax 0.9.0)
+_BEFORE_THE_SCHEDULE = {
+    ("rotate_half", "bfloat16"):
+        "a708344497409c60dfc34ffe8959acf117032527b1436eb8cd6c9160cc081e94",
+    ("rotate_half", "float32"):
+        "3a888e8cebe52ff2c8053e5651c98057b34bedb635fb5552fc364f3afc6b34c2",
+    ("interleaved", "bfloat16"):
+        "4acb4939678ed35699205d9e1a5121e9cbe2d2ad0c3b72fbf424880175ad3a30",
+    ("interleaved", "float32"):
+        "c3191d2e22a774bf760a40d2f6167138ae1320075757a1d84272d9a819361fbd",
+    ("partial", "bfloat16"):
+        "25baad7388e283ee0220e45ec1f6ab11a571238ae11a759231ba90e32e75d6f5",
+    ("partial", "float32"):
+        "95f32baa9f159be15b6b8583b80dc23c160cc19960331d42954a55e0f3b7835e",
+}
+
+
+@pytest.mark.parametrize("form,dtype", sorted(_BEFORE_THE_SCHEDULE))
+def test_the_defaults_trace_the_jaxpr_they_traced(form, dtype):
+    """With ``scaling`` "" a node traces, forward and backward, the jaxpr
+    the parent's operator traced, to the digest; a scaled node traces the
+    same equations over other constants."""
+    import jax
+    import jax.numpy as jnp
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests are of jax 0.9.0's printed jaxprs")
+    x = jax.ShapeDtypeStruct((2, 3, 40, 64), jnp.dtype(dtype))
+
+    def text(params):
+        return str(jax.make_jaxpr(lambda x, g: _with_pull_back(
+            lambda x: dt._rotary([x], params, CPU), x, g))(x, x))
+
+    assert hashlib.sha256(text(FORMS[form]).encode()).hexdigest() \
+        == _BEFORE_THE_SCHEDULE[form, dtype]
+    assert text(dict(FORMS[form], **SMALL_YARN)) == text(FORMS[form])
+
+
+@pytest.mark.parametrize("case,t,half", [("published", 16384, 64),
+                                         ("small", 40, 32)])
+def test_yarn_tables_are_the_formula_in_float64(case, t, half):
+    """The frequencies against the formula a pair at a time (the published
+    numbers give the ramp over pairs 18 to 35 of 64: the fast pairs as
+    trained, the slow ones stretched 16 times, a blend between), and the
+    tables bit for bit in the stated precision, the amplitude in them."""
+    raw = PUBLISHED_YARN if case == "published" else SMALL_YARN
+    params = registry.get("RotaryEmbedding").parse_params(raw)
+    schedule = dt._schedule(params)
+    want, (low, high) = yarn_inv_freq(
+        half, *(raw[k] for k in ("base", "factor", "original_max_position",
+                                 "beta_fast", "beta_slow")))
+    got = schedule.inv_freq(half)
+    assert got.dtype == np.float64
+    assert np.allclose(got, want, rtol=1e-15, atol=0.0)
+    plain = raw["base"] ** (-np.arange(half) / half)
+    assert 0 < low < high < half - 1
+    assert np.array_equal(got[:low + 1], plain[:low + 1])
+    assert np.allclose(got[high:], plain[high:] / raw["factor"], rtol=1e-15)
+    ratio = got[low + 1:high] / plain[low + 1:high]
+    assert np.all(np.diff(ratio) < 0) and ratio[0] < 1 \
+        and ratio[-1] > 1 / raw["factor"]
+    if case == "published":
+        assert (low, high) == (18, 35)
+        assert schedule.amplitude() == 1.2772588722239782 \
+            == 0.1 * math.log(16) + 1
+    else:
+        assert schedule.amplitude() == 0.1 * math.log(4.0) + 1.0
+    cos, sin = dt._rotary_tables(t, half, schedule)
+    want_cos, want_sin = oracle_tables(t, half, params)
+    assert np.array_equal(cos, want_cos) and np.array_equal(sin, want_sin)
+    # the amplitude, and that it is no rotation: a pair's length grows by it
+    assert np.allclose(np.hypot(cos, sin), schedule.amplitude(), rtol=1e-6)
+    assert np.array_equal(cos[0], np.full(half, np.float32(
+        schedule.amplitude())))
+
+
+@pytest.mark.parametrize("bad", [
+    dict(scaling="linear"), dict(scaling="yarn"),
+    dict(SMALL_YARN, factor=0.5), dict(SMALL_YARN, beta_slow=4.0),
+    dict(SMALL_YARN, attention_factor=-1.0)])
+def test_a_schedule_the_operator_does_not_define_is_refused(bad):
+    x, _ = _inputs((1, 1, 8, 64), "float32")
+    params = registry.get("RotaryEmbedding").parse_params(bad)
+    with pytest.raises(MXNetError, match="RotaryEmbedding"):
+        dt._rotary([x], params, CPU)
 
 
 @pytest.mark.parametrize("rotary_dim", [3, 65, -2])
@@ -153,14 +297,14 @@ SHAPES = {"batch_2_heads_4": ((2, 4), 64, PLAN),
 _TABLES = dt._rotary_tables
 
 
-def _short_tables(t, half, base, lanes=False):
+def _short_tables(t, half, schedule, lanes=False):
     """The operator's tables kept to bfloat16's 8 bits: a float32 product of
     such a factor with a bfloat16 value is exact, so whether XLA:CPU
     contracts it into the add that follows cannot show in a bit."""
     import jax.numpy as jnp
 
     cos, sin = (np.asarray(jnp.asarray(a).astype(jnp.bfloat16), np.float32)
-                for a in _TABLES(t, half, base))
+                for a in _TABLES(t, half, schedule))
     return rk.lane_tables(cos, sin) if lanes else (cos, sin)
 
 
@@ -174,8 +318,9 @@ def _through_the_kernel(monkeypatch, plan=PLAN):
                                                      True))
 
 
+@pytest.mark.parametrize("form", ["rotate_half", "yarn"])
 @pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_kernel_is_the_form_in_both_directions(monkeypatch, shape):
+def test_kernel_is_the_form_in_both_directions(monkeypatch, shape, form):
     """Output and pull-back of the operator through the kernel against the
     ``jax.numpy`` form: every bit where the products are exact
     (``_short_tables``), and at the operator's own tables to the one
@@ -183,7 +328,7 @@ def test_kernel_is_the_form_in_both_directions(monkeypatch, shape):
     chip the two agree in every bit at the cells' shapes: PERF.md section 6,
     PR 59.)"""
     lead, t, plan = SHAPES[shape]
-    params = FORMS["rotate_half"]
+    params = FORMS[form]
     x, g = _inputs(lead + (t, 128), "bfloat16", seed=1)
 
     def both():
@@ -198,6 +343,11 @@ def test_kernel_is_the_form_in_both_directions(monkeypatch, shape):
         assert off.mean() < 1e-3
         assert np.abs(np.asarray(a, np.float32)
                       - np.asarray(b, np.float32)).max() <= 2.0 ** -6
+    if form == "yarn":      # the scaled tables reached both: a pair grew
+        xf, yf = (np.asarray(a, np.float32) for a in (x, want[0]))
+        grew = np.hypot(yf[..., :64], yf[..., 64:]) / np.maximum(
+            np.hypot(xf[..., :64], xf[..., 64:]), 1e-3)
+        assert abs(np.median(grew) - 1.2772588722239782) < 2.0 ** -6
     monkeypatch.setattr(dt, "_rotary_tables", _short_tables)
     want = both()
     _through_the_kernel(monkeypatch, plan)
@@ -211,7 +361,7 @@ def test_position_zero_is_the_identity_and_a_pairs_length_is_kept():
     """The kernel alone: the angle at t = 0 is 0 in every pair, and a
     rotation keeps every pair's length, to the one rounding."""
     x, _ = _inputs((2, 4, 64, 128), "bfloat16", seed=5)
-    y = np.asarray(rk.turn(x, *dt._rotary_tables(64, 64, 1e6, True), False,
+    y = np.asarray(rk.turn(x, *dt._rotary_tables(64, 64, dt._Schedule(1e6), True), False,
                            PLAN, True), np.float32)
     xf = np.asarray(x, np.float32)
     assert np.array_equal(y[..., 0, :], xf[..., 0, :])
@@ -317,22 +467,27 @@ def test_the_op_asks_the_rule_and_on_the_cpu_hears_none(monkeypatch):
 
 
 # --- a bound train program and its counts --------------------------------------
-def _two_node_graph():
+def _two_node_graph(**schedule):
     """Queries of 4 heads and keys of 1 from one (B, T, 5 x 128) input,
     rotated and scored against each other under a loss head."""
     data = mx.sym.Variable("data")
     heads = mx.sym.transpose(mx.sym.Reshape(data, shape=(0, 0, 5, 128)),
                              axes=(0, 2, 1, 3))
+    schedule = schedule or dict(base=1e6)
     q = mx.sym.RotaryEmbedding(
-        mx.sym.slice_axis(heads, axis=1, begin=0, end=4), base=1e6, name="q")
+        mx.sym.slice_axis(heads, axis=1, begin=0, end=4), name="q",
+        **schedule)
     k = mx.sym.RotaryEmbedding(
-        mx.sym.slice_axis(heads, axis=1, begin=4, end=5), base=1e6, name="k")
+        mx.sym.slice_axis(heads, axis=1, begin=4, end=5), name="k",
+        **schedule)
     return mx.sym.MakeLoss(mx.sym.sum(mx.sym.broadcast_mul(q, k)))
 
 
+@pytest.mark.parametrize("schedule", [{}, PUBLISHED_YARN],
+                         ids=["geometric", "yarn"])
 @pytest.mark.parametrize("mirror", ["", "1"], ids=["kept", "recomputed"])
 def test_a_train_programs_counts_ask_the_rule_the_op_asks(monkeypatch,
-                                                          mirror):
+                                                          mirror, schedule):
     """On the CPU a launch counts both nodes and no kernel node. With the
     rule asked as for one TPU whose half VMEM the queries (4 heads, 32 KiB)
     reach and the keys do not, the program launches through the interpreted
@@ -345,7 +500,7 @@ def test_a_train_programs_counts_ask_the_rule_the_op_asks(monkeypatch,
     x = rs.randn(1, 32, 5 * 128).astype(np.float32)
 
     def launch():
-        exe = _two_node_graph().simple_bind(
+        exe = _two_node_graph(**schedule).simple_bind(
             mx.cpu(), grad_req="write", type_dict={"data": "bfloat16"},
             data=x.shape)
         exe.arg_dict["data"][:] = mx.nd.array(x).astype("bfloat16")
@@ -356,13 +511,16 @@ def test_a_train_programs_counts_ask_the_rule_the_op_asks(monkeypatch,
         after = tm.snapshot()["executor"]
         return (exe.graph.launch_counts,
                 [after.get(n, 0) - before.get(n, 0)
-                 for n in ("rotary_nodes", "rotary_kernel_nodes")],
+                 for n in ("rotary_nodes", "rotary_kernel_nodes",
+                           "rotary_scaled_nodes")],
                 exe.outputs[0].astype("float32").asnumpy(), grad)
 
+    scaled = 2 * bool(schedule)
     counts, moved, out, grad = launch()
     assert counts == {"executor.rotary_nodes": 2,
-                      "executor.rotary_kernel_nodes": 0}
-    assert moved == [2, 0]
+                      "executor.rotary_kernel_nodes": 0,
+                      "executor.rotary_scaled_nodes": scaled}
+    assert moved == [2, 0, scaled]
     rule, turn = rk.kernel_plan, rk.turn
     monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: 64 << 10)
     monkeypatch.setattr(rk, "_TILE", 16)
@@ -375,8 +533,9 @@ def test_a_train_programs_counts_ask_the_rule_the_op_asks(monkeypatch,
                                                      True))
     counts, moved, kernel_out, kernel_grad = launch()
     assert counts == {"executor.rotary_nodes": 2,
-                      "executor.rotary_kernel_nodes": 1}
-    assert moved == [2, 1]
+                      "executor.rotary_kernel_nodes": 1,
+                      "executor.rotary_scaled_nodes": scaled}
+    assert moved == [2, 1, scaled]
     for a, b in ((kernel_out, out), (kernel_grad, grad)):
         assert np.abs(a - b).max() <= 2.0 ** -7 * np.abs(b).max()
 
@@ -389,20 +548,32 @@ def test_the_counts_without_a_bind():
 
     op = registry.get("RotaryEmbedding")
     assert op.launch_instruments == ("executor.rotary_nodes",
-                                     "executor.rotary_kernel_nodes")
+                                     "executor.rotary_kernel_nodes",
+                                     "executor.rotary_scaled_nodes")
     x = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16)
-    assert op.launch_counts([x], [x], FORMS["rotate_half"], "cpu") == {
-        "executor.rotary_nodes": 1, "executor.rotary_kernel_nodes": 0}
+    for form, scaled in (("rotate_half", 0), ("yarn", 1)):
+        assert op.launch_counts([x], [x], FORMS[form], "cpu") == {
+            "executor.rotary_nodes": 1, "executor.rotary_kernel_nodes": 0,
+            "executor.rotary_scaled_nodes": scaled}
 
 
 def test_the_tables_are_made_once_and_cannot_be_written():
     """Every node of a program asks for its layer's tables, once a
     direction: the same read-only arrays come back, the kernel's as
     ``lane_tables`` lays them over a head's 128 lanes."""
-    cos, sin = dt._rotary_tables(48, 64, 1e6)
-    again = dt._rotary_tables(48, 64, 1e6)
+    plain = dt._schedule(FORMS["interleaved"])
+    assert plain == dt._Schedule(1e6) == dt._schedule(dict(
+        FORMS["interleaved"], factor=8.0, attention_factor=2.0))
+    cos, sin = dt._rotary_tables(48, 64, plain)
+    again = dt._rotary_tables(48, 64, dt._Schedule(1e6))
     assert again[0] is cos and again[1] is sin
-    c, s = dt._rotary_tables(48, 64, 1e6, True)
+    # a program's second schedule is another entry and evicts nothing
+    scaled = dt._rotary_tables(48, 64, dt._schedule(FORMS["yarn"]))
+    assert scaled[0] is not cos and not np.array_equal(scaled[0], cos)
+    assert dt._rotary_tables(48, 64, plain)[0] is cos
+    assert dt._rotary_tables(48, 64, dt._schedule(FORMS["yarn"]))[0] \
+        is scaled[0]
+    c, s = dt._rotary_tables(48, 64, plain, True)
     assert np.array_equal(c, np.concatenate([cos, cos], -1))
     assert np.array_equal(s, np.concatenate([-sin, sin], -1))
     for table in (cos, sin, c, s):
