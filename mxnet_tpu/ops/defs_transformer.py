@@ -8,7 +8,9 @@ the public OLMoE model (Muennighoff et al. 2024, arXiv:2409.02060; HF
 ``modeling_olmoe.py``). All are plain jax lowered by XLA, but where an
 operator's rule says its Pallas kernels engage, asked with the platform the
 program is lowered for (``OpMode.platform``): the grouped matmuls of ``MoE``
-(``grouped_matmul.py``, else ``jax.lax.ragged_dot``), ``GatedDeltaRule``,
+(``grouped_matmul.py``, else ``jax.lax.ragged_dot``) and the two row sums of
+its held rounds (``row_sum_kernels.py``, else XLA's scatter-add),
+``GatedDeltaRule``,
 the depthwise ``CausalConv1D`` and ``RotaryEmbedding`` (``rotary_kernels.py``:
 one TPU, a bfloat16 ``data`` of at least half the chip's VMEM, the size from
 which a v5e no longer holds the array between XLA's fusions, whose heads of
@@ -43,6 +45,7 @@ from . import gated_delta as _gdr
 from . import grouped_matmul as _gmm
 from . import pallas_support as _ps
 from . import rotary_kernels as _rk
+from . import row_sum_kernels as _rs
 from .defs_nn import _castp, _prec
 from .registry import Param, keep, register
 
@@ -706,8 +709,61 @@ def held_round_rows(assignments, held, experts):
     return min(assignments, -(-rows // tile) * tile)
 
 
+def _row_sum_plan(platform, dtype, rows, n, h, weights, top_k,
+                  vmem_bytes=None):
+    """``row_sum_kernels``' blocks for the two row sums of a held round of
+    ``rows`` rows of ``dtype`` (., ``h``) into ``n`` tokens, or None:
+    XLA's scatter-add. The kernel engages where the layer's grouped
+    matmuls do (``_expert_plans``) and ``row_sum_kernels.kernel_plan`` has
+    blocks for the round (its size among what it asks). ``_moe``,
+    ``_held_round`` and the layer's launch counts ask it, with the same
+    arguments."""
+    if _expert_plans(platform, dtype, rows, weights, vmem_bytes) is None:
+        return None
+    return _rs.kernel_plan(
+        platform or jax.default_backend(),
+        vmem_bytes or _ps.attached_vmem_bytes(), dtype, rows, n, h,
+        weights[0].shape[0], top_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _take_rows(n, plan, x, tok, runs):
+    """``x[tok]`` for the rows of a held round, whose gradient is the row
+    sum kernel over the round's ``runs`` (float32 sums rounded once to x's
+    dtype) and not the scatter-add autodiff would write."""
+    return x[tok]
+
+
+_take_rows.defvjp(
+    lambda n, plan, x, tok, runs: (x[tok], (tok, runs)),
+    lambda n, plan, res, g: (
+        _rs.sum_rows(g, res[0], None, res[1], n, g.dtype, plan), None, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _sum_weighted_rows(n, plan, y, weight, tok, runs):
+    """(n, H) float32 ``zeros.at[tok].add(y * weight[:, None])`` over a held
+    round's ``runs`` in the row sum kernel; backward is what autodiff
+    writes of that form: the gather ``g[tok]`` times the weight, and its
+    product with ``y`` summed over a row for the weight."""
+    return _rs.sum_rows(y, tok, weight, runs, n, jnp.float32, plan)
+
+
+def _sum_weighted_rows_bwd(n, plan, res, g):
+    y, weight, tok = res
+    g = g[tok]
+    return ((g * weight[:, None]).astype(y.dtype),
+            jnp.sum(g * y.astype(jnp.float32), axis=1), None, None)
+
+
+_sum_weighted_rows.defvjp(
+    lambda n, plan, y, weight, tok, runs: (
+        _sum_weighted_rows(n, plan, y, weight, tok, runs), (y, weight, tok)),
+    _sum_weighted_rows_bwd)
+
+
 def _held_round(first, rows, platform, x, order, weight, counts, w_gate, w_up,
-                w_down):
+                w_down, runs=None):
     """(N, H) float32: what rows ``[first, first + rows)`` of the held
     assignments add to the layer's output. ``order``: the assignments
     (token ``// k``, its j-th expert ``% k``) sorted by expert, the dead
@@ -718,13 +774,18 @@ def _held_round(first, rows, platform, x, order, weight, counts, w_gate, w_up,
     A dead or padded row's weight only has to be finite. The
     grouped matmuls visit only the live rows; their outputs past those are
     not written, so each is masked on both sides (forward and cotangent
-    are then zeros there, never what the buffer held)."""
+    are then zeros there, never what the buffer held). ``runs``
+    (``row_sum_kernels.block_runs`` of the whole list), where
+    ``_row_sum_plan`` has blocks: the round's rows are summed into their
+    tokens, here and in the backward of ``x[tok]``, by the row sum kernel
+    over the runs that fall in the round, and no scatter is traced."""
     ends = jnp.cumsum(counts)
     here = (jnp.clip(ends, first, first + rows)
             - jnp.clip(ends - counts, first, first + rows)).astype(jnp.int32)
     live = (jnp.arange(rows) < jnp.sum(here))[:, None]
     order = jax.lax.dynamic_slice_in_dim(order, first, rows)
-    tok = order // (weight.shape[0] // x.shape[0])            # // top_k
+    top_k = weight.shape[0] // x.shape[0]
+    tok = order // top_k
     weight = keep(weight[order])
     matmul = _expert_matmul(here, x.dtype, rows, (w_gate, w_up, w_down),
                             platform)
@@ -735,15 +796,23 @@ def _held_round(first, rows, platform, x, order, weight, counts, w_gate, w_up,
     # the gathered rows and what each matmul's backward reads, kept under
     # per-operator recomputation (``registry.keep``); masks, casts and the
     # float32 product are made again from them
-    r = keep(x[tok])
+    kernel = None   # (tokens, blocks) of the row sum kernel, where it runs
+    if runs is not None:
+        kernel = (x.shape[0], _row_sum_plan(
+            platform, x.dtype, rows, *x.shape, (w_gate, w_up, w_down),
+            top_k))
+        runs = jnp.clip(runs - first, 0, rows)
+    r = keep(x[tok] if kernel is None else _take_rows(*kernel, x, tok, runs))
     gate, up = keep((live_matmul(r, w_gate), live_matmul(r, w_up)))
     y = keep(live_matmul(keep(jax.nn.silu(gate) * up), w_down))
-    return jnp.zeros(x.shape, jnp.float32).at[tok].add(
-        y.astype(jnp.float32) * weight[:, None])
+    if kernel is None:
+        return jnp.zeros(x.shape, jnp.float32).at[tok].add(
+            y.astype(jnp.float32) * weight[:, None])
+    return _sum_weighted_rows(*kernel, y, weight, tok, runs)
 
 
 def _held_rounds(rows, platform, x, weight, w_gate, w_up, w_down, order,
-                 counts):
+                 counts, runs):
     """The sum of ``_held_round`` over the rounds of ``rows`` rows that
     hold a live row, ``order`` a whole number of rounds long. One round
     (``held_round_rows`` gave every assignment): no loop is traced, the
@@ -753,14 +822,14 @@ def _held_rounds(rows, platform, x, weight, w_gate, w_up, w_down, order,
     routing has collapsed onto the experts held here."""
     if order.shape[0] == rows:
         return _held_round(0, rows, platform, x, order, weight, counts,
-                           w_gate, w_up, w_down)
+                           w_gate, w_up, w_down, runs)
     return _looped_rounds(rows, platform, x, weight, w_gate, w_up, w_down,
-                          order, counts)
+                          order, counts, runs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _looped_rounds(rows, platform, x, weight, w_gate, w_up, w_down, order,
-                   counts):
+                   counts, runs):
     """The first round and a loop over the further live ones. Nothing is
     differentiated through the loop: forward keeps the first round's
     residuals as autodiff would, backward recomputes each further round
@@ -772,35 +841,36 @@ def _looped_rounds(rows, platform, x, weight, w_gate, w_up, w_down, order,
     bfloat16 wgrad written for a loop that does not run is 6 bytes a
     held parameter that the optimizer's fusion would not have moved."""
     return _looped_rounds_fwd(rows, platform, x, weight, w_gate, w_up,
-                              w_down, order, counts)[0]
+                              w_down, order, counts, runs)[0]
 
 
-def _round_of(first, rows, platform, order, counts):
+def _round_of(first, rows, platform, order, counts, runs=None):
     """``_held_round`` at ``first`` as a function of what it is
     differentiated in: x, the routing weights, the three expert weights."""
     return lambda x, weight, *w: _held_round(first, rows, platform, x, order,
-                                             weight, counts, *w)
+                                             weight, counts, *w, runs)
 
 
 def _looped_rounds_fwd(rows, platform, x, weight, w_gate, w_up, w_down,
-                       order, counts):
+                       order, counts, runs):
     wrt = (x, weight, w_gate, w_up, w_down)
-    out, vjp = jax.vjp(_round_of(0, rows, platform, order, counts), *wrt)
+    out, vjp = jax.vjp(_round_of(0, rows, platform, order, counts, runs),
+                       *wrt)
     rounds = (jnp.sum(counts) + rows - 1) // rows
 
     def further(out):
         return jax.lax.fori_loop(
             1, rounds,
             lambda r, acc: acc + _round_of(r * rows, rows, platform, order,
-                                           counts)(*wrt),
+                                           counts, runs)(*wrt),
             out)
 
     out = jax.lax.cond(rounds > 1, further, lambda out: out, out)
-    return out, (vjp, wrt, order, counts, rounds)
+    return out, (vjp, wrt, order, counts, runs, rounds)
 
 
 def _looped_rounds_bwd(rows, platform, res, g):
-    vjp, wrt, order, counts, rounds = res
+    vjp, wrt, order, counts, runs, rounds = res
     # a wgrad is made in the rows' dtype (the kernel writes it, ``_castp``'s
     # transpose rounds to it) and widened to the weight's: narrowing it
     # back is exact, and the narrow one is what crosses the branch
@@ -812,8 +882,8 @@ def _looped_rounds_bwd(rows, platform, res, g):
         return tuple(c.astype(d) for c, d in zip(cts, dtypes))
 
     def more(r, cts):
-        back = jax.vjp(_round_of(r * rows, rows, platform, order, counts),
-                       *wrt)[1]
+        back = jax.vjp(_round_of(r * rows, rows, platform, order, counts,
+                                 runs), *wrt)[1]
         return jax.tree.map(jnp.add, cts, back(g))
 
     def further(cts):   # summed in the weights' dtype, rounded once
@@ -821,7 +891,7 @@ def _looped_rounds_bwd(rows, platform, res, g):
 
     cts = jax.lax.cond(rounds > 1, further, lambda cts: cts,
                        cast(vjp(g), made))
-    return cast(cts, wide) + (None, None)
+    return cast(cts, wide) + (None, None, None)
 
 
 _looped_rounds.defvjp(_looped_rounds_fwd, _looped_rounds_bwd)
@@ -852,7 +922,14 @@ def _moe(ins, params, mode):
     held, one pass over all the rows; a held range whose round
     (``held_round_rows``) is every assignment, that one round and no loop;
     any other held range, the first round and a loop over the further
-    ones (``_held_rounds``).
+    ones (``_held_rounds``). A held round gathers its rows, runs the three
+    grouped matmuls and sums the rows into their tokens; where
+    ``_row_sum_plan`` has blocks (the grouped matmuls' kernels engage, the
+    round is whole long copies and the tokens whole blocks: every
+    held-range cell of the benchmark) that sum and the backward of the
+    gather are one Pallas kernel
+    (``row_sum_kernels.py``) and no scatter is traced, elsewhere XLA's
+    scatter-add.
     """
     x, router, w_gate, w_up, w_down = ins[:5]    # its weight, or logits
     bias = ins[5] if params["expert_bias"] else None
@@ -895,8 +972,15 @@ def _moe(ins, params, mode):
     rounds = -(-n * k // m)
     if rounds * m > n * k:   # whole rounds: more of the dead tail
         order = jnp.pad(order, (0, rounds * m - n * k))
+    # where the round's row sums run their kernel: each token block's runs
+    # of the sorted list, from a count a block of who chose whom
+    plan = _row_sum_plan(mode.platform, x.dtype, m, *x.shape,
+                         (w_gate, w_up, w_down), k)
+    runs = None if plan is None else keep(_rs.block_runs(
+        jnp.any(_chosen(local.reshape(n, k), held), axis=1),
+        jnp.cumsum(counts) - counts, plan.block))
     out = _held_rounds(m, mode.platform, x, p.reshape(-1), w_gate, w_up,
-                       w_down, order, counts)
+                       w_down, order, counts, runs)
     return out.astype(x.dtype).reshape(shape)
 
 
@@ -926,19 +1010,28 @@ def _moe_counts(ins, outs, params, platform):
     them all: ``_moe`` traces no loop over rounds), and how many of
     its nine expert matmuls (forward, dgrad and wgrad of gate, up and down)
     a train program runs in the Pallas kernels, all nine or none: ``_moe``'s
-    own ask of ``_expert_plans``, at the rows of one round."""
+    own ask of ``_expert_plans``, at the rows of one round; and how many of
+    a held round's two row sums (the combine, the dispatch's backward) run
+    the row sum kernel, both or none: ``_moe``'s own ask of
+    ``_row_sum_plan``."""
     x, weights = ins[0], tuple(ins[2:5])
-    routed = int(np.prod(x.shape[:-1])) * params["top_k"]
+    tokens = int(np.prod(x.shape[:-1]))
+    routed = tokens * params["top_k"]
     held = weights[0].shape[0]
     m = held_round_rows(routed, held, params["num_experts"])
     kernels = _expert_plans(platform, x.dtype, m, weights) is not None
+    all_held = held == params["num_experts"] and not params["expert_offset"]
+    row_sums = not all_held and _row_sum_plan(
+        platform, x.dtype, m, tokens, x.shape[-1], weights,
+        params["top_k"]) is not None
     return {"executor.moe_layers": 1,
             "executor.moe_assignments": routed,
             "executor.moe_local_experts": held,
             "executor.moe_graph_routed_layers":
                 int(params["router"] == "graph"),
             "executor.moe_one_round_layers": int(m == routed),
-            "executor.moe_kernel_matmuls": 9 * kernels}
+            "executor.moe_kernel_matmuls": 9 * kernels,
+            "executor.moe_kernel_row_sums": 2 * row_sums}
 
 
 register(
@@ -973,5 +1066,6 @@ register(
                         "executor.moe_local_experts",
                         "executor.moe_graph_routed_layers",
                         "executor.moe_one_round_layers",
-                        "executor.moe_kernel_matmuls"),
+                        "executor.moe_kernel_matmuls",
+                        "executor.moe_kernel_row_sums"),
 )

@@ -1,6 +1,6 @@
 """What the Pallas kernel families share (``grouped_matmul``,
 ``flash_attention``, ``gated_delta_kernels``, ``causal_conv_kernels``,
-``rotary_kernels``) and
+``rotary_kernels``, ``row_sum_kernels``) and
 none of them owns: what the program knows of its device (the VMEM of the one
 TPU the process holds: every family's rule reads it), Pallas itself, imported
 when a kernel is first traced, and the store that keeps a traced kernel

@@ -841,3 +841,47 @@ def test_rotary_kernel_compiles_for_a_v5e_at_the_cell_widths(
     compiled = jax.jit(step).lower(arg, arg).compile()
     assert compiled.as_text().count("rotary_turn") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
+
+
+@pytest.mark.parametrize("cell", ["mellum2", "keye_vl2", "sdar"])
+def test_row_sum_kernel_compiles_for_a_v5e_at_the_cell_widths(
+        monkeypatch, one_chip, cell):
+    """Mosaic accepts ``MoE``'s row sum kernel (``ops/row_sum_kernels.py``;
+    its other tests are in ``test_row_sum_kernels.py``) at the rule's blocks
+    for a layer of the Mellum2 cell (16 384 tokens of 2304, 8 of 64 experts
+    at top-8: rounds of 32 768 rows), of the Keye-VL-2.0 cell (2048, 8 of
+    128: 16 384 rows) and of the SDAR cell (2048, 16 of 128: the widest
+    slots, and the most VMEM the rule asks for): the operator lowered for the chip, forward and
+    gradient, holds the weighted sum, the unweighted one, both again under
+    the branch of the further rounds, and no scatter of rows (the one left
+    is of scalars: the backward of the round's window of weights)."""
+    import jax
+    import jax.numpy as jnp
+
+    n, h, e, f = {"mellum2": (16384, 2304, 64, 896),
+                  "keye_vl2": (16384, 2048, 128, 768),
+                  "sdar": (16384, 2048, 128, 768)}[cell]
+    held = 16 if cell == "sdar" else 8   # the widest slots: 1536 rows
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
+    params = registry.get("MoE").parse_params(dict(
+        num_experts=e, num_hidden=f, top_k=8, num_local_experts=held,
+        route_norm=True))
+    mode = registry.OpMode(is_train=True, platform="tpu")
+
+    def step(x, router, gate, up, down, dy):
+        out, vjp = jax.vjp(
+            lambda *ins: dt._moe(list(ins), params, mode), x, router, gate,
+            up, down)
+        return (out,) + vjp(dy)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        arg((n, h), jnp.bfloat16), arg((e, h), jnp.float32),
+        arg((held, h, f), jnp.float32), arg((held, h, f), jnp.float32),
+        arg((held, f, h), jnp.float32), arg((n, h), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("moe_row_sum") >= 4
+    assert not re.search(r"= \w+\[\d+,\d+\]\S* scatter\(", text)
+    assert re.search(r"= \w+\[\d+\]\S* scatter\(", text)

@@ -33,12 +33,13 @@ def test_the_four_kernel_families_declare_and_nobody_else():
     those of them in the kernel; since PR 61 ``RingAttention``'s layers whose
     own block (of the block-diffusion mask) is a tile of the kernels; since
     PR 62 ``RotaryEmbedding``'s nodes of a scaled schedule and the kept and
-    scored pairs of ``RingAttention``'s window layers."""
+    scored pairs of ``RingAttention``'s window layers; since PR 63 the row
+    sums of ``MoE``'s held rounds that run their kernel."""
     declaring = {name for name, op in registry.canonical_ops().items()
                  if op.launch_instruments}
     assert declaring == set(DECLARING)
     assert sum(len(registry.get(n).launch_instruments)
-               for n in DECLARING) == 34
+               for n in DECLARING) == 35
 
 
 @pytest.mark.parametrize("op", DECLARING)

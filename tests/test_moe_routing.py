@@ -5,7 +5,10 @@ N * k weights. Same bits, forward and backward, and no gather or scatter of
 N * k indices left in a lowered train step. And the held range's rounds
 against a copy of the loop they were: one static round traces no loop, the
 further rounds of the others sit under a branch that the first round's
-wgrads cross in the dtype they were made in."""
+wgrads cross in the dtype they were made in. And the held range's rounds
+once more with their two row sums in the row sum kernel
+(``ops/row_sum_kernels.py``, Pallas's interpreter) against the scatter-adds
+they hold on the CPU."""
 
 import functools
 import re
@@ -19,6 +22,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import executor as ex
 from mxnet_tpu.ops import defs_transformer as dt
 from mxnet_tpu.ops import registry
+from mxnet_tpu.ops import row_sum_kernels as rsk
 from mxnet_tpu.ops.registry import keep
 
 SWITCH = "MXNET_BACKWARD_DO_MIRROR"
@@ -107,7 +111,7 @@ _rounds_before.defvjp(_rounds_before_fwd, _rounds_before_bwd)
 
 
 def _held_rounds_before(rows, platform, x, flat, w_gate, w_up, w_down, order,
-                        counts):
+                        counts, runs=None):
     """Today's call of ``_held_rounds`` answered as ``_moe`` answered it
     before: every assignment's weight gathered into the sorted order, the
     padding zeros of ``tok`` and ``weight``. One static round is answered
@@ -313,16 +317,17 @@ def test_train_step_moves_no_scalar_an_assignment(monkeypatch, held):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _held_rounds_looped(rows, platform, x, weight, w_gate, w_up, w_down,
-                        order, counts):
+                        order, counts, runs):
     """``_held_rounds`` as it was: a loop over the further rounds whatever
     their static count, forward and backward, the backward's started by the
     first round's cotangents in the weights' dtype."""
     return _held_rounds_looped_fwd(rows, platform, x, weight, w_gate, w_up,
-                                   w_down, order, counts)[0]
+                                   w_down, order, counts, runs)[0]
 
 
 def _held_rounds_looped_fwd(rows, platform, x, weight, w_gate, w_up, w_down,
-                            order, counts):
+                            order, counts, runs):
+    assert runs is None   # the CPU: no row sum kernel
     wrt = (x, weight, w_gate, w_up, w_down)
     out, vjp = jax.vjp(dt._round_of(0, rows, platform, order, counts), *wrt)
     rounds = (jnp.sum(counts) + rows - 1) // rows
@@ -340,7 +345,7 @@ def _held_rounds_looped_bwd(rows, platform, res, g):
                        *wrt)[1]
         return jax.tree.map(jnp.add, cts, back(g))
 
-    return jax.lax.fori_loop(1, rounds, more, vjp(g)) + (None, None)
+    return jax.lax.fori_loop(1, rounds, more, vjp(g)) + (None, None, None)
 
 
 _held_rounds_looped.defvjp(_held_rounds_looped_fwd, _held_rounds_looped_bwd)
@@ -502,3 +507,78 @@ def test_launch_counts_say_which_layers_trace_no_loop(held, top_k,
     counts = op.launch_counts(ins, [ins[0]], params, "cpu")
     assert counts["executor.moe_one_round_layers"] == one_round
     assert counts["executor.moe_layers"] == 1
+
+
+# --- the held range's rounds through the row sum kernel ----------------------
+
+def _wide_layer(steer, layout, kernel):
+    """``_layer`` at whole blocks of tokens and whole registers of hidden
+    (256 x 128 over 16 experts: the kernel's blocks), bfloat16 rows;
+    ``kernel``: the two row sums of every round in the kernel."""
+    top_k, held, first, collapsed = LAYOUTS[layout]
+    n, h = 256, 128
+    rs = np.random.RandomState(4)
+    x = rs.randn(n, h).astype(np.float32)
+    router = (rs.randn(E, h) * 0.3).astype(np.float32)
+    if collapsed:
+        x[:, 0] = 3.0
+        router[first:first + held, 0] += 2.0
+    ws = [(rs.randn(*s) * 0.3).astype(np.float32)[first:first + held]
+          for s in ((E, h, F), (E, h, F), (E, F, h))]
+    ins = [jnp.asarray(x, jnp.bfloat16)] + [jnp.asarray(a)
+                                            for a in [router] + ws]
+    params = registry.get("MoE").parse_params(dict(
+        num_experts=E, num_hidden=F, top_k=top_k, num_local_experts=held,
+        expert_offset=first, route_norm=True, lb_coef=0.01))
+    calls = []
+    if kernel:
+        sum_rows = rsk.sum_rows
+
+        def shipped(platform, dtype, rows, tokens, hidden, weights, k,
+                    vmem=None):
+            """The blocks the rule ships, as on a v5e; the layer and each
+            of its rounds ask with the layer's own sizes (a slot sized for
+            fewer rows a token than ``top_k`` overflows under a collapsed
+            router: on the chip a halt)."""
+            assert (rows, tokens, hidden, k) == (
+                dt.held_round_rows(n * top_k, held, E), n, h, top_k)
+            return rsk.kernel_plan("tpu", 128 << 20, dtype, rows, tokens,
+                                   hidden, weights[0].shape[0], k)
+
+        steer.setattr(dt, "_row_sum_plan", shipped)
+        steer.setattr(rsk, "sum_rows", lambda *a: calls.append(a[2] is None)
+                      or sum_rows(*a, interpret=True))
+    head = jnp.asarray(np.random.RandomState(6).randn(n, h), jnp.float32)
+
+    def scalar(*ins):
+        out = dt._moe(list(ins), params, registry.OpMode())
+        return jnp.sum(out.astype(jnp.float32) * head), out
+
+    grads, out = jax.jit(jax.grad(scalar, argnums=tuple(range(5)),
+                                  has_aux=True))(*ins)
+    rounds = -(-n * top_k // dt.held_round_rows(n * top_k, held, E))
+    return [np.asarray(a, np.float32) for a in [out] + list(grads)], (
+        calls, rounds)
+
+
+@pytest.mark.parametrize("layout", [l for l in LAYOUTS if l != "all-held"])
+def test_held_rounds_through_the_row_sum_kernel(monkeypatch, layout):
+    """Output and all five gradients of the held-range layouts with every
+    round's combine and the backward of its dispatch in the kernel, against
+    the scatter-adds: the output's float32 sums differ by the order of their
+    additions, the rows' gradient is the float32 sum rounded once where the
+    scatter-add rounds after every row, and the router's and the three
+    expert weights' gradients read the same rows and cotangents."""
+    sides = []
+    for kernel in (True, False):
+        with monkeypatch.context() as steer:
+            grads, (calls, rounds) = _wide_layer(steer, layout, kernel)
+            sides.append(grads)
+            if kernel:  # a combine and a dispatch's backward a traced round
+                assert sorted(set(calls)) == [False, True]
+                assert len(calls) >= 2 * (1 + (rounds > 1))
+    for name, a, b in zip(MOE_INPUTS, *sides):
+        assert np.abs(b).max() > 0, name
+        tol = 2.0 ** -7 if name in ("out", "x") else 2.0 ** -20
+        np.testing.assert_allclose(a, b, rtol=0, atol=tol * np.abs(b).max(),
+                                   err_msg=name)

@@ -29,6 +29,7 @@ from mxnet_tpu.ops import flash_attention as fa
 from mxnet_tpu.ops import gated_delta as gd
 from mxnet_tpu.ops import gated_delta_kernels as gk
 from mxnet_tpu.ops import registry
+from mxnet_tpu.ops import row_sum_kernels as rsk
 
 ra = importlib.import_module("mxnet_tpu.parallel.ring_attention")
 
@@ -86,7 +87,7 @@ def _gated_delta(steer, kernels=False, channel=False):
     return sym, shapes, {n: "bfloat16" for n in names[:3]}
 
 
-def _moe(steer, held=0, kernels=False):
+def _moe(steer, held=0, kernels=False, row_sums=False):
     sym = mx.sym.MoE(mx.sym.Variable("data"), name="moe", num_experts=8,
                      num_hidden=128, top_k=2, num_local_experts=held,
                      lb_coef=0.01, z_coef=0.001)
@@ -96,6 +97,14 @@ def _moe(steer, held=0, kernels=False):
             dt, "_expert_matmul",
             lambda counts, dtype, m, weights, platform=None: matmul(
                 counts, dtype, m, weights, "tpu", V5E_VMEM, interpret=True))
+    if row_sums:   # a round's two row sums in their kernel too
+        steer.setattr(
+            dt, "_row_sum_plan",
+            lambda platform, dtype, rows, n, h, weights, top_k, vmem=None:
+            rsk.kernel_plan("tpu", V5E_VMEM, dtype, rows, n, h,
+                            weights[0].shape[0], top_k))
+        steer.setattr(rsk, "sum_rows",
+                      functools.partial(rsk.sum_rows, interpret=True))
     return sym, dict(data=(1, 256, 128)), {"data": "bfloat16"}
 
 
@@ -139,6 +148,16 @@ CASES = {
     "moe-one-round-kernels": (
         functools.partial(_moe, held=4, kernels=True), True,
         "pallas_call", 6, 9),
+    # the same two with a round's row sums in their kernel: one more
+    # backward kernel a round (the dispatch's) and, where a round runs
+    # forward again, the combine's; no backward reads the combine's output,
+    # so without the policy the first round's three matmuls alone run twice
+    "moe-held-range-row-sum-kernels": (
+        functools.partial(_moe, held=2, kernels=True, row_sums=True), True,
+        "pallas_call", 18, 21),
+    "moe-one-round-row-sum-kernels": (
+        functools.partial(_moe, held=4, kernels=True, row_sums=True), True,
+        "pallas_call", 7, 10),
 }
 
 
