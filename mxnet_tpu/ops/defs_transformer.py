@@ -581,18 +581,22 @@ def _expert_plans(platform, rows_dtype, m, weights, vmem_bytes=None):
 
 def _expert_matmul(counts, rows_dtype, m, weights, platform=None,
                    vmem_bytes=None, interpret=False):
-    """``f(rows, w)`` for one layer: ``rows`` (M, K) sorted by expert times
-    ``w`` (E, K, N), one of ``weights``, as the parameter is stored,
-    ``counts`` rows an expert. Where ``_expert_plans`` has tiles ``f`` is
-    the Pallas kernels (they cast a weight tile in VMEM), anywhere else
-    ``_castp`` + ``ragged_dot``: decided here, once, in Python."""
+    """``(f, kernels)``: ``f(rows, w)`` for one layer, ``rows`` (M, K)
+    sorted by expert times ``w`` (E, K, N), one of ``weights``, as the
+    parameter is stored, ``counts`` rows an expert. Where ``_expert_plans``
+    has tiles ``f`` is the Pallas kernels (they cast a weight tile in VMEM;
+    ``kernels`` True), anywhere else ``_castp`` + ``ragged_dot``: decided
+    here, once, in Python. The kernels own the rows past ``counts.sum()``
+    (zeros there forward and backward, none read into a live row:
+    ``grouped_matmul.py``); what ``ragged_dot`` leaves there is not
+    specified."""
     plans = _expert_plans(platform, rows_dtype, m, weights, vmem_bytes)
     if plans is None:
         return lambda rows, w: jax.lax.ragged_dot(
-            rows, _castp(w, rows), counts, precision=_prec(rows.dtype))
+            rows, _castp(w, rows), counts, precision=_prec(rows.dtype)), False
     groups = _gmm.groups(counts, m, plans[weights[0].shape[1:]])
     return lambda rows, w: _gmm.grouped_matmul(
-        rows, w, groups, plans[w.shape[1:]], interpret)
+        rows, w, groups, plans[w.shape[1:]], interpret), True
 
 
 def _router_logits(x, w_router):
@@ -763,7 +767,7 @@ _sum_weighted_rows.defvjp(
 
 
 def _held_round(first, rows, platform, x, order, weight, counts, w_gate, w_up,
-                w_down, runs=None):
+                w_down, runs=None, looped=False):
     """(N, H) float32: what rows ``[first, first + rows)`` of the held
     assignments add to the layer's output. ``order``: the assignments
     (token ``// k``, its j-th expert ``% k``) sorted by expert, the dead
@@ -771,31 +775,38 @@ def _held_round(first, rows, platform, x, order, weight, counts, w_gate, w_up,
     routing weight of every assignment, unsorted; ``counts`` (L,) rows an
     expert over the whole list. The round slices its window of ``order``
     and gathers that window's weights alone: ``rows`` scalars, not N * k.
-    A dead or padded row's weight only has to be finite. The
-    grouped matmuls visit only the live rows; their outputs past those are
-    not written, so each is masked on both sides (forward and cotangent
-    are then zeros there, never what the buffer held). ``runs``
-    (``row_sum_kernels.block_runs`` of the whole list), where
+    A dead or padded row's weight only has to be finite. The dead rows
+    are the grouped matmul's: the kernels return zeros there, forward and
+    dgrad, and read none of them into a live row, so ``silu(gate) * up``,
+    ``y``, every cotangent a kernel writes and the weight's gradient are
+    zeros there and no select is traced around a matmul (the cotangent of
+    ``y`` is finite and not zero there: dgrad does not read it into a live
+    row and wgrad zeroes it in VMEM). ``ragged_dot`` specifies nothing past
+    its groups, so on that path each matmul is masked on both sides.
+    ``looped``: a round of the backward's loop over the further rounds
+    (``_looped_rounds_bwd``), which keeps the select of ``y``.
+    ``runs`` (``row_sum_kernels.block_runs`` of the whole list), where
     ``_row_sum_plan`` has blocks: the round's rows are summed into their
     tokens, here and in the backward of ``x[tok]``, by the row sum kernel
     over the runs that fall in the round, and no scatter is traced."""
     ends = jnp.cumsum(counts)
     here = (jnp.clip(ends, first, first + rows)
             - jnp.clip(ends - counts, first, first + rows)).astype(jnp.int32)
+    matmul, kernels = _expert_matmul(here, x.dtype, rows,
+                                     (w_gate, w_up, w_down), platform)
     live = (jnp.arange(rows) < jnp.sum(here))[:, None]
+    live_matmul = matmul
+    if not kernels:   # ragged_dot: what its rows past the groups hold
+        def live_matmul(r, w):
+            return jnp.where(live, matmul(jnp.where(live, r, 0), w), 0)
+
     order = jax.lax.dynamic_slice_in_dim(order, first, rows)
     top_k = weight.shape[0] // x.shape[0]
     tok = order // top_k
     weight = keep(weight[order])
-    matmul = _expert_matmul(here, x.dtype, rows, (w_gate, w_up, w_down),
-                            platform)
-
-    def live_matmul(r, w):
-        return jnp.where(live, matmul(jnp.where(live, r, 0), w), 0)
-
     # the gathered rows and what each matmul's backward reads, kept under
-    # per-operator recomputation (``registry.keep``); masks, casts and the
-    # float32 product are made again from them
+    # per-operator recomputation (``registry.keep``); casts and the float32
+    # product (ragged_dot's masks too) are made again from them
     kernel = None   # (tokens, blocks) of the row sum kernel, where it runs
     if runs is not None:
         kernel = (x.shape[0], _row_sum_plan(
@@ -804,7 +815,16 @@ def _held_round(first, rows, platform, x, order, weight, counts, w_gate, w_up,
         runs = jnp.clip(runs - first, 0, rows)
     r = keep(x[tok] if kernel is None else _take_rows(*kernel, x, tok, runs))
     gate, up = keep((live_matmul(r, w_gate), live_matmul(r, w_up)))
-    y = keep(live_matmul(keep(jax.nn.silu(gate) * up), w_down))
+    y = live_matmul(keep(jax.nn.silu(gate) * up), w_down)
+    if kernels and looped:
+        # zeros selected where zeros are. Its transpose makes the cotangent
+        # of ``y`` a fusion of its own in the loop's body, without which
+        # libtpu 0.0.34 dies compiling a layer's output beside its
+        # gradients at the Keye-VL-2.0 and SDAR shapes (memory space
+        # assignment's repacker; test_row_sum_kernel_compiles_for_a_v5e).
+        # The loop runs where routing has collapsed and nowhere else.
+        y = jnp.where(live, y, 0)
+    y = keep(y)
     if kernel is None:
         return jnp.zeros(x.shape, jnp.float32).at[tok].add(
             y.astype(jnp.float32) * weight[:, None])
@@ -844,11 +864,11 @@ def _looped_rounds(rows, platform, x, weight, w_gate, w_up, w_down, order,
                               w_down, order, counts, runs)[0]
 
 
-def _round_of(first, rows, platform, order, counts, runs=None):
+def _round_of(first, rows, platform, order, counts, runs=None, looped=False):
     """``_held_round`` at ``first`` as a function of what it is
     differentiated in: x, the routing weights, the three expert weights."""
     return lambda x, weight, *w: _held_round(first, rows, platform, x, order,
-                                             weight, counts, *w, runs)
+                                             weight, counts, *w, runs, looped)
 
 
 def _looped_rounds_fwd(rows, platform, x, weight, w_gate, w_up, w_down,
@@ -883,7 +903,7 @@ def _looped_rounds_bwd(rows, platform, res, g):
 
     def more(r, cts):
         back = jax.vjp(_round_of(r * rows, rows, platform, order, counts,
-                                 runs), *wrt)[1]
+                                 runs, looped=True), *wrt)[1]
         return jax.tree.map(jnp.add, cts, back(g))
 
     def further(cts):   # summed in the weights' dtype, rounded once
@@ -956,8 +976,8 @@ def _moe(ins, params, mode):
         # what the three matmuls' backward reads, kept under per-operator
         # recomputation like the held range's first round
         rows = keep(_permute_rows(jnp.repeat(x, k, axis=0), order, inverse))
-        matmul = _expert_matmul(counts, x.dtype, m, (w_gate, w_up, w_down),
-                                mode.platform)
+        matmul, _ = _expert_matmul(counts, x.dtype, m,
+                                   (w_gate, w_up, w_down), mode.platform)
         gate, up = keep((matmul(rows, w_gate), matmul(rows, w_up)))
         out = keep(matmul(keep(jax.nn.silu(gate) * up), w_down))
         out = _permute_rows(out, inverse, order).reshape(n, k, -1)
@@ -1010,7 +1030,12 @@ def _moe_counts(ins, outs, params, platform):
     them all: ``_moe`` traces no loop over rounds), and how many of
     its nine expert matmuls (forward, dgrad and wgrad of gate, up and down)
     a train program runs in the Pallas kernels, all nine or none: ``_moe``'s
-    own ask of ``_expert_plans``, at the rows of one round; and how many of
+    own ask of ``_expert_plans``, at the rows of one round; how many of
+    those nine a held round runs with no row select around them, nine
+    where the kernels run (they own the round's dead rows; the first
+    round's count, the one every step runs: a round of the backward's loop
+    keeps the select of ``y``) or none (``ragged_dot``; every expert held:
+    no dead row); and how many of
     a held round's two row sums (the combine, the dispatch's backward) run
     the row sum kernel, both or none: ``_moe``'s own ask of
     ``_row_sum_plan``."""
@@ -1031,7 +1056,8 @@ def _moe_counts(ins, outs, params, platform):
                 int(params["router"] == "graph"),
             "executor.moe_one_round_layers": int(m == routed),
             "executor.moe_kernel_matmuls": 9 * kernels,
-            "executor.moe_kernel_row_sums": 2 * row_sums}
+            "executor.moe_kernel_row_sums": 2 * row_sums,
+            "executor.moe_unmasked_matmuls": 9 * (kernels and not all_held)}
 
 
 register(
@@ -1067,5 +1093,6 @@ register(
                         "executor.moe_graph_routed_layers",
                         "executor.moe_one_round_layers",
                         "executor.moe_kernel_matmuls",
-                        "executor.moe_kernel_row_sums"),
+                        "executor.moe_kernel_row_sums",
+                        "executor.moe_unmasked_matmuls"),
 )

@@ -4,10 +4,19 @@ expert weights as the parameter is stored.
 ``grouped_matmul(rows, w, groups(counts, M, plan), plan)``: ``rows`` (M, K)
 sorted by expert, ``w`` (E, K, N) in the dtype and row-major layout of the
 parameter (float32 masters under a bfloat16 trunk), ``counts`` (E,) int32
-summing to M. Expert ``e`` multiplies its own ``counts[e]`` rows by
+summing to M or less. Expert ``e`` multiplies its own ``counts[e]`` rows by
 ``w[e]``; float32 accumulation; the result comes back in ``rows.dtype``. No
 capacity and no dropped row: an expert with no rows, one expert with every
 row and group boundaries inside a row tile all work.
+
+The rows past ``counts.sum()`` are no group's: the dead rows of a held
+round of ``MoE``. The kernels own them, so a caller masks nothing. Forward
+and dgrad return zeros there, in every row tile (one no group visits is
+visited once more for its zeros, ``_visit_lists``), and read none of them
+into a live row: what ``rows`` and the cotangent hold there may be anything,
+NaN too. wgrad multiplies no dead row of the cotangent (zeroed in VMEM, NaN
+too) and asks of ``rows`` there finite numbers only. Counts that sum to M
+visit what they did before.
 
 What the kernels do that ``_castp`` + ``jax.lax.ragged_dot`` + autodiff
 does not:
@@ -143,7 +152,7 @@ class Groups(NamedTuple):
     counts: jax.Array        # (E,) rows a group: what ragged_dot reads
     offsets: jax.Array       # (E + 1,) first row of each group
     group_ids: jax.Array     # a visit's group, forward and dgrad
-    m_tile_ids: jax.Array    # a visit's row tile
+    m_tile_ids: jax.Array    # a visit's row tile; the dead tiles' come last
     ordinal: jax.Array       # a visit's group, counted among the visited
     order: jax.Array         # (E,) the visited groups in order
     nvisited: jax.Array      # (1,) how many groups have rows
@@ -153,29 +162,42 @@ class Groups(NamedTuple):
     wgrad_visits: jax.Array
 
 
-def _visit_lists(counts, m, tm, visit_empty):
+def _visit_lists(counts, m, tm, wgrad):
     """(offsets (E + 1,), a visit's group, its row tile, how many visits):
     one visit a (group, row tile) pair that share a row, groups in order,
-    so a tile that holds a boundary is visited once a group; with
-    ``visit_empty`` an empty group has one visit too (wgrad has its zeros
-    to write). At most ``m / tm + E - 1`` visits, the lists' static
-    length; entries past the count are never run. megablox's
-    ``make_group_metadata`` gives the same lists; this one is dense
-    compares over (visits, E), a fraction of its program text."""
+    so a tile that holds a boundary is visited once a group. ``wgrad``'s
+    lists give an empty group one visit too (it has its zeros to write);
+    the forward's and dgrad's give one to each row tile past
+    ``counts.sum()``, after the live ones, under the last group that has
+    rows (they have its zeros to write: the group's rows end before the
+    tile, so no product runs). At most ``m / tm + E - 1`` visits either
+    way (the live ones are at most the live tiles + E - 1), the lists'
+    static length; entries past the count are never run. Where the counts
+    fill ``m`` megablox's ``make_group_metadata`` gives the same lists;
+    this one is dense compares over (visits, E), a fraction of its program
+    text."""
     e, tiles = counts.shape[0], m // tm
     ends = jnp.cumsum(counts)
     starts = ends - counts
     first = jnp.minimum(starts // tm, tiles - 1)
     n = jnp.where(counts > 0, (ends - 1) // tm - first + 1,
-                  1 if visit_empty else 0)
+                  1 if wgrad else 0)
     upto = jnp.cumsum(n)                  # visits of groups 0 .. g
     i = jnp.arange(tiles + e - 1, dtype=jnp.int32)
     group = jnp.minimum(jnp.sum(i[:, None] >= upto[None, :], axis=1), e - 1)
     tile = _pick(first - (upto - n), group) + i
+    visits = upto[-1]
+    if not wgrad:
+        live_tiles = (ends[-1] + tm - 1) // tm
+        dead = i >= visits
+        group = jnp.where(dead, jnp.max(jnp.where(counts > 0, jnp.arange(e),
+                                                  0)), group)
+        tile = jnp.where(dead, live_tiles + i - visits, tile)
+        visits = visits + tiles - live_tiles
     offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
     return (offsets.astype(jnp.int32), group.astype(jnp.int32),
             jnp.clip(tile, 0, tiles - 1).astype(jnp.int32),
-            upto[-1].astype(jnp.int32))
+            visits.astype(jnp.int32))
 
 
 def _pick(values, index):
@@ -250,9 +272,14 @@ def _for_the_slabs(width, slab):
     "tm", "panel", "transposed", "vmem_limit", "interpret"))
 def _gmm(rows, w, gr, *, tm, panel, transposed, vmem_limit, interpret):
     """``rows[group e] @ w[e]`` (``transposed``: ``@ w[e].T``, read as
-    stored). Grid (panels of the output width, visits); a panel of one
-    expert's matrix stays in VMEM over the visits of its group while the
-    next group's is in flight."""
+    stored), zeros in every row past ``counts.sum()``. Grid (panels of the
+    output width, visits); a panel of one expert's matrix stays in VMEM
+    over the visits of its group while the next group's is in flight. The
+    visit of a dead row tile (``_visit_lists``) comes under the last
+    group, whose rows end before it: no product, no weight copy (the
+    ordinal of the visit before it) and no row tile fetched (the input's
+    block is the last live tile's again, which the pipeline does not copy
+    twice); it costs the store of its zeros."""
     pl, pltpu = _ps._pallas()
     m, depth = rows.shape
     e, k, n = w.shape
@@ -268,7 +295,9 @@ def _gmm(rows, w, gr, *, tm, panel, transposed, vmem_limit, interpret):
         j, i = pl.program_id(0), pl.program_id(1)
         group, nth = group_ids[i], ordinal[i]
         prev = jnp.maximum(i - 1, 0)
-        first = jnp.logical_or(i == 0, ordinal[prev] != nth)
+        # no group has rows: every visit is a dead tile's, no weight moves
+        first = jnp.logical_and(
+            nvisited[0] > 0, jnp.logical_or(i == 0, ordinal[prev] != nth))
         slot = (j * nvisited[0] + nth) % 2
 
         def fetch(g, p, s):
@@ -332,8 +361,10 @@ def _gmm(rows, w, gr, *, tm, panel, transposed, vmem_limit, interpret):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             in_specs=[
-                pl.BlockSpec((tm, depth),
-                             lambda j, i, o, g, mt, *_: (mt[i], 0)),
+                # a dead tile's visit fetches no rows: the last live tile's
+                # block again
+                pl.BlockSpec((tm, depth), lambda j, i, o, g, mt, *_: (
+                    jnp.minimum(mt[i], jnp.maximum(o[e] - 1, 0) // tm), 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((tm, panel),
@@ -432,7 +463,8 @@ def _tgmm(rows, g, gr, *, tm, panel, vmem_limit, interpret):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def grouped_matmul(rows, w, gr, plan, interpret=False):
     """``rows`` (M, K) sorted by group times ``w`` (E, K, N) as stored;
-    ``gr = groups(counts, M, plan)`` with ``counts.sum() == M``; tiles from
+    ``gr = groups(counts, M, plan)`` with ``counts.sum() <= M``, zeros in
+    the rows past it (the module's docstring has the contract); tiles from
     ``plan`` (a ``Plan``): the kernels, in a program lowered for a TPU.
     ``interpret`` runs them in Pallas's interpreter (tests on the CPU)."""
     return _ps._kernel(_gmm, (rows, w, gr), tm=plan.tm, panel=plan.tn,

@@ -31,7 +31,20 @@ PATTERNS = {
     "one_expert_takes_every_row": [0, 0, 512, 0],
     "boundaries_inside_a_tile": [100, 156, 3, 253],
     "last_group_short": [255, 129, 127, 1],
+    # a dead tail: rows past counts.sum() that are no group's (``ROWS`` has
+    # M), which the kernels own: zeros forward and dgrad, nothing of them
+    # in a live row or in wgrad
+    "dead_tail_half_the_rows": [100, 56, 3, 97],
+    "dead_tail_all_but_one_row": [0, 1, 0, 0],
+    "dead_tail_of_whole_tiles": [60, 40, 20, 8],
+    "dead_tail_after_an_empty_last_group": [130, 126, 44, 0],
+    "dead_tail_and_no_live_row": [0, 0, 0, 0],
 }
+# M where it is more than the counts' sum
+ROWS = {"dead_tail_half_the_rows": 512, "dead_tail_all_but_one_row": 512,
+        "dead_tail_of_whole_tiles": 1024,
+        "dead_tail_after_an_empty_last_group": 768,
+        "dead_tail_and_no_live_row": 512}
 DTYPES = {"bf16_rows_f32_weights": "float32",
           "bf16_rows_bf16_weights": "bfloat16"}
 K, N = 256, 128
@@ -39,33 +52,54 @@ K, N = 256, 128
 
 @functools.lru_cache(maxsize=None)
 def _kernels_and_ragged_dot(pattern, weight_dtype):
-    """{kind: (kernel's, ragged_dot's)} for one pattern and weight dtype;
-    panels of 128 so that the forward and dgrad kernels walk two weight
-    panels a group and the prefetch wraps from one panel to the next."""
+    """{kind: (kernel's, ragged_dot's, the kernel's as it came, its twin)}
+    for one pattern and weight dtype; panels of 128 so that the forward and
+    dgrad kernels walk two weight panels a group and the prefetch wraps
+    from one panel to the next. With a dead tail the kernels are handed
+    large finite rows and a NaN cotangent there and ``ragged_dot`` is masked
+    on both sides, the form ``MoE`` wrapped around it; the twin is the same
+    kernel where the call is what it was before the tail was the kernels':
+    forward and dgrad with the tail given to the last group that has rows
+    (counts that fill M), wgrad with zeros in the tail."""
     import jax
     import jax.numpy as jnp
 
     counts = jnp.asarray(PATTERNS[pattern], jnp.int32)
-    m, e = int(counts.sum()), counts.shape[0]
+    live, e = int(counts.sum()), counts.shape[0]
+    m = ROWS.get(pattern, live)
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
     rows = jax.random.normal(keys[0], (m, K)).astype(jnp.bfloat16)
     w = (0.1 * jax.random.normal(keys[1], (e, K, N))).astype(weight_dtype)
     g = jax.random.normal(keys[2], (m, N)).astype(jnp.bfloat16)
+    mask = (jnp.arange(m) < live)[:, None]
     plan = gm.Plan(tm=256, tmw=128, tn=128, tk=128, tw=128,
                    vmem_limit=32 << 20)
 
-    def kernel(r, w):
+    def kernel(r, w, counts=counts):
         return gm.grouped_matmul(r, w, gm.groups(counts, m, plan), plan, True)
 
     def ragged(r, w):
-        return jax.lax.ragged_dot(r, w.astype(jnp.bfloat16), counts)
+        return jnp.where(mask, jax.lax.ragged_dot(
+            jnp.where(mask, r, 0), w.astype(jnp.bfloat16), counts), 0)
 
-    out, vjp = jax.vjp(kernel, rows, w)
-    want, want_vjp = jax.vjp(ragged, rows, w)
-    got = (out,) + vjp(g)
-    want = (want,) + want_vjp(g)
-    return {kind: (np.asarray(a, np.float32), np.asarray(b, np.float32), a)
-            for kind, a, b in zip(("forward", "dgrad", "wgrad"), got, want)}
+    def both(f, r, g):
+        out, vjp = jax.vjp(f, r, w)
+        return (out,) + vjp(g)
+
+    got = both(kernel, jnp.where(mask, rows, 3e38).astype(rows.dtype),
+               jnp.where(mask, g, jnp.nan))
+    want = both(ragged, rows, g)
+    twin = (None,) * 3
+    if live < m:
+        last = max(np.flatnonzero(PATTERNS[pattern]), default=0)
+        filled = both(functools.partial(
+            kernel, counts=counts.at[last].add(m - live)), rows, g)
+        zeros = both(kernel, jnp.where(mask, rows, 0), jnp.where(mask, g, 0))
+        twin = tuple(np.asarray(a, np.float32)
+                     for a in filled[:2] + zeros[2:])
+    return {kind: (np.asarray(a, np.float32), np.asarray(b, np.float32), a, t)
+            for kind, a, b, t in zip(("forward", "dgrad", "wgrad"), got,
+                                     want, twin)}
 
 
 @pytest.mark.parametrize("kind", ["forward", "dgrad", "wgrad"])
@@ -75,15 +109,28 @@ def test_kernel_matches_ragged_dot(pattern, weights, kind):
     """Both round float32 sums of the same bfloat16 products to bfloat16
     once; they may order the sums differently, so an element is at most a
     last place of a bfloat16 (2^-8 of its size) apart."""
-    got, want, raw = _kernels_and_ragged_dot(pattern, DTYPES[weights])[kind]
+    got, want, raw, twin = _kernels_and_ragged_dot(
+        pattern, DTYPES[weights])[kind]
     assert got.shape == want.shape
     assert str(raw.dtype) == {"wgrad": DTYPES[weights]}.get(kind, "bfloat16")
     assert np.isfinite(got).all()
-    scale = np.maximum(np.abs(want), np.abs(want).max() * 2.0 ** -7)
+    scale = np.maximum(np.abs(want), max(np.abs(want).max() * 2.0 ** -7,
+                                         1e-30))  # no live row: all zeros
     assert np.max(np.abs(got - want) / scale) <= 2.0 ** -7
     if kind == "wgrad":     # an expert with no rows has a zero gradient
         for e, c in enumerate(PATTERNS[pattern]):
             assert c or not got[e].any()
+    if twin is None:
+        return
+    # a dead tail: the live rows' bits are those of the call that had no
+    # tail, and nothing the tail held reached them or wgrad
+    live = sum(PATTERNS[pattern])
+    if kind == "wgrad":
+        assert np.array_equal(got, twin)
+    else:
+        assert got.shape[0] == ROWS[pattern] > live
+        assert not got[live:].any()
+        assert np.array_equal(got[:live], twin[:live])
 
 
 def test_in_kernel_cast_is_astype_bfloat16_bit_for_bit():
@@ -112,6 +159,12 @@ VISIT_CASES = {
     "empty_groups_first_last_and_between": ([0, 300, 0, 0, 212, 0], 128),
     "one_group_has_every_row": ([0, 0, 1024, 0], 256),
     "many_groups_in_one_tile": ([5, 7, 1, 3, 112, 128], 128),
+    # (counts, row tile, M): a dead tail past the counts' sum
+    "dead_tail_of_whole_tiles": ([100, 56, 0, 100], 256, 1024),
+    "dead_tail_all_but_one_row": ([0, 0, 1, 0], 128, 1024),
+    "dead_tail_from_inside_a_tile": ([0, 300, 0, 0, 212, 0], 128, 1024),
+    "dead_tail_after_an_empty_last_group": ([130, 126, 44, 0], 256, 768),
+    "dead_tail_and_no_live_row": ([0, 0, 0], 128, 256),
 }
 
 
@@ -120,25 +173,34 @@ VISIT_CASES = {
 def test_visit_lists_are_megabloxs(case, visit_empty):
     """The dense-compare visit lists against the metadata of jax's own
     megablox kernels, which they replace: same visits in the same order
-    (the row tile of an empty group's visit is free: nothing is read)."""
+    (the row tile of an empty group's visit is free: nothing is read). The
+    lists as ``groups`` asks for them: wgrad's visit the empty groups, the
+    forward's the dead tiles, one visit each after megablox's, in order,
+    under the last group that has rows, within the static length."""
     import jax.numpy as jnp
     from jax.experimental.pallas.ops.tpu.megablox.gmm import (
         make_group_metadata)
 
-    counts, tm = VISIT_CASES[case]
-    m = sum(counts)
+    counts, tm = VISIT_CASES[case][:2]
+    m = (VISIT_CASES[case] + (sum(counts),))[2]
     (offsets, group_ids, m_tile_ids), visits = make_group_metadata(
         group_sizes=jnp.asarray(counts, jnp.int32), m=m, tm=tm,
         start_group=jnp.int32(0), num_nonzero_groups=len(counts),
         visit_empty_groups=visit_empty)
     got = gm._visit_lists(jnp.asarray(counts, jnp.int32), m, tm, visit_empty)
     v = int(visits)
-    assert int(got[3]) == v
+    live_tiles = -(-sum(counts) // tm)
+    dead = 0 if visit_empty else m // tm - live_tiles
+    assert int(got[3]) == v + dead <= len(got[1]) == m // tm + len(counts) - 1
     assert np.array_equal(got[0], offsets)
     assert np.array_equal(got[1][:v], group_ids[:v])
     held = np.asarray(counts)[np.asarray(group_ids[:v])] > 0
     assert np.array_equal(np.asarray(got[2][:v])[held],
                           np.asarray(m_tile_ids[:v])[held])
+    assert dead == 0 or len(VISIT_CASES[case]) == 3
+    assert np.array_equal(got[2][v:v + dead], live_tiles + np.arange(dead))
+    assert np.array_equal(got[1][v:v + dead], [max(
+        np.flatnonzero(counts), default=0)] * dead)
 
 
 def test_kernels_come_back_from_the_cache_directory(tmp_path, monkeypatch):
@@ -357,13 +419,13 @@ def _moe_three_ways():
     kernel = run(on_a_v5e)
     assert "pallas_call" in str(jax.make_jaxpr(
         lambda *ins: on_a_v5e(
-            jnp.asarray([32] * 4, jnp.int32), tok.dtype, 128, ws[1:])(*ins))(
-                jnp.repeat(tok, 2, 0), ws[1]))
+            jnp.asarray([32] * 4, jnp.int32), tok.dtype, 128, ws[1:])[0](
+                *ins))(jnp.repeat(tok, 2, 0), ws[1]))
     # lowered for the CPU: ragged_dot alone, whatever is attached
     assert "pallas_call" not in str(jax.make_jaxpr(
         lambda *ins: expert_matmul(
             jnp.asarray([32] * 4, jnp.int32), tok.dtype, 128, ws[1:], "cpu",
-            V5E_VMEM)(*ins))(jnp.repeat(tok, 2, 0), ws[1]))
+            V5E_VMEM)[0](*ins))(jnp.repeat(tok, 2, 0), ws[1]))
     ragged = run(expert_matmul)     # no TPU here: ragged_dot alone
     with jax.default_matmul_precision("highest"):
         grads, out = jax.grad(reference, argnums=tuple(range(5)),
@@ -851,10 +913,16 @@ def test_row_sum_kernel_compiles_for_a_v5e_at_the_cell_widths(
     for a layer of the Mellum2 cell (16 384 tokens of 2304, 8 of 64 experts
     at top-8: rounds of 32 768 rows), of the Keye-VL-2.0 cell (2048, 8 of
     128: 16 384 rows) and of the SDAR cell (2048, 16 of 128: the widest
-    slots, and the most VMEM the rule asks for): the operator lowered for the chip, forward and
-    gradient, holds the weighted sum, the unweighted one, both again under
-    the branch of the further rounds, and no scatter of rows (the one left
-    is of scalars: the backward of the round's window of weights)."""
+    slots, and the most VMEM the rule asks for): the operator lowered for the
+    chip, forward and gradient, holds the weighted sum, the unweighted one,
+    both again under the branch of the further rounds, no scatter of rows
+    (the one left is of scalars: the backward of the round's window of
+    weights) and, since the grouped matmuls own a round's dead rows, no
+    select over a round's rows outside the loop of the further rounds'
+    backward, whose select of ``y`` stays (``_held_round``). One program
+    returns the output beside the gradients: without that select libtpu
+    0.0.34 falls over compiling it at the Keye-VL-2.0 and SDAR shapes
+    (PERF.md section 7)."""
     import jax
     import jax.numpy as jnp
 
@@ -885,3 +953,8 @@ def test_row_sum_kernel_compiles_for_a_v5e_at_the_cell_widths(
     assert text.count("moe_row_sum") >= 4
     assert not re.search(r"= \w+\[\d+,\d+\]\S* scatter\(", text)
     assert re.search(r"= \w+\[\d+\]\S* scatter\(", text)
+    rows = dt.held_round_rows(n * 8, held, e)
+    assert re.search(rf"= f32\[{rows},{f}\]\S* multiply\(", text)   # is read
+    # the select of ``y`` and its transpose, in the further rounds' backward
+    assert len(re.findall(rf"= bf16\[{rows},(?:{h}|{f})\]\S* select\(",
+                          text)) == 2

@@ -34,12 +34,13 @@ def test_the_four_kernel_families_declare_and_nobody_else():
     own block (of the block-diffusion mask) is a tile of the kernels; since
     PR 62 ``RotaryEmbedding``'s nodes of a scaled schedule and the kept and
     scored pairs of ``RingAttention``'s window layers; since PR 63 the row
-    sums of ``MoE``'s held rounds that run their kernel."""
+    sums of ``MoE``'s held rounds that run their kernel; since PR 64 the
+    expert matmuls of a held round that no row select is traced around."""
     declaring = {name for name, op in registry.canonical_ops().items()
                  if op.launch_instruments}
     assert declaring == set(DECLARING)
     assert sum(len(registry.get(n).launch_instruments)
-               for n in DECLARING) == 35
+               for n in DECLARING) == 36
 
 
 @pytest.mark.parametrize("op", DECLARING)
@@ -170,3 +171,32 @@ def test_an_imperative_moe_is_told_its_operands_platform(monkeypatch):
     # the same operands with no platform said: the default backend's, a plan
     assert rule(None, "bfloat16", 512, [w._data for w in weights[1:]]) \
         is not None
+
+
+@pytest.mark.parametrize("held,trunk,kernels,unmasked", [
+    (8, "bfloat16", 9, 9),    # the kernels own a held round's dead rows
+    (8, "float32", 0, 0),     # ragged_dot keeps its masks
+    (0, "bfloat16", 9, 0),    # every expert held: no dead row to mask
+], ids=["held-range-bfloat16", "held-range-float32", "every-expert-held"])
+def test_moe_counts_the_matmuls_no_select_is_traced_around(
+        monkeypatch, held, trunk, kernels, unmasked):
+    """``executor.moe_unmasked_matmuls`` with a v5e attached, a layer of
+    the Mellum2 cell's shapes: nine where the grouped matmuls' kernels run
+    a held round, from the same ask of the rule as
+    ``executor.moe_kernel_matmuls``."""
+    import jax
+
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: 128 << 20)
+    op = registry.get("MoE")
+    params = op.parse_params(dict(num_experts=64, num_hidden=896, top_k=8,
+                                  num_local_experts=held))
+    local = held or 64
+    ins = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((16384, 2304), trunk), ((64, 2304), "float32"),
+        ((local, 2304, 896), "float32"), ((local, 2304, 896), "float32"),
+        ((local, 896, 2304), "float32"))]
+    counts = op.launch_counts(ins, ins[:1], params, "tpu")
+    assert counts["executor.moe_kernel_matmuls"] == kernels
+    assert counts["executor.moe_unmasked_matmuls"] == unmasked
+    assert op.launch_counts(ins, ins[:1], params, "cpu")[
+        "executor.moe_unmasked_matmuls"] == 0

@@ -8,7 +8,10 @@ further rounds of the others sit under a branch that the first round's
 wgrads cross in the dtype they were made in. And the held range's rounds
 once more with their two row sums in the row sum kernel
 (``ops/row_sum_kernels.py``, Pallas's interpreter) against the scatter-adds
-they hold on the CPU."""
+they hold on the CPU. And a held round whose grouped matmuls run their
+kernels (``ops/grouped_matmul.py``, the interpreter), which own the round's
+dead rows: no row select is traced around a matmul, and the bits are those
+of the same kernels masked on both sides, the form ``ragged_dot`` keeps."""
 
 import functools
 import re
@@ -63,7 +66,7 @@ def _held_round_before(first, rows, x, tok, weight, counts, w_gate, w_up,
     live = (jnp.arange(rows) < jnp.sum(here))[:, None]
     tok = jax.lax.dynamic_slice_in_dim(tok, first, rows)
     weight = jax.lax.dynamic_slice_in_dim(weight, first, rows)
-    matmul = dt._expert_matmul(here, x.dtype, rows, (w_gate, w_up, w_down))
+    matmul = dt._expert_matmul(here, x.dtype, rows, (w_gate, w_up, w_down))[0]
 
     def live_matmul(r, w):
         return jnp.where(live, matmul(jnp.where(live, r, 0), w), 0)
@@ -511,12 +514,16 @@ def test_launch_counts_say_which_layers_trace_no_loop(held, top_k,
 
 # --- the held range's rounds through the row sum kernel ----------------------
 
-def _wide_layer(steer, layout, kernel):
+def _wide_layer(steer, layout, kernel, width=F, masked=None):
     """``_layer`` at whole blocks of tokens and whole registers of hidden
     (256 x 128 over 16 experts: the kernel's blocks), bfloat16 rows;
-    ``kernel``: the two row sums of every round in the kernel."""
+    ``kernel``: the two row sums of every round in the kernel; ``width``:
+    of an expert; ``masked`` not None: the grouped matmuls in their kernels
+    too (``_on_the_kernels``, which wants ``width`` whole registers)."""
     top_k, held, first, collapsed = LAYOUTS[layout]
     n, h = 256, 128
+    if masked is not None:
+        _on_the_kernels(steer, masked)
     rs = np.random.RandomState(4)
     x = rs.randn(n, h).astype(np.float32)
     router = (rs.randn(E, h) * 0.3).astype(np.float32)
@@ -524,11 +531,11 @@ def _wide_layer(steer, layout, kernel):
         x[:, 0] = 3.0
         router[first:first + held, 0] += 2.0
     ws = [(rs.randn(*s) * 0.3).astype(np.float32)[first:first + held]
-          for s in ((E, h, F), (E, h, F), (E, F, h))]
+          for s in ((E, h, width), (E, h, width), (E, width, h))]
     ins = [jnp.asarray(x, jnp.bfloat16)] + [jnp.asarray(a)
                                             for a in [router] + ws]
     params = registry.get("MoE").parse_params(dict(
-        num_experts=E, num_hidden=F, top_k=top_k, num_local_experts=held,
+        num_experts=E, num_hidden=width, top_k=top_k, num_local_experts=held,
         expert_offset=first, route_norm=True, lb_coef=0.01))
     calls = []
     if kernel:
@@ -582,3 +589,166 @@ def test_held_rounds_through_the_row_sum_kernel(monkeypatch, layout):
         tol = 2.0 ** -7 if name in ("out", "x") else 2.0 ** -20
         np.testing.assert_allclose(a, b, rtol=0, atol=tol * np.abs(b).max(),
                                    err_msg=name)
+
+
+# --- a held round on the kernel path: the grouped matmuls own its dead rows -------
+
+V5E_VMEM = 128 << 20
+WIDE = 128          # hidden and expert width: whole registers, so a plan
+
+
+def _on_the_kernels(steer, masked):
+    """``MoE``'s expert matmuls through the Pallas kernels, as on a v5e
+    (the rule's own plan, Pallas's interpreter); ``masked``: each wrapped in
+    the two row selects a held round traced around it before the kernels
+    owned the dead rows, so what ``_held_round`` then traces is that form."""
+    plain = dt._expert_matmul
+
+    def forced(counts, dtype, m, weights, platform=None):
+        matmul, kernels = plain(counts, dtype, m, weights, "tpu", V5E_VMEM,
+                                interpret=True)
+        assert kernels
+        if not masked:
+            return matmul, True
+        live = (jnp.arange(m) < jnp.sum(counts))[:, None]
+        return lambda r, w: jnp.where(
+            live, matmul(jnp.where(live, r, 0), w), 0), True
+
+    steer.setattr(dt, "_expert_matmul", forced)
+
+
+# rounds of 384 rows (three row tiles of 128) over 768 assignments of 256
+# tokens; name: (rows an expert, the round's first row)
+ROUND = 384
+HELD_ROUNDS = {
+    # 197 live rows: a full tile, a boundary inside the second, a dead one
+    "one-live-round": ([100, 0, 37, 60], 0),
+    "two-live-rounds-the-first": ([200, 150, 0, 130], 0),     # no dead row
+    # 96 live rows: two dead tiles after the one that holds the boundary
+    "two-live-rounds-the-second": ([200, 150, 0, 130], ROUND),
+    "a-dead-further-round": ([100, 0, 37, 60], ROUND),
+}
+
+
+def _round_inputs(counts):
+    rs = np.random.RandomState(9)
+    n, k = 256, 3
+    x = jnp.asarray(rs.randn(n, WIDE), jnp.bfloat16)
+    flat = jnp.asarray(rs.rand(n * k) + 0.1, jnp.float32)
+    ws = [jnp.asarray(rs.randn(*s) * 0.2, jnp.float32)
+          for s in ((4, WIDE, WIDE), (4, WIDE, WIDE), (4, WIDE, WIDE))]
+    order = jnp.asarray(rs.permutation(n * k), jnp.int32)
+    head = jnp.asarray(rs.randn(n, WIDE), jnp.float32)
+    return (x, flat, *ws), order, jnp.asarray(counts, jnp.int32), head
+
+
+def _round(first, order, counts, head, looped=False):
+    def scalar(x, flat, *ws):
+        out = dt._held_round(first, ROUND, None, x, order, flat, counts, *ws,
+                             looped=looped)
+        return jnp.sum(out * head), out
+
+    return scalar
+
+
+@pytest.mark.parametrize("case", list(HELD_ROUNDS))
+def test_unmasked_round_gives_the_masked_rounds_bits(monkeypatch, case):
+    """Output and all five gradients of one round: the kernels alone
+    against the kernels masked on both sides."""
+    counts, first = HELD_ROUNDS[case]
+    wrt, order, counts, head = _round_inputs(counts)
+    sides = []
+    for masked in (False, True):
+        with monkeypatch.context() as steer:
+            _on_the_kernels(steer, masked)
+            grads, out = jax.jit(jax.grad(
+                _round(first, order, counts, head), argnums=tuple(range(5)),
+                has_aux=True))(*wrt)
+            sides.append([np.asarray(a, np.float32)
+                          for a in [out] + list(grads)])
+    for name, a, b in zip(["out", "x", "weight", "g", "u", "o"], *sides):
+        assert (np.abs(b).max() > 0) == (case != "a-dead-further-round"), name
+        np.testing.assert_array_equal(a, b, name)
+
+
+def _row_selects(jaxpr, shapes):
+    """``select_n`` equations of ``jaxpr`` and what it calls (the Pallas
+    kernels' bodies apart) over an operand of one of ``shapes``."""
+    from jax._src import core as jcore
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "select_n" and any(
+                v.aval.shape in shapes for v in eqn.invars):
+            found.append(eqn)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    found += _row_selects(sub, shapes)
+    return found
+
+
+@pytest.mark.parametrize("looped", [False, True],
+                         ids=["first-round", "backwards-loop"])
+@pytest.mark.parametrize("gradient", [False, True],
+                         ids=["forward", "gradient"])
+def test_kernel_path_traces_no_row_select(monkeypatch, gradient, looped):
+    """With a plan, the round and its gradient hold no ``select_n`` over a
+    (rows, H) or (rows, F) array (F is twice H here, to tell them apart),
+    but the select of ``y`` and its transpose in a round of the backward's
+    loop; without a plan (the CPU as it stands: ``ragged_dot``) every
+    matmul keeps its two, and autodiff transposes them."""
+    counts, first = HELD_ROUNDS["one-live-round"]
+    (x, flat, *_), order, counts, head = _round_inputs(counts)
+    rs = np.random.RandomState(3)
+    wide = 2 * WIDE
+    ws = [jnp.asarray(rs.randn(*s) * 0.2, jnp.float32)
+          for s in ((4, WIDE, wide), (4, WIDE, wide), (4, wide, WIDE))]
+    shapes = ((ROUND, WIDE), (ROUND, wide))
+
+    def jaxpr():    # a function of its own a trace: jax keeps traces by it
+        scalar = _round(first, order, counts, head, looped)
+        return jax.make_jaxpr(
+            jax.grad(scalar, argnums=tuple(range(5)), has_aux=True)
+            if gradient else scalar)(x, flat, *ws).jaxpr
+
+    with monkeypatch.context() as steer:
+        _on_the_kernels(steer, masked=False)
+        kernels = jaxpr()
+    assert "pallas_call" in str(kernels)
+    assert len(_row_selects(kernels, shapes)) == looped * (1 + gradient)
+    assert not _row_selects(kernels, shapes[1:])
+    with monkeypatch.context() as steer:       # what this test sees
+        _on_the_kernels(steer, masked=True)
+        masked = jaxpr()
+    plain = jaxpr()
+    assert "pallas_call" not in str(plain) and "ragged_dot" in str(plain)
+    both = 12 if gradient else 6
+    assert len(_row_selects(plain, shapes)) == both
+    assert len(_row_selects(masked, shapes)) == both + looped * (1 + gradient)
+
+
+@pytest.mark.parametrize("row_sums", [False, True],
+                         ids=["scatter-adds", "row-sum-kernel"])
+@pytest.mark.parametrize("layout", [l for l in LAYOUTS if l != "all-held"])
+def test_unmasked_layer_gives_the_masked_layers_bits(monkeypatch, layout,
+                                                     row_sums):
+    """A whole held-range layer, its first round and the loop over the
+    further ones: the kernels alone against the kernels masked on both
+    sides, with a round's rows summed by XLA's scatter-add and by the row
+    sum kernel, whose chunks read dead rows of ``y`` and of the rows'
+    cotangent beside the live ones (zeros either way)."""
+    sides = []
+    for masked in (False, True):
+        with monkeypatch.context() as steer:
+            grads, (calls, _) = _wide_layer(steer, layout, row_sums, WIDE,
+                                            masked)
+            assert bool(calls) == row_sums
+            sides.append(grads)
+    for name, a, b in zip(MOE_INPUTS, *sides):
+        assert np.abs(b).max() > 0, name
+        np.testing.assert_array_equal(a, b, name)

@@ -237,12 +237,13 @@ def _recomputed(jaxpr, primitive):
 
 # --- (a) what the operator's checkpoint saves ---------------------------------
 
-def test_checkpoint_saves_the_marked_values_and_nothing_the_rule_forbids(
-        monkeypatch, case):
+def _kept_by_the_operators_checkpoint(monkeypatch, build):
+    """(the bound executor, the operands of the executor's checkpoint around
+    the operator, [(aval, why)] of what that checkpoint saves beside its
+    arguments) under the switch."""
     import jax
     from jax._src.ad_checkpoint import saved_residuals
 
-    name, build, marks = case[:3]
     made = []
     checkpoint = jax.checkpoint
 
@@ -262,8 +263,15 @@ def test_checkpoint_saves_the_marked_values_and_nothing_the_rule_forbids(
     jax.make_jaxpr(fun)(*args)
     monkeypatch.setattr(jax, "checkpoint", checkpoint)
     operator, (ins,) = made[0]          # the executor's: the outermost
-    kept = [(aval, why) for aval, why in saved_residuals(operator, ins)
-            if "from the argument" not in why]
+    return exe, ins, [(aval, why) for aval, why
+                      in saved_residuals(operator, ins)
+                      if "from the argument" not in why]
+
+
+def test_checkpoint_saves_the_marked_values_and_nothing_the_rule_forbids(
+        monkeypatch, case):
+    name, build, marks = case[:3]
+    exe, ins, kept = _kept_by_the_operators_checkpoint(monkeypatch, build)
     assert bool(kept) == marks
     # of the order of the operands and the output: no score tile, nothing
     # that grows with T x T or with a vocabulary (a float32 copy of the
@@ -292,6 +300,34 @@ def test_checkpoint_saves_the_marked_values_and_nothing_the_rule_forbids(
         # the routed experts, the rows an expert, the sorted order
         assert {(512,), (8,)} <= set(shapes)
     assert exe._kept_residual_nodes == int(marks)
+
+
+# name: (rows of a round, how many round-sized arrays its checkpoint keeps)
+ROUNDS_KEPT = {
+    # ``ragged_dot`` keeps its masks: the rows as each of the gate's and
+    # the up's matmul read them and the product as the down's did (masked
+    # copies, ``_where``'s outputs) beside the marked values
+    "moe-held-range": (256, 7),
+    # the kernels own the dead rows: their backward reads the marked rows
+    # and product themselves, and the three masked copies are two arrays
+    "moe-held-range-kernels": (256, 6),
+    "moe-held-range-row-sum-kernels": (256, 6),
+    "moe-one-round-kernels": (512, 5),
+    "moe-one-round-row-sum-kernels": (512, 5),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ROUNDS_KEPT))
+def test_a_rounds_kept_residuals_are_the_marked_values(monkeypatch, name):
+    """What a held round's checkpoint keeps at the round's size: on the
+    kernel path the values ``_held_round`` marks and nothing a select
+    made of them (no ``_where`` output: no select is traced there), one
+    array fewer than with the masks; the ``ragged_dot`` path as it was."""
+    rows, count = ROUNDS_KEPT[name]
+    kept = [why for aval, why in _kept_by_the_operators_checkpoint(
+        monkeypatch, CASES[name][0])[2] if aval.shape == (rows, 128)]
+    assert len(kept) == count
+    assert any("'_where'" in why for why in kept) == ("kernels" not in name)
 
 
 # --- (b) the forward runs once --------------------------------------------------
