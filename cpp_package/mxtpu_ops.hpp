@@ -533,6 +533,13 @@ inline Symbol SVMOutput(const std::string& symbol_name, const Symbol& data, cons
   return detail::CreateOp("SVMOutput", symbol_name, 2, keys__, ins__, params__);
 }
 
+inline Symbol SelectiveScan(const std::string& symbol_name, const Symbol& data, const Symbol* dt = nullptr, const Symbol* A_log = nullptr, const Symbol* B = nullptr, const Symbol* C = nullptr, const Symbol* D = nullptr, const Symbol* dt_bias = nullptr) {
+  detail::ParamMap params__;
+  const char* keys__[] = {"data", "dt", "A_log", "B", "C", "D", "dt_bias"};
+  const Symbol* ins__[] = {&data, dt, A_log, B, C, D, dt_bias};
+  return detail::CreateOp("SelectiveScan", symbol_name, 7, keys__, ins__, params__);
+}
+
 inline Symbol SequenceLast(const std::string& symbol_name, const Symbol& data, bool use_sequence_length = false) {
   detail::ParamMap params__;
   params__.emplace_back("use_sequence_length", detail::str(use_sequence_length));

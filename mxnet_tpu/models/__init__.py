@@ -9,8 +9,11 @@ the keys a learned indexer selects) sparse-expert decoders, Ouro (a
 looped dense decoder: one stack run four times over the same weights, an
 exit after every pass), SDAR (a sparse-expert decoder trained as a
 block-diffusion model: every row read twice, a noised copy and a clean one)
-and Mellum 2 (a sparse-expert decoder whose window and full layers each turn
-by their own rotary schedule, the full ones by YaRN's).
+, Mellum 2 (a sparse-expert decoder whose window and full layers each turn
+by their own rotary schedule, the full ones by YaRN's) and Phi-4-mini-flash
+(SambaY: Mamba-1 selective scans and differential attention over a band,
+then a cross-decoder whose layers read one layer's keys and values and one
+scan's memory).
 
 Reference: ``example/image-classification/symbols/*.py`` and
 ``example/rnn``/``example/gan``. Builders return plain Symbols usable with
@@ -40,6 +43,7 @@ from .keye_vl2 import keye_vl2_sym_gen
 from .ouro import ouro_sym_gen
 from .sdar import sdar_sym_gen
 from .mellum import mellum_sym_gen
+from .phi4flash import phi4flash_sym_gen
 from . import ssd
 from . import zoo
 from .zoo import SCORE_SYMBOLS

@@ -90,22 +90,26 @@ def kernel_plan(dtype, x_shape, taps, platform=None,
     cannot partition a Mosaic call over several), the convolution is
     depthwise (``num_group`` 0), ``data`` is bfloat16 (a float32 trunk keeps
     the ``jax.numpy`` form), 128 divides the channels, the taps before t
-    fit the carried rows, and ``data`` is at least half the chip's VMEM: a
-    smaller array XLA can hold there between its fusions, where the
+    fit the carried rows, and ``data`` is at least a quarter of the chip's
+    VMEM: a smaller array XLA can hold there between its fusions, where the
     ``jax.numpy`` form's passes cost less than HBM's and fuse with the
     nodes around them, while a Mosaic call reads and writes HBM (on a v5e,
     128 MiB: at 20 MiB, ZAYA1's 1280 channels, the form runs forward and
     backward in 0.10 ms, under the 0.13 its bytes would take across HBM,
-    and the cell's step is 1.4 ms longer with the kernels; at 128 MiB,
-    Qwen3-Next's 8192 channels, 5.04 ms against the kernels' 1.28; PERF.md
-    section 6, PR 46). The op and its launch counts ask it with the same
+    and the cell's step is 1.4 ms longer with the kernels; at 40 MiB, T 4096
+    over a Mamba mixer's 5120 channels, forward / forward + backward 0.33 /
+    1.35 ms against the kernels' 0.23 / 0.58; at 128 MiB, Qwen3-Next's 8192
+    channels, 5.04 ms against the kernels' 1.28; PERF.md section 6, PR 46
+    and PR 65: the threshold was a half until PR 65 measured between the
+    two, and no other cell's convolution lies between a quarter and a
+    half). The op and its launch counts ask it with the same
     arguments."""
     vmem = _ps.attached_vmem_bytes()
     if (platform or jax.default_backend()) != "tpu" or not vmem:
         return None
     B, T, C = x_shape
     if (num_group or jnp.dtype(dtype) != jnp.bfloat16 or C % _LANES
-            or not 1 <= taps <= _HALO + 1 or B * T * C * 2 < vmem // 2):
+            or not 1 <= taps <= _HALO + 1 or B * T * C * 2 < vmem // 4):
         return None
     channels = next(c for c in _CHANNELS if C % c == 0)
     # the fewest blocks of at most _TIME rows, T padded to whole tiles

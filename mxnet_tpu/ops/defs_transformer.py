@@ -1,6 +1,7 @@
 """Transformer-block operators: ``RMSNorm``, ``RotaryEmbedding``, the
-sparse-expert layer ``MoE`` and the linear-attention pair ``CausalConv1D``
-and ``GatedDeltaRule`` (``gated_delta.py``). (Attention is ``RingAttention``
+sparse-expert layer ``MoE``, the linear-attention pair ``CausalConv1D``
+and ``GatedDeltaRule`` (``gated_delta.py``) and the state-space mixer's
+``SelectiveScan`` (``selective_scan.py``). (Attention is ``RingAttention``
 in ``defs_contrib.py``, whose one-device path is blockwise.)
 
 No reference twin: MXNet 0.x has none of them. The equations are those of
@@ -10,12 +11,12 @@ operator's rule says its Pallas kernels engage, asked with the platform the
 program is lowered for (``OpMode.platform``): the grouped matmuls of ``MoE``
 (``grouped_matmul.py``, else ``jax.lax.ragged_dot``) and the two row sums of
 its held rounds (``row_sum_kernels.py``, else XLA's scatter-add),
-``GatedDeltaRule``,
+``GatedDeltaRule``, ``SelectiveScan``,
 the depthwise ``CausalConv1D`` and ``RotaryEmbedding`` (``rotary_kernels.py``:
 one TPU, a bfloat16 ``data`` of at least half the chip's VMEM, the size from
 which a v5e no longer holds the array between XLA's fusions, whose heads of
 128 turn whole in rotate-half pairs; PERF.md section 6, PR 59). Each of the
-four also declares, beside its ``fn`` and asking the same rule with the same
+five also declares, beside its ``fn`` and asking the same rule with the same
 arguments, what one launch of a train program that holds it counts
 (``OpDef.launch_counts``). Where the whole head turns ``RotaryEmbedding``
 is differentiated by no autodiff, in either form: its backward is the
@@ -25,7 +26,9 @@ What is float32 whatever the trunk's dtype: the statistics of ``RMSNorm``,
 the angles and the rotation of ``RotaryEmbedding``, in ``MoE`` the
 router (logits, softmax, top-k, both regularisers), and in
 ``GatedDeltaRule`` the decays (a head or a key channel), ``beta``, the
-chunks' triangular inverse and the state. Outputs come back in the dtype of ``data``.
+chunks' triangular inverse and the state, and in ``SelectiveScan`` the
+steps, the decays, the state and the sum over it. Outputs come back in the
+dtype of ``data``.
 """
 
 from __future__ import annotations
@@ -45,6 +48,7 @@ from . import gated_delta as _gdr
 from . import grouped_matmul as _gmm
 from . import pallas_support as _ps
 from . import rotary_kernels as _rk
+from . import selective_scan as _ssm
 from . import row_sum_kernels as _rs
 from .defs_nn import _castp, _prec
 from .registry import Param, keep, register
@@ -370,7 +374,7 @@ def _causal_conv1d(ins, params, mode):
     (C, K). K shifted multiply-adds in float32.
     Where the rule says so (``causal_conv_kernels.kernel_plan``, asked with
     the platform the program is lowered for: one TPU, a bfloat16 ``data``
-    of at least half its VMEM whose channels 128 divides) forward and
+    of at least a quarter of its VMEM whose channels 128 divides) forward and
     backward are one Pallas kernel each (``ops/causal_conv_kernels.py``,
     PR 46), the same arithmetic in the same order. Everywhere else (the
     CPU, several chips, a float32 trunk, other widths, smaller arrays) the
@@ -516,6 +520,66 @@ register(
                         "executor.linear_attention_kernel_layers",
                         "executor.linear_attention_scan_kernel_layers",
                         "executor.linear_attention_channel_gated_layers"),
+)
+
+
+# --- SelectiveScan -------------------------------------------------------------
+def _selective_scan(ins, params, mode):
+    """The selective scan of a Mamba-1 mixer (``selective_scan.py`` has the
+    equations and what is float32): ``data`` and ``dt`` (B, T, C), ``A_log``
+    (C, N), ``B`` and ``C`` (B, T, N), ``D`` and ``dt_bias`` (C,) -> (B, T,
+    C) in ``data``'s dtype. ``dt`` is the step's projection as it leaves its
+    ``FullyConnected``, bias-free: the operator adds ``dt_bias`` and takes
+    the softplus itself, in float32, so that no float32 (B, T, C) array of
+    steps stands between the two nodes. The mixer's gate ``y * silu(z)`` is
+    the graph's. The state starts at 0 in every row and is never reset inside
+    one. Where the rule says so (``selective_scan.kernel_plan``, asked with
+    the platform the program is lowered for) forward and backward are one
+    Pallas kernel each; everywhere else the ``jax.numpy`` form, a chunk of
+    tokens at a time."""
+    x, dt, a_log, b, c, d, dt_bias = ins
+    kernels = _ssm.kernel_plan(x.dtype, x.shape, a_log.shape[1],
+                               mode.platform)
+    if kernels is not None:
+        return _ssm.selective_scan(x, dt, a_log, b, c, d, dt_bias, kernels)
+    return _ssm.selective_scan_chunked(x, dt, a_log, b, c, d, dt_bias)
+
+
+def _selective_scan_fill(shapes, p):
+    data, a_log = shapes[0], shapes[2]
+    if data is not None:
+        shapes[1] = shapes[1] or data
+        for i in (5, 6):
+            shapes[i] = shapes[i] or (data[-1],)
+        if a_log is not None:
+            for i in (3, 4):
+                shapes[i] = shapes[i] or (data[0], data[1], a_log[1])
+    return shapes
+
+
+def _selective_scan_counts(ins, outs, params, platform):
+    """A launch's counts for one node: the state elements it updates (batch
+    x T x channels x states: one decay, one multiply-add and one share of
+    the output's sum each) and whether a train program runs it in the
+    Pallas kernels: ``_selective_scan``'s own ask of
+    ``selective_scan.kernel_plan``."""
+    x, a_log = ins[0], ins[2]
+    kernels = _ssm.kernel_plan(x.dtype, x.shape, a_log.shape[1], platform)
+    return {"executor.selective_scan_layers": 1,
+            "executor.selective_scan_kernel_layers": int(kernels is not None),
+            "executor.selective_scan_state_updates":
+                int(np.prod(x.shape)) * a_log.shape[1]}
+
+
+register(
+    "SelectiveScan",
+    _selective_scan,
+    arg_names=["data", "dt", "A_log", "B", "C", "D", "dt_bias"],
+    fill_in_shapes=_selective_scan_fill,
+    launch_counts=_selective_scan_counts,
+    launch_instruments=("executor.selective_scan_layers",
+                        "executor.selective_scan_kernel_layers",
+                        "executor.selective_scan_state_updates"),
 )
 
 

@@ -135,8 +135,10 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
     the program is lowered for one TPU whose VMEM is known, the operands
     are bfloat16 (a float32 trunk keeps the ``jax.numpy`` blocks at
     ``precision=HIGHEST``), the value width is a multiple of 128 and the
-    key width one of 128 or of 128 plus a half (192: a half tile of lanes
-    is the narrowest Mosaic contracts over unpadded), the key/value heads
+    key width a multiple of a half tile of lanes (64 alone, a differential
+    pair's queries and keys under its two value heads side by side; 128s;
+    128s plus a half, 192: a half tile of lanes is the narrowest Mosaic
+    contracts over unpadded), the key/value heads
     divide the query heads, T is a multiple of a key block, and what
     backward keeps in VMEM for one key/value head (k and dk, v and dv in
     and out, float32 accumulators: 12 T bytes a lane of either width as
@@ -177,7 +179,7 @@ def plan(platform, vmem_bytes, dtype, heads, kv_heads, T, D, causal=True,
     value_dim = value_dim or D
     if jnp.dtype(dtype) != jnp.bfloat16 or heads % kv_heads:
         return None
-    if value_dim % _LANES or D % _LANES not in (0, _LANES // 2) or D < _LANES:
+    if value_dim % _LANES or D % _LANES not in (0, _LANES // 2):
         return None
     if window and not causal:
         return None

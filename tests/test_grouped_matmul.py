@@ -871,6 +871,78 @@ def test_causal_conv_kernels_compile_for_a_v5e_at_the_cell_widths(
     assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
 
 
+def test_selective_scan_kernels_compile_for_a_v5e_at_the_cell_widths(
+        monkeypatch, one_chip):
+    """Mosaic accepts the two kernels of ``SelectiveScan``
+    (``ops/selective_scan.py``; their other tests are in
+    ``test_selective_scan.py``) at the rule's blocks for the phi4-mini-flash
+    cell's scan, T 4096 over 5120 channels of 16 states. Between forward and
+    backward the program holds ``B`` and ``C`` over lanes (bfloat16, 16 MiB
+    each), their gradients' sums (float32, 32 MiB each) and the start
+    states: no (T, C, N) array (1.25 GiB in float32)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import selective_scan as ss
+
+    b, t, c, n = 1, 4096, 5120, 16
+    monkeypatch.setattr(ps, "attached_vmem_bytes", lambda: V5E_VMEM)
+    plan = ss.kernel_plan(jnp.bfloat16, (b, t, c), n, "tpu")
+    assert plan is not None and t % plan.time == 0 and c % plan.lanes == 0
+
+    def step(*a):
+        out, vjp = jax.vjp(lambda *z: ss.selective_scan(*z, plan), *a[:-1])
+        return (out,) + vjp(a[-1])
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        arg((b, t, c)), arg((b, t, c)), arg((c, n), jnp.float32),
+        arg((b, t, n)), arg((b, t, n)), arg((c,), jnp.float32),
+        arg((c,), jnp.float32), arg((b, t, c))).compile()
+    text = compiled.as_text()
+    assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 128 << 20
+
+
+@pytest.mark.parametrize("window", [512, 0], ids=["band_512", "full"])
+def test_differential_attention_kernels_compile_for_a_v5e_at_the_cell_widths(
+        one_chip, window):
+    """Mosaic accepts the fused attention kernels at keys of 64 under values
+    of 128 (a differential pair's node in the phi4-mini-flash cell: 20
+    query heads over 10 key/value heads, T 4096), under the band of 512 keys
+    and without one; no score tile among the temporaries."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import flash_attention as fa
+
+    ra = importlib.import_module("mxnet_tpu.parallel.ring_attention")
+    heads, kv, t, d, dv = 20, 10, 4096, 64, 128
+    plan = fa.plan("tpu", V5E_VMEM, jnp.bfloat16, heads, kv, t, d, True,
+                   window, dv)
+    assert (plan.bq, plan.bk) == (512, 128 if window else 512)
+
+    def step(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v: ra.blockwise_attention(
+                q, k, v, True, d ** -0.5, 256, window, plan), q, k, v)
+        return (out,) + vjp(g)
+
+    def arg(h, w):
+        return jax.ShapeDtypeStruct((1, h, t, w), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(step).lower(arg(heads, d), arg(kv, d), arg(kv, dv),
+                                   arg(heads, dv)).compile()
+    text = compiled.as_text()
+    assert "attention_fwd" in text and "attention_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 8 << 20
+
+
 @pytest.mark.parametrize("cell", ["sdar", "keye_vl2", "mellum2_full"])
 def test_rotary_kernel_compiles_for_a_v5e_at_the_cell_widths(
         monkeypatch, one_chip, cell):

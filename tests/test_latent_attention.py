@@ -165,7 +165,11 @@ V5E_VMEM = 128 << 20
     # widths the kernels do not take
     ("keys_of_160", (32, 32, 4096, 160, True, 0, 128), None),
     ("values_of_64", (32, 32, 4096, 192, True, 0, 64), None),
-    ("keys_of_64_over_values_of_128", (32, 32, 4096, 64, True, 0, 128), None),
+    ("keys_of_32_over_values_of_128", (32, 32, 4096, 32, True, 0, 128), None),
+    # since PR 65 a half tile of lanes alone is a key width (a differential
+    # pair's queries and keys under its two value heads side by side)
+    ("keys_of_64_over_values_of_128", (32, 32, 4096, 64, True, 0, 128),
+     (512, 512)),
 ])
 def test_rule_gives_a_plan_at_192_over_128_and_the_old_tiles(case, args,
                                                              tiles):

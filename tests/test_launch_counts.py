@@ -3,7 +3,7 @@
 launch of a train program that holds it counts, and lists the instruments
 it may name; the executor sums what the nodes say while it lowers them and
 knows no operator by name. No Pallas interpreter and no Mosaic compile
-here: the kernel families' rules (five since PR 59's ``RotaryEmbedding``)
+here: the kernel families' rules (six since PR 65's ``SelectiveScan``)
 have their own files (the other two
 declaring operators, ``ExitSoftmaxOutput`` and ``BlockDiffusionNoise``, have
 no kernel and no rule)."""
@@ -22,7 +22,8 @@ from mxnet_tpu.ops import registry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DECLARING = ("BlockDiffusionNoise", "CausalConv1D", "ExitSoftmaxOutput",
-             "GatedDeltaRule", "MoE", "RingAttention", "RotaryEmbedding")
+             "GatedDeltaRule", "MoE", "RingAttention", "RotaryEmbedding",
+             "SelectiveScan")
 
 
 def test_the_four_kernel_families_declare_and_nobody_else():
@@ -35,12 +36,14 @@ def test_the_four_kernel_families_declare_and_nobody_else():
     PR 62 ``RotaryEmbedding``'s nodes of a scaled schedule and the kept and
     scored pairs of ``RingAttention``'s window layers; since PR 63 the row
     sums of ``MoE``'s held rounds that run their kernel; since PR 64 the
-    expert matmuls of a held round that no row select is traced around."""
+    expert matmuls of a held round that no row select is traced around;
+    since PR 65 ``SelectiveScan``, a sixth family (its nodes, those in the
+    kernels, the state elements they update)."""
     declaring = {name for name, op in registry.canonical_ops().items()
                  if op.launch_instruments}
     assert declaring == set(DECLARING)
     assert sum(len(registry.get(n).launch_instruments)
-               for n in DECLARING) == 36
+               for n in DECLARING) == 39
 
 
 @pytest.mark.parametrize("op", DECLARING)
